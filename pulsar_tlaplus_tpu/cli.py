@@ -23,6 +23,18 @@ import sys
 import time
 
 
+def _jax_setup(args) -> None:
+    """Backend choice and the one compile cache (utils/device.py),
+    settled before anything compiles."""
+    import jax
+
+    from pulsar_tlaplus_tpu.utils.device import setup_compile_cache
+
+    if args.cpu:
+        jax.config.update("jax_platforms", "cpu")
+    setup_compile_cache()
+
+
 def _positive_or_tpu(v: str):
     return v if v == "tpu" else int(v)
 
@@ -801,10 +813,7 @@ def _report_job_result(job_id: str, state: str, result, error) -> int:
 
 
 def _cmd_serve(args) -> int:
-    if args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+    _jax_setup(args)
     from pulsar_tlaplus_tpu.service.scheduler import ServiceConfig
     from pulsar_tlaplus_tpu.service.server import ServiceDaemon
 
@@ -1163,7 +1172,7 @@ def _cmd_ledger(args) -> int:
 
     def _rec_of(ref: str, recs):
         # a REF that names an existing file ingests on the fly, so
-        # `ledger compare BENCH_r04.json BENCH_r05.json` works with no
+        # `ledger compare bench_a.json bench_b.json` works with no
         # ledger file at all
         if os.path.exists(ref):
             return ledger.record_from_file(ref)
@@ -1277,10 +1286,7 @@ def _cmd_tune(args) -> int:
     the calibrated cost model, measure the top-K survivors with short
     interleaved runs, persist the winner as a tuned profile the
     engines / bench / daemon resolve by config signature."""
-    if args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+    _jax_setup(args)
     from pulsar_tlaplus_tpu.models import registry
     from pulsar_tlaplus_tpu.obs import attribution, ledger
     from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
@@ -1448,10 +1454,7 @@ def _cmd_simulate(args) -> int:
     thousands of vectorized random walks per dispatch, running until a
     violation or the step/walk/time budget, resumable via
     -checkpoint/-recover."""
-    if args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+    _jax_setup(args)
     from pulsar_tlaplus_tpu.sim.engine import StreamingSimulator
 
     try:
@@ -1488,30 +1491,6 @@ def _cmd_simulate(args) -> int:
     except (ValueError, RuntimeError) as e:
         sys.exit(f"tpu-tlc: {e}")
     return _report_simulation(sres, constants, args.checkpoint)
-
-
-def _cmd_cache(args) -> int:
-    from pulsar_tlaplus_tpu.utils import aot_cache
-
-    if args.clear:
-        n, b = aot_cache.clear()
-        print(f"cleared {n} entrie(s), {b / 1e6:.1f} MB")
-    elif args.evict_to is not None:
-        # enforce_cap treats cap <= 0 as "eviction disabled" (the
-        # PTT_AOT_MAX_BYTES contract); an explicit --evict-to 0 means
-        # evict everything
-        if args.evict_to <= 0:
-            n, b = aot_cache.clear()
-        else:
-            n, b = aot_cache.enforce_cap(args.evict_to)
-        print(f"evicted {n} entrie(s), {b / 1e6:.1f} MB")
-    st = aot_cache.stats()
-    print(
-        f"AOT executable cache at {st['dir']}: {st['entries']} "
-        f"entrie(s), {st['bytes'] / 1e6:.1f} MB "
-        f"(cap {st['max_bytes'] / 1e9:.1f} GB)"
-    )
-    return 0
 
 
 def _add_client_args(sp) -> None:
@@ -2143,23 +2122,6 @@ def main(argv=None):
         "-cpu", action="store_true", help="force the CPU backend"
     )
 
-    pch = sub.add_parser(
-        "cache",
-        help="AOT executable cache inspector (--stats default)",
-    )
-    pch.add_argument(
-        "--stats", action="store_true",
-        help="print entry count / bytes / cap (the default action)",
-    )
-    pch.add_argument(
-        "--clear", action="store_true", help="delete every entry"
-    )
-    pch.add_argument(
-        "--evict-to", type=int, default=None, metavar="BYTES",
-        help="LRU-evict down to BYTES now (stores self-cap at "
-        "PTT_AOT_MAX_BYTES)",
-    )
-
     pc = sub.add_parser("check", help="exhaustive BFS model checking")
     pc.add_argument("spec", help="path to the .tla module (module 'compaction')")
     pc.add_argument("-config", help=".cfg file (defaults to SPEC's .cfg)")
@@ -2169,7 +2131,7 @@ def main(argv=None):
         default="tpu",
         help="'tpu' (default: single-chip device engine) or a worker "
         "count N (TLC parity: maps to '-sharded N' mesh-sharded "
-        "checking over N devices)",
+        "checking over N devices; an error when the host has fewer)",
     )
     pc.add_argument(
         "-sharded",
@@ -2213,13 +2175,12 @@ def main(argv=None):
     pc.add_argument(
         "-probe-impl",
         dest="probe_impl",
-        choices=["legacy", "tile", "pallas"],
+        choices=["legacy", "tile"],
         default="legacy",
         help="fpset flush probe kernel (round 23, ops/tiles.py): "
-        "'legacy' (dense probe rounds inside flush_acc, default), "
-        "'tile' (lane-tiled membership prefilter + chunked insert) or "
-        "'pallas' (the prefilter as a Pallas kernel; interpreted off-"
-        "TPU).  All three are exact — discovery order is identical",
+        "'legacy' (dense probe rounds inside flush_acc, default) or "
+        "'tile' (lane-tiled membership prefilter + chunked insert).  "
+        "Both are exact — discovery order is identical",
     )
     pc.add_argument(
         "-expand-impl",
@@ -2458,7 +2419,6 @@ def main(argv=None):
             "status": _cmd_status,
             "watch": _cmd_watch,
             "cancel": _cmd_cancel,
-            "cache": _cmd_cache,
             "ledger": _cmd_ledger,
             "trace": _cmd_trace,
             "metrics": _cmd_metrics,
@@ -2482,10 +2442,7 @@ def main(argv=None):
             "(both drive jax.profiler; pick the whole-check trace OR "
             "the level window)"
         )
-    if args.cpu:
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
+    _jax_setup(args)
     if args.profile:
         import atexit
 
@@ -2506,36 +2463,28 @@ def main(argv=None):
     invariants = tuple(args.invariant or tlc_cfg.invariants)
     if isinstance(args.workers, int) and not args.sharded:
         # TLC parity: -workers N is worker parallelism; here that is
-        # mesh sharding (round-2 judge: do not silently ignore it).
-        # TLC happily runs N workers on any host, so cap at the devices
-        # actually present rather than erroring out
+        # mesh sharding over N devices.  A request for more devices
+        # than the host has is an error, never a silent cap: a user
+        # who asked for 4 chips must not get a 1-chip run
         import jax
 
-        n = min(args.workers, len(jax.devices()))
-        capped = (
-            f" (capped from {args.workers}: {len(jax.devices())} "
-            "devices available)" if n != args.workers else ""
-        )
-        if n == 1:
-            # one worker IS the single-chip engine: identical
-            # semantics, and the sharded engine's accumulator/flush
-            # bookkeeping is pure overhead on a singleton mesh
-            # (measured r5: 0.77-0.96M st/s vs 2.1-2.9M single-chip
-            # at bench shapes) — never route users into a perf trap
-            # for TLC flag parity (VERDICT r3 #4)
-            print(
-                f"tpu-tlc: note: -workers {args.workers} runs the "
-                f"single-chip device engine{capped}",
-                file=sys.stderr,
+        have = len(jax.devices())
+        if args.workers > have:
+            sys.exit(
+                f"tpu-tlc: -workers {args.workers} needs "
+                f"{args.workers} devices; this host has {have}"
             )
+        if args.workers == 1:
+            # one worker IS the single-chip engine: identical
+            # semantics, without the sharded engine's routing and
+            # per-shard bookkeeping on a singleton mesh
             args.workers = "tpu"
-            args.sharded = 0
         else:
             print(
                 f"tpu-tlc: note: -workers {args.workers} maps to "
-                f"-sharded {n} (mesh-sharded checking){capped}"
+                f"-sharded {args.workers} (mesh-sharded checking)"
             )
-            args.sharded = n
+            args.sharded = args.workers
     if not args.sharded and (
         args.slices > 1 or args.sharded_dedup != "sort"
     ):

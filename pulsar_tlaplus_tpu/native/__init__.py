@@ -1,19 +1,55 @@
-"""Native runtime components (C++ CPython extensions).
+"""Native runtime components, built from source on first use.
 
-``build()`` compiles ``logstore.cpp`` with the system toolchain directly
-(g++; no pybind11 in the image) into this package directory.  Import of
-``_logstore`` triggers a build on first use; failures fall back to the
-pure-python implementation in ``engine/statelog.py``.
+``build()`` compiles ``logstore.cpp`` (a CPython extension) and
+``build_baseline()`` compiles ``compaction_bfs.cpp`` (the standalone
+TLC-class baseline checker) with the system toolchain directly (g++; no
+pybind11 in the image) into this package directory.  No binary is
+tracked: an artifact is rebuilt whenever the hash of its source and
+compile command differs from the stamp written beside it — file times
+mean nothing in a copied or freshly checked-out tree.  A ``_logstore``
+build failure falls back to the pure-python implementation in
+``engine/statelog.py``.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import subprocess
-import sys
 import sysconfig
 
 _DIR = os.path.dirname(__file__)
+
+
+def _build(src: str, out: str, cmd: list, force: bool) -> str:
+    """Run ``cmd + [src, "-o", out]`` unless ``out`` was already built
+    from this exact source and command; returns ``out``."""
+    with open(src, "rb") as f:
+        want = hashlib.sha256(
+            f.read() + "\0".join(cmd).encode()
+        ).hexdigest()
+    stamp = out + ".srchash"
+    if not force and os.path.exists(out):
+        try:
+            with open(stamp) as f:
+                if f.read().strip() == want:
+                    return out
+        except OSError:
+            pass
+    # compile beside the target and rename: a concurrent builder or
+    # loader never sees a half-written artifact
+    tmp = f"{out}.tmp.{os.getpid()}"
+    try:
+        subprocess.run(
+            cmd + [src, "-o", tmp], check=True, capture_output=True
+        )
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    with open(stamp, "w") as f:
+        f.write(want + "\n")
+    return out
 
 
 def _ext_path() -> str:
@@ -23,26 +59,13 @@ def _ext_path() -> str:
 
 def build(force: bool = False) -> str:
     """Compile the extension if needed; returns the .so path."""
-    out = _ext_path()
-    src = os.path.join(_DIR, "logstore.cpp")
-    if not force and os.path.exists(out) and os.path.getmtime(
-        out
-    ) >= os.path.getmtime(src):
-        return out
     include = sysconfig.get_paths()["include"]
-    cmd = [
-        "g++",
-        "-O2",
-        "-shared",
-        "-fPIC",
-        "-std=c++17",
-        f"-I{include}",
-        src,
-        "-o",
-        out,
-    ]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+    return _build(
+        os.path.join(_DIR, "logstore.cpp"),
+        _ext_path(),
+        ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", f"-I{include}"],
+        force,
+    )
 
 
 def build_baseline(force: bool = False) -> str:
@@ -50,17 +73,12 @@ def build_baseline(force: bool = False) -> str:
     (``compaction_bfs.cpp``) into a standalone binary; returns its path.
     See BASELINE.md: this is the in-image stand-in for 8-worker CPU TLC
     (no JVM in the image)."""
-    src = os.path.join(_DIR, "compaction_bfs.cpp")
-    out = os.path.join(_DIR, "compaction_bfs")
-    if not force and os.path.exists(out) and os.path.getmtime(
-        out
-    ) >= os.path.getmtime(src):
-        return out
-    subprocess.run(
-        ["g++", "-O2", "-std=c++17", "-pthread", src, "-o", out],
-        check=True, capture_output=True,
+    return _build(
+        os.path.join(_DIR, "compaction_bfs.cpp"),
+        os.path.join(_DIR, "compaction_bfs"),
+        ["g++", "-O2", "-std=c++17", "-pthread"],
+        force,
     )
-    return out
 
 
 def run_baseline(
@@ -106,12 +124,7 @@ def load_logstore():
     Raises on toolchain/build failure — callers fall back to the python
     implementation.
     """
-    try:
-        from pulsar_tlaplus_tpu.native import _logstore  # type: ignore
+    import importlib
 
-        return _logstore
-    except ImportError:
-        build()
-        import importlib
-
-        return importlib.import_module("pulsar_tlaplus_tpu.native._logstore")
+    build()
+    return importlib.import_module("pulsar_tlaplus_tpu.native._logstore")

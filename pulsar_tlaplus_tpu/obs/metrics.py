@@ -765,8 +765,6 @@ def scheduler_metrics(
     snapshot of the active checker.  Reads ONLY host-side dicts: a
     scrape never touches the device (asserted fetch-count-identical in
     tests)."""
-    from pulsar_tlaplus_tpu.utils import aot_cache
-
     with sched.cv:
         jobs = list(sched.jobs.values())
         running_id = sched._running_id
@@ -814,22 +812,6 @@ def scheduler_metrics(
         "ptt_warmed_specs", "gauge",
         "Registry specs with warmed executables",
     ).add(len(warmed) if warmed is not None else None)
-    try:
-        cache = aot_cache.stats()
-        f_cache = Family(
-            "ptt_aot_cache_bytes", "gauge",
-            "AOT executable cache size on disk",
-        ).add(cache["bytes"])
-        f_centries = Family(
-            "ptt_aot_cache_entries", "gauge",
-            "AOT executable cache entry count",
-        ).add(cache["entries"])
-    except OSError:  # cache dir unreadable: skip, don't fail the scrape
-        f_cache = Family("ptt_aot_cache_bytes", "gauge", "unavailable")
-        f_centries = Family(
-            "ptt_aot_cache_entries", "gauge", "unavailable"
-        )
-
     last = getattr(sched, "last_engine", None) or {}
     stats = dict(last.get("stats") or {})
     snap = dict(last.get("snap") or {})
@@ -850,7 +832,7 @@ def scheduler_metrics(
         snap["states_per_sec"] = last["states_per_sec"]
     fams = [
         f_up, f_uptime, f_jobs, f_queue, f_active, f_slices, f_susp,
-        f_warm, f_cache, f_centries,
+        f_warm,
     ] + _engine_families(stats, snap)
     adm = getattr(sched, "admission", None)
     if adm is not None:

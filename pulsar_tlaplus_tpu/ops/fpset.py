@@ -527,8 +527,8 @@ def flush_acc(
     sort-merge flush's discovery order.
 
     ``probe_impl`` selects the probe kernel (round 23): ``legacy`` is
-    the staged loop below; ``tile`` / ``pallas`` route to the blocked
-    membership-prefilter formulations in ``ops/tiles.py``, which are
+    the staged loop below; ``tile`` routes to the blocked
+    membership-prefilter formulation in ``ops/tiles.py``, which is
     pinned bit-identical on ``is_new`` (discovery order depends only
     on pre-flush membership + min-lane-wins, never slot placement).
     """
@@ -538,7 +538,7 @@ def flush_acc(
         return tiles.flush_acc_tiles(
             tcols, kcols, n_acc, fpm,
             dense_rounds=dense_rounds, stages=stages,
-            compact_impl=compact_impl, probe_impl=probe_impl,
+            compact_impl=compact_impl,
         )
     nq = kcols[0].shape[0]
     lanei = jnp.arange(nq, dtype=jnp.int32)

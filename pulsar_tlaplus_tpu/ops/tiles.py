@@ -16,9 +16,11 @@ kernel ships two variants behind one constructor knob:
   TILE_L)`` planes that vectorize on the CPU mesh and lower to
   MXU/VPU tiles on TPU);
 - ``pallas`` — the same blocking as an explicit
-  ``jax.experimental.pallas`` kernel (``interpret=True`` on the CPU
-  backend, real lowering on the chip; chip-only native lowering is
-  skip-gated by ``tests/helpers.needs_pallas_tpu``).
+  ``jax.experimental.pallas`` kernel, for the expand key plane and the
+  sieve (``interpret=True`` on the CPU backend, native lowering on the
+  chip — ``chip_smoke.py``'s ``kernels`` phase compiles both there and
+  compares them bit for bit).  The probe has none: its random gather
+  from the table does not lower through Mosaic.
 
 **(1) Tiled probe** (``probe_impl``).  The legacy flush interleaves
 membership resolution and insertion: every dense round gathers K slot
@@ -93,7 +95,6 @@ Measured CPU-mesh verdicts per kernel: BASELINE.md Round 23.
 
 from __future__ import annotations
 
-from functools import lru_cache
 from typing import Optional, Tuple
 
 import jax
@@ -119,68 +120,47 @@ TILE_R = 8
 # (TILE_R, TILE_L) intermediate planes so a bench-width accumulator
 # never materializes an (R, 26M) gather (the r5 relayout lesson)
 TILE_L = 1 << 16
-# lane-tile width for the Pallas kernels (one grid program per tile;
-# sized for VPU-friendly blocks without interpret-mode overhead
-# dominating at test shapes)
+# lane-tile width of the sieve's Pallas kernel (one grid program per
+# 1-D tile of every table plane)
 PALLAS_TILE = 4096
+# block of the key-plane Pallas kernel: the lane axis is viewed as
+# ``(rows, PALLAS_LANES)`` and one grid program takes ``PALLAS_ROWS``
+# of them for every word of the state — whole (8, 128) u32 register
+# tiles per word, 2.6 MB of VMEM per buffer at 20 words (a row-major
+# ``(4096, 20)`` block pads its minor axis to 128 lanes and overflows
+# the 16 MB scoped VMEM; measured on the v5e, PR 23)
+PALLAS_LANES = 512
+PALLAS_ROWS = 64
+PALLAS_BLOCK = PALLAS_ROWS * PALLAS_LANES
 
-IMPLS = ("legacy", "tile", "pallas")
+# selectable values per knob.  The probe has no ``pallas`` variant:
+# its membership pass is a random gather from the table planes, which
+# Mosaic does not lower ("Cannot do int indexing on TPU"; v5e, PR 23)
+IMPLS = {
+    "probe_impl": ("legacy", "tile"),
+    "expand_impl": ("legacy", "tile", "pallas"),
+    "sieve_impl": ("legacy", "tile", "pallas"),
+}
 
 
 def validate_impl(knob: str, impl: Optional[str]) -> str:
     """Normalize/validate one ``*_impl`` knob value (``None`` = the
     engine default ``legacy``)."""
     impl = impl or "legacy"
-    if impl not in IMPLS:
+    if impl not in IMPLS[knob]:
         raise ValueError(
-            f"{knob} must be one of {'|'.join(IMPLS)}: {impl}"
+            f"{knob} must be one of {'|'.join(IMPLS[knob])}: {impl}"
         )
     return impl
 
 
-@lru_cache(maxsize=1)
-def pallas_available() -> bool:
-    """Whether ``jax.experimental.pallas`` imports at all (it does on
-    the container's jax 0.4.37; guarded so a stripped-down jax build
-    degrades to the pure-XLA tile path instead of an ImportError)."""
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-
-        return True
-    except Exception:  # noqa: BLE001 — any import failure = absent
-        return False
-
-
-@lru_cache(maxsize=1)
-def pallas_lowers_natively() -> bool:
-    """Whether Pallas lowers for the CURRENT backend without the
-    interpreter (True on real TPU/GPU lowering paths, False on the CPU
-    mesh of jax 0.4.37).  The kernels below pass
-    ``interpret=not pallas_lowers_natively()`` so the same code runs
-    everywhere; chip-only native tests skip-gate on this probe
-    (``tests/helpers.needs_pallas_tpu``)."""
-    if not pallas_available():
-        return False
-    try:
-        from jax.experimental import pallas as pl
-
-        def _k(x_ref, o_ref):
-            o_ref[...] = x_ref[...] + 1
-
-        x = jnp.zeros((8,), jnp.int32)
-        jax.jit(
-            lambda v: pl.pallas_call(
-                _k,
-                out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            )(v)
-        )(x).block_until_ready()
-        return True
-    except Exception:  # noqa: BLE001 — no native lowering here
-        return False
-
-
-def _interpret() -> bool:
-    return not pallas_lowers_natively()
+def interpret() -> bool:
+    """Pallas interpret mode follows the backend and nothing else: the
+    CPU backend has no Mosaic lowering, so the kernels run interpreted
+    there (the tier-1 parity tests); on an accelerator they compile
+    natively, and a kernel that does not lower raises — it never runs
+    interpreted on the chip unnoticed."""
+    return jax.default_backend() == "cpu"
 
 
 # ------------------------------------------------------------- probe
@@ -268,83 +248,6 @@ def member_block(
     return member & valid, resolved | ~valid
 
 
-def member_block_pallas(
-    tcols: Tuple[jax.Array, ...],
-    kcols: Tuple[jax.Array, ...],
-    valid: jax.Array,
-    rounds: int = TILE_R,
-):
-    """The membership prefilter as an explicit Pallas kernel: one grid
-    program per :data:`PALLAS_TILE` lane tile, the table planes passed
-    whole (the kernel gathers its (rounds, tile) slot tile from them —
-    interpret-mode on the CPU mesh; on-chip lowering keeps the table
-    in HBM and the key tiles in VMEM).  Same contract as
-    :func:`member_block`."""
-    from jax.experimental import pallas as pl
-
-    nq = kcols[0].shape[0]
-    K = len(kcols)
-    h = fpset.slot_hash(kcols)
-    lt = min(PALLAS_TILE, nq)
-    ntiles = -(-nq // lt)
-    pad = ntiles * lt - nq
-    if pad:
-        h = jnp.pad(h, (0, pad))
-        kcols = tuple(
-            jnp.pad(c, (0, pad), constant_values=SENTINEL)
-            for c in kcols
-        )
-    cap = tcols[0].shape[0] - 1
-
-    def kernel(*refs):
-        trefs = refs[:K]
-        krefs = refs[K: 2 * K]
-        h_ref = refs[2 * K]
-        m_ref, r_ref = refs[2 * K + 1], refs[2 * K + 2]
-        off = _triangular_offsets(rounds)
-        hh = h_ref[...]
-        # weak Python literals only — jnp scalar constants would be
-        # captured by the kernel trace, which pallas_call rejects
-        slots = ((hh[None, :] + off[:, None]) & (cap - 1)).astype(
-            jnp.int32
-        )
-        sv = tuple(t[slots] for t in trefs)
-        empty = sv[0] == _SENT
-        for c in sv[1:]:
-            empty = empty & (c == _SENT)
-        eq = sv[0] == krefs[0][...][None, :]
-        for cv, kr in zip(sv[1:], krefs[1:]):
-            eq = eq & (cv == kr[...][None, :])
-        match = eq & ~empty
-        ri = jnp.arange(rounds, dtype=jnp.int32)[:, None]
-        fm = jnp.min(jnp.where(match, ri, rounds), axis=0)
-        fe = jnp.min(jnp.where(empty, ri, rounds), axis=0)
-        m_ref[...] = fm < fe
-        r_ref[...] = (fm < fe) | (fe < rounds)
-
-    whole = lambda i: (0,)  # noqa: E731 — table planes unblocked
-    member, resolved = pl.pallas_call(
-        kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((ntiles * lt,), jnp.bool_),
-            jax.ShapeDtypeStruct((ntiles * lt,), jnp.bool_),
-        ),
-        grid=(ntiles,),
-        in_specs=(
-            [pl.BlockSpec(tcols[0].shape, whole) for _ in range(K)]
-            + [pl.BlockSpec((lt,), lambda i: (i,)) for _ in range(K)]
-            + [pl.BlockSpec((lt,), lambda i: (i,))]
-        ),
-        out_specs=(
-            pl.BlockSpec((lt,), lambda i: (i,)),
-            pl.BlockSpec((lt,), lambda i: (i,)),
-        ),
-        interpret=_interpret(),
-    )(*tcols, *kcols, h)
-    member, resolved = member[:nq], resolved[:nq]
-    return member & valid, resolved | ~valid
-
-
 def flush_acc_tiles(
     tcols: Tuple[jax.Array, ...],
     kcols: Tuple[jax.Array, ...],
@@ -353,13 +256,11 @@ def flush_acc_tiles(
     dense_rounds: Optional[int] = None,
     stages=None,
     compact_impl: str = "logshift",
-    probe_impl: str = "tile",
 ):
     """The tiled accumulator flush — drop-in for
     :func:`ops.fpset.flush_acc` with IDENTICAL ``(tcols', n_new,
     flag_acc, fpm')`` semantics and bit-identical ``is_new`` (see the
-    module docstring's exactness argument).  ``probe_impl`` selects
-    the membership kernel (``tile`` pure-XLA blocked / ``pallas``)."""
+    module docstring's exactness argument)."""
     nq = kcols[0].shape[0]
     K = len(kcols)
     dense_rounds, stages = fpset.resolve_schedule(dense_rounds, stages)
@@ -371,10 +272,7 @@ def flush_acc_tiles(
     lanei = jnp.arange(nq, dtype=jnp.int32)
     amask = lanei < n_acc
     valid = amask & ~fpset.all_sentinel(kcols)
-    member_fn = (
-        member_block_pallas if probe_impl == "pallas" else member_block
-    )
-    member, _resolved = member_fn(tcols, kcols, valid, rounds_blk)
+    member, _resolved = member_block(tcols, kcols, valid, rounds_blk)
     survivors = valid & ~member
     # order-preserving compaction of survivors + ORIGINAL lane ids —
     # chunk order is lane order, so cross-chunk equal-key resolution
@@ -447,31 +345,31 @@ def _fmix_k(h):
     return h ^ (h >> np.uint32(16))
 
 
-def _murmur3_words_k(words, seed: int):
-    w = words.shape[-1]
-    h = jnp.full(words.shape[:-1], np.uint32(seed), jnp.uint32)
-    for i in range(w):
-        k = words[..., i] * np.uint32(0xCC9E2D51)
+def _murmur3_planes_k(planes, seed: int):
+    """murmur3 over a state's words, one u32 plane per word."""
+    w = len(planes)
+    h = jnp.full(planes[0].shape, np.uint32(seed), jnp.uint32)
+    for p in planes:
+        k = p * np.uint32(0xCC9E2D51)
         k = _rotl_k(k, 15) * np.uint32(0x1B873593)
         h = h ^ k
         h = _rotl_k(h, 13) * np.uint32(5) + np.uint32(0xE6546B64)
     return _fmix_k(h ^ np.uint32(4 * w))
 
 
-def _key_cols_kernel(keyspec, packed):
-    """``KeySpec.make`` re-expressed with kernel-safe numpy-literal
-    constants (the dedup originals are jnp scalars, which a Pallas
-    kernel trace would capture).  Bit-identical to ``keyspec.make`` —
-    pinned by the ``key_plane`` parity properties in
-    ``tests/test_tiles.py``."""
-    n, w = packed.shape
+def _key_cols_kernel(keyspec, planes):
+    """``KeySpec.make`` re-expressed over word planes (``planes[i]`` is
+    word ``i`` of every lane) with kernel-safe numpy-literal constants
+    (the dedup originals are jnp scalars, which a Pallas kernel trace
+    would capture).  Bit-identical to ``keyspec.make`` — pinned by the
+    ``key_plane`` parity properties in ``tests/test_tiles.py``."""
     if keyspec.exact:
-        cols = [packed[:, i] for i in range(w)]
+        cols = list(planes)
         while len(cols) < keyspec.ncols:
-            cols.append(jnp.zeros((n,), jnp.uint32))
+            cols.append(jnp.zeros(planes[0].shape, jnp.uint32))
         return tuple(cols)
     h = [
-        _murmur3_words_k(packed, seed)
+        _murmur3_planes_k(planes, seed)
         for seed in (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35)[
             : keyspec.ncols
         ]
@@ -488,10 +386,11 @@ def key_plane(keyspec, packedf: jax.Array, vflat: jax.Array,
     """Key-column formation for one expand window's flattened
     successor matrix: ``packed u32[nc, W] -> K masked u32[nc]``
     columns (invalid lanes SENTINEL).  ``tile`` runs the mixing chain
-    as one full-matrix XLA op; ``pallas`` blocks it into
-    :data:`PALLAS_TILE` row tiles through an explicit kernel.  Both
-    are elementwise per lane — bit-identical to the legacy per-chunk
-    path."""
+    as one full-matrix XLA op; ``pallas`` runs it as an explicit
+    kernel over word-major ``(W, PALLAS_ROWS, PALLAS_LANES)`` blocks
+    (each word of the state is one lane-dense register plane, so the
+    mixing chain is plain elementwise VPU work).  Both are elementwise
+    per lane — bit-identical to the legacy per-chunk path."""
     if impl != "pallas":
         kcols = keyspec.make(packedf)
         return tuple(
@@ -501,38 +400,40 @@ def key_plane(keyspec, packedf: jax.Array, vflat: jax.Array,
 
     nc, w = packedf.shape
     K = keyspec.ncols
-    lt = min(PALLAS_TILE, nc)
-    ntiles = -(-nc // lt)
-    pad = ntiles * lt - nc
+    pad = -nc % PALLAS_BLOCK
+    words = packedf.T  # word-major, as the accumulator stores rows
+    vmask = vflat.astype(jnp.uint32)
     if pad:
-        packedf = jnp.pad(packedf, ((0, pad), (0, 0)))
-        vflat = jnp.pad(vflat, (0, pad))
+        words = jnp.pad(words, ((0, 0), (0, pad)))
+        vmask = jnp.pad(vmask, (0, pad))
+    words = words.reshape(w, -1, PALLAS_LANES)
+    vmask = vmask.reshape(-1, PALLAS_LANES)
+    rows = vmask.shape[0]
 
     def kernel(p_ref, v_ref, *orefs):
-        cols = _key_cols_kernel(keyspec, p_ref[...])
-        v = v_ref[...]
+        cols = _key_cols_kernel(keyspec, [p_ref[i] for i in range(w)])
+        v = v_ref[...] != 0
         for o, c in zip(orefs, cols):
             o[...] = jnp.where(v, c, _SENT)
 
+    plane = pl.BlockSpec((PALLAS_ROWS, PALLAS_LANES), lambda i: (i, 0))
     cols = pl.pallas_call(
         kernel,
         out_shape=tuple(
-            jax.ShapeDtypeStruct((ntiles * lt,), jnp.uint32)
+            jax.ShapeDtypeStruct((rows, PALLAS_LANES), jnp.uint32)
             for _ in range(K)
         ),
-        grid=(ntiles,),
+        grid=(rows // PALLAS_ROWS,),
         in_specs=(
-            pl.BlockSpec((lt, w), lambda i: (i, 0)),
-            pl.BlockSpec((lt,), lambda i: (i,)),
+            pl.BlockSpec(
+                (w, PALLAS_ROWS, PALLAS_LANES), lambda i: (0, i, 0)
+            ),
+            plane,
         ),
-        out_specs=tuple(
-            pl.BlockSpec((lt,), lambda i: (i,)) for _ in range(K)
-        ),
-        interpret=_interpret(),
-    )(packedf, vflat)
-    if isinstance(cols, jax.Array):  # K == 1 unwraps
-        cols = (cols,)
-    return tuple(c[:nc] for c in cols)
+        out_specs=tuple(plane for _ in range(K)),
+        interpret=interpret(),
+    )(words, vmask)
+    return tuple(c.reshape(-1)[:nc] for c in cols)
 
 
 # ------------------------------------------------------------- sieve
@@ -599,7 +500,7 @@ def sieve_mask_planes(
         grid=(ntiles,),
         in_specs=[spec] * (K + 2),
         out_specs=tuple([spec] * (2 * K + 1)),
-        interpret=_interpret(),
+        interpret=interpret(),
     )(*cols, cold, gen)
     masked = tuple(c[:cap1] for c in out[:K])
     holed = tuple(c[:cap1] for c in out[K: 2 * K])

@@ -1,9 +1,9 @@
 """Checking-as-a-service — the resident multi-tenant checker daemon.
 
-The one-shot CLI pays the full compile warmup (46 s at bench shapes)
-per verdict; a CI fleet submitting Pulsar spec revisions cannot.  This
-package composes the ingredients the repo already has — the AOT
-executable cache + capacity-tier prewarm (warm-start ~0 s), checkpoint
+The one-shot CLI pays the full compile warmup per verdict; a CI fleet
+submitting Pulsar spec revisions cannot.  This package composes the
+ingredients the repo already has — capacity-tier prewarm over JAX's
+persistent compilation cache, checkpoint
 frames + preemption-safe shutdown, JSONL telemetry with run_ids — into
 a long-lived service:
 

@@ -4,7 +4,7 @@
 ``(spec, constant bindings, invariant set, max_states)`` key.  Warming
 runs ``warmup(tiers=True)`` once — every jitted program for every
 capacity tier reachable under the service's state ceiling compiles (or
-loads from the AOT executable cache) up front, so a submit against a
+loads from the persistent compilation cache) up front, so a submit against a
 warmed key pays **zero** jit compiles (the test suite asserts this via
 the same ``set(ck._jits)`` harness as the capacity-tier prewarm
 tests).  The invariant set is part of the key because the engine bakes
@@ -116,8 +116,8 @@ class ServiceConfig:
     #   queue.json, AND their jobs/<id>/ dirs) — a resident daemon
     #   must not grow per-submit forever.  0 disables pruning.
     # incremental checking (r19, warm/, docs/incremental.md): the warm
-    # artifact store's LRU byte cap (`serve --warm-max-bytes`, the
-    # aot_cache precedent).  0 disables the warm layer entirely —
+    # artifact store's LRU byte cap (`serve --warm-max-bytes`).
+    # 0 disables the warm layer entirely —
     # no artifacts harvested, every submit plans cold.
     warm_max_bytes: int = warm_store.DEFAULT_MAX_BYTES
     # fleet tier (r20, docs/fleet.md): N local device slots — the
@@ -242,7 +242,7 @@ class CheckerPool:
                 model = self.build_model(spec, tlc_cfg)
                 # tuned-profile resolution (r15): the profile's knobs
                 # override the service-wide defaults, so prewarm
-                # compiles (and the AOT cache stores) the TUNED
+                # compiles (and the compile cache stores) the TUNED
                 # programs — a warm submit against this key runs the
                 # tuned executables with zero jit compiles
                 prof = None

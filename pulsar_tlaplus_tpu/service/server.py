@@ -1,7 +1,7 @@
 """The resident daemon: socket accept loop + graceful shutdown.
 
 ``cli.py serve`` builds a :class:`ServiceDaemon`, prewarms the spec
-registry (AOT cache + capacity-tier prewarm, so warm submits pay zero
+registry (capacity-tier prewarm, so warm submits pay zero
 jit compiles), and serves the JSONL protocol on a unix socket inside
 the state dir.  The scheduler runs in its own thread; signal handlers
 stay on the main thread, so SIGTERM/SIGINT trigger the graceful path:

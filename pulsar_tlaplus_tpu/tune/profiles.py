@@ -3,11 +3,10 @@
 A profile is the persisted winner of one ``cli.py tune`` search: the
 knob assignment for one ``(engine, spec + constants, invariant set,
 backend)`` configuration, written to ``PTT_TUNE_DIR`` (default
-``~/.ptt_profiles``, beside the AOT executable cache) as
-``<sig>.json``.  Engines, bench.py, and the daemon's CheckerPool look
-profiles up at construction; ``run_header.profile_sig`` then
-attributes every run (and every ledger record) to the profile that
-shaped it.
+``~/.ptt_profiles``) as ``<sig>.json``.  Engines, bench.py, and the
+daemon's CheckerPool look profiles up at construction;
+``run_header.profile_sig`` then attributes every run (and every ledger
+record) to the profile that shaped it.
 
 Robustness contract (pinned in tests/test_tune.py): a corrupt,
 stale-versioned, wrong-engine, or sig-mismatched profile file is
@@ -60,9 +59,13 @@ _POSITIVE_INT_KNOBS = (
     "n_walkers", "segment_len",
 )
 _COMPACT_IMPLS = ("logshift", "sort")
-# dense-tile kernel knobs (r23, ops/tiles.py) share one impl enum
-_TILE_IMPL_KNOBS = ("probe_impl", "expand_impl", "sieve_impl")
-_TILE_IMPLS = ("legacy", "tile", "pallas")
+# dense-tile kernel knobs (r23): what the search space offers, plus
+# the engine default its ``None`` stands for
+_TILE_IMPLS = {
+    k.name: ("legacy",) + tuple(v for v in k.values if v)
+    for k in tune_space.DEVICE_KNOBS + tune_space.SPILL_KNOBS
+    if k.name in ("probe_impl", "expand_impl", "sieve_impl")
+}
 
 
 def profiles_dir() -> str:
@@ -242,10 +245,10 @@ def validate(profile, path: str = "<profile>") -> List[str]:
                 f"{path}: knob compact_impl must be one of "
                 f"{_COMPACT_IMPLS} (got {val!r})"
             )
-        elif k in _TILE_IMPL_KNOBS and val not in _TILE_IMPLS:
+        elif k in _TILE_IMPLS and val not in _TILE_IMPLS[k]:
             errs.append(
                 f"{path}: knob {k!r} must be one of "
-                f"{_TILE_IMPLS} (got {val!r})"
+                f"{_TILE_IMPLS[k]} (got {val!r})"
             )
         elif k == "adapt" and not isinstance(val, bool):
             errs.append(

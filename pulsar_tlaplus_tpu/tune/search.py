@@ -254,8 +254,11 @@ def tune_sim(
     t0 = time.perf_counter()
     backend = tune_profiles.default_backend()
     total = int(total_steps or 1024 * depth * 4)
+    import jax
+
     ref = {
         "backend": backend,
+        "device_kind": jax.devices()[0].device_kind,
         "A": int(getattr(model, "A", 1)),
         "n_inv": len(
             tuple(invariants)

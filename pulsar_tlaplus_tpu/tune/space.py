@@ -19,7 +19,7 @@ order; pinned by the differential tests in tests/test_tune.py):
 - ``fpset_dense_rounds``  full-width probe rounds before the staged
                       pending-compaction shrinks the batch
 - ``compact_impl``    stream-compaction materialization (logshift|sort)
-- ``probe_impl``      fpset flush probe kernel (legacy|tile|pallas —
+- ``probe_impl``      fpset flush probe kernel (legacy|tile —
                       round 23, ops/tiles.py; exact reformulations,
                       discovery order pinned identical)
 - ``expand_impl``     successor-sweep structure (legacy|tile|pallas)
@@ -69,7 +69,7 @@ DEVICE_KNOBS: Tuple[Knob, ...] = (
     # against the legacy baseline.  predict.py prices each impl's
     # probe/expand lanes at calibrated (or default-ratio) unit costs.
     Knob(
-        "probe_impl", (None, "tile", "pallas"),
+        "probe_impl", (None, "tile"),
         "fpset flush probe kernel (None = legacy)",
     ),
     Knob(

@@ -28,9 +28,9 @@ Robustness discipline (the r7/r9 treatment, docs/robustness.md):
   verification's computed digest to drill exactly this path;
   ``torn@warmwrite:N`` / ``kill@warmwrite:N`` fire inside the N-th
   artifact write);
-- the store is LRU-capped by bytes (``--warm-max-bytes``, the
-  aot_cache precedent): loads touch the manifest mtime, saves evict
-  oldest-touched entries past the cap.
+- the store is LRU-capped by bytes (``--warm-max-bytes``): loads
+  touch the manifest mtime, saves evict oldest-touched entries past
+  the cap.
 """
 
 from __future__ import annotations
@@ -497,7 +497,7 @@ class WarmStore:
 
     def enforce_cap(self) -> int:
         """Evict oldest-touched artifacts past ``max_bytes`` (mtime
-        LRU, the aot_cache discipline).  0 disables the store rather
+        LRU).  0 disables the store rather
         than the cap — the scheduler never constructs one then.
         Returns the number evicted.  Takes the store lock: evicting
         while another writer is mid-save would rmtree a dir that

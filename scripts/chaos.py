@@ -805,6 +805,8 @@ def _spawn_dispatcher(
     already rebuilt the job table)."""
     import subprocess
 
+    # one process per chip: the dispatcher child runs no device work
+    # and is held to the CPU, so it never contends for the chip
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     if fault:
         env["PTT_FAULT"] = fault
@@ -1363,6 +1365,9 @@ def main(argv=None) -> int:
         "and a solo-exact warm restart on the survivor",
     )
     args = ap.parse_args(argv)
+    from pulsar_tlaplus_tpu.utils.device import setup_compile_cache
+
+    setup_compile_cache()
     state_dir = args.state_dir
     if state_dir is None:
         import tempfile

@@ -672,6 +672,9 @@ def main(argv=None) -> int:
         "equality (docs/incremental.md)",
     )
     args = ap.parse_args(argv)
+    from pulsar_tlaplus_tpu.utils.device import setup_compile_cache
+
+    setup_compile_cache()
     specs = tuple(args.spec) if args.spec else SPECS
     unknown = [s for s in specs if s not in SPECS]
     if unknown:

@@ -24,9 +24,12 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
 
 import jax  # noqa: E402
+
+from pulsar_tlaplus_tpu.utils.device import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
 
 
 def main():

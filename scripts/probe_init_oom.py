@@ -6,11 +6,14 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from pulsar_tlaplus_tpu.utils.device import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
 
 
 def barrier(o, tag):

@@ -12,11 +12,14 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/root/repo/.jax_cache")
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from pulsar_tlaplus_tpu.utils.device import setup_compile_cache  # noqa: E402
+
+setup_compile_cache()
 
 
 def t(tag, fn):

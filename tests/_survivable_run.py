@@ -50,17 +50,13 @@ def main():
 
     import jax
 
+    from pulsar_tlaplus_tpu.utils.device import setup_compile_cache
+
     jax.config.update("jax_platforms", "cpu")
     # share the suite's persistent compile cache (tests/conftest.py):
     # drill subprocesses otherwise pay the full cold compile of the
     # engine programs on every single drill
-    jax.config.update(
-        "jax_compilation_cache_dir",
-        os.path.join(
-            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            ".jax_cache",
-        ),
-    )
+    setup_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     from pulsar_tlaplus_tpu.models.compaction import CompactionModel

@@ -2,10 +2,10 @@
 counterexample-trace validation."""
 
 import functools
+import os
 import random
 import subprocess
 
-import jax
 import pytest
 
 from pulsar_tlaplus_tpu.frontend.loader import reference_spec_path
@@ -16,77 +16,36 @@ from pulsar_tlaplus_tpu.ref import pyeval as pe
 # on hosts that still carry it.
 REFERENCE_TLA = reference_spec_path("compaction")
 
-# Both sharded engines build on jax.shard_map (added after jax 0.4.37,
-# the container's version).  Known-environment failures are noise, not
-# signal: tier-1 SKIPS these tests where shard_map is absent — the real
-# host (and any jax >= 0.5) still runs them.
-needs_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="sharded engines need jax.shard_map (newer jax; container "
-    "jax 0.4.37 lacks it)",
+# the vendored specs/ directory, resolved from this file (a checkout
+# need not sit at any particular path)
+SPECS = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "specs"
 )
-
-
-@functools.lru_cache(maxsize=1)
-def _pallas_lowers_natively() -> bool:
-    try:
-        from pulsar_tlaplus_tpu.ops import tiles
-
-        return tiles.pallas_lowers_natively()
-    except Exception:  # noqa: BLE001 — any failure mode means "skip"
-        return False
-
-
-# The r23 Pallas tile kernels compile natively only on a TPU backend;
-# everywhere else ops/tiles.py runs them under interpret=True, which
-# the always-on parity tests already exercise.  Same regime as
-# needs_shard_map: tests pinning NATIVE lowering behavior (mosaic
-# compilation, on-chip timing) SKIP on the CPU-mesh container and run
-# on the real host.
-needs_pallas_tpu = pytest.mark.skipif(
-    not _pallas_lowers_natively(),
-    reason="native Pallas lowering needs a TPU backend (interpret-"
-    "mode parity tests still run here)",
-)
-
 
 @functools.lru_cache(maxsize=1)
 def _native_baseline_runnable() -> bool:
-    """True when the COMMITTED native baseline binary actually RUNS
-    here.  The binary was built on the real host; a container with an
-    older glibc loads it and dies before main — probe with a tiny
-    config instead of pattern-matching on toolchain presence.  Probes
-    the tracked binary path directly, never ``build_baseline()``: a
-    rebuild would overwrite the tracked binary AND mask the very
-    environment difference the skip exists to report."""
+    """True when the native baseline checker builds from source here
+    (``native.build_baseline()``; no binary is tracked) and the result
+    runs a tiny config."""
+    from pulsar_tlaplus_tpu import native
+
     try:
-        import os
-
-        from pulsar_tlaplus_tpu import native
-
-        binary = os.path.join(
-            os.path.dirname(native.__file__), "compaction_bfs"
-        )
-        if not os.path.exists(binary):
-            return False
+        binary = native.build_baseline()
         p = subprocess.run(
             [binary, "1", "1", "1", "1", "0", "0", "1", "5", "1", "10"],
             capture_output=True, text=True, timeout=60,
         )
-        return p.returncode in (0, 1) and bool(p.stdout.strip())
-    except Exception:  # noqa: BLE001 — any failure mode means "skip"
+    except (OSError, subprocess.SubprocessError):
         return False
+    return p.returncode in (0, 1) and bool(p.stdout.strip())
 
 
-# The native TLC-class baseline (BASELINE.md) needs a binary the
-# current libc can actually load.  Same regime as needs_shard_map: a
-# clean container run reports SKIPs, not failures; the real host (and
-# any glibc >= the build host's) still runs the tests.
+# The native TLC-class baseline (BASELINE.md) needs a C++ toolchain:
+# a host without one reports SKIPs, not failures.
 needs_native_binary = pytest.mark.skipif(
     not _native_baseline_runnable(),
-    reason="native baseline binary is not runnable in this "
-    "environment (glibc/toolchain mismatch; runnable on the real "
-    "host)",
+    reason="native baseline checker cannot be built or run here (no "
+    "g++ toolchain)",
 )
 
 

@@ -17,7 +17,7 @@ The acceptance bar:
   deterministic at the calibration shape);
 - **v7 schema**: validator positive/negative streams for the new
   ``fuse`` work fields and the ``attribution`` record;
-- **the run ledger**: round-trips every committed BENCH_r0*.json,
+- **the run ledger**: round-trips legacy-shape BENCH_r0*.json artifacts,
   renders a correct delta table between two artifacts, and ``ledger
   gate`` catches an injected dispatches/level / work-units/state
   regression against the pinned mini-bench baseline (tier-1 gate).
@@ -455,18 +455,15 @@ def test_engine_snap_carries_partial_flag(tmp_path):
 # ---- the run ledger (tentpole part 3) -------------------------------
 
 
-def test_ledger_roundtrip_every_committed_bench_artifact(tmp_path):
-    """All five committed BENCH artifacts (pre-schema r1 through
-    schema-2 r5, driver-wrapper shape) ingest, dedup, validate, and
-    render."""
+def test_ledger_roundtrip_legacy_bench_artifacts(tmp_path, bench_dir):
+    """Driver-wrapper BENCH artifacts, pre-schema r1 through schema-2
+    r5, ingest, dedup, validate, and render."""
     path = str(tmp_path / "ledger.jsonl")
-    sources = sorted(
-        p for p in os.listdir(ROOT)
-        if p.startswith("BENCH_r0") and p.endswith(".json")
-    )
-    assert len(sources) >= 5
+    sources = sorted(os.listdir(bench_dir))
+    assert len(sources) == 5
     recs = [
-        ledger.record_from_file(os.path.join(ROOT, p)) for p in sources
+        ledger.record_from_file(os.path.join(bench_dir, p))
+        for p in sources
     ]
     assert ledger.append(path, recs) == len(sources)
     assert ledger.append(path, recs) == 0  # idempotent by digest
@@ -480,16 +477,16 @@ def test_ledger_roundtrip_every_committed_bench_artifact(tmp_path):
     assert "BENCH_r05.json" in table
 
 
-def test_ledger_compare_two_committed_artifacts():
+def test_ledger_compare_two_bench_artifacts(bench_dir):
     """The acceptance delta table: r04 -> r05 shows the headline rate
-    moving by the published amounts."""
-    a = ledger.record_from_file(os.path.join(ROOT, "BENCH_r04.json"))
-    b = ledger.record_from_file(os.path.join(ROOT, "BENCH_r05.json"))
+    moving by the artifacts' amounts."""
+    a = ledger.record_from_file(os.path.join(bench_dir, "BENCH_r04.json"))
+    b = ledger.record_from_file(os.path.join(bench_dir, "BENCH_r05.json"))
     rows = {r["key"]: r for r in ledger.compare(a, b)}
-    assert rows["value"]["a"] == pytest.approx(2021923.9)
-    assert rows["value"]["b"] == pytest.approx(3184662.1)
+    assert rows["value"]["a"] == pytest.approx(2_000_000.0)
+    assert rows["value"]["b"] == pytest.approx(3_150_000.0)
     assert rows["value"]["pct"] == pytest.approx(57.5, abs=0.1)
-    assert rows["distinct_states"]["delta"] == 171410570 - 61685485
+    assert rows["distinct_states"]["delta"] == 170_000_000 - 60_000_000
     out = ledger.render_compare(a, b)
     assert "+57.5%" in out
     # same config key: no incomparability warning
@@ -564,9 +561,9 @@ def test_ledger_gate_tier1_pinned_baseline(tmp_path):
     }
 
 
-def test_ledger_validator_catches_tampering(tmp_path):
+def test_ledger_validator_catches_tampering(tmp_path, bench_dir):
     path = str(tmp_path / "t.jsonl")
-    rec = ledger.record_from_file(os.path.join(ROOT, "BENCH_r05.json"))
+    rec = ledger.record_from_file(os.path.join(bench_dir, "BENCH_r05.json"))
     ledger.append(path, [rec])
     # hand-edit a value without refreshing the digest
     lines = open(path).read().splitlines()
@@ -578,13 +575,13 @@ def test_ledger_validator_catches_tampering(tmp_path):
     assert errs and any("digest" in e for e in errs)
 
 
-def test_ledger_cli_validator_front_end(tmp_path):
+def test_ledger_cli_validator_front_end(tmp_path, bench_dir):
     """check_telemetry_schema.py --ledger validates ledger files."""
     ckr = _checker_mod()
     path = str(tmp_path / "v.jsonl")
     ledger.append(
         path,
-        [ledger.record_from_file(os.path.join(ROOT, "BENCH_r05.json"))],
+        [ledger.record_from_file(os.path.join(bench_dir, "BENCH_r05.json"))],
     )
     assert ckr.main([path, "--ledger"]) == 0
     with open(path, "a") as f:
@@ -613,12 +610,12 @@ def test_liveness_stream_attributes_engine_and_sweep_stages(tmp_path):
     assert {r["stage"] for r in rows} >= {"expand", "flush", "append"}
 
 
-def test_gate_rejects_unknown_keys(tmp_path):
+def test_gate_rejects_unknown_keys(tmp_path, bench_dir):
     """A typo'd --keys must error (exit 2), never pass vacuously."""
     from pulsar_tlaplus_tpu import cli
 
-    a = ledger.record_from_file(os.path.join(ROOT, "BENCH_r04.json"))
-    b = ledger.record_from_file(os.path.join(ROOT, "BENCH_r05.json"))
+    a = ledger.record_from_file(os.path.join(bench_dir, "BENCH_r04.json"))
+    b = ledger.record_from_file(os.path.join(bench_dir, "BENCH_r05.json"))
     with pytest.raises(KeyError, match="dispaches_per_level"):
         ledger.gate(a, b, keys=("dispaches_per_level",))
     path = str(tmp_path / "l.jsonl")
@@ -632,7 +629,7 @@ def test_gate_rejects_unknown_keys(tmp_path):
     assert rc == 2
 
 
-def test_ledger_rejects_non_telemetry_jsonl(tmp_path):
+def test_ledger_rejects_non_telemetry_jsonl(tmp_path, bench_dir):
     """The append-only ledger must refuse to ingest a .jsonl that is
     not a telemetry stream (e.g. the ledger file itself) — a junk
     record could never be deleted again."""
@@ -641,11 +638,11 @@ def test_ledger_rejects_non_telemetry_jsonl(tmp_path):
     path = str(tmp_path / "self.jsonl")
     ledger.append(
         path,
-        [ledger.record_from_file(os.path.join(ROOT, "BENCH_r05.json"))],
+        [ledger.record_from_file(os.path.join(bench_dir, "BENCH_r05.json"))],
     )
     ledger.append(
         path,
-        [ledger.record_from_file(os.path.join(ROOT, "BENCH_r04.json"))],
+        [ledger.record_from_file(os.path.join(bench_dir, "BENCH_r04.json"))],
     )
     with pytest.raises(ValueError, match="not a telemetry stream"):
         ledger.record_from_file(path)

@@ -4,6 +4,8 @@ counts, diameters, verdicts, and traces — on the real reference spec
 (/root/reference/compaction.tla) WITHOUT the hand-written model, and on
 the original specs in specs/."""
 
+import os
+
 import pytest
 
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
@@ -12,7 +14,7 @@ from pulsar_tlaplus_tpu.frontend.codegen import CompiledSpec
 from pulsar_tlaplus_tpu.frontend.loader import compaction_constants
 from pulsar_tlaplus_tpu.frontend.parser import parse_file
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import SMALL_CONFIGS
+from tests.helpers import SMALL_CONFIGS, SPECS
 
 from tests.helpers import REFERENCE_TLA  # specs/ first, /root/reference fallback
 
@@ -201,8 +203,8 @@ def test_compiled_original_specs(name):
     from pulsar_tlaplus_tpu.frontend.loader import bind_cfg
     from pulsar_tlaplus_tpu.utils.cfg import parse_cfg
 
-    mod = parse_file(f"/root/repo/specs/{name}.tla")
-    cfg = parse_cfg(open(f"/root/repo/specs/{name}.cfg").read())
+    mod = parse_file(os.path.join(SPECS, f"{name}.tla"))
+    cfg = parse_cfg(open(os.path.join(SPECS, f"{name}.cfg")).read())
     consts = bind_cfg(mod, cfg)
     spec = I.Spec(mod, consts)
     from pulsar_tlaplus_tpu.engine.interp_check import InterpChecker

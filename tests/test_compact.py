@@ -19,7 +19,7 @@ from pulsar_tlaplus_tpu.engine.liveness import LivenessChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.ops import compact, dedup, fpset
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import SMALL_CONFIGS, needs_shard_map
+from tests.helpers import SMALL_CONFIGS
 
 CONSUMER_CFG = dataclasses.replace(
     SMALL_CONFIGS["producer_on"], model_consumer=True
@@ -210,7 +210,6 @@ def test_device_engine_full_cfg_compact_differential():
     assert np.array_equal(logs["logshift"][1], logs["sort"][1])
 
 
-@needs_shard_map
 def test_sharded_engine_compact_differential_state_for_state():
     """The sharded append's compaction carries rows + routed parent +
     lane: both impls must produce identical per-shard stores on the
@@ -254,7 +253,6 @@ def test_sharded_engine_compact_differential_state_for_state():
         assert np.array_equal(la, lb)
 
 
-@needs_shard_map
 @pytest.mark.slow
 @pytest.mark.parametrize("impl", ["logshift", "sort"])
 def test_sharded_engine_full_cfg_both_compact_impls(impl):
@@ -353,7 +351,6 @@ def test_prewarm_compiles_every_tier_before_run():
     assert set(ck2._jits) < keys_before
 
 
-@needs_shard_map
 def test_sharded_prewarm_compiles_every_tier_before_run():
     from pulsar_tlaplus_tpu.engine.sharded_device import (
         ShardedDeviceChecker,

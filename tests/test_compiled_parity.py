@@ -4,6 +4,8 @@ built from raw .tla text and produce identical counts/verdicts —
 sharded checking, simulation, checkpoint/resume, and compiled temporal
 properties (the ``<>(predicate)`` fragment)."""
 
+import os
+
 import pytest
 
 from pulsar_tlaplus_tpu.engine.bfs import Checker
@@ -15,7 +17,7 @@ from pulsar_tlaplus_tpu.frontend.codegen import CompiledSpec
 from pulsar_tlaplus_tpu.frontend.loader import compaction_constants
 from pulsar_tlaplus_tpu.frontend.parser import parse_file
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import needs_shard_map, SMALL_CONFIGS
+from tests.helpers import SMALL_CONFIGS, SPECS
 
 from tests.helpers import REFERENCE_TLA  # specs/ first, /root/reference fallback
 
@@ -30,7 +32,6 @@ def _compiled(module, c, invariants=()):
     return CompiledSpec(spec, invariants=invariants)
 
 
-@needs_shard_map
 def test_compiled_sharded_matches_oracle(module):
     """-compile -sharded: the device-resident sharded engine accepts a
     CompiledSpec and matches the oracle exactly on an 8-shard mesh."""
@@ -48,14 +49,13 @@ def test_compiled_sharded_matches_oracle(module):
 @pytest.mark.parametrize(
     "name", ["subscription", "bookkeeper", "georeplication"]
 )
-@needs_shard_map
 def test_compiled_sharded_original_specs(name):
     from pulsar_tlaplus_tpu.engine.interp_check import InterpChecker
     from pulsar_tlaplus_tpu.frontend.loader import bind_cfg
     from pulsar_tlaplus_tpu.utils.cfg import parse_cfg
 
-    mod = parse_file(f"/root/repo/specs/{name}.tla")
-    cfg = parse_cfg(open(f"/root/repo/specs/{name}.cfg").read())
+    mod = parse_file(os.path.join(SPECS, f"{name}.tla"))
+    cfg = parse_cfg(open(os.path.join(SPECS, f"{name}.cfg")).read())
     spec = I.Spec(mod, bind_cfg(mod, cfg))
     want = InterpChecker(spec, invariants=()).run()
     got = ShardedDeviceChecker(

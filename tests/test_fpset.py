@@ -232,10 +232,6 @@ def test_load_seed_frontier_window_guard():
 # ---- sharded engine differential (virtual CPU mesh) ------------------
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="sharded engine needs jax.shard_map (newer jax)",
-)
 @pytest.mark.parametrize("impl", ["fpset", "sort"])
 def test_sharded_fpset_counts_match_oracle(impl):
     from pulsar_tlaplus_tpu.engine.sharded_device import (

@@ -17,7 +17,6 @@ from pulsar_tlaplus_tpu.models.georeplication import (
     GeoConstants,
     GeoreplicationModel,
 )
-from tests.helpers import needs_shard_map
 
 SPEC_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -121,7 +120,6 @@ def test_golden_bug_duplicate_delivery(module):
         cur = nxt[0]
 
 
-@needs_shard_map
 def test_sharded_counts_match():
     from pulsar_tlaplus_tpu.engine.sharded import ShardedChecker
 

@@ -8,7 +8,7 @@ from pulsar_tlaplus_tpu.engine.liveness import LivenessChecker
 from pulsar_tlaplus_tpu.engine.simulate import Simulator
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import needs_shard_map, SMALL_CONFIGS, assert_valid_counterexample
+from tests.helpers import SMALL_CONFIGS, assert_valid_counterexample
 
 LIVENESS_CASES = {
     "producer_on": SMALL_CONFIGS["producer_on"],
@@ -112,7 +112,6 @@ def test_liveness_wf_next_at_full_cfg_scale():
 
 
 @pytest.mark.parametrize("fairness", ["none", "wf_next"])
-@needs_shard_map
 def test_liveness_sharded_exploration_matches_oracle(fairness):
     """Round 5 (VERDICT r4 #7): LivenessChecker can explore on the
     mesh-sharded engine; the per-shard row stores are remapped to a

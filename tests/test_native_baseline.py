@@ -9,9 +9,8 @@ from pulsar_tlaplus_tpu import native
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from tests.helpers import SMALL_CONFIGS, needs_native_binary
 
-# every test here shells out to the committed baseline binary; where
-# the environment cannot run it (container glibc older than the build
-# host's) the whole module SKIPS — same regime as needs_shard_map
+# every test here shells out to the baseline binary, built from source
+# on first use; a host with no C++ toolchain SKIPS the whole module
 pytestmark = needs_native_binary
 
 

@@ -663,72 +663,6 @@ def test_validator_accepts_interleaved_runs_rejects_torn_seq(
     assert len(errs) == 1 and "status" in errs[0]
 
 
-# ---- the AOT cache cap (satellite) ----------------------------------
-
-
-class TestAotCacheCap:
-    @pytest.fixture(autouse=True)
-    def _isolated_cache(self, tmp_path, monkeypatch):
-        monkeypatch.setenv("PTT_AOT_DIR", str(tmp_path / "aot"))
-        monkeypatch.delenv("PTT_AOT_MAX_BYTES", raising=False)
-        self.dir = str(tmp_path / "aot")
-        os.makedirs(self.dir)
-
-    def _seed(self, n=4, size=1000):
-        from pulsar_tlaplus_tpu.utils import aot_cache
-
-        for i in range(n):
-            p = os.path.join(self.dir, f"e{i}.aotx")
-            with open(p, "wb") as f:
-                f.write(b"x" * size)
-            os.utime(p, (1000.0 + i, 1000.0 + i))  # e0 oldest
-        return aot_cache
-
-    def test_stats_and_clear(self):
-        aot_cache = self._seed(3)
-        st = aot_cache.stats()
-        assert st["entries"] == 3 and st["bytes"] == 3000
-        assert st["dir"] == self.dir
-        n, b = aot_cache.clear()
-        assert (n, b) == (3, 3000)
-        assert aot_cache.stats()["entries"] == 0
-
-    def test_lru_evicts_oldest_mtime_first(self):
-        aot_cache = self._seed(4)
-        n, b = aot_cache.enforce_cap(2500)
-        assert (n, b) == (2, 2000)  # two oldest gone
-        left = sorted(os.listdir(self.dir))
-        assert left == ["e2.aotx", "e3.aotx"]
-        assert aot_cache.enforce_cap(2500) == (0, 0)  # already fits
-
-    def test_cap_zero_disables_and_env_overrides(self, monkeypatch):
-        aot_cache = self._seed(4)
-        assert aot_cache.enforce_cap(0) == (0, 0)
-        monkeypatch.setenv("PTT_AOT_MAX_BYTES", "1500")
-        assert aot_cache.max_bytes() == 1500
-        n, _b = aot_cache.enforce_cap()  # default = env cap
-        assert n == 3 and os.listdir(self.dir) == ["e3.aotx"]
-        monkeypatch.setenv("PTT_AOT_MAX_BYTES", "not-a-number")
-        assert aot_cache.max_bytes() == aot_cache.DEFAULT_MAX_BYTES
-
-    def test_cli_cache_inspector(self, capsys):
-        from pulsar_tlaplus_tpu import cli
-
-        self._seed(2)
-        assert cli.main(["cache", "--stats"]) == 0
-        out = capsys.readouterr().out
-        assert "2 entrie(s)" in out
-        assert cli.main(["cache", "--evict-to", "1500"]) == 0
-        out = capsys.readouterr().out
-        assert "evicted 1 entrie(s)" in out
-        assert cli.main(["cache", "--clear"]) == 0
-        out = capsys.readouterr().out
-        assert "cleared 1 entrie(s)" in out
-        from pulsar_tlaplus_tpu.utils import aot_cache
-
-        assert aot_cache.stats()["entries"] == 0
-
-
 # ---- bench stale-stream hygiene (satellite) -------------------------
 
 

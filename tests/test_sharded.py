@@ -7,9 +7,7 @@ import pytest
 from pulsar_tlaplus_tpu.engine.sharded import ShardedChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import needs_shard_map, SMALL_CONFIGS
-
-pytestmark = needs_shard_map
+from tests.helpers import SMALL_CONFIGS
 
 
 @pytest.mark.parametrize("nd", [1, 2, 4, 8])

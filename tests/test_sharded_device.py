@@ -9,9 +9,7 @@ import pytest
 from pulsar_tlaplus_tpu.engine.sharded_device import ShardedDeviceChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.ref import pyeval as pe
-from tests.helpers import needs_shard_map, SMALL_CONFIGS, assert_valid_counterexample
-
-pytestmark = needs_shard_map
+from tests.helpers import SMALL_CONFIGS, assert_valid_counterexample
 
 
 @pytest.mark.parametrize("n", [1, 2, 8])

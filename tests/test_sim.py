@@ -448,11 +448,16 @@ def test_sim_ledger_gate_pinned_baseline(small_model, tmp_path):
     path = str(tmp_path / "sim_ledger.jsonl")
     shutil.copy(SIM_PINNED, path)
     assert ledger.validate_ledger(path) == []
-    # the committed CPU-mesh sim bench artifact (BASELINE.md round 18)
-    # ingests cleanly beside the pinned baseline
-    rec = ledger.record_from_file(
-        os.path.join(ROOT, "BENCH_sim_r18.json")
-    )
+    # a simulate-mode bench artifact (made-up figures) ingests cleanly
+    # beside the pinned baseline
+    art = tmp_path / "BENCH_sim.json"
+    art.write_text(json.dumps({
+        "metric": "simulation steps/sec", "value": 1000.0,
+        "unit": "sim steps/sec/chip", "bench_schema": 9,
+        "mode": "simulate", "walks_per_sec": 10.0,
+        "steps_per_state": 0.98, "sim_walkers": 4096, "sim_depth": 64,
+    }))
+    rec = ledger.record_from_file(str(art))
     assert rec["values"]["walks_per_sec"] > 0
     assert rec["values"]["mode"] == "simulate"
     assert ledger.append(path, [rec]) == 1

@@ -18,7 +18,7 @@ from pulsar_tlaplus_tpu.models.subscription import (
     SubscriptionConstants,
     SubscriptionModel,
 )
-from tests.helpers import needs_shard_map, tight_hbm_budget
+from tests.helpers import tight_hbm_budget
 
 SPEC_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
@@ -136,7 +136,6 @@ def test_no_crash_config_is_exactly_once(module):
     assert ri.distinct_states == rm.distinct_states
 
 
-@needs_shard_map
 def test_sharded_counts_match():
     from pulsar_tlaplus_tpu.engine.sharded import ShardedChecker
 

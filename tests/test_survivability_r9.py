@@ -24,7 +24,7 @@ from pulsar_tlaplus_tpu.engine.liveness import LivenessChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from pulsar_tlaplus_tpu.utils import ckpt, faults
-from tests.helpers import SMALL_CONFIGS, needs_shard_map
+from tests.helpers import SMALL_CONFIGS
 
 KW = dict(sub_batch=2048, visited_cap=1 << 16, frontier_cap=1 << 15)
 SKW = dict(n_devices=4, sub_batch=512, visited_cap=1 << 13)
@@ -167,7 +167,6 @@ def test_level1_fault_site_has_breadcrumb(monkeypatch, tmp_path):
 # ---- mesh-wide OOM recovery on the sharded engine --------------------
 
 
-@needs_shard_map
 @pytest.mark.parametrize(
     "invariant,oom_level,depth",
     [
@@ -210,7 +209,6 @@ def test_sharded_oom_recovery_parity(
     assert r.trace == full.trace
 
 
-@needs_shard_map
 def test_sharded_oom_at_flush_recovers(monkeypatch, tmp_path):
     """The new flush-site drill hits the sharded fpset flush: recovery
     rebuilds mesh-wide and the full published count is reached."""
@@ -229,7 +227,6 @@ def test_sharded_oom_at_flush_recovers(monkeypatch, tmp_path):
     assert r.distinct_states == 45198 and r.diameter == 20
 
 
-@needs_shard_map
 def test_sharded_oom_without_frame_truncates(monkeypatch):
     """No checkpoint configured: exhaustion keeps the honest
     truncate contract (stop_reason "hbm") instead of crashing."""
@@ -245,7 +242,6 @@ def test_sharded_oom_without_frame_truncates(monkeypatch):
     assert 0 < r.distinct_states < 45198
 
 
-@needs_shard_map
 def test_sharded_oom_then_kill_resume_parity(tmp_path):
     """Subprocess drill: the run recovers from an injected OOM, is
     then hard-killed, and ``-recover`` still reproduces the unfaulted
@@ -465,7 +461,6 @@ def test_smoke_device_oom_at_flush(monkeypatch, tmp_path):
     assert r.violation == "DuplicateNullKeyMessage"
 
 
-@needs_shard_map
 def test_smoke_sharded_fpset_fail(monkeypatch):
     """The sharded fpset_fail drill must fail-stop like a real probe
     overflow — one synthetic dropped lane, on one shard."""
@@ -479,7 +474,6 @@ def test_smoke_sharded_fpset_fail(monkeypatch):
         ShardedDeviceChecker(_shipped(), **SKW).run()
 
 
-@needs_shard_map
 def test_smoke_sharded_ckpt_fail(monkeypatch, tmp_path):
     from pulsar_tlaplus_tpu.engine.sharded_device import (
         ShardedDeviceChecker,

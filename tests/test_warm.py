@@ -681,7 +681,7 @@ def test_no_warm_opt_out(tmp_path, pool, cfg_dir):
 
 def test_warm_store_lru_byte_cap(tmp_path, base_artifact):
     """--warm-max-bytes: oldest-touched artifacts evict past the cap
-    (the aot_cache discipline)."""
+    (mtime LRU)."""
     store, adir, _ck, _invs = _copy_store(base_artifact, tmp_path / "s")
     nbytes = store.entry_bytes(adir)
     # a second entry under a forged sig key, with the first made OLD
