@@ -1,0 +1,8 @@
+"""Device self seconds of the window's operations under the program's
+``ptt.append`` stage scope (``benchmark/lib/program_spans.py``)."""
+
+from benchmark.lib import program_spans
+
+
+def read(ctx, params):
+    return program_spans.stage_seconds(ctx, "append")

@@ -1,0 +1,8 @@
+"""Device self seconds of the window's operations under the program's
+``ptt.compact`` stage scope (``benchmark/lib/program_spans.py``)."""
+
+from benchmark.lib import program_spans
+
+
+def read(ctx, params):
+    return program_spans.stage_seconds(ctx, "compact")

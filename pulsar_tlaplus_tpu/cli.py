@@ -448,6 +448,7 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
     """Engine selection shared by the registry and spec->kernel compiler
     paths: liveness property, simulation, sharded (device or host), or
     the single-device checker — all via the generic model protocol."""
+    from pulsar_tlaplus_tpu.obs import spans
     from pulsar_tlaplus_tpu.utils.render import render_trace
 
     if args.xprof and (
@@ -583,33 +584,38 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         # states/ directory contract on the device-resident path)
         from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 
-        ck = DeviceChecker(
-            model,
-            invariants=invariants,
-            check_deadlock=not args.nodeadlock,
-            sub_batch=min(args.chunk, 4096),
-            visited_cap=1 << 16,
-            frontier_cap=1 << 14,
-            max_states=args.maxstates,
-            progress=True,
-            metrics_path=args.metrics,
-            visited_impl=args.visited,
-            compact_impl=_tunable(args, "compact", args.compact),
-            probe_impl=_tunable(args, "probe_impl", args.probe_impl),
-            expand_impl=_tunable(args, "expand_impl", args.expand_impl),
-            sieve_impl=_tunable(args, "sieve_impl", args.sieve_impl),
-            fuse=args.fuse,
-            fuse_group=args.fuse_group,
-            hbm_budget=args.hbm_budget,
-            spill_compress=(False if args.no_spill_compress else None),
-            profile=_profile_arg(args),
-            adapt=_adapt_arg(args),
-            checkpoint_path=args.checkpoint,
-            telemetry=args.telemetry,
-            heartbeat_s=args.progress,
-            xprof_dir=args.xprof,
-            xprof_levels=args.xprof_window,
-        )
+        with spans.span("cli.engine_init"):
+            ck = DeviceChecker(
+                model,
+                invariants=invariants,
+                check_deadlock=not args.nodeadlock,
+                sub_batch=min(args.chunk, 4096),
+                visited_cap=1 << 16,
+                frontier_cap=1 << 14,
+                max_states=args.maxstates,
+                progress=True,
+                metrics_path=args.metrics,
+                visited_impl=args.visited,
+                compact_impl=_tunable(args, "compact", args.compact),
+                probe_impl=_tunable(args, "probe_impl", args.probe_impl),
+                expand_impl=_tunable(
+                    args, "expand_impl", args.expand_impl
+                ),
+                sieve_impl=_tunable(args, "sieve_impl", args.sieve_impl),
+                fuse=args.fuse,
+                fuse_group=args.fuse_group,
+                hbm_budget=args.hbm_budget,
+                spill_compress=(
+                    False if args.no_spill_compress else None
+                ),
+                profile=_profile_arg(args),
+                adapt=_adapt_arg(args),
+                checkpoint_path=args.checkpoint,
+                telemetry=args.telemetry,
+                heartbeat_s=args.progress,
+                xprof_dir=args.xprof,
+                xprof_levels=args.xprof_window,
+            )
     else:
         from pulsar_tlaplus_tpu.engine.bfs import Checker
 
@@ -650,7 +656,10 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 "engine resume with -engine host)"
             )
         sys.exit(f"tpu-tlc: {msg}")
-    rc = _report(r, constants, time.time() - t0, checkpoint=args.checkpoint)
+    with spans.span("cli.report"):
+        rc = _report(
+            r, constants, time.time() - t0, checkpoint=args.checkpoint
+        )
     # cfg PROPERTIES are honored automatically after a clean safety pass
     # (TLC checks temporal properties from the same run); the sharded
     # drivers do not keep the state log the liveness engine needs
@@ -1521,7 +1530,7 @@ def _add_client_args(sp) -> None:
     )
 
 
-def main(argv=None):
+def _build_parser():
     p = argparse.ArgumentParser(prog="tpu-tlc")
     sub = p.add_subparsers(dest="cmd", required=True)
 
@@ -2407,9 +2416,14 @@ def main(argv=None):
     )
     pc.add_argument("-chunk", type=int, default=4096)
     pc.add_argument("-maxstates", type=int, default=200_000_000)
-    args = p.parse_args(argv)
+    return p
 
-    if args.cmd != "check":
+
+def main(argv=None):
+    argv = sys.argv[1:] if argv is None else list(argv)
+    if argv[:1] != ["check"]:
+        # the daemon and the client verbs (which never import jax)
+        args = _build_parser().parse_args(argv)
         return {
             "serve": _cmd_serve,
             "dispatch": _cmd_dispatch,
@@ -2424,6 +2438,19 @@ def main(argv=None):
             "metrics": _cmd_metrics,
             "top": _cmd_top,
         }[args.cmd](args)
+    # host spans of one check (obs/spans.py; with no profiler trace
+    # running they cost nothing): ptt:check around ptt:cli.parse,
+    # .build, .engine_init, .report and the engine's own ptt:run
+    from pulsar_tlaplus_tpu.obs import spans
+
+    with spans.span("check"):
+        with spans.span("cli.parse"):
+            args = _build_parser().parse_args(argv)
+        return _cmd_check(args)
+
+
+def _cmd_check(args) -> int:
+    from pulsar_tlaplus_tpu.obs import spans
 
     args.xprof_window = None
     if args.xprof_levels:
@@ -2459,7 +2486,8 @@ def main(argv=None):
     cfg_path = args.config or os.path.splitext(spec_path)[0] + ".cfg"
     if not os.path.exists(cfg_path):
         sys.exit(f"tpu-tlc: config file not found: {cfg_path}")
-    tlc_cfg = cfgmod.load(cfg_path)
+    with spans.span("cli.parse"):
+        tlc_cfg = cfgmod.load(cfg_path)
     invariants = tuple(args.invariant or tlc_cfg.invariants)
     if isinstance(args.workers, int) and not args.sharded:
         # TLC parity: -workers N is worker parallelism; here that is
@@ -2506,7 +2534,8 @@ def main(argv=None):
             )
 
     try:
-        model, constants = registry.COMPILED[module](tlc_cfg)
+        with spans.span("cli.build"):
+            model, constants = registry.COMPILED[module](tlc_cfg)
     except ValueError as e:
         sys.exit(f"tpu-tlc: {e}")
     unknown = [i for i in invariants if i not in model.invariants]

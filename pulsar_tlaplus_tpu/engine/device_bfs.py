@@ -67,6 +67,7 @@ min-lane-wins dedup).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -77,6 +78,7 @@ import numpy as np
 from jax import lax
 
 from pulsar_tlaplus_tpu.engine.bfs import CheckerResult
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.store import budget as store_budget
 from pulsar_tlaplus_tpu.store import sieve as store_sieve
@@ -122,6 +124,30 @@ TAG_BIT = jnp.uint32(1 << 31)
 IDX_MASK = jnp.uint32((1 << 31) - 1)
 
 
+# The shared traced sub-functions of ops/ under the stage names of the
+# work counters (obs/spans.py): the stage chain's jits and the fused
+# level kernel both call these, so a device trace names the same stages
+# under either -fuse mode.
+_probe_flush_acc = spans.staged("probe")(fpset.flush_acc)
+_compact_rows = spans.staged("compact")(compact_ops.compact_rows)
+
+
+def _in_phase(name: str):
+    """Method decorator: run under the exclusive host phase ``name`` of
+    the current run's clock (``spans.PhaseClock``) — a site the host
+    passes once per dispatch, fetch or boundary, never per row."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *args, **kwargs):
+            with self._clock.phase(name):
+                return fn(self, *args, **kwargs)
+
+        return wrapped
+
+    return deco
+
+
 class DeviceChecker:
     """Level-synchronous BFS on one device with no hot-path host syncs.
 
@@ -130,6 +156,17 @@ class DeviceChecker:
     the accumulator; a flush merges ``VCAP + ACAP`` keys.  The host
     grows VCAP / the row store between flushes (geometric tiers,
     re-jitting per tier via the jit cache).
+
+    Beside the ``CheckerResult`` a run leaves two things on the
+    checker, both part of its result: ``last_stats`` (the run's
+    counters; the telemetry ``result`` event carries all of them,
+    the ``host_<phase>_s`` and ``jit_*`` keys of ``obs/spans.py``
+    among them) and ``last_bufs`` — the device buffers as the run
+    left them: ``rows`` (packed states in gid order, windowed by
+    ``rows_window``), ``parent`` and ``lane`` (the trace logs, one
+    entry per gid), ``vk`` (the visited set).  The liveness engine,
+    the tuner's differential, the tests and the benchmark's sample
+    replay read them; set it to None to free the device memory.
     """
 
     def __init__(
@@ -602,6 +639,10 @@ class DeviceChecker:
         self._flush_seq = 0
         self._jits: Dict[tuple, object] = {}
         self.last_stats: Dict[str, float] = {}
+        self.last_bufs = None  # part of the result: the class docstring
+        # the host-phase clock of the current run (a fresh one per
+        # run(); this one serves growth sites reached outside a run)
+        self._clock = spans.PhaseClock()
         # telemetry (round 8): a path or obs.telemetry.Telemetry; the
         # stream is opened per run() with a fresh run_id, and the
         # heartbeat reports from ``_snap`` — the last fetched stats
@@ -723,10 +764,11 @@ class DeviceChecker:
         )
         if not self._stage_timing:
             return out
-        t0 = time.time()
+        t0 = time.perf_counter()
         jax.block_until_ready(out)
         self.last_stats[f"stage_{name}_s"] = (
-            self.last_stats.get(f"stage_{name}_s", 0.0) + time.time() - t0
+            self.last_stats.get(f"stage_{name}_s", 0.0)
+            + time.perf_counter() - t0
         )
         return out
 
@@ -763,13 +805,15 @@ class DeviceChecker:
             return self._jits[key]
         G, W = self.G, self.W
 
-        def step(rows, off):
+        @spans.staged("expand")
+        def ptt_slice(rows, off):
             return lax.dynamic_slice(rows, (off * W,), (G * W,))
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_slice)
         self._jits[key] = fn
         return fn
 
+    @spans.staged("expand")
     def _expand_body(
         self, ak, arows, window, f_off, n_live, dead_gid, gid_base,
         acc_off,
@@ -879,7 +923,7 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
 
-        def step(*args):
+        def ptt_expand(*args):
             ak = args[: self.K]
             arows, window, f_off, n_live, dead_gid, gid_base, acc_off = args[
                 self.K:
@@ -890,7 +934,7 @@ class DeviceChecker:
             )
             return (*ak, arows, dead)
 
-        fn = jax.jit(step, donate_argnums=tuple(range(self.K + 1)))
+        fn = jax.jit(ptt_expand, donate_argnums=tuple(range(self.K + 1)))
         self._jits[key] = fn
         return fn
 
@@ -921,7 +965,8 @@ class DeviceChecker:
                 packed,
             )
 
-        def step(*args):
+        @spans.staged("init")
+        def ptt_init(*args):
             ak = args[: self.K]
             arows, f_off, acc_off = args[self.K:]
             _, (kcols, packed) = lax.scan(
@@ -939,7 +984,7 @@ class DeviceChecker:
             )
             return (*ak, arows)
 
-        fn = jax.jit(step, donate_argnums=tuple(range(self.K + 1)))
+        fn = jax.jit(ptt_init, donate_argnums=tuple(range(self.K + 1)))
         self._jits[key] = fn
         return fn
 
@@ -957,7 +1002,8 @@ class DeviceChecker:
             return self._jits[key]
         ACAP, K = self.ACAP, self.K
 
-        def step(*args):
+        @spans.staged("probe")
+        def ptt_flush(*args):
             vk = args[:K]
             ak = args[K: 2 * K]
             n_acc = args[2 * K]
@@ -984,7 +1030,7 @@ class DeviceChecker:
             flag_acc = flag_sorted[sp.shape[0] - ACAP:]
             return (*vk2, n_new, flag_acc)
 
-        fn = jax.jit(step, donate_argnums=tuple(range(self.K)))
+        fn = jax.jit(ptt_flush, donate_argnums=tuple(range(self.K)))
         self._jits[key] = fn
         return fn
 
@@ -1014,13 +1060,13 @@ class DeviceChecker:
             return self._jits[key]
         K = self.K
 
-        def step(*args):
+        def ptt_fpflush(*args):
             tc = args[:K]
             ak = args[K: 2 * K]
             n_acc, fpm = args[2 * K], args[2 * K + 1]
             # the flush body lives in ops/fpset.py since r13 so the
             # fused level megakernel chains the IDENTICAL trace
-            tc2, n_new, flag, fpm = fpset.flush_acc(
+            tc2, n_new, flag, fpm = _probe_flush_acc(
                 tc, ak, n_acc, fpm,
                 dense_rounds=self.fps_dense, stages=self.fps_stages,
                 compact_impl=self.compact_impl,
@@ -1028,7 +1074,7 @@ class DeviceChecker:
             )
             return (*tc2, n_new, flag, fpm)
 
-        fn = jax.jit(step, donate_argnums=tuple(range(self.K)))
+        fn = jax.jit(ptt_fpflush, donate_argnums=tuple(range(self.K)))
         self._jits[key] = fn
         return fn
 
@@ -1042,7 +1088,8 @@ class DeviceChecker:
             return self._jits[key]
         K, TCAP = self.K, self.TCAP
 
-        def step(*old):
+        @spans.staged("rehash")
+        def ptt_rehash(*old):
             new, failed = fpset.rehash_cols(
                 old, fpset.empty_cols(2 * TCAP, K)
             )
@@ -1050,7 +1097,7 @@ class DeviceChecker:
 
         # no donation: the inputs are half the output shape, so XLA
         # could never reuse them (donating only produces warnings)
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_rehash)
         self._jits[key] = fn
         return fn
 
@@ -1083,12 +1130,12 @@ class DeviceChecker:
             return self._jits[key]
         impl = self.compact_impl
 
-        def step(arows, flag_acc):
+        def ptt_compact(arows, flag_acc):
             # the row-matrix compaction body lives in ops/compact.py
             # since r13 (shared with the fused level megakernel)
-            return compact_ops.compact_rows(arows, flag_acc, impl=impl)
+            return _compact_rows(arows, flag_acc, impl=impl)
 
-        fn = jax.jit(step, donate_argnums=(0,))
+        fn = jax.jit(ptt_compact, donate_argnums=(0,))
         self._jits[key] = fn
         return fn
 
@@ -1120,19 +1167,20 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
 
-        def step(rows_store, parent_log, lane_log, crows, idx,
-                 n_new, n_visited, viol, acc_base, is_init, row_base,
-                 rows_ok, log_base):
+        def ptt_append(rows_store, parent_log, lane_log, crows, idx,
+                       n_new, n_visited, viol, acc_base, is_init,
+                       row_base, rows_ok, log_base):
             return self._append_body(
                 rows_store, parent_log, lane_log, crows, idx, n_new,
                 n_visited, viol, acc_base, is_init, row_base, rows_ok,
                 log_base,
             )
 
-        fn = jax.jit(step, donate_argnums=(0, 1, 2))
+        fn = jax.jit(ptt_append, donate_argnums=(0, 1, 2))
         self._jits[key] = fn
         return fn
 
+    @spans.staged("append")
     def _append_body(self, rows_store, parent_log, lane_log, crows,
                      idx, n_new, n_visited, viol, acc_base, is_init,
                      row_base, rows_ok, log_base=jnp.int32(0)):
@@ -1296,7 +1344,12 @@ class DeviceChecker:
         plimit = jnp.int32(PCAP - APAD)
         llimit = None if frontier_mode else jnp.int32(LCAP - APAD)
 
-        def step(*args):
+        # the whole kernel traces under ptt.levelctl; the four stages
+        # nest inside it, and an operation belongs to its innermost
+        # scope — so the loop's own control flow, the boundary
+        # bookkeeping and the stats vector are what levelctl keeps
+        @spans.staged("levelctl")
+        def ptt_level(*args):
             vk = args[:K]
             ak = args[K: 2 * K]
             (arows, rows, parent, lane, n_visited, dead, viol, fpm,
@@ -1343,22 +1396,22 @@ class DeviceChecker:
                 # same masking the stage chain's partial fills rely on)
                 for w in range(FLUSH):
                     f_off = w_off + jnp.int32(w * G)
-                    window = lax.dynamic_slice(
-                        rows, ((lb - row_base + f_off) * W,), (G * W,)
-                    )
+                    with spans.stage("expand"):
+                        window = lax.dynamic_slice(
+                            rows, ((lb - row_base + f_off) * W,),
+                            (G * W,),
+                        )
                     ak, arows, dead = self._expand_body(
                         ak, arows, window, f_off, nf, dead, lb,
                         jnp.int32(w * NCs),
                     )
-                vk, n_new, flag, fpm = fpset.flush_acc(
+                vk, n_new, flag, fpm = _probe_flush_acc(
                     vk, ak, jnp.int32(ACAP), fpm,
                     dense_rounds=self.fps_dense,
                     stages=self.fps_stages, compact_impl=impl,
                     probe_impl=self.probe_impl,
                 )
-                crows, idx = compact_ops.compact_rows(
-                    arows, flag, impl=impl
-                )
+                crows, idx = _compact_rows(arows, flag, impl=impl)
                 if frontier_mode:
                     # per-group actual-occupancy check — exactly the
                     # predicate the stage loop evaluates at its forced
@@ -1431,7 +1484,7 @@ class DeviceChecker:
                 fpm, wkm, statsvec,
             )
 
-        fn = jax.jit(step, donate_argnums=tuple(range(2 * K + 4)))
+        fn = jax.jit(ptt_level, donate_argnums=tuple(range(2 * K + 4)))
         self._jits[key] = fn
         return fn
 
@@ -1452,7 +1505,8 @@ class DeviceChecker:
         W = self.W
         CW = self.SHIFT_CW
 
-        def step(rows, src_off, n_rows):
+        @spans.staged("levelctl")
+        def ptt_shift(rows, src_off, n_rows):
             nw = n_rows * W
 
             def body(i, rows):
@@ -1465,7 +1519,7 @@ class DeviceChecker:
                 0, (nw + CW - 1) // CW, body, rows
             )
 
-        fn = jax.jit(step, donate_argnums=(0,))
+        fn = jax.jit(ptt_shift, donate_argnums=(0,))
         self._jits[key] = fn
         return fn
 
@@ -1611,17 +1665,19 @@ class DeviceChecker:
 
         if self.visited_impl == "fpset":
             # stats layout: [nv, dead, viol..., flushes, rounds, failed]
-            def step(n_visited, dead_gid, viol, fpm):
+            @spans.staged("levelctl")
+            def ptt_stats(n_visited, dead_gid, viol, fpm):
                 return jnp.concatenate(
                     [jnp.stack([n_visited, dead_gid]), viol, fpm]
                 )
         else:
-            def step(n_visited, dead_gid, viol):
+            @spans.staged("levelctl")
+            def ptt_stats(n_visited, dead_gid, viol):
                 return jnp.concatenate(
                     [jnp.stack([n_visited, dead_gid]), viol]
                 )
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_stats)
         self._jits[key] = fn
         return fn
 
@@ -1671,7 +1727,8 @@ class DeviceChecker:
         n_inv = len(self.invariant_names)
         keyspec = self.keys
 
-        def merge(*args):
+        @spans.staged("seed")
+        def ptt_seed_merge(*args):
             vk = args[:K]
             rows, n_valid, n_visited, viol, gid_base = args[K:]
             kcols = keyspec.make(rows)
@@ -1694,7 +1751,7 @@ class DeviceChecker:
                 viol = jnp.minimum(viol, jnp.stack(vnew))
             return (*vk2, n_visited + n_new, viol)
 
-        fn = jax.jit(merge, donate_argnums=tuple(range(self.K)))
+        fn = jax.jit(ptt_seed_merge, donate_argnums=tuple(range(self.K)))
         self._jits[key] = fn
         return fn
 
@@ -1717,7 +1774,8 @@ class DeviceChecker:
         n_inv = len(self.invariant_names)
         keyspec = self.keys
 
-        def merge(*args):
+        @spans.staged("seed")
+        def ptt_fpseed_merge(*args):
             tc = args[:K]
             rows, n_valid, n_visited, viol, gid_base, fpm = args[K:]
             kcols = keyspec.make(rows)
@@ -1748,7 +1806,9 @@ class DeviceChecker:
                 viol, fpm,
             )
 
-        fn = jax.jit(merge, donate_argnums=tuple(range(self.K)))
+        fn = jax.jit(
+            ptt_fpseed_merge, donate_argnums=tuple(range(self.K))
+        )
         self._jits[key] = fn
         return fn
 
@@ -1762,7 +1822,9 @@ class DeviceChecker:
 
         W = self.W
 
-        def write(rows_store, parent_log, lane_log, rows, par, lane, off):
+        @spans.staged("seed")
+        def ptt_seed_write(rows_store, parent_log, lane_log, rows, par,
+                           lane, off):
             rows_store = lax.dynamic_update_slice(
                 rows_store, rows.reshape(rows.shape[0] * W), (off * W,)
             )
@@ -1770,7 +1832,7 @@ class DeviceChecker:
             lane_log = lax.dynamic_update_slice(lane_log, lane, (off,))
             return rows_store, parent_log, lane_log
 
-        fn = jax.jit(write, donate_argnums=(0, 1, 2))
+        fn = jax.jit(ptt_seed_write, donate_argnums=(0, 1, 2))
         self._jits[key] = fn
         return fn
 
@@ -1816,6 +1878,7 @@ class DeviceChecker:
             int(np.asarray(parents[::step], np.int64).sum()),
         )
 
+    @_in_phase("seed_load")
     def _load_seed(self, bufs, st, seed):
         """Bulk-load a host-enumerated BFS prefix: packed states in BFS
         (= gid) order with parent gids (roots: ``-1 - init_idx``) and
@@ -1985,6 +2048,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------ growth
 
+    @_in_phase("grow")
     def _grow_visited(self, bufs, need: int):
         cap = self._capv()
         # clamp at the most any run can use: nv never exceeds SCAP, so
@@ -2073,6 +2137,7 @@ class DeviceChecker:
             tcap *= 2
         return tcap
 
+    @_in_phase("grow")
     def _grow_logs(self, bufs, need: int):
         cap = self._capp()
         target = self._next_cap(self.PCAP, need, cap)
@@ -2086,6 +2151,7 @@ class DeviceChecker:
             )
             self.PCAP += pad
 
+    @_in_phase("grow")
     def _grow_store(self, bufs, need: int):
         """Admit ``need`` states in the trace logs and (all-mode only)
         the row store.  Frontier mode's rows window is fixed — row
@@ -2332,6 +2398,7 @@ class DeviceChecker:
             jnp.int32(0), jnp.int32(0), jnp.bool_(True),
         )
 
+    @spans.spanned("warmup")
     def warmup(self, seed: bool = False, tiers: bool = True) -> float:
         """Compile every hot-path jit at the current tiers on dummy data
         (outside any timed budget); returns the compile wall time.
@@ -2342,14 +2409,14 @@ class DeviceChecker:
         (VERDICT r5 #8).  Per-stage compile times land in
         ``self.last_stats`` as ``compile_<stage>_s`` (the warmup
         breakdown VERDICT r3 asks for)."""
-        t0 = time.time()
+        t0 = time.perf_counter()
         z = jnp.zeros
         n_inv = len(self.invariant_names)
         K = self.K
         tlast = [t0]
 
         def mark(stage: str):
-            now = time.time()
+            now = time.perf_counter()
             self.last_stats[f"compile_{stage}_s"] = round(
                 now - tlast[0], 1
             )
@@ -2529,7 +2596,7 @@ class DeviceChecker:
         if tiers:
             self._prewarm_tiers()
             mark("tiers")
-        compile_s = time.time() - t0
+        compile_s = time.perf_counter() - t0
         # one-time RTT probe, AFTER the compile clock stops (it
         # is a measurement, not a compile — ~3 round trips must not
         # inflate compile_warmup_s): the report layer subtracts
@@ -2545,9 +2612,40 @@ class DeviceChecker:
         device state from the ``checkpoint_path`` frame and continues
         the interrupted run (wall clock cumulative across resumes; the
         time budget gets a fresh clock)."""
-        t0 = time.time()
+        # this run's exclusive host phases, and the compile meter's
+        # reading before it (obs/spans.py); every duration of the run
+        # is on the monotonic clock the phases use
+        clock = self._clock = spans.PhaseClock(obs.new_run_id())
+        self._jit0 = spans.compile_meter().snapshot()
+        with spans.span("run", run_id=clock.run_id):
+            with clock.phase("init"):
+                hb = self._begin_run(clock.t0, resume)
+            try:
+                with self._watcher:
+                    if hb is not None:
+                        hb.start()
+                    return self._run(clock.t0, seed, resume)
+            except BaseException as e:
+                # the stream must tell WHY it ends when no result
+                # record will follow (probe overflow, OOM without a
+                # frame, ^C ^C)
+                self.tel.emit("error", error=repr(e)[:300])
+                raise
+            finally:
+                if hb is not None:
+                    hb.stop()
+                faults.set_observer(None)
+                self._xprof_close()
+                self._watcher = None
+                if obs.owns_stream(self._telemetry_arg):
+                    self.tel.close()
+                self.tel = obs.NULL
+
+    def _begin_run(self, t0, resume: bool):
+        """Per-run state, the telemetry stream and the crash
+        breadcrumbs of one :meth:`run`; returns the heartbeat (or
+        None) and leaves the preemption watcher in ``_watcher``."""
         self._budget_t0 = t0
-        self._host_wait_s = 0.0
         self._bufs_poisoned = False
         self._last_fpm = None
         self._flush_seq = 0
@@ -2564,7 +2662,12 @@ class DeviceChecker:
         # (cost attribution prices THIS run; a pooled checker's next
         # job must not inherit the last job's work), so clear them and
         # rebaseline the device-vector / nv-delta trackers
-        for k in [k for k in self.last_stats if k.startswith("work_")]:
+        # (the phase and compile-meter keys likewise: a run that ends
+        # in an error must not show the last run's)
+        for k in [
+            k for k in self.last_stats
+            if k.startswith(("work_", "host_", "jit_", "level_wall_max_"))
+        ]:
             del self.last_stats[k]
         self._wkm_prev = np.zeros((fpset.WKM_LOGICAL_N,), np.int64)
         self._last_wkm_delta: Dict[str, int] = {}
@@ -2625,9 +2728,9 @@ class DeviceChecker:
         ckpt.cleanup_stale_tmp(self.checkpoint_path)
         # telemetry stream: fresh run_id per run() (frames embed it, so
         # a resumed run can link back to the writer of its frame)
-        rid = obs.new_run_id()
+        rid = self._clock.run_id
         self.tel = obs.as_telemetry(self._telemetry_arg, run_id=rid)
-        self._run_id = self.tel.run_id or rid
+        self._run_id = self._clock.run_id = self.tel.run_id or rid
         self._snap = {"distinct_states": 0}
         # crash breadcrumbs: fault events flush BEFORE the fault fires
         # (kill sites leave no other trace).  Installed FIRST — before
@@ -2657,25 +2760,7 @@ class DeviceChecker:
             enabled=bool(self.checkpoint_path), log=self._log
         )
         self._watcher = watcher
-        try:
-            with watcher:
-                if hb is not None:
-                    hb.start()
-                return self._run(t0, seed, resume)
-        except BaseException as e:
-            # the stream must tell WHY it ends when no result record
-            # will follow (probe overflow, OOM without a frame, ^C ^C)
-            self.tel.emit("error", error=repr(e)[:300])
-            raise
-        finally:
-            if hb is not None:
-                hb.stop()
-            faults.set_observer(None)
-            self._xprof_close()
-            self._watcher = None
-            if obs.owns_stream(self._telemetry_arg):
-                self.tel.close()
-            self.tel = obs.NULL
+        return hb
 
     def _emit_header(self, resume: bool):
         """The run-header record: config signature, device, engine —
@@ -2772,7 +2857,24 @@ class DeviceChecker:
             self._xprof_done = True  # one window per run
         self.tel.emit("xprof", action="stop", dir=self.xprof_dir)
 
+    @property
+    def _host_wait_s(self) -> float:
+        """Seconds this run's host has been blocked on stats fetches:
+        the ``fetch`` phase of its clock."""
+        return self._clock.seconds_of("fetch")
+
     def _run(self, t0, seed, resume) -> CheckerResult:
+        with self._clock.phase("init"):
+            frame = self._start(t0, seed, resume)
+        # everything of the level loop that is no phase of its own
+        # (level replay, telemetry emits, the log line, tuner, fault
+        # polls) is ``account``
+        with self._clock.phase("account"):
+            return self._run_recoverable(*frame)
+
+    def _start(self, t0, seed, resume):
+        """Fresh, seeded or restored device state up to the first
+        level boundary: the arguments of :meth:`_run_recoverable`."""
         if resume:
             if seed is not None:
                 raise ValueError("resume and seed are mutually exclusive")
@@ -2787,11 +2889,11 @@ class DeviceChecker:
             # write stall; the scheduler reads it per resumed slice
             self._restore_s = time.perf_counter() - t_restore
             self.last_stats["restore_s"] = round(self._restore_s, 3)
-            t0 = time.time() - saved_wall
+            t0 = time.perf_counter() - saved_wall
             self.rec.arm()  # the on-disk frame is valid
             self._emit_header(resume=True)
             stats = self._fetch(st)
-            return self._run_recoverable(
+            return (
                 t0, bufs, st, rb, level_sizes, level_base, nf, stats
             )
         m = self.model
@@ -2914,10 +3016,11 @@ class DeviceChecker:
                 self._work_add(
                     init_lanes=min(self.NCs, n_init - f_off)
                 )
-                out = self._init_jit()(
-                    *bufs["ak"], bufs["arows"], jnp.int32(f_off),
-                    jnp.int32(w * self.NCs),
-                )
+                with self._clock.phase("dispatch", level=1):
+                    out = self._init_jit()(
+                        *bufs["ak"], bufs["arows"], jnp.int32(f_off),
+                        jnp.int32(w * self.NCs),
+                    )
                 bufs["ak"], bufs["arows"] = out[:K], out[K]
                 w += 1
                 if w == self.FLUSH or f_off + self.NCs >= n_init:
@@ -2932,9 +3035,7 @@ class DeviceChecker:
         nv = int(stats[0])
         level_base = nv - (level_sizes[-1] if level_sizes else 0)
         nf = nv - level_base
-        return self._run_recoverable(
-            t0, bufs, st, rb, level_sizes, level_base, nf, stats
-        )
+        return t0, bufs, st, rb, level_sizes, level_base, nf, stats
 
     def _fetch(self, st, vec=None):
         """One stats fetch (the only hot-path host sync): returns the
@@ -2946,24 +3047,24 @@ class DeviceChecker:
         one, so a fused level pays NO separate stats dispatch); its
         prefix layout matches ``_stats_jit`` and any tail beyond the
         fpm block is returned untouched for the caller to parse."""
-        tf = time.time()
         fpmode = self.visited_impl == "fpset"
-        if vec is not None:
-            out = np.asarray(vec)
-        elif fpmode:
-            out = np.asarray(
-                self._stats_jit()(
-                    st["n_visited"], st["dead_gid"], st["viol"],
-                    st["fpm"],
+        # the host blocked on the device: host_fetch_s (= host_wait_s)
+        with self._clock.phase("fetch"):
+            if vec is not None:
+                out = np.asarray(vec)
+            elif fpmode:
+                out = np.asarray(
+                    self._stats_jit()(
+                        st["n_visited"], st["dead_gid"], st["viol"],
+                        st["fpm"],
+                    )
                 )
-            )
-        else:
-            out = np.asarray(
-                self._stats_jit()(
-                    st["n_visited"], st["dead_gid"], st["viol"]
+            else:
+                out = np.asarray(
+                    self._stats_jit()(
+                        st["n_visited"], st["dead_gid"], st["viol"]
+                    )
                 )
-            )
-        self._host_wait_s += time.time() - tf
         self._fetch_n += 1
         nv = int(out[0])
         self._snap["distinct_states"] = nv
@@ -3073,6 +3174,7 @@ class DeviceChecker:
             self._compact_prev_s = s
         self.tel.emit("compact", **f)
 
+    @_in_phase("dispatch")
     def _flush_acc(self, bufs, st, rb, n_acc, acc_base, is_init):
         """Dispatch the dedup + append for the current accumulator
         fill (``n_acc`` valid lanes covering source rows starting
@@ -3180,6 +3282,7 @@ class DeviceChecker:
     def _spill_tier_label(self) -> str:
         return "ram+disk" if self.tstore.durable else "ram"
 
+    @_in_phase("spill")
     def _resolve_cold_misses(self, bufs, flag_acc, n_new):
         """Sieve the flush's hot-filter survivors, resolve them
         against the cold runs in ``miss_batch``-wide batches, and
@@ -3272,6 +3375,7 @@ class DeviceChecker:
         )
         return n
 
+    @_in_phase("spill")
     def _ensure_hot_capacity(self, bufs, head: int) -> None:
         """The tiered replacement for unbounded visited growth: admit
         ``head`` more states in the hot table by growing WITHIN the
@@ -3328,6 +3432,7 @@ class DeviceChecker:
         rb["row_base"] = upto
         self._spill_active = True
 
+    @_in_phase("spill")
     def _tiered_ensure_windows(self, bufs, rb, level_base: int,
                                need_abs: int, nv: int) -> None:
         """Admit ``need_abs`` absolute states in the row/log windows:
@@ -3373,6 +3478,7 @@ class DeviceChecker:
             self._spill_active = True
         return self._spill_active
 
+    @_in_phase("spill")
     def _tiered_boundary(self, bufs, st, rb, level_base: int,
                          nf: int, nv: int, level: int) -> None:
         """Level-boundary spill housekeeping: tag the epoch, spill
@@ -3622,11 +3728,14 @@ class DeviceChecker:
                     # gets the same guarantee as every later level
                     # (the expand's +G read slack would otherwise
                     # clamp when a large seed nearly fills the window)
-                    bufs["rows"] = self._shift_jit()(
-                        bufs["rows"],
-                        jnp.int32(level_base - rb["row_base"]),
-                        jnp.int32(nf),
-                    )
+                    with self._clock.phase(
+                        "dispatch", level=len(level_sizes) + 1
+                    ):
+                        bufs["rows"] = self._shift_jit()(
+                            bufs["rows"],
+                            jnp.int32(level_base - rb["row_base"]),
+                            jnp.int32(nf),
+                        )
                     rb["row_base"] = level_base
                 if nf + self.G > self.LCAP:
                     # the frontier itself exceeds the rows window
@@ -3702,20 +3811,25 @@ class DeviceChecker:
                     # live rows this window expands (the fused kernel
                     # counts the identical clip in-kernel)
                     self._work_add(expand_rows=min(self.G, nf - f_off))
-                    out = self._stage_mark(
-                        "expand",
-                        self._expand_jit()(
-                            *bufs["ak"], bufs["arows"],
-                            self._slice_jit()(
-                                bufs["rows"],
-                                jnp.int32(
-                                    level_base - rb["row_base"] + f_off
+                    with self._clock.phase(
+                        "dispatch", level=len(level_sizes) + 1
+                    ):
+                        out = self._stage_mark(
+                            "expand",
+                            self._expand_jit()(
+                                *bufs["ak"], bufs["arows"],
+                                self._slice_jit()(
+                                    bufs["rows"],
+                                    jnp.int32(
+                                        level_base - rb["row_base"]
+                                        + f_off
+                                    ),
                                 ),
+                                jnp.int32(f_off), jnp.int32(nf),
+                                st["dead_gid"], jnp.int32(level_base),
+                                jnp.int32(w * self.NCs),
                             ),
-                            jnp.int32(f_off), jnp.int32(nf), st["dead_gid"],
-                            jnp.int32(level_base), jnp.int32(w * self.NCs),
-                        ),
-                    )
+                        )
                     bufs["ak"], bufs["arows"] = out[:K], out[K]
                     st["dead_gid"] = out[K + 1]
                     w += 1
@@ -3848,7 +3962,7 @@ class DeviceChecker:
             if level_count or stop:
                 level_sizes.append(max(level_count, 0))
                 self._emit_metrics(t0, len(level_sizes), level_count, nv, nf)
-                wall = time.time() - t0
+                wall = time.perf_counter() - t0
                 self._log(
                     f"level {len(level_sizes)}: +{level_count} "
                     f"(total {nv}, {nv/max(wall,1e-9):.0f} st/s)"
@@ -4014,20 +4128,25 @@ class DeviceChecker:
                     if self._last_fpm is not None
                     else 0
                 )
-                out = self._stage_mark(
-                    "fused",
-                    self._fused_jit()(
-                        *bufs["vk"], *bufs["ak"], bufs["arows"],
-                        bufs["rows"], bufs["parent"], bufs["lane"],
-                        st["n_visited"], st["dead_gid"], st["viol"],
-                        st["fpm"], st["wkm"], jnp.int32(level_base),
-                        jnp.int32(nf), jnp.int32(w_off),
-                        jnp.int32(lv_cap),
-                        jnp.int32(self._groups_cap()),
-                        jnp.int32(rb["row_base"]),
-                        jnp.bool_(rb["rows_ok"]),
-                    ),
-                )
+                # the call into the level kernel: a tier's first call
+                # traces, lowers and compiles (or loads) in here
+                with self._clock.phase(
+                    "dispatch", level=len(level_sizes) + 1
+                ):
+                    out = self._stage_mark(
+                        "fused",
+                        self._fused_jit()(
+                            *bufs["vk"], *bufs["ak"], bufs["arows"],
+                            bufs["rows"], bufs["parent"], bufs["lane"],
+                            st["n_visited"], st["dead_gid"],
+                            st["viol"], st["fpm"], st["wkm"],
+                            jnp.int32(level_base), jnp.int32(nf),
+                            jnp.int32(w_off), jnp.int32(lv_cap),
+                            jnp.int32(self._groups_cap()),
+                            jnp.int32(rb["row_base"]),
+                            jnp.bool_(rb["rows_ok"]),
+                        ),
+                    )
                 bufs["vk"] = out[:K]
                 bufs["ak"] = out[K: 2 * K]
                 (
@@ -4119,7 +4238,7 @@ class DeviceChecker:
                     self._emit_metrics(
                         t0, len(level_sizes), sz, cum, prev_nf
                     )
-                    wall = time.time() - t0
+                    wall = time.perf_counter() - t0
                     self._log(
                         f"level {len(level_sizes)}: +{sz} "
                         f"(total {cum}, {cum/max(wall,1e-9):.0f} st/s)"
@@ -4214,6 +4333,7 @@ class DeviceChecker:
     def _can_recover(self) -> bool:
         return self.rec.can_recover()
 
+    @_in_phase("ckpt")
     def _save_frame(
         self, bufs, st, rb, level_sizes, level_base, nf, nv, t0
     ) -> bool:
@@ -4308,7 +4428,7 @@ class DeviceChecker:
             arrays["spill_epoch"] = np.int64(self._epoch)
         nbytes, write_s, retries = ckpt.save_frame(
             self.checkpoint_path, self._config_sig(), arrays,
-            wall_s=time.time() - t0,
+            wall_s=time.perf_counter() - t0,
             meta={
                 "run_id": self._run_id,
                 "frame_seq": self._ckpt_frames + 1,
@@ -4532,7 +4652,7 @@ class DeviceChecker:
         # ``time_budget_s`` of fresh runway
         return (
             self.time_budget_s is not None
-            and time.time() - getattr(self, "_budget_t0", t0)
+            and time.perf_counter() - getattr(self, "_budget_t0", t0)
             > self.time_budget_s
         )
 
@@ -4568,7 +4688,9 @@ class DeviceChecker:
         consumers can separate them from level-boundary records — the
         fused-run validator holds only boundary records to the
         strictly-increasing / sizes-match-result contract."""
-        wall = time.time() - t0
+        wall = time.perf_counter() - t0
+        if not partial:
+            self._clock.level_boundary(level)
         self._snap.update(
             level=level, frontier=int(nf), distinct_states=int(nv),
             # the heartbeat marks its line when the newest record was
@@ -4585,7 +4707,7 @@ class DeviceChecker:
             frontier=int(nf),
             wall_s=round(wall, 3),
             states_per_sec=round(nv / max(wall, 1e-9), 1),
-            host_wait_s=round(getattr(self, "_host_wait_s", 0.0), 3),
+            host_wait_s=round(self._host_wait_s, 3),
         )
         if not self.metrics_path:
             return
@@ -4602,9 +4724,7 @@ class DeviceChecker:
                         # cumulative time the host spent blocked on stats
                         # fetches (everything else is device kernel time
                         # plus free async dispatch)
-                        "host_wait_s": round(
-                            getattr(self, "_host_wait_s", 0.0), 3
-                        ),
+                        "host_wait_s": round(self._host_wait_s, 3),
                         "states_per_sec": round(nv / max(wall, 1e-9), 1),
                         "visited_cap": self.VCAP,
                     }
@@ -4614,6 +4734,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------- trace
 
+    @_in_phase("trace_walk")
     def _trace(self, bufs, gid: int, max_depth: int):
         """Walk the parent chain on device (one fetch), replay lanes
         through the oracle on the host (SURVEY.md §2.2-E7).  Tiered
@@ -4694,6 +4815,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------ result
 
+    @_in_phase("result")
     def _result(
         self, t0, nv, level_sizes, bufs,
         viol: Optional[Tuple[str, int]] = None,
@@ -4701,8 +4823,8 @@ class DeviceChecker:
         truncated: bool = False,
         stop_reason: Optional[str] = None,
     ) -> CheckerResult:
-        self.last_bufs = bufs  # debugging/inspection hook
-        wall = time.time() - t0
+        self.last_bufs = bufs  # part of the result: the class docstring
+        wall = time.perf_counter() - t0
         if self.visited_impl == "fpset" and self._last_fpm is not None:
             # per-run fpset metrics for bench.py artifacts: flush count,
             # cumulative probe rounds (avg = rounds/flushes), failures
@@ -4784,7 +4906,6 @@ class DeviceChecker:
             ckpt_bytes=self._ckpt_bytes,
             ckpt_write_s=round(self._ckpt_write_s, 3),
             ckpt_retries=self._ckpt_retries,
-            host_wait_s=round(getattr(self, "_host_wait_s", 0.0), 3),
             stats_fetches=self._fetch_n,
         )
         res = CheckerResult(
@@ -4833,6 +4954,17 @@ class DeviceChecker:
         }
         if work:
             self.tel.emit("attribution", stages=work)
+        # host phases and the compile meter (obs/spans.py), taken here,
+        # at the emit, the last thing a run does: host_<phase>_s sum
+        # with host_unaccounted_s to the wall of run(); host_wait_s
+        # keeps its key and is the fetch phase; jit_* is an orthogonal
+        # cut (what JAX traced, lowered, compiled and loaded)
+        phases = self._clock.stats()
+        self.last_stats.update(
+            phases,
+            host_wait_s=phases["host_fetch_s"],
+            **spans.compile_meter().since(self._jit0),
+        )
         # the final stream record carries the whole last_stats dict
         # (stage counters/timings, rtt_s, fpset_*, ckpt_*) — the report
         # layer rebuilds the per-stage table and BENCH keys from it

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.ops.packing import Layout, SState
 from pulsar_tlaplus_tpu.ref import pyeval
 from pulsar_tlaplus_tpu.ref.pyeval import Constants
@@ -476,6 +477,7 @@ class CompactionModel:
             actions.append(pyeval.ACTION_NAMES[int(self.action_ids[lane])])
         return states, actions
 
+    @spans.spanned("host_seed")
     def host_seed(
         self, max_level_states: int = 30_000, max_total: int = 32_000
     ):

@@ -1,0 +1,279 @@
+"""Names for what the chip and the host are doing, from inside the engine.
+
+Three things, all riding what JAX already has — no flag, environment
+variable, exporter or telemetry event of their own:
+
+- **Stage scopes** (``stage`` / ``staged``): ``jax.named_scope`` under
+  the names the in-kernel work counters use — ``ptt.expand``,
+  ``ptt.probe``, ``ptt.compact``, ``ptt.append`` — plus
+  ``ptt.levelctl`` (the level kernel's loop control, boundary
+  bookkeeping, the packed stats vector and the frontier-window shift),
+  ``ptt.rehash`` (table growth), ``ptt.seed`` (seed merge/write) and
+  ``ptt.init`` (initial-state generation).  A scope is HLO metadata
+  only: it lands in every operation's ``op_name`` path, which a device
+  trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
+  metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of
+  its path.  (Metadata is not part of JAX's persistent-cache key, so
+  the jitted functions that carry scopes are named ``ptt_*``: a cache
+  written before they had scopes misses by module name instead of
+  handing back executables without them — docs/observability.md.)
+- **Host spans** (``span`` / ``spanned`` / ``PhaseClock``):
+  ``jax.profiler.TraceAnnotation`` named ``ptt:<name>``, so they lie
+  in the same ``.xplane.pb`` and on the same clock as the device's
+  operations; with no trace running one costs under a microsecond
+  (a phase of the clock about three).  A ``PhaseClock`` also adds each phase's seconds up,
+  exclusively (an inner phase pauses the outer), so the phases of one
+  ``run()`` sum to its wall.
+- **The compile meter** (``compile_meter``): one process-wide
+  ``jax.monitoring`` listener, registered on first use, that counts per
+  calling thread how often JAX traced, lowered, compiled or loaded from
+  the persistent cache, and the seconds each took.  An orthogonal cut
+  of the phases, not one of them: a first call of a jitted function
+  spends its trace/lower/compile seconds inside whichever phase made
+  the call (``dispatch``, mostly).
+
+This module is the only place of the package that constructs a
+``TraceAnnotation`` or registers a ``jax.monitoring`` listener.
+"""
+
+from __future__ import annotations
+
+import functools
+import threading
+import time
+from typing import Dict, Optional
+
+SPAN_PREFIX = "ptt:"
+SCOPE_PREFIX = "ptt."
+
+# the exclusive phases of one DeviceChecker.run(), in the order a run
+# meets them; each lands in last_stats as host_<phase>_s
+PHASES = (
+    "init", "seed_load", "grow", "dispatch", "fetch", "account", "ckpt",
+    "spill", "trace_walk", "result",
+)
+
+
+# ------------------------------------------------------- device: scopes
+
+def stage(name: str):
+    """``jax.named_scope("ptt.<name>")`` — HLO metadata only."""
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def _under(enter):
+    """Decorator: run the function inside the context ``enter()``."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with enter():
+                return fn(*args, **kwargs)
+
+        return wrapped
+
+    return deco
+
+
+def staged(name: str):
+    """Decorator: trace the function under the stage scope ``name``."""
+    return _under(lambda: stage(name))
+
+
+# ----------------------------------------------------------- host: spans
+
+def span(name: str, **fields):
+    """A host span ``ptt:<name>`` in the profiler's trace (``fields``
+    ride as the event's stats)."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(SPAN_PREFIX + name, **fields)
+
+
+def spanned(name: str):
+    """Decorator: run the function under the host span ``name``."""
+    return _under(lambda: span(name))
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "ann")
+
+    def __init__(self, clock, name, ann):
+        self.clock, self.name, self.ann = clock, name, ann
+
+    def __enter__(self):
+        self.clock._push(self.name)
+        self.ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self.ann.__exit__(*exc)
+        self.clock._pop()
+        return False
+
+
+class PhaseClock:
+    """Exclusive per-phase seconds of one run, on the monotonic clock.
+
+    ``with clock.phase("dispatch", level=n):`` enters the span
+    ``ptt:dispatch`` (carrying ``run_id`` and ``level``) and charges the
+    time to ``dispatch``; a phase entered inside it pauses it.  Used
+    from the run's own thread only."""
+
+    def __init__(self, run_id: Optional[str] = None):
+        self.run_id = run_id or ""
+        self.t0 = time.perf_counter()
+        self.seconds: Dict[str, float] = {}
+        self._stack = []  # [name, charged-up-to]
+        # the longest stretch between two level-boundary records (the
+        # first runs from the start of the run) and the level it ends on
+        self.level_wall_max_s = 0.0
+        self.level_wall_max_at = 0
+        self._boundary_t = self.t0
+
+    def phase(self, name: str, **fields) -> _Phase:
+        return _Phase(self, name, span(name, run_id=self.run_id, **fields))
+
+    def _push(self, name):
+        now = time.perf_counter()
+        if self._stack:
+            top = self._stack[-1]
+            self.seconds[top[0]] = self.seconds.get(top[0], 0.0) + now - top[1]
+        self._stack.append([name, now])
+
+    def _pop(self):
+        now = time.perf_counter()
+        name, since = self._stack.pop()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - since
+        if self._stack:
+            self._stack[-1][1] = now
+
+    def elapsed(self) -> float:
+        return time.perf_counter() - self.t0
+
+    def seconds_of(self, name: str) -> float:
+        """Seconds charged to ``name`` so far, an open phase included."""
+        s = self.seconds.get(name, 0.0)
+        if self._stack and self._stack[-1][0] == name:
+            s += time.perf_counter() - self._stack[-1][1]
+        return s
+
+    def level_boundary(self, level: int) -> None:
+        now = time.perf_counter()
+        gap, self._boundary_t = now - self._boundary_t, now
+        if gap > self.level_wall_max_s:
+            self.level_wall_max_s, self.level_wall_max_at = gap, int(level)
+
+    def stats(self) -> Dict[str, float]:
+        """``host_<phase>_s`` for every phase (0.0 for one never
+        entered), ``host_unaccounted_s`` = the run's wall so far less
+        their sum, and the longest level stretch."""
+        wall = self.elapsed()
+        out = {f"host_{p}_s": self.seconds_of(p) for p in PHASES}
+        out["host_unaccounted_s"] = wall - sum(out.values())
+        out["level_wall_max_s"] = self.level_wall_max_s
+        out["level_wall_max_at"] = self.level_wall_max_at
+        return out
+
+
+# ------------------------------------------------------ the compile meter
+
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+
+# duration event -> (its count, its seconds) among the counters below
+_DURATIONS = {
+    _TRACE: ("traces", "trace_s"),
+    _LOWER: ("lowerings", "lower_s"),
+    _COMPILE: ("compile_requests", "compile_request_s"),
+    _CACHE_LOAD: (None, "cache_load_s"),
+}
+
+_COUNTERS = (
+    "traces", "trace_s", "lowerings", "lower_s", "compile_requests",
+    "compile_request_s", "cache_hits", "cache_misses", "cache_load_s",
+)
+
+
+class CompileMeter:
+    """Counts and seconds of JAX's own compile-path events, per calling
+    thread (the daemon runs checks on threads; a listener is called on
+    the thread that compiles)."""
+
+    def __init__(self):
+        import jax
+
+        self._local = threading.local()
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _mine(self) -> Dict[str, float]:
+        c = getattr(self._local, "c", None)
+        if c is None:
+            c = self._local.c = dict.fromkeys(_COUNTERS, 0)
+        return c
+
+    def _duration(self, event, secs, **_kw):
+        keys = _DURATIONS.get(event)
+        if keys is None:
+            return
+        c = self._mine()
+        if keys[0]:
+            c[keys[0]] += 1
+        c[keys[1]] += secs
+
+    def _event(self, event, **_kw):
+        if event == _CACHE_HIT:
+            self._mine()["cache_hits"] += 1
+        elif event == _CACHE_MISS:
+            self._mine()["cache_misses"] += 1
+
+    def snapshot(self) -> Dict[str, float]:
+        """This thread's counters so far."""
+        return dict(self._mine())
+
+    def since(self, before: Dict[str, float]) -> Dict[str, float]:
+        """What this thread added since ``before`` (a ``snapshot``), as
+        the ``jit_*`` keys of ``last_stats``.  A compile request the
+        persistent cache answers is a cache load, not a backend
+        compile: JAX's ``backend_compile_duration`` spans both, so
+        hits and their retrieval seconds are taken out of it, and the
+        four durations add up to ``jit_host_s`` without overlap."""
+        now = self._mine()
+        d = {k: now[k] - before.get(k, 0) for k in _COUNTERS}
+        compile_s = max(d["compile_request_s"] - d["cache_load_s"], 0.0)
+        return {
+            "jit_traces": int(d["traces"]),
+            "jit_trace_s": d["trace_s"],
+            "jit_lower_s": d["lower_s"],
+            "jit_backend_compiles": int(
+                d["compile_requests"] - d["cache_hits"]
+            ),
+            "jit_compile_s": compile_s,
+            "jit_cache_hits": int(d["cache_hits"]),
+            "jit_cache_misses": int(d["cache_misses"]),
+            "jit_cache_load_s": d["cache_load_s"],
+            "jit_host_s": (
+                d["trace_s"] + d["lower_s"] + compile_s + d["cache_load_s"]
+            ),
+        }
+
+
+_meter: Optional[CompileMeter] = None
+_meter_lock = threading.Lock()
+
+
+def compile_meter() -> CompileMeter:
+    """The process's one meter, registered on first use."""
+    global _meter
+    if _meter is None:
+        with _meter_lock:
+            if _meter is None:
+                _meter = CompileMeter()
+    return _meter

@@ -1,0 +1,333 @@
+"""Spans, stage scopes and the compile meter (``obs/spans.py``, ISSUE 27).
+
+What is held here: the host phases of one ``run()`` are exclusive and
+sum to its wall; scopes and spans change no behaviour (the counts below
+were read off the parent commit); the lowered text of the level kernel
+and of every stage jit carries the four stage names; a profiler trace
+shows the phase spans nested in the run's span; the compile meter
+counts per run and per thread.
+"""
+
+import glob
+import importlib.util
+import json
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from pulsar_tlaplus_tpu.engine import device_bfs
+from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
+from pulsar_tlaplus_tpu.models.compaction import CompactionModel
+from pulsar_tlaplus_tpu.obs import spans
+from pulsar_tlaplus_tpu.ref import pyeval as pe
+from tests.helpers import SMALL_CONFIGS, assert_valid_counterexample
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+STAGES = ("ptt.expand", "ptt.probe", "ptt.compact", "ptt.append")
+
+
+def _mk(c, fuse="level", invariants=(), **kw):
+    return DeviceChecker(
+        CompactionModel(c), invariants=invariants, sub_batch=256,
+        fuse=fuse, visited_cap=1 << 12, frontier_cap=1 << 12, **kw,
+    )
+
+
+# ---- (f) the clock itself --------------------------------------------
+
+
+def test_phase_clock_nesting_is_exclusive():
+    clock = spans.PhaseClock("rid")
+    with clock.phase("account"):
+        time.sleep(0.02)
+        with clock.phase("dispatch", level=3):
+            time.sleep(0.03)
+            with clock.phase("grow"):
+                time.sleep(0.01)
+        time.sleep(0.02)
+    s = clock.seconds
+    assert 0.03 <= s["account"] < 0.06  # both halves, not the inside
+    assert 0.025 <= s["dispatch"] < 0.05
+    assert 0.008 <= s["grow"] < 0.03
+    st = clock.stats()
+    total = sum(
+        v for k, v in st.items()
+        if k.startswith("host_") and k.endswith("_s")
+    )
+    assert abs(total - clock.elapsed()) < 0.005
+    assert abs(st["host_unaccounted_s"]) < 0.005
+    assert st["host_ckpt_s"] == 0.0  # a phase never entered still reads
+
+
+def test_phase_clock_survives_an_exception_in_a_phase():
+    clock = spans.PhaseClock()
+    with pytest.raises(ValueError):
+        with clock.phase("account"):
+            with clock.phase("fetch"):
+                raise ValueError("in a phase")
+    assert clock._stack == []
+    assert set(clock.seconds) == {"account", "fetch"}
+    with clock.phase("result"):  # and goes on counting
+        assert clock.seconds_of("result") >= 0.0
+    assert "result" in clock.seconds
+
+
+def test_level_wall_max_names_the_level_the_stretch_ends_on():
+    clock = spans.PhaseClock()
+    clock.level_boundary(2)
+    time.sleep(0.03)
+    clock.level_boundary(3)
+    clock.level_boundary(4)
+    st = clock.stats()
+    assert st["level_wall_max_at"] == 3
+    assert 0.025 <= st["level_wall_max_s"] < 0.2
+
+
+# ---- (a) the keys, their sum, and the stream --------------------------
+
+
+def _checker_mod():
+    spec = importlib.util.spec_from_file_location(
+        "check_telemetry_schema",
+        os.path.join(ROOT, "scripts", "check_telemetry_schema.py"),
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+JIT_KEYS = (
+    "jit_traces", "jit_trace_s", "jit_lower_s", "jit_backend_compiles",
+    "jit_compile_s", "jit_cache_hits", "jit_cache_load_s", "jit_host_s",
+)
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_phases_sum_to_the_wall_and_ride_the_result_event(fuse, tmp_path):
+    stream = str(tmp_path / f"spans_{fuse}.jsonl")
+    ck = _mk(SMALL_CONFIGS["producer_on"], fuse=fuse, telemetry=stream)
+    t0 = time.perf_counter()
+    r = ck.run()
+    wall = time.perf_counter() - t0
+    st = ck.last_stats
+    phase_keys = [f"host_{p}_s" for p in spans.PHASES]
+    for k in (*phase_keys, "host_unaccounted_s", "level_wall_max_s",
+              "level_wall_max_at", *JIT_KEYS):
+        assert k in st, k
+    total = sum(st[k] for k in phase_keys)
+    assert abs(total - wall) <= 0.05 * wall
+    assert abs(total + st["host_unaccounted_s"] - r.wall_s) <= 0.01 * wall
+    assert st["host_wait_s"] == st["host_fetch_s"] > 0.0
+    assert st["host_dispatch_s"] > 0.0 and st["host_account_s"] > 0.0
+    assert 2 <= st["level_wall_max_at"] <= r.diameter
+    assert 0.0 < st["level_wall_max_s"] <= wall
+    assert st["jit_host_s"] == pytest.approx(
+        st["jit_trace_s"] + st["jit_lower_s"] + st["jit_compile_s"]
+        + st["jit_cache_load_s"]
+    )
+    with open(stream, encoding="utf-8") as f:
+        events = [json.loads(x) for x in f if x.strip()]
+    (res,) = [e for e in events if e["event"] == "result"]
+    for k in (*phase_keys, "host_unaccounted_s", "host_wait_s", *JIT_KEYS):
+        assert k in res["stats"], k
+    # the stream stays valid: ``stats`` is free-form, no schema bump
+    assert _checker_mod().validate_stream(stream) == []
+
+
+# ---- (b) no behaviour changes -----------------------------------------
+
+# read off the parent commit (81af5b3), same constructor arguments
+PARENT = {
+    "level": dict(fetches=3, dpl=0.31),
+    "stage": dict(fetches=18, dpl=4.19),
+}
+PARENT_LEVELS = [1, 5, 24, 56, 76, 108, 124, 128, 156, 156, 160, 192,
+                 212, 56, 88, 112]
+PARENT_WORK = {
+    "work_init_lanes": 1, "work_probe_lanes": 47872,
+    "work_compact_elems": 47872, "work_groups": 17,
+    "work_append_rows": 1654, "work_expand_rows": 1654,
+}
+PARENT_BUG = {
+    "level": dict(n=5832, levels=[729, 1458, 1458, 2187], fetches=4,
+                  dpl=1.5),
+    "stage": dict(n=5181, levels=[729, 1458, 1458, 1536], fetches=9,
+                  dpl=14.0),
+}
+PARENT_BUG_ACTIONS = [
+    "CompactorPhaseOne", "CompactorPhaseTwoWrite",
+    "CompactorPhaseTwoUpdateContext",
+]
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_counts_and_syncs_are_the_parents(fuse):
+    ck = _mk(SMALL_CONFIGS["producer_on"], fuse=fuse)
+    r = ck.run()
+    st = ck.last_stats
+    assert [int(x) for x in r.level_sizes] == PARENT_LEVELS
+    assert st["stats_fetches"] == PARENT[fuse]["fetches"]
+    assert st["dispatches_per_level"] == PARENT[fuse]["dpl"]
+    assert {k: v for k, v in st.items()
+            if k.startswith("work_")} == PARENT_WORK
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_counterexample_is_the_parents(fuse):
+    inv = "DuplicateNullKeyMessage"
+    ck = _mk(pe.SHIPPED_CFG, fuse=fuse, invariants=(inv,))
+    r = ck.run()
+    want = PARENT_BUG[fuse]
+    assert r.violation == inv and r.violation_gid == 3645
+    assert r.distinct_states == want["n"]
+    assert [int(x) for x in r.level_sizes] == want["levels"]
+    assert ck.last_stats["stats_fetches"] == want["fetches"]
+    assert ck.last_stats["dispatches_per_level"] == want["dpl"]
+    assert [str(a) for a in r.trace_actions] == PARENT_BUG_ACTIONS
+    assert_valid_counterexample(
+        pe.SHIPPED_CFG, r.trace, r.trace_actions, inv
+    )
+    assert ck.last_stats["host_trace_walk_s"] > 0.0
+
+
+# ---- (c) the scopes are in what is compiled ---------------------------
+
+
+def _lowered_texts(monkeypatch, fuse):
+    """``{jitted function name: lowered text}`` of every engine jit one
+    run calls, re-lowered at the shapes it was called with."""
+    real = jax.jit
+    seen = {}
+
+    def recording_jit(fn, **kw):
+        j = real(fn, **kw)
+
+        def call(*args):
+            seen.setdefault(fn.__name__, (j, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    jnp.shape(a), jnp.result_type(a)), args)))
+            return j(*args)
+
+        return call
+
+    monkeypatch.setattr(device_bfs.jax, "jit", recording_jit)
+    _mk(SMALL_CONFIGS["producer_on"], fuse=fuse).run()
+    monkeypatch.undo()
+    return {
+        name: j.lower(*shapes).as_text(debug_info=True)
+        for name, (j, shapes) in seen.items()
+    }
+
+
+def test_level_kernel_carries_the_four_stage_scopes(monkeypatch):
+    txt = _lowered_texts(monkeypatch, "level")["ptt_level"]
+    found = set(re.findall(r"ptt\.[a-z]+", txt))
+    assert set(STAGES) <= found and "ptt.levelctl" in found
+    # a stage nests inside the kernel's own scope: innermost wins
+    assert re.search(r"ptt\.levelctl/while/body/ptt\.probe", txt)
+
+
+def test_each_stage_jit_carries_its_scope(monkeypatch):
+    texts = _lowered_texts(monkeypatch, "stage")
+    for name, scope in (
+        ("ptt_slice", "ptt.expand"), ("ptt_expand", "ptt.expand"),
+        ("ptt_fpflush", "ptt.probe"), ("ptt_compact", "ptt.compact"),
+        ("ptt_append", "ptt.append"), ("ptt_stats", "ptt.levelctl"),
+        ("ptt_init", "ptt.init"), ("ptt_rehash", "ptt.rehash"),
+    ):
+        assert scope in texts[name], name
+
+
+# ---- (d) the spans are in a profiler trace ----------------------------
+
+
+def _host_events(trace_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(spans.SPAN_PREFIX):
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns,
+                                {k: v for k, v in e.stats}))
+    return out
+
+
+def test_profiler_trace_shows_phase_spans_inside_the_run_span(tmp_path):
+    ck = _mk(SMALL_CONFIGS["producer_on"])
+    ck.run()  # compiled, so the traced run is short
+    po = jax.profiler.ProfileOptions()
+    po.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=po)
+    try:
+        ck.run()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(str(tmp_path))
+    (run,) = [e for e in ev if e[0] == "ptt:run"]
+    rid = run[3]["run_id"]
+    assert rid == ck._clock.run_id and len(rid) == 12
+    for name in ("ptt:dispatch", "ptt:fetch"):
+        inside = [e for e in ev if e[0] == name]
+        assert inside, name
+        for _n, s, e, stats in inside:
+            assert run[1] <= s and e <= run[2]
+            assert stats["run_id"] == rid
+    assert any("level" in e[3] for e in ev if e[0] == "ptt:dispatch")
+    assert {"ptt:init", "ptt:account", "ptt:result", "ptt:grow"} <= {
+        e[0] for e in ev}
+
+
+# ---- (e) the compile meter --------------------------------------------
+
+
+def test_compile_meter_counts_per_run():
+    ck = _mk(SMALL_CONFIGS["producer_on"])
+    ck.run()
+    first = dict(ck.last_stats)
+    ck.run()
+    second = dict(ck.last_stats)
+    assert first["jit_traces"] > 0 and first["jit_trace_s"] > 0.0
+    assert first["jit_backend_compiles"] > 0
+    assert second["jit_traces"] < first["jit_traces"]
+    assert second["jit_host_s"] < first["jit_host_s"]
+    assert spans.compile_meter() is spans.compile_meter()
+
+
+def test_compile_meter_threads_do_not_read_each_other():
+    meter = spans.compile_meter()
+    got = {}
+
+    def compiles():
+        before = meter.snapshot()
+        jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
+        got["busy"] = meter.since(before)
+
+    def idles(started, done):
+        before = meter.snapshot()
+        started.set()
+        done.wait(60)
+        got["idle"] = meter.since(before)
+
+    started, done = threading.Event(), threading.Event()
+    idle = threading.Thread(target=idles, args=(started, done))
+    idle.start()
+    started.wait(60)
+    busy = threading.Thread(target=compiles)
+    busy.start()
+    busy.join()
+    done.set()
+    idle.join()
+    assert got["busy"]["jit_traces"] >= 1
+    assert got["busy"]["jit_backend_compiles"] >= 1
+    assert got["idle"]["jit_traces"] == 0
+    assert got["idle"]["jit_host_s"] == 0.0
