@@ -1781,10 +1781,13 @@ class DeviceChecker:
             kcols = keyspec.make(rows)
             lane = jnp.arange(NCs, dtype=jnp.int32)
             valid = lane < n_valid
-            is_new, tc2, n_failed, rounds = fpset.lookup_or_insert(
-                tc, kcols, valid,
-                dense_rounds=self.fps_dense, stages=self.fps_stages,
-                compact_impl=self.compact_impl,
+            is_new, tc2, n_failed, rounds, lane_rounds = (
+                fpset.lookup_or_insert(
+                    tc, kcols, valid,
+                    dense_rounds=self.fps_dense,
+                    stages=self.fps_stages,
+                    compact_impl=self.compact_impl,
+                )
             )
             if n_inv:
                 states = jax.vmap(layout.unpack)(rows)
@@ -1798,7 +1801,7 @@ class DeviceChecker:
                 viol = jnp.minimum(viol, jnp.stack(vnew))
             fpm = fpset.fpm_update(
                 fpm, rounds, n_failed,
-                jnp.sum(valid.astype(jnp.int32)),
+                jnp.sum(valid.astype(jnp.int32)), lane_rounds,
             )
             return (
                 *tc2,
@@ -4843,13 +4846,21 @@ class DeviceChecker:
                 # zero-sync device counters (r8): candidate lanes after
                 # validity masking (duplicate-rate denominator — 64-bit
                 # hi/lo reassembly since r12, honest past 2.1G lanes)
-                # and the worst single flush's probe depth
-                vl = int(fpset.fpm_logical(self._last_fpm)[3])
+                # and the worst single flush's probe depth; lanes
+                # presented to the table over all probe rounds (PR 28)
+                # against the valid ones says how closely the probe's
+                # width followed its pending count
+                fpml = fpset.fpm_logical(self._last_fpm)
+                vl, lr = int(fpml[3]), int(fpml[5])
                 self.last_stats.update(
                     fpset_valid_lanes=vl,
                     fpset_max_probe_rounds=int(self._last_fpm[4]),
                     fpset_duplicate_ratio=round(
                         max(1.0 - nv / vl, 0.0), 4
+                    ) if vl else None,
+                    fpset_lane_rounds=lr,
+                    fpset_lanes_presented_per_valid=round(
+                        lr / vl, 4
                     ) if vl else None,
                 )
         # fusion telemetry (r13): this run's total dispatches per BFS

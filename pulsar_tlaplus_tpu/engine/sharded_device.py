@@ -821,11 +821,13 @@ class ShardedDeviceChecker:
             amask = lanei < n_acc
             if self.visited_impl == "fpset":
                 valid = amask & ~fpset.all_sentinel(ak)
-                is_new, vk2, n_failed, rounds = fpset.lookup_or_insert(
-                    vk, ak, valid,
-                    dense_rounds=self.fps_dense,
-                    stages=self.fps_stages,
-                    compact_impl=self.compact_impl,
+                is_new, vk2, n_failed, rounds, lane_rounds = (
+                    fpset.lookup_or_insert(
+                        vk, ak, valid,
+                        dense_rounds=self.fps_dense,
+                        stages=self.fps_stages,
+                        compact_impl=self.compact_impl,
+                    )
                 )
                 n_new_owner = jnp.sum(is_new.astype(jnp.int32))
                 flag_own = is_new.astype(jnp.uint32)
@@ -836,7 +838,7 @@ class ShardedDeviceChecker:
                 # depth (running max, not a sum)
                 fpm = fpset.fpm_update(
                     fpm, rounds, n_failed,
-                    jnp.sum(valid.astype(jnp.int32)),
+                    jnp.sum(valid.astype(jnp.int32)), lane_rounds,
                 )
             else:
                 ccols = tuple(
@@ -2146,7 +2148,12 @@ class ShardedDeviceChecker:
         per = np.stack(
             [fpset.fpm_logical(row) for row in self._last_fpm]
         )
-        cur = np.concatenate([per[:, :4].sum(axis=0), [per[:, 4].max()]])
+        cur = np.concatenate(
+            [
+                per[:, :4].sum(axis=0),
+                [per[:, 4].max(), per[:, 5].sum()],
+            ]
+        )
         d = cur - self._fpm_prev
         if d[0] <= 0:
             return
@@ -2683,13 +2690,12 @@ class ShardedDeviceChecker:
                 # lanes after validity masking (duplicate-rate
                 # denominator; per-shard hi/lo reassembly since r12)
                 # and the worst single flush's probe depth anywhere on
-                # the mesh
-                vl = int(
-                    sum(
-                        fpset.fpm_logical(row)[3]
-                        for row in self._last_fpm
-                    )
+                # the mesh; lanes presented to the tables over all
+                # probe rounds (PR 28), against the valid ones
+                per = np.stack(
+                    [fpset.fpm_logical(row) for row in self._last_fpm]
                 )
+                vl, lr = int(per[:, 3].sum()), int(per[:, 5].sum())
                 self.last_stats.update(
                     fpset_valid_lanes=vl,
                     fpset_max_probe_rounds=int(
@@ -2697,6 +2703,10 @@ class ShardedDeviceChecker:
                     ),
                     fpset_duplicate_ratio=round(
                         max(1.0 - nv / vl, 0.0), 4
+                    ) if vl else None,
+                    fpset_lane_rounds=lr,
+                    fpset_lanes_presented_per_valid=round(
+                        lr / vl, 4
                     ) if vl else None,
                 )
         self.last_stats.update(

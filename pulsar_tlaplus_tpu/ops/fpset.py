@@ -18,20 +18,29 @@ probing design generalised to the device hot path:
   3+occupancy layout — the all-SENTINEL tuple is the empty marker
   (KeySpec reserves it), so no occupancy column and one fewer scatter
   per insert round.
-- **Staged pending compaction.**  The probe loop's dense per-round cost
-  is O(nq) random accesses whether one lane is pending or all are; the
-  expected MAX probe count over millions of lanes is ~log2(nq) /
-  log2(1/load) rounds, so a single monolithic loop pays ~20+ dense
+- **Staged pending compaction, width following the pending count.**
+  A probe round costs by the lane PRESENTED, parked or pending: 2K+1
+  gathers and K+1 scatters over the whole buffer (on a v5e a gather
+  costs about 13 ns for a pending lane and 31 ns for a parked one,
+  which reads the one trash row; my chip runs, PR 28).
+  The expected MAX probe count over millions of lanes is ~log2(nq) /
+  log2(1/load) rounds, so a single monolithic loop pays ~10-20 dense
   rounds for a tail that involves a few thousand lanes (this is what
   kept the table off the hot path in rounds 3-5).  ``lookup_or_insert``
-  runs a few dense rounds, then compacts the surviving pending lanes
-  (one single-key sort, the `compact_by_flag` idiom) into a 4x-smaller
-  buffer, probes on, compacts again into a 16x-smaller buffer — the
-  tail rounds cost 1/4 and 1/16 of a dense round.  At load <= 1/2 the
-  expected pending fraction after r rounds is ~2^-r, so the static
-  stage capacities carry 2-8x safety margins; a lane that overflows a
-  stage is counted in ``n_failed`` and the engine fails LOUDLY (the
-  same fail-stop contract as `ops/hashtable.py`), never a silent drop.
+  therefore walks a ladder of narrowing buffers — all ``nq`` lanes,
+  then 1/div of them per stage — compacting the surviving pending
+  lanes (the `compact_by_flag` idiom) at each step, and a step ends
+  as soon as what is pending fits the next one (PR 28).  The round
+  counts of the schedule are CEILINGS: ``dense_rounds`` and each
+  stage's limit bound how long a step may wait for its survivors to
+  fit.  At load <= 1/2 the expected pending fraction after r rounds
+  is ~2^-r, so at a ceiling the static stage capacities carry 2-8x
+  safety margins; a lane that overflows a stage there is counted in
+  ``n_failed`` and the engine fails LOUDLY (the same fail-stop
+  contract as `ops/hashtable.py`), never a silent drop.  Every
+  pending lane probes slot (h + r(r+1)/2) at the same global round r
+  with its original lane id whichever buffer holds it, so winners,
+  table and round count are those of the single loop, bit for bit.
 - **Deterministic discovery order.**  Equal-key lanes resolve to the
   minimum lane id (scatter-min bidding; compaction is order-preserving
   and stages bid with original lane ids), which is exactly the
@@ -63,65 +72,88 @@ from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, _fmix
 # Width of the zero-sync device metrics vector engines accumulate next
 # to the table and ride on their ONE hot-path stats fetch: [flushes,
 # probe_rounds, failures, valid_lanes_lo, max_probe_rounds,
-# valid_lanes_hi].  valid_lanes is the candidate count after validity
-# masking (the duplicate-rate denominator the host cannot know without
-# a sync); it is the one counter that genuinely outgrows int32 — a
-# 1B-state run examines far more than 2.1G candidate lanes — so it is
-# carried as hi/lo uint32 WORDS (r12; lo at the historical index 3,
-# the hi carry word appended at index 5 so every older index keeps its
-# meaning and pre-widening checkpoint frames restore zero-padded, the
-# same pattern as the r8/r9 widenings).  :func:`fpm_update` owns the
-# device-side carry arithmetic and :func:`fpm_logical` the host-side
-# 64-bit reassembly.  max_probe_rounds is the worst flush's probe
-# depth (a running max, not a sum) — with avg probes the
-# probe-schedule tuning signal for DENSE_ROUNDS/STAGES below.  Shared
-# by device_bfs and sharded_device.
-FPM_N = 6
+# valid_lanes_hi, lane_rounds_lo, lane_rounds_hi].  valid_lanes is the
+# candidate count after validity masking (the duplicate-rate
+# denominator the host cannot know without a sync); it genuinely
+# outgrows int32 — a 1B-state run examines far more than 2.1G
+# candidate lanes — so it is carried as hi/lo uint32 WORDS (r12; lo at
+# the historical index 3, the hi carry word appended at index 5 so
+# every older index keeps its meaning and pre-widening checkpoint
+# frames restore zero-padded, the same pattern as the r8/r9
+# widenings).  lane_rounds (PR 28, appended at 6/7 the same way) is
+# the lanes PRESENTED to the table summed over probe rounds — each
+# stage's width times the rounds run at it, the quantity a round's
+# gathers and scatters cost by; over valid_lanes it says how far the
+# presented width follows the pending count.  :func:`fpm_update` owns
+# the device-side carry arithmetic and :func:`fpm_logical` the
+# host-side 64-bit reassembly.  max_probe_rounds is the worst flush's
+# probe depth (a running max, not a sum).  Shared by device_bfs and
+# sharded_device.
+FPM_N = 8
 
 # length of the host-side LOGICAL view: [flushes, probe_rounds,
-# failures, valid_lanes (64-bit), max_probe_rounds]
-FPM_LOGICAL_N = 5
+# failures, valid_lanes (64-bit), max_probe_rounds, lane_rounds
+# (64-bit)]
+FPM_LOGICAL_N = 6
 
 
-def fpm_update(fpm, rounds, n_failed, n_valid):
+def _add_u32(lo_word, hi_word, n):
+    """``n`` (non-negative, < 2^32) added to a hi/lo uint32 counter
+    held as int32 bit patterns (bitcast, never a value conversion)."""
+    lo = lax.bitcast_convert_type(lo_word, jnp.uint32)
+    new_lo = lo + n.astype(jnp.uint32)
+    carry = (new_lo < lo).astype(jnp.int32)
+    return lax.bitcast_convert_type(new_lo, jnp.int32), hi_word + carry
+
+
+def _u64(lo_word, hi_word):
+    """Host-side reassembly of a hi/lo uint32 counter (numpy int64
+    scalars holding the fetched int32 bit patterns)."""
+    import numpy as np
+
+    return (hi_word << 32) | np.int64(np.uint32(lo_word & 0xFFFFFFFF))
+
+
+def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds):
     """One flush's device-side metrics update (jit-traceable).
 
     ``fpm`` is the int32[FPM_N] vector; ``n_valid`` (int32, < 2^31 per
-    flush) accumulates into the valid-lane LO word with uint32 wraparound
-    and the carry lands in the HI word — int32 storage holds the uint32
-    bit patterns (bitcast, never a value conversion), so 1B-state runs
-    report honest duplicate ratios instead of a wrapped counter."""
-    lo = lax.bitcast_convert_type(fpm[3], jnp.uint32)
-    new_lo = lo + n_valid.astype(jnp.uint32)
-    carry = (new_lo < lo).astype(jnp.int32)
+    flush) and ``lane_rounds`` (uint32) accumulate into their LO words
+    with uint32 wraparound and the carry lands in the HI words, so
+    1B-state runs report honest duplicate ratios instead of a wrapped
+    counter."""
+    valid_lo, valid_hi = _add_u32(fpm[3], fpm[5], n_valid)
+    lanes_lo, lanes_hi = _add_u32(fpm[6], fpm[7], lane_rounds)
     return jnp.stack(
         [
             fpm[0] + 1,
             fpm[1] + rounds,
             fpm[2] + n_failed,
-            lax.bitcast_convert_type(new_lo, jnp.int32),
+            valid_lo,
             jnp.maximum(fpm[4], rounds),
-            fpm[5] + carry,
+            valid_hi,
+            lanes_lo,
+            lanes_hi,
         ]
     )
 
 
 def fpm_logical(vec):
     """int64[FPM_LOGICAL_N] logical view of a fetched fpm vector:
-    [flushes, probe_rounds, failures, valid_lanes, max_probe_rounds]
-    with the hi/lo valid-lane words reassembled into one 64-bit count.
-    Accepts the historical widths too (3-wide pre-r8, 5-wide r9-r11
-    frames restore zero-padded): a missing hi word reads as 0 and a
-    5-wide vector's index-3 int32 reinterprets as the lo uint32 word —
-    identical for every pre-wrap value."""
+    [flushes, probe_rounds, failures, valid_lanes, max_probe_rounds,
+    lane_rounds] with the hi/lo words reassembled into 64-bit counts.
+    Accepts the historical widths too (3-wide pre-r8, 5-wide r9-r11 and
+    6-wide r12 frames restore zero-padded): a missing word reads as 0
+    and a 5-wide vector's index-3 int32 reinterprets as the lo uint32
+    word — identical for every pre-wrap value."""
     import numpy as np
 
     a = np.asarray(vec, np.int64).reshape(-1)
     v = np.zeros((FPM_N,), np.int64)
     v[: min(len(a), FPM_N)] = a[:FPM_N]
-    lo = np.int64(np.uint32(v[3] & 0xFFFFFFFF))
     return np.array(
-        [v[0], v[1], v[2], (v[5] << 32) | lo, v[4]], np.int64
+        [v[0], v[1], v[2], _u64(v[3], v[5]), v[4], _u64(v[6], v[7])],
+        np.int64,
     )
 
 # Width of the zero-sync WORK-UNIT vector (r14, fused-era cost
@@ -167,21 +199,17 @@ def wkm_update(wkm, rows, lanes, elems, appended, groups):
     ``elems`` accumulate into uint32 lo words with the carry landing in
     the hi words (bitcast storage, the :func:`fpm_update` pattern) so
     1B-state runs report honest work totals instead of wrapped ones."""
-    lo_l = lax.bitcast_convert_type(wkm[1], jnp.uint32)
-    new_l = lo_l + lanes.astype(jnp.uint32)
-    carry_l = (new_l < lo_l).astype(jnp.int32)
-    lo_e = lax.bitcast_convert_type(wkm[2], jnp.uint32)
-    new_e = lo_e + elems.astype(jnp.uint32)
-    carry_e = (new_e < lo_e).astype(jnp.int32)
+    lanes_lo, lanes_hi = _add_u32(wkm[1], wkm[5], lanes)
+    elems_lo, elems_hi = _add_u32(wkm[2], wkm[6], elems)
     return jnp.stack(
         [
             wkm[0] + rows,
-            lax.bitcast_convert_type(new_l, jnp.int32),
-            lax.bitcast_convert_type(new_e, jnp.int32),
+            lanes_lo,
+            elems_lo,
             wkm[3] + appended,
             wkm[4] + groups,
-            wkm[5] + carry_l,
-            wkm[6] + carry_e,
+            lanes_hi,
+            elems_hi,
         ]
     )
 
@@ -195,29 +223,40 @@ def wkm_logical(vec):
     a = np.asarray(vec, np.int64).reshape(-1)
     v = np.zeros((WKM_N,), np.int64)
     v[: min(len(a), WKM_N)] = a[:WKM_N]
-    lanes = (v[5] << 32) | np.int64(np.uint32(v[1] & 0xFFFFFFFF))
-    elems = (v[6] << 32) | np.int64(np.uint32(v[2] & 0xFFFFFFFF))
-    return np.array([v[0], lanes, elems, v[3], v[4]], np.int64)
+    return np.array(
+        [v[0], _u64(v[1], v[5]), _u64(v[2], v[6]), v[3], v[4]], np.int64
+    )
 
 
 MAX_PROBES = 64
-# staged-compaction schedule for the engine hot path: a few dense
-# rounds, then (shrink divisor, probe-round limit) per stage.  At load
-# <= 1/2 the expected pending fraction entering stage i is ~2^-rounds,
-# well under 1/divisor (see module docstring).  These are first-guess
-# constants — the real-chip tuning signal is the zero-sync
-# ``fpset_max_probe_rounds``/``fpset_avg_probe_rounds`` counters
-# (docs/observability.md), and the schedule is sweepable without code
-# edits: engine/FPSet ctor params, or the ``PTT_FPSET_SCHEDULE`` env
-# override parsed by :func:`resolve_schedule` (round 10).
+# staged-compaction schedule for the engine hot path: the CEILING on
+# full-width rounds, then (shrink divisor, probe-round ceiling) per
+# stage.  A stage hands over as soon as what is pending fits the next
+# one (PR 28), so the ceilings only matter where that never happens:
+# at load <= 1/2 the expected pending fraction at a ceiling is
+# ~2^-rounds, well under 1/divisor (see module docstring).  The
+# real-chip signal is the zero-sync ``fpset_lane_rounds`` counter over
+# ``fpset_valid_lanes`` (lanes presented per valid lane; with
+# ``fpset_max_probe_rounds``, docs/observability.md), and the schedule
+# is sweepable without code edits: engine/FPSet ctor params, or the
+# ``PTT_FPSET_SCHEDULE`` env override parsed by
+# :func:`resolve_schedule` (round 10).  The last stage is 1/64 wide
+# (1/16 before PR 28): with hand-over on the pending count the tail's
+# ~8 rounds run there as soon as under 1.6% of the lanes are pending,
+# which on the flagship level is after round 1 (7.47 s against 8.47 s
+# a level; my chip run, PR 28).  A third stage reads another second
+# better there and is not added: every stage is one more traced loop
+# and compaction in each flush program a ``cli check`` re-traces
+# (+0.5-0.65 s a check, PERF.md §6 "PR 28").
 DENSE_ROUNDS = 4
-STAGES = ((4, 16), (16, MAX_PROBES))
+STAGES = ((4, 16), (64, MAX_PROBES))
 
 
 def parse_schedule(spec: str) -> Tuple[int, Tuple[Tuple[int, int], ...]]:
     """Parse a probe-schedule spec ``"DENSE[,DIV:LIMIT]*"`` — e.g. the
-    default is ``"4,4:16,16:64"`` (4 dense rounds, then a 1/4-width
-    stage probing to round 16 and a 1/16-width stage to round 64).
+    default is ``"4,4:16,64:64"`` (at most 4 dense rounds, then a
+    1/4-width stage probing to round 16 at the latest and a 1/64-width
+    stage to round 64).
     Raises ValueError with the offending token on malformed input."""
     parts = [p.strip() for p in spec.split(",") if p.strip()]
     if not parts:
@@ -345,6 +384,7 @@ def probe_insert(
     max_probes: int = MAX_PROBES,
     start_round: int | jax.Array = 0,
     lane_ids: Optional[jax.Array] = None,
+    handover: int = 0,
 ):
     """One batched triangular-probing lookup-or-insert loop.
 
@@ -360,10 +400,13 @@ def probe_insert(
     ``lane_ids`` let the staged wrapper resume the probe sequence on a
     compacted buffer while bidding with ORIGINAL lane ids (preserving
     min-lane-wins — the sort-merge flush's discovery order).
+    ``handover`` ends the loop as soon as no more than that many lanes
+    are pending (the staged wrapper passes the next, narrower stage's
+    capacity; the default 0 probes until every lane resolved).
 
     Returns ``(is_new, tcols', occ', pending, rounds)``; ``pending``
-    lanes are unresolved after ``max_probes`` rounds (callers count
-    them as hard failures, never silent drops).
+    lanes are unresolved after ``max_probes`` rounds or handed over
+    (callers count the former as hard failures, never silent drops).
     """
     cap = tcols[0].shape[0] - 1
     nq = kcols[0].shape[0]
@@ -379,12 +422,15 @@ def probe_insert(
             return oc[s] == 1
         return ~all_sentinel(sv)
 
+    def n_set(flags):
+        return jnp.sum(flags.astype(jnp.int32))
+
     def cond(st):
-        r, pending = st[0], st[1]
-        return (r < max_probes) & jnp.any(pending)
+        r, npend = st[0], st[1]
+        return (r < max_probes) & (npend > handover)
 
     def body(st):
-        r, pending, is_new, tc, oc = st
+        r, _, pending, is_new, tc, oc = st
         ru = r.astype(jnp.uint32)
         off = (ru * (ru + jnp.uint32(1))) >> 1
         slot = ((h + off) & capm).astype(jnp.int32)
@@ -416,16 +462,17 @@ def probe_insert(
             eq2 = eq2 & (cv == ck)
         occ2 = occupied_at(tc, oc, s, sv2)
         pending = pending & ~(occ2 & eq2)
-        return (r + 1, pending, is_new, tc, oc)
+        return (r + 1, n_set(pending), pending, is_new, tc, oc)
 
     st = (
         jnp.asarray(start_round, jnp.int32),
+        n_set(valid),
         valid,
         jnp.zeros((nq,), jnp.bool_),
         tuple(tcols),
         occ0,
     )
-    r, pending, is_new, tcols, occ_out = lax.while_loop(cond, body, st)
+    r, _, pending, is_new, tcols, occ_out = lax.while_loop(cond, body, st)
     return is_new, tcols, (occ_out if has_occ else None), pending, r
 
 
@@ -441,57 +488,71 @@ def lookup_or_insert(
     """Engine hot path: staged batched lookup-or-insert (see module
     docstring for the why of the stages).
 
-    Returns ``(is_new, tcols', n_failed, rounds)`` where ``is_new`` is
-    in ORIGINAL lane order (exactly one True per distinct new key — the
-    minimum valid lane), ``n_failed`` counts lanes dropped at a stage
-    overflow or still pending at ``max_probes`` (callers treat nonzero
-    as a hard error), and ``rounds`` is the probe rounds consumed (the
-    per-flush probe metric).
+    Returns ``(is_new, tcols', n_failed, rounds, lane_rounds)`` where
+    ``is_new`` is in ORIGINAL lane order (exactly one True per distinct
+    new key — the minimum valid lane), ``n_failed`` counts lanes
+    dropped at a stage overflow or still pending at ``max_probes``
+    (callers treat nonzero as a hard error), ``rounds`` is the probe
+    rounds consumed (the per-flush probe metric) and ``lane_rounds``
+    (uint32) the lanes presented to the table summed over those rounds:
+    each stage's width times the rounds run at it.
     """
     nq = kcols[0].shape[0]
     K = len(kcols)
     dense_rounds, stages = resolve_schedule(dense_rounds, stages)
-    is_new, tcols, _, pending, r = probe_insert(
-        tcols, kcols, valid, max_probes=min(dense_rounds, max_probes)
-    )
-    n_failed = jnp.int32(0)
-    cur_keys, cur_ids, cur_pending = kcols, None, pending
+    # the ladder as static (width, round ceiling) steps; a stage with
+    # no shrink to be had (tiny batches) only raises the ceiling of the
+    # step before it: it probes on in place
+    ladder = [(nq, min(dense_rounds, max_probes))]
     for div, limit in stages:
         limit = min(limit, max_probes)
         capi = max(nq // div, min(nq, MIN_STAGE))
-        if capi >= nq or limit <= dense_rounds:
-            # no shrink to be had (tiny batches): just probe on in place
-            is_new2, tcols, _, cur_pending, r = probe_insert(
-                tcols, cur_keys, cur_pending, max_probes=limit,
-                start_round=r, lane_ids=cur_ids,
+        width, ceiling = ladder[-1]
+        if capi >= width or limit <= dense_rounds:
+            ladder[-1] = (width, max(ceiling, limit))
+        else:
+            ladder.append((capi, limit))
+    is_new = jnp.zeros((nq,), jnp.bool_)
+    n_failed = jnp.int32(0)
+    lane_rounds = jnp.uint32(0)
+    r = jnp.int32(0)
+    cur_keys, cur_ids, cur_pending, width = kcols, None, valid, nq
+    for i, (capi, limit) in enumerate(ladder):
+        if capi < width:
+            # order-preserving compaction of the pending lanes (+ their
+            # original lane ids) into the narrower stage buffer —
+            # log-shift by default (round 10), sort behind compact_impl
+            ids = (
+                cur_ids
+                if cur_ids is not None
+                else jnp.arange(nq, dtype=jnp.int32)
             )
-            is_new = _merge_new(is_new, is_new2, cur_ids, nq)
-            continue
-        # order-preserving compaction of the pending lanes (+ their
-        # original lane ids) into the 1/div-size stage buffer —
-        # log-shift by default (round 10), sort behind compact_impl
-        ids = (
-            cur_ids
-            if cur_ids is not None
-            else jnp.arange(nq, dtype=jnp.int32)
-        )
-        drop = (~cur_pending).astype(jnp.uint32)
-        ccols, _ = compact_ops.compact_by_flag(
-            drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
-            impl=compact_impl, need_idx=False,
-        )
-        npend = jnp.sum(cur_pending.astype(jnp.int32))
-        n_failed = n_failed + jnp.maximum(npend - capi, 0)
-        cur_keys = tuple(c[:capi] for c in ccols[:K])
-        cur_ids = ccols[K][:capi].astype(jnp.int32)
-        cur_pending = jnp.arange(capi, dtype=jnp.int32) < npend
-        is_new2, tcols, _, cur_pending, r = probe_insert(
+            drop = (~cur_pending).astype(jnp.uint32)
+            ccols, _ = compact_ops.compact_by_flag(
+                drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
+                impl=compact_impl, need_idx=False,
+            )
+            npend = jnp.sum(cur_pending.astype(jnp.int32))
+            n_failed = n_failed + jnp.maximum(npend - capi, 0)
+            cur_keys = tuple(c[:capi] for c in ccols[:K])
+            cur_ids = ccols[K][:capi].astype(jnp.int32)
+            cur_pending = jnp.arange(capi, dtype=jnp.int32) < npend
+            width = capi
+        # a step ends as soon as what is pending fits the next one: a
+        # round costs by the lane presented, parked or not, so the
+        # ``limit`` is a ceiling and the pending count sets the width
+        fits = ladder[i + 1][0] if i + 1 < len(ladder) else 0
+        stage_new, tcols, _, cur_pending, r2 = probe_insert(
             tcols, cur_keys, cur_pending, max_probes=limit,
-            start_round=r, lane_ids=cur_ids,
+            start_round=r, lane_ids=cur_ids, handover=fits,
         )
-        is_new = _merge_new(is_new, is_new2, cur_ids, nq)
+        is_new = _merge_new(is_new, stage_new, cur_ids, nq)
+        lane_rounds = lane_rounds + jnp.uint32(width) * (
+            r2 - r
+        ).astype(jnp.uint32)
+        r = r2
     n_failed = n_failed + jnp.sum(cur_pending.astype(jnp.int32))
-    return is_new, tcols, n_failed, r
+    return is_new, tcols, n_failed, r, lane_rounds
 
 
 def _merge_new(is_new, stage_new, stage_ids, nq):
@@ -544,14 +605,15 @@ def flush_acc(
     lanei = jnp.arange(nq, dtype=jnp.int32)
     amask = lanei < n_acc
     valid = amask & ~all_sentinel(kcols)
-    is_new, tcols2, n_failed, rounds = lookup_or_insert(
+    is_new, tcols2, n_failed, rounds, lane_rounds = lookup_or_insert(
         tcols, kcols, valid,
         dense_rounds=dense_rounds, stages=stages,
         compact_impl=compact_impl,
     )
     n_new = jnp.sum(is_new.astype(jnp.int32))
     fpm2 = fpm_update(
-        fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32))
+        fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32)),
+        lane_rounds,
     )
     return tcols2, n_new, is_new.astype(jnp.uint32), fpm2
 
@@ -661,7 +723,10 @@ class FPSet:
             dense_rounds, stages
         )
         self.compact_impl = compact_ops.validate_impl(compact_impl)
-        self.stats = {"inserts": 0, "probe_rounds": 0, "failures": 0}
+        self.stats = {
+            "inserts": 0, "probe_rounds": 0, "lane_rounds": 0,
+            "failures": 0,
+        }
         # optional JSONL stream (obs.telemetry): one ``fpset_insert``
         # record per batched insert — host-loop users get the same
         # per-flush visibility the device engines emit
@@ -700,10 +765,12 @@ class FPSet:
         if valid is None:
             valid = jnp.ones((nq,), jnp.bool_)
         self.reserve(self.n + nq)
-        is_new, self.cols, n_failed, rounds = lookup_or_insert(
-            self.cols, kcols, valid,
-            dense_rounds=self.dense_rounds, stages=self.stages,
-            compact_impl=self.compact_impl,
+        is_new, self.cols, n_failed, rounds, lane_rounds = (
+            lookup_or_insert(
+                self.cols, kcols, valid,
+                dense_rounds=self.dense_rounds, stages=self.stages,
+                compact_impl=self.compact_impl,
+            )
         )
         nf = int(n_failed)
         from pulsar_tlaplus_tpu.utils import faults
@@ -718,6 +785,7 @@ class FPSet:
         self.n += int(jnp.sum(is_new.astype(jnp.int32)))
         self.stats["inserts"] += 1
         self.stats["probe_rounds"] += int(rounds)
+        self.stats["lane_rounds"] += int(lane_rounds)
         self.stats["failures"] += nf
         self.tel.emit(
             "fpset_insert",

@@ -324,8 +324,14 @@ def flush_acc_tiles(
         ),
     )
     n_new = jnp.sum(is_new.astype(jnp.int32))
+    # lanes presented: the membership block reads all nq lanes for its
+    # rounds, every chunk round after it presents one chunk's width
+    lane_rounds = jnp.uint32(nq * rounds_blk) + jnp.uint32(cw) * (
+        rounds - rounds_blk
+    ).astype(jnp.uint32)
     fpm2 = fpset.fpm_update(
-        fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32))
+        fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32)),
+        lane_rounds,
     )
     return tcols2, n_new, is_new.astype(jnp.uint32), fpm2
 

@@ -82,7 +82,7 @@ _NOMINAL_SPILL_RATIO = 0.4
 # default probe schedule constants mirrored from ops/fpset.py (not
 # imported: predict must stay importable without jax)
 _DENSE_DEFAULT = 4
-_STAGES_DEFAULT = ((4, 16), (16, 64))
+_STAGES_DEFAULT = ((4, 16), (64, 64))
 
 # dense-tile kernel lane-cost MULTIPLIERS vs legacy (round 23,
 # ops/tiles.py) when no calibration measured the per-impl unit

@@ -90,7 +90,7 @@ def test_failure_count_on_overload():
     rng = np.random.default_rng(5)
     keys = rng.integers(0, 2**31, size=(4 * cap, 2), dtype=np.uint32)
     cols = fpset.empty_cols(cap, 2)
-    is_new, cols, n_failed, _rounds = fpset.lookup_or_insert(
+    is_new, cols, n_failed, _rounds, _lanes = fpset.lookup_or_insert(
         cols, (keys[:, 0], keys[:, 1]),
         jnp.ones((len(keys),), jnp.bool_),
     )
@@ -117,7 +117,7 @@ def test_staged_compaction_matches_single_loop():
     keys = pool[rng.integers(0, len(pool), size=4096)]
     kcols = (jnp.asarray(keys[:, 0]), jnp.asarray(keys[:, 1]))
     valid = jnp.ones((len(keys),), jnp.bool_)
-    staged_new, staged_cols, nf, _ = fpset.lookup_or_insert(
+    staged_new, staged_cols, nf, _, _ = fpset.lookup_or_insert(
         fpset.empty_cols(cap, 2), kcols, valid
     )
     simple_new, simple_cols, _, pending, _ = fpset.probe_insert(
@@ -127,6 +127,220 @@ def test_staged_compaction_matches_single_loop():
     assert np.array_equal(np.asarray(staged_new), np.asarray(simple_new))
     for a, b in zip(staged_cols, simple_cols):
         assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
+
+
+# ---- the pending-driven schedule (PR 28) -----------------------------
+#
+# A stage of ``lookup_or_insert`` ends as soon as what is pending fits
+# the next, narrower one.  The single loop below is the reference for
+# every decision; the replay gives the pending count before each round,
+# from which both schedules' presented lanes follow by arithmetic.
+
+
+def _batch(seed, nq, cap, load, dup, K):
+    """A table pre-filled so that it ends near ``load`` once the batch
+    is in, and ``nq`` lanes of which a ``dup`` share repeat an earlier
+    lane or a key the table already holds; ~5% of the lanes invalid."""
+    rng = np.random.default_rng(seed)
+    n_fresh = max(int(nq * (1.0 - dup)), 1)
+    n_pre = max(int(load * cap) - n_fresh, 0)
+    pool = np.unique(
+        rng.integers(0, 2**32 - 2, size=(n_fresh + n_pre + 64, K),
+                     dtype=np.uint32),
+        axis=0,
+    )
+    rng.shuffle(pool)
+    pre, fresh = pool[:n_pre], pool[n_pre:n_pre + n_fresh]
+    known = pool[:n_pre + n_fresh]
+    keys = np.concatenate(
+        [fresh, known[rng.integers(0, len(known), size=nq - len(fresh))]]
+    )[:nq]
+    rng.shuffle(keys)
+    tcols = fpset.empty_cols(cap, K)
+    if n_pre:
+        _, tcols, _, pending, _ = fpset.probe_insert(
+            tcols, tuple(jnp.asarray(pre[:, i]) for i in range(K)),
+            jnp.ones((n_pre,), jnp.bool_),
+        )
+        assert not bool(np.asarray(pending).any())
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(K))
+    valid = jnp.asarray(rng.random(nq) < 0.95)
+    return tcols, kcols, valid
+
+
+def _ladder(nq, dense, stages):
+    """[(width, ceiling)] as the module builds it: written out again so
+    the arithmetic below does not lean on the code under test."""
+    steps = [(nq, min(dense, fpset.MAX_PROBES))]
+    for div, limit in stages:
+        limit = min(limit, fpset.MAX_PROBES)
+        capi = max(nq // div, min(nq, fpset.MIN_STAGE))
+        if capi >= steps[-1][0] or limit <= dense:
+            steps[-1] = (steps[-1][0], max(steps[-1][1], limit))
+        else:
+            steps.append((capi, limit))
+    return steps
+
+
+def _pending_by_round(tcols, kcols, valid, ceiling):
+    """Lanes pending BEFORE round r of the single loop, r = 0.. until
+    nothing is pending or the ceiling is reached."""
+    counts, r, pending = [], 0, valid
+    while True:
+        counts.append(int(np.asarray(pending).sum()))
+        if counts[-1] == 0 or r >= ceiling:
+            return counts
+        _, tcols, _, pending, r2 = fpset.probe_insert(
+            tcols, kcols, pending, max_probes=r + 1, start_round=r
+        )
+        assert int(r2) == r + 1
+        r += 1
+
+
+def _lane_rounds(ladder, pending, follow):
+    """Presented lanes of a schedule over the replayed pending counts:
+    ``follow`` hands a step over once what is pending fits the next
+    (this PR); without it each step runs to its ceiling (the parent)."""
+    total = r = 0
+    for i, (width, ceiling) in enumerate(ladder):
+        fits = ladder[i + 1][0] if follow and i + 1 < len(ladder) else 0
+        while r < ceiling and r < len(pending) and pending[r] > fits:
+            total += width
+            r += 1
+    return total
+
+
+SCHEDULE_CASES = [
+    # nq, cap, load, dup, K, dense_rounds, stages
+    pytest.param(512, 1 << 12, 0.1, 0.0, 2, None, None, id="below-min-stage"),
+    pytest.param(1024, 1 << 12, 0.25, 0.5, 3, None, None, id="min-stage-k3"),
+    pytest.param(4096, 1 << 13, 0.5, 0.0, 2, None, None, id="4k-half-load"),
+    pytest.param(1 << 14, 1 << 16, 0.25, 0.9, 2, None, None, id="16k-dups"),
+    pytest.param(1 << 15, 1 << 22, 0.01, 0.3, 3, None, None, id="32k-sparse"),
+    pytest.param(1 << 17, 1 << 18, 0.5, 0.0, 2, None, None, id="128k-expansion"),
+    pytest.param(1 << 17, 1 << 19, 0.3, 0.6, 3, None, None, id="128k-k3-dups"),
+    pytest.param(1 << 16, 1 << 17, 0.5, 0.3, 3, 2, ((2, 8), (8, 64)),
+                 id="custom-halving"),
+    pytest.param(1 << 15, 1 << 16, 0.4, 0.1, 2, 1,
+                 ((4, 16), (16, 32), (256, 64)), id="custom-deep-ladder"),
+    pytest.param(1 << 13, 1 << 14, 0.45, 0.2, 2, 6, (), id="dense-only"),
+    pytest.param(1 << 14, 1 << 15, 0.4, 0.0, 2, 8, ((4, 4), (16, 64)),
+                 id="stage-under-dense-ceiling"),
+]
+
+
+@pytest.mark.parametrize(
+    "nq,cap,load,dup,K,dense,stages", SCHEDULE_CASES
+)
+def test_pending_driven_schedule_matches_single_loop(
+    nq, cap, load, dup, K, dense, stages
+):
+    """``is_new``, the table columns, ``n_failed`` and ``rounds`` equal
+    the single loop's: every pending lane still probes the same slot at
+    the same global round with its original lane id, whichever buffer
+    holds it."""
+    tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
+    d, st = fpset.resolve_schedule(dense, stages)
+    ceiling = _ladder(nq, d, st)[-1][1]
+    got_new, got_cols, n_failed, rounds, _ = fpset.lookup_or_insert(
+        tcols, kcols, valid, dense_rounds=dense, stages=stages
+    )
+    want_new, want_cols, _, pending, want_rounds = fpset.probe_insert(
+        tcols, kcols, valid, max_probes=ceiling
+    )
+    assert np.array_equal(np.asarray(got_new), np.asarray(want_new))
+    for a, b in zip(got_cols, want_cols):
+        assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
+    assert int(n_failed) == int(np.asarray(pending).sum())
+    assert int(rounds) == int(want_rounds)
+
+
+@pytest.mark.parametrize(
+    "nq,cap,load,dup,K,dense,stages", SCHEDULE_CASES
+)
+def test_lane_rounds_is_width_times_rounds_and_never_above_fixed(
+    nq, cap, load, dup, K, dense, stages
+):
+    """``lane_rounds`` is each step's width times the rounds run at it,
+    never more than the parent's fixed schedule presents for the same
+    batch, and strictly less where the batch is mostly new keys and the
+    ladder has somewhere narrower to go."""
+    tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
+    d, st = fpset.resolve_schedule(dense, stages)
+    ladder = _ladder(nq, d, st)
+    _, _, n_failed, rounds, lane_rounds = fpset.lookup_or_insert(
+        tcols, kcols, valid, dense_rounds=dense, stages=stages
+    )
+    pending = _pending_by_round(tcols, kcols, valid, ladder[-1][1])
+    assert int(rounds) == len(pending) - 1
+    followed = _lane_rounds(ladder, pending, follow=True)
+    fixed = _lane_rounds(ladder, pending, follow=False)
+    assert int(lane_rounds) == followed <= fixed
+    if len(ladder) > 1 and int(rounds) > 1 and dup <= 0.3:
+        assert followed < fixed
+    if len(ladder) == 1:
+        assert followed == fixed == nq * int(rounds)
+
+
+def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
+    """A stage left at its ceiling with more survivors than the next
+    can hold drops none of them silently: the excess is exactly
+    ``n_failed``, the lanes that were carried resolve as in the single
+    loop, and the wrapper still raises ``probe overflow``."""
+    nq, cap, K = 1 << 14, 1 << 15, 2
+    tcols, kcols, valid = _batch(99, nq, cap, 0.5, 0.0, K)
+    # one dense round, then a 1/16 stage: far more than 1,024 lanes
+    # lose their first bid at this load
+    sched = dict(dense_rounds=1, stages=((16, 64),))
+    carried = max(nq // 16, fpset.MIN_STAGE)
+    after_one = _pending_by_round(tcols, kcols, valid, 1)[1]
+    assert after_one > carried
+    is_new, cols, n_failed, _, lane_rounds = fpset.lookup_or_insert(
+        tcols, kcols, valid, **sched
+    )
+    assert int(n_failed) == after_one - carried
+    # every valid lane whose key is not in the table afterwards is one
+    # of the counted ones
+    member = np.asarray(fpset.lookup(cols, kcols, valid))
+    assert int((np.asarray(valid) & ~member).sum()) <= int(n_failed)
+    assert int(lane_rounds) >= nq + carried
+    # the same batch with room in the next stage: nothing fails
+    _, _, none_failed, _, _ = fpset.lookup_or_insert(
+        tcols, kcols, valid, dense_rounds=1, stages=((2, 64),)
+    )
+    assert int(none_failed) == 0
+
+    class NoGrow(fpset.FPSet):
+        def reserve(self, n):
+            return self
+
+    s = NoGrow(K, cap=cap, **sched)
+    s.cols = tcols
+    with pytest.raises(RuntimeError, match="probe overflow"):
+        s.insert(kcols, valid)
+    assert s.stats["failures"] == after_one - carried
+    assert s.stats["lane_rounds"] == int(lane_rounds)
+
+
+def test_fpm_carries_lane_rounds_past_32_bits_and_pads_old_frames():
+    """The widened metrics vector: ``lane_rounds`` rides as hi/lo
+    uint32 words (the r12 pattern) and every narrower historical frame
+    reads back zero-padded with its old counters where they were."""
+    fpm = jnp.zeros((fpset.FPM_N,), jnp.int32)
+    big = np.uint32(3_000_000_000)  # one flush can pass 2^31
+    for _ in range(3):
+        fpm = fpset.fpm_update(
+            fpm, jnp.int32(4), jnp.int32(0), jnp.int32(7),
+            jnp.uint32(big),
+        )
+    got = fpset.fpm_logical(np.asarray(fpm))
+    assert list(got) == [3, 12, 0, 21, 4, 3 * int(big)]
+    assert 3 * int(big) > (1 << 32)
+    six = np.asarray(fpm)[:6]  # an r12-r27 frame
+    assert list(fpset.fpm_logical(six)) == [3, 12, 0, 21, 4, 0]
+    assert list(fpset.fpm_logical(np.asarray([2, 5, 0]))) == [
+        2, 5, 0, 0, 0, 0,
+    ]
 
 
 # ---- engine differential: fpset vs the legacy sort-merge flush -------

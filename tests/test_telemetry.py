@@ -93,8 +93,9 @@ def test_zero_sync_counters_ride_the_stats_fetch(std_run):
     telemetry-specific fetches (one flush record per stats fetch at
     most)."""
     _stream, _frame, ck, r, events = std_run
-    # r12: valid_lanes split into hi/lo uint32 words (int32-wrap fix)
-    assert FPM_N == 6
+    # r12: valid_lanes split into hi/lo uint32 words (int32-wrap fix);
+    # PR 28: lane_rounds appended the same way
+    assert FPM_N == 8
     stats = [e for e in events if e["event"] == "result"][-1]["stats"]
     flushes = [e for e in events if e["event"] == "flush"]
     assert stats["fpset_flushes"] == sum(e["flushes"] for e in flushes)
@@ -123,6 +124,54 @@ def test_zero_sync_counters_ride_the_stats_fetch(std_run):
     assert "stage_flush_s" not in stats  # timing stays legacy-only
     # flush records only ever ride an existing fetch
     assert len(flushes) <= stats["stats_fetches"]
+
+
+def test_lane_rounds_ride_the_result_stats_and_the_stream_validates(
+    std_run, checker_mod
+):
+    """The probe's presented-lane counter (PR 28) reaches ``last_stats``
+    and the ``result`` event's free-form ``stats`` with no schema bump:
+    the stream validates as before.  Every valid lane is presented at
+    least once, and no flush presents more than the fixed schedule's
+    ceiling of 10 accumulator widths."""
+    stream, _frame, ck, _r, events = std_run
+    stats = [e for e in events if e["event"] == "result"][-1]["stats"]
+    for k in ("fpset_lane_rounds", "fpset_lanes_presented_per_valid"):
+        assert stats[k] == ck.last_stats[k], k
+    assert stats["fpset_lane_rounds"] >= stats["fpset_valid_lanes"]
+    assert stats["fpset_lane_rounds"] <= (
+        10 * ck.ACAP * stats["fpset_flushes"]
+    )
+    assert stats["fpset_lanes_presented_per_valid"] == round(
+        stats["fpset_lane_rounds"] / stats["fpset_valid_lanes"], 4
+    )
+    assert checker_mod.validate_stream(stream) == []
+
+
+def test_six_wide_fpm_frame_restores_zero_padded(std_run, tmp_path):
+    """A frame written before PR 28 carries a 6-wide ``fpm``: it resumes
+    with the old counters where they were and the new words at zero, to
+    the published count."""
+    import numpy as np
+
+    _stream, frame, ck, _r, _events = std_run
+    with np.load(frame) as d:
+        arrays = {k: d[k] for k in d.files}
+    assert arrays["fpm"].shape == (FPM_N,)
+    old_flushes, old_valid = int(arrays["fpm"][0]), int(arrays["fpm"][3])
+    arrays["fpm"] = arrays["fpm"][:6]
+    narrow = str(tmp_path / "narrow.npz")
+    np.savez_compressed(narrow, **arrays)
+    ck2 = DeviceChecker(_shipped(), checkpoint_path=narrow, **KW)
+    r2 = ck2.run(resume=True)
+    assert r2.distinct_states == 45198 and r2.diameter == 20
+    st = ck2.last_stats
+    assert st["fpset_flushes"] == ck.last_stats["fpset_flushes"]
+    assert st["fpset_valid_lanes"] == ck.last_stats["fpset_valid_lanes"]
+    assert st["fpset_flushes"] > old_flushes
+    # the new counter restarts at the frame: it covers the resumed part
+    assert 0 < st["fpset_lane_rounds"] < ck.last_stats["fpset_lane_rounds"]
+    assert st["fpset_lane_rounds"] >= st["fpset_valid_lanes"] - old_valid
 
 
 def test_ckpt_frame_stall_accounting(std_run):
