@@ -533,22 +533,23 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
 
         if args.slices > 1 and args.sharded % args.slices:
             sys.exit("tpu-tlc: -sharded must be divisible by -slices")
-        ck = ShardedDeviceChecker(
-            model,
-            n_devices=args.sharded,
-            invariants=invariants,
-            check_deadlock=not args.nodeadlock,
-            sub_batch=args.chunk,
-            max_states=args.maxstates,
-            metrics_path=args.metrics,
-            progress=True,
-            checkpoint_path=args.checkpoint,
-            n_slices=args.slices,
-            visited_impl=args.visited,
-            compact_impl=args.compact,
-            telemetry=args.telemetry,
-            heartbeat_s=args.progress,
-        )
+        with spans.span("cli.engine_init"):
+            ck = ShardedDeviceChecker(
+                model,
+                n_devices=args.sharded,
+                invariants=invariants,
+                check_deadlock=not args.nodeadlock,
+                sub_batch=args.chunk,
+                max_states=args.maxstates,
+                metrics_path=args.metrics,
+                progress=True,
+                checkpoint_path=args.checkpoint,
+                n_slices=args.slices,
+                visited_impl=args.visited,
+                compact_impl=args.compact,
+                telemetry=args.telemetry,
+                heartbeat_s=args.progress,
+            )
     elif args.sharded:
         if args.sharded_engine == "device":
             print(

@@ -67,7 +67,6 @@ min-lane-wins dedup).
 
 from __future__ import annotations
 
-import functools
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -130,22 +129,6 @@ IDX_MASK = jnp.uint32((1 << 31) - 1)
 # under either -fuse mode.
 _probe_flush_acc = spans.staged("probe")(fpset.flush_acc)
 _compact_rows = spans.staged("compact")(compact_ops.compact_rows)
-
-
-def _in_phase(name: str):
-    """Method decorator: run under the exclusive host phase ``name`` of
-    the current run's clock (``spans.PhaseClock``) — a site the host
-    passes once per dispatch, fetch or boundary, never per row."""
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapped(self, *args, **kwargs):
-            with self._clock.phase(name):
-                return fn(self, *args, **kwargs)
-
-        return wrapped
-
-    return deco
 
 
 class DeviceChecker:
@@ -1881,7 +1864,7 @@ class DeviceChecker:
             int(np.asarray(parents[::step], np.int64).sum()),
         )
 
-    @_in_phase("seed_load")
+    @spans.in_phase("seed_load")
     def _load_seed(self, bufs, st, seed):
         """Bulk-load a host-enumerated BFS prefix: packed states in BFS
         (= gid) order with parent gids (roots: ``-1 - init_idx``) and
@@ -2051,7 +2034,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------ growth
 
-    @_in_phase("grow")
+    @spans.in_phase("grow")
     def _grow_visited(self, bufs, need: int):
         cap = self._capv()
         # clamp at the most any run can use: nv never exceeds SCAP, so
@@ -2140,7 +2123,7 @@ class DeviceChecker:
             tcap *= 2
         return tcap
 
-    @_in_phase("grow")
+    @spans.in_phase("grow")
     def _grow_logs(self, bufs, need: int):
         cap = self._capp()
         target = self._next_cap(self.PCAP, need, cap)
@@ -2154,7 +2137,7 @@ class DeviceChecker:
             )
             self.PCAP += pad
 
-    @_in_phase("grow")
+    @spans.in_phase("grow")
     def _grow_store(self, bufs, need: int):
         """Admit ``need`` states in the trace logs and (all-mode only)
         the row store.  Frontier mode's rows window is fixed — row
@@ -3177,7 +3160,7 @@ class DeviceChecker:
             self._compact_prev_s = s
         self.tel.emit("compact", **f)
 
-    @_in_phase("dispatch")
+    @spans.in_phase("dispatch")
     def _flush_acc(self, bufs, st, rb, n_acc, acc_base, is_init):
         """Dispatch the dedup + append for the current accumulator
         fill (``n_acc`` valid lanes covering source rows starting
@@ -3285,7 +3268,7 @@ class DeviceChecker:
     def _spill_tier_label(self) -> str:
         return "ram+disk" if self.tstore.durable else "ram"
 
-    @_in_phase("spill")
+    @spans.in_phase("spill")
     def _resolve_cold_misses(self, bufs, flag_acc, n_new):
         """Sieve the flush's hot-filter survivors, resolve them
         against the cold runs in ``miss_batch``-wide batches, and
@@ -3378,7 +3361,7 @@ class DeviceChecker:
         )
         return n
 
-    @_in_phase("spill")
+    @spans.in_phase("spill")
     def _ensure_hot_capacity(self, bufs, head: int) -> None:
         """The tiered replacement for unbounded visited growth: admit
         ``head`` more states in the hot table by growing WITHIN the
@@ -3435,7 +3418,7 @@ class DeviceChecker:
         rb["row_base"] = upto
         self._spill_active = True
 
-    @_in_phase("spill")
+    @spans.in_phase("spill")
     def _tiered_ensure_windows(self, bufs, rb, level_base: int,
                                need_abs: int, nv: int) -> None:
         """Admit ``need_abs`` absolute states in the row/log windows:
@@ -3481,7 +3464,7 @@ class DeviceChecker:
             self._spill_active = True
         return self._spill_active
 
-    @_in_phase("spill")
+    @spans.in_phase("spill")
     def _tiered_boundary(self, bufs, st, rb, level_base: int,
                          nf: int, nv: int, level: int) -> None:
         """Level-boundary spill housekeeping: tag the epoch, spill
@@ -4336,7 +4319,7 @@ class DeviceChecker:
     def _can_recover(self) -> bool:
         return self.rec.can_recover()
 
-    @_in_phase("ckpt")
+    @spans.in_phase("ckpt")
     def _save_frame(
         self, bufs, st, rb, level_sizes, level_base, nf, nv, t0
     ) -> bool:
@@ -4737,7 +4720,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------- trace
 
-    @_in_phase("trace_walk")
+    @spans.in_phase("trace_walk")
     def _trace(self, bufs, gid: int, max_depth: int):
         """Walk the parent chain on device (one fetch), replay lanes
         through the oracle on the host (SURVEY.md §2.2-E7).  Tiered
@@ -4818,7 +4801,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------ result
 
-    @_in_phase("result")
+    @spans.in_phase("result")
     def _result(
         self, t0, nv, level_sizes, bufs,
         viol: Optional[Tuple[str, int]] = None,

@@ -51,6 +51,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.utils import ckpt, faults, recovery
 from pulsar_tlaplus_tpu.engine.bfs import CheckerResult
@@ -69,6 +70,10 @@ BIG = jnp.int32(2**31 - 1)
 FPM_N = fpset.FPM_N
 TAG_BIT = jnp.uint32(1 << 31)
 IDX_MASK = jnp.uint32((1 << 31) - 1)
+
+# per-shard route state riding the stats fetch: [sticky overflow flag,
+# lanes sent through the key exchange as a hi/lo uint32 pair (LO, HI)]
+RT_N = 3
 
 AXIS = "shard"
 DCN_AXIS = "dcn"  # across slices (multi-slice; data-center network)
@@ -95,6 +100,18 @@ def _owner(kcols, n: int):
     return (h % jnp.uint32(n)).astype(jnp.int32)
 
 
+@spans.staged("route")
+def _route_note(rt, over, q):
+    """The route state after one exchange: the overflow flag stays up
+    once raised, and the lanes this shard sent (``q >= 0``: those that
+    got a slot) add to the hi/lo counter."""
+    lo, hi = fpset.add_u32(
+        rt[1], rt[2], jnp.sum((q >= 0).astype(jnp.int32))
+    )
+    return jnp.stack([rt[0] | over.astype(jnp.int32), lo, hi])
+
+
+@spans.staged("route")
 def _route_keys(kcols, ak, acc_off, N: int, CAPO: int):
     """Round-5 producer-local routing (VERDICT r4 #3): bucket candidate
     KEYS by owner (one-hot running rank — no sort, no host), route them
@@ -127,6 +144,7 @@ def _route_keys(kcols, ak, acc_off, N: int, CAPO: int):
     return ak, q, over
 
 
+@spans.staged("route")
 def _flags_back(flag_owner, FLUSH: int, N: int, CAPO: int):
     """Inverse of ``_route_keys`` for the dedup flags: the owner's
     acc-order flag vector (slot ``r * N*CAPO + p * CAPO + j`` = round
@@ -141,6 +159,7 @@ def _flags_back(flag_owner, FLUSH: int, N: int, CAPO: int):
     ).reshape(N * FLUSH * CAPO)
 
 
+@spans.staged("route")
 def _flag_gather(recv, aq, FLUSH: int, cap: int, NCs: int):
     """Producer-side per-lane flags from the returned flag planes:
     ``aq`` is the saved q per producer lane (acc order, -1 = invalid).
@@ -186,6 +205,7 @@ def _bucket_scatter(dest, ndest: int, cap: int, valid, cols, fills):
     return outs, jnp.where(fit, q, -1), over
 
 
+@spans.staged("route")
 def _route_keys_2d(
     kcols, ak, aq2, acc_off, r,
     D: int, I: int, CAPD: int, CAPO2: int,
@@ -235,6 +255,7 @@ def _route_keys_2d(
     return ak, q1, aq2, over1 | over2
 
 
+@spans.staged("route")
 def _flags_back_2d(
     flag_owner, aq2, FLUSH: int, D: int, I: int, CAPD: int, CAPO2: int,
 ):
@@ -436,6 +457,12 @@ class ShardedDeviceChecker:
         self._compact_n = 0
         self._compact_prev = 0
         self._resume_meta: Dict[str, object] = {}
+        # host phases, dispatches and exchanges of the current run
+        # (obs/spans.py); outside a run they count into these idle ones
+        self._clock = spans.PhaseClock()
+        self._dispatch_n = 0
+        self._route_rounds: Dict[int, int] = {}
+        self._route_overflows = 0
 
     # -------------------------------------------------------------- util
 
@@ -453,6 +480,12 @@ class ShardedDeviceChecker:
     def _headroom_frozen(self) -> bool:
         return self.rec.headroom_frozen
 
+    @property
+    def _host_wait_s(self) -> float:
+        """Seconds this run's host has been blocked on stats fetches:
+        the ``fetch`` phase of its clock."""
+        return self._clock.seconds_of("fetch")
+
     def _calc_route(self):
         """Derive every route-capacity-dependent size from the current
         ``route_slack`` (re-run by overflow recovery).
@@ -460,7 +493,9 @@ class ShardedDeviceChecker:
         Round 5 (producer-local rows): two accumulators per shard —
         ``ACAP`` lanes of OWNER-side routed keys (K planes) and
         ``PACAP = NCs * FLUSH`` lanes of PRODUCER-side candidate rows /
-        parent / lane / return-address, which never travel."""
+        parent / lane / return-address, which never travel.
+        ``route_cap`` is the lanes one round's key exchange is compiled
+        to carry a shard (both hops of a 2-D mesh together)."""
         if self.N == 1:
             # singleton mesh: no routing at all (the n=1 fast path
             # appends lanes straight into the accumulator), so no
@@ -468,15 +503,18 @@ class ShardedDeviceChecker:
             # engine exactly
             self.CAPO = self.NCs
             self.RCV = self.NCs
+            self.route_cap = 0
         elif len(self._axes) == 1:
             self.CAPO = int(-(-self.NCs * self.route_slack // self.N))
             self.RCV = self.N * self.CAPO
+            self.route_cap = self.RCV
         else:
             # expected per-destination fill is NCs/D (stage 1, slices)
             # and NCs/I (stage 2, chips within the slice)
             self.CAPD = int(-(-self.NCs * self.route_slack // self.D))
             self.CAPO2 = int(-(-self.NCs * self.route_slack // self.I))
             self.RCV = self.I * self.CAPO2
+            self.route_cap = self.D * self.CAPD + self.RCV
         self.ACAP = self.RCV * self.FLUSH
         self.PACAP = self.NCs * self.FLUSH
         # append chunking runs over the PRODUCER accumulator
@@ -542,11 +580,12 @@ class ShardedDeviceChecker:
             lax.axis_index(DCN_AXIS) * self.I + lax.axis_index(ICI_AXIS)
         ).astype(jnp.int32)
 
-    def _route_acc(self, kcols, ak, aq, aq2, w):
+    def _route_acc(self, kcols, ak, aq, aq2, rt, w):
         """Producer-side half of a round: route keys to their owners
         and save the per-lane return address.  Rows/par/lane are NOT
         here — the caller stores them producer-locally.  Returns
-        ``(ak', aq', aq2', over)``."""
+        ``(ak', aq', aq2', rt')``, ``rt`` being the shard's route state
+        (``_route_note``)."""
         o_off = w * self.RCV
         if self.N == 1:
             # -workers 1 must not be a perf trap (VERDICT r3 #4): the
@@ -558,20 +597,18 @@ class ShardedDeviceChecker:
                 lax.dynamic_update_slice(a, c, (o_off,))
                 for a, c in zip(ak, kcols)
             )
-            return ak, aq, aq2, jnp.bool_(False)
-        p_off = w * self.NCs
+            return ak, aq, aq2, rt
         if len(self._axes) == 1:
             ak, q, over = _route_keys(
                 kcols, ak, o_off, self.N, self.CAPO
             )
-            aq = lax.dynamic_update_slice(aq, q, (p_off,))
-            return ak, aq, aq2, over
-        ak, q1, aq2, over = _route_keys_2d(
-            kcols, ak, aq2, o_off, w,
-            self.D, self.I, self.CAPD, self.CAPO2,
-        )
-        aq = lax.dynamic_update_slice(aq, q1, (p_off,))
-        return ak, aq, aq2, over
+        else:
+            ak, q, aq2, over = _route_keys_2d(
+                kcols, ak, aq2, o_off, w,
+                self.D, self.I, self.CAPD, self.CAPO2,
+            )
+        aq = lax.dynamic_update_slice(aq, q, (w * self.NCs,))
+        return ak, aq, aq2, _route_note(rt, over, q)
 
     def _round_cap(self, c: int) -> int:
         n = 1 << 10
@@ -597,12 +634,31 @@ class ShardedDeviceChecker:
             self.mesh, P(self._axes) if spec is None else spec
         )
 
-    def _smap(self, body, in_specs, out_specs, donate=()):
+    def _program(self, fn, donate=(), exchange=False):
+        """``jax.jit(fn)`` whose calls count as dispatches of the run
+        and, for a program that holds a key exchange, as a round at
+        the route capacity it was compiled with.  ``fn`` is named
+        ``ptt_*``: a program's name is part of its compile-cache key
+        and its scopes are not (docs/observability.md)."""
+        jitted = jax.jit(fn, donate_argnums=donate)
+        cap = self.route_cap if exchange else 0
+
+        def call(*args):
+            self._dispatch_n += 1
+            if cap:
+                self._route_rounds[cap] = (
+                    self._route_rounds.get(cap, 0) + 1
+                )
+            return jitted(*args)
+
+        return call
+
+    def _smap(self, body, in_specs, out_specs, donate=(), exchange=False):
         fn = jax.shard_map(
             body, mesh=self.mesh, in_specs=in_specs, out_specs=out_specs,
             check_vma=False,
         )
-        return jax.jit(fn, donate_argnums=donate)
+        return self._program(fn, donate, exchange)
 
     # ------------------------------------------------------ device code
 
@@ -612,8 +668,8 @@ class ShardedDeviceChecker:
         keys to their owners (VERDICT r4 #3).
 
         (ak cols, arows, apar, alane, aq, aq2, rows, lb, nf, dead,
-        ovf, r, w) -> (ak', arows', apar', alane', aq', aq2', dead',
-        ovf')
+        rt, r, w) -> (ak', arows', apar', alane', aq', aq2', dead',
+        rt')
         """
         key = ("round", self.LCAP)
         if key in self._jits:
@@ -622,14 +678,15 @@ class ShardedDeviceChecker:
         K, W, A, N = self.K, self.W, self.A, self.N
         G, Fi, NCs = self.G, self.Fi, self.NCs
 
-        def body(ak, arows, apar, alane, aq, aq2, rows, lb, nf, dead,
-                 ovf, r, w):
+        @spans.staged("expand")
+        def ptt_shard_round(ak, arows, apar, alane, aq, aq2, rows, lb,
+                            nf, dead, rt, r, w):
             # local blocks arrive with a leading length-1 shard axis
             ak = tuple(a[0] for a in ak)
             arows, apar, alane = arows[0], apar[0], alane[0]
             aq, aq2 = aq[0], aq2[0]
-            rows, lb, nf, dead, ovf = (
-                rows[0], lb[0], nf[0], dead[0], ovf[0]
+            rows, lb, nf, dead, rt = (
+                rows[0], lb[0], nf[0], dead[0], rt[0]
             )
             shard = self._shard_idx()
             f_off = r * G
@@ -695,12 +752,13 @@ class ShardedDeviceChecker:
             )
             apar = lax.dynamic_update_slice(apar, par, (p_off,))
             alane = lax.dynamic_update_slice(alane, lane, (p_off,))
-            ak, aq, aq2, over = self._route_acc(kcols, ak, aq, aq2, w)
-            ovf = ovf | over
+            ak, aq, aq2, rt = self._route_acc(
+                kcols, ak, aq, aq2, rt, w
+            )
             return (
                 tuple(a[None] for a in ak), arows[None], apar[None],
                 alane[None], aq[None], aq2[None], dead[None],
-                ovf[None],
+                rt[None],
             )
 
         sh = P(self._axes)
@@ -710,7 +768,8 @@ class ShardedDeviceChecker:
         )
         out_specs = ((sh,) * self.K, sh, sh, sh, sh, sh, sh, sh)
         fn = self._smap(
-            body, in_specs, out_specs, donate=(0, 1, 2, 3, 4, 5)
+            ptt_shard_round, in_specs, out_specs,
+            donate=(0, 1, 2, 3, 4, 5), exchange=True,
         )
         self._jits[key] = fn
         return fn
@@ -754,9 +813,10 @@ class ShardedDeviceChecker:
                 packed,
             )
 
-        def body(ak, arows, apar, alane, aq, aq2, ovf, base, w):
+        @spans.staged("init")
+        def ptt_shard_init(ak, arows, apar, alane, aq, aq2, rt, base, w):
             ak = tuple(a[0] for a in ak)
-            arows, apar, alane, ovf = arows[0], apar[0], alane[0], ovf[0]
+            arows, apar, alane, rt = arows[0], apar[0], alane[0], rt[0]
             aq, aq2 = aq[0], aq2[0]
             start = base + self._shard_idx()
             idx = start + jnp.arange(NCs, dtype=jnp.int32) * N
@@ -776,18 +836,20 @@ class ShardedDeviceChecker:
             )
             apar = lax.dynamic_update_slice(apar, par, (p_off,))
             alane = lax.dynamic_update_slice(alane, lane, (p_off,))
-            ak, aq, aq2, over = self._route_acc(kcols, ak, aq, aq2, w)
-            ovf = ovf | over
+            ak, aq, aq2, rt = self._route_acc(
+                kcols, ak, aq, aq2, rt, w
+            )
             return (
                 tuple(a[None] for a in ak), arows[None], apar[None],
-                alane[None], aq[None], aq2[None], ovf[None],
+                alane[None], aq[None], aq2[None], rt[None],
             )
 
         sh = P(self._axes)
         in_specs = ((sh,) * self.K, sh, sh, sh, sh, sh, sh, P(), P())
         out_specs = ((sh,) * self.K, sh, sh, sh, sh, sh, sh)
         fn = self._smap(
-            body, in_specs, out_specs, donate=(0, 1, 2, 3, 4, 5)
+            ptt_shard_init, in_specs, out_specs,
+            donate=(0, 1, 2, 3, 4, 5), exchange=True,
         )
         self._jits[key] = fn
         return fn
@@ -813,7 +875,8 @@ class ShardedDeviceChecker:
             return self._jits[key]
         K, ACAP, PACAP = self.K, self.ACAP, self.PACAP
 
-        def body(vk, ak, aq, aq2, n_keys, fpm, n_acc):
+        @spans.staged("probe")
+        def ptt_shard_flush(vk, ak, aq, aq2, n_keys, fpm, n_acc):
             vk = tuple(v[0] for v in vk)
             ak = tuple(a[0] for a in ak)
             aq, aq2, n_keys, fpm = aq[0], aq2[0], n_keys[0], fpm[0]
@@ -882,7 +945,7 @@ class ShardedDeviceChecker:
 
         sh = P(self._axes)
         fn = self._smap(
-            body,
+            ptt_shard_flush,
             ((sh,) * self.K, (sh,) * self.K, sh, sh, sh, sh, P()),
             ((sh,) * self.K, sh, sh, sh, sh),
             donate=(0,),
@@ -908,7 +971,8 @@ class ShardedDeviceChecker:
         W = self.W
         impl = self.compact_impl
 
-        def body(arows, apar, alane, flag_acc):
+        @spans.staged("compact")
+        def ptt_shard_compact(arows, apar, alane, flag_acc):
             arows, apar, alane = arows[0], apar[0], alane[0]
             flag_acc = flag_acc[0]
             drop = flag_acc ^ jnp.uint32(1)
@@ -926,7 +990,8 @@ class ShardedDeviceChecker:
 
         sh = P(self._axes)
         fn = self._smap(
-            body, (sh, sh, sh, sh), (sh, sh, sh), donate=(0, 1, 2),
+            ptt_shard_compact, (sh, sh, sh, sh), (sh, sh, sh),
+            donate=(0, 1, 2),
         )
         self._jits[key] = fn
         return fn
@@ -945,8 +1010,9 @@ class ShardedDeviceChecker:
         inv_fns = [self.model.invariants[n] for n in self.invariant_names]
         n_inv = len(self.invariant_names)
 
-        def body(rows, parent_log, lane_log, crows, cpar, clane,
-                 n_new, n_visited, viol):
+        @spans.staged("append")
+        def ptt_shard_append(rows, parent_log, lane_log, crows, cpar,
+                             clane, n_new, n_visited, viol):
             rows, parent_log, lane_log = rows[0], parent_log[0], lane_log[0]
             crows, cpar, clane = crows[0], cpar[0], clane[0]
             n_new = n_new[0]
@@ -1027,7 +1093,7 @@ class ShardedDeviceChecker:
 
         sh = P(self._axes)
         fn = self._smap(
-            body, (sh,) * 9, (sh,) * 5, donate=(0, 1, 2),
+            ptt_shard_append, (sh,) * 9, (sh,) * 5, donate=(0, 1, 2),
         )
         self._jits[key] = fn
         return fn
@@ -1053,8 +1119,10 @@ class ShardedDeviceChecker:
         inv_fns = [self.model.invariants[n] for n in self.invariant_names]
         n_inv = len(self.invariant_names)
 
-        def body(rows, parent_log, lane_log, viol, seed_rows, seed_par,
-                 seed_lane, n_local, off):
+        @spans.staged("seed")
+        def ptt_shard_seed_write(rows, parent_log, lane_log, viol,
+                                 seed_rows, seed_par, seed_lane, n_local,
+                                 off):
             rows, parent_log, lane_log = (
                 rows[0], parent_log[0], lane_log[0],
             )
@@ -1088,7 +1156,7 @@ class ShardedDeviceChecker:
 
         sh = P(self._axes)
         fn = self._smap(
-            body, (sh, sh, sh, sh, sh, sh, sh, sh, P()),
+            ptt_shard_seed_write, (sh, sh, sh, sh, sh, sh, sh, sh, P()),
             (sh, sh, sh, sh), donate=(0, 1, 2),
         )
         self._jits[key] = fn
@@ -1118,9 +1186,11 @@ class ShardedDeviceChecker:
         W = self.W
         keyspec = self.keys
 
-        def body(ak, aq, aq2, ovf, rows_flat, n_local, off, w):
+        @spans.staged("seed")
+        def ptt_shard_seed_round(ak, aq, aq2, rt, rows_flat, n_local,
+                                 off, w):
             ak = tuple(a[0] for a in ak)
-            aq, aq2, ovf = aq[0], aq2[0], ovf[0]
+            aq, aq2, rt = aq[0], aq2[0], rt[0]
             rows_flat, n_local = rows_flat[0], n_local[0]
             chunk = lax.dynamic_slice(
                 rows_flat, (off * W,), (SRC * W,)
@@ -1135,24 +1205,26 @@ class ShardedDeviceChecker:
                     lax.dynamic_update_slice(a, c, (w * SRC,))
                     for a, c in zip(ak, kcols)
                 )
-                over = jnp.bool_(False)
             else:
-                ak, aq, aq2, over = self._route_acc(
-                    kcols, ak, aq, aq2, w
+                ak, aq, aq2, rt = self._route_acc(
+                    kcols, ak, aq, aq2, rt, w
                 )
             return (
                 tuple(a[None] for a in ak), aq[None], aq2[None],
-                (ovf | over)[None],
+                rt[None],
             )
 
         sh = P(self._axes)
         fn = self._smap(
-            body, ((sh,) * self.K, sh, sh, sh, sh, sh, P(), P()),
+            ptt_shard_seed_round,
+            ((sh,) * self.K, sh, sh, sh, sh, sh, P(), P()),
             ((sh,) * self.K, sh, sh, sh), donate=(0, 1, 2),
+            exchange=True,
         )
         self._jits[key] = fn
         return fn
 
+    @spans.in_phase("seed_load")
     def _load_seed(self, bufs, st, seed):
         """Bulk-load a host-enumerated BFS prefix (same contract as
         ``device_bfs._load_seed``): states in BFS order with parent
@@ -1229,11 +1301,11 @@ class ShardedDeviceChecker:
                 w = 0
                 for off in range(0, Mp, SRC):
                     out = seed_round(
-                        bufs["ak"], bufs["aq"], bufs["aq2"], st["ovf"],
+                        bufs["ak"], bufs["aq"], bufs["aq2"], st["rt"],
                         rows_d, nloc_d, jnp.int32(off), jnp.int32(w),
                     )
                     bufs["ak"] = tuple(out[0])
-                    bufs["aq"], bufs["aq2"], st["ovf"] = out[1:]
+                    bufs["aq"], bufs["aq2"], st["rt"] = out[1:]
                     w += 1
                     if w == self.FLUSH or off + SRC >= Mp:
                         # singleton meshes pack contiguously (w * SRC
@@ -1248,7 +1320,8 @@ class ShardedDeviceChecker:
                         st["n_keys"] = fout[1]
                         st["fpm"] = fout[4]
                         w = 0
-                # the fetch surfaces routing overflows (sticky ovf flag)
+                # the fetch surfaces routing overflows (``rt``'s sticky
+                # flag)
                 # so the except below can actually engage — without it
                 # dropped seed keys would masquerade as duplicates
                 stats = self._fetch(st)
@@ -1268,16 +1341,17 @@ class ShardedDeviceChecker:
         if key in self._jits:
             return self._jits[key]
 
-        def step(n_visited, n_keys, dead, viol, ovf, fpm):
+        @spans.staged("levelctl")
+        def ptt_shard_stats(n_visited, n_keys, dead, viol, rt, fpm):
             return jnp.concatenate(
                 [
                     n_visited[:, None], n_keys[:, None], dead[:, None],
-                    viol, ovf[:, None].astype(jnp.int32), fpm,
+                    viol, rt, fpm,
                 ],
                 axis=1,
             )
 
-        fn = jax.jit(step)
+        fn = self._program(ptt_shard_stats)
         self._jits[key] = fn
         return fn
 
@@ -1292,7 +1366,8 @@ class ShardedDeviceChecker:
             return self._jits[key]
         K, TCAP = self.K, self.TCAP
 
-        def body(vk):
+        @spans.staged("rehash")
+        def ptt_shard_rehash(vk):
             vk = tuple(v[0] for v in vk)
             new, failed = fpset.rehash_cols(
                 vk, fpset.empty_cols(2 * TCAP, K)
@@ -1300,10 +1375,11 @@ class ShardedDeviceChecker:
             return tuple(v[None] for v in new), failed[None]
 
         sh = P(self._axes)
-        fn = self._smap(body, ((sh,) * K,), ((sh,) * K, sh))
+        fn = self._smap(ptt_shard_rehash, ((sh,) * K,), ((sh,) * K, sh))
         self._jits[key] = fn
         return fn
 
+    @spans.in_phase("grow")
     def _grow_visited(self, bufs, need: int):
         if self.visited_impl == "fpset":
             while self.VCAP < need:
@@ -1333,6 +1409,7 @@ class ShardedDeviceChecker:
             )
             self.VCAP *= 2
 
+    @spans.in_phase("grow")
     def _grow_store(self, bufs, need: int):
         cap = max(
             self.SCAP // self.N + self.APAD, self.NCs + self.APAD
@@ -1421,6 +1498,7 @@ class ShardedDeviceChecker:
             )
         )
 
+    @spans.in_phase("ckpt")
     def _save_checkpoint(self, bufs, st, level_sizes, lb, nf, t0):
         """Level-boundary snapshot of the full per-shard device state
         (SURVEY.md §2.2-E8 on the device-resident sharded engine:
@@ -1596,7 +1674,7 @@ class ShardedDeviceChecker:
             "n_keys": jax.device_put(nkeys.astype(np.int32), sh),
             "dead": self._dev_fill((N,), int(BIG), jnp.int32),
             "viol": self._dev_fill((N, n_inv), int(BIG), jnp.int32),
-            "ovf": self._dev_fill((N,), 0, jnp.bool_),
+            "rt": self._dev_fill((N, RT_N), 0, jnp.int32),
             "fpm": self._dev_fill((N, FPM_N), 0, jnp.int32),
         }
         if self.visited_impl == "fpset":
@@ -1697,10 +1775,10 @@ class ShardedDeviceChecker:
         rows = self._dev_fill((N, self.LCAP * self.W), 0, jnp.uint32)
         zq = self._dev_fill((N,), 0, jnp.int32)
         dead = self._dev_fill((N,), int(BIG), jnp.int32)
-        ovf = self._dev_fill((N,), 0, jnp.bool_)
+        rt = self._dev_fill((N, RT_N), 0, jnp.int32)
         out = self._round_jit()(
             bufs["ak"], bufs["arows"], bufs["apar"], bufs["alane"],
-            bufs["aq"], bufs["aq2"], rows, zq, zq, dead, ovf,
+            bufs["aq"], bufs["aq2"], rows, zq, zq, dead, rt,
             jnp.int32(0), jnp.int32(0),
         )
         jax.block_until_ready(out)
@@ -1757,7 +1835,7 @@ class ShardedDeviceChecker:
         )
         bufs["parent"] = self._dev_fill((N, self.LCAP), 0, jnp.int32)
         bufs["lane"] = self._dev_fill((N, self.LCAP), 0, jnp.int32)
-        ovf = self._dev_fill((N,), 0, jnp.bool_)
+        rt = self._dev_fill((N, RT_N), 0, jnp.int32)
         dead = self._dev_fill((N,), int(BIG), jnp.int32)
         viol = self._dev_fill((N, n_inv), int(BIG), jnp.int32)
         nvis = self._dev_fill((N,), 0, jnp.int32)
@@ -1766,13 +1844,13 @@ class ShardedDeviceChecker:
         mark("alloc")
         out = self._init_round_jit()(
             bufs["ak"], bufs["arows"], bufs["apar"], bufs["alane"],
-            bufs["aq"], bufs["aq2"], ovf, jnp.int32(0), jnp.int32(0),
+            bufs["aq"], bufs["aq2"], rt, jnp.int32(0), jnp.int32(0),
         )
         drain(out)
         bufs["ak"] = tuple(out[0])
         (
             bufs["arows"], bufs["apar"], bufs["alane"], bufs["aq"],
-            bufs["aq2"], ovf,
+            bufs["aq2"], rt,
         ) = out[1:]
         mark("initround")
         zq = jax.device_put(
@@ -1780,14 +1858,14 @@ class ShardedDeviceChecker:
         )
         out = self._round_jit()(
             bufs["ak"], bufs["arows"], bufs["apar"], bufs["alane"],
-            bufs["aq"], bufs["aq2"], bufs["rows"], zq, zq, dead, ovf,
+            bufs["aq"], bufs["aq2"], bufs["rows"], zq, zq, dead, rt,
             jnp.int32(0), jnp.int32(0),
         )
         drain(out)
         bufs["ak"] = tuple(out[0])
         (
             bufs["arows"], bufs["apar"], bufs["alane"], bufs["aq"],
-            bufs["aq2"], dead, ovf,
+            bufs["aq2"], dead, rt,
         ) = out[1:]
         mark("round")
         out = self._flush_jit()(
@@ -1810,7 +1888,7 @@ class ShardedDeviceChecker:
         )
         drain(app)
         mark("append")
-        drain(self._stats_jit()(nvis, nkeys, dead, viol, ovf, fpm))
+        drain(self._stats_jit()(nvis, nkeys, dead, viol, rt, fpm))
         mark("misc")
         if seed_states:
             # precompile the host-seed loader's programs at the shape
@@ -1834,7 +1912,7 @@ class ShardedDeviceChecker:
             )
             del spar, slane
             out = self._seed_round_jit(SRC)(
-                bufs["ak"], bufs["aq"], bufs["aq2"], ovf, srows,
+                bufs["ak"], bufs["aq"], bufs["aq2"], rt, srows,
                 nloc, jnp.int32(0), jnp.int32(0),
             )
             drain(out)
@@ -1851,11 +1929,22 @@ class ShardedDeviceChecker:
         ``(packed_rows, parent_gids, action_lanes, level_sizes)`` —
         the warm start that removed half the single-chip engine's wall
         clock (VERDICT r4 #4 asked for it on this engine too)."""
-        rid = obs.new_run_id()
+        # this run's exclusive host phases, and the compile meter's
+        # reading before it (obs/spans.py)
+        clock = self._clock = spans.PhaseClock(obs.new_run_id())
+        self._jit0 = spans.compile_meter().snapshot()
+        with spans.span("run", run_id=clock.run_id):
+            return self._run_spanned(resume, seed)
+
+    def _run_spanned(self, resume: bool, seed) -> CheckerResult:
+        rid = self._clock.run_id
         self.tel = obs.as_telemetry(self._telemetry_arg, run_id=rid)
-        self._run_id = self.tel.run_id or rid
+        self._run_id = self._clock.run_id = self.tel.run_id or rid
         self._snap = {"distinct_states": 0}
         self._fetch_n = 0
+        self._dispatch_n = 0
+        self._route_rounds = {}
+        self._route_overflows = 0
         # per-run recovery/frame state: a fresh run() must not inherit
         # a previous run's degraded capacity or frame counts
         self.rec.reset()
@@ -1960,6 +2049,17 @@ class ShardedDeviceChecker:
         self.tel.emit("run_header", **f)
 
     def _run(self, resume: bool, seed) -> CheckerResult:
+        with self._clock.phase("init"):
+            frame = self._start(resume, seed)
+        # everything of the level loop that is no phase of its own
+        # (telemetry emits, the log line, fault polls, the host's
+        # bounds arithmetic) is ``account``
+        with self._clock.phase("account"):
+            return self._run_levels(*frame)
+
+    def _start(self, resume: bool, seed):
+        """Fresh, seeded or restored device state up to the first level
+        boundary: the arguments of :meth:`_run_levels`."""
         t0 = time.time()
         # the time budget always gets a fresh clock on resume (t0 is
         # rewound below so wall_s stays cumulative; without a separate
@@ -1977,9 +2077,8 @@ class ShardedDeviceChecker:
             ) = self._restore(d)
             t0 = time.time() - saved_wall
             self.rec.arm()  # the on-disk frame is valid
-            self._host_wait_s = 0.0
             self._emit_header(resume=True)
-            return self._run_levels(t0, bufs, st, level_sizes, lb, nf)
+            return t0, bufs, st, level_sizes, lb, nf
         bufs = {
             "vk": tuple(
                 self._dev_fill(
@@ -1999,10 +2098,9 @@ class ShardedDeviceChecker:
             "n_keys": self._dev_fill((N,), 0, jnp.int32),
             "dead": self._dev_fill((N,), int(BIG), jnp.int32),
             "viol": self._dev_fill((N, n_inv), int(BIG), jnp.int32),
-            "ovf": self._dev_fill((N,), 0, jnp.bool_),
+            "rt": self._dev_fill((N, RT_N), 0, jnp.int32),
             "fpm": self._dev_fill((N, FPM_N), 0, jnp.int32),
         }
-        self._host_wait_s = 0.0
         self._emit_header(resume=False)
 
         if seed is not None:
@@ -2023,9 +2121,7 @@ class ShardedDeviceChecker:
                     if i < cum:
                         level_sizes = level_sizes[: li + 1]
                         break
-            return self._run_levels(
-                t0, bufs, st, level_sizes, lb, nf, stats=stats
-            )
+            return t0, bufs, st, level_sizes, lb, nf, stats
 
         # ---- level 1: initial states (keys to owners, rows local) ----
         # level-1 fault site: the level loop's poll counts start at 2,
@@ -2042,15 +2138,16 @@ class ShardedDeviceChecker:
                 per_round = N * self.NCs
                 w = 0
                 for base in range(0, n_init, per_round):
-                    out = self._init_round_jit()(
-                        bufs["ak"], bufs["arows"], bufs["apar"],
-                        bufs["alane"], bufs["aq"], bufs["aq2"],
-                        st["ovf"], jnp.int32(base), jnp.int32(w),
-                    )
+                    with self._clock.phase("dispatch", level=1):
+                        out = self._init_round_jit()(
+                            bufs["ak"], bufs["arows"], bufs["apar"],
+                            bufs["alane"], bufs["aq"], bufs["aq2"],
+                            st["rt"], jnp.int32(base), jnp.int32(w),
+                        )
                     bufs["ak"] = tuple(out[0])
                     (
                         bufs["arows"], bufs["apar"], bufs["alane"],
-                        bufs["aq"], bufs["aq2"], st["ovf"],
+                        bufs["aq"], bufs["aq2"], st["rt"],
                     ) = out[1:]
                     w += 1
                     if w == self.FLUSH or base + per_round >= n_init:
@@ -2083,32 +2180,30 @@ class ShardedDeviceChecker:
         # per-shard level-1 counts: LivenessChecker's dense gid remap
         # needs to place exactly the initial states first
         self.last_level1_counts = nv.copy()
-        return self._run_levels(
-            t0, bufs, st, level_sizes, lb, nf, stats=stats
-        )
+        return t0, bufs, st, level_sizes, lb, nf, stats
 
     def _fetch(self, st):
         """Stats matrix columns: 0 = per-shard producer-local state
         count, 1 = per-shard owned-key count, 2 = deadlock gid, 3.. =
-        per-invariant violation gids, then the routing-overflow flag
-        and the per-shard fpset metrics [flushes, probe rounds,
-        failures, valid lanes, max probe rounds] (zeros in sort
-        mode)."""
-        tf = time.time()
-        out = np.asarray(
-            self._stats_jit()(
-                st["n_visited"], st["n_keys"], st["dead"], st["viol"],
-                st["ovf"], st["fpm"],
+        per-invariant violation gids, then the route state (the
+        routing-overflow flag, the lanes sent as LO and HI words) and
+        the per-shard fpset metrics [flushes, probe rounds, failures,
+        valid lanes, max probe rounds] (zeros in sort mode)."""
+        with self._clock.phase("fetch"):
+            out = np.asarray(
+                self._stats_jit()(
+                    st["n_visited"], st["n_keys"], st["dead"],
+                    st["viol"], st["rt"], st["fpm"],
+                )
             )
-        )
-        self._host_wait_s += time.time() - tf
         self._fetch_n += 1
         n_inv = len(self.invariant_names)
         nv = int(out[:, 0].sum())
         self._snap["distinct_states"] = nv
         if out[:, 3 + n_inv].any():
             raise _RouteOverflow
-        self._last_fpm = out[:, 4 + n_inv: 4 + n_inv + FPM_N]
+        f0 = 3 + n_inv + RT_N
+        self._last_fpm = out[:, f0: f0 + FPM_N]
         if self.visited_impl == "fpset":
             self._snap["occupancy"] = float(out[:, 1].max()) / max(
                 self.TCAP, 1
@@ -2186,6 +2281,7 @@ class ShardedDeviceChecker:
             "compact", dispatches=d, impl=self.compact_impl
         )
 
+    @spans.in_phase("dispatch")
     def _flush(self, bufs, st, n_acc: int):
         # deterministic fault site (utils/faults.py): oom@flush:N hits
         # the sharded fpset flush — raised BEFORE the dispatch mutates
@@ -2230,6 +2326,7 @@ class ShardedDeviceChecker:
             n_new, st["n_visited"], st["viol"],
         )
 
+    @spans.in_phase("grow")
     def _grow_route(self, bufs, st):
         """Auto-recover from a routing overflow (VERDICT r3 #8): double
         ``route_slack``, re-derive every route-capacity-dependent size,
@@ -2239,6 +2336,7 @@ class ShardedDeviceChecker:
         state appended by the partial attempt deduplicates to a no-op,
         so counts stay exact (the overflow itself only ever DROPPED
         candidates, never corrupted the visited set)."""
+        self._route_overflows += 1
         self.route_slack *= 2.0
         self._calc_route()
         if self.ACAP * self.W >= 1 << 31:
@@ -2248,7 +2346,7 @@ class ShardedDeviceChecker:
             )
         self._jits.clear()
         self._alloc_acc(bufs)
-        st["ovf"] = self._dev_fill((self.N,), 0, jnp.bool_)
+        st["rt"] = st["rt"].at[:, 0].set(0)
         self._log(
             f"routing overflow: retrying with route_slack="
             f"{self.route_slack} (ACAP={self.ACAP})"
@@ -2320,7 +2418,7 @@ class ShardedDeviceChecker:
         totals — the per-shard stats matrix is gone (poisoned or never
         fetched), so a minimal one carries the mesh total."""
         n_inv = len(self.invariant_names)
-        stats = np.zeros((self.N, 4 + n_inv + FPM_N), np.int64)
+        stats = np.zeros((self.N, 3 + n_inv + RT_N + FPM_N), np.int64)
         stats[:, 2] = int(BIG)
         stats[:, 3: 3 + n_inv] = int(BIG)
         stats[0, 0] = nv
@@ -2383,7 +2481,8 @@ class ShardedDeviceChecker:
                         "level", len(level_sizes) + 1
                     )
                 stats, nv2, stop = self._run_one_level(
-                    t0, bufs, st, stats, nv, lb, nf
+                    t0, bufs, st, stats, nv, lb, nf,
+                    len(level_sizes) + 1,
                 )
             except _RouteOverflow:
                 self._grow_route(bufs, st)
@@ -2419,6 +2518,7 @@ class ShardedDeviceChecker:
                     f"level {len(level_sizes)}: +{level_count} "
                     f"(total {total}, {total/max(wall,1e-9):.0f} st/s)"
                 )
+                self._clock.level_boundary(len(level_sizes))
             if stop:
                 reason = self._stop_reason(stats, t0) or {
                     "truncated": True
@@ -2453,8 +2553,8 @@ class ShardedDeviceChecker:
             self._log(f"      {tag}: +{now - tref[0]:.2f}s")
             tref[0] = now
 
-    def _run_one_level(self, t0, bufs, st, stats, nv, lb, nf):
-        """Expand one full level; returns (stats, nv2, stop)."""
+    def _run_one_level(self, t0, bufs, st, stats, nv, lb, nf, level):
+        """Expand level ``level``; returns (stats, nv2, stop)."""
         tref = [time.time()]
         self._grow_store(bufs, int((lb + nf).max()) + self.G)
         self._dbg("grow", tref)
@@ -2476,16 +2576,17 @@ class ShardedDeviceChecker:
         nk_bound = stats[:, 1].max()
         for r in range(rounds):
             last = r + 1 >= rounds
-            out = self._round_jit()(
-                bufs["ak"], bufs["arows"], bufs["apar"],
-                bufs["alane"], bufs["aq"], bufs["aq2"], bufs["rows"],
-                lb_dev, nf_dev, st["dead"], st["ovf"], jnp.int32(r),
-                jnp.int32(w),
-            )
+            with self._clock.phase("dispatch", level=level):
+                out = self._round_jit()(
+                    bufs["ak"], bufs["arows"], bufs["apar"],
+                    bufs["alane"], bufs["aq"], bufs["aq2"],
+                    bufs["rows"], lb_dev, nf_dev, st["dead"], st["rt"],
+                    jnp.int32(r), jnp.int32(w),
+                )
             bufs["ak"] = tuple(out[0])
             (
                 bufs["arows"], bufs["apar"], bufs["alane"],
-                bufs["aq"], bufs["aq2"], st["dead"], st["ovf"],
+                bufs["aq"], bufs["aq2"], st["dead"], st["rt"],
             ) = out[1:]
             self._dbg(f"round {r} dispatch", tref)
             w += 1
@@ -2631,6 +2732,7 @@ class ShardedDeviceChecker:
 
     # ------------------------------------------------------------- trace
 
+    @spans.in_phase("trace_walk")
     def _trace(self, bufs, gid: int, max_depth: int):
         """Walk the cross-shard parent chain on the host (per-hop fetch
         of two scalars; traces are rare and shallow), then replay lanes
@@ -2661,6 +2763,7 @@ class ShardedDeviceChecker:
 
     # ------------------------------------------------------------ result
 
+    @spans.in_phase("result")
     def _result(
         self, t0, stats, level_sizes, bufs,
         viol: Optional[Tuple[str, int]] = None,
@@ -2709,15 +2812,32 @@ class ShardedDeviceChecker:
                         lr / vl, 4
                     ) if vl else None,
                 )
+        # what exists only across shards: the key exchange and the
+        # owner map's skew.  Lanes are counted on the device and ride
+        # the stats matrix; rounds, capacity and retries are the
+        # host's own (it dispatches every exchange)
+        r0 = 3 + len(self.invariant_names) + 1  # past the overflow flag
+        sent = stats[:, r0: r0 + 2].astype(np.int64)
+
+        def imbalance(col):
+            a = stats[:, col].astype(np.float64)
+            return round(
+                100.0 * (a.max() / a.mean() - 1.0), 4
+            ) if a.sum() else 0.0
+
         self.last_stats.update(
-            compact_impl=self.compact_impl,
-            hbm_recovered=self._hbm_recovered,
-            ckpt_frames=self._ckpt_frames,
-            ckpt_bytes=self._ckpt_bytes,
-            ckpt_write_s=round(self._ckpt_write_s, 3),
-            ckpt_retries=self._ckpt_retries,
-            host_wait_s=round(self._host_wait_s, 3),
-            stats_fetches=self._fetch_n,
+            route_lanes=int(sum(fpset.u64(lo, hi) for lo, hi in sent)),
+            route_rounds=sum(self._route_rounds.values()),
+            route_capacity_lanes=self.route_cap,
+            route_rounds_by_capacity={
+                str(cap): n for cap, n in self._route_rounds.items()
+            },
+            route_overflows=self._route_overflows,
+            # keys owned (the owner map) and states stored, which are
+            # the states a shard expands: discovery stays on the
+            # producing shard, so one initial state leaves one producer
+            shard_imbalance_pct=imbalance(1),
+            producer_imbalance_pct=imbalance(0),
         )
         res = CheckerResult(
             distinct_states=nv,
@@ -2752,6 +2872,26 @@ class ShardedDeviceChecker:
                 res.trace, res.trace_actions = self._trace(
                     bufs, gid, len(level_sizes) + 2
                 )
+        # host phases and the compile meter (obs/spans.py), taken at the
+        # emit, the last thing a run does: host_<phase>_s sum with
+        # host_unaccounted_s to the wall of run(); host_wait_s keeps its
+        # key and is the fetch phase; jit_* is an orthogonal cut
+        phases = self._clock.stats()
+        self.last_stats.update(
+            phases,
+            **spans.compile_meter().since(self._jit0),
+            compact_impl=self.compact_impl,
+            hbm_recovered=self._hbm_recovered,
+            ckpt_frames=self._ckpt_frames,
+            ckpt_bytes=self._ckpt_bytes,
+            ckpt_write_s=round(self._ckpt_write_s, 3),
+            ckpt_retries=self._ckpt_retries,
+            host_wait_s=phases["host_fetch_s"],
+            stats_fetches=self._fetch_n,
+            dispatches_per_level=round(
+                self._dispatch_n / max(len(level_sizes), 1), 2
+            ),
+        )
         self.tel.emit(
             "result",
             distinct_states=nv,

@@ -114,6 +114,23 @@ class _Phase:
         return False
 
 
+def in_phase(name: str):
+    """Method decorator: run under the exclusive host phase ``name`` of
+    the current run's clock (``self._clock``, a ``PhaseClock``) — a
+    site the host passes once per dispatch, fetch or boundary, never
+    per row."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *args, **kwargs):
+            with self._clock.phase(name):
+                return fn(self, *args, **kwargs)
+
+        return wrapped
+
+    return deco
+
+
 class PhaseClock:
     """Exclusive per-phase seconds of one run, on the monotonic clock.
 

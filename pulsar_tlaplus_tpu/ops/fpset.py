@@ -97,7 +97,7 @@ FPM_N = 8
 FPM_LOGICAL_N = 6
 
 
-def _add_u32(lo_word, hi_word, n):
+def add_u32(lo_word, hi_word, n):
     """``n`` (non-negative, < 2^32) added to a hi/lo uint32 counter
     held as int32 bit patterns (bitcast, never a value conversion)."""
     lo = lax.bitcast_convert_type(lo_word, jnp.uint32)
@@ -106,7 +106,7 @@ def _add_u32(lo_word, hi_word, n):
     return lax.bitcast_convert_type(new_lo, jnp.int32), hi_word + carry
 
 
-def _u64(lo_word, hi_word):
+def u64(lo_word, hi_word):
     """Host-side reassembly of a hi/lo uint32 counter (numpy int64
     scalars holding the fetched int32 bit patterns)."""
     import numpy as np
@@ -122,8 +122,8 @@ def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds):
     with uint32 wraparound and the carry lands in the HI words, so
     1B-state runs report honest duplicate ratios instead of a wrapped
     counter."""
-    valid_lo, valid_hi = _add_u32(fpm[3], fpm[5], n_valid)
-    lanes_lo, lanes_hi = _add_u32(fpm[6], fpm[7], lane_rounds)
+    valid_lo, valid_hi = add_u32(fpm[3], fpm[5], n_valid)
+    lanes_lo, lanes_hi = add_u32(fpm[6], fpm[7], lane_rounds)
     return jnp.stack(
         [
             fpm[0] + 1,
@@ -152,7 +152,7 @@ def fpm_logical(vec):
     v = np.zeros((FPM_N,), np.int64)
     v[: min(len(a), FPM_N)] = a[:FPM_N]
     return np.array(
-        [v[0], v[1], v[2], _u64(v[3], v[5]), v[4], _u64(v[6], v[7])],
+        [v[0], v[1], v[2], u64(v[3], v[5]), v[4], u64(v[6], v[7])],
         np.int64,
     )
 
@@ -199,8 +199,8 @@ def wkm_update(wkm, rows, lanes, elems, appended, groups):
     ``elems`` accumulate into uint32 lo words with the carry landing in
     the hi words (bitcast storage, the :func:`fpm_update` pattern) so
     1B-state runs report honest work totals instead of wrapped ones."""
-    lanes_lo, lanes_hi = _add_u32(wkm[1], wkm[5], lanes)
-    elems_lo, elems_hi = _add_u32(wkm[2], wkm[6], elems)
+    lanes_lo, lanes_hi = add_u32(wkm[1], wkm[5], lanes)
+    elems_lo, elems_hi = add_u32(wkm[2], wkm[6], elems)
     return jnp.stack(
         [
             wkm[0] + rows,
@@ -224,7 +224,7 @@ def wkm_logical(vec):
     v = np.zeros((WKM_N,), np.int64)
     v[: min(len(a), WKM_N)] = a[:WKM_N]
     return np.array(
-        [v[0], _u64(v[1], v[5]), _u64(v[2], v[6]), v[3], v[4]], np.int64
+        [v[0], u64(v[1], v[5]), u64(v[2], v[6]), v[3], v[4]], np.int64
     )
 
 
