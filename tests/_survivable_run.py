@@ -53,11 +53,11 @@ def main():
     from pulsar_tlaplus_tpu.utils.device import setup_compile_cache
 
     jax.config.update("jax_platforms", "cpu")
-    # share the suite's persistent compile cache (tests/conftest.py):
+    # share the suite's persistent compile cache (tests/conftest.py,
+    # whose compile-time threshold arrives through the environment):
     # drill subprocesses otherwise pay the full cold compile of the
     # engine programs on every single drill
     setup_compile_cache()
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
     from pulsar_tlaplus_tpu.models.compaction import CompactionModel
     from pulsar_tlaplus_tpu.ref import pyeval as pe

@@ -1,14 +1,18 @@
 """Start-up and device plumbing (PR 23 bring-up): the one compile-cache
-helper, the Pallas interpret rule, ``-workers N`` with too few devices,
+helper and what it does to a second process (PR 30), the Pallas
+interpret rule, ``-workers N`` with too few devices,
 and the source-hash native build."""
 
 import os
 import shutil
+import subprocess
+import sys
 
 import jax
 import pytest
 
 from pulsar_tlaplus_tpu import native
+from pulsar_tlaplus_tpu.obs import report
 from pulsar_tlaplus_tpu.ops import tiles
 from pulsar_tlaplus_tpu.utils import device
 
@@ -25,12 +29,22 @@ def config_updates(monkeypatch):
     return calls
 
 
+# what ``setup_compile_cache`` sets whichever way the directory comes:
+# both of JAX's write thresholds are taken away
+KEEPS_EVERY_PROGRAM = [
+    ("jax_persistent_cache_min_compile_time_secs", 0.0),
+    ("jax_persistent_cache_min_entry_size_bytes", -1),
+]
+THRESHOLD_ENV = "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"
+
+
 def test_compile_cache_env_wins(monkeypatch, config_updates):
     """``JAX_COMPILATION_CACHE_DIR`` from outside is left to JAX: no
-    directory is set in code."""
+    directory is set in code, the thresholds are."""
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    monkeypatch.delenv(THRESHOLD_ENV)  # the suite's own, conftest.py
     assert device.setup_compile_cache() == "/some/dir"
-    assert config_updates == []
+    assert config_updates == KEEPS_EVERY_PROGRAM
 
 
 def test_compile_cache_default_is_under_checkout(
@@ -39,10 +53,70 @@ def test_compile_cache_default_is_under_checkout(
     """Unset: ``<checkout>/.jax_cache`` whatever the working
     directory."""
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv(THRESHOLD_ENV)
     monkeypatch.chdir(tmp_path)
     want = os.path.join(CHECKOUT, ".jax_cache")
     assert device.setup_compile_cache() == want
-    assert config_updates == [("jax_compilation_cache_dir", want)]
+    assert config_updates == KEEPS_EVERY_PROGRAM + [
+        ("jax_compilation_cache_dir", want)
+    ]
+
+
+def test_compile_cache_threshold_from_outside_is_left_alone(
+    monkeypatch, config_updates
+):
+    """A threshold in the environment wins as the directory does: JAX
+    reads it itself."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    monkeypatch.setenv(THRESHOLD_ENV, "2.5")
+    device.setup_compile_cache()
+    assert config_updates == KEEPS_EVERY_PROGRAM[1:]
+
+
+def _check_in_a_new_process(cache_dir, telemetry):
+    """``cli check`` of the shipped binding with the leak invariant in
+    a process of its own, as a user's shell would start it: the cache
+    directory from outside, the thresholds the helper's."""
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", JAX_COMPILATION_CACHE_DIR=cache_dir
+    )
+    del env[THRESHOLD_ENV]
+    spec = os.path.join(CHECKOUT, "specs", "compaction.tla")
+    proc = subprocess.run(
+        [
+            sys.executable, "-m", "pulsar_tlaplus_tpu.cli", "check", spec,
+            "-config", os.path.join(CHECKOUT, "specs", "compaction.cfg"),
+            "-invariant", "CompactedLedgerLeak", "-telemetry", telemetry,
+        ],
+        capture_output=True, text=True, timeout=180, env=env, cwd=CHECKOUT,
+    )
+    assert proc.returncode == 1, proc.stderr  # the counterexample
+    events, errors = report.load_events(telemetry)
+    assert not errors, errors
+    # all but the last line, which says how long the check took
+    return report.result(events), proc.stdout.rsplit("\n", 2)[0]
+
+
+def test_second_process_compiles_nothing(tmp_path):
+    """The effect: with every executable kept, the same check in a
+    second process makes no backend compile; each program the first
+    compiled is a cache hit, and verdict, counts and the printed trace
+    are the first's."""
+    cache_dir = str(tmp_path / "cache")
+    first, out1 = _check_in_a_new_process(cache_dir, str(tmp_path / "1.jsonl"))
+    second, out2 = _check_in_a_new_process(
+        cache_dir, str(tmp_path / "2.jsonl")
+    )
+    compiled = first["stats"]["jit_backend_compiles"]
+    assert compiled > 0 and first["stats"]["jit_cache_hits"] == 0
+    assert second["stats"]["jit_backend_compiles"] == 0
+    assert second["stats"]["jit_cache_misses"] == 0
+    assert second["stats"]["jit_cache_hits"] == compiled
+    assert second["stats"]["jit_traces"] == first["stats"]["jit_traces"]
+    for k in ("distinct_states", "diameter", "truncated"):
+        assert second[k] == first[k], k
+    assert (second["distinct_states"], second["diameter"]) == (25515, 12)
+    assert "CompactedLedgerLeak" in out1 and out2 == out1
 
 
 def test_pallas_interpret_follows_backend(monkeypatch):
