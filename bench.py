@@ -37,6 +37,8 @@ import re
 import sys
 import time
 
+from pulsar_tlaplus_tpu.obs.telemetry import IMPL_FIELDS
+
 BENCH_BUDGET_S = 150.0
 BASELINE_SLICE_S = 30.0
 # sentinel: resolved after parse to
@@ -286,9 +288,8 @@ def artifact_skeleton() -> dict:
         # fleet survivability latencies (r21, bench_schema 11): null
         # on non-fleet runs and on drills that saw no drain/rejoin
         "fleet_failover_ms", "fleet_reconcile_ms",
-        # dense-tile kernel selection (r23, bench_schema 12): the impl
-        # knobs the run executed under + the flush-stage throughput
-        # the tiles ledger gate watches (higher is better)
+        # bench_schema 12 (r23): the kernel fields (constants now:
+        # obs/telemetry.IMPL_FIELDS) + the flush-stage throughput
         "probe_impl", "expand_impl", "sieve_impl",
         "probe_lanes_per_sec",
     )
@@ -517,8 +518,8 @@ def run_matrix(args) -> None:
             mode="check",
             vs_baseline_definition="none (matrix point)",
             engine="device_bfs (matrix point)",
-            visited_impl="fpset",
-            compact_impl="logshift",
+            visited_impl=IMPL_FIELDS["visited_impl"],
+            compact_impl=IMPL_FIELDS["compact_impl"],
             fuse=ck.fuse,
             matrix_spec=spec,
             matrix_axis=axis,
@@ -827,43 +828,10 @@ def parse_args(argv=None):
         help="device-run time budget in seconds",
     )
     ap.add_argument(
-        "--visited", choices=["fpset", "sort"], default="fpset",
-        help="visited-set implementation: fpset (HBM hash-table FPSet, "
-        "default) or sort (legacy sort-merge flush, kept for "
-        "differential timing)",
-    )
-    ap.add_argument(
-        "--compact", choices=["logshift", "sort"], default="logshift",
-        help="stream-compaction implementation on the append hot path: "
-        "logshift (sort-free prefix-sum + doubling shifts, default) "
-        "or sort (the round-4 chunked single-key sorts, kept for "
-        "differential timing)",
-    )
-    ap.add_argument(
-        "--probe-impl", dest="probe_impl",
-        choices=["legacy", "tile"], default="legacy",
-        help="fpset flush probe kernel (r23, ops/tiles.py): legacy "
-        "(dense rounds in flush_acc, default) or tile (membership "
-        "prefilter + chunked insert).  Both exact — same discovery",
-    )
-    ap.add_argument(
-        "--expand-impl", dest="expand_impl",
-        choices=["legacy", "tile", "pallas"], default="legacy",
-        help="successor-sweep structure (r23): legacy (per-window "
-        "scan), tile (flat row sweep + full-matrix key plane) or "
-        "pallas (key plane as a Pallas kernel)",
-    )
-    ap.add_argument(
-        "--sieve-impl", dest="sieve_impl",
-        choices=["legacy", "tile", "pallas"], default="legacy",
-        help="cold-extract kernel on the eviction path (r23): legacy "
-        "(compact+mask+sort), tile (mask-in-place + sort) or pallas",
-    )
-    ap.add_argument(
         "--fuse", choices=["level", "stage"], default="level",
         help="dispatch fusion: level (one fused megakernel dispatch "
         "per BFS level, ramp levels batched — default) or stage (the "
-        "r10 per-stage dispatch chain, kept for differential timing)",
+        "per-stage dispatch chain)",
     )
     ap.add_argument(
         "--fuse-group", dest="fuse_group", type=int, default=None,
@@ -1037,13 +1005,6 @@ def main(argv=None):
         user_set = set()
         if args.fuse_group is not None:
             user_set.add("fuse_group")
-        if args.compact != "logshift":
-            user_set.add("compact_impl")
-        # dense-tile kernel knobs (r23): an explicit impl flag wins
-        # over the tuned profile, mirroring --compact
-        for flag in ("probe_impl", "expand_impl", "sieve_impl"):
-            if getattr(args, flag) != "legacy":
-                user_set.add(flag)
         for k, v in sorted(pk.items()):
             if k == "adapt" or k in user_set:
                 continue
@@ -1073,11 +1034,6 @@ def main(argv=None):
         time_budget_s=args.budget_s,
         progress=True,
         metrics_path=metrics_path,
-        visited_impl=args.visited,
-        compact_impl=kw.pop("compact_impl", args.compact),
-        probe_impl=kw.pop("probe_impl", args.probe_impl),
-        expand_impl=kw.pop("expand_impl", args.expand_impl),
-        sieve_impl=kw.pop("sieve_impl", args.sieve_impl),
         fuse=args.fuse,
         fuse_group=kw.pop("fuse_group", args.fuse_group),
         hbm_budget=args.hbm_budget,
@@ -1249,11 +1205,9 @@ def _emit(args, ck, c, r, compile_s, metrics_path):
                 # schema 11 (r21) adds the fleet survivability
                 # latencies (fleet_failover_ms, fleet_reconcile_ms —
                 # null on solo runs and on drills without a
-                # drain/rejoin); schema 12 (r23) adds the dense-tile
-                # kernel selection (probe_impl, expand_impl,
-                # sieve_impl — the impls that actually ran) and
-                # probe_lanes_per_sec, the flush-stage throughput the
-                # tiles ledger gate watches
+                # drain/rejoin); schema 12 (r23) adds probe_impl,
+                # expand_impl, sieve_impl and probe_lanes_per_sec,
+                # the flush-stage throughput
                 "bench_schema": 12,
                 "mode": "check",
                 "walks_per_sec": None,
@@ -1321,18 +1275,11 @@ def _emit(args, ck, c, r, compile_s, metrics_path):
                     round(host_wait, 2) if host_wait is not None else None
                 ),
                 "fp_collision_prob": r.fp_collision_prob,
-                "visited_impl": args.visited,
-                # stream-compaction impl on the append hot path (r10:
-                # logshift default; sort kept for differential timing)
-                "compact_impl": args.compact,
-                # dense-tile kernel selection (r23, bench_schema 12):
-                # ck.*, not args.*: a tuned profile may have picked
-                # the impl, and the artifact must report what ran.
-                # probe_lanes_per_sec is the flush-stage throughput
-                # the tiles ledger gate watches (higher is better)
-                "probe_impl": ck.probe_impl,
-                "expand_impl": ck.expand_impl,
-                "sieve_impl": ck.sieve_impl,
+                # visited_impl, compact_impl, probe_impl, expand_impl,
+                # sieve_impl: one value each since there is one
+                # implementation of each stage
+                **IMPL_FIELDS,
+                # the flush-stage throughput (higher is better)
                 "probe_lanes_per_sec": (
                     round(stat("work_probe_lanes") / r.wall_s, 1)
                     if stat("work_probe_lanes") and r.wall_s > 0
@@ -1340,10 +1287,7 @@ def _emit(args, ck, c, r, compile_s, metrics_path):
                 ),
                 # level fusion (r13): the megakernel's dispatch
                 # economy — total dispatches per BFS level, fused
-                # dispatches, and levels the ramp batched.  ck.fuse,
-                # not args.fuse: the engine silently falls back to the
-                # stage chain under --visited sort, and the artifact
-                # must report the mode that actually ran
+                # dispatches, and levels the ramp batched
                 "fuse": ck.fuse,
                 # tuned-profile attribution (r15): null on untuned
                 # runs — lets `ledger compare/gate` split tuned vs
@@ -1400,9 +1344,9 @@ def _emit(args, ck, c, r, compile_s, metrics_path):
                     "hash-table visited set, frontier-window row "
                     "store, flush_factor=3, "
                     "64-bit fingerprints)"
-                    if args.visited == "fpset" and args.fuse == "level"
-                    else "device_bfs r10-compat (--fuse stage / "
-                    "--visited sort: per-stage dispatch chain)"
+                    if args.fuse == "level"
+                    else "device_bfs r10-compat (--fuse stage: "
+                    "per-stage dispatch chain)"
                 ),
             }
         )

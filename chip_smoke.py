@@ -19,10 +19,7 @@ references.  One process; nothing here sets ``JAX_PLATFORMS``.  Phases:
                   runs it, ``max_states`` cut so the run stops inside
                   level 7: level 6 must be +17,150,616 (17,787,334
                   cumulative — native-checker ground truth, BASELINE.md)
-                  on the default kernels with no fallback taken;
-- ``kernels``     every Pallas variant still selectable compiled
-                  natively (no interpreter) at the flagship lane width
-                  and compared bit-for-bit with the legacy kernel;
+                  with no fallback taken;
 - ``sharded``     with >= 4 devices, ``cli check -workers 4`` on the
                   producer-on config = 253,361 states / diameter 23;
                   with fewer, a named skip.
@@ -31,7 +28,7 @@ Times, peak memory and states reached are printed as observations of
 this run, not as metrics.  Exit code 0 and a last stdout line
 ``{"ok": true, "device": {...}}`` only when every phase passed.
 
-    python3 chip_smoke.py [--seed N] [--max-states N]
+    python3 chip_smoke.py [--max-states N]
 """
 
 from __future__ import annotations
@@ -54,9 +51,6 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # "Level-size ground truth"): the host seed covers levels 1-5
 SEED_STATES, SEED_LEVELS = 636_718, 5
 LEVEL6_NEW, LEVEL6_CUM = 17_150_616, 17_787_334
-# the kernels phase's sieve table: 2^27 slots, the tier the flagship
-# phase's run holds
-SIEVE_CAP_LOG2 = 27
 
 
 class Failed(Exception):
@@ -201,14 +195,6 @@ def phase_flagship(jax, max_states):
         f"{model.A} lanes, sub_batch={ck.G}, table 2^"
         f"{ck.TCAP.bit_length() - 1} slots, max_states={max_states}",
     )
-    impls = (
-        ck.visited_impl, ck.compact_impl, ck.fuse,
-        ck.probe_impl, ck.expand_impl, ck.sieve_impl,
-    )
-    check(
-        impls == ("fpset", "logshift", "level") + ("legacy",) * 3,
-        f"defaults changed: {impls}",
-    )
     # bench.py's set-up: the Python oracle enumerates the narrow early
     # levels on a thread while the device programs compile
     box = {}
@@ -273,83 +259,6 @@ def phase_flagship(jax, max_states):
     check(r.violation is None and not r.deadlock, "unexpected violation")
 
 
-def phase_kernels(jax, seed):
-    import jax.numpy as jnp
-    from jax import random
-
-    import bench
-    from pulsar_tlaplus_tpu.models.compaction import CompactionModel
-    from pulsar_tlaplus_tpu.ops import fpset, tiles
-    from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, KeySpec
-    from pulsar_tlaplus_tpu.store import sieve as store_sieve
-
-    check(
-        not tiles.interpret(),
-        "Pallas would run interpreted on this backend",
-    )
-    live = [k for k, impls in tiles.IMPLS.items() if "pallas" in impls]
-    say("kernels", f"pallas variants selectable: {live or 'none'}")
-    key = random.PRNGKey(seed)
-
-    def same(a, b):
-        return all(
-            bool(jnp.array_equal(x, y))
-            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))
-        )
-
-    if "expand_impl" in live:
-        # one flagship expand window: sub_batch x A candidate lanes of
-        # 20-word packed rows -> 64-bit fingerprint columns
-        model = CompactionModel(bench.scaled_config())
-        W = model.layout.W
-        nc = bench.BENCH_CHECKER_KW["sub_batch"] * model.A
-        ks = KeySpec(model.layout.total_bits, W, None)
-        key, k1, k2 = random.split(key, 3)
-        packed = random.bits(k1, (nc, W), jnp.uint32)
-        valid = random.bits(k2, (nc,), jnp.uint32) % 3 != 0
-        legacy = jax.jit(
-            lambda p, v: tuple(
-                jnp.where(v, c, SENTINEL) for c in ks.make(p)
-            )
-        )(packed, valid)
-        pallas = jax.jit(
-            lambda p, v: tiles.key_plane(ks, p, v, impl="pallas")
-        )(packed, valid)
-        ok = same(legacy, pallas)
-        say("kernels", f"expand key plane, {nc} lanes x {W} words: "
-            f"bit-identical={ok}")
-        check(ok, "pallas key plane != legacy")
-        del packed, valid, legacy, pallas
-    if "sieve_impl" in live:
-        # the sieve's cold extraction over the table, a quarter full
-        cap = 1 << SIEVE_CAP_LOG2
-        K = 2
-        key, k1, k2, k3 = random.split(key, 4)
-        fill = tuple(
-            random.bits(k, (cap // 4,), jnp.uint32) for k in (k1, k2)
-        )
-        tcols, _, _, _ = jax.jit(fpset.flush_acc)(
-            fpset.empty_cols(cap, K), fill, jnp.int32(cap // 4),
-            jnp.zeros((fpset.FPM_N,), jnp.int32),
-        )
-        del fill
-        gen = (random.bits(k3, (cap + 1,), jnp.uint32) % 5).astype(
-            jnp.int32
-        )
-        legacy = jax.jit(
-            lambda t, g: store_sieve.extract_cold(t, g, 2)
-        )(tcols, gen)
-        pallas = jax.jit(
-            lambda t, g: store_sieve.extract_cold(
-                t, g, 2, sieve_impl="pallas"
-            )
-        )(tcols, gen)
-        ok = same(legacy, pallas)
-        say("kernels", f"sieve extract_cold, 2^{SIEVE_CAP_LOG2}-slot "
-            f"table: bit-identical={ok}")
-        check(ok, "pallas sieve != legacy")
-
-
 def phase_sharded(jax):
     n = len(jax.devices())
     if n < 4:
@@ -372,10 +281,6 @@ def phase_sharded(jax):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument(
-        "--seed", type=int, default=0,
-        help="PRNG seed for the kernels phase's random inputs",
-    )
-    ap.add_argument(
         "--max-states", type=int, default=32_000_000,
         help="flagship state cap: past level 6 (17,787,334), inside "
         "level 7 (default 32M)",
@@ -395,7 +300,6 @@ def main(argv=None) -> int:
     phases = [
         ("cli-shipped", lambda: phase_cli_shipped(jax)),
         ("flagship", lambda: phase_flagship(jax, args.max_states)),
-        ("kernels", lambda: phase_kernels(jax, args.seed)),
         ("sharded", lambda: phase_sharded(jax)),
     ]
     failed = []

@@ -169,11 +169,6 @@ def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
         max_states=args.maxstates,
         progress=True,
         metrics_path=args.metrics,
-        visited_impl=args.visited,
-        compact_impl=_tunable(args, "compact", args.compact),
-        probe_impl=_tunable(args, "probe_impl", args.probe_impl),
-        expand_impl=_tunable(args, "expand_impl", args.expand_impl),
-        sieve_impl=_tunable(args, "sieve_impl", args.sieve_impl),
         fuse=args.fuse,
         fuse_group=args.fuse_group,
         hbm_budget=args.hbm_budget,
@@ -377,7 +372,6 @@ def _check_properties(args, model, properties, rc):
                     sweep_group=args.sweep_group,
                     hbm_budget=args.hbm_budget,
                     spill_compress=(False if args.no_spill_compress else None),
-                    compact_impl=_tunable(args, "compact", args.compact),
                     profile=_profile_arg(args),
                     telemetry=args.telemetry,
                     heartbeat_s=args.progress,
@@ -403,33 +397,6 @@ def _check_properties(args, model, properties, rc):
         if not lres.holds:
             rc = 1
     return rc
-
-
-# argparse defaults for the tuned knobs ("explicit flags still win":
-# a flag left at its default counts as unset, so a tuned profile may
-# fill it — docs/tuning.md.  An explicitly typed default value is
-# indistinguishable from the default; pass -no-profile to pin it.)
-# NOTE `-chunk` is NOT here: its CLI default (sub_batch 4096) differs
-# from the engine default (8192), so treating it as "unset" would
-# silently change every untuned check's geometry — `cli check` always
-# passes sub_batch explicitly, and sub_batch stays tunable through
-# bench/tune/serve, whose defaults ARE the engine's (docs/tuning.md).
-_TUNABLE_DEFAULTS = {
-    "compact": "logshift",
-    # dense-tile kernel knobs (r23, ops/tiles.py): all exact
-    # reformulations, so a tuned profile may pick any of them
-    "probe_impl": "legacy",
-    "expand_impl": "legacy",
-    "sieve_impl": "legacy",
-}
-
-
-def _tunable(args, name, value):
-    """None (profile-resolvable) when the flag sits at its argparse
-    default, else the explicit value."""
-    if getattr(args, name) == _TUNABLE_DEFAULTS[name]:
-        return None
-    return value
 
 
 def _profile_arg(args):
@@ -478,7 +445,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 sweep_group=args.sweep_group,
                 hbm_budget=args.hbm_budget,
                 spill_compress=(False if args.no_spill_compress else None),
-                compact_impl=_tunable(args, "compact", args.compact),
                 profile=_profile_arg(args),
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
@@ -545,8 +511,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 progress=True,
                 checkpoint_path=args.checkpoint,
                 n_slices=args.slices,
-                visited_impl=args.visited,
-                compact_impl=args.compact,
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
             )
@@ -596,13 +560,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 max_states=args.maxstates,
                 progress=True,
                 metrics_path=args.metrics,
-                visited_impl=args.visited,
-                compact_impl=_tunable(args, "compact", args.compact),
-                probe_impl=_tunable(args, "probe_impl", args.probe_impl),
-                expand_impl=_tunable(
-                    args, "expand_impl", args.expand_impl
-                ),
-                sieve_impl=_tunable(args, "sieve_impl", args.sieve_impl),
                 fuse=args.fuse,
                 fuse_group=args.fuse_group,
                 hbm_budget=args.hbm_budget,
@@ -2165,61 +2122,13 @@ def _build_parser():
         help="sharded visited-set structure (default: sorted columns)",
     )
     pc.add_argument(
-        "-visited",
-        choices=["fpset", "sort"],
-        default="fpset",
-        help="device-engine visited-set implementation: 'fpset' (HBM "
-        "hash-table FPSet, default — dedup cost independent of the "
-        "visited count) or 'sort' (the legacy sort-merge flush, kept "
-        "for differential testing)",
-    )
-    pc.add_argument(
-        "-compact",
-        choices=["logshift", "sort"],
-        default="logshift",
-        help="stream-compaction implementation on the device engines' "
-        "append/sweep hot paths: 'logshift' (sort-free prefix-sum + "
-        "doubling shifts, default) or 'sort' (the legacy chunked "
-        "single-key sorts, kept for differential timing)",
-    )
-    pc.add_argument(
-        "-probe-impl",
-        dest="probe_impl",
-        choices=["legacy", "tile"],
-        default="legacy",
-        help="fpset flush probe kernel (round 23, ops/tiles.py): "
-        "'legacy' (dense probe rounds inside flush_acc, default) or "
-        "'tile' (lane-tiled membership prefilter + chunked insert).  "
-        "Both are exact — discovery order is identical",
-    )
-    pc.add_argument(
-        "-expand-impl",
-        dest="expand_impl",
-        choices=["legacy", "tile", "pallas"],
-        default="legacy",
-        help="successor-sweep structure (round 23): 'legacy' (per-"
-        "window scan), 'tile' (flat row sweep + full-matrix key "
-        "plane) or 'pallas' (tile with the key plane as a Pallas "
-        "kernel)",
-    )
-    pc.add_argument(
-        "-sieve-impl",
-        dest="sieve_impl",
-        choices=["legacy", "tile", "pallas"],
-        default="legacy",
-        help="cold-extract kernel on the tiered-store eviction path "
-        "(round 23): 'legacy' (compact+mask+sort), 'tile' (mask-in-"
-        "place + sort) or 'pallas' (the mask as a Pallas kernel)",
-    )
-    pc.add_argument(
         "-fuse",
         choices=["level", "stage"],
         default="level",
         help="device-engine dispatch fusion: 'level' (default — one "
         "fused megakernel dispatch per BFS level, with shallow ramp "
-        "levels batched several-per-dispatch) or 'stage' (the legacy "
-        "per-stage dispatch chain, kept for bit-for-bit differential "
-        "timing, mirroring -visited sort / -compact sort)",
+        "levels batched several-per-dispatch) or 'stage' (the "
+        "per-stage dispatch chain)",
     )
     pc.add_argument(
         "-fuse-group",

@@ -1,41 +1,31 @@
-"""Fully device-resident BFS checker — the round-3 throughput engine.
+"""Fully device-resident BFS checker — the single-chip throughput engine.
 
 Motivation:
 
 - every host<->device sync serializes the pipeline, so per-chunk host
   involvement is kept off the hot path entirely;
-- device sorts are bandwidth-bound while random-access gathers are
-  latency-bound — the design keeps every hot-path operation a sort, a
-  contiguous copy, or a contiguous-index scatter;
+- random-access gathers are latency-bound on the device — the design
+  keeps every hot-path operation but the table probe a contiguous copy
+  or a contiguous-index scatter;
 - dispatch is async: the host enqueues work far ahead and fetches one
   small stats vector per group of flushes.
 
-Round-3 redesign (VERDICT r2 #1: kill the per-sub-batch full-table
-re-sort).  The round-2 engine merged every expand sub-batch (``G*A``
-candidate lanes) into the visited set with a ``VCAP + G*A``-wide sort —
-sorting 33.5M visited keys to admit ~260k new states, ~8x per deep
-level.  Round 3 amortizes that merge:
+Layout:
 
 - **Candidate accumulator**: expand sub-batches append their candidate
   keys + packed rows into an HBM accumulator (``ACAP = flush_factor *
-  G * A`` lanes); the visited merge ("flush") runs once per accumulator
-  fill, so the big sort is paid per ~ACAP candidates instead of per
-  sub-batch.  Sort traffic per state drops ~3x at bench shapes.
+  G * A`` lanes); the visited-set probe ("flush", ``ops/fpset.py``)
+  runs once per accumulator fill.
 - **Row store instead of frontier double-buffering**: all discovered
   states live in one append-only packed-row store in gid order; a BFS
   level is just a contiguous gid range, so expand windows are
   contiguous slices (no gathers) and trace reconstruction reads rows
-  directly.  Memory at 50M+ states beats two full-level frontier
-  buffers, which is what capped the round-2 run at ~25M states.
+  directly.
 - **Fingerprint keys sized to the state** (``ops.dedup.KeySpec``):
   exact 2-column keys for <64-bit states, exact 3-column for <96, and
   64-bit murmur3 fingerprints (TLC's fingerprint-width regime, with
-  the collision probability reported like TLC does) for wide states —
-  one fewer sort operand everywhere vs round 2's fixed 3x32 keys.
-- **Invariants evaluate at append time on deduped new states only**
-  (round 2 evaluated them on every candidate lane and carried verdict
-  bits in the sort payload).  The payload is now a bare accumulator
-  index, which is what lets ACAP grow past the round-2 2^25 lane limit;
+  the collision probability reported like TLC does) for wide states.
+- **Invariants evaluate at append time on deduped new states only**;
   invariant work drops by the duplication factor for free.
 
 Counterexample traces: the per-state ``(parent gid, action lane)`` log
@@ -59,8 +49,8 @@ returns per-level sizes so host-side level accounting, telemetry
 sites replay exactly.  Steady-state levels therefore cost 1 dispatch +
 1 stats fetch (the kernel returns the stats vector — no separate stats
 dispatch), and the whole ramp costs 1.  ``fuse="stage"`` keeps the
-round-10 chain verbatim for bit-for-bit differential timing (mirroring
-``-visited sort`` / ``-compact sort``); discovery order is identical
+per-stage chain (the tiered store's path under pressure, and the
+reference the fused kernel is held to); discovery order is identical
 state-for-state either way (same flush partition, same lane ids, same
 min-lane-wins dedup).
 """
@@ -86,8 +76,7 @@ from pulsar_tlaplus_tpu.tune import online as tune_online
 from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
 from pulsar_tlaplus_tpu.utils import ckpt, faults, recovery
 from pulsar_tlaplus_tpu.ops import compact as compact_ops
-from pulsar_tlaplus_tpu.ops import dedup, fpset
-from pulsar_tlaplus_tpu.ops import tiles as tile_ops
+from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, KeySpec
 from pulsar_tlaplus_tpu.ref import pyeval
 
@@ -116,12 +105,6 @@ FPM_N = fpset.FPM_N
 # totals are equal state-for-state (pinned in tests).
 WKM_N = fpset.WKM_N
 
-# payload word: low 31 bits = accumulator slot index, bit 31 = the
-# candidate tag (visited entries carry payload 0, so the payload doubles
-# as the visited-vs-candidate sort tie-breaker)
-TAG_BIT = jnp.uint32(1 << 31)
-IDX_MASK = jnp.uint32((1 << 31) - 1)
-
 
 # The shared traced sub-functions of ops/ under the stage names of the
 # work counters (obs/spans.py): the stage chain's jits and the fused
@@ -136,9 +119,9 @@ class DeviceChecker:
 
     Shapes are static per capacity tier: ``G`` frontier states per
     expand window produce ``NCs = G * A`` candidate lanes appended to
-    the accumulator; a flush merges ``VCAP + ACAP`` keys.  The host
-    grows VCAP / the row store between flushes (geometric tiers,
-    re-jitting per tier via the jit cache).
+    the accumulator; a flush probes ``ACAP`` keys into the table.  The
+    host grows the table / the row store between flushes (geometric
+    tiers, re-jitting per tier via the jit cache).
 
     Beside the ``CheckerResult`` a run leaves two things on the
     checker, both part of its result: ``last_stats`` (the run's
@@ -172,11 +155,6 @@ class DeviceChecker:
         seed_cap: Optional[int] = None,
         rows_window: str = "all",
         row_cap_states: Optional[int] = None,
-        visited_impl: str = "fpset",
-        compact_impl: Optional[str] = None,
-        probe_impl: Optional[str] = None,
-        expand_impl: Optional[str] = None,
-        sieve_impl: Optional[str] = None,
         fuse: str = "level",
         fuse_group: Optional[int] = None,
         fpset_dense_rounds: Optional[int] = None,
@@ -252,10 +230,6 @@ class DeviceChecker:
                     "fuse_group": fuse_group,
                     "fpset_dense_rounds": fpset_dense_rounds,
                     "fpset_stages": fpset_stages,
-                    "compact_impl": compact_impl,
-                    "probe_impl": probe_impl,
-                    "expand_impl": expand_impl,
-                    "sieve_impl": sieve_impl,
                     "hbm_headroom": hbm_headroom,
                     "spill_compress": spill_compress,
                     "miss_batch": miss_batch,
@@ -273,16 +247,6 @@ class DeviceChecker:
         sub_batch = sub_batch or _pk.get("sub_batch") or 8192
         group = group or _pk.get("group") or 4
         flush_factor = flush_factor or _pk.get("flush_factor") or 1
-        compact_impl = (
-            compact_impl or _pk.get("compact_impl") or "logshift"
-        )
-        # dense-tile kernel knobs (round 23, ops/tiles.py): same
-        # explicit > profile > default resolution; the tile/pallas
-        # variants are exact reformulations (discovery order pinned
-        # state-for-state), so the tuner may swap them freely per shape
-        probe_impl = probe_impl or _pk.get("probe_impl")
-        expand_impl = expand_impl or _pk.get("expand_impl")
-        sieve_impl = sieve_impl or _pk.get("sieve_impl")
         fuse_group = (
             fuse_group if fuse_group is not None
             else _pk.get("fuse_group")
@@ -330,58 +294,13 @@ class DeviceChecker:
             self._round_cap(visited_cap),
             max(max_states + self.ACAP, self.ACAP * 2),
         )
-        # Visited-set implementation (round 6 tentpole):
-        #
-        # - "fpset" (default): the HBM-resident hash-table FPSet
-        #   (ops/fpset.py) — dedup cost O(batch * E[probes]) independent
-        #   of the visited count, killing the 3-full-width-sort flush
-        #   that was ~50% of round-5 stage time.  ``VCAP`` keeps its
-        #   meaning (max states admissible before growth); the table
-        #   carries ``TCAP = 2 * VCAP`` slots so the run-loop bound
-        #   ``nv_bound <= VCAP`` IS the load-factor <= 1/2 contract.
-        # - "sort": the legacy sort-merge flush, kept verbatim behind
-        #   this flag for differential testing (bench --visited sort,
-        #   CLI -visited sort).
-        if visited_impl not in ("fpset", "sort"):
-            raise ValueError(
-                f"visited_impl must be fpset|sort: {visited_impl}"
-            )
-        self.visited_impl = visited_impl
-        # Stream-compaction implementation (round 10 tentpole): the
-        # append's "move new states to the front in discovery order"
-        # step runs as its OWN dispatch between flush and append —
-        # "logshift" (default, ops/compact.py: prefix-sum + doubling
-        # shifts, no sort) or "sort" (the round-4 chunked single-key
-        # sorts, kept for bit-for-bit differential timing, mirroring
-        # the round-6 -visited sort pattern).  The fpset's staged
-        # pending-compaction uses the same impl inside the flush.
-        self.compact_impl = compact_ops.validate_impl(compact_impl)
-        # Dense-tile kernel layer (round 23 tentpole, ops/tiles.py):
-        # per-kernel impl selection — "legacy" keeps the existing
-        # formulations, "tile" the blocked pure-XLA ones, "pallas" the
-        # explicit Pallas kernels (interpret-mode on CPU).  All three
-        # are pinned state-for-state identical; the knobs exist so
-        # `cli.py tune` can arbitrate the winner per shape.
-        self.probe_impl = tile_ops.validate_impl(
-            "probe_impl", probe_impl
-        )
-        self.expand_impl = tile_ops.validate_impl(
-            "expand_impl", expand_impl
-        )
-        self.sieve_impl = tile_ops.validate_impl(
-            "sieve_impl", sieve_impl
-        )
         # Level fusion (round 13 tentpole): "level" (default) runs each
         # BFS level as ONE fused megakernel dispatch (ramp levels batch
         # several levels per dispatch — see the module docstring);
-        # "stage" keeps the round-10 per-stage dispatch chain for
-        # bit-for-bit differential timing.  The fused kernel chains the
-        # fpset probe, so the legacy sort-merge visited set always runs
-        # the stage chain (the r6 differential path stays exact).
+        # "stage" runs the per-stage dispatch chain (the tiered store
+        # falls back to it under pressure).
         if fuse not in ("level", "stage"):
             raise ValueError(f"fuse must be level|stage: {fuse}")
-        if visited_impl == "sort":
-            fuse = "stage"
         self.fuse = fuse
         # ramp batch depth: max levels one fused dispatch may close
         # (static — it shapes the kernel's per-level size vector).  The
@@ -404,12 +323,16 @@ class DeviceChecker:
         self._fps_base = (self.fps_dense, self.fps_stages)
         self._adapt_cap: Optional[int] = None
         self._tuner = None
-        if visited_impl == "fpset":
-            t = 1 << 11
-            while t < 2 * self.VCAP:
-                t <<= 1
-            self.TCAP = t
-            self.VCAP = t // 2
+        # The visited set is the HBM-resident hash-table FPSet
+        # (ops/fpset.py).  ``VCAP`` is the max states admissible before
+        # growth; the table carries ``TCAP = 2 * VCAP`` slots so the
+        # run-loop bound ``nv_bound <= VCAP`` IS the load-factor <= 1/2
+        # contract.
+        t = 1 << 11
+        while t < 2 * self.VCAP:
+            t <<= 1
+        self.TCAP = t
+        self.VCAP = t // 2
         # Row-store policy (round 5, VERDICT r4 #2 — break the HBM wall):
         #
         # - ``rows_window="all"`` (default): every discovered state's
@@ -499,12 +422,6 @@ class DeviceChecker:
         # log-shift chunk (tiered log windows slide like the rows)
         self.LOG_CW = min(1 << 22, self.APAD)
         if self.tiered:
-            if self.visited_impl != "fpset":
-                raise ValueError(
-                    "the tiered store needs the fpset visited set "
-                    "(hbm_budget with visited_impl='sort' is "
-                    "unsupported)"
-                )
             if self.rows_window != "all":
                 raise ValueError(
                     "hbm_budget and rows_window='frontier' are "
@@ -592,13 +509,12 @@ class DeviceChecker:
         # engine (utils/recovery.py); ``group`` (the dispatch
         # group-ahead) lives there because recovery halves it
         self.rec = recovery.RecoveryState(checkpoint_path, group)
-        if seed_cap is not None:
-            # sorted-column capacity of the host-seed merge path; a
-            # bench-scale warm start (VERDICT r3: the first ~10 s of
-            # the round-3 run produced 0.6M of its 32M states because
-            # tiny early levels pay full-width sort latency) needs a
-            # bigger tier than the 2^16 default
-            self.SEED_VCAP = self._round_cap(seed_cap)
+        # ``seed_cap`` sized the sorted columns of a seed-merge path
+        # that is gone: the seed inserts straight into the main table.
+        # The benchmark's configuration still passes it
+        # (benchmark/configs/compaction-scaled.json), so it is accepted
+        # and unused.
+        del seed_cap
         # run-survivability state (round 7): level-boundary checkpoint
         # frames shared with the sharded engine via utils/ckpt.py,
         # HBM-exhaustion recovery (utils/recovery.py), and
@@ -807,52 +723,10 @@ class DeviceChecker:
         rows into the accumulator at ``acc_off``.  ``f_off`` is the
         window's first row index within the current level (for
         liveness masking and deadlock gids).  Returns
-        ``(ak', arows', dead_gid')``.
-
-        ``expand_impl`` (round 23) selects the sweep's compiled
-        structure: ``legacy`` is the ``lax.scan`` over ``G/Fi`` chunks
-        below; ``tile`` / ``pallas`` evaluate the whole ``(G, A)``
-        successor matrix as one batched tile op and form the key plane
-        on the full ``(G*A, W)`` matrix via ``ops.tiles.key_plane``
-        (``pallas`` runs the key mixing as an explicit row-tiled
-        kernel).  Per-lane math is identical elementwise and the
-        deadlock min-of-mins equals the scan's, so gids, rows, and
-        logs are bit-identical under every impl."""
+        ``(ak', arows', dead_gid')``."""
         m, layout = self.model, self.layout
         Fi, A, W, G = self.Fi, self.A, self.W, self.G
         keyspec = self.keys
-
-        if self.expand_impl != "legacy":
-            rows = window.reshape(G, W)
-            pos = f_off + jnp.arange(G, dtype=jnp.int32)
-            live = pos < n_live
-            states = jax.vmap(layout.unpack)(rows)
-            succ, valid = jax.vmap(m.successors)(states)  # [G, A]
-            valid = valid & live[:, None]
-            packed = jax.vmap(jax.vmap(layout.pack))(succ)
-            nc = G * A
-            packedf = packed.reshape(nc, W)
-            vflat = valid.reshape(nc)
-            kcols = tile_ops.key_plane(
-                keyspec, packedf, vflat, impl=self.expand_impl
-            )
-            if self.check_deadlock:
-                stut = jax.vmap(m.stutter_enabled)(states)
-                dead_rows = live & ~jnp.any(valid, axis=1) & ~stut
-                didx = jnp.min(jnp.where(dead_rows, pos, BIG))
-            else:
-                didx = BIG
-            dead = jnp.minimum(
-                dead_gid, jnp.where(didx < BIG, gid_base + didx, BIG)
-            )
-            ak = tuple(
-                lax.dynamic_update_slice(akc, kc, (acc_off,))
-                for akc, kc in zip(ak, kcols)
-            )
-            arows = lax.dynamic_update_slice(
-                arows, packedf.T, (0, acc_off)
-            )
-            return ak, arows, dead
 
         def chunk(i):
             rows = lax.dynamic_slice(
@@ -902,7 +776,7 @@ class DeviceChecker:
         f_off, n_live, dead_gid, gid_base, acc_off) -> (ak', arows',
         dead_gid') — the stage-chain dispatch over ``_expand_body``;
         capacity-independent apart from the fixed ACAP."""
-        key = ("expand", self.expand_impl)
+        key = ("expand",)
         if key in self._jits:
             return self._jits[key]
 
@@ -971,63 +845,15 @@ class DeviceChecker:
         self._jits[key] = fn
         return fn
 
-    def _flush_jit(self):
-        """Sort-merge the accumulator into the visited set: (vk cols,
-        ak cols, n_acc) -> (vk' cols, n_new, flag_acc[ACAP]).
-
-        One unstable ``K+1``-operand sort resolves in-accumulator
-        duplicates AND visited membership in the same pass (payload 0 =
-        visited orders before same-key candidates); a stable flag-sort
-        compacts the merged visited set; a payload sort projects the
-        new-state flags back to accumulator slot order."""
-        key = ("flush", self.VCAP)
-        if key in self._jits:
-            return self._jits[key]
-        ACAP, K = self.ACAP, self.K
-
-        @spans.staged("probe")
-        def ptt_flush(*args):
-            vk = args[:K]
-            ak = args[K: 2 * K]
-            n_acc = args[2 * K]
-            lanei = jnp.arange(ACAP, dtype=jnp.int32)
-            amask = lanei < n_acc  # stale tail from a previous fill
-            ccols = tuple(
-                jnp.where(amask, ac, SENTINEL) for ac in ak
-            )
-            cpay = lanei.astype(jnp.uint32) | TAG_BIT
-            vk2, n_new, sp, new_flag = dedup.merge_new_keys(
-                vk, ccols, cpay
-            )
-            # project new_flag back to ACCUMULATOR order: candidate
-            # payloads (idx | TAG) sort above every visited payload (0)
-            # and ascend in idx order, so the tail of a payload sort is
-            # the per-slot flag vector — the append then compacts rows
-            # with a value-carrying sort instead of a gather (gathers
-            # are latency-bound per element on TPU: an appended flush
-            # measured 10.9 s/8.9M lanes before this, scripts/profile.py stages)
-            _, flag_sorted = lax.sort(
-                (sp, new_flag.astype(jnp.uint32)), num_keys=1,
-                is_stable=False,
-            )
-            flag_acc = flag_sorted[sp.shape[0] - ACAP:]
-            return (*vk2, n_new, flag_acc)
-
-        fn = jax.jit(ptt_flush, donate_argnums=tuple(range(self.K)))
-        self._jits[key] = fn
-        return fn
-
     def _fpflush_jit(self):
-        """fpset-mode flush: probe-or-insert the accumulator keys into
-        the HBM hash table — (table cols, ak cols, n_acc, fpm) ->
+        """The flush: probe-or-insert the accumulator keys into the HBM
+        hash table — (table cols, ak cols, n_acc, fpm) ->
         (table' cols, n_new, flag_acc[ACAP], fpm').
 
-        No visited-width sort anywhere: cost is O(ACAP * E[probes])
-        regardless of how many states have been visited (the round-5
-        structural ceiling).  ``flag_acc`` comes back directly in
-        accumulator order (min-lane-wins == the sort-merge's lowest-
-        slot-wins, so gid assignment is IDENTICAL to the legacy flush),
-        feeding the unchanged append.  ``fpm`` accumulates the
+        Cost is O(ACAP * E[probes]) regardless of how many states have
+        been visited.  ``flag_acc`` comes back directly in accumulator
+        order (min-lane-wins: the lowest slot holding a key is the one
+        flagged new), feeding the append.  ``fpm`` accumulates the
         per-flush metrics [flushes, probe_rounds, failures,
         valid_lanes_lo, max_probe_rounds, valid_lanes_hi] on device
         (:data:`FPM_N`) so
@@ -1036,8 +862,7 @@ class DeviceChecker:
         stats fetch as a hard error — states were dropped, the run
         cannot continue honestly."""
         key = (
-            "fpflush", self.TCAP, self.compact_impl, self.fps_dense,
-            self.fps_stages, self.probe_impl,
+            "fpflush", self.TCAP, self.fps_dense, self.fps_stages,
         )
         if key in self._jits:
             return self._jits[key]
@@ -1052,8 +877,6 @@ class DeviceChecker:
             tc2, n_new, flag, fpm = _probe_flush_acc(
                 tc, ak, n_acc, fpm,
                 dense_rounds=self.fps_dense, stages=self.fps_stages,
-                compact_impl=self.compact_impl,
-                probe_impl=self.probe_impl,
             )
             return (*tc2, n_new, flag, fpm)
 
@@ -1064,8 +887,7 @@ class DeviceChecker:
     def _rehash_jit(self):
         """fpset growth: old table cols -> double-capacity cols + a
         failure count, fully on device (``fpset.rehash_cols``).  The
-        transient is old+new table — far below the retired flush
-        sort's 3x-visited-width scratch."""
+        transient is old+new table."""
         key = ("rehash", self.TCAP)
         if key in self._jits:
             return self._jits[key]
@@ -1098,25 +920,23 @@ class DeviceChecker:
 
         Gathers are latency-bound per element on TPU (~17-50 ns — a
         gather-based append measured 10.9 s per 8.9M lanes,
-        scripts/profile.py stages), so compaction is dense passes: log-shift
-        by default (``ops/compact.py``: exclusive prefix sum + log2(A)
-        masked doubling shifts, contiguous copies only), the round-4
-        chunked single-key sorts behind ``compact_impl="sort"`` for
-        differential timing.  Standing alone it gets per-dispatch
+        scripts/profile.py stages), so compaction is dense passes: the
+        log-shift of ``ops/compact.py`` (exclusive prefix sum + log2(A)
+        masked doubling shifts, contiguous copies only).  Standing
+        alone it gets per-dispatch
         ``stage_compact_n``/``_s`` accounting (the BASELINE per-stage
         table's before/after), and the accumulator is DONATED: the
         compacted matrix aliases its memory and is recycled as the
         next fill's accumulator buffer, so the split adds only the idx
         plane per in-flight flush — not a second W x ACAP store."""
-        key = ("compact", self.compact_impl)
+        key = ("compact",)
         if key in self._jits:
             return self._jits[key]
-        impl = self.compact_impl
 
         def ptt_compact(arows, flag_acc):
             # the row-matrix compaction body lives in ops/compact.py
             # since r13 (shared with the fused level megakernel)
-            return _compact_rows(arows, flag_acc, impl=impl)
+            return _compact_rows(arows, flag_acc)
 
         fn = jax.jit(ptt_compact, donate_argnums=(0,))
         self._jits[key] = fn
@@ -1309,8 +1129,7 @@ class DeviceChecker:
         resident-BFS premise this kernel is built on)."""
         key = (
             "fused", self.TCAP, self.LCAP, self.PCAP,
-            self.compact_impl, self.fps_dense, self.fps_stages,
-            self.RMAX, self.probe_impl, self.expand_impl,
+            self.fps_dense, self.fps_stages, self.RMAX,
         )
         if key in self._jits:
             return self._jits[key]
@@ -1319,7 +1138,6 @@ class DeviceChecker:
         VCAP, LCAP, PCAP, SCAP = self.VCAP, self.LCAP, self.PCAP, self.SCAP
         RMAX = self.RMAX
         frontier_mode = self.rows_window == "frontier"
-        impl = self.compact_impl
         ramp_t = jnp.int32(G)  # new-level batch threshold: one window
         # write-capacity limits, trace-time constants per tier: the
         # append's blind APAD window and the ACAP-wide log DUS must
@@ -1391,10 +1209,9 @@ class DeviceChecker:
                 vk, n_new, flag, fpm = _probe_flush_acc(
                     vk, ak, jnp.int32(ACAP), fpm,
                     dense_rounds=self.fps_dense,
-                    stages=self.fps_stages, compact_impl=impl,
-                    probe_impl=self.probe_impl,
+                    stages=self.fps_stages,
                 )
-                crows, idx = _compact_rows(arows, flag, impl=impl)
+                crows, idx = _compact_rows(arows, flag)
                 if frontier_mode:
                     # per-group actual-occupancy check — exactly the
                     # predicate the stage loop evaluates at its forced
@@ -1561,19 +1378,14 @@ class DeviceChecker:
         cutoff, sorted for the host's delta codec.  The holed table
         must be rehashed (:meth:`_rehash_same_jit`) before it serves
         lookups again."""
-        key = (
-            "spill_evict", self.TCAP, self.compact_impl,
-            self.sieve_impl,
-        )
+        key = ("spill_evict", self.TCAP)
         if key in self._jits:
             return self._jits[key]
         K = self.K
-        impl = self.compact_impl
 
         def step(*args):
             holed, gen, ev, n = store_sieve.extract_cold(
-                args[:K], args[K], args[K + 1], compact_impl=impl,
-                sieve_impl=self.sieve_impl,
+                args[:K], args[K], args[K + 1]
             )
             return (*holed, gen, *ev, n)
 
@@ -1606,16 +1418,13 @@ class DeviceChecker:
         """``(ak cols, flag_acc) -> (kcols dense, lane_ids, n_new)``
         — pack exactly the hot-filter survivors for cold-tier miss
         resolution; only these keys ever cross the link (the sieve)."""
-        key = ("spill_sieve", self.compact_impl)
+        key = ("spill_sieve",)
         if key in self._jits:
             return self._jits[key]
         K = self.K
-        impl = self.compact_impl
 
         def step(*args):
-            return store_sieve.sieve_new(
-                args[:K], args[K], compact_impl=impl
-            )
+            return store_sieve.sieve_new(args[:K], args[K])
 
         fn = jax.jit(step)
         self._jits[key] = fn
@@ -1642,23 +1451,16 @@ class DeviceChecker:
         return fn
 
     def _stats_jit(self):
-        key = ("stats", self.visited_impl)
+        key = ("stats",)
         if key in self._jits:
             return self._jits[key]
 
-        if self.visited_impl == "fpset":
-            # stats layout: [nv, dead, viol..., flushes, rounds, failed]
-            @spans.staged("levelctl")
-            def ptt_stats(n_visited, dead_gid, viol, fpm):
-                return jnp.concatenate(
-                    [jnp.stack([n_visited, dead_gid]), viol, fpm]
-                )
-        else:
-            @spans.staged("levelctl")
-            def ptt_stats(n_visited, dead_gid, viol):
-                return jnp.concatenate(
-                    [jnp.stack([n_visited, dead_gid]), viol]
-                )
+        # stats layout: [nv, dead, viol..., flushes, rounds, failed]
+        @spans.staged("levelctl")
+        def ptt_stats(n_visited, dead_gid, viol, fpm):
+            return jnp.concatenate(
+                [jnp.stack([n_visited, dead_gid]), viol, fpm]
+            )
 
         fn = jax.jit(ptt_stats)
         self._jits[key] = fn
@@ -1694,59 +1496,14 @@ class DeviceChecker:
     # ----------------------------------------------- host-seeded starts
 
     SEED_CHUNK = 1 << 15
-    SEED_VCAP = 1 << 16
-
-    def _seed_merge_jit(self):
-        """Small-shape merge for host-seeded warm starts: the seed
-        prefix is tiny, so it must not pay the full-size (data-
-        independent) sort latency of the main flush kernel."""
-        key = ("seedmerge",)
-        if key in self._jits:
-            return self._jits[key]
-        NCs, VCs, K = self.SEED_CHUNK, self.SEED_VCAP, self.K
-        layout = self.layout
-        m = self.model
-        inv_fns = [m.invariants[n] for n in self.invariant_names]
-        n_inv = len(self.invariant_names)
-        keyspec = self.keys
-
-        @spans.staged("seed")
-        def ptt_seed_merge(*args):
-            vk = args[:K]
-            rows, n_valid, n_visited, viol, gid_base = args[K:]
-            kcols = keyspec.make(rows)
-            lane = jnp.arange(NCs, dtype=jnp.int32)
-            valid = lane < n_valid
-            kcols = tuple(jnp.where(valid, c, SENTINEL) for c in kcols)
-            cpay = lane.astype(jnp.uint32) | TAG_BIT
-            vk2, n_new, _sp, _nf = dedup.merge_new_keys(vk, kcols, cpay)
-            # fused invariant check on the seed states (discovery-time
-            # semantics, same as the main append path)
-            if n_inv:
-                states = jax.vmap(layout.unpack)(rows)
-                vnew = []
-                for fn in inv_fns:
-                    ok = jax.vmap(fn)(states)
-                    bad = valid & ~ok
-                    vnew.append(
-                        jnp.min(jnp.where(bad, gid_base + lane, BIG))
-                    )
-                viol = jnp.minimum(viol, jnp.stack(vnew))
-            return (*vk2, n_visited + n_new, viol)
-
-        fn = jax.jit(ptt_seed_merge, donate_argnums=tuple(range(self.K)))
-        self._jits[key] = fn
-        return fn
 
     def _fpseed_merge_jit(self):
-        """fpset-mode seed merge: insert one SEED_CHUNK of host-seeded
-        states straight into the MAIN table (probes are O(chunk)
-        whatever the table size, so the sort path's small-shape
-        SEED_VCAP trick is unnecessary) and fuse the same
-        discovery-time invariant check."""
+        """Seed merge: insert one SEED_CHUNK of host-seeded states
+        straight into the MAIN table (probes are O(chunk) whatever the
+        table size) and fuse the discovery-time invariant check of the
+        main append path."""
         key = (
-            "fpseedmerge", self.TCAP, self.compact_impl,
-            self.fps_dense, self.fps_stages,
+            "fpseedmerge", self.TCAP, self.fps_dense, self.fps_stages,
         )
         if key in self._jits:
             return self._jits[key]
@@ -1769,7 +1526,6 @@ class DeviceChecker:
                     tc, kcols, valid,
                     dense_rounds=self.fps_dense,
                     stages=self.fps_stages,
-                    compact_impl=self.compact_impl,
                 )
             )
             if n_inv:
@@ -1878,9 +1634,7 @@ class DeviceChecker:
         n = len(rows)
         if sum(lsizes) != n:
             raise ValueError("seed level sizes do not sum to the state count")
-        if n > self.SCAP or (
-            self.visited_impl == "sort" and n > self.SEED_VCAP // 2
-        ):
+        if n > self.SCAP:
             raise ValueError(f"seed too large ({n} states)")
         if (
             self.rows_window == "frontier"
@@ -1924,12 +1678,7 @@ class DeviceChecker:
                 f"{self.APAD} reserved for the append); raise "
                 "row_cap_states"
             )
-        self._grow_visited(
-            bufs,
-            n + self.ACAP
-            if self.visited_impl == "fpset"
-            else max(n + self.ACAP, self.SEED_VCAP),
-        )
+        self._grow_visited(bufs, n + self.ACAP)
         # seed writes are SEED_CHUNK-padded DUS windows starting at
         # offsets up to n, so the store must admit one full chunk past
         # the worst-case write start or the DUS would clamp and corrupt
@@ -1939,10 +1688,7 @@ class DeviceChecker:
             # so this covers the guard above and keeps the first fused
             # dispatch on a prewarmed tier triple)
             self._grow_fused(bufs, n)
-        if self.visited_impl == "fpset":
-            merge = self._fpseed_merge_jit()
-        else:
-            merge = self._seed_merge_jit()
+        merge = self._fpseed_merge_jit()
         write = self._seed_write_jit()
         NCs = self.SEED_CHUNK
         W = self.W
@@ -1963,14 +1709,7 @@ class DeviceChecker:
         # happened off the measured path
         _, rows_d, par_d, lan_d = staged
         self._seed_staged = None
-        fpmode = self.visited_impl == "fpset"
-        if fpmode:
-            vks = bufs["vk"]  # insert straight into the main table
-        else:
-            vks = tuple(
-                jnp.full((self.SEED_VCAP,), SENTINEL, jnp.uint32)
-                for _ in range(self.K)
-            )
+        vks = bufs["vk"]  # insert straight into the main table
         n_vis = jnp.int32(0)
         off = 0
         for count in lsizes:
@@ -1980,20 +1719,12 @@ class DeviceChecker:
                 jrows = lax.dynamic_slice(
                     rows_d, (s0, 0), (NCs, W)
                 )
-                if fpmode:
-                    out = merge(
-                        *vks, jrows, jnp.int32(cn), n_vis, st["viol"],
-                        jnp.int32(s0), st["fpm"],
-                    )
-                    vks = out[: self.K]
-                    n_vis, st["viol"], st["fpm"] = out[self.K:]
-                else:
-                    out = merge(
-                        *vks, jrows, jnp.int32(cn), n_vis, st["viol"],
-                        jnp.int32(s0),
-                    )
-                    vks = out[: self.K]
-                    n_vis, st["viol"] = out[self.K], out[self.K + 1]
+                out = merge(
+                    *vks, jrows, jnp.int32(cn), n_vis, st["viol"],
+                    jnp.int32(s0), st["fpm"],
+                )
+                vks = out[: self.K]
+                n_vis, st["viol"], st["fpm"] = out[self.K:]
                 (
                     bufs["rows"], bufs["parent"], bufs["lane"],
                 ) = write(
@@ -2004,27 +1735,16 @@ class DeviceChecker:
                     jnp.int32(s0),
                 )
             off += count
-        if fpmode:
-            bufs["vk"] = vks
-            if int(np.asarray(st["fpm"])[2]):
-                raise RuntimeError(
-                    "fpset probe overflow while loading the seed — "
-                    "raise visited_cap"
-                )
+        bufs["vk"] = vks
+        if int(np.asarray(st["fpm"])[2]):
+            raise RuntimeError(
+                "fpset probe overflow while loading the seed — "
+                "raise visited_cap"
+            )
         if int(np.asarray(n_vis)) != n:
             raise ValueError(
                 "seed states are not all distinct "
                 f"({int(np.asarray(n_vis))} of {n} unique)"
-            )
-        if not fpmode:
-            # hand the small sorted columns to the main engine
-            # (SENTINEL pad)
-            bufs["vk"] = tuple(
-                jnp.concatenate(
-                    [col, jnp.full((self.VCAP - self.SEED_VCAP,),
-                                   SENTINEL, jnp.uint32)]
-                )
-                for col in vks
             )
         st["n_visited"] = jnp.int32(n)
         # seed states land via seed_write, not the append body: they
@@ -2043,48 +1763,37 @@ class DeviceChecker:
         # is what lets warmup(tiers=True) pre-compile every reachable
         # tier (VERDICT r5 #8: a 317 s lazy compile landed mid-window)
         need = min(need, cap)
-        if self.visited_impl == "fpset":
-            # double + on-device rehash, capped at the most any run can
-            # use (nv never exceeds SCAP, so a table admitting
-            # SCAP + ACAP states at load 1/2 never needs to grow again
-            # even when the caller's headroom ask overshoots it).  In
-            # tiered mode the cap is additionally budget-clamped — a
-            # need past it is served by EVICTION, not growth
-            # (_ensure_hot_capacity).
-            grew = False
-            while self.VCAP < need and self.VCAP < cap:
-                out = self._rehash_jit()(*bufs["vk"])
-                bufs["vk"], failed = out[: self.K], out[self.K]
-                if int(np.asarray(failed)):
-                    raise RuntimeError(
-                        "fpset rehash overflow — table corrupted its "
-                        "load-factor contract (bug)"
-                    )
-                self.TCAP *= 2
-                self.VCAP = self.TCAP // 2
-                grew = True
-            if grew and self.tiered and "gen" in bufs:
-                # the rehash scattered every key to a fresh slot, so
-                # per-slot ages are void: restart the epoch clock with
-                # all survivors at the base generation (a documented
-                # coarsening — eviction order resets, membership and
-                # discovery order are untouched)
-                bufs["gen"] = self._tag_jit()(
-                    *bufs["vk"],
-                    jnp.zeros((self.TCAP + 1,), jnp.int32),
-                    jnp.int32(1),
+        # double + on-device rehash, capped at the most any run can
+        # use (nv never exceeds SCAP, so a table admitting
+        # SCAP + ACAP states at load 1/2 never needs to grow again
+        # even when the caller's headroom ask overshoots it).  In
+        # tiered mode the cap is additionally budget-clamped — a
+        # need past it is served by EVICTION, not growth
+        # (_ensure_hot_capacity).
+        grew = False
+        while self.VCAP < need and self.VCAP < cap:
+            out = self._rehash_jit()(*bufs["vk"])
+            bufs["vk"], failed = out[: self.K], out[self.K]
+            if int(np.asarray(failed)):
+                raise RuntimeError(
+                    "fpset rehash overflow — table corrupted its "
+                    "load-factor contract (bug)"
                 )
-                self._epoch = 2
-            return
-        while self.VCAP < need:
-            pad = min(self.VCAP, max(cap - self.VCAP, need - self.VCAP))
-            bufs["vk"] = tuple(
-                jnp.concatenate(
-                    [col, jnp.full((pad,), SENTINEL, jnp.uint32)]
-                )
-                for col in bufs["vk"]
+            self.TCAP *= 2
+            self.VCAP = self.TCAP // 2
+            grew = True
+        if grew and self.tiered and "gen" in bufs:
+            # the rehash scattered every key to a fresh slot, so
+            # per-slot ages are void: restart the epoch clock with
+            # all survivors at the base generation (a documented
+            # coarsening — eviction order resets, membership and
+            # discovery order are untouched)
+            bufs["gen"] = self._tag_jit()(
+                *bufs["vk"],
+                jnp.zeros((self.TCAP + 1,), jnp.int32),
+                jnp.int32(1),
             )
-            self.VCAP += pad
+            self._epoch = 2
 
     def _rows_len(self) -> int:
         """Rows buffer length in words (frontier AND tiered modes pad
@@ -2215,48 +1924,32 @@ class DeviceChecker:
         z = jnp.zeros
         drain = jax.block_until_ready
         K = self.K
-        save = (self.TCAP if self.visited_impl == "fpset" else None,
-                self.VCAP, self.LCAP, self.PCAP)
+        save = (self.TCAP, self.VCAP, self.LCAP, self.PCAP)
         cap = self._capv()
         fused = self.fuse == "level"
-        if self.visited_impl == "fpset":
-            while self.VCAP < cap:
-                # the growth path's exact sequence: rehash AT the
-                # current tier (old -> doubled), then flush at the new.
-                # Fused mode never dispatches the standalone flush
-                # mid-run (the megakernel owns it — the triple walk
-                # below covers its tiers), so only rehash compiles here
-                out = self._rehash_jit()(*fpset.empty_cols(self.TCAP, K))
-                drain(out)
-                del out
-                self.TCAP *= 2
-                self.VCAP = self.TCAP // 2
-                if fused:
-                    continue
-                ak = tuple(
-                    jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
-                    for _ in range(K)
-                )
-                out = self._fpflush_jit()(
-                    *fpset.empty_cols(self.TCAP, K), *ak,
-                    jnp.int32(0), z((FPM_N,), jnp.int32),
-                )
-                drain(out)
-                del ak, out
-        else:
-            while self.VCAP < cap:
-                self.VCAP += min(self.VCAP, cap - self.VCAP)
-                vk = tuple(
-                    jnp.full((self.VCAP,), SENTINEL, jnp.uint32)
-                    for _ in range(K)
-                )
-                ak = tuple(
-                    jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
-                    for _ in range(K)
-                )
-                out = self._flush_jit()(*vk, *ak, jnp.int32(0))
-                drain(out)
-                del vk, ak, out
+        while self.VCAP < cap:
+            # the growth path's exact sequence: rehash AT the
+            # current tier (old -> doubled), then flush at the new.
+            # Fused mode never dispatches the standalone flush
+            # mid-run (the megakernel owns it — the triple walk
+            # below covers its tiers), so only rehash compiles here
+            out = self._rehash_jit()(*fpset.empty_cols(self.TCAP, K))
+            drain(out)
+            del out
+            self.TCAP *= 2
+            self.VCAP = self.TCAP // 2
+            if fused:
+                continue
+            ak = tuple(
+                jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
+                for _ in range(K)
+            )
+            out = self._fpflush_jit()(
+                *fpset.empty_cols(self.TCAP, K), *ak,
+                jnp.int32(0), z((FPM_N,), jnp.int32),
+            )
+            drain(out)
+            del ak, out
         # row/log tiers grow only in rows_window="all" (frontier mode
         # fixes the window and presizes the logs to SCAP up front).
         # Fused mode skips the stage slice/append tier compiles for
@@ -2286,9 +1979,7 @@ class DeviceChecker:
                 )
                 drain(app)
                 del app
-        (tc, self.VCAP, self.LCAP, self.PCAP) = save
-        if tc is not None:
-            self.TCAP = tc
+        (self.TCAP, self.VCAP, self.LCAP, self.PCAP) = save
         if fused:
             # walk the UNIFIED fused growth staircase (one need drives
             # every dimension — see _grow_fused) and compile the level
@@ -2300,18 +1991,15 @@ class DeviceChecker:
                 self.TCAP, self.VCAP = tcap, vcap
                 self.LCAP, self.PCAP = lcap, pcap
                 key = (
-                    "fused", tcap, lcap, pcap, self.compact_impl,
+                    "fused", tcap, lcap, pcap,
                     self.fps_dense, self.fps_stages, self.RMAX,
-                    self.probe_impl, self.expand_impl,
                 )
                 if key in self._jits:
                     continue  # the entry triple compiled in warmup()
                 out = self._warm_fused(viol0)
                 drain(out)
                 del out
-            (tc, self.VCAP, self.LCAP, self.PCAP) = save
-            if tc is not None:
-                self.TCAP = tc
+            (self.TCAP, self.VCAP, self.LCAP, self.PCAP) = save
             # the INIT path still dispatches the stage chain, at the
             # tier its growth reaches (n_initial + one accumulator /
             # append window — model-known here): compile the two
@@ -2331,8 +2019,7 @@ class DeviceChecker:
                     self.LCAP, n_init + self.APAD, capl
                 )
             if (
-                "fpflush", self.TCAP, self.compact_impl,
-                self.fps_dense, self.fps_stages, self.probe_impl,
+                "fpflush", self.TCAP, self.fps_dense, self.fps_stages,
             ) not in self._jits:
                 ak = tuple(
                     jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
@@ -2448,29 +2135,17 @@ class DeviceChecker:
             mark("expand")
             ak, arows = out[:K], out[K]
             del window
-        fpmode = self.visited_impl == "fpset"
-        seed_tbl = None
-        if fpmode:
-            tc = fpset.empty_cols(self.TCAP, K)
-            fpm0 = jnp.zeros((FPM_N,), jnp.int32)
-            out = self._fpflush_jit()(*tc, *ak, jnp.int32(0), fpm0)
-            drain(out)
-            mark("flush")
-            del tc
-            # the donated-input flush returns the table; reuse it as the
-            # seed-merge compile dummy instead of allocating a second
-            # TCAP-sized table (dropped right away when no seed compile
-            # is coming — it must not squat HBM under the append dummy)
-            seed_tbl = out[:K] if seed else None
-        else:
-            vk = tuple(
-                jnp.full((self.VCAP,), SENTINEL, jnp.uint32)
-                for _ in range(K)
-            )
-            out = self._flush_jit()(*vk, *ak, jnp.int32(0))
-            drain(out)
-            mark("flush")
-            del vk
+        tc = fpset.empty_cols(self.TCAP, K)
+        fpm0 = jnp.zeros((FPM_N,), jnp.int32)
+        out = self._fpflush_jit()(*tc, *ak, jnp.int32(0), fpm0)
+        drain(out)
+        mark("flush")
+        del tc
+        # the donated-input flush returns the table; reuse it as the
+        # seed-merge compile dummy instead of allocating a second
+        # TCAP-sized table (dropped right away when no seed compile
+        # is coming — it must not squat HBM under the append dummy)
+        seed_tbl = out[:K] if seed else None
         flag_w = out[K + 1]
         del out
         crows, idx_w = self._compact_jit()(arows, flag_w)
@@ -2488,14 +2163,11 @@ class DeviceChecker:
         drain(app)
         mark("append")
         del app, ak, crows, idx_w
-        if fpmode:
-            drain(
-                self._stats_jit()(
-                    jnp.int32(0), BIG, viol0, jnp.zeros((FPM_N,), jnp.int32)
-                )
+        drain(
+            self._stats_jit()(
+                jnp.int32(0), BIG, viol0, jnp.zeros((FPM_N,), jnp.int32)
             )
-        else:
-            drain(self._stats_jit()(jnp.int32(0), BIG, viol0))
+        )
         drain(
             self._chain_jit(4)(
                 z((self._logs_len(),), jnp.int32),
@@ -2544,27 +2216,14 @@ class DeviceChecker:
             mark("spill")
         if seed:
             write = self._seed_write_jit()
-            if fpmode:
-                drain(
-                    self._fpseed_merge_jit()(
-                        *seed_tbl,
-                        z((self.SEED_CHUNK, self.W), jnp.uint32),
-                        jnp.int32(0), jnp.int32(0), viol0,
-                        jnp.int32(0), jnp.zeros((FPM_N,), jnp.int32),
-                    )
+            drain(
+                self._fpseed_merge_jit()(
+                    *seed_tbl,
+                    z((self.SEED_CHUNK, self.W), jnp.uint32),
+                    jnp.int32(0), jnp.int32(0), viol0,
+                    jnp.int32(0), jnp.zeros((FPM_N,), jnp.int32),
                 )
-            else:
-                merge = self._seed_merge_jit()
-                vks = tuple(
-                    jnp.full((self.SEED_VCAP,), SENTINEL, jnp.uint32)
-                    for _ in range(K)
-                )
-                drain(
-                    merge(
-                        *vks, z((self.SEED_CHUNK, self.W), jnp.uint32),
-                        jnp.int32(0), jnp.int32(0), viol0, jnp.int32(0),
-                    )
-                )
+            )
             drain(
                 write(
                     z((self._rows_len(),), jnp.uint32),
@@ -2695,9 +2354,7 @@ class DeviceChecker:
             tune_online.OnlineController(
                 self.RMAX, self.fps_dense, self.fps_stages
             )
-            if self.adapt
-            and self.fuse == "level"
-            and self.visited_impl == "fpset"
+            if self.adapt and self.fuse == "level"
             else None
         )
         # per-run dispatch accounting baseline (the stage counters in
@@ -2761,13 +2418,7 @@ class DeviceChecker:
         f = dict(
             engine="device_bfs",
             device=dev,
-            visited_impl=self.visited_impl,
-            compact_impl=self.compact_impl,
-            # v16: dense-tile kernel selection (r23, ops/tiles.py) —
-            # always present so the ledger can split impl trajectories
-            probe_impl=self.probe_impl,
-            expand_impl=self.expand_impl,
-            sieve_impl=self.sieve_impl,
+            **obs.IMPL_FIELDS,
             fuse=self.fuse,
             fuse_group=self.RMAX,
             config_sig=self._config_sig(),
@@ -2894,14 +2545,7 @@ class DeviceChecker:
         n_inv = len(self.invariant_names)
         K = self.K
         bufs = {
-            "vk": (
-                fpset.empty_cols(self.TCAP, K)
-                if self.visited_impl == "fpset"
-                else tuple(
-                    jnp.full((self.VCAP,), SENTINEL, jnp.uint32)
-                    for _ in range(K)
-                )
-            ),
+            "vk": fpset.empty_cols(self.TCAP, K),
             "ak": tuple(
                 jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
                 for _ in range(K)
@@ -2919,12 +2563,10 @@ class DeviceChecker:
             "n_visited": jnp.int32(0),
             "dead_gid": BIG,
             "viol": jnp.full((n_inv,), int(BIG), jnp.int32),
-        }
-        fpmode = self.visited_impl == "fpset"
-        if fpmode:
             # device-accumulated fpset metrics [flushes, probe rounds,
             # failures] — ride the regular stats fetch
-            st["fpm"] = jnp.zeros((FPM_N,), jnp.int32)
+            "fpm": jnp.zeros((FPM_N,), jnp.int32),
+        }
         if self.fuse == "level":
             # device-accumulated work units (r14) — ride the fused
             # kernel's packed stats vector, zero extra syncs
@@ -3033,22 +2675,15 @@ class DeviceChecker:
         one, so a fused level pays NO separate stats dispatch); its
         prefix layout matches ``_stats_jit`` and any tail beyond the
         fpm block is returned untouched for the caller to parse."""
-        fpmode = self.visited_impl == "fpset"
         # the host blocked on the device: host_fetch_s (= host_wait_s)
         with self._clock.phase("fetch"):
             if vec is not None:
                 out = np.asarray(vec)
-            elif fpmode:
+            else:
                 out = np.asarray(
                     self._stats_jit()(
                         st["n_visited"], st["dead_gid"], st["viol"],
                         st["fpm"],
-                    )
-                )
-            else:
-                out = np.asarray(
-                    self._stats_jit()(
-                        st["n_visited"], st["dead_gid"], st["viol"]
                     )
                 )
         self._fetch_n += 1
@@ -3088,30 +2723,28 @@ class DeviceChecker:
         if stage_append > 0:
             self._work_add(append_rows=stage_append)
         self._work_nv_prev = nv
-        if fpmode:
-            n_inv = len(self.invariant_names)
-            self._last_fpm = out[2 + n_inv: 2 + n_inv + FPM_N]
-            self._snap["occupancy"] = nv / max(self.TCAP, 1)
-            if len(self._last_fpm) >= 4:
-                # TLC's "states generated": candidate lanes examined
-                # (64-bit reassembly of the hi/lo words, r12)
-                self._snap["generated"] = int(
-                    fpset.fpm_logical(self._last_fpm)[3]
-                )
-            self._emit_flush_event(nv)
+        n_inv = len(self.invariant_names)
+        self._last_fpm = out[2 + n_inv: 2 + n_inv + FPM_N]
+        self._snap["occupancy"] = nv / max(self.TCAP, 1)
+        if len(self._last_fpm) >= 4:
+            # TLC's "states generated": candidate lanes examined
+            # (64-bit reassembly of the hi/lo words, r12)
+            self._snap["generated"] = int(
+                fpset.fpm_logical(self._last_fpm)[3]
+            )
+        self._emit_flush_event(nv)
         self._emit_compact_event()
-        if fpmode:
-            if self._last_fpm[2]:
-                # probe overflow: lanes were dropped by flushes
-                # already appended — the counts cannot be trusted,
-                # so this is a hard abort, not a truncation
-                raise RuntimeError(
-                    "fpset probe overflow "
-                    f"({int(self._last_fpm[2])} lanes) — "
-                    + fpset.schedule_hint(
-                        self.fps_dense, self.fps_stages
-                    )
+        if self._last_fpm[2]:
+            # probe overflow: lanes were dropped by flushes
+            # already appended — the counts cannot be trusted,
+            # so this is a hard abort, not a truncation
+            raise RuntimeError(
+                "fpset probe overflow "
+                f"({int(self._last_fpm[2])} lanes) — "
+                + fpset.schedule_hint(
+                    self.fps_dense, self.fps_stages
                 )
+            )
         return out
 
     def _emit_flush_event(self, nv: int):
@@ -3143,9 +2776,7 @@ class DeviceChecker:
         """One ``compact`` record per stats fetch covering the compact
         dispatches since the previous fetch — free host-side counters
         (``stage_compact_n``; drain seconds under PTT_STAGE_TIMING),
-        zero extra device syncs.  The per-stage report layer pairs it
-        with the run header's ``compact_impl`` for the sort-vs-logshift
-        before/after table (round 10)."""
+        zero extra device syncs."""
         if not self.tel.enabled:
             return
         n = int(self.last_stats.get("stage_compact_n", 0))
@@ -3153,7 +2784,7 @@ class DeviceChecker:
         if d <= 0:
             return
         self._compact_prev = n
-        f = dict(dispatches=d, impl=self.compact_impl)
+        f = dict(dispatches=d, impl=obs.IMPL_FIELDS["compact_impl"])
         s = self.last_stats.get("stage_compact_s")
         if s is not None:
             f["drain_s"] = round(s - self._compact_prev_s, 4)
@@ -3164,11 +2795,8 @@ class DeviceChecker:
     def _flush_acc(self, bufs, st, rb, n_acc, acc_base, is_init):
         """Dispatch the dedup + append for the current accumulator
         fill (``n_acc`` valid lanes covering source rows starting
-        at ``acc_base``): table probe-or-insert in fpset mode, the
-        legacy 3-sort merge in sort mode — identical flag/append
-        contract either way."""
+        at ``acc_base``): table probe-or-insert, compaction, append."""
         K = self.K
-        fpmode = self.visited_impl == "fpset"
         # host-side work units (r14), mirroring the fused kernel's
         # in-kernel definitions exactly: the full accumulator width is
         # what the flush probes and the compaction moves (dense cost is
@@ -3181,34 +2809,22 @@ class DeviceChecker:
         kinds = faults.poll("flush", self._flush_seq)
         if "oom" in kinds:
             raise faults.oom_error("flush", self._flush_seq)
-        if "fpset_fail" in kinds and fpmode:
+        if "fpset_fail" in kinds:
             # synthetic stage overflow: account one dropped lane in
             # the device metrics — the next stats fetch fail-stops
             # exactly like a real probe overflow would
             st["fpm"] = st["fpm"] + jnp.asarray(
                 [0, 0, 1] + [0] * (FPM_N - 3), jnp.int32
             )
-        if fpmode:
-            out = self._stage_mark(
-                "flush",
-                self._fpflush_jit()(
-                    *bufs["vk"], *bufs["ak"], jnp.int32(n_acc),
-                    st["fpm"],
-                ),
-            )
-            bufs["vk"] = out[:K]
-            n_new, flag_acc, st["fpm"] = (
-                out[K], out[K + 1], out[K + 2]
-            )
-        else:
-            out = self._stage_mark(
-                "flush",
-                self._flush_jit()(
-                    *bufs["vk"], *bufs["ak"], jnp.int32(n_acc)
-                ),
-            )
-            bufs["vk"] = out[:K]
-            n_new, flag_acc = out[K], out[K + 1]
+        out = self._stage_mark(
+            "flush",
+            self._fpflush_jit()(
+                *bufs["vk"], *bufs["ak"], jnp.int32(n_acc),
+                st["fpm"],
+            ),
+        )
+        bufs["vk"] = out[:K]
+        n_new, flag_acc, st["fpm"] = out[K], out[K + 1], out[K + 2]
         if self.tiered:
             # cold-tier miss resolution (r16): lanes the hot filter
             # flagged new may be duplicates of EVICTED keys; resolve
@@ -4310,7 +3926,7 @@ class DeviceChecker:
             state_bits=self.layout.total_bits,
             key_cols=self.K,
             key_exact=self.keys.exact,
-            visited_impl=self.visited_impl,
+            visited_impl=obs.IMPL_FIELDS["visited_impl"],
             rows_window=self.rows_window,
             engine="device_bfs_r7",
             **({"tiered": True} if self.tiered else {}),
@@ -4360,11 +3976,7 @@ class DeviceChecker:
             "nf": np.int64(nf),
             "rows_lo": np.int64(lo),
             "hbm_recovered": np.int64(self._hbm_recovered),
-            "fpm": (
-                np.asarray(st["fpm"])
-                if self.visited_impl == "fpset"
-                else np.zeros((FPM_N,), np.int32)
-            ),
+            "fpm": np.asarray(st["fpm"]),
             # logs are windowed ONLY in tiered mode (frontier mode
             # windows the rows but keeps full logs)
             "parent": np.asarray(
@@ -4380,19 +3992,11 @@ class DeviceChecker:
                 ]
             ),
         }
-        if self.visited_impl == "fpset":
-            # compacted occupancy (keys + slot index): frame size
-            # scales with the state count, not the table tier
-            arrays.update(
-                ckpt.pack_fpset(
-                    tuple(np.asarray(c) for c in bufs["vk"])
-                )
-            )
-        else:
-            for i, col in enumerate(bufs["vk"]):
-                # sorted columns: the first nv entries are the real
-                # keys (SENTINEL pad sorts behind every real key)
-                arrays[f"vk{i}"] = np.asarray(col[:nv])
+        # compacted occupancy (keys + slot index): frame size
+        # scales with the state count, not the table tier
+        arrays.update(
+            ckpt.pack_fpset(tuple(np.asarray(c) for c in bufs["vk"]))
+        )
         if self.tiered:
             # the spill manifest: every cold run/segment with file
             # names + content digests, so resume restores the WHOLE
@@ -4477,33 +4081,18 @@ class DeviceChecker:
                 f"checkpoint holds {nv} states — beyond max_states "
                 f"({self.SCAP}); raise max_states to resume it"
             )
-        if self.visited_impl == "fpset":
-            cols = ckpt.unpack_fpset(d, K)
-            # the snapshot fixes the table tier (jit programs are
-            # tier-keyed, so no cache invalidation is needed); growth,
-            # if the resumed run needs it, goes through regular rehash.
-            # jnp.array (copy=True), NOT jnp.asarray: on the CPU
-            # backend asarray can alias the numpy buffer zero-copy,
-            # and the flush DONATES these columns — donating memory
-            # numpy still owns is a use-after-free (observed as flaky
-            # probe overflows and GC segfaults in the resume tests)
-            self.TCAP = cols[0].shape[0] - 1
-            self.VCAP = self.TCAP // 2
-            vk = tuple(jnp.array(c) for c in cols)
-        else:
-            while self.VCAP < nv + self.ACAP:
-                self.VCAP *= 2
-            vk = tuple(
-                jnp.concatenate(
-                    [
-                        jnp.asarray(np.asarray(d[f"vk{i}"], np.uint32)),
-                        jnp.full(
-                            (self.VCAP - nv,), SENTINEL, jnp.uint32
-                        ),
-                    ]
-                )
-                for i in range(K)
-            )
+        cols = ckpt.unpack_fpset(d, K)
+        # the snapshot fixes the table tier (jit programs are
+        # tier-keyed, so no cache invalidation is needed); growth,
+        # if the resumed run needs it, goes through regular rehash.
+        # jnp.array (copy=True), NOT jnp.asarray: on the CPU
+        # backend asarray can alias the numpy buffer zero-copy,
+        # and the flush DONATES these columns — donating memory
+        # numpy still owns is a use-after-free (observed as flaky
+        # probe overflows and GC segfaults in the resume tests)
+        self.TCAP = cols[0].shape[0] - 1
+        self.VCAP = self.TCAP // 2
+        vk = tuple(jnp.array(c) for c in cols)
         # size the row/log tiers BEFORE allocating (same doubling-with-
         # cap formulas as _grow_store/_grow_logs, minus the buffers).
         # Tiered frames hold the device WINDOW only, so the need is
@@ -4601,17 +4190,16 @@ class DeviceChecker:
             "dead_gid": BIG,
             "viol": jnp.full((n_inv,), int(BIG), jnp.int32),
         }
-        if self.visited_impl == "fpset":
-            # pre-widening frames carry the 3- or 5-wide fpm prefix;
-            # zero-pad the new counters (the r8 valid_lanes /
-            # max_probe_rounds and the r12 valid_lanes_hi word restart)
-            old = np.asarray(d["fpm"], np.int32).reshape(-1)
-            fpm = np.zeros((FPM_N,), np.int32)
-            fpm[: min(len(old), FPM_N)] = old[:FPM_N]
-            st["fpm"] = jnp.asarray(fpm)
-            # flush telemetry deltas continue from the frame's counts,
-            # not from zero (a resumed run must not re-report them)
-            self._fpm_prev = fpset.fpm_logical(fpm)
+        # pre-widening frames carry the 3- or 5-wide fpm prefix;
+        # zero-pad the new counters (the r8 valid_lanes /
+        # max_probe_rounds and the r12 valid_lanes_hi word restart)
+        old = np.asarray(d["fpm"], np.int32).reshape(-1)
+        fpm = np.zeros((FPM_N,), np.int32)
+        fpm[: min(len(old), FPM_N)] = old[:FPM_N]
+        st["fpm"] = jnp.asarray(fpm)
+        # flush telemetry deltas continue from the frame's counts,
+        # not from zero (a resumed run must not re-report them)
+        self._fpm_prev = fpset.fpm_logical(fpm)
         if self.fuse == "level":
             # work counters restart after resume (frames don't carry
             # them — the same regime as the r8 counter widenings);
@@ -4811,7 +4399,7 @@ class DeviceChecker:
     ) -> CheckerResult:
         self.last_bufs = bufs  # part of the result: the class docstring
         wall = time.perf_counter() - t0
-        if self.visited_impl == "fpset" and self._last_fpm is not None:
+        if self._last_fpm is not None:
             # per-run fpset metrics for bench.py artifacts: flush count,
             # cumulative probe rounds (avg = rounds/flushes), failures
             # (always 0 here — nonzero aborts at the fetch), and the
@@ -4888,13 +4476,7 @@ class DeviceChecker:
         # survivability telemetry for bench artifacts (r7/r8/r9)
         self.last_stats.update(
             fuse=self.fuse,
-            compact_impl=self.compact_impl,
-            # dense-tile kernel selection (r23): ride the stats dict so
-            # bench artifacts and the ledger see the impls without a
-            # header join
-            probe_impl=self.probe_impl,
-            expand_impl=self.expand_impl,
-            sieve_impl=self.sieve_impl,
+            **obs.IMPL_FIELDS,
             hbm_recovered=self._hbm_recovered,
             ckpt_frames=self._ckpt_frames,
             ckpt_bytes=self._ckpt_bytes,

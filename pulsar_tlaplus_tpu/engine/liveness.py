@@ -105,7 +105,6 @@ class LivenessChecker:
         max_states: int = 50_000_000,
         sweep_chunk: Optional[int] = None,
         sweep_group: Optional[int] = None,
-        compact_impl: Optional[str] = None,
         hbm_budget=None,
         spill_compress: Optional[bool] = None,
         profile=None,
@@ -165,16 +164,7 @@ class LivenessChecker:
         _pk = tune_profiles.knobs_for(prof, "liveness")
         if sweep_group is None:
             sweep_group = _pk.get("sweep_group")
-        compact_impl = (
-            compact_impl or _pk.get("compact_impl") or "logshift"
-        )
         self.sweep_group = sweep_group
-        # stream-compaction impl for the sweep's edge compaction (and
-        # the inner explorer's append): ops/compact.py log-shift by
-        # default, "sort" for differential timing
-        from pulsar_tlaplus_tpu.ops import compact as compact_ops
-
-        self.compact_impl = compact_ops.validate_impl(compact_impl)
         # pointer-jumping cap for the sweep's equal-key gid propagation
         # (ADVICE r5): doubling shifts d = 1, 2, ..., p (p = the
         # largest power of two <= max_run) cover a fill distance of
@@ -209,7 +199,6 @@ class LivenessChecker:
         inner_kw = dict(
             checkpoint_path=checkpoint_path,
             checkpoint_every=checkpoint_every,
-            compact_impl=compact_impl,
         )
         # resolve the ctor-or-PTT_HBM_BUDGET budget HERE so the env
         # var gets the same gating/forwarding as the explicit knob
@@ -506,7 +495,7 @@ class LivenessChecker:
         compacted output planes scale with G.  Chunks past the live
         prefix produce zero kept lanes (their query lanes are masked
         invalid), so a partial tail group is harmless."""
-        key = ("sweep", cap, G, self.compact_impl)
+        key = ("sweep", cap, G)
         if key in self._jits:
             return self._jits[key]
         m, layout = self.model, self.model.layout
@@ -583,7 +572,7 @@ class LivenessChecker:
                 (~keep).astype(jnp.uint32),
                 (lane.astype(jnp.uint32),
                  lax.bitcast_convert_type(dst, jnp.uint32)),
-                impl=self.compact_impl, need_idx=False,
+                need_idx=False,
             )
             n_kept = jnp.sum(keep.astype(jnp.int32))
             return n_kept, idxc, dstc
@@ -1064,8 +1053,7 @@ class LivenessChecker:
         f = dict(
             engine="liveness",
             device=dev,
-            visited_impl=self._checker.visited_impl,
-            compact_impl=self.compact_impl,
+            **obs.IMPL_FIELDS,
             config_sig=self._config_sig(),
             # v8: the liveness engine's own tuned-profile attribution
             # (the inner explorer's header carries its own)
@@ -1076,11 +1064,6 @@ class LivenessChecker:
             warm=getattr(self, "warm", None),
             # v15: distributed-trace identity (None outside the daemon)
             trace_id=getattr(self, "trace_id", None),
-            # v16: dense-tile kernel selection — null here; only
-            # device_bfs carries the ops/tiles.py impl knobs
-            probe_impl=None,
-            expand_impl=None,
-            sieve_impl=None,
             # v11: workload class (two-phase liveness check)
             mode="liveness",
             wall_unix=round(time.time(), 3),

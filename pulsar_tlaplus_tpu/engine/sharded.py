@@ -524,8 +524,8 @@ class ShardedChecker:
             warm=getattr(self, "warm", None),
             # v15: distributed-trace identity (None outside the daemon)
             trace_id=getattr(self, "trace_id", None),
-            # v16: dense-tile kernel selection — null here; only
-            # device_bfs carries the ops/tiles.py impl knobs
+            # v16: the kernel fields of the device engines' headers
+            # (obs/telemetry.py IMPL_FIELDS) — null here
             probe_impl=None,
             expand_impl=None,
             sieve_impl=None,

@@ -13,10 +13,9 @@ design (``engine/device_bfs.py``) into ``shard_map``:
   buckets the candidate lanes by key owner (one-hot running-rank, no
   host), and one ``all_to_all`` routes keys + packed rows + parent gid +
   action lane to the owning shards (ICI traffic on a real slice);
-- received lanes accumulate locally; the flush (the shared
-  ``ops.dedup.merge_new_keys`` sort-merge) and append run per shard
-  inside the same jitted program — sort sizes are ``1/n_shards`` of the
-  single-chip engine's, which is where the multi-chip speedup lives;
+- received lanes accumulate locally; the flush (a probe of the
+  owner's ``ops.fpset`` table) and append run per shard inside the
+  same jitted program;
 - the host fetches ONE per-shard stats matrix per group of flushes and
   only orchestrates: rounds, levels, growth, verdicts.
 
@@ -56,7 +55,7 @@ from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.utils import ckpt, faults, recovery
 from pulsar_tlaplus_tpu.engine.bfs import CheckerResult
 from pulsar_tlaplus_tpu.ops import compact as compact_ops
-from pulsar_tlaplus_tpu.ops import dedup, fpset
+from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, KeySpec
 from pulsar_tlaplus_tpu.ref import pyeval
 
@@ -68,8 +67,6 @@ BIG = jnp.int32(2**31 - 1)
 # r12 (hi/lo uint32 valid-lane words survive the int32 wrap;
 # ops/fpset.py is the shared source)
 FPM_N = fpset.FPM_N
-TAG_BIT = jnp.uint32(1 << 31)
-IDX_MASK = jnp.uint32((1 << 31) - 1)
 
 # per-shard route state riding the stats fetch: [sticky overflow flag,
 # lanes sent through the key exchange as a hi/lo uint32 pair (LO, HI)]
@@ -320,8 +317,6 @@ class ShardedDeviceChecker:
         checkpoint_path: Optional[str] = None,
         checkpoint_every: int = 5,
         n_slices: int = 1,
-        visited_impl: str = "fpset",
-        compact_impl: str = "logshift",
         fpset_dense_rounds: Optional[int] = None,
         fpset_stages=None,
         telemetry=None,
@@ -387,25 +382,12 @@ class ShardedDeviceChecker:
         self.K = self.keys.ncols
         if fp_bits is None:
             self.keys.warn_if_hashed(max_states)
-        # Visited-set implementation (round 6): "fpset" = per-shard
-        # ownership-sharded HBM hash tables (ops/fpset.py) — the routed
-        # key planes probe the OWNER's table instead of feeding the
-        # per-shard sort-merge, so owner-side dedup is O(routed batch),
-        # not O(owned keys).  "sort" keeps the legacy flush for
-        # differential testing.  VCAP stays "max owned keys per shard
-        # before growth"; the fpset table carries TCAP = 2 * VCAP slots
-        # so the existing nk_bound <= VCAP invariant IS the load-factor
-        # <= 1/2 contract.
-        if visited_impl not in ("fpset", "sort"):
-            raise ValueError(
-                f"visited_impl must be fpset|sort: {visited_impl}"
-            )
-        self.visited_impl = visited_impl
-        # stream-compaction impl for the per-shard append and the
-        # fpset's staged pending-compaction (round 10): log-shift by
-        # default, the round-4 chunked sorts behind "sort" for
-        # differential timing (see ops/compact.py)
-        self.compact_impl = compact_ops.validate_impl(compact_impl)
+        # The visited set: per-shard ownership-sharded HBM hash tables
+        # (ops/fpset.py) — the routed key planes probe the OWNER's
+        # table, so owner-side dedup is O(routed batch), not O(owned
+        # keys).  VCAP is "max owned keys per shard before growth"; the
+        # table carries TCAP = 2 * VCAP slots so the nk_bound <= VCAP
+        # invariant IS the load-factor <= 1/2 contract.
         # fpset probe schedule: ctor params > PTT_FPSET_SCHEDULE env >
         # ops/fpset.py defaults (sweepable on the real chip against
         # the fpset_max_probe_rounds telemetry signal)
@@ -618,10 +600,8 @@ class ShardedDeviceChecker:
 
     def _vk_width(self) -> int:
         """Per-shard width of a visited column: TCAP slots + the trash
-        row in fpset mode, the sorted-column capacity in sort mode."""
-        return (
-            self.TCAP + 1 if self.visited_impl == "fpset" else self.VCAP
-        )
+        row."""
+        return self.TCAP + 1
 
     def _log(self, msg: str):
         if self.progress:
@@ -862,15 +842,11 @@ class ShardedDeviceChecker:
         addresses — one u32 plane per hop instead of the round-4
         design's K+2+W routed planes per round.
 
-        fpset mode (round 6): the routed key planes PROBE the owner's
-        HBM hash table (``fpset.lookup_or_insert``) instead of feeding
-        the per-shard sort-merge — no owned-keys-width sort, no payload
-        projection sort (the probe's is_new IS the owner-acc-order flag
-        vector), and per-shard probe metrics accumulate in ``fpm``."""
-        key = (
-            "flush", self.VCAP, self.visited_impl, self.compact_impl,
-            self.fps_dense, self.fps_stages,
-        )
+        The routed key planes PROBE the owner's HBM hash table
+        (``fpset.lookup_or_insert``): the probe's is_new IS the
+        owner-acc-order flag vector, and per-shard probe metrics
+        accumulate in ``fpm``."""
+        key = ("flush", self.VCAP, self.fps_dense, self.fps_stages)
         if key in self._jits:
             return self._jits[key]
         K, ACAP, PACAP = self.K, self.ACAP, self.PACAP
@@ -882,43 +858,25 @@ class ShardedDeviceChecker:
             aq, aq2, n_keys, fpm = aq[0], aq2[0], n_keys[0], fpm[0]
             lanei = jnp.arange(ACAP, dtype=jnp.int32)
             amask = lanei < n_acc
-            if self.visited_impl == "fpset":
-                valid = amask & ~fpset.all_sentinel(ak)
-                is_new, vk2, n_failed, rounds, lane_rounds = (
-                    fpset.lookup_or_insert(
-                        vk, ak, valid,
-                        dense_rounds=self.fps_dense,
-                        stages=self.fps_stages,
-                        compact_impl=self.compact_impl,
-                    )
+            valid = amask & ~fpset.all_sentinel(ak)
+            is_new, vk2, n_failed, rounds, lane_rounds = (
+                fpset.lookup_or_insert(
+                    vk, ak, valid,
+                    dense_rounds=self.fps_dense,
+                    stages=self.fps_stages,
                 )
-                n_new_owner = jnp.sum(is_new.astype(jnp.int32))
-                flag_own = is_new.astype(jnp.uint32)
-                # zero-sync metrics (r9, = device_bfs.FPM_N):
-                # valid_lanes is the routed-candidate count after
-                # masking (duplicate-rate denominator; hi/lo uint32
-                # words since r12); col 4 is the worst flush's probe
-                # depth (running max, not a sum)
-                fpm = fpset.fpm_update(
-                    fpm, rounds, n_failed,
-                    jnp.sum(valid.astype(jnp.int32)), lane_rounds,
-                )
-            else:
-                ccols = tuple(
-                    jnp.where(amask, a, SENTINEL) for a in ak
-                )
-                cpay = lanei.astype(jnp.uint32) | TAG_BIT
-                vk2, n_new_owner, sp, new_flag = dedup.merge_new_keys(
-                    vk, ccols, cpay
-                )
-                # owner-acc-order flags (candidate payloads sort above
-                # visited zeros, ascending by slot — the tail of a
-                # payload sort)
-                _, flag_sorted = lax.sort(
-                    (sp, new_flag.astype(jnp.uint32)), num_keys=1,
-                    is_stable=False,
-                )
-                flag_own = flag_sorted[sp.shape[0] - ACAP:]
+            )
+            n_new_owner = jnp.sum(is_new.astype(jnp.int32))
+            flag_own = is_new.astype(jnp.uint32)
+            # zero-sync metrics (r9, = device_bfs.FPM_N):
+            # valid_lanes is the routed-candidate count after
+            # masking (duplicate-rate denominator; hi/lo uint32
+            # words since r12); col 4 is the worst flush's probe
+            # depth (running max, not a sum)
+            fpm = fpset.fpm_update(
+                fpm, rounds, n_failed,
+                jnp.sum(valid.astype(jnp.int32)), lane_rounds,
+            )
             if self.N == 1:
                 flag_local = flag_own  # PACAP == ACAP, same order
             elif len(self._axes) == 1:
@@ -958,18 +916,15 @@ class ShardedDeviceChecker:
         own dispatch (round 10): the producer-acc-order new-flag
         compacts the W word columns + routed parent/lane to the front
         in arrival order — ``(arows, apar, alane, flag_acc) -> (crows,
-        cpar, clane)``, all producer-local.  Log-shift by default
-        (``ops/compact.py``), the round-4 chunked single-key sorts
-        behind ``compact_impl="sort"`` for differential timing.  The
+        cpar, clane)``, all producer-local (``ops/compact.py``).  The
         producer accumulator triple is DONATED and the compacted
         triple recycled as the next fill's buffers (same contract as
         the single-chip engine's split), so the extra dispatch adds no
         resident HBM."""
-        key = ("compact", self.compact_impl)
+        key = ("compact",)
         if key in self._jits:
             return self._jits[key]
         W = self.W
-        impl = self.compact_impl
 
         @spans.staged("compact")
         def ptt_shard_compact(arows, apar, alane, flag_acc):
@@ -981,7 +936,7 @@ class ShardedDeviceChecker:
                 lax.bitcast_convert_type(alane, jnp.uint32),
             )
             out, _idx = compact_ops.compact_by_flag(
-                drop, cols, impl=impl, need_idx=False
+                drop, cols, need_idx=False
             )
             crows = jnp.stack(out[:W])
             cpar = lax.bitcast_convert_type(out[W], jnp.int32)
@@ -1381,33 +1336,16 @@ class ShardedDeviceChecker:
 
     @spans.in_phase("grow")
     def _grow_visited(self, bufs, need: int):
-        if self.visited_impl == "fpset":
-            while self.VCAP < need:
-                out = self._rehash_jit()(bufs["vk"])
-                bufs["vk"] = tuple(out[0])
-                if np.asarray(out[1]).any():
-                    raise RuntimeError(
-                        "fpset rehash overflow — table corrupted its "
-                        "load-factor contract (bug)"
-                    )
-                self.TCAP *= 2
-                self.VCAP = self.TCAP // 2
-            return
         while self.VCAP < need:
-            pad = self.VCAP
-            bufs["vk"] = tuple(
-                jnp.concatenate(
-                    [
-                        col,
-                        self._dev_fill(
-                            (self.N, pad), SENTINEL, jnp.uint32
-                        ),
-                    ],
-                    axis=1,
+            out = self._rehash_jit()(bufs["vk"])
+            bufs["vk"] = tuple(out[0])
+            if np.asarray(out[1]).any():
+                raise RuntimeError(
+                    "fpset rehash overflow — table corrupted its "
+                    "load-factor contract (bug)"
                 )
-                for col in bufs["vk"]
-            )
-            self.VCAP *= 2
+            self.TCAP *= 2
+            self.VCAP = self.TCAP // 2
 
     @spans.in_phase("grow")
     def _grow_store(self, bufs, need: int):
@@ -1489,12 +1427,9 @@ class ShardedDeviceChecker:
                 self.SB,
                 # r5: producer-local rows changed the gid numbering and
                 # the checkpoint fields — r4 frames must not resume.
-                # r6: fpset mode stores full hash-table columns instead
-                # of sorted prefixes; sort-mode frames keep the r5 sig
-                # so they remain resumable under -visited sort
-                "sharded_device_r5"
-                if self.visited_impl == "sort"
-                else "sharded_device_r6_fpset",
+                # r6: frames store hash-table columns, not the sorted
+                # prefixes of r5
+                "sharded_device_r6_fpset",
             )
         )
 
@@ -1519,18 +1454,10 @@ class ShardedDeviceChecker:
         nvis = np.asarray(st["n_visited"]).astype(np.int64)
         nkeys = np.asarray(st["n_keys"]).astype(np.int64)
         mx = int(nvis.max())
-        mk = int(nkeys.max())  # owner-side key counts size the vk slice
         W = self.W
-        if self.visited_impl == "fpset":
-            vk_arrays = ckpt.pack_fpset(
-                [np.asarray(col) for col in bufs["vk"]]
-            )
-        else:
-            # sorted columns keep the compact mk-prefix slice
-            vk_arrays = {
-                f"vk{i}": np.asarray(col[:, :mk])
-                for i, col in enumerate(bufs["vk"])
-            }
+        vk_arrays = ckpt.pack_fpset(
+            [np.asarray(col) for col in bufs["vk"]]
+        )
         nbytes, write_s, retries = ckpt.save_frame(
             self.checkpoint_path,
             self._config_sig(),
@@ -1602,23 +1529,19 @@ class ShardedDeviceChecker:
         # capacity planning BEFORE allocating: the next flush may add a
         # full accumulator per shard, and the store must admit one
         # append window past the restored high-water mark
-        if self.visited_impl == "fpset":
-            # the snapshot fixes the table tier; growth (if the resumed
-            # run needs it) goes through the regular rehash below.
-            # v2 frames use the compacted-occupancy codec ("fp_tcap");
-            # v1 frames snapshotted the full columns ("vk0") — both load
-            fp_cols = (
-                ckpt.unpack_fpset(d, K) if "fp_tcap" in d else None
-            )
-            self.TCAP = (
-                fp_cols[0].shape[1] - 1
-                if fp_cols is not None
-                else int(d["vk0"].shape[1]) - 1
-            )
-            self.VCAP = self.TCAP // 2
-        else:
-            while self.VCAP < mk + self.ACAP:
-                self.VCAP *= 2
+        # the snapshot fixes the table tier; growth (if the resumed
+        # run needs it) goes through the regular rehash below.
+        # v2 frames use the compacted-occupancy codec ("fp_tcap");
+        # v1 frames snapshotted the full columns ("vk0") — both load
+        fp_cols = (
+            ckpt.unpack_fpset(d, K) if "fp_tcap" in d else None
+        )
+        self.TCAP = (
+            fp_cols[0].shape[1] - 1
+            if fp_cols is not None
+            else int(d["vk0"].shape[1]) - 1
+        )
+        self.VCAP = self.TCAP // 2
         need_l = max(mx + self.APAD, self.NCs + self.APAD)
         while self.LCAP < need_l:
             self.LCAP = min(self.LCAP * 2, need_l)
@@ -1640,28 +1563,20 @@ class ShardedDeviceChecker:
                 axis=1,
             )
 
-        if self.visited_impl == "fpset":
-            bufs = {
-                "vk": tuple(
-                    jax.device_put(np.ascontiguousarray(c), sh)
-                    for c in fp_cols
+        bufs = {
+            "vk": tuple(
+                jax.device_put(np.ascontiguousarray(c), sh)
+                for c in fp_cols
+            )
+            if fp_cols is not None
+            else tuple(
+                jax.device_put(
+                    np.ascontiguousarray(d[f"vk{i}"], np.uint32),
+                    sh,
                 )
-                if fp_cols is not None
-                else tuple(
-                    jax.device_put(
-                        np.ascontiguousarray(d[f"vk{i}"], np.uint32),
-                        sh,
-                    )
-                    for i in range(K)
-                ),
-            }
-        else:
-            bufs = {
-                "vk": tuple(
-                    pad_to(f"vk{i}", self.VCAP, SENTINEL, jnp.uint32)
-                    for i in range(K)
-                ),
-            }
+                for i in range(K)
+            ),
+        }
         self._alloc_acc(bufs)
         bufs["rows"] = pad_to("rows", self.LCAP * W, 0, jnp.uint32)
         bufs["parent"] = pad_to("parent", self.LCAP, 0, jnp.int32)
@@ -1677,11 +1592,10 @@ class ShardedDeviceChecker:
             "rt": self._dev_fill((N, RT_N), 0, jnp.int32),
             "fpm": self._dev_fill((N, FPM_N), 0, jnp.int32),
         }
-        if self.visited_impl == "fpset":
-            # the next flush may add a full accumulator of owned keys
-            # per shard; grow (rehash) now if the snapshot tier cannot
-            # absorb that at load <= 1/2
-            self._grow_visited(bufs, mk + self.ACAP)
+        # the next flush may add a full accumulator of owned keys
+        # per shard; grow (rehash) now if the snapshot tier cannot
+        # absorb that at load <= 1/2
+        self._grow_visited(bufs, mk + self.ACAP)
         if "hbm_recovered" in d:
             # pre-r9 frames predate the field and restore at 0
             self.rec.hbm_recovered = max(
@@ -1702,9 +1616,9 @@ class ShardedDeviceChecker:
     def _prewarm_tiers(self):
         """Pre-compile the capacity tiers reachable under
         ``max_states`` (VERDICT r5 #8, sharded half).  The visited
-        tiers are exact (fpset rehash doubles, sort-mode columns
-        double); the per-shard row-store tiers follow the balanced
-        doubling schedule toward ``SCAP/N`` — producer skew can push a
+        tiers are exact (the fpset rehash doubles); the per-shard
+        row-store tiers follow the balanced doubling schedule toward
+        ``SCAP/N`` — producer skew can push a
         shard past that (the growth formula then grows to exact need),
         so the store prewarm is best-effort: it covers the schedule
         every balanced run takes."""
@@ -1712,25 +1626,20 @@ class ShardedDeviceChecker:
         N, K = self.N, self.K
         save = (self.TCAP, self.VCAP, self.LCAP)
         cap_k = self.SCAP // self.N + (self.group + 1) * self.ACAP
-        if self.visited_impl == "fpset":
-            while self.VCAP < cap_k:
-                out = self._rehash_jit()(
-                    tuple(
-                        self._dev_fill(
-                            (N, self._vk_width()), SENTINEL, jnp.uint32
-                        )
-                        for _ in range(K)
+        while self.VCAP < cap_k:
+            out = self._rehash_jit()(
+                tuple(
+                    self._dev_fill(
+                        (N, self._vk_width()), SENTINEL, jnp.uint32
                     )
+                    for _ in range(K)
                 )
-                drain(out)
-                del out
-                self.TCAP *= 2
-                self.VCAP = self.TCAP // 2
-                self._compile_flush_tier()
-        else:
-            while self.VCAP < cap_k:
-                self.VCAP *= 2
-                self._compile_flush_tier()
+            )
+            drain(out)
+            del out
+            self.TCAP *= 2
+            self.VCAP = self.TCAP // 2
+            self._compile_flush_tier()
         cap_l = max(
             self.SCAP // self.N + self.APAD, self.NCs + self.APAD
         )
@@ -2010,8 +1919,7 @@ class ShardedDeviceChecker:
             device=dev,
             n_devices=self.N,
             n_slices=self.D,
-            visited_impl=self.visited_impl,
-            compact_impl=self.compact_impl,
+            **obs.IMPL_FIELDS,
             config_sig=self._config_sig(),
             # v8 envelope: the sharded engine is not profile-tuned
             # yet; the field must still exist (schema v8 contract)
@@ -2022,11 +1930,6 @@ class ShardedDeviceChecker:
             warm=getattr(self, "warm", None),
             # v15: distributed-trace identity (None outside the daemon)
             trace_id=getattr(self, "trace_id", None),
-            # v16: dense-tile kernel selection — null here; only
-            # device_bfs carries the ops/tiles.py impl knobs
-            probe_impl=None,
-            expand_impl=None,
-            sieve_impl=None,
             # v11: workload class (exhaustive BFS)
             mode="check",
             wall_unix=round(time.time(), 3),
@@ -2188,7 +2091,7 @@ class ShardedDeviceChecker:
         per-invariant violation gids, then the route state (the
         routing-overflow flag, the lanes sent as LO and HI words) and
         the per-shard fpset metrics [flushes, probe rounds, failures,
-        valid lanes, max probe rounds] (zeros in sort mode)."""
+        valid lanes, max probe rounds]."""
         with self._clock.phase("fetch"):
             out = np.asarray(
                 self._stats_jit()(
@@ -2204,20 +2107,19 @@ class ShardedDeviceChecker:
             raise _RouteOverflow
         f0 = 3 + n_inv + RT_N
         self._last_fpm = out[:, f0: f0 + FPM_N]
-        if self.visited_impl == "fpset":
-            self._snap["occupancy"] = float(out[:, 1].max()) / max(
-                self.TCAP, 1
-            )
-            if self._last_fpm.shape[1] >= 4:
-                # TLC's "states generated": routed lanes examined
-                # (per-shard 64-bit reassembly before the mesh sum)
-                self._snap["generated"] = int(
-                    sum(
-                        fpset.fpm_logical(row)[3]
-                        for row in self._last_fpm
-                    )
+        self._snap["occupancy"] = float(out[:, 1].max()) / max(
+            self.TCAP, 1
+        )
+        if self._last_fpm.shape[1] >= 4:
+            # TLC's "states generated": routed lanes examined
+            # (per-shard 64-bit reassembly before the mesh sum)
+            self._snap["generated"] = int(
+                sum(
+                    fpset.fpm_logical(row)[3]
+                    for row in self._last_fpm
                 )
-            self._emit_flush_event(nv, out)
+            )
+        self._emit_flush_event(nv, out)
         self._emit_compact_event()
         if self._last_fpm[:, 2].any():
             # probe overflow: some owner table dropped routed keys in a
@@ -2278,7 +2180,8 @@ class ShardedDeviceChecker:
             return
         self._compact_prev = self._compact_n
         self.tel.emit(
-            "compact", dispatches=d, impl=self.compact_impl
+            "compact", dispatches=d,
+            impl=obs.IMPL_FIELDS["compact_impl"],
         )
 
     @spans.in_phase("dispatch")
@@ -2293,7 +2196,7 @@ class ShardedDeviceChecker:
         kinds = faults.poll("flush", self._flush_seq)
         if "oom" in kinds:
             raise faults.oom_error("flush", self._flush_seq)
-        if "fpset_fail" in kinds and self.visited_impl == "fpset":
+        if "fpset_fail" in kinds:
             # one synthetic dropped lane on ONE shard (shard 0) — a
             # full-mesh broadcast would misstate the drill's blast
             # radius in the failure telemetry and the abort message
@@ -2775,7 +2678,7 @@ class ShardedDeviceChecker:
         self.last_stats_matrix = stats
         wall = time.time() - t0
         nv = int(stats[:, 0].sum())
-        if self.visited_impl == "fpset" and self._last_fpm is not None:
+        if self._last_fpm is not None:
             fl = int(self._last_fpm[:, 0].sum())
             rd = int(self._last_fpm[:, 1].sum())
             self.last_stats.update(
@@ -2880,7 +2783,7 @@ class ShardedDeviceChecker:
         self.last_stats.update(
             phases,
             **spans.compile_meter().since(self._jit0),
-            compact_impl=self.compact_impl,
+            **obs.IMPL_FIELDS,
             hbm_recovered=self._hbm_recovered,
             ckpt_frames=self._ckpt_frames,
             ckpt_bytes=self._ckpt_bytes,

@@ -109,12 +109,6 @@ SIM_GATE_KEYS = ("steps_per_state",)
 # fixed workload; the latency keys ride along so a committed
 # baseline documents the survivability envelope too.
 FLEET_GATE_KEYS = ("fleet_replicated_wire_bytes",)
-# the dense-tile kernel gate subset (r23): the impl knobs may not
-# change the state-determined economy (tests/test_tiles.py gates a
-# tile-impl record against the committed legacy mini baseline on
-# exactly these keys; probe_lanes_per_sec is wall-clock and gates
-# real-chip trajectories only)
-TILES_GATE_KEYS = DETERMINISTIC_GATE_KEYS
 
 
 def _digest(values: dict) -> str:

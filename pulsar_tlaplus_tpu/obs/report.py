@@ -235,8 +235,7 @@ def bench_keys(events: List[dict]) -> Dict[str, object]:
         out.setdefault("hbm_recovered", len(recov))
     if "compact_impl" in stats:
         out["compact_impl"] = stats["compact_impl"]
-    # dense-tile kernel selection (r23, bench_schema 12): which impl
-    # served each kernel this run
+    # the kernel fields of bench_schema 12 (telemetry.IMPL_FIELDS)
     for k in ("probe_impl", "expand_impl", "sieve_impl"):
         if k in stats:
             out[k] = stats[k]

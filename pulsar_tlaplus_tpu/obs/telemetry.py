@@ -168,16 +168,28 @@ from typing import Callable, Dict, Optional, Tuple, Union
 # or overflowing a submit), and ``persist_fail`` (a fleet_jobs.json
 # persist that stayed failed after the retry — the counter was
 # previously invisible to stream replay).
-# v16 (round 23, the dense-tile kernel layer): every run header
-# carries the per-kernel impl selection — ``probe_impl`` /
-# ``expand_impl`` / ``sieve_impl`` (legacy|tile|pallas, ops/tiles.py;
-# null on engines without the knobs) — REQUIRED at v16 like the other
-# header attribution fields so impl trajectories always split in the
-# ledger without a stats join.
+# v16 (round 23): every run header carries ``probe_impl`` /
+# ``expand_impl`` / ``sieve_impl`` (null on the host engines) —
+# REQUIRED at v16.  They named a choice of kernel while there was one;
+# see IMPL_FIELDS below.
 # Validators accept <= SCHEMA_VERSION and hold a record only to the
 # fields its OWN version requires (FIELD_SINCE) — pre-r10 streams stay
 # valid.
 SCHEMA_VERSION = 16
+
+# The five fields that recorded which implementation of a kernel stage
+# a run of the device engines used.  There is one implementation of
+# each now, so they are constants — kept because checkpoint frames
+# (``visited_impl`` is an input of the configuration signature), warm
+# artifacts, ledger keys and telemetry streams written before carry
+# them.  Every writer takes them from here.
+IMPL_FIELDS: Dict[str, str] = {
+    "visited_impl": "fpset",
+    "compact_impl": "logshift",
+    "probe_impl": "legacy",
+    "expand_impl": "legacy",
+    "sieve_impl": "legacy",
+}
 
 # Authoritative event table: event name -> required fields beyond the
 # base envelope.  Unknown events are legal (forward compatibility) but
@@ -303,9 +315,9 @@ FIELD_SINCE: Dict[Tuple[str, str], int] = {
     ("job_result", "trace_id"): 15,
     ("job_cancel", "trace_id"): 15,
     ("run_header", "trace_id"): 15,
-    # v16 (round 23): the dense-tile kernel selection on every run
-    # header (null on engines without the knobs) — gated so every
-    # committed v15-and-older stream stays validator-clean.
+    # v16 (round 23): the kernel fields (IMPL_FIELDS) on every run
+    # header (null on the host engines) — gated so every committed
+    # v15-and-older stream stays validator-clean.
     ("run_header", "probe_impl"): 16,
     ("run_header", "expand_impl"): 16,
     ("run_header", "sieve_impl"): 16,

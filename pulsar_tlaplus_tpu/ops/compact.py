@@ -1,22 +1,14 @@
-"""Sort-free stream compaction — the log-shift pass that retires the
-chunked single-key sorts on every hot path (round 10 tentpole).
+"""Sort-free stream compaction — the log-shift pass on every hot path.
 
 Every engine hot path ends with the same primitive: "move the value
 columns whose ``drop`` flag is 0 to the front, preserving original
 order" — the append (device + sharded), the fpset's staged
-pending-compaction, and the liveness sweep's edge compaction.  Since
-round 4 that primitive was ``ops/dedup.compact_by_flag``: chunked
-single-key unstable sorts with the row iota embedded in the key.  The
-sort was chosen for its COMPILE behavior (a monolithic multi-operand
-stable sort compiled 4-5x slower), but its RUN cost is still a sort —
-width-linear data movement across O(log^2 n) comparator stages, and at
-round-9 bench shapes the append stage it dominates is the largest
-stage (17.6 s of ~45 s, BASELINE.md r5 split) now that the flush sort
-is gone.
+pending-compaction, and the liveness sweep's edge compaction.
 
-The replacement is prefix-sum stream compaction: one exclusive prefix
-sum of the drop flags gives every kept element its destination, and a
-sort-free materialization moves the columns.  The materialization is
+It is prefix-sum stream compaction: one exclusive prefix sum of the
+drop flags gives every kept element its destination, and a sort-free
+materialization moves the columns (a sort's run cost is width-linear
+data movement across O(log^2 n) comparator stages).  The materialization is
 picked for the backend's memory system at trace time:
 
 - **Accelerators (the TPU hot path): masked doubling shifts** — the
@@ -27,7 +19,7 @@ picked for the backend's memory system at trace time:
   per column — the cheapest ops on the TPU memory system (9-30 ns/elem
   contiguous vs 17-50 ns/elem latency-bound random access, BASELINE.md
   environment facts) — and there is no comparator network, so the
-  compile is trivial (the round-4 sort-compile blowup is gone too).
+  compile is trivial.
 - **The CPU backend (the virtual-mesh test/differential tier):
   prefix-sum + branchless-binary-search gather** — XLA:CPU lowers
   sorts AND scatters to serial per-element loops (measured here:
@@ -35,13 +27,12 @@ picked for the backend's memory system at trace time:
   vectorize at ~2 ns/elem, so the shift passes' 10-19 full-array
   sweeps lose to one ``log2(n)``-round branchless binary search over
   the inclusive kept-count (the ``dedup.bsearch_member`` idiom) + one
-  gather per column.  Same outputs element-for-element; measured
-  2-4x faster than the sort path at the 253k-oracle shapes where the
-  shifts only break even (the CPU profile is flat — there is no
-  contiguous-vs-random asymmetry to exploit).
+  gather per column.  Same outputs element-for-element (the CPU
+  profile is flat — there is no contiguous-vs-random asymmetry to
+  exploit).
 
-``PTT_COMPACT_MATERIALIZE=shift|gather`` overrides the choice for
-differential measurement of the materializations themselves.
+``PTT_COMPACT_MATERIALIZE=shift|gather`` overrides the choice, so a CPU
+test can run the materialization the TPU picks.
 
 Correctness sketch for the shift passes (the property test hammers
 both materializations with random masks): ``delta`` (dropped elements
@@ -53,15 +44,9 @@ j lands there on its final moving pass and never moves again.  Dropped
 elements never move (their remaining distance starts at 0) and slots
 vacated without replacement have their distance zeroed, so stale
 copies never travel; both are eventually overwritten inside the kept
-prefix and are DON'T-CARE beyond it — the same tail contract as the
-sort path (callers consume only the ``n_kept`` prefix; the
-differential tests pin the prefix element-for-element against the
-sort).
-
-``compact_by_flag`` below is the dispatcher: ``impl="logshift"`` (the
-default everywhere since round 10) or ``impl="sort"`` — the round-4
-chunked sort kept bit-for-bit for differential timing, mirroring the
-round-6 ``-visited sort`` pattern.
+prefix and are DON'T-CARE beyond it (callers consume only the
+``n_kept`` prefix; the tests pin the prefix element-for-element against
+a numpy reference).
 """
 
 from __future__ import annotations
@@ -71,20 +56,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-
-from pulsar_tlaplus_tpu.ops import dedup
-
-IMPLS = ("logshift", "sort")
-
-
-def validate_impl(impl: str) -> str:
-    """The one ``compact_impl`` membership check — every ctor and the
-    dispatcher route through here so a new impl is a one-line change."""
-    if impl not in IMPLS:
-        raise ValueError(
-            f"compact_impl must be {'|'.join(IMPLS)}: {impl}"
-        )
-    return impl
 
 
 def _materialization() -> str:
@@ -155,15 +126,15 @@ def _gather_compact(drop, vals):
     return [v[src] for v in vals], src
 
 
-def logshift_compact(
+def compact_by_flag(
     drop: jax.Array, cols, need_idx: bool = True
 ) -> Tuple[tuple, Optional[jax.Array]]:
     """Sort-free stable compaction of ``cols`` to the front where
     ``drop == 0`` (module docstring; materialization is
     backend-adaptive at trace time).
 
-    Same contract as :func:`ops.dedup.compact_by_flag`: the kept prefix
-    is in original order; positions past the kept count are don't-care.
+    The kept prefix is in original order; positions past the kept
+    count are don't-care.
     ``idx[j]`` is the original row of compacted position ``j`` (valid
     in the kept prefix); pass ``need_idx=False`` to skip carrying the
     index column when the caller discards it.
@@ -186,7 +157,7 @@ def logshift_compact(
 
 
 def compact_rows(
-    arows: jax.Array, flag_keep: jax.Array, impl: str = "logshift"
+    arows: jax.Array, flag_keep: jax.Array
 ) -> Tuple[jax.Array, jax.Array]:
     """Compact a word-major ``[W, N]`` packed-row matrix to the front
     where ``flag_keep`` (uint32 0/1) is set, preserving original order
@@ -196,22 +167,5 @@ def compact_rows(
     ``idx[j]`` is the original lane of compacted position ``j``."""
     drop = flag_keep ^ jnp.uint32(1)
     cols = tuple(arows[j] for j in range(arows.shape[0]))
-    ccols, idx = compact_by_flag(drop, cols, impl=impl)
+    ccols, idx = compact_by_flag(drop, cols)
     return jnp.stack(ccols), idx
-
-
-def compact_by_flag(
-    drop: jax.Array,
-    cols,
-    impl: str = "logshift",
-    chunk: int = 5,
-    need_idx: bool = True,
-):
-    """Dispatch stream compaction: ``"logshift"`` (default — the
-    sort-free kernel above) or ``"sort"`` (the round-4 chunked
-    single-key sorts, kept verbatim in ``ops/dedup.py`` for
-    differential timing).  Returns ``(compacted cols, idx)`` with
-    identical kept-prefix semantics either way."""
-    if validate_impl(impl) == "sort":
-        return dedup.compact_by_flag(drop, cols, chunk=chunk)
-    return logshift_compact(drop, cols, need_idx=need_idx)

@@ -168,80 +168,6 @@ class KeySpec:
         )
 
 
-def merge_new_keys(vcols, ccols, cpay):
-    """Sort-merge candidate key columns into the sorted visited columns
-    (both SENTINEL-padded) — the shared dedup core of the device
-    engine's flush and seed-merge paths.
-
-    ``cpay`` is the candidates' payload word with the tag bit (1 << 31)
-    set; visited entries ride payload 0, so one unstable sort orders
-    visited before same-key candidates and resolves in-batch duplicates
-    and visited membership in a single pass.  Returns ``(vcols',
-    n_new, sorted_payload, new_flag)`` where ``vcols'`` has the same
-    width as ``vcols`` (callers guarantee the merged set fits).
-    """
-    V = vcols[0].shape[0]
-    cols = tuple(
-        jnp.concatenate([v, c]) for v, c in zip(vcols, ccols)
-    )
-    pay = jnp.concatenate([jnp.zeros((V,), jnp.uint32), cpay])
-    out = jax.lax.sort((*cols, pay), num_keys=len(cols) + 1,
-                       is_stable=False)
-    scols, sp = out[:-1], out[-1]
-    tag = sp >> 31  # 1 = candidate, 0 = visited
-    sent = scols[0] == SENTINEL
-    for c in scols[1:]:
-        sent = sent & (c == SENTINEL)
-    eq = scols[0][1:] == scols[0][:-1]
-    for c in scols[1:]:
-        eq = eq & (c[1:] == c[:-1])
-    prev_same = jnp.zeros(sp.shape, jnp.bool_).at[1:].set(eq)
-    new_flag = (tag == 1) & ~sent & ~prev_same
-    keep = ~sent & ((tag == 0) | new_flag)
-    n_new = jnp.sum(new_flag.astype(jnp.int32))
-    # blank dropped entries to SENTINEL *before* compacting: their key
-    # values must not survive into the visited columns, or the table
-    # silently fills with phantom duplicates
-    kk = (~keep).astype(jnp.uint32)
-    masked = tuple(jnp.where(keep, c, SENTINEL) for c in scols)
-    vout = jax.lax.sort((kk, *masked), num_keys=1, is_stable=True)
-    return tuple(c[:V] for c in vout[1:]), n_new, sp, new_flag
-
-
-def compact_by_flag(drop, cols, chunk: int = 5):
-    """Stable-compact value columns to the front where ``drop == 0``
-    (original order preserved), without a wide multi-operand sort.
-
-    XLA sort COMPILE time grows superlinearly in operand count
-    (``scripts/profile.py prims`` measures it) while RUN time grows
-    sublinearly.  So: ONE u32 key ``drop << 31 |
-    iota`` (all keys distinct, so an unstable single-key sort IS the
-    stable (drop, original-order) sort), applied in ``chunk``-column
-    value-carrying sorts.  ~4x faster compile at bench shapes for
-    ~25% more sort traffic.
-
-    Returns (compacted cols, idx) where ``idx[j]`` is the original row
-    of compacted position ``j`` (valid in the kept prefix).
-    """
-    n = drop.shape[0]
-    key = (drop.astype(jnp.uint32) << jnp.uint32(31)) | jnp.arange(
-        n, dtype=jnp.uint32
-    )
-    outs = []
-    idx = None
-    for i in range(0, len(cols), chunk):
-        res = jax.lax.sort(
-            (key, *cols[i: i + chunk]), num_keys=1, is_stable=False
-        )
-        if idx is None:
-            idx = (res[0] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
-        outs.extend(res[1:])
-    if idx is None:
-        srt = jax.lax.sort((key,), num_keys=1, is_stable=False)
-        idx = (srt[0] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
-    return tuple(outs), idx
-
-
 def _lex_less(
     a1: jax.Array, a2: jax.Array, a3: jax.Array,
     b1: jax.Array, b2: jax.Array, b3: jax.Array,

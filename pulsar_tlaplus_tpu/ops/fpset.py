@@ -1,10 +1,9 @@
-"""Device-resident growable FPSet — the hash-table visited set that
-retires the flush's visited-width sort-merge (round 6 tentpole).
+"""Device-resident growable FPSet — the hash-table visited set.
 
-Why a table, and why now.  The round-5 per-stage split (BASELINE.md)
-showed the flush — three full-width sorts of up to 203M keys per
-26.7M-candidate accumulator — at ~50% of stage time: a per-candidate
-cost that GROWS with the visited set.  An HBM-resident open-addressing
+Why a table.  A sort-merge flush (three full-width sorts of up to 203M
+keys per 26.7M-candidate accumulator, ~50% of stage time in
+BASELINE.md's round-5 split) is a per-candidate cost that GROWS with
+the visited set.  An HBM-resident open-addressing
 table makes dedup O(batch * E[probes]) independent of how many states
 have been visited — the frontier-expansion shape tensor-core BFS work
 (BLEST, arxiv 2512.21967; Graph Traversal on Tensor Cores, arxiv
@@ -43,14 +42,12 @@ probing design generalised to the device hot path:
   table and round count are those of the single loop, bit for bit.
 - **Deterministic discovery order.**  Equal-key lanes resolve to the
   minimum lane id (scatter-min bidding; compaction is order-preserving
-  and stages bid with original lane ids), which is exactly the
-  sort-merge flush's "lowest accumulator slot wins" — the fpset-backed
-  engine assigns the SAME gids as the legacy flush, state for state.
+  and stages bid with original lane ids): "lowest accumulator slot
+  wins", so gids follow lane order whatever the table's layout.
 - **On-device growth**: :func:`rehash_cols` re-inserts every occupied
   slot of the old table into a double-size table with a `fori_loop` of
   chunked probe rounds — one dispatch, no host staging, and the
-  transient is old+new table (far below the retired flush sort's
-  3x-visited-width transients).
+  transient is old+new table.
 
 Load factor is the caller's contract: engines grow before the table
 exceeds 1/2 (`ops/hashtable.py`'s regime), which bounds expected probes
@@ -399,7 +396,7 @@ def probe_insert(
     ``ops.hashtable`` compatibility layout).  ``start_round`` /
     ``lane_ids`` let the staged wrapper resume the probe sequence on a
     compacted buffer while bidding with ORIGINAL lane ids (preserving
-    min-lane-wins — the sort-merge flush's discovery order).
+    min-lane-wins).
     ``handover`` ends the loop as soon as no more than that many lanes
     are pending (the staged wrapper passes the next, narrower stage's
     capacity; the default 0 probes until every lane resolved).
@@ -483,7 +480,6 @@ def lookup_or_insert(
     max_probes: int = MAX_PROBES,
     dense_rounds: Optional[int] = None,
     stages=None,
-    compact_impl: str = "logshift",
 ):
     """Engine hot path: staged batched lookup-or-insert (see module
     docstring for the why of the stages).
@@ -520,8 +516,7 @@ def lookup_or_insert(
     for i, (capi, limit) in enumerate(ladder):
         if capi < width:
             # order-preserving compaction of the pending lanes (+ their
-            # original lane ids) into the narrower stage buffer —
-            # log-shift by default (round 10), sort behind compact_impl
+            # original lane ids) into the narrower stage buffer
             ids = (
                 cur_ids
                 if cur_ids is not None
@@ -530,7 +525,7 @@ def lookup_or_insert(
             drop = (~cur_pending).astype(jnp.uint32)
             ccols, _ = compact_ops.compact_by_flag(
                 drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
-                impl=compact_impl, need_idx=False,
+                need_idx=False,
             )
             npend = jnp.sum(cur_pending.astype(jnp.int32))
             n_failed = n_failed + jnp.maximum(npend - capi, 0)
@@ -571,8 +566,6 @@ def flush_acc(
     fpm: jax.Array,
     dense_rounds: Optional[int] = None,
     stages=None,
-    compact_impl: str = "logshift",
-    probe_impl: str = "legacy",
 ):
     """One accumulator flush as a traced sub-function (round 13): mask
     the live prefix, probe-or-insert, count the new states, and ride
@@ -584,23 +577,10 @@ def flush_acc(
     one dispatch while the per-stage jit keeps calling the identical
     trace — bit-for-bit the same flush either way.  Lanes past
     ``n_acc`` (a stale tail from a previous fill) and all-SENTINEL
-    lanes (masked expand output) are invalid; min-lane-wins keeps the
-    sort-merge flush's discovery order.
-
-    ``probe_impl`` selects the probe kernel (round 23): ``legacy`` is
-    the staged loop below; ``tile`` routes to the blocked
-    membership-prefilter formulation in ``ops/tiles.py``, which is
-    pinned bit-identical on ``is_new`` (discovery order depends only
-    on pre-flush membership + min-lane-wins, never slot placement).
+    lanes (masked expand output) are invalid; min-lane-wins fixes the
+    discovery order (it depends only on pre-flush membership and the
+    lane, never on slot placement).
     """
-    if probe_impl != "legacy":
-        from pulsar_tlaplus_tpu.ops import tiles  # lazy: tiles imports us
-
-        return tiles.flush_acc_tiles(
-            tcols, kcols, n_acc, fpm,
-            dense_rounds=dense_rounds, stages=stages,
-            compact_impl=compact_impl,
-        )
     nq = kcols[0].shape[0]
     lanei = jnp.arange(nq, dtype=jnp.int32)
     amask = lanei < n_acc
@@ -608,7 +588,6 @@ def flush_acc(
     is_new, tcols2, n_failed, rounds, lane_rounds = lookup_or_insert(
         tcols, kcols, valid,
         dense_rounds=dense_rounds, stages=stages,
-        compact_impl=compact_impl,
     )
     n_new = jnp.sum(is_new.astype(jnp.int32))
     fpm2 = fpm_update(
@@ -709,7 +688,6 @@ class FPSet:
         telemetry=None,
         dense_rounds: Optional[int] = None,
         stages=None,
-        compact_impl: str = "logshift",
     ):
         from pulsar_tlaplus_tpu.obs import telemetry as obs
 
@@ -722,7 +700,6 @@ class FPSet:
         self.dense_rounds, self.stages = resolve_schedule(
             dense_rounds, stages
         )
-        self.compact_impl = compact_ops.validate_impl(compact_impl)
         self.stats = {
             "inserts": 0, "probe_rounds": 0, "lane_rounds": 0,
             "failures": 0,
@@ -769,7 +746,6 @@ class FPSet:
             lookup_or_insert(
                 self.cols, kcols, valid,
                 dense_rounds=self.dense_rounds, stages=self.stages,
-                compact_impl=self.compact_impl,
             )
         )
         nf = int(n_failed)

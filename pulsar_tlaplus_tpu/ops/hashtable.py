@@ -4,16 +4,10 @@
 Round 6 promoted this design to the device hot path as the growable,
 K-column, staged-compaction FPSet in ``ops/fpset.py`` (see its module
 docstring for the probing/bidding algorithm and the discovery-order
-guarantee).  Round 23 added alternative dense-tile formulations of the
-flush-stage probe behind ``fpset.flush_acc(..., probe_impl=...)``
-(``legacy`` | ``tile`` | ``pallas``, kernels in ``ops/tiles.py``,
-arbitrated by ``cli.py tune``); all of them preserve the same
-min-lane-wins discovery order.  The host-loop engines
+guarantee).  The host-loop engines
 (``engine/core.py``, ``engine/bfs.py``, ``engine/sharded.py``) keep
-this module's original fixed 3-column + occupancy-column API; that
-path always uses ``fpset.probe_insert``'s triangular probing and
-scatter-min bidding — the impl knobs apply only to the device
-engines' accumulate-then-flush path.
+this module's original fixed 3-column + occupancy-column API on
+``fpset.probe_insert``'s triangular probing and scatter-min bidding.
 
 Layout: four uint32[cap + 1] columns — three key words plus an
 occupancy column.  ``cap`` is a power of two; slot ``cap`` is the

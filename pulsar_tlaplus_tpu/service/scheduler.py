@@ -86,8 +86,6 @@ class ServiceConfig:
     frontier_cap: int = 1 << 14
     max_states: int = 50_000_000  # service ceiling + default budget
     checkpoint_every: int = 2
-    visited_impl: str = "fpset"
-    compact_impl: str = "logshift"
     # tuned-profile policy (r15, tune/profiles.py): "auto" resolves a
     # profile per (spec, constants, invariants, backend) at checker
     # construction — so PREWARM compiles the tuned knobs and a warm
@@ -260,10 +258,6 @@ class CheckerPool:
                     visited_cap=cfg.visited_cap,
                     frontier_cap=cfg.frontier_cap,
                     max_states=key[3],
-                    visited_impl=cfg.visited_impl,
-                    compact_impl=pk.get(
-                        "compact_impl", cfg.compact_impl
-                    ),
                     flush_factor=pk.get("flush_factor"),
                     group=pk.get("group"),
                     fuse_group=pk.get("fuse_group"),

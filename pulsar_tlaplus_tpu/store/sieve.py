@@ -61,8 +61,6 @@ def extract_cold(
     tcols: Tuple[jax.Array, ...],
     gen: jax.Array,
     cutoff,
-    compact_impl: str = "logshift",
-    sieve_impl: str = "legacy",
 ):
     """Select slots with ``1 <= gen <= cutoff``, pack their keys
     densely, sort them, and clear the slots.
@@ -71,25 +69,14 @@ def extract_cold(
     — ``ev_cols_sorted`` are full-table-width columns whose first
     ``n_evicted`` lanes hold the evicted keys in unsigned
     lexicographic column order (SENTINEL padding sorts last).  The
-    holed table MUST be rehashed before serving lookups again.
-
-    ``sieve_impl`` selects the extract kernel (round 23): ``legacy``
-    is the compact+mask+sort below; ``tile`` / ``pallas`` route to
-    ``ops/tiles.py``'s mask-in-place formulation (the sort sees the
-    same multiset, so outputs are array-identical)."""
-    if sieve_impl != "legacy":
-        from pulsar_tlaplus_tpu.ops import tiles  # lazy: avoids cycle
-
-        return tiles.extract_cold_tiles(
-            tcols, gen, cutoff, sieve_impl=sieve_impl
-        )
+    holed table MUST be rehashed before serving lookups again."""
     cap1 = tcols[0].shape[0]
     occ = _occupied_full(tcols)
     cold = occ & (gen >= 1) & (gen <= jnp.int32(cutoff))
     n_ev = jnp.sum(cold.astype(jnp.int32))
     drop = (~cold).astype(jnp.uint32)
     packed, _ = compact_ops.compact_by_flag(
-        drop, tuple(tcols), impl=compact_impl, need_idx=False
+        drop, tuple(tcols), need_idx=False
     )
     lane = jnp.arange(cap1, dtype=jnp.int32)
     masked = tuple(
@@ -103,7 +90,7 @@ def extract_cold(
     return tcols_holed, gen_cleared, ev_sorted, n_ev
 
 
-def sieve_new(ak_cols, flag_acc, compact_impl: str = "logshift"):
+def sieve_new(ak_cols, flag_acc):
     """Pack the hot-filter survivors: the accumulator lanes flagged
     new, as dense key columns + their ORIGINAL lane ids.  Returns
     ``(kcols..., lane_ids, n_new)`` — only the ``n_new`` prefix is
@@ -112,8 +99,7 @@ def sieve_new(ak_cols, flag_acc, compact_impl: str = "logshift"):
     lane = jnp.arange(nq, dtype=jnp.uint32)
     drop = flag_acc ^ jnp.uint32(1)
     packed, _ = compact_ops.compact_by_flag(
-        drop, tuple(ak_cols) + (lane,), impl=compact_impl,
-        need_idx=False,
+        drop, tuple(ak_cols) + (lane,), need_idx=False
     )
     n_new = jnp.sum(flag_acc.astype(jnp.int32))
     return (*packed[:-1], packed[-1].astype(jnp.int32), n_new)

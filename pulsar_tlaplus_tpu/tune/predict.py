@@ -84,49 +84,6 @@ _NOMINAL_SPILL_RATIO = 0.4
 _DENSE_DEFAULT = 4
 _STAGES_DEFAULT = ((4, 16), (64, 64))
 
-# dense-tile kernel lane-cost MULTIPLIERS vs legacy (round 23,
-# ops/tiles.py) when no calibration measured the per-impl unit
-# (``probe_lane_tile_ns`` etc.).  The CPU numbers are the measured
-# r23 microbench cost ratios at the 253k-oracle shape (BASELINE.md
-# round 23; ``scripts/profile.py tiles``): the tile probe's
-# membership prefilter pays a full extra gather pass that a serial
-# CPU cannot hide (it only wins on dup-heavy flush populations), the
-# tile expand's flat key plane beats the chunked scan slightly, and
-# interpret-mode Pallas tracks tile for the probe but loses badly on
-# the grid-stepped elementwise kernels.  TPU ratios are the paper's
-# modeled MXU expectation until a device calibration overwrites them.
-_IMPL_LANE_RATIO = {
-    "probe_lane": {
-        "cpu": {"legacy": 1.0, "tile": 1.65},
-        "tpu": {"legacy": 1.0, "tile": 0.7},
-    },
-    "expand_row": {
-        "cpu": {"legacy": 1.0, "tile": 0.84, "pallas": 4.1},
-        "tpu": {"legacy": 1.0, "tile": 0.7, "pallas": 0.9},
-    },
-}
-
-
-def _impl_factor(
-    backend: str, units: Dict, stage: str, cand_impl, ref_impl
-) -> float:
-    """Multiplier on a stage's lane/row cost for a candidate impl
-    against the reference run's impl.  Calibrated per-impl units
-    (``{stage}_{impl}_ns``) win; otherwise the default ratio table."""
-    ci = cand_impl or ref_impl or "legacy"
-    ri = ref_impl or "legacy"
-    if ci == ri:
-        return 1.0
-    base = units.get(f"{stage}_ns")
-    u_c = units.get(f"{stage}_{ci}_ns") if ci != "legacy" else base
-    u_r = units.get(f"{stage}_{ri}_ns") if ri != "legacy" else base
-    if u_c is not None and u_r:
-        return float(u_c) / float(u_r)
-    table = _IMPL_LANE_RATIO.get(stage, {})
-    ratios = table.get(backend, table.get("tpu", {}))
-    return ratios.get(ci, 1.0) / ratios.get(ri, 1.0)
-
-
 def schedule_lane_factor(
     dense: int, stages: Tuple[Tuple[int, int], ...], avg_rounds: float
 ) -> float:
@@ -192,32 +149,6 @@ def predict_candidate(
         u = units.get(ukey)
         if w and u is not None:
             est += w * u * 1e-9
-    # the "sort" compaction materialization re-sorts instead of
-    # log-shifting: the r10 differential measured it ~2x the element
-    # cost on the compact stage
-    if cand.get("compact_impl") == "sort":
-        w = work.get("compact_elems")
-        u = units.get("compact_elem_ns")
-        if w and u is not None:
-            est += w * u * 1e-9
-    # dense-tile kernel selection (r23, ops/tiles.py): scale the probe
-    # and expand stage costs by the candidate impl's calibrated unit
-    # (``probe_lane_tile_ns`` etc.) against the reference impl's, or
-    # by the default ratio table when uncalibrated.  The extra est is
-    # (factor - 1) x the already-priced stage cost, so a legacy
-    # candidate against a legacy reference adds exactly zero.
-    for stage_unit, wkey2, knob in (
-        ("probe_lane", "probe_lanes", "probe_impl"),
-        ("expand_row", "expand_rows", "expand_impl"),
-    ):
-        w = work.get(wkey2)
-        u = units.get(f"{stage_unit}_ns")
-        if not w or u is None:
-            continue
-        factor = _impl_factor(
-            backend, units, stage_unit, cand.get(knob), ref.get(knob)
-        )
-        est += w * u * (factor - 1.0) * 1e-9
     g = int(cand.get("sub_batch") or ref.get("sub_batch") or 8192)
     fg = int(cand.get("fuse_group") or ref.get("fuse_group") or 8)
     levels = list(ref.get("level_sizes", ()))
@@ -319,11 +250,6 @@ def reference_of(ck, result) -> Dict[str, object]:
         ),
         "spill_compress": bool(getattr(ck, "spill_compress", True)),
         "miss_batch": int(getattr(ck, "miss_batch", 1 << 15)),
-        # dense-tile kernel selection (r23): the impls the reference
-        # actually ran, so candidate factors are priced relative
-        "probe_impl": getattr(ck, "probe_impl", "legacy") or "legacy",
-        "expand_impl": getattr(ck, "expand_impl", "legacy") or "legacy",
-        "sieve_impl": getattr(ck, "sieve_impl", "legacy") or "legacy",
     }
 
 

@@ -58,14 +58,6 @@ _POSITIVE_INT_KNOBS = (
     # swarm-simulation knobs (r18, engine "sim")
     "n_walkers", "segment_len",
 )
-_COMPACT_IMPLS = ("logshift", "sort")
-# dense-tile kernel knobs (r23): what the search space offers, plus
-# the engine default its ``None`` stands for
-_TILE_IMPLS = {
-    k.name: ("legacy",) + tuple(v for v in k.values if v)
-    for k in tune_space.DEVICE_KNOBS + tune_space.SPILL_KNOBS
-    if k.name in ("probe_impl", "expand_impl", "sieve_impl")
-}
 
 
 def profiles_dir() -> str:
@@ -239,16 +231,6 @@ def validate(profile, path: str = "<profile>") -> List[str]:
             errs.append(
                 f"{path}: knob {k!r} must be a positive integer "
                 f"(got {val!r})"
-            )
-        elif k == "compact_impl" and val not in _COMPACT_IMPLS:
-            errs.append(
-                f"{path}: knob compact_impl must be one of "
-                f"{_COMPACT_IMPLS} (got {val!r})"
-            )
-        elif k in _TILE_IMPLS and val not in _TILE_IMPLS[k]:
-            errs.append(
-                f"{path}: knob {k!r} must be one of "
-                f"{_TILE_IMPLS[k]} (got {val!r})"
             )
         elif k == "adapt" and not isinstance(val, bool):
             errs.append(

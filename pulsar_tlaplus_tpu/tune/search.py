@@ -27,7 +27,7 @@ from pulsar_tlaplus_tpu.tune import space as tune_space
 # ctor-parameter knobs forwarded verbatim to DeviceChecker
 _CTOR_KNOBS = (
     "sub_batch", "flush_factor", "group", "fuse_group",
-    "fpset_dense_rounds", "fpset_stages", "compact_impl",
+    "fpset_dense_rounds", "fpset_stages",
     "hbm_headroom", "spill_compress", "miss_batch",
 )
 

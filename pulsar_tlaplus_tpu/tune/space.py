@@ -18,14 +18,6 @@ order; pinned by the differential tests in tests/test_tune.py):
 - ``fuse_group``      max ramp levels one fused dispatch may close
 - ``fpset_dense_rounds``  full-width probe rounds before the staged
                       pending-compaction shrinks the batch
-- ``compact_impl``    stream-compaction materialization (logshift|sort)
-- ``probe_impl``      fpset flush probe kernel (legacy|tile —
-                      round 23, ops/tiles.py; exact reformulations,
-                      discovery order pinned identical)
-- ``expand_impl``     successor-sweep structure (legacy|tile|pallas)
-- ``sieve_impl``      cold-extract kernel (legacy|tile|pallas;
-                      searched only for budgeted workloads, with the
-                      other spill knobs)
 
 Tiered-store knobs (round 16, searched only for budgeted workloads —
 ``candidates(spill=True)``; they are no-ops untiered and would only
@@ -62,27 +54,6 @@ DEVICE_KNOBS: Tuple[Knob, ...] = (
     Knob("group", (None, 2, 8), "dispatch group-ahead"),
     Knob("fuse_group", (None, 1, 4, 16), "ramp levels per dispatch"),
     Knob("fpset_dense_rounds", (None, 2, 8), "dense probe rounds"),
-    # dense-tile kernel selection (round 23, ops/tiles.py).  Unlike
-    # compact_impl below, these ARE searched: every impl is an exact
-    # reformulation pinned state-for-state identical (same ledger
-    # comparability class), so a tuned tile profile gates cleanly
-    # against the legacy baseline.  predict.py prices each impl's
-    # probe/expand lanes at calibrated (or default-ratio) unit costs.
-    Knob(
-        "probe_impl", (None, "tile"),
-        "fpset flush probe kernel (None = legacy)",
-    ),
-    Knob(
-        "expand_impl", (None, "tile", "pallas"),
-        "successor-sweep structure (None = legacy)",
-    ),
-    # compact_impl is deliberately NOT searched: the ledger's config
-    # key folds it in (a sort-impl run is a different comparability
-    # class, kept for differential timing), so a profile that tuned
-    # it could never gate against the hand-default baseline — the
-    # headline "tuning never regresses" check would be structurally
-    # impossible.  It remains a loadable profile knob for manual
-    # profiles (PROFILE_KNOBS below).
 )
 
 # tiered-store knobs (r16): searched only when the workload is
@@ -97,12 +68,6 @@ SPILL_KNOBS: Tuple[Knob, ...] = (
     Knob(
         "miss_batch", (None, 1 << 14, 1 << 16),
         "sieved keys per cold-lookup batch",
-    ),
-    # the sieve tile kernel (round 23) only runs on the eviction path,
-    # so it is searched with the other budgeted-workload knobs
-    Knob(
-        "sieve_impl", (None, "tile", "pallas"),
-        "cold-extract kernel (None = legacy)",
     ),
 )
 
@@ -152,11 +117,10 @@ LIVENESS_KNOBS: Tuple[Knob, ...] = (
 PROFILE_KNOBS: Dict[str, Tuple[str, ...]] = {
     "device_bfs": (
         "sub_batch", "flush_factor", "group", "fuse_group",
-        "fpset_dense_rounds", "fpset_stages", "compact_impl", "adapt",
+        "fpset_dense_rounds", "fpset_stages", "adapt",
         "hbm_headroom", "spill_compress", "miss_batch",
-        "probe_impl", "expand_impl", "sieve_impl",
     ),
-    "liveness": ("sweep_group", "compact_impl", "adapt"),
+    "liveness": ("sweep_group", "adapt"),
     "sim": ("n_walkers", "segment_len"),
 }
 

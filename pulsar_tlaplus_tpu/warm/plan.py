@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from pulsar_tlaplus_tpu.models import registry
+from pulsar_tlaplus_tpu.obs.telemetry import IMPL_FIELDS
 
 # cold reasons (the `reason` label on warm events + metrics).  The
 # fallback matrix test enumerates these against forged manifests.
@@ -136,7 +137,7 @@ def manifest_for(
         "layout_sig": layout_sig(model),
         "state_bits": int(model.layout.total_bits),
         "n_initial": int(model.n_initial),
-        "visited_impl": ck.visited_impl,
+        "visited_impl": IMPL_FIELDS["visited_impl"],
         "rows_window": ck.rows_window,
         "check_deadlock": bool(ck.check_deadlock),
         "tiered": bool(ck.tiered),

@@ -107,13 +107,12 @@ and the new ``complete``/``relay``/``hold``/``shed``/``persist_fail``
 events close the job, time the watch-relay legs, and make the
 dispatcher's hold/shed/persist counters stream-derivable
 (``persist_fail`` carries the CUMULATIVE count) — all
-FIELD_SINCE-gated.  r23: v16 run headers carry the dense-tile kernel
-selection (``probe_impl``/``expand_impl``/``sieve_impl`` — the
-ops/tiles.py impls the run executed under; null on engines without
-the knobs), and bench_schema >= 12 artifacts additionally require
-those three keys plus ``probe_lanes_per_sec`` (the flush-stage
-throughput the tiles ledger gate watches) — all FIELD_SINCE-gated so
-committed v15-and-older streams stay clean.  ``--metrics`` validates
+FIELD_SINCE-gated.  r23: v16 run headers carry ``probe_impl`` /
+``expand_impl`` / ``sieve_impl`` (``legacy`` on the device engines,
+null on the host engines: ``obs/telemetry.py IMPL_FIELDS``), and
+bench_schema >= 12 artifacts additionally require those three keys
+plus ``probe_lanes_per_sec`` (the flush-stage throughput) — all
+FIELD_SINCE-gated so committed v15-and-older streams stay clean.  ``--metrics`` validates
 Prometheus exposition
 text files (``cli.py metrics`` output) instead: TYPE-histogram
 families must carry cumulative monotone buckets ending at ``+Inf``,
@@ -194,11 +193,9 @@ BENCH_KEYS_V10 = BENCH_KEYS_V9 + (
 BENCH_KEYS_V11 = BENCH_KEYS_V10 + (
     "fleet_failover_ms", "fleet_reconcile_ms",
 )
-# v12 (r23): the dense-tile kernel selection — the probe/expand/sieve
-# impls the run actually executed under (null on engines without the
-# ops/tiles.py knobs) and the flush-stage probe throughput the tiles
-# ledger gate watches (null when no probe lanes were counted; the
-# keys themselves are required)
+# v12 (r23): the kernel fields (obs/telemetry.py IMPL_FIELDS; null on
+# the host engines) and the flush-stage probe throughput (null when no
+# probe lanes were counted; the keys themselves are required)
 BENCH_KEYS_V12 = BENCH_KEYS_V11 + (
     "probe_impl", "expand_impl", "sieve_impl", "probe_lanes_per_sec",
 )

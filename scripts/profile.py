@@ -801,180 +801,6 @@ def cmd_calibrate(args):
     return 0
 
 
-# --------------------------------------------------------------- tiles
-
-
-def cmd_tiles(args):
-    """Dense-tile kernel head-to-head (round 23, ops/tiles.py): the
-    probe / expand / sieve kernels timed per impl at one shape,
-    INTERLEAVED min-of-N (impls alternate inside each rep, so clock
-    drift and cache warmth hit all impls equally).  The default shape
-    is the 253k-oracle flush stage (table cap 2^18, 64Ki accumulator
-    lanes — BASELINE.md round-23 tables).
-
-        python scripts/profile.py tiles                    # all kernels
-        python scripts/profile.py tiles --kernel probe --reps 5
-        python scripts/profile.py tiles --impls legacy,tile  # skip pallas
-        python scripts/profile.py tiles --cal calibration.json  # persist
-            # per-impl unit costs (probe_lane_tile_ns ...) for predict
-
-    Pallas (expand and sieve only) runs under interpret=True on the
-    CPU backend — honestly catastrophic there (the ratio
-    tune/predict.py prices it at); on a TPU the same command measures
-    the native Mosaic kernels.
-    """
-    import functools
-    import json
-
-    from pulsar_tlaplus_tpu.ops import fpset, tiles
-    from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, KeySpec
-    from pulsar_tlaplus_tpu.store import sieve
-
-    cap = 1 << args.cap_log2
-    nq = args.nq
-    K = 2
-    impls = tuple(s for s in args.impls.split(",") if s)
-    known = set().union(*tiles.IMPLS.values())
-    for s in impls:
-        if s not in known:
-            sys.exit(f"tiles: unknown impl {s!r} (choose from "
-                     f"{sorted(known)})")
-    kernels = (
-        ("probe", "expand", "sieve")
-        if args.kernel == "all" else (args.kernel,)
-    )
-    print(f"device {jax.devices()[0]}; cap 2^{args.cap_log2}, "
-          f"nq {nq}, dup_frac {args.dup_frac}, impls {impls}, "
-          f"interleaved min-of-{args.reps}", flush=True)
-
-    def interleave(fns, inputs, label, lanes):
-        """One warm call per impl (compile), then args.reps rounds
-        visiting every impl per round; min per impl."""
-        best = {}
-        for name, fn in fns.items():
-            out = fn(*inputs[name])
-            barrier(out)
-        for _ in range(args.reps):
-            for name, fn in fns.items():
-                t0 = time.time()
-                out = fn(*inputs[name])
-                barrier(out)
-                dt = time.time() - t0
-                best[name] = min(best.get(name, dt), dt)
-        base = best.get("legacy")
-        rows = {}
-        for name in fns:
-            ns = best[name] / lanes * 1e9
-            ratio = (base / best[name]) if base else float("nan")
-            rows[name] = ns
-            print(f"  {label}:{name:8s} {best[name]*1e3:10.2f} ms   "
-                  f"{ns:9.2f} ns/lane   {ratio:6.2f}x vs legacy",
-                  flush=True)
-        return rows
-
-    # one shared prefilled table: cap/2 random keys inserted, the
-    # load factor the 253k run's flush stage sees mid-run
-    fill = cap // 2
-    fk = rng_cols(fill, K, seed=1)
-    tcols0 = fpset.empty_cols(cap, K)
-    seed_fn = jax.jit(functools.partial(fpset.flush_acc))
-    fpm0 = jnp.zeros((fpset.FPM_N,), jnp.int32)
-    tcols, _, _, _ = seed_fn(
-        tcols0, tuple(fk), jnp.int32(fill), fpm0
-    )
-    barrier(tcols)
-    measured = {}
-
-    if "probe" in kernels:
-        # the flush batch: dup_frac lanes re-present inserted keys
-        # (the dominant flush population), the rest are fresh
-        ndup = int(nq * args.dup_frac)
-        dup = tuple(c[:ndup] for c in fk)
-        fresh = rng_cols(nq - ndup, K, seed=2)
-        kcols = tuple(
-            jnp.concatenate([d, f]) for d, f in zip(dup, fresh)
-        )
-        pimpls = [s for s in impls if s in tiles.IMPLS["probe_impl"]]
-        fns = {
-            s: jax.jit(functools.partial(fpset.flush_acc, probe_impl=s))
-            for s in pimpls
-        }
-        inputs = {
-            s: (tcols, kcols, jnp.int32(nq), fpm0) for s in pimpls
-        }
-        measured["probe_lane"] = interleave(fns, inputs, "probe", nq)
-
-    if "expand" in kernels:
-        # the successor key plane at the same lane count: hashed
-        # 5-word states -> 64-bit fingerprints (the bench layout)
-        W = 5
-        ks = KeySpec(160, W, 64)
-        key = jax.random.PRNGKey(3)
-        packedf = jax.random.bits(key, (nq, W), jnp.uint32)
-        vflat = jnp.arange(nq) < int(nq * 0.9)
-        chunk = min(8192, nq)
-
-        def legacy_plane(p, v):
-            # the legacy expand's chunked scan structure
-            pc = p.reshape(nq // chunk, chunk, W)
-            vc = v.reshape(nq // chunk, chunk)
-
-            def one(c):
-                pi, vi = c
-                return tuple(
-                    jnp.where(vi, col, SENTINEL)
-                    for col in ks.make(pi)
-                )
-
-            cols = lax.map(one, (pc, vc))
-            return tuple(c.reshape(nq) for c in cols)
-
-        fns, inputs = {}, {}
-        for s in impls:
-            if s == "legacy":
-                fns[s] = jax.jit(legacy_plane)
-            else:
-                fns[s] = jax.jit(
-                    functools.partial(tiles.key_plane, ks, impl=s)
-                )
-            inputs[s] = (packedf, vflat)
-        measured["expand_row"] = interleave(fns, inputs, "expand", nq)
-
-    if "sieve" in kernels:
-        occ = fpset.occupied_mask(tcols)
-        gen = jnp.where(
-            occ,
-            (jnp.arange(cap, dtype=jnp.int32) % 4) + 1,
-            0,
-        )
-        gen = jnp.concatenate([gen, jnp.zeros((1,), jnp.int32)])
-        fns = {
-            s: jax.jit(
-                functools.partial(sieve.extract_cold, sieve_impl=s)
-            )
-            for s in impls
-        }
-        inputs = {s: (tcols, gen, jnp.int32(2)) for s in impls}
-        measured["sieve_slot"] = interleave(fns, inputs, "sieve", cap)
-
-    if args.cal:
-        try:
-            with open(args.cal) as f:
-                cal = json.load(f)
-        except (OSError, json.JSONDecodeError):
-            cal = {"units": {}}
-        units = cal.setdefault("units", {})
-        for stage, rows in measured.items():
-            for name, ns in rows.items():
-                if name == "legacy":
-                    continue  # the plain stage unit stays calibrate's
-                units[f"{stage}_{name}_ns"] = round(ns, 4)
-        with open(args.cal, "w") as f:
-            json.dump(cal, f, indent=1, sort_keys=True)
-        print(f"merged per-impl units into {args.cal}")
-    return 0
-
-
 # --------------------------------------------------------------- main
 
 
@@ -1037,30 +863,6 @@ def main(argv=None):
                     help="also run a liveness check and calibrate the "
                     "sweep unit cost from its measured sweep wall")
     pc.set_defaults(fn=cmd_calibrate)
-
-    pt = sub.add_parser(
-        "tiles",
-        help="dense-tile kernel head-to-head (r23, ops/tiles.py): "
-        "probe/expand/sieve per-impl ns/lane, interleaved min-of-N")
-    pt.add_argument("--kernel", choices=["probe", "expand", "sieve",
-                                         "all"], default="all")
-    pt.add_argument("--impls", default="legacy,tile,pallas",
-                    help="comma list from legacy,tile,pallas")
-    pt.add_argument("--cap-log2", type=int, default=18,
-                    help="fpset table capacity (default 2^18 — the "
-                    "253k-oracle shape)")
-    pt.add_argument("--nq", type=int, default=1 << 16,
-                    help="accumulator lanes per flush (default 64Ki)")
-    pt.add_argument("--dup-frac", type=float, default=0.5,
-                    help="fraction of flush lanes re-presenting "
-                    "already-inserted keys")
-    pt.add_argument("--reps", type=int, default=2,
-                    help="interleaved timing rounds (min-of-N)")
-    pt.add_argument("--cal", default=None, metavar="FILE",
-                    help="merge measured per-impl unit costs "
-                    "(probe_lane_tile_ns ...) into this "
-                    "calibration.json for tune/predict.py")
-    pt.set_defaults(fn=cmd_tiles)
 
     args = ap.parse_args(argv)
     return args.fn(args) or 0

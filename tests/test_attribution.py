@@ -362,6 +362,38 @@ def test_bench_schema_v7_keys():
     assert ckr.validate_bench_artifact(v6, "v6") == []
 
 
+def test_bench_schema_v12_keys():
+    """bench_schema 12 artifacts must carry the kernel fields +
+    probe_lanes_per_sec; a v12 artifact missing them fails; a v11
+    artifact without them stays clean (additive versioning)."""
+    ckr = _checker_mod()
+    base = {k: 1 for k in ckr.BENCH_KEYS_V12}
+    base.update(bench_schema=12, value=1.0)
+    assert ckr.validate_bench_artifact(dict(base), "good") == []
+    bad = dict(base)
+    del bad["probe_impl"], bad["probe_lanes_per_sec"]
+    errs = ckr.validate_bench_artifact(bad, "bad")
+    assert any("probe_impl" in e for e in errs)
+    assert any("probe_lanes_per_sec" in e for e in errs)
+    v11 = {k: 1 for k in ckr.BENCH_KEYS_V11}
+    v11.update(bench_schema=11, value=1.0)
+    assert ckr.validate_bench_artifact(v11, "v11") == []
+
+
+def test_stream_record_derives_probe_lanes_per_sec(tmp_path):
+    """Stream-ingested records derive the flush-stage throughput from
+    the work counters + wall clock, and carry the kernel fields at
+    their one value."""
+    stream = str(tmp_path / "run.jsonl")
+    _mk(SMALL_CONFIGS["producer_on"], telemetry=stream).run()
+    v = ledger.record_from_file(stream)["values"]
+    assert v["probe_lanes_per_sec"] == round(
+        v["work_probe_lanes"] / v["wall_s"], 1
+    )
+    for k, want in obs.IMPL_FIELDS.items():
+        assert v[k] == want, k
+
+
 # ---- liveness sweep attribution (satellite 1) -----------------------
 
 

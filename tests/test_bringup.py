@@ -1,7 +1,6 @@
 """Start-up and device plumbing (PR 23 bring-up): the one compile-cache
-helper and what it does to a second process (PR 30), the Pallas
-interpret rule, ``-workers N`` with too few devices,
-and the source-hash native build."""
+helper and what it does to a second process (PR 30), ``-workers N``
+with too few devices, and the source-hash native build."""
 
 import os
 import shutil
@@ -13,7 +12,6 @@ import pytest
 
 from pulsar_tlaplus_tpu import native
 from pulsar_tlaplus_tpu.obs import report
-from pulsar_tlaplus_tpu.ops import tiles
 from pulsar_tlaplus_tpu.utils import device
 
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -119,21 +117,6 @@ def test_second_process_compiles_nothing(tmp_path):
     assert "CompactedLedgerLeak" in out1 and out2 == out1
 
 
-def test_pallas_interpret_follows_backend(monkeypatch):
-    assert jax.default_backend() == "cpu" and tiles.interpret()
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert not tiles.interpret()
-
-
-def test_pallas_interpret_swallows_nothing(monkeypatch):
-    def boom():
-        raise RuntimeError("no backend")
-
-    monkeypatch.setattr(jax, "default_backend", boom)
-    with pytest.raises(RuntimeError, match="no backend"):
-        tiles.interpret()
-
-
 def test_workers_beyond_device_count_is_an_error(capsys):
     """``-workers N`` never silently runs on fewer devices."""
     from pulsar_tlaplus_tpu import cli
@@ -177,3 +160,30 @@ def test_predict_unknown_device_is_an_error():
         predict._device_link(ref, {}, "rtt_s")
     cal = {"rtt_s": 0.002}
     assert predict._device_link(ref, cal, "rtt_s") == 0.002
+
+
+@pytest.mark.parametrize(
+    "flag,value",
+    [
+        ("-visited", "sort"), ("-compact", "sort"),
+        ("-probe-impl", "tile"), ("-expand-impl", "tile"),
+        ("-sieve-impl", "tile"),
+    ],
+)
+def test_removed_selector_flag_is_refused(flag, value, capsys):
+    """The five kernel selectors are gone with their second
+    implementations: a command line that still passes one exits 2 with
+    argparse's message, it does not run some other path."""
+    from pulsar_tlaplus_tpu import cli
+
+    with pytest.raises(SystemExit) as ei:
+        cli.main(
+            [
+                "check", os.path.join(CHECKOUT, "specs", "compaction.tla"),
+                flag, value,
+            ]
+        )
+    assert ei.value.code == 2
+    io = capsys.readouterr()
+    assert "unrecognized arguments" in io.err and flag in io.err
+    assert "distinct states" not in io.out

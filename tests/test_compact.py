@@ -1,7 +1,7 @@
-"""Round-10 tentpole tests: the sort-free log-shift stream compaction
-(`ops/compact.py`) — kernel properties against the sort path, engine
-discovery-order differentials pinned state-for-state on the published
-oracles, the fused+grouped liveness sweep parity, the capacity-tier
+"""The sort-free log-shift stream compaction (`ops/compact.py`) —
+kernel properties against a numpy reference, the TPU materialisation
+held to the CPU's through the engine, the fused+grouped liveness sweep
+parity, the capacity-tier
 prewarm (zero post-run() compiles), and the fpset probe-schedule
 exposure."""
 
@@ -17,15 +17,12 @@ import jax.numpy as jnp
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 from pulsar_tlaplus_tpu.engine.liveness import LivenessChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
-from pulsar_tlaplus_tpu.ops import compact, dedup, fpset
+from pulsar_tlaplus_tpu.ops import compact, fpset
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from tests.helpers import SMALL_CONFIGS
 
 CONSUMER_CFG = dataclasses.replace(
     SMALL_CONFIGS["producer_on"], model_consumer=True
-)
-FULL_CFG = dataclasses.replace(
-    pe.SHIPPED_CFG, model_producer=True, retain_null_key=False
 )
 
 
@@ -44,8 +41,7 @@ def test_logshift_matches_sort_random_masks_and_widths(
     """Random masks, drop rates, lengths (incl. non-powers-of-two) and
     column counts, under BOTH materializations (the TPU doubling-shift
     passes and the CPU prefix+gather): the kept prefix must equal the
-    numpy reference AND the sort path element-for-element, idx
-    included."""
+    numpy reference element-for-element, idx included."""
     monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
     rng = np.random.default_rng(0)
     for trial in range(10):
@@ -58,15 +54,19 @@ def test_logshift_matches_sort_random_masks_and_widths(
             for _ in range(ncols)
         ]
         jcols = tuple(jnp.asarray(c) for c in cols)
-        out, idx = compact.logshift_compact(jnp.asarray(drop), jcols)
-        sout, sidx = dedup.compact_by_flag(jnp.asarray(drop), jcols)
+        out, idx = compact.compact_by_flag(jnp.asarray(drop), jcols)
         ref_cols, kept = _ref_compact(drop, cols)
         k = len(kept)
-        for got, srt, want in zip(out, sout, ref_cols):
+        for got, want in zip(out, ref_cols):
             assert np.array_equal(np.asarray(got)[:k], want), trial
-            assert np.array_equal(np.asarray(srt)[:k], want), trial
         assert np.array_equal(np.asarray(idx)[:k], kept), trial
-        assert np.array_equal(np.asarray(sidx)[:k], kept), trial
+        # need_idx=False skips the index column, not the values
+        out2, idx2 = compact.compact_by_flag(
+            jnp.asarray(drop), jcols, need_idx=False
+        )
+        assert idx2 is None
+        for got, want in zip(out2, ref_cols):
+            assert np.array_equal(np.asarray(got)[:k], want), trial
 
 
 @pytest.mark.parametrize("mat", ["shift", "gather"])
@@ -76,7 +76,7 @@ def test_logshift_all_keep_all_drop_edges(n, all_drop, mat, monkeypatch):
     monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
     drop = np.full(n, 1 if all_drop else 0, np.uint32)
     c = np.arange(n, dtype=np.uint32) * 3
-    out, idx = compact.logshift_compact(
+    out, idx = compact.compact_by_flag(
         jnp.asarray(drop), (jnp.asarray(c),)
     )
     k = 0 if all_drop else n
@@ -88,203 +88,48 @@ def test_device_engine_shift_materialization_state_for_state(
     monkeypatch,
 ):
     """The TPU materialization (doubling shifts) forced end-to-end
-    through the device engine on the CPU backend: identical logs to
-    the sort path."""
-    monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", "shift")
+    through the device engine on the CPU backend: identical rows and
+    logs to the gather materialization the CPU picks."""
     c = SMALL_CONFIGS["producer_on"]
     logs = {}
-    for impl in ("logshift", "sort"):
+    for mat in ("shift", "gather"):
+        monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
         ck = DeviceChecker(
             CompactionModel(c), invariants=(), sub_batch=64,
             visited_cap=1 << 8, frontier_cap=1 << 8, group=2,
-            compact_impl=impl,
         )
         r = ck.run()
         n = r.distinct_states
-        logs[impl] = (
+        logs[mat] = (
             n,
+            np.asarray(ck.last_bufs["rows"][: n * ck.W]).copy(),
             np.asarray(ck.last_bufs["parent"][:n]).copy(),
             np.asarray(ck.last_bufs["lane"][:n]).copy(),
         )
-    assert logs["logshift"][0] == logs["sort"][0]
-    assert np.array_equal(logs["logshift"][1], logs["sort"][1])
-    assert np.array_equal(logs["logshift"][2], logs["sort"][2])
+    assert logs["shift"][0] == logs["gather"][0]
+    for a, b in zip(logs["shift"][1:], logs["gather"][1:]):
+        assert np.array_equal(a, b)
 
 
 def test_materialization_env_validation(monkeypatch):
     monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", "bogus")
     with pytest.raises(ValueError, match="shift|gather"):
-        compact.logshift_compact(
+        compact.compact_by_flag(
             jnp.zeros((4,), jnp.uint32),
             (jnp.arange(4, dtype=jnp.uint32),),
         )
 
 
-def test_compact_dispatcher_validates_impl():
-    drop = jnp.zeros((4,), jnp.uint32)
-    cols = (jnp.arange(4, dtype=jnp.uint32),)
-    with pytest.raises(ValueError, match="logshift|sort"):
-        compact.compact_by_flag(drop, cols, impl="bogus")
-    # need_idx=False skips the iota column
-    out, idx = compact.compact_by_flag(drop, cols, need_idx=False)
-    assert idx is None and np.array_equal(np.asarray(out[0]),
-                                          np.arange(4))
-
-
-# ---- engine differential: logshift vs sort, state for state ----------
-
-
-def test_device_engine_compact_differential_state_for_state():
-    """Same model, both compaction impls, growth + mid-level syncs
-    forced by tiny caps: identical counts, levels, AND identical row
-    stores / parent / lane logs — the log-shift append must assign
-    every gid exactly like the sort append."""
-    c = SMALL_CONFIGS["producer_on"]
-    m = CompactionModel(c)
-    results = {}
-    for impl in ("logshift", "sort"):
-        ck = DeviceChecker(
-            CompactionModel(c), invariants=(), sub_batch=64,
-            visited_cap=1 << 6, frontier_cap=1 << 6, group=2,
-            compact_impl=impl,
-        )
-        r = ck.run()
-        n = r.distinct_states
-        results[impl] = (
-            r,
-            np.asarray(ck.last_bufs["rows"][: n * m.layout.W]).copy(),
-            np.asarray(ck.last_bufs["parent"][:n]).copy(),
-            np.asarray(ck.last_bufs["lane"][:n]).copy(),
-        )
-    rl, rows_l, par_l, lane_l = results["logshift"]
-    rs, rows_s, par_s, lane_s = results["sort"]
-    want = pe.check(c, invariants=())
-    assert rl.distinct_states == rs.distinct_states == want.distinct_states
-    assert rl.level_sizes == rs.level_sizes
-    assert np.array_equal(rows_l, rows_s)
-    assert np.array_equal(par_l, par_s)
-    assert np.array_equal(lane_l, lane_s)
-
-
-def test_device_engine_shipped_oracle_sort_compact_impl():
-    """First published oracle (45,198 / diameter 20, compaction.tla:23)
-    pinned on the SORT compaction path explicitly (the rest of the
-    suite pins it on the logshift default — this stays meaningful if
-    the default ever flips back)."""
-    r = DeviceChecker(
-        CompactionModel(pe.SHIPPED_CFG), sub_batch=2048,
-        visited_cap=1 << 16, frontier_cap=1 << 15, compact_impl="sort",
-    ).run()
-    assert r.distinct_states == 45198
-    assert r.diameter == 20
-    assert r.violation is None and not r.deadlock
-
-
-@pytest.mark.slow
-def test_device_engine_full_cfg_compact_differential():
-    """Second published oracle (253,361 / diameter 23): logshift vs
-    sort pinned state-for-state (parent/lane logs equal) at the
-    round-6 differential shape — the acceptance oracle for the
-    CPU-mesh append differential.  Slow-marked (two full-cfg runs) so
-    tier-1 stays inside its budget; the real host runs it, and the
-    45k state-for-state + the small-config differentials cover the
-    same property in-tier."""
-    m = CompactionModel(FULL_CFG)
-    logs = {}
-    for impl in ("logshift", "sort"):
-        ck = DeviceChecker(
-            CompactionModel(FULL_CFG), invariants=(), sub_batch=4096,
-            visited_cap=1 << 18, frontier_cap=1 << 17, flush_factor=2,
-            compact_impl=impl,
-        )
-        r = ck.run()
-        assert r.distinct_states == 253361
-        assert r.diameter == 23
-        n = r.distinct_states
-        logs[impl] = (
-            np.asarray(ck.last_bufs["parent"][:n]).copy(),
-            np.asarray(ck.last_bufs["lane"][:n]).copy(),
-        )
-        del ck
-    assert np.array_equal(logs["logshift"][0], logs["sort"][0])
-    assert np.array_equal(logs["logshift"][1], logs["sort"][1])
-
-
-def test_sharded_engine_compact_differential_state_for_state():
-    """The sharded append's compaction carries rows + routed parent +
-    lane: both impls must produce identical per-shard stores on the
-    virtual mesh."""
-    from pulsar_tlaplus_tpu.engine.sharded_device import (
-        ShardedDeviceChecker,
-    )
-
-    c = SMALL_CONFIGS["producer_on"]
-    want = pe.check(c, invariants=())
-    stores = {}
-    for impl in ("logshift", "sort"):
-        ck = ShardedDeviceChecker(
-            CompactionModel(c), n_devices=4, invariants=(),
-            sub_batch=64, visited_cap=1 << 6, group=2,
-            compact_impl=impl,
-        )
-        r = ck.run()
-        assert r.distinct_states == want.distinct_states
-        assert r.diameter == want.diameter
-        counts = np.asarray(ck.last_stats_matrix[:, 0])
-        stores[impl] = [
-            (
-                np.asarray(
-                    ck.last_bufs["rows"][s, : int(counts[s]) * ck.W]
-                ).copy(),
-                np.asarray(
-                    ck.last_bufs["parent"][s, : int(counts[s])]
-                ).copy(),
-                np.asarray(
-                    ck.last_bufs["lane"][s, : int(counts[s])]
-                ).copy(),
-            )
-            for s in range(ck.N)
-        ]
-    for (ra, pa, la), (rb, pb, lb) in zip(
-        stores["logshift"], stores["sort"]
-    ):
-        assert np.array_equal(ra, rb)
-        assert np.array_equal(pa, pb)
-        assert np.array_equal(la, lb)
-
-
-@pytest.mark.slow
-@pytest.mark.parametrize("impl", ["logshift", "sort"])
-def test_sharded_engine_full_cfg_both_compact_impls(impl):
-    """253,361 pinned on the sharded engine under both compaction
-    impls (slow: two full-cfg runs on the virtual mesh — tier-1 skips
-    via -m 'not slow'; the real host runs it)."""
-    from pulsar_tlaplus_tpu.engine.sharded_device import (
-        ShardedDeviceChecker,
-    )
-
-    r = ShardedDeviceChecker(
-        CompactionModel(FULL_CFG), n_devices=4, invariants=(),
-        sub_batch=2048, visited_cap=1 << 16, compact_impl=impl,
-    ).run()
-    assert r.distinct_states == 253361
-    assert r.diameter == 23
-
-
-# ---- fused + grouped liveness sweep ---------------------------------
-
-
 def test_liveness_fused_sweep_parity_consumer_oracle():
     """The grouped sweep (G chunks per dispatch) must produce the same
     wf_next verdict, edge count, and out-degrees as the per-chunk
-    pipeline on the consumer_on lasso oracle, for sort and logshift
-    compaction alike."""
+    pipeline on the consumer_on lasso oracle."""
     want_holds, _ = pe.check_eventually(CONSUMER_CFG, "wf_next")
     base = None
     for kw in (
         dict(sweep_group=1),
         dict(sweep_group=3),
-        dict(sweep_group=2, compact_impl="sort"),
+        dict(sweep_group=2),
     ):
         lck = LivenessChecker(
             CompactionModel(CONSUMER_CFG), fairness="wf_next",

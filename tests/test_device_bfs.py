@@ -249,3 +249,28 @@ def test_frontier_window_exhaustion_stops_honestly():
     assert r.truncated
     assert r.stop_reason == "row_window"
     assert 0 < r.distinct_states < 45198
+
+
+@pytest.mark.parametrize(
+    "engine,param",
+    [("device", p) for p in (
+        "visited_impl", "compact_impl", "probe_impl", "expand_impl",
+        "sieve_impl",
+    )] + [("sharded", p) for p in ("visited_impl", "compact_impl")],
+)
+def test_kernel_selectors_are_not_constructor_parameters(engine, param):
+    """One implementation per kernel stage: the constructors take no
+    selector, and the benchmark's surface (``seed_cap`` among it) is
+    still accepted."""
+    from pulsar_tlaplus_tpu.engine.sharded_device import (
+        ShardedDeviceChecker,
+    )
+
+    m = CompactionModel(SMALL_CONFIGS["producer_on"])
+    if engine == "device":
+        DeviceChecker(m, seed_cap=1 << 21, rows_window="frontier")
+        with pytest.raises(TypeError, match=param):
+            DeviceChecker(m, **{param: "fpset"})
+    else:
+        with pytest.raises(TypeError, match=param):
+            ShardedDeviceChecker(m, n_devices=2, **{param: "fpset"})

@@ -1,9 +1,8 @@
-"""Property and differential tests for the fpset visited-set subsystem
-(round 6 tentpole): the table must behave as an exact set (insert/
-lookup round-trips, adversarial same-key batches, growth-preserving
-rehash, loud failure on overload), and the fpset-backed device engine
-must match the legacy sort-merge flush STATE FOR STATE — same counts,
-same levels, same gid assignment, same trace logs."""
+"""Property tests for the fpset visited-set subsystem: the table must
+behave as an exact set (insert/lookup round-trips, adversarial
+same-key batches, growth-preserving rehash, loud failure on overload);
+the flush is held to a Python set and the keys to a numpy murmur3; the
+engines reach the published counts."""
 
 import numpy as np
 import pytest
@@ -343,48 +342,14 @@ def test_fpm_carries_lane_rounds_past_32_bits_and_pads_old_frames():
     ]
 
 
-# ---- engine differential: fpset vs the legacy sort-merge flush -------
+# ---- the engines on the published oracles ---------------------------
 
 
-def test_fpset_engine_matches_sort_engine_state_for_state():
-    """Same model, both visited implementations: identical counts,
-    levels, AND identical row stores / parent / lane logs — the fpset
-    flush must assign every gid exactly like the sort-merge flush."""
-    c = SMALL_CONFIGS["producer_on"]
-    m = CompactionModel(c)
-    results = {}
-    for impl in ("fpset", "sort"):
-        ck = DeviceChecker(
-            CompactionModel(c), invariants=(), sub_batch=64,
-            visited_cap=1 << 10, frontier_cap=1 << 10, group=2,
-            visited_impl=impl,
-        )
-        r = ck.run()
-        n = r.distinct_states
-        results[impl] = (
-            r,
-            np.asarray(ck.last_bufs["rows"][: n * m.layout.W]).copy(),
-            np.asarray(ck.last_bufs["parent"][:n]).copy(),
-            np.asarray(ck.last_bufs["lane"][:n]).copy(),
-        )
-    rf, rows_f, par_f, lane_f = results["fpset"]
-    rs, rows_s, par_s, lane_s = results["sort"]
-    assert rf.distinct_states == rs.distinct_states
-    assert rf.diameter == rs.diameter
-    assert rf.level_sizes == rs.level_sizes
-    assert np.array_equal(rows_f, rows_s)
-    assert np.array_equal(par_f, par_s)
-    assert np.array_equal(lane_f, lane_s)
-
-
-@pytest.mark.parametrize("impl", ["fpset", "sort"])
-def test_engine_shipped_oracle_both_impls(impl):
-    """45,198 / diameter 20 (compaction.tla:23) pinned on BOTH visited
-    implementations explicitly (the rest of the suite exercises the
-    default; this stays meaningful if the default ever flips back)."""
+def test_engine_shipped_oracle():
+    """45,198 / diameter 20 (compaction.tla:23) on the device engine."""
     r = DeviceChecker(
         CompactionModel(pe.SHIPPED_CFG), sub_batch=2048,
-        visited_cap=1 << 16, frontier_cap=1 << 15, visited_impl=impl,
+        visited_cap=1 << 16, frontier_cap=1 << 15,
     ).run()
     assert r.distinct_states == 45198
     assert r.diameter == 20
@@ -392,9 +357,8 @@ def test_engine_shipped_oracle_both_impls(impl):
 
 
 def test_fpset_full_cfg_published_count():
-    """The second published oracle (253,361 / diameter 23) on the
-    fpset-backed engine explicitly, with growth forced from a small
-    initial table (ISSUE r6 acceptance)."""
+    """The second published oracle (253,361 / diameter 23) with
+    growth forced from a small initial table (ISSUE r6 acceptance)."""
     import dataclasses
 
     c = dataclasses.replace(
@@ -403,7 +367,6 @@ def test_fpset_full_cfg_published_count():
     r = DeviceChecker(
         CompactionModel(c), invariants=(), sub_batch=4096,
         visited_cap=1 << 12, frontier_cap=1 << 17, flush_factor=2,
-        visited_impl="fpset",
     ).run()
     assert r.distinct_states == 253361
     assert r.diameter == 23
@@ -446,8 +409,7 @@ def test_load_seed_frontier_window_guard():
 # ---- sharded engine differential (virtual CPU mesh) ------------------
 
 
-@pytest.mark.parametrize("impl", ["fpset", "sort"])
-def test_sharded_fpset_counts_match_oracle(impl):
+def test_sharded_fpset_counts_match_oracle():
     from pulsar_tlaplus_tpu.engine.sharded_device import (
         ShardedDeviceChecker,
     )
@@ -456,7 +418,165 @@ def test_sharded_fpset_counts_match_oracle(impl):
     want = pe.check(c, invariants=())
     got = ShardedDeviceChecker(
         CompactionModel(c), n_devices=4, invariants=(), sub_batch=64,
-        visited_cap=1 << 6, group=2, visited_impl=impl,
+        visited_cap=1 << 6, group=2,
     ).run()
     assert got.distinct_states == want.distinct_states
     assert got.diameter == want.diameter
+
+
+# ---- the flush and the keys against plain references ------------------
+
+
+def _rand_cols(key, n, K):
+    cols = []
+    for _ in range(K):
+        key, sub = jax.random.split(key)
+        cols.append(jax.random.bits(sub, (n,), jnp.uint32))
+    return key, tuple(cols)
+
+
+def _flush_against_a_set(tcols, kcols, n_acc, members):
+    """One ``flush_acc`` held to a Python set: the lanes flagged new
+    are exactly the first occurrence of each key that is valid (inside
+    ``n_acc``, not all-SENTINEL) and not yet a member."""
+    fpm = jnp.zeros((fpset.FPM_N,), jnp.int32)
+    t2, n_new, flags, fpm2 = fpset.flush_acc(
+        tcols, kcols, jnp.int32(n_acc), fpm
+    )
+    host = np.stack([np.asarray(c) for c in kcols], axis=1)
+    seen = set(members)
+    want = np.zeros((host.shape[0],), np.uint32)
+    for lane in range(n_acc):
+        k = tuple(int(x) for x in host[lane])
+        if all(x == 0xFFFFFFFF for x in k) or k in seen:
+            continue
+        seen.add(k)
+        want[lane] = 1
+    assert np.array_equal(np.asarray(flags), want)
+    assert int(n_new) == int(want.sum())
+    assert int(np.asarray(fpm2)[2]) == 0  # no lane failed
+    # the table now holds exactly the set
+    cap = t2[0].shape[0] - 1
+    occ = np.asarray(fpset.occupied_mask(t2))[:cap]
+    held = {
+        tuple(int(np.asarray(c)[i]) for c in t2)
+        for i in np.flatnonzero(occ)
+    }
+    assert held == seen
+    return want
+
+
+# (cap_log2, nq, dup_frac, n_acc_frac, fill_frac): ragged lane counts,
+# dup-heavy batches, stale tails past n_acc, and a growth-boundary
+# load; fill_frac keeps the post-flush load under the engines' growth
+# threshold (they rehash BEFORE a flush could overload the table)
+FLUSH_SHAPES = [
+    (12, 1000, 0.0, 1.0, 0.375),
+    (12, 1024, 0.6, 1.0, 0.5),
+    (11, 777, 0.5, 0.61, 0.375),
+    (11, 2048, 0.9, 0.83, 0.25),
+    (13, 3000, 0.3, 1.0, 0.375),
+]
+
+
+@pytest.mark.parametrize(
+    "cap_log2,nq,dup_frac,n_acc_frac,fill_frac", FLUSH_SHAPES
+)
+def test_flush_acc_against_a_python_set(
+    cap_log2, nq, dup_frac, n_acc_frac, fill_frac
+):
+    cap, K = 1 << cap_log2, 2
+    key = jax.random.PRNGKey(cap_log2 * 1000 + nq)
+    key, fill_cols = _rand_cols(key, int(cap * fill_frac), K)
+    nfill = fill_cols[0].shape[0]
+    _flush_against_a_set(
+        fpset.empty_cols(cap, K), fill_cols, nfill, set()
+    )
+    tcols, _, _, _ = fpset.flush_acc(
+        fpset.empty_cols(cap, K), fill_cols, jnp.int32(nfill),
+        jnp.zeros((fpset.FPM_N,), jnp.int32),
+    )
+    members = set(
+        zip(*(np.asarray(c).tolist() for c in fill_cols))
+    )
+    ndup = int(nq * dup_frac)
+    key, fresh = _rand_cols(key, nq - ndup, K)
+    dup_ix = jnp.arange(ndup) % nfill
+    kcols = tuple(
+        jnp.concatenate([f[dup_ix], g])
+        for f, g in zip(fill_cols, fresh)
+    )
+    # a few SENTINEL (masked-expand) lanes sprinkled in
+    sent = jnp.arange(nq) % 97 == 3
+    kcols = tuple(
+        jnp.where(sent, jnp.uint32(0xFFFFFFFF), c) for c in kcols
+    )
+    _flush_against_a_set(tcols, kcols, int(nq * n_acc_frac), members)
+
+
+def test_flush_acc_within_batch_duplicates_first_lane_wins():
+    """Lanes presenting the SAME new key in one batch: exactly one
+    winner, the minimum lane id — what gives every state its gid."""
+    cap, K, nq = 1 << 10, 2, 512
+    _key, cols = _rand_cols(jax.random.PRNGKey(7), nq, K)
+    # groups of 4 consecutive lanes share a key
+    kcols = tuple(c[::4].repeat(4)[:nq] for c in cols)
+    want = _flush_against_a_set(
+        fpset.empty_cols(cap, K), kcols, nq, set()
+    )
+    assert (np.flatnonzero(want) % 4 == 0).all()
+
+
+def _np_murmur3_words(words, seed):
+    """murmur3_32 over the trailing word axis, in 64-bit numpy integers
+    masked to 32 bits (no reliance on wrap-around)."""
+    m = np.uint64(0xFFFFFFFF)
+    w64 = words.astype(np.uint64)
+
+    def rotl(x, r):
+        return ((x << np.uint64(r)) | (x >> np.uint64(32 - r))) & m
+
+    h = np.full(words.shape[:-1], seed, np.uint64)
+    for i in range(words.shape[-1]):
+        k = (w64[..., i] * np.uint64(0xCC9E2D51)) & m
+        k = (rotl(k, 15) * np.uint64(0x1B873593)) & m
+        h = h ^ k
+        h = (rotl(h, 13) * np.uint64(5) + np.uint64(0xE6546B64)) & m
+    h = h ^ np.uint64(4 * words.shape[-1])
+    h = h ^ (h >> np.uint64(16))
+    h = (h * np.uint64(0x85EBCA6B)) & m
+    h = h ^ (h >> np.uint64(13))
+    h = (h * np.uint64(0xC2B2AE35)) & m
+    return (h ^ (h >> np.uint64(16))).astype(np.uint32)
+
+
+@pytest.mark.parametrize(
+    "total_bits,W,fp_bits",
+    [(60, 2, None), (90, 3, None), (160, 5, 64), (160, 5, 96)],
+)
+def test_keyspec_make_against_numpy(total_bits, W, fp_bits):
+    """Exact layouts: the key IS the packed words (zero-padded to the
+    column count).  Hashed layouts: one murmur3 per column, and the
+    all-SENTINEL tuple (the table's empty marker) never comes out."""
+    from pulsar_tlaplus_tpu.ops.dedup import KeySpec
+
+    ks = KeySpec(total_bits, W, fp_bits)
+    for nc in (257, 4096, 5000):
+        packed = np.asarray(
+            jax.random.bits(jax.random.PRNGKey(nc), (nc, W), jnp.uint32)
+        )
+        got = [np.asarray(c) for c in ks.make(jnp.asarray(packed))]
+        if ks.exact:
+            want = [packed[:, i] for i in range(W)]
+            want += [np.zeros((nc,), np.uint32)] * (ks.ncols - W)
+        else:
+            assert ks.ncols == fp_bits // 32
+            seeds = (0x9E3779B9, 0x85EBCA6B, 0xC2B2AE35)[: ks.ncols]
+            want = [_np_murmur3_words(packed, s) for s in seeds]
+            sent = np.all(
+                np.stack(want) == np.uint32(0xFFFFFFFF), axis=0
+            )
+            want[-1] = np.where(sent, want[-1] ^ np.uint32(1), want[-1])
+        assert len(got) == len(want) == ks.ncols
+        for a, b in zip(got, want):
+            assert np.array_equal(a, b)

@@ -177,16 +177,6 @@ def test_fused_growth_and_flush_factor_matches_oracle():
     assert got.diameter == want.diameter
 
 
-def test_fused_sort_visited_falls_back_to_stage():
-    """The fused kernel chains the fpset probe; the legacy sort-merge
-    visited set keeps the stage chain (the r6 differential path stays
-    bit-for-bit) — silently, so existing -visited sort flows work."""
-    ck = _mk(SMALL_CONFIGS["producer_on"], visited_impl="sort")
-    assert ck.fuse == "stage"
-    r = ck.run()
-    assert r.distinct_states == 1654
-
-
 def test_fuse_ctor_validation():
     with pytest.raises(ValueError, match="fuse must be"):
         _mk(SMALL_CONFIGS["producer_on"], fuse="banana")

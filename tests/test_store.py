@@ -305,6 +305,51 @@ def test_sieve_tag_evict_unflag_roundtrip():
     assert (np.asarray(out[0][:n]) == np.asarray(ak[0])[[2, 5, 11]]).all()
 
 
+def test_extract_cold_against_numpy():
+    """``extract_cold`` on a three-quarters-full 2^11-slot table with
+    five generations: the holed table, the cleared generations, the
+    eviction run (sorted, SENTINEL-padded) and the count are what numpy
+    computes from the same arrays."""
+    import jax
+
+    from pulsar_tlaplus_tpu.ops import fpset as fps
+    from pulsar_tlaplus_tpu.store import sieve as store_sieve
+
+    cap, K = 1 << 11, 3
+    key = jax.random.PRNGKey(3)
+    cols = []
+    for _ in range(K):
+        key, sub = jax.random.split(key)
+        cols.append(jax.random.bits(sub, ((cap * 3) // 4,), jnp.uint32))
+    tcols, _, _, _ = fps.flush_acc(
+        fps.empty_cols(cap, K), tuple(cols),
+        jnp.int32(cols[0].shape[0]),
+        jnp.zeros((fps.FPM_N,), jnp.int32),
+    )
+    occ = fps.occupied_mask(tcols)
+    gen = jnp.where(occ, (jnp.arange(cap, dtype=jnp.int32) % 5) + 1, 0)
+    gen = jnp.concatenate([gen, jnp.zeros((1,), jnp.int32)])
+    t_np = [np.asarray(c) for c in tcols]
+    g_np = np.asarray(gen)
+    sent = np.uint32(0xFFFFFFFF)
+    live = ~np.all(np.stack(t_np) == sent, axis=0)
+    live[cap] = False  # the trash row is never occupied
+    for cutoff in (1, 3):
+        holed, gen2, ev, n_ev = store_sieve.extract_cold(
+            tcols, gen, cutoff
+        )
+        cold = live & (g_np >= 1) & (g_np <= cutoff)
+        assert int(n_ev) == int(cold.sum()) > 0
+        for got, c in zip(holed, t_np):
+            assert np.array_equal(np.asarray(got), np.where(cold, sent, c))
+        assert np.array_equal(np.asarray(gen2), np.where(cold, 0, g_np))
+        order = np.lexsort([c[cold] for c in reversed(t_np)])
+        for got, c in zip(ev, t_np):
+            want = np.full((cap + 1,), sent)
+            want[: int(n_ev)] = c[cold][order]
+            assert np.array_equal(np.asarray(got), want)
+
+
 # --------------------------- tiered-vs-untiered exactness (the hinge)
 
 

@@ -96,26 +96,6 @@ def test_device_resume_trace_spans_checkpoint(tmp_path):
     )
 
 
-def test_device_sort_mode_resume(tmp_path):
-    """-visited sort keeps its own frame layout (sorted key prefix);
-    resume is exact there too."""
-    m = _shipped()
-    path = str(tmp_path / "sort.npz")
-    DeviceChecker(
-        m, visited_impl="sort", checkpoint_path=path,
-        checkpoint_every=3, max_states=10_000, **KW,
-    ).run()
-    r = DeviceChecker(
-        m, visited_impl="sort", checkpoint_path=path, **KW
-    ).run(resume=True)
-    assert r.distinct_states == 45198 and r.diameter == 20
-    # sort-mode frames must not resume under fpset (different layout)
-    with pytest.raises(ValueError, match="different configuration"):
-        DeviceChecker(
-            m, visited_impl="fpset", checkpoint_path=path, **KW
-        ).run(resume=True)
-
-
 def test_device_frontier_window_resume(tmp_path):
     """Frontier-window mode checkpoints only the live rows window;
     resume restores it at window offset 0 and stays exact."""
@@ -368,3 +348,63 @@ def test_preemption_watcher_signal_sets_flag():
         assert w.requested
     # handlers restored on exit
     assert signal.getsignal(signal.SIGTERM) != w._handle
+
+
+# ---- the configuration signature: frames and warm artifacts survive ---
+
+_SIG_SHIPPED = (
+    "Constants(message_sent_limit=3, compaction_times_limit=3, "
+    "model_consumer=False, consume_times_limit=2, num_keys=2, "
+    "num_values=2, retain_null_key=True, max_crash_times=1, "
+    "model_producer=False)"
+)
+_SIG_253K = _SIG_SHIPPED.replace(
+    "retain_null_key=True", "retain_null_key=False"
+).replace("model_producer=False", "model_producer=True")
+_SIG_INVS = ("TypeSafe", "CompactionHorizonCorrectness")
+
+
+@pytest.mark.parametrize("engine", ["device", "sharded"])
+@pytest.mark.parametrize(
+    "cfg,model_sig",
+    [("compaction.cfg", _SIG_SHIPPED), ("compaction_253k.cfg", _SIG_253K)],
+)
+def test_config_signature_is_the_one_frames_were_written_under(
+    cfg, model_sig, engine
+):
+    """The signature a frame or a warm artifact must agree on, as the
+    commit before the kernel selectors went computed it (PR 30): it
+    still names ``visited_impl='fpset'`` / ``sharded_device_r6_fpset``,
+    so what that commit wrote loads under this one."""
+    from pulsar_tlaplus_tpu.engine.sharded_device import (
+        ShardedDeviceChecker,
+    )
+    from pulsar_tlaplus_tpu.utils import cfg as cfgmod
+    from tests.helpers import SPECS
+
+    with open(os.path.join(SPECS, cfg)) as f:
+        m = CompactionModel(cfgmod.to_constants(cfgmod.parse_cfg(f.read())))
+    if engine == "device":
+        got = DeviceChecker(m)._config_sig()
+        want = repr(
+            (
+                ("check_deadlock", "True"),
+                ("engine", "'device_bfs_r7'"),
+                ("invariants", repr(_SIG_INVS)),
+                ("key_cols", "2"),
+                ("key_exact", "True"),
+                ("model", repr(model_sig)),
+                ("rows_window", "'all'"),
+                ("state_bits", "42"),
+                ("visited_impl", "'fpset'"),
+            )
+        )
+    else:
+        got = ShardedDeviceChecker(m, n_devices=4)._config_sig()
+        want = repr(
+            (
+                model_sig, _SIG_INVS, True, 42, 2, True, 4, ("shard",),
+                28, "sharded_device_r6_fpset",
+            )
+        )
+    assert got == want

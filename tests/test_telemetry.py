@@ -79,11 +79,31 @@ def test_stream_validates_and_has_lifecycle(std_run, checker_mod):
     hdr = events[0]
     assert hdr["event"] == "run_header"
     assert hdr["engine"] == "device_bfs"
-    assert hdr["visited_impl"] == "fpset"
     res = events[-1]
     assert res["event"] == "result"
     assert res["distinct_states"] == 45198
     assert res["diameter"] == 20
+
+
+def test_run_header_and_stats_carry_the_kernel_fields(std_run):
+    """Streams, frames and warm artifacts written while the kernel
+    stages had selectors carry ``visited_impl``, ``compact_impl``,
+    ``probe_impl``, ``expand_impl`` and ``sieve_impl``; a new run
+    writes all five at the one value each has left (v16 requires the
+    last three on every header)."""
+    _stream, _frame, ck, _r, events = std_run
+    want = {
+        "visited_impl": "fpset", "compact_impl": "logshift",
+        "probe_impl": "legacy", "expand_impl": "legacy",
+        "sieve_impl": "legacy",
+    }
+    assert telemetry.IMPL_FIELDS == want
+    hdr = events[0]
+    assert hdr["v"] == 16
+    stats = [e for e in events if e["event"] == "result"][-1]["stats"]
+    for k, v in want.items():
+        assert hdr[k] == v, k
+        assert stats[k] == v and ck.last_stats[k] == v, k
 
 
 def test_zero_sync_counters_ride_the_stats_fetch(std_run):

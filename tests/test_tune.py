@@ -276,6 +276,41 @@ def test_profile_validator_catches_unknown_knobs():
         profiles.save(bad)
 
 
+@pytest.mark.parametrize(
+    "knob,value",
+    [
+        ("probe_impl", "tile"), ("expand_impl", "pallas"),
+        ("sieve_impl", "tile"), ("compact_impl", "sort"),
+    ],
+)
+def test_profile_naming_a_removed_knob_is_ignored(knob, value, capsys):
+    """A profile on disk is input from outside the program, and earlier
+    versions wrote the kernel selectors into it.  Such a file is
+    refused whole, with a note that names the knob, and the run
+    completes at the defaults."""
+    m = _bk_model()
+    sig = profiles.profile_key(
+        model=m, invariants=tuple(m.default_invariants),
+        engine="device_bfs",
+    )
+    prof = profiles.build(
+        sig=sig, engine="device_bfs", backend="cpu",
+        knobs={"fuse_group": 2}, spec="bookkeeper",
+    )
+    prof["knobs"][knob] = value
+    path = profiles.path_for(sig)
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(prof, f)
+    ck = DeviceChecker(_bk_model(), profile="auto", **BK_KW)
+    note = capsys.readouterr().err
+    assert "tuned profile ignored" in note and knob in note
+    assert ck.profile_sig is None and ck.profile_applied == ()
+    assert ck.RMAX == 8  # the profile's fuse_group went with it
+    r = ck.run()
+    assert (r.distinct_states, r.diameter) == (297, 14)
+
+
 # ---- engine resolution ----------------------------------------------
 
 
