@@ -38,9 +38,9 @@ chain (expand -> fpset lookup_or_insert -> stream compact -> append,
 each its own jitted dispatch since round 10) collapses into ONE
 megakernel dispatch per level — ``_fused_jit`` chains the identical
 traced sub-functions (``ops.fpset.flush_acc``, ``ops.compact.
-compact_rows``, the expand/append bodies below) with every buffer
-donated end-to-end, and a ``lax.while_loop`` walks flush groups AND
-level boundaries inside the dispatch.  Small consecutive levels (the
+compact_rows``, the expand/append bodies of ``engine/bodies.py``) with
+every buffer donated end-to-end, and a ``lax.while_loop`` walks flush
+groups AND level boundaries inside the dispatch.  Small consecutive levels (the
 dispatch-bound ramp: frontiers at or below one expand window) batch up
 to ``fuse_group`` levels per dispatch, with early exit on frontier
 growth past the window, violation/deadlock, or capacity; the kernel
@@ -57,6 +57,7 @@ min-lane-wins dedup).
 
 from __future__ import annotations
 
+import functools
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -66,6 +67,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from pulsar_tlaplus_tpu.engine import bodies
 from pulsar_tlaplus_tpu.engine.bfs import CheckerResult
 from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
@@ -80,7 +82,10 @@ from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, KeySpec
 from pulsar_tlaplus_tpu.ref import pyeval
 
-BIG = jnp.int32(2**31 - 1)
+# one object: JAX merges a program's array constants by identity, and
+# the traced bodies compare against the same sentinel as the programs
+# around them
+BIG = bodies.BIG
 
 # Zero-sync device counters (round 8): the fpset metrics vector rides
 # the ONE hot-path stats fetch — [flushes, probe_rounds, failures,
@@ -104,14 +109,6 @@ FPM_N = fpset.FPM_N
 # host-side at its dispatch sites (``_work_add``), so fused and stage
 # totals are equal state-for-state (pinned in tests).
 WKM_N = fpset.WKM_N
-
-
-# The shared traced sub-functions of ops/ under the stage names of the
-# work counters (obs/spans.py): the stage chain's jits and the fused
-# level kernel both call these, so a device trace names the same stages
-# under either -fuse mode.
-_probe_flush_acc = spans.staged("probe")(fpset.flush_acc)
-_compact_rows = spans.staged("compact")(compact_ops.compact_rows)
 
 
 class DeviceChecker:
@@ -686,6 +683,19 @@ class DeviceChecker:
 
     # -------------------------------------------------------- jitted ops
 
+    def _program(self, key, unit, **statics):
+        """A program unit (``engine/bodies.py``) bound to this
+        checker's static arguments, remembered under ``key``: JAX keys
+        the unit on those arguments, so a later checker of the same
+        binding, sizes and tier is handed the executable this one
+        built.  A ``partial``, not a closure: a Python frame between a
+        dispatch site and the program is on the traceback of every
+        equation the program traces, and a first check pays for it
+        (PERF.md §6, PR 33).  Key columns are passed as tuples."""
+        fn = functools.partial(unit, **statics)
+        self._jits[key] = fn
+        return fn
+
     def _slice_jit(self):
         """Trivial LCAP-dependent slicer: flat rows[LCAP*W], off ->
         flat [G*W] window (a BFS level is a contiguous gid range of the
@@ -712,88 +722,20 @@ class DeviceChecker:
         self._jits[key] = fn
         return fn
 
-    @spans.staged("expand")
-    def _expand_body(
-        self, ak, arows, window, f_off, n_live, dead_gid, gid_base,
-        acc_off,
-    ):
-        """Traced expand sub-function (shared by ``_expand_jit`` and
-        the fused level megakernel): expand one G-state window into
-        ``NCs`` candidate lanes and append their key columns + packed
-        rows into the accumulator at ``acc_off``.  ``f_off`` is the
-        window's first row index within the current level (for
-        liveness masking and deadlock gids).  Returns
-        ``(ak', arows', dead_gid')``."""
-        m, layout = self.model, self.layout
-        Fi, A, W, G = self.Fi, self.A, self.W, self.G
-        keyspec = self.keys
-
-        def chunk(i):
-            rows = lax.dynamic_slice(
-                window, (i * Fi * W,), (Fi * W,)
-            ).reshape(Fi, W)
-            pos = f_off + i * Fi + jnp.arange(Fi, dtype=jnp.int32)
-            live = pos < n_live
-            states = jax.vmap(layout.unpack)(rows)
-            succ, valid = jax.vmap(m.successors)(states)  # [Fi, A]
-            valid = valid & live[:, None]
-            packed = jax.vmap(jax.vmap(layout.pack))(succ)  # [Fi, A, W]
-            fa = Fi * A
-            packedf = packed.reshape(fa, W)
-            kcols = keyspec.make(packedf)
-            vflat = valid.reshape(fa)
-            kcols = tuple(jnp.where(vflat, c, SENTINEL) for c in kcols)
-            if self.check_deadlock:
-                stut = jax.vmap(m.stutter_enabled)(states)
-                dead_rows = live & ~jnp.any(valid, axis=1) & ~stut
-                didx = jnp.min(jnp.where(dead_rows, pos, BIG))
-            else:
-                didx = BIG
-            return kcols, packedf, didx
-
-        def body(dead, i):
-            kcols, p, didx = chunk(i)
-            dead = jnp.minimum(
-                dead, jnp.where(didx < BIG, gid_base + didx, BIG)
-            )
-            return dead, (kcols, p)
-
-        dead, (kcols, packed) = lax.scan(
-            body, dead_gid, jnp.arange(G // Fi, dtype=jnp.int32)
-        )
-        nc = G * A
-        ak = tuple(
-            lax.dynamic_update_slice(akc, kc.reshape(nc), (acc_off,))
-            for akc, kc in zip(ak, kcols)
-        )
-        arows = lax.dynamic_update_slice(
-            arows, packed.reshape(nc, W).T, (0, acc_off)
-        )
-        return ak, arows, dead
-
     def _expand_jit(self):
         """(ak cols, arows[W, ACAP] (word-major SoA), flat window[G*W],
         f_off, n_live, dead_gid, gid_base, acc_off) -> (ak', arows',
-        dead_gid') — the stage-chain dispatch over ``_expand_body``;
-        capacity-independent apart from the fixed ACAP."""
+        dead_gid') — the stage-chain dispatch ``bodies.ptt_expand`` (a
+        unit: built once a process per binding, window and chunk size,
+        not per checker); capacity-independent apart from the fixed
+        ACAP."""
         key = ("expand",)
         if key in self._jits:
             return self._jits[key]
-
-        def ptt_expand(*args):
-            ak = args[: self.K]
-            arows, window, f_off, n_live, dead_gid, gid_base, acc_off = args[
-                self.K:
-            ]
-            ak, arows, dead = self._expand_body(
-                ak, arows, window, f_off, n_live, dead_gid, gid_base,
-                acc_off,
-            )
-            return (*ak, arows, dead)
-
-        fn = jax.jit(ptt_expand, donate_argnums=tuple(range(self.K + 1)))
-        self._jits[key] = fn
-        return fn
+        return self._program(
+            key, bodies.ptt_expand, model=self.model, keys=self.keys,
+            Fi=self.Fi, G=self.G, check_deadlock=self.check_deadlock,
+        )
 
     def _init_jit(self):
         """(ak cols, arows, f_off, acc_off) -> (ak', arows').  Generates
@@ -803,47 +745,10 @@ class DeviceChecker:
         key = ("init",)
         if key in self._jits:
             return self._jits[key]
-        m, layout = self.model, self.layout
-        NCs, W, Fi = self.NCs, self.W, self.Fi
-        keyspec = self.keys
-        n_init = min(m.n_initial, (1 << 31) - 1)
-
-        def chunk(f_off, i):
-            # Fi lanes per scan step: an unchunked vmap over all NCs
-            # lanes materializes the full unpacked state structs —
-            # gigabytes at bench widths (this OOMed the first bench run)
-            idx = f_off + i * Fi + jnp.arange(Fi, dtype=jnp.int32)
-            states = jax.vmap(m.gen_initial)(idx)
-            packed = jax.vmap(layout.pack)(states)
-            valid = idx < n_init
-            kcols = keyspec.make(packed)
-            return (
-                tuple(jnp.where(valid, c, SENTINEL) for c in kcols),
-                packed,
-            )
-
-        @spans.staged("init")
-        def ptt_init(*args):
-            ak = args[: self.K]
-            arows, f_off, acc_off = args[self.K:]
-            _, (kcols, packed) = lax.scan(
-                lambda c, i: (c, chunk(f_off, i)),
-                0,
-                jnp.arange(NCs // Fi, dtype=jnp.int32),
-            )
-            kcols = tuple(c.reshape(NCs) for c in kcols)
-            ak = tuple(
-                lax.dynamic_update_slice(akc, kc, (acc_off,))
-                for akc, kc in zip(ak, kcols)
-            )
-            arows = lax.dynamic_update_slice(
-                arows, packed.reshape(NCs, W).T, (0, acc_off)
-            )
-            return (*ak, arows)
-
-        fn = jax.jit(ptt_init, donate_argnums=tuple(range(self.K + 1)))
-        self._jits[key] = fn
-        return fn
+        return self._program(
+            key, bodies.ptt_init, model=self.model, keys=self.keys,
+            NCs=self.NCs, Fi=self.Fi,
+        )
 
     def _fpflush_jit(self):
         """The flush: probe-or-insert the accumulator keys into the HBM
@@ -863,26 +768,14 @@ class DeviceChecker:
         cannot continue honestly."""
         key = (
             "fpflush", self.TCAP, self.fps_dense, self.fps_stages,
+            compact_ops.materialization(),
         )
         if key in self._jits:
             return self._jits[key]
-        K = self.K
-
-        def ptt_fpflush(*args):
-            tc = args[:K]
-            ak = args[K: 2 * K]
-            n_acc, fpm = args[2 * K], args[2 * K + 1]
-            # the flush body lives in ops/fpset.py since r13 so the
-            # fused level megakernel chains the IDENTICAL trace
-            tc2, n_new, flag, fpm = _probe_flush_acc(
-                tc, ak, n_acc, fpm,
-                dense_rounds=self.fps_dense, stages=self.fps_stages,
-            )
-            return (*tc2, n_new, flag, fpm)
-
-        fn = jax.jit(ptt_fpflush, donate_argnums=tuple(range(self.K)))
-        self._jits[key] = fn
-        return fn
+        return self._program(
+            key, bodies.ptt_fpflush, dense_rounds=self.fps_dense,
+            stages=self.fps_stages, materialize=key[-1],
+        )
 
     def _rehash_jit(self):
         """fpset growth: old table cols -> double-capacity cols + a
@@ -891,20 +784,7 @@ class DeviceChecker:
         key = ("rehash", self.TCAP)
         if key in self._jits:
             return self._jits[key]
-        K, TCAP = self.K, self.TCAP
-
-        @spans.staged("rehash")
-        def ptt_rehash(*old):
-            new, failed = fpset.rehash_cols(
-                old, fpset.empty_cols(2 * TCAP, K)
-            )
-            return (*new, failed)
-
-        # no donation: the inputs are half the output shape, so XLA
-        # could never reuse them (donating only produces warnings)
-        fn = jax.jit(ptt_rehash)
-        self._jits[key] = fn
-        return fn
+        return self._program(key, bodies.ptt_rehash)
 
     # invariant-evaluation chunk for the append: bounds the unpacked-
     # state / invariant intermediates (all proportional to SL lanes; a
@@ -929,18 +809,14 @@ class DeviceChecker:
         compacted matrix aliases its memory and is recycled as the
         next fill's accumulator buffer, so the split adds only the idx
         plane per in-flight flush — not a second W x ACAP store."""
-        key = ("compact",)
+        key = ("compact", compact_ops.materialization())
         if key in self._jits:
             return self._jits[key]
-
-        def ptt_compact(arows, flag_acc):
-            # the row-matrix compaction body lives in ops/compact.py
-            # since r13 (shared with the fused level megakernel)
-            return _compact_rows(arows, flag_acc)
-
-        fn = jax.jit(ptt_compact, donate_argnums=(0,))
-        self._jits[key] = fn
-        return fn
+        # the row-matrix compaction body lives in ops/compact.py
+        # since r13 (shared with the fused level megakernel)
+        return self._program(
+            key, bodies.ptt_compact, materialize=key[-1]
+        )
 
     def _append_jit(self):
         """Land the flush's new states (already compacted to the front
@@ -969,111 +845,10 @@ class DeviceChecker:
         key = ("append", self.LCAP, self.PCAP)
         if key in self._jits:
             return self._jits[key]
-
-        def ptt_append(rows_store, parent_log, lane_log, crows, idx,
-                       n_new, n_visited, viol, acc_base, is_init,
-                       row_base, rows_ok, log_base):
-            return self._append_body(
-                rows_store, parent_log, lane_log, crows, idx, n_new,
-                n_visited, viol, acc_base, is_init, row_base, rows_ok,
-                log_base,
-            )
-
-        fn = jax.jit(ptt_append, donate_argnums=(0, 1, 2))
-        self._jits[key] = fn
-        return fn
-
-    @spans.staged("append")
-    def _append_body(self, rows_store, parent_log, lane_log, crows,
-                     idx, n_new, n_visited, viol, acc_base, is_init,
-                     row_base, rows_ok, log_base=jnp.int32(0)):
-        """Traced append sub-function (shared by ``_append_jit`` and
-        the fused level megakernel) — see :meth:`_append_jit` for the
-        full contract."""
-        A, W, ACAP = self.A, self.W, self.ACAP
-        SL, C = self.SLc, self.C
-        LCAP = self.LCAP
-        layout = self.layout
-        inv_fns = [self.model.invariants[n] for n in self.invariant_names]
-        n_inv = len(self.invariant_names)
-        ccols = tuple(crows[j] for j in range(W))
-        lanei = jnp.arange(ACAP, dtype=jnp.int32)
-        live = lanei < n_new
-        par = jnp.where(
-            is_init, -1 - (acc_base + idx), acc_base + idx // A
-        )
-        lane = jnp.where(is_init, 0, idx % A)
-        par = jnp.where(live, par, 0)
-        lane = jnp.where(live, lane, 0)
-        # pad so the chunks can never clamp mid-window
-        pad = C * SL - ACAP
-        ecols = (
-            tuple(
-                jnp.concatenate(
-                    [c, jnp.zeros((pad,), jnp.uint32)]
-                )
-                for c in ccols
-            )
-            if pad
-            else ccols
-        )
-        woff = jnp.where(
-            rows_ok, n_visited - row_base, jnp.int32(LCAP - C * SL)
-        )
-
-        # the SL-chunked loop does BOTH invariant evaluation and
-        # the row-store append: each chunk interleaves its [SL, W]
-        # rows (needed for the unpack anyway) and lands them with a
-        # blind DUS at [woff + off, ...).  Writing the store
-        # chunk-wise keeps every intermediate SL-sized — a
-        # monolithic [ACAP, W] stack takes the 128-padded T(8,128)
-        # tiled layout on TPU (6.4x memory = 9.1 GB at the ff=2
-        # bench tier; it OOMed the XLA memory planner).  The run
-        # loop guarantees ``woff + APAD <= LCAP`` before
-        # dispatching, so no DUS can clamp.
-        def chunk(c, carry):
-            viol, store = carry
-            off = c * SL
-            rows = jnp.stack(
-                [
-                    lax.dynamic_slice(col, (off,), (SL,))
-                    for col in ecols
-                ],
-                axis=1,
-            )
-            if n_inv:
-                gids = n_visited + off + jnp.arange(
-                    SL, dtype=jnp.int32
-                )
-                livec = (
-                    off + jnp.arange(SL, dtype=jnp.int32) < n_new
-                )
-                states = jax.vmap(layout.unpack)(rows)
-                vnew = []
-                for fn in inv_fns:
-                    ok = jax.vmap(fn)(states)
-                    bad = livec & ~ok
-                    vnew.append(jnp.min(jnp.where(bad, gids, BIG)))
-                viol = jnp.minimum(viol, jnp.stack(vnew))
-            store = lax.dynamic_update_slice(
-                store, rows.reshape(SL * W),
-                ((woff + off) * W,),
-            )
-            return (viol, store)
-
-        n_chunks = jnp.minimum((n_new + SL - 1) // SL, C)
-        viol, rows_store = lax.fori_loop(
-            0, n_chunks, chunk, (viol, rows_store)
-        )
-        parent_log = lax.dynamic_update_slice(
-            parent_log, par, (n_visited - log_base,)
-        )
-        lane_log = lax.dynamic_update_slice(
-            lane_log, lane, (n_visited - log_base,)
-        )
-        return (
-            rows_store, parent_log, lane_log, n_visited + n_new,
-            viol,
+        return self._program(
+            key, bodies.ptt_append, model=self.model,
+            invariant_names=self.invariant_names, SL=self.SLc,
+            C=self.C, LCAP=self.LCAP,
         )
 
     # ------------------------------------------- fused level megakernel
@@ -1088,9 +863,9 @@ class DeviceChecker:
         groups — and, on the ramp, whole level boundaries — of the BFS
         inside a ``lax.while_loop``, chaining the identical traced
         sub-functions the stage chain dispatches separately
-        (``_expand_body`` -> ``ops.fpset.flush_acc`` ->
-        ``ops.compact.compact_rows`` -> ``_append_body``) with every
-        buffer donated end-to-end.
+        (expand -> ``ops.fpset.flush_acc`` ->
+        ``ops.compact.compact_rows`` -> append) with every buffer
+        donated end-to-end.  The program itself is ``bodies.ptt_level``.
 
         Operands: ``(vk, ak, arows, rows, parent, lane, n_visited,
         dead_gid, viol, fpm, wkm, level_base, nf, w_off, levels_left,
@@ -1130,163 +905,22 @@ class DeviceChecker:
         key = (
             "fused", self.TCAP, self.LCAP, self.PCAP,
             self.fps_dense, self.fps_stages, self.RMAX,
+            # read when the program is built (it was read when the
+            # program traced): part of the program's key
+            compact_ops.materialization(),
         )
         if key in self._jits:
             return self._jits[key]
-        K, W, A, G = self.K, self.W, self.A, self.G
-        NCs, ACAP, APAD, FLUSH = self.NCs, self.ACAP, self.APAD, self.FLUSH
-        VCAP, LCAP, PCAP, SCAP = self.VCAP, self.LCAP, self.PCAP, self.SCAP
-        RMAX = self.RMAX
-        frontier_mode = self.rows_window == "frontier"
-        ramp_t = jnp.int32(G)  # new-level batch threshold: one window
-        # write-capacity limits, trace-time constants per tier: the
-        # append's blind APAD window and the ACAP-wide log DUS must
-        # never clamp (reads are clamp-safe — masked by n_live)
-        plimit = jnp.int32(PCAP - APAD)
-        llimit = None if frontier_mode else jnp.int32(LCAP - APAD)
-
-        # the whole kernel traces under ptt.levelctl; the four stages
-        # nest inside it, and an operation belongs to its innermost
-        # scope — so the loop's own control flow, the boundary
-        # bookkeeping and the stats vector are what levelctl keeps
-        @spans.staged("levelctl")
-        def ptt_level(*args):
-            vk = args[:K]
-            ak = args[K: 2 * K]
-            (arows, rows, parent, lane, n_visited, dead, viol, fpm,
-             wkm, level_base, nf, w_off, levels_left, groups_left,
-             row_base, rows_ok) = args[2 * K:]
-
-            def viol_found(viol, dead):
-                return jnp.any(viol < BIG) | (dead < BIG)
-
-            def cond(st):
-                (vk, ak, arows, rows, parent, lane, nv, dead, viol,
-                 fpm, wkm, lb, nf, w_off, lv_left, g_left, rows_ok,
-                 lsizes, n_lv) = st
-                live = nf - w_off  # frontier rows not yet expanded
-                gnew = jnp.where(
-                    live > ACAP // A, jnp.int32(ACAP),
-                    live * A,
-                )
-                fits = (
-                    (nv + gnew <= VCAP)
-                    & (nv <= plimit)
-                    & (nv < SCAP)
-                )
-                if llimit is not None:
-                    fits = fits & (nv <= llimit)
-                mid = (w_off > 0) & (w_off < nf)
-                fresh = (
-                    (w_off == 0)
-                    & (nf > 0)
-                    & (lv_left > 0)
-                    & ~viol_found(viol, dead)
-                    # ramp early-exit: only the dispatch's FIRST level
-                    # may exceed one expand window
-                    & ((n_lv == 0) | (nf <= ramp_t))
-                )
-                return (g_left > 0) & fits & (mid | fresh)
-
-            def body(st):
-                (vk, ak, arows, rows, parent, lane, nv, dead, viol,
-                 fpm, wkm, lb, nf, w_off, lv_left, g_left, rows_ok,
-                 lsizes, n_lv) = st
-                # expand FLUSH windows into the accumulator (windows
-                # past the frontier end produce SENTINEL lanes — the
-                # same masking the stage chain's partial fills rely on)
-                for w in range(FLUSH):
-                    f_off = w_off + jnp.int32(w * G)
-                    with spans.stage("expand"):
-                        window = lax.dynamic_slice(
-                            rows, ((lb - row_base + f_off) * W,),
-                            (G * W,),
-                        )
-                    ak, arows, dead = self._expand_body(
-                        ak, arows, window, f_off, nf, dead, lb,
-                        jnp.int32(w * NCs),
-                    )
-                vk, n_new, flag, fpm = _probe_flush_acc(
-                    vk, ak, jnp.int32(ACAP), fpm,
-                    dense_rounds=self.fps_dense,
-                    stages=self.fps_stages,
-                )
-                crows, idx = _compact_rows(arows, flag)
-                if frontier_mode:
-                    # per-group actual-occupancy check — exactly the
-                    # predicate the stage loop evaluates at its forced
-                    # pre-overflow fetch (monotone: once lost, lost)
-                    rows_ok = rows_ok & (
-                        nv - row_base + APAD <= LCAP
-                    )
-                rows, parent, lane, nv2, viol = self._append_body(
-                    rows, parent, lane, crows, idx, n_new, nv, viol,
-                    lb + w_off, jnp.bool_(False), row_base, rows_ok,
-                )
-                arows = crows  # recycled as the next group's buffer
-                # in-kernel work units (r14): the group's LIVE frontier
-                # rows (level totals then equal the stage chain's
-                # per-dispatch sums exactly), the full accumulator
-                # width presented to flush + compact (their dense cost
-                # driver), the deduped rows appended, and this
-                # iteration — all riding the stats vector below
-                wkm = fpset.wkm_update(
-                    wkm,
-                    jnp.clip(nf - w_off, 0, FLUSH * G),
-                    jnp.int32(ACAP), jnp.int32(ACAP),
-                    n_new, jnp.int32(1),
-                )
-                w_off2 = w_off + jnp.int32(FLUSH * G)
-                g_left = g_left - 1
-                # level boundary?
-                done = w_off2 >= nf
-                size = nv2 - (lb + nf)
-                lsizes = jnp.where(
-                    done,
-                    lsizes.at[jnp.minimum(n_lv, RMAX - 1)].set(size),
-                    lsizes,
-                )
-                di = done.astype(jnp.int32)
-                n_lv = n_lv + di
-                lv_left = lv_left - di
-                lb = jnp.where(done, lb + nf, lb)
-                nf = jnp.where(done, size, nf)
-                w_off = jnp.where(done, jnp.int32(0), w_off2)
-                return (
-                    vk, ak, arows, rows, parent, lane, nv2, dead,
-                    viol, fpm, wkm, lb, nf, w_off, lv_left, g_left,
-                    rows_ok, lsizes, n_lv,
-                )
-
-            st = (
-                tuple(vk), tuple(ak), arows, rows, parent, lane,
-                n_visited, dead, viol, fpm, wkm, level_base, nf, w_off,
-                levels_left, groups_left, rows_ok,
-                jnp.zeros((RMAX,), jnp.int32), jnp.int32(0),
-            )
-            (vk, ak, arows, rows, parent, lane, nv, dead, viol, fpm,
-             wkm, lb, nf, w_off, lv_left, g_left, rows_ok, lsizes,
-             n_lv) = lax.while_loop(cond, body, st)
-            statsvec = jnp.concatenate(
-                [
-                    jnp.stack([nv, dead]), viol, fpm, wkm,
-                    jnp.stack(
-                        [
-                            lb, nf, w_off, n_lv,
-                            rows_ok.astype(jnp.int32), g_left,
-                        ]
-                    ),
-                    lsizes,
-                ]
-            )
-            return (
-                *vk, *ak, arows, rows, parent, lane, nv, dead, viol,
-                fpm, wkm, statsvec,
-            )
-
-        fn = jax.jit(ptt_level, donate_argnums=tuple(range(2 * K + 4)))
-        self._jits[key] = fn
-        return fn
+        return self._program(
+            key, bodies.ptt_level, model=self.model, keys=self.keys,
+            invariant_names=self.invariant_names, Fi=self.Fi, G=self.G,
+            FLUSH=self.FLUSH, check_deadlock=self.check_deadlock,
+            dense_rounds=self.fps_dense, stages=self.fps_stages,
+            materialize=key[-1], SL=self.SLc, C=self.C, VCAP=self.VCAP,
+            LCAP=self.LCAP, PCAP=self.PCAP, SCAP=self.SCAP,
+            RMAX=self.RMAX,
+            frontier_mode=self.rows_window == "frontier",
+        )
 
     def _shift_jit(self):
         """Frontier-window mode: slide the new frontier's rows to
@@ -1772,7 +1406,7 @@ class DeviceChecker:
         # (_ensure_hot_capacity).
         grew = False
         while self.VCAP < need and self.VCAP < cap:
-            out = self._rehash_jit()(*bufs["vk"])
+            out = self._rehash_jit()(bufs["vk"])
             bufs["vk"], failed = out[: self.K], out[self.K]
             if int(np.asarray(failed)):
                 raise RuntimeError(
@@ -1933,7 +1567,7 @@ class DeviceChecker:
             # Fused mode never dispatches the standalone flush
             # mid-run (the megakernel owns it — the triple walk
             # below covers its tiers), so only rehash compiles here
-            out = self._rehash_jit()(*fpset.empty_cols(self.TCAP, K))
+            out = self._rehash_jit()(fpset.empty_cols(self.TCAP, K))
             drain(out)
             del out
             self.TCAP *= 2
@@ -1945,7 +1579,7 @@ class DeviceChecker:
                 for _ in range(K)
             )
             out = self._fpflush_jit()(
-                *fpset.empty_cols(self.TCAP, K), *ak,
+                fpset.empty_cols(self.TCAP, K), ak,
                 jnp.int32(0), z((FPM_N,), jnp.int32),
             )
             drain(out)
@@ -2026,7 +1660,7 @@ class DeviceChecker:
                     for _ in range(K)
                 )
                 out = self._fpflush_jit()(
-                    *fpset.empty_cols(self.TCAP, K), *ak,
+                    fpset.empty_cols(self.TCAP, K), ak,
                     jnp.int32(0), z((FPM_N,), jnp.int32),
                 )
                 drain(out)
@@ -2056,8 +1690,8 @@ class DeviceChecker:
         z = jnp.zeros
         K = self.K
         return self._fused_jit()(
-            *fpset.empty_cols(self.TCAP, K),
-            *tuple(
+            fpset.empty_cols(self.TCAP, K),
+            tuple(
                 jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
                 for _ in range(K)
             ),
@@ -2109,7 +1743,7 @@ class DeviceChecker:
             )
 
         ak, arows = acc()
-        out = self._init_jit()(*ak, arows, jnp.int32(0), jnp.int32(0))
+        out = self._init_jit()(ak, arows, jnp.int32(0), jnp.int32(0))
         drain(out)
         mark("init")
         ak, arows = out[:K], out[K]
@@ -2128,7 +1762,7 @@ class DeviceChecker:
             # the standalone expand program is a stage-chain dispatch;
             # fused mode compiles the expand body inside the megakernel
             out = self._expand_jit()(
-                *ak, arows, window, jnp.int32(0), jnp.int32(0), BIG,
+                ak, arows, window, jnp.int32(0), jnp.int32(0), BIG,
                 jnp.int32(0), jnp.int32(0),
             )
             drain(out)
@@ -2137,7 +1771,7 @@ class DeviceChecker:
             del window
         tc = fpset.empty_cols(self.TCAP, K)
         fpm0 = jnp.zeros((FPM_N,), jnp.int32)
-        out = self._fpflush_jit()(*tc, *ak, jnp.int32(0), fpm0)
+        out = self._fpflush_jit()(tc, ak, jnp.int32(0), fpm0)
         drain(out)
         mark("flush")
         del tc
@@ -2646,7 +2280,7 @@ class DeviceChecker:
                 )
                 with self._clock.phase("dispatch", level=1):
                     out = self._init_jit()(
-                        *bufs["ak"], bufs["arows"], jnp.int32(f_off),
+                        bufs["ak"], bufs["arows"], jnp.int32(f_off),
                         jnp.int32(w * self.NCs),
                     )
                 bufs["ak"], bufs["arows"] = out[:K], out[K]
@@ -2819,7 +2453,7 @@ class DeviceChecker:
         out = self._stage_mark(
             "flush",
             self._fpflush_jit()(
-                *bufs["vk"], *bufs["ak"], jnp.int32(n_acc),
+                bufs["vk"], bufs["ak"], jnp.int32(n_acc),
                 st["fpm"],
             ),
         )
@@ -3419,7 +3053,7 @@ class DeviceChecker:
                         out = self._stage_mark(
                             "expand",
                             self._expand_jit()(
-                                *bufs["ak"], bufs["arows"],
+                                bufs["ak"], bufs["arows"],
                                 self._slice_jit()(
                                     bufs["rows"],
                                     jnp.int32(
@@ -3738,7 +3372,7 @@ class DeviceChecker:
                     out = self._stage_mark(
                         "fused",
                         self._fused_jit()(
-                            *bufs["vk"], *bufs["ak"], bufs["arows"],
+                            bufs["vk"], bufs["ak"], bufs["arows"],
                             bufs["rows"], bufs["parent"], bufs["lane"],
                             st["n_visited"], st["dead_gid"],
                             st["viol"], st["fpm"], st["wkm"],

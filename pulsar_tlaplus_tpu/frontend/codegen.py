@@ -38,6 +38,7 @@ Init/Next (lines 188-231) and invariants (236-294) under
 
 from __future__ import annotations
 
+import hashlib
 import itertools
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -2434,7 +2435,25 @@ class CompiledSpec:
         )
         self.requested_invariants = tuple(invariants)
         self.default_invariants = tuple(invariants) + ("__EvalError__",)
+        # value identity (a model is a static argument of the traced
+        # units of engine/bodies.py): everything the kernels read is a
+        # function of the module as parsed, the constants binding and
+        # the invariants asked for
+        self._identity = (
+            hashlib.sha256(repr(spec.module).encode()).hexdigest(),
+            tuple(sorted((k, repr(v)) for k, v in spec.constants.items())),
+            self.requested_invariants,
+        )
         self._check_compiles()
+
+    def __eq__(self, other):
+        return (
+            type(other) is type(self)
+            and other._identity == self._identity
+        )
+
+    def __hash__(self):
+        return hash(self._identity)
 
     # -- model protocol ------------------------------------------------
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.models import ByConstants
 from pulsar_tlaplus_tpu.ops.packing import StructLayout, bitlen
 
 
@@ -72,7 +73,7 @@ DEFAULT_INVARIANTS = (
 )
 
 
-class BookkeeperModel:
+class BookkeeperModel(ByConstants):
     """Compiled ``bookkeeper`` spec for a fixed constants binding."""
 
     def __init__(self, c: BookkeeperConstants):

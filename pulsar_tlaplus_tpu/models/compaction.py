@@ -26,13 +26,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.models import ByConstants
 from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.ops.packing import Layout, SState
 from pulsar_tlaplus_tpu.ref import pyeval
 from pulsar_tlaplus_tpu.ref.pyeval import Constants
 
 
-class CompactionModel:
+class CompactionModel(ByConstants):
     """Compiled ``compaction`` spec for a fixed ``Constants`` binding."""
 
     def __init__(self, c: Constants):

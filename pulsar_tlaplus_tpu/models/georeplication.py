@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.models import ByConstants
 from pulsar_tlaplus_tpu.ops.packing import StructLayout, bitlen
 
 
@@ -61,7 +62,7 @@ ACTION_NAMES = (
 DEFAULT_INVARIANTS = ("TypeOK", "CursorWithinWatermark", "NoPhantomMessages")
 
 
-class GeoreplicationModel:
+class GeoreplicationModel(ByConstants):
     """Compiled ``georeplication`` spec for a fixed constants binding."""
 
     def __init__(self, c: GeoConstants):

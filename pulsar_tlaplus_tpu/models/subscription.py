@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.models import ByConstants
 from pulsar_tlaplus_tpu.ops.packing import StructLayout, bitlen
 from typing import NamedTuple
 
@@ -68,7 +69,7 @@ ACTION_NAMES = (
 DEFAULT_INVARIANTS = ("TypeOK", "NoLostMessage", "AckedWasProcessed")
 
 
-class SubscriptionModel:
+class SubscriptionModel(ByConstants):
     """Compiled ``subscription`` spec for a fixed constants binding."""
 
     def __init__(self, c: SubscriptionConstants):

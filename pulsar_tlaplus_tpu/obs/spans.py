@@ -31,6 +31,9 @@ variable, exporter or telemetry event of their own:
   of the phases, not one of them: a first call of a jitted function
   spends its trace/lower/compile seconds inside whichever phase made
   the call (``dispatch``, mostly).
+  The meter also carries a counter the engine's program units report
+  (``engine/units.py``): ``jit_body_traces``, the units whose Python
+  body ran (misses).
 
 This module is the only place of the package that constructs a
 ``TraceAnnotation`` or registers a ``jax.monitoring`` listener.
@@ -215,6 +218,7 @@ _DURATIONS = {
 _COUNTERS = (
     "traces", "trace_s", "lowerings", "lower_s", "compile_requests",
     "compile_request_s", "cache_hits", "cache_misses", "cache_load_s",
+    "body_traces",
 )
 
 
@@ -251,6 +255,12 @@ class CompileMeter:
         elif event == _CACHE_MISS:
             self._mine()["cache_misses"] += 1
 
+    def count(self, counter: str) -> None:
+        """One more of ``counter`` on the calling thread (the program
+        units of ``engine/units.py`` report the runs of their
+        bodies)."""
+        self._mine()[counter] += 1
+
     def snapshot(self) -> Dict[str, float]:
         """This thread's counters so far."""
         return dict(self._mine())
@@ -279,6 +289,7 @@ class CompileMeter:
             "jit_host_s": (
                 d["trace_s"] + d["lower_s"] + compile_s + d["cache_load_s"]
             ),
+            "jit_body_traces": int(d["body_traces"]),
         }
 
 

@@ -58,8 +58,12 @@ import jax
 import jax.numpy as jnp
 
 
-def _materialization() -> str:
-    """Trace-time materialization choice (see module docstring)."""
+def materialization() -> str:
+    """The materialization this process compacts with (see module
+    docstring): the default of the compactions below, read at trace
+    time.  A program that outlives its trace (``engine/bodies.py``)
+    reads it when it is dispatched and passes it as a static argument
+    instead, so that the environment is part of the program's key."""
     env = os.environ.get("PTT_COMPACT_MATERIALIZE")
     if env in ("shift", "gather"):
         return env
@@ -127,11 +131,12 @@ def _gather_compact(drop, vals):
 
 
 def compact_by_flag(
-    drop: jax.Array, cols, need_idx: bool = True
+    drop: jax.Array, cols, need_idx: bool = True,
+    materialize: Optional[str] = None,
 ) -> Tuple[tuple, Optional[jax.Array]]:
     """Sort-free stable compaction of ``cols`` to the front where
-    ``drop == 0`` (module docstring; materialization is
-    backend-adaptive at trace time).
+    ``drop == 0`` (module docstring; ``materialize`` is ``"shift"`` or
+    ``"gather"``, by default the process's, :func:`materialization`).
 
     The kept prefix is in original order; positions past the kept
     count are don't-care.
@@ -141,7 +146,7 @@ def compact_by_flag(
     """
     n = drop.shape[0]
     vals = list(cols)
-    if _materialization() == "gather":
+    if (materialize or materialization()) == "gather":
         # the search's src vector IS the original-index map — idx
         # rides for free, no extra column travels
         out, src = _gather_compact(drop, vals)
@@ -157,7 +162,8 @@ def compact_by_flag(
 
 
 def compact_rows(
-    arows: jax.Array, flag_keep: jax.Array
+    arows: jax.Array, flag_keep: jax.Array,
+    materialize: Optional[str] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Compact a word-major ``[W, N]`` packed-row matrix to the front
     where ``flag_keep`` (uint32 0/1) is set, preserving original order
@@ -167,5 +173,5 @@ def compact_rows(
     ``idx[j]`` is the original lane of compacted position ``j``."""
     drop = flag_keep ^ jnp.uint32(1)
     cols = tuple(arows[j] for j in range(arows.shape[0]))
-    ccols, idx = compact_by_flag(drop, cols)
+    ccols, idx = compact_by_flag(drop, cols, materialize=materialize)
     return jnp.stack(ccols), idx

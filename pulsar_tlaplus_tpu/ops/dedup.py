@@ -112,6 +112,18 @@ class KeySpec:
         self.total_bits = total_bits
         self.W = W
 
+    # value identity: a key spec is a static argument of the traced
+    # units (engine/bodies.py), so two checkers of one layout must
+    # present equal specs
+    def _key(self):
+        return (self.ncols, self.exact, self.total_bits, self.W)
+
+    def __eq__(self, other):
+        return type(other) is KeySpec and other._key() == self._key()
+
+    def __hash__(self):
+        return hash(self._key())
+
     def make(self, packed: jax.Array) -> Tuple[jax.Array, ...]:
         """packed u32[N, W] -> ``ncols`` x u32[N] key columns."""
         n, w = packed.shape

@@ -480,9 +480,11 @@ def lookup_or_insert(
     max_probes: int = MAX_PROBES,
     dense_rounds: Optional[int] = None,
     stages=None,
+    materialize: Optional[str] = None,
 ):
     """Engine hot path: staged batched lookup-or-insert (see module
-    docstring for the why of the stages).
+    docstring for the why of the stages; ``materialize`` is the
+    ladder's compactions', ``ops.compact.compact_by_flag``).
 
     Returns ``(is_new, tcols', n_failed, rounds, lane_rounds)`` where
     ``is_new`` is in ORIGINAL lane order (exactly one True per distinct
@@ -525,7 +527,7 @@ def lookup_or_insert(
             drop = (~cur_pending).astype(jnp.uint32)
             ccols, _ = compact_ops.compact_by_flag(
                 drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
-                need_idx=False,
+                need_idx=False, materialize=materialize,
             )
             npend = jnp.sum(cur_pending.astype(jnp.int32))
             n_failed = n_failed + jnp.maximum(npend - capi, 0)
@@ -566,6 +568,7 @@ def flush_acc(
     fpm: jax.Array,
     dense_rounds: Optional[int] = None,
     stages=None,
+    materialize: Optional[str] = None,
 ):
     """One accumulator flush as a traced sub-function (round 13): mask
     the live prefix, probe-or-insert, count the new states, and ride
@@ -587,7 +590,7 @@ def flush_acc(
     valid = amask & ~all_sentinel(kcols)
     is_new, tcols2, n_failed, rounds, lane_rounds = lookup_or_insert(
         tcols, kcols, valid,
-        dense_rounds=dense_rounds, stages=stages,
+        dense_rounds=dense_rounds, stages=stages, materialize=materialize,
     )
     n_new = jnp.sum(is_new.astype(jnp.int32))
     fpm2 = fpm_update(

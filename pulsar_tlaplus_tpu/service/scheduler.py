@@ -166,6 +166,15 @@ class CheckerPool:
     (re)assigned per scheduling slice, and ``run()`` rebuilds device
     buffers from scratch (or from the job's frame on resume) — the
     pooled object carries only compiled programs and tier sizes.
+
+    Since PR 33 the engine's seven big programs (``engine/bodies.py``)
+    live in ``jax.jit``'s own process-wide cache, keyed by what they
+    read, so a pool MISS on a binding this process has met (another
+    ``max_states``, a solo run before it) finds them built.  The pool
+    still holds what that cache does not: the checker's tier sizes and
+    tuned profile, its per-instance programs (stats, slice, shift, seed,
+    trace walk) and the prewarm of its growth tiers; a pool HIT costs
+    the same as before (PERF.md §6, PR 33).
     """
 
     def __init__(self, config: ServiceConfig):

@@ -461,7 +461,7 @@ def cmd_stages(args):
     def do_expand():
         nonlocal ak, arows
         out = ck._expand_jit()(
-            *ak, arows, window, jnp.int32(0), jnp.int32(ck.G), BIG,
+            ak, arows, window, jnp.int32(0), jnp.int32(ck.G), BIG,
             jnp.int32(0), jnp.int32(0),
         )
         ak, arows = out[:K], out[K]
@@ -471,13 +471,13 @@ def cmd_stages(args):
 
     def do_flush():
         nonlocal vk, fpm
-        out = ck._fpflush_jit()(*vk, *ak, jnp.int32(ck.ACAP), fpm)
+        out = ck._fpflush_jit()(vk, ak, jnp.int32(ck.ACAP), fpm)
         vk, fpm = out[:K], out[K + 2]
         return out[K]
 
     t_flush = bench("flush (fpset probe-or-insert)", do_flush)
 
-    out = ck._fpflush_jit()(*vk, *ak, jnp.int32(ck.ACAP), fpm)
+    out = ck._fpflush_jit()(vk, ak, jnp.int32(ck.ACAP), fpm)
     vk, n_new, flag, fpm = out[:K], out[K], out[K + 1], out[K + 2]
     barrier(n_new)
     print(f"  (n_new in flush probe: {int(np.asarray(n_new))})",
@@ -545,7 +545,7 @@ def cmd_stages(args):
 
     def do_fused():
         out = ck2._fused_jit()(
-            *fstate["vk"], *fstate["ak"], fstate["arows"],
+            fstate["vk"], fstate["ak"], fstate["arows"],
             fstate["rows"], fstate["parent"], fstate["lane"],
             fstate["nv"], BIG, viol0, fstate["fpm"],
             jnp.int32(0), jnp.int32(ck2.G), jnp.int32(0),

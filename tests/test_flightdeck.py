@@ -113,7 +113,7 @@ def service_run(tmp_path_factory, pool):
     state = tmp_path_factory.mktemp("fd-two-job")
     (state / "small_compaction.cfg").write_text(SMALL_COMPACTION_CFG)
     config = ServiceConfig(
-        state_dir=str(state / "state"), slice_s=0.3, **GEOM
+        state_dir=str(state / "state"), slice_s=0.02, **GEOM
     )
     svc_stream = str(state / "service.jsonl")
     tel = Telemetry(svc_stream)

@@ -268,7 +268,7 @@ def test_daemon_timeslices_fused_jobs_with_solo_parity(tmp_path):
     )
     config = ServiceConfig(
         state_dir=str(tmp_path / "state"),
-        slice_s=0.2,
+        slice_s=0.02,
         sub_batch=64,
         visited_cap=1 << 10,
         frontier_cap=1 << 8,

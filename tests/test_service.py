@@ -195,7 +195,7 @@ def two_job_run(tmp_path_factory, pool, cfg_dir):
     from pulsar_tlaplus_tpu.obs.telemetry import Telemetry
 
     state = tmp_path_factory.mktemp("two-job")
-    config = _config(state / "state", slice_s=0.3)
+    config = _config(state / "state", slice_s=0.02)
     svc_stream = str(state / "service.jsonl")
     tel = Telemetry(svc_stream)
     sched = Scheduler(config, pool=pool, telemetry=tel)
@@ -701,7 +701,7 @@ def test_bench_cleans_stale_telemetry_streams(tmp_path):
 def test_load_many_jobs_mixed_specs(tmp_path, pool, cfg_dir):
     """>= 2-job load: six queued jobs across three bindings time-slice
     one device; every result equals its solo baseline."""
-    config = _config(tmp_path / "state", slice_s=0.2)
+    config = _config(tmp_path / "state", slice_s=0.02)
     sched = Scheduler(config, pool=pool)
     jobs = []
     for i in range(2):

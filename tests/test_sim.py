@@ -371,7 +371,7 @@ def test_daemon_two_job_slice_with_sim_solo_parity(
     with open(cfg, "w") as f:
         f.write(SMALL_COMPACTION_CFG)
     config = ServiceConfig(
-        state_dir=str(tmp_path / "state"), slice_s=0.2, sub_batch=64,
+        state_dir=str(tmp_path / "state"), slice_s=0.02, sub_batch=64,
         visited_cap=1 << 10, frontier_cap=1 << 8, max_states=1 << 20,
         prewarm_tiers=False, checkpoint_every=1,
     )

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from pulsar_tlaplus_tpu.engine import device_bfs
+from pulsar_tlaplus_tpu.engine import bodies, device_bfs
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.obs import spans
@@ -198,9 +198,18 @@ def test_counterexample_is_the_parents(fuse):
 # ---- (c) the scopes are in what is compiled ---------------------------
 
 
-def _lowered_texts(monkeypatch, fuse):
-    """``{jitted function name: lowered text}`` of every engine jit one
-    run calls, re-lowered at the shapes it was called with."""
+def _struct(args):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a)),
+        args,
+    )
+
+
+def _recorded_jits(monkeypatch, fuse):
+    """``{program name: lower()}`` of every engine program one run
+    calls, to be re-lowered at the shapes it was called with: the jits
+    a checker builds for itself (``device_bfs``'s ``jax.jit``) and the
+    program units of ``engine/bodies.py`` it dispatches."""
     real = jax.jit
     seen = {}
 
@@ -208,19 +217,37 @@ def _lowered_texts(monkeypatch, fuse):
         j = real(fn, **kw)
 
         def call(*args):
-            seen.setdefault(fn.__name__, (j, jax.tree.map(
-                lambda a: jax.ShapeDtypeStruct(
-                    jnp.shape(a), jnp.result_type(a)), args)))
+            shapes = _struct(args)
+            seen.setdefault(fn.__name__, lambda: j.lower(*shapes))
             return j(*args)
 
         return call
 
+    def recording_unit(name, unit):
+        def call(*args, **statics):
+            shapes = _struct(args)
+            seen.setdefault(
+                name, lambda: unit.lower(*shapes, **statics)
+            )
+            return unit(*args, **statics)
+
+        return call
+
     monkeypatch.setattr(device_bfs.jax, "jit", recording_jit)
+    for name, unit in vars(bodies).items():
+        if name.startswith("ptt_"):
+            monkeypatch.setattr(bodies, name, recording_unit(name, unit))
     _mk(SMALL_CONFIGS["producer_on"], fuse=fuse).run()
     monkeypatch.undo()
+    return seen
+
+
+def _lowered_texts(monkeypatch, fuse):
+    """``{program name: lowered text}`` of every engine program one
+    run calls, re-lowered at the shapes it was called with."""
     return {
-        name: j.lower(*shapes).as_text(debug_info=True)
-        for name, (j, shapes) in seen.items()
+        name: lower().as_text(debug_info=True)
+        for name, lower in _recorded_jits(monkeypatch, fuse).items()
     }
 
 
@@ -241,6 +268,63 @@ def test_each_stage_jit_carries_its_scope(monkeypatch):
         ("ptt_init", "ptt.init"), ("ptt_rehash", "ptt.rehash"),
     ):
         assert scope in texts[name], name
+
+
+def test_level_kernel_scopes_are_the_same_on_a_miss_and_on_a_hit(
+    monkeypatch,
+):
+    """The level kernel is a unit (``engine/units.py``): its
+    operations carry the same ``op_name`` paths when its body is
+    traced (a miss), when JAX's cache answers it (a hit), and when the
+    stage chain's programs, which share its bodies, were traced
+    first."""
+
+    def op_names(lower):
+        txt = lower().as_text(debug_info=True)
+        return sorted(re.findall(r'"(jit\(ptt_level\)/[^"]*)"', txt))
+
+    level = _recorded_jits(monkeypatch, "level")["ptt_level"]
+    meter = spans.compile_meter()
+    jax.clear_caches()
+    before = meter.snapshot()
+    miss = op_names(level)
+    assert meter.since(before)["jit_body_traces"] == 1
+    before = meter.snapshot()
+    hit = op_names(level)
+    assert meter.since(before)["jit_body_traces"] == 0
+    jax.clear_caches()
+    for lower in _recorded_jits(monkeypatch, "stage").values():
+        lower()
+    stage_first = op_names(level)
+    assert len(miss) > 100
+    assert any("/ptt.levelctl/while/body/ptt.probe/" in n for n in miss)
+    assert miss == hit == stage_first
+
+
+def test_an_ops_body_carries_the_scope_of_the_site_that_traces_it():
+    """``ops/``'s bodies are plain functions with no scope and no cache
+    of their own: each program that traces one (the sharded engine's,
+    the seed merge, the level kernel) puts it under its own scope, and
+    the code is the same whichever site traced it first."""
+    from pulsar_tlaplus_tpu.ops import compact
+
+    drop = jnp.asarray([0, 1, 0, 1, 1, 0, 0, 1, 1], jnp.uint32)
+    col = jnp.arange(9, dtype=jnp.uint32)
+
+    def site(scope):
+        @jax.jit
+        def program(drop, col):
+            with spans.stage(scope):
+                return compact.compact_by_flag(drop, (col,))[0][0]
+
+        lowered = program.lower(drop, col)
+        return lowered.as_text(debug_info=True), lowered.as_text()
+
+    first, first_code = site("seed")
+    second, second_code = site("route")
+    assert "ptt.seed" in first and "ptt.route" not in first
+    assert "ptt.route" in second and "ptt.seed" not in second
+    assert first_code == second_code
 
 
 # ---- (d) the spans are in a profiler trace ----------------------------
