@@ -323,6 +323,15 @@ def ptt_rehash(old):
     return (*new, failed)
 
 
+@unit("grow", static=("pad",))
+def ptt_grow(buf, *, pad):
+    """Store growth: ``buf`` (the flat row store or a trace log) with
+    ``pad`` zeros after it — one program under the ``ptt.grow`` scope
+    where an eager fill and an eager concatenate ran unnamed.  No
+    donation: the output is larger than the input."""
+    return jnp.concatenate([buf, jnp.zeros((pad,), buf.dtype)])
+
+
 @unit(static=("materialize",), donate=(0,))
 def ptt_compact(arows, flag_acc, *, materialize):
     """The compaction dispatch: ``(crows, idx)``."""

@@ -111,6 +111,41 @@ FPM_N = fpset.FPM_N
 WKM_N = fpset.WKM_N
 
 
+class _Growth:
+    """What one ``run()`` grew, counted on the host at the growth sites
+    (no dispatch, sync or fetch of their own; ``last_stats`` carries
+    them as ``grow_*``, docs/observability.md)."""
+
+    def __init__(self):
+        self.events = 0  # outermost growth calls that moved a capacity
+        self.rehashes = 0  # table doublings
+        self.rehash_slots = 0  # slots of every OLD table rehashed
+        self.copy_bytes = 0  # bytes of the old rows and logs copied
+
+
+def _growth_call(fn):
+    """Method decorator of the growers: the exclusive host phase
+    ``grow`` and, for the outermost of nested growth calls
+    (``_grow_fused`` > ``_grow_store`` > ``_grow_logs``), one
+    ``grow_events`` if any of (TCAP, LCAP, PCAP) moved.  (Not
+    ``spans.in_phase`` under a second wrapper: a grower's first call at
+    a tier traces ``ptt_rehash`` / ``ptt_grow``, and every frame above
+    a traced equation is on its traceback.)"""
+
+    @functools.wraps(fn)
+    def wrapped(self, bufs, need):
+        outermost = not self._clock.open("grow")
+        tiers = (self.TCAP, self.LCAP, self.PCAP)
+        try:
+            with self._clock.phase("grow"):
+                return fn(self, bufs, need)
+        finally:
+            if outermost and (self.TCAP, self.LCAP, self.PCAP) != tiers:
+                self._growth.events += 1
+
+    return wrapped
+
+
 class DeviceChecker:
     """Level-synchronous BFS on one device with no hot-path host syncs.
 
@@ -539,6 +574,7 @@ class DeviceChecker:
         # the host-phase clock of the current run (a fresh one per
         # run(); this one serves growth sites reached outside a run)
         self._clock = spans.PhaseClock()
+        self._growth = _Growth()
         # telemetry (round 8): a path or obs.telemetry.Telemetry; the
         # stream is opened per run() with a fresh run_id, and the
         # heartbeat reports from ``_snap`` — the last fetched stats
@@ -1388,7 +1424,7 @@ class DeviceChecker:
 
     # ------------------------------------------------------------ growth
 
-    @spans.in_phase("grow")
+    @_growth_call
     def _grow_visited(self, bufs, need: int):
         cap = self._capv()
         # clamp at the most any run can use: nv never exceeds SCAP, so
@@ -1413,6 +1449,8 @@ class DeviceChecker:
                     "fpset rehash overflow — table corrupted its "
                     "load-factor contract (bug)"
                 )
+            self._growth.rehashes += 1
+            self._growth.rehash_slots += self.TCAP
             self.TCAP *= 2
             self.VCAP = self.TCAP // 2
             grew = True
@@ -1466,21 +1504,24 @@ class DeviceChecker:
             tcap *= 2
         return tcap
 
-    @spans.in_phase("grow")
+    def _grow_buf(self, buf, pad: int):
+        """``buf`` with ``pad`` zeros after it, as one program under
+        the ``ptt.grow`` scope (``bodies.ptt_grow``); the old buffer's
+        bytes are the copy ``grow_copy_bytes`` counts."""
+        self._growth.copy_bytes += buf.nbytes
+        return bodies.ptt_grow(buf, pad=pad)
+
+    @_growth_call
     def _grow_logs(self, bufs, need: int):
         cap = self._capp()
         target = self._next_cap(self.PCAP, need, cap)
         while self.PCAP < target:
             pad = min(self.PCAP, target - self.PCAP)
-            bufs["parent"] = jnp.concatenate(
-                [bufs["parent"], jnp.zeros((pad,), jnp.int32)]
-            )
-            bufs["lane"] = jnp.concatenate(
-                [bufs["lane"], jnp.zeros((pad,), jnp.int32)]
-            )
+            bufs["parent"] = self._grow_buf(bufs["parent"], pad)
+            bufs["lane"] = self._grow_buf(bufs["lane"], pad)
             self.PCAP += pad
 
-    @spans.in_phase("grow")
+    @_growth_call
     def _grow_store(self, bufs, need: int):
         """Admit ``need`` states in the trace logs and (all-mode only)
         the row store.  Frontier mode's rows window is fixed — row
@@ -1496,11 +1537,10 @@ class DeviceChecker:
         target = self._next_cap(self.LCAP, need, cap)
         while self.LCAP < target:
             pad = min(self.LCAP, target - self.LCAP)
-            bufs["rows"] = jnp.concatenate(
-                [bufs["rows"], jnp.zeros((pad * self.W,), jnp.uint32)]
-            )
+            bufs["rows"] = self._grow_buf(bufs["rows"], pad * self.W)
             self.LCAP += pad
 
+    @_growth_call
     def _grow_fused(self, bufs, need_states: int):
         """Unified growth for the fused path: every fused-mode growth
         site sizes visited + store/logs from ONE need, so the
@@ -1895,6 +1935,7 @@ class DeviceChecker:
         # reading before it (obs/spans.py); every duration of the run
         # is on the monotonic clock the phases use
         clock = self._clock = spans.PhaseClock(obs.new_run_id())
+        self._growth = _Growth()
         self._jit0 = spans.compile_meter().snapshot()
         with spans.span("run", run_id=clock.run_id):
             with clock.phase("init"):
@@ -4170,9 +4211,15 @@ class DeviceChecker:
         # keeps its key and is the fetch phase; jit_* is an orthogonal
         # cut (what JAX traced, lowered, compiled and loaded)
         phases = self._clock.stats()
+        g = self._growth
         self.last_stats.update(
             phases,
             host_wait_s=phases["host_fetch_s"],
+            grow_events=g.events,
+            grow_rehashes=g.rehashes,
+            grow_rehash_slots=g.rehash_slots,
+            grow_copy_bytes=g.copy_bytes,
+            grow_tiers_final=[self.TCAP, self.LCAP, self.PCAP],
             **spans.compile_meter().since(self._jit0),
         )
         # the final stream record carries the whole last_stats dict

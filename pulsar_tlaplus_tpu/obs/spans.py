@@ -8,8 +8,9 @@ variable, exporter or telemetry event of their own:
   ``ptt.probe``, ``ptt.compact``, ``ptt.append`` — plus
   ``ptt.levelctl`` (the level kernel's loop control, boundary
   bookkeeping, the packed stats vector and the frontier-window shift),
-  ``ptt.rehash`` (table growth), ``ptt.seed`` (seed merge/write) and
-  ``ptt.init`` (initial-state generation).  A scope is HLO metadata
+  ``ptt.rehash`` (table growth), ``ptt.grow`` (row-store and log
+  growth), ``ptt.seed`` (seed merge/write) and ``ptt.init``
+  (initial-state generation).  A scope is HLO metadata
   only: it lands in every operation's ``op_name`` path, which a device
   trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
   metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of
@@ -152,6 +153,14 @@ class PhaseClock:
         self.level_wall_max_s = 0.0
         self.level_wall_max_at = 0
         self._boundary_t = self.t0
+        # the longest single stay in the phase ``grow`` (one inside
+        # another is one stay) and the level in whose stretch it fell:
+        # the level of the next boundary record, or one past the last
+        self.grow_wall_max_s = 0.0
+        self.grow_wall_max_at = 0
+        self._grow_t = self.t0  # start of the newest stay
+        self._grow_longest = 0.0  # longest stay since the last boundary
+        self._boundary_level = 0
 
     def phase(self, name: str, **fields) -> _Phase:
         return _Phase(self, name, span(name, run_id=self.run_id, **fields))
@@ -161,6 +170,8 @@ class PhaseClock:
         if self._stack:
             top = self._stack[-1]
             self.seconds[top[0]] = self.seconds.get(top[0], 0.0) + now - top[1]
+        if name == "grow" and not self.open("grow"):
+            self._grow_t = now
         self._stack.append([name, now])
 
     def _pop(self):
@@ -169,6 +180,19 @@ class PhaseClock:
         self.seconds[name] = self.seconds.get(name, 0.0) + now - since
         if self._stack:
             self._stack[-1][1] = now
+        if name == "grow" and not self.open("grow"):
+            self._grow_longest = max(self._grow_longest, now - self._grow_t)
+
+    def open(self, name: str) -> bool:
+        """Whether a phase ``name`` is entered and not yet left (an
+        inner phase pauses it, it stays open)."""
+        return any(p[0] == name for p in self._stack)
+
+    def _settle_grow(self, level: int) -> None:
+        if self._grow_longest > self.grow_wall_max_s:
+            self.grow_wall_max_s = self._grow_longest
+            self.grow_wall_max_at = int(level)
+        self._grow_longest = 0.0
 
     def elapsed(self) -> float:
         return time.perf_counter() - self.t0
@@ -185,16 +209,22 @@ class PhaseClock:
         gap, self._boundary_t = now - self._boundary_t, now
         if gap > self.level_wall_max_s:
             self.level_wall_max_s, self.level_wall_max_at = gap, int(level)
+        self._settle_grow(level)
+        self._boundary_level = int(level)
 
     def stats(self) -> Dict[str, float]:
         """``host_<phase>_s`` for every phase (0.0 for one never
         entered), ``host_unaccounted_s`` = the run's wall so far less
-        their sum, and the longest level stretch."""
+        their sum, the longest level stretch and the longest stay in
+        ``grow``."""
         wall = self.elapsed()
         out = {f"host_{p}_s": self.seconds_of(p) for p in PHASES}
         out["host_unaccounted_s"] = wall - sum(out.values())
         out["level_wall_max_s"] = self.level_wall_max_s
         out["level_wall_max_at"] = self.level_wall_max_at
+        self._settle_grow(self._boundary_level + 1)
+        out["grow_wall_max_s"] = self.grow_wall_max_s
+        out["grow_wall_max_at"] = self.grow_wall_max_at
         return out
 
 

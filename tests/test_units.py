@@ -218,7 +218,7 @@ def test_the_materialization_is_in_the_key(monkeypatch, base_verdict):
 
 UNITS = (
     "ptt_level", "ptt_expand", "ptt_init", "ptt_fpflush", "ptt_rehash",
-    "ptt_compact", "ptt_append",
+    "ptt_compact", "ptt_append", "ptt_grow",
 )
 
 
