@@ -311,16 +311,18 @@ def ptt_fpflush(tc, ak, n_acc, fpm, *, dense_rounds, stages, materialize):
     return (*tc2, n_new, flag, fpm)
 
 
-@unit("rehash")
-def ptt_rehash(old):
+@unit("rehash", static=("materialize",))
+def ptt_rehash(old, *, materialize):
     """Table growth: the old table's columns -> double-capacity
-    columns and a failure count, ``(*new, n_failed)``.  No donation:
-    the inputs are half the output's shape, so XLA could never reuse
-    them (donating only produces warnings)."""
-    new, failed = fpset.rehash_cols(
-        old, fpset.empty_cols(2 * (old[0].shape[0] - 1), len(old))
+    columns and the rehash vector (``fpset.rhm_logical``: failures,
+    keys moved, lanes presented), ``(*new, rhm)``.  No donation: the
+    inputs are half the output's shape, so XLA could never reuse them
+    (donating only produces warnings)."""
+    new, rhm = fpset.rehash_cols(
+        old, fpset.empty_cols(2 * (old[0].shape[0] - 1), len(old)),
+        materialize=materialize,
     )
-    return (*new, failed)
+    return (*new, rhm)
 
 
 @unit("grow", static=("pad",))

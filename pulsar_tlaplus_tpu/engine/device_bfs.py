@@ -120,6 +120,10 @@ class _Growth:
         self.events = 0  # outermost growth calls that moved a capacity
         self.rehashes = 0  # table doublings
         self.rehash_slots = 0  # slots of every OLD table rehashed
+        self.rehash_keys = 0  # occupied ones among them: the keys moved
+        # lanes presented to the NEW tables summed over probe rounds;
+        # over rehash_keys: the rehash's lanes_presented_per_valid
+        self.rehash_lane_rounds = 0
         self.copy_bytes = 0  # bytes of the old rows and logs copied
 
 
@@ -814,13 +818,16 @@ class DeviceChecker:
         )
 
     def _rehash_jit(self):
-        """fpset growth: old table cols -> double-capacity cols + a
-        failure count, fully on device (``fpset.rehash_cols``).  The
-        transient is old+new table."""
-        key = ("rehash", self.TCAP)
+        """fpset growth: old table cols -> double-capacity cols + the
+        rehash vector (failures, keys moved, lanes presented), fully on
+        device (``fpset.rehash_cols``).  The transient is old+new table
+        and a few columns of one chunk."""
+        key = ("rehash", self.TCAP, compact_ops.materialization())
         if key in self._jits:
             return self._jits[key]
-        return self._program(key, bodies.ptt_rehash)
+        return self._program(
+            key, bodies.ptt_rehash, materialize=key[-1]
+        )
 
     # invariant-evaluation chunk for the append: bounds the unpacked-
     # state / invariant intermediates (all proportional to SL lanes; a
@@ -1075,10 +1082,10 @@ class DeviceChecker:
         K, TCAP = self.K, self.TCAP
 
         def step(*old):
-            new, failed = fpset.rehash_cols(
+            new, rhm = fpset.rehash_cols(
                 old, fpset.empty_cols(TCAP, K)
             )
-            return (*new, failed)
+            return (*new, rhm[0])
 
         fn = jax.jit(step)
         self._jits[key] = fn
@@ -1443,14 +1450,19 @@ class DeviceChecker:
         grew = False
         while self.VCAP < need and self.VCAP < cap:
             out = self._rehash_jit()(bufs["vk"])
-            bufs["vk"], failed = out[: self.K], out[self.K]
-            if int(np.asarray(failed)):
+            bufs["vk"] = out[: self.K]
+            # the one host sync of a doubling: the fail-stop count and
+            # the rehash's two counters in the same vector
+            failed, keys, lane_rounds = fpset.rhm_logical(out[self.K])
+            if failed:
                 raise RuntimeError(
                     "fpset rehash overflow — table corrupted its "
                     "load-factor contract (bug)"
                 )
             self._growth.rehashes += 1
             self._growth.rehash_slots += self.TCAP
+            self._growth.rehash_keys += keys
+            self._growth.rehash_lane_rounds += lane_rounds
             self.TCAP *= 2
             self.VCAP = self.TCAP // 2
             grew = True
@@ -4218,6 +4230,8 @@ class DeviceChecker:
             grow_events=g.events,
             grow_rehashes=g.rehashes,
             grow_rehash_slots=g.rehash_slots,
+            grow_rehash_keys=g.rehash_keys,
+            grow_rehash_lane_rounds=g.rehash_lane_rounds,
             grow_copy_bytes=g.copy_bytes,
             grow_tiers_final=[self.TCAP, self.LCAP, self.PCAP],
             **spans.compile_meter().since(self._jit0),

@@ -445,6 +445,9 @@ class ShardedDeviceChecker:
         self._dispatch_n = 0
         self._route_rounds: Dict[int, int] = {}
         self._route_overflows = 0
+        # keys moved and lanes presented by the run's table doublings
+        self._rehash_keys = 0
+        self._rehash_lane_rounds = 0
 
     # -------------------------------------------------------------- util
 
@@ -1315,7 +1318,8 @@ class ShardedDeviceChecker:
     def _rehash_jit(self):
         """fpset growth: every shard rehashes its own table into a
         double-capacity one inside the same shard_map dispatch —
-        (vk cols) -> (vk' cols, per-shard failure count)."""
+        (vk cols) -> (vk' cols, per-shard rehash vector:
+        ``fpset.rhm_logical``)."""
         key = ("rehash", self.TCAP)
         if key in self._jits:
             return self._jits[key]
@@ -1324,10 +1328,10 @@ class ShardedDeviceChecker:
         @spans.staged("rehash")
         def ptt_shard_rehash(vk):
             vk = tuple(v[0] for v in vk)
-            new, failed = fpset.rehash_cols(
+            new, rhm = fpset.rehash_cols(
                 vk, fpset.empty_cols(2 * TCAP, K)
             )
-            return tuple(v[None] for v in new), failed[None]
+            return tuple(v[None] for v in new), rhm[None]
 
         sh = P(self._axes)
         fn = self._smap(ptt_shard_rehash, ((sh,) * K,), ((sh,) * K, sh))
@@ -1339,11 +1343,16 @@ class ShardedDeviceChecker:
         while self.VCAP < need:
             out = self._rehash_jit()(bufs["vk"])
             bufs["vk"] = tuple(out[0])
-            if np.asarray(out[1]).any():
+            # one fetch, as before: every shard's fail-stop count and
+            # its two rehash counters
+            failed, keys, lane_rounds = fpset.rhm_logical(out[1])
+            if failed:
                 raise RuntimeError(
                     "fpset rehash overflow — table corrupted its "
                     "load-factor contract (bug)"
                 )
+            self._rehash_keys += keys
+            self._rehash_lane_rounds += lane_rounds
             self.TCAP *= 2
             self.VCAP = self.TCAP // 2
 
@@ -1854,6 +1863,7 @@ class ShardedDeviceChecker:
         self._dispatch_n = 0
         self._route_rounds = {}
         self._route_overflows = 0
+        self._rehash_keys = self._rehash_lane_rounds = 0
         # per-run recovery/frame state: a fresh run() must not inherit
         # a previous run's degraded capacity or frame counts
         self.rec.reset()
@@ -2794,6 +2804,8 @@ class ShardedDeviceChecker:
             dispatches_per_level=round(
                 self._dispatch_n / max(len(level_sizes), 1), 2
             ),
+            grow_rehash_keys=self._rehash_keys,
+            grow_rehash_lane_rounds=self._rehash_lane_rounds,
         )
         self.tel.emit(
             "result",

@@ -34,6 +34,16 @@ picked for the backend's memory system at trace time:
 ``PTT_COMPACT_MATERIALIZE=shift|gather`` overrides the choice, so a CPU
 test can run the materialization the TPU picks.
 
+A caller whose program is built anew for every table size (the table
+rehash, ``ops/fpset.py: rehash_cols``) passes ``materialize="roll"``:
+the shift passes as ONE loop over the bit, the same values pass for
+pass.  A program's host cost (trace, lowering, compile) goes by its
+equations, and three compactions of 16 to 18 unrolled passes were
+2,700 of a rehash program's 3,700; rolled they are a copy more a pass
+on the device, which a rehash does not notice and the level kernel,
+whose compactions stay unrolled, was not asked to pay (PERF.md §6
+"PR 35").
+
 Correctness sketch for the shift passes (the property test hammers
 both materializations with random masks): ``delta`` (dropped elements
 before position i) is monotone non-decreasing and increases by at most
@@ -56,6 +66,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 
 def materialization() -> str:
@@ -74,15 +85,24 @@ def materialization() -> str:
     return "gather" if jax.default_backend() == "cpu" else "shift"
 
 
-def _shifted(x: jax.Array, d: int) -> jax.Array:
-    """``x`` shifted left by ``d``: out[i] = x[i + d], zero-padded."""
-    return jnp.concatenate([x[d:], jnp.zeros((d,), x.dtype)])
+def _shifted(x: jax.Array, d) -> jax.Array:
+    """``x`` shifted left by ``d``: out[i] = x[i + d], zero-padded
+    (``d`` a Python int, or traced: one dynamic slice of the padded
+    column)."""
+    if isinstance(d, int):
+        return jnp.concatenate([x[d:], jnp.zeros((d,), x.dtype)])
+    return lax.dynamic_slice(
+        jnp.concatenate([x, jnp.zeros_like(x)]), (d,), x.shape
+    )
 
 
-def _shift_compact(drop, vals):
+def _shift_compact(drop, vals, rolled=False):
     """Masked doubling-shift materialization (the TPU path): move every
     kept element left by its drop-prefix-sum distance, one bit of the
-    distance per pass — contiguous copies and selects only."""
+    distance per pass — contiguous copies and selects only.  ``rolled``
+    runs the passes as ONE loop over the bit (the same values, pass for
+    pass): ``log2(n)`` times fewer equations to trace, lower and
+    compile, and as much less code, for a copy more a pass."""
     n = drop.shape[0]
     keep = drop == 0
     # delta[i] = dropped elements strictly before i = how far a kept
@@ -93,8 +113,8 @@ def _shift_compact(drop, vals):
     # elements get 0 so they never ride a shift (a dropped element
     # pulled over a kept one was the classic corruption mode).
     rem = jnp.where(keep, delta, jnp.uint32(0))
-    d = 1
-    while d < n:
+
+    def one_pass(rem, vals, d):
         du = jnp.uint32(d)
         rem_s = _shifted(rem, d)
         # pull from i+d when THAT element's remaining distance has this
@@ -104,7 +124,18 @@ def _shift_compact(drop, vals):
         # a slot whose occupant left with nothing arriving holds a
         # stale copy: zero its distance so it can never move again
         rem_keep = jnp.where((rem & du) != 0, jnp.uint32(0), rem)
-        rem = jnp.where(take, rem_s - du, rem_keep)
+        return jnp.where(take, rem_s - du, rem_keep), vals
+
+    if rolled:
+        rem, vals = lax.fori_loop(
+            0, (n - 1).bit_length(),
+            lambda b, c: one_pass(c[0], c[1], jnp.int32(1) << b),
+            (rem, list(vals)),
+        )
+        return vals
+    d = 1
+    while d < n:
+        rem, vals = one_pass(rem, vals, d)
         d <<= 1
     return vals
 
@@ -135,8 +166,9 @@ def compact_by_flag(
     materialize: Optional[str] = None,
 ) -> Tuple[tuple, Optional[jax.Array]]:
     """Sort-free stable compaction of ``cols`` to the front where
-    ``drop == 0`` (module docstring; ``materialize`` is ``"shift"`` or
-    ``"gather"``, by default the process's, :func:`materialization`).
+    ``drop == 0`` (module docstring; ``materialize`` is ``"shift"``,
+    ``"gather"`` or ``"roll"``, by default the process's,
+    :func:`materialization`).
 
     The kept prefix is in original order; positions past the kept
     count are don't-care.
@@ -153,7 +185,7 @@ def compact_by_flag(
         return tuple(out), (src if need_idx else None)
     if need_idx:
         vals.append(jnp.arange(n, dtype=jnp.uint32))
-    out = _shift_compact(drop, vals)
+    out = _shift_compact(drop, vals, rolled=materialize == "roll")
     idx = None
     if need_idx:
         idx = out[-1].astype(jnp.int32)

@@ -45,9 +45,27 @@ probing design generalised to the device hot path:
   and stages bid with original lane ids): "lowest accumulator slot
   wins", so gids follow lane order whatever the table's layout.
 - **On-device growth**: :func:`rehash_cols` re-inserts every occupied
-  slot of the old table into a double-size table with a `fori_loop` of
-  chunked probe rounds — one dispatch, no host staging, and the
-  transient is old+new table.
+  slot of the old table into the new one, fully on device: a
+  `fori_loop` over chunks of ``REHASH_CHUNK`` old slots; a chunk's
+  occupied slots are packed to the front (one ``compact_by_flag``;
+  the buffer is 5/8 of the chunk, the load contract's 1/2 and a
+  margin) and go through ``lookup_or_insert``'s ladder at the
+  module's schedule — one dispatch, no host staging, and the
+  transient is old + new table + a few columns of one chunk.  A
+  rehash so costs by the key it moves: an empty slot is read once and
+  never presented to the new table (a parked lane costs MORE than a
+  pending one, above), and the tail of a chunk's probes runs in the
+  1/4 and 1/64 buffers.  Before PR 35 a chunk was 65,536 slots in ONE
+  ``probe_insert`` loop, at full width until its last lane resolved:
+  17.76 lanes presented a key over the eight doublings of the
+  9,445,152-state binding (2^17 -> 2^25 slots, old tables 23-50%
+  full), 29.4 of a 52.3 s check; now 2.09, and 4.3 of 28.3 s (PERF.md
+  §6 "PR 35", which also has what the ladder alone read, with the
+  empty slots dropped by its first hand-over).  It hands back the
+  vector ``[failures, keys, lane_rounds lo, hi]``
+  (:func:`rhm_logical`), which the engines fetch in the one host sync
+  a doubling always had (``grow_rehash_keys``,
+  ``grow_rehash_lane_rounds``: docs/observability.md).
 
 Load factor is the caller's contract: engines grow before the table
 exceeds 1/2 (`ops/hashtable.py`'s regime), which bounds expected probes
@@ -56,6 +74,7 @@ per lane at ~2 and makes stage overflow astronomically unlikely.
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Tuple
 
@@ -640,42 +659,128 @@ def lookup(
     return member
 
 
+# Slots of the old table a rehash step takes (:func:`rehash_cols`): wide
+# enough for the ladder to narrow and for a round's ``claims`` fill of
+# the NEW table to be paid tens of times a doubling, bounded so that the
+# transient stays old + new table + a few columns of one chunk (a rehash
+# runs when memory is shortest).  Not wider: a program's code goes by
+# the widths of its probe loops, the chip keeps code in HBM, and a first
+# process compiles a program for every tier (PERF.md §6 "PR 35").
+REHASH_CHUNK = 1 << 18
+
+# A chunk's occupied slots are packed into a buffer of this share of
+# its width before round 0.  The callers' contract is load <= 1/2, so a
+# chunk holds chunk/2 keys give or take a few sqrt(chunk): the eighth
+# above it is 11 standard deviations at the narrowest chunk that is
+# packed (2^11 slots) and over 100 at ``REHASH_CHUNK``.  A key that
+# does not fit is counted as a failure, as a stage overflow is.
+REHASH_PACK_NUM, REHASH_PACK_DEN = 5, 8
+
+# Width of the vector a rehash hands back: [failures, keys,
+# lane_rounds_lo, lane_rounds_hi] — the lanes presented to the new
+# table summed over probe rounds as hi/lo uint32 words (the
+# :func:`fpm_update` pattern), so that ONE fetch reads the fail-stop
+# count and the counters.  :func:`rhm_logical` is the host's view.
+RHM_N = 4
+
+
+def rhm_logical(vec) -> Tuple[int, int, int]:
+    """``(failures, keys, lane_rounds)`` as Python integers from
+    fetched rehash vectors (``[RHM_N]``, or ``[shards, RHM_N]``, summed)."""
+    import numpy as np
+
+    v = np.asarray(vec, np.int64).reshape(-1, RHM_N)
+    lane_rounds = sum(int(u64(lo, hi)) for lo, hi in v[:, 2:])
+    return int(v[:, 0].sum()), int(v[:, 1].sum()), lane_rounds
+
+
 def rehash_cols(
     old_cols: Tuple[jax.Array, ...],
     new_cols: Tuple[jax.Array, ...],
-    chunk: int = 1 << 16,
+    chunk: int = REHASH_CHUNK,
     max_probes: int = MAX_PROBES,
+    materialize: Optional[str] = None,
 ):
-    """Re-insert every occupied slot of ``old_cols`` into the (larger)
-    ``new_cols`` — fully on device (one `fori_loop` of chunked probe
-    rounds), so it is usable inside jit and shard_map bodies alike.
+    """Re-insert every occupied slot of ``old_cols`` into ``new_cols``
+    (larger, or as large and empty) — fully on device, so it is usable
+    inside jit and shard_map bodies alike.  A `fori_loop` over chunks
+    of the old table: a chunk's occupied slots are packed to the front
+    (``REHASH_PACK_NUM / REHASH_PACK_DEN`` of its width; a chunk no
+    wider than ``MIN_STAGE`` goes as it is) and go through
+    :func:`lookup_or_insert`'s ladder at the module's schedule
+    (``DENSE_ROUNDS`` / ``STAGES``), so that every round presents the
+    lanes still pending and little else (module docstring, "On-device
+    growth").
 
-    Returns ``(new_cols, n_failed)``; the keys are distinct by
-    construction and the post-growth load is <= 1/4, so a nonzero
+    Returns ``(new_cols, rhm)``, ``rhm`` the int32[RHM_N] vector of
+    :func:`rhm_logical`: failures, the old table's keys, and the lanes
+    presented for them.  The keys are distinct by construction and the
+    new table's load is <= 1/2 (<= 1/4 after a doubling), so a nonzero
     failure count means the caller's capacity contract was broken
     (fail-stop upstream, like every other capacity violation here).
+
+    The body is a module-level ``jax.jit`` of its own, keyed on the
+    shapes and the three static values: a program that is traced again
+    for every checker (the sharded engine's, nine tiers a check) finds
+    the ladder's equations from the process's first trace.
     """
+    materialize = materialize or compact_ops.materialization()
+    return _rehash_cols(
+        tuple(old_cols), tuple(new_cols), chunk=chunk,
+        max_probes=max_probes,
+        # a rehash program is built anew for every table size, so its
+        # shift passes are one loop each, not log2(chunk) copies
+        materialize="roll" if materialize == "shift" else materialize,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("chunk", "max_probes", "materialize")
+)
+def _rehash_cols(old_cols, new_cols, *, chunk, max_probes, materialize):
     ocap = old_cols[0].shape[0] - 1
     chunk = min(chunk, ocap)
     if ocap % chunk:
         raise ValueError("rehash chunk must divide the old capacity")
+    width = max(
+        chunk * REHASH_PACK_NUM // REHASH_PACK_DEN, min(chunk, MIN_STAGE)
+    )
 
     def body(i, carry):
-        new, failed = carry
+        new, rhm = carry
         ks = tuple(
             lax.dynamic_slice(c, (i * chunk,), (chunk,))
             for c in old_cols
         )
         occm = ~all_sentinel(ks)
-        _new_flags, new, _, pending, _r = probe_insert(
-            new, ks, occm, max_probes=max_probes
+        n_occ = jnp.sum(occm.astype(jnp.int32))
+        if width < chunk:
+            packed, _ = compact_ops.compact_by_flag(
+                (~occm).astype(jnp.uint32), ks, need_idx=False,
+                materialize=materialize,
+            )
+            ks = tuple(c[:width] for c in packed)
+            occm = jnp.arange(width, dtype=jnp.int32) < n_occ
+        _new_flags, new, n_failed, _r, lane_rounds = lookup_or_insert(
+            new, ks, occm, max_probes=max_probes,
+            dense_rounds=DENSE_ROUNDS, stages=STAGES,
+            materialize=materialize,
         )
-        return new, failed + jnp.sum(pending.astype(jnp.int32))
+        lanes_lo, lanes_hi = add_u32(rhm[2], rhm[3], lane_rounds)
+        rhm = jnp.stack(
+            [
+                rhm[0] + n_failed + jnp.maximum(n_occ - width, 0),
+                rhm[1] + n_occ,
+                lanes_lo,
+                lanes_hi,
+            ]
+        )
+        return new, rhm
 
-    new_cols, n_failed = lax.fori_loop(
-        0, ocap // chunk, body, (tuple(new_cols), jnp.int32(0))
+    return lax.fori_loop(
+        0, ocap // chunk, body,
+        (tuple(new_cols), jnp.zeros((RHM_N,), jnp.int32)),
     )
-    return new_cols, n_failed
 
 
 class FPSet:
@@ -732,8 +837,8 @@ class FPSet:
         load factor <= 1/2."""
         while 2 * n_entries > self.cap:
             new = empty_cols(self.cap * 2, self.ncols)
-            self.cols, failed = rehash_cols(self.cols, new)
-            if int(failed):
+            self.cols, rhm = rehash_cols(self.cols, new)
+            if rhm_logical(rhm)[0]:
                 raise RuntimeError("fpset rehash overflow")
         return self
 

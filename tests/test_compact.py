@@ -34,15 +34,26 @@ def _ref_compact(drop, cols):
     return [c[kept] for c in cols], kept
 
 
-@pytest.mark.parametrize("mat", ["shift", "gather"])
+def _materialize(mat, monkeypatch):
+    """``compact_by_flag``'s keywords for ``mat``: the process's two
+    through the environment, as a process picks them; ``roll`` (the
+    shift passes as one loop, the rehash's) only as an argument."""
+    if mat == "roll":
+        return {"materialize": "roll"}
+    monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
+    return {}
+
+
+@pytest.mark.parametrize("mat", ["shift", "gather", "roll"])
 def test_logshift_matches_sort_random_masks_and_widths(
     mat, monkeypatch
 ):
     """Random masks, drop rates, lengths (incl. non-powers-of-two) and
-    column counts, under BOTH materializations (the TPU doubling-shift
-    passes and the CPU prefix+gather): the kept prefix must equal the
-    numpy reference element-for-element, idx included."""
-    monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
+    column counts, under every materialization (the TPU doubling-shift
+    passes, unrolled and as one loop, and the CPU prefix+gather): the
+    kept prefix must equal the numpy reference element-for-element,
+    idx included."""
+    kw = _materialize(mat, monkeypatch)
     rng = np.random.default_rng(0)
     for trial in range(10):
         n = int(rng.integers(1, 200))
@@ -54,7 +65,7 @@ def test_logshift_matches_sort_random_masks_and_widths(
             for _ in range(ncols)
         ]
         jcols = tuple(jnp.asarray(c) for c in cols)
-        out, idx = compact.compact_by_flag(jnp.asarray(drop), jcols)
+        out, idx = compact.compact_by_flag(jnp.asarray(drop), jcols, **kw)
         ref_cols, kept = _ref_compact(drop, cols)
         k = len(kept)
         for got, want in zip(out, ref_cols):
@@ -62,22 +73,22 @@ def test_logshift_matches_sort_random_masks_and_widths(
         assert np.array_equal(np.asarray(idx)[:k], kept), trial
         # need_idx=False skips the index column, not the values
         out2, idx2 = compact.compact_by_flag(
-            jnp.asarray(drop), jcols, need_idx=False
+            jnp.asarray(drop), jcols, need_idx=False, **kw
         )
         assert idx2 is None
         for got, want in zip(out2, ref_cols):
             assert np.array_equal(np.asarray(got)[:k], want), trial
 
 
-@pytest.mark.parametrize("mat", ["shift", "gather"])
+@pytest.mark.parametrize("mat", ["shift", "gather", "roll"])
 @pytest.mark.parametrize("n", [1, 2, 129])
 @pytest.mark.parametrize("all_drop", [False, True])
 def test_logshift_all_keep_all_drop_edges(n, all_drop, mat, monkeypatch):
-    monkeypatch.setenv("PTT_COMPACT_MATERIALIZE", mat)
+    kw = _materialize(mat, monkeypatch)
     drop = np.full(n, 1 if all_drop else 0, np.uint32)
     c = np.arange(n, dtype=np.uint32) * 3
     out, idx = compact.compact_by_flag(
-        jnp.asarray(drop), (jnp.asarray(c),)
+        jnp.asarray(drop), (jnp.asarray(c),), **kw
     )
     k = 0 if all_drop else n
     assert np.array_equal(np.asarray(out[0])[:k], c[:k])

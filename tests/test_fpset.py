@@ -81,6 +81,141 @@ def test_growth_preserves_membership():
     assert np.asarray(s.contains((stacked[:, 0], stacked[:, 1]))).all()
 
 
+def _table_at_load(cap, load, ncols, seed):
+    """``(keys [n, ncols], table columns)``: ``cap * load`` distinct
+    random keys inserted into an empty table of ``cap`` slots."""
+    rng = np.random.default_rng(seed)
+    keys = np.unique(
+        rng.integers(
+            0, 2**32 - 2, size=(int(cap * load), ncols), dtype=np.uint32
+        ),
+        axis=0,
+    )
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
+    _new, tcols, n_failed, _r, _l = fpset.lookup_or_insert(
+        fpset.empty_cols(cap, ncols), kcols,
+        jnp.ones((len(keys),), jnp.bool_),
+    )
+    assert int(n_failed) == 0
+    return keys, tcols
+
+
+def _assert_holds_exactly(tcols, keys):
+    """The table's occupied slots hold ``keys`` (sorted rows), each
+    once, and ``lookup`` finds every one of them."""
+    occ = np.asarray(fpset.occupied_mask(tcols))
+    held = np.stack([np.asarray(c)[:-1][occ] for c in tcols], axis=1)
+    assert int(occ.sum()) == len(keys)
+    assert np.array_equal(np.unique(held, axis=0), keys)
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(keys.shape[1]))
+    found = fpset.lookup(tcols, kcols, jnp.ones((len(keys),), jnp.bool_))
+    assert np.asarray(found).all()
+
+
+REHASH_TEST_CHUNK = 1 << 13
+
+
+@pytest.mark.parametrize("ncols", [2, 3])
+@pytest.mark.parametrize("load", [0.1, 0.3, 0.5])
+@pytest.mark.parametrize(
+    "ocap", [1 << 11, REHASH_TEST_CHUNK, 4 * REHASH_TEST_CHUNK],
+    ids=["below_chunk", "one_chunk", "four_chunks"],
+)
+def test_rehash_doubling_moves_exactly_the_old_keys(ocap, load, ncols):
+    keys, old = _table_at_load(ocap, load, ncols, seed=ocap + ncols)
+    new, rhm = fpset.rehash_cols(
+        old, fpset.empty_cols(2 * ocap, ncols), chunk=REHASH_TEST_CHUNK
+    )
+    failed, moved, lane_rounds = fpset.rhm_logical(rhm)
+    assert failed == 0 and moved == len(keys)
+    assert lane_rounds >= moved
+    _assert_holds_exactly(new, keys)
+
+
+@pytest.mark.parametrize("ncols", [2, 3])
+@pytest.mark.parametrize(
+    "ocap", [1 << 11, 4 * REHASH_TEST_CHUNK],
+    ids=["below_chunk", "four_chunks"],
+)
+def test_rehash_same_capacity_rebuild_at_half_load(ocap, ncols):
+    """The tiered store's rebuild after an eviction: the new table is
+    as large as the old one and ends as full (load 1/2, where the
+    ladder's margins are stated)."""
+    keys, old = _table_at_load(ocap, 0.5, ncols, seed=11 * ncols)
+    new, rhm = fpset.rehash_cols(
+        old, fpset.empty_cols(ocap, ncols), chunk=REHASH_TEST_CHUNK
+    )
+    assert fpset.rhm_logical(rhm)[:2] == (0, len(keys))
+    _assert_holds_exactly(new, keys)
+
+
+def test_rehash_with_the_shift_passes_the_chip_compacts_with():
+    """``materialize="shift"`` (what a TPU process picks; the rehash
+    runs the passes as one loop) packs and hands over as the CPU's
+    gather does."""
+    ocap = 4 * REHASH_TEST_CHUNK
+    keys, old = _table_at_load(ocap, 0.4, 2, seed=23)
+    new, rhm = fpset.rehash_cols(
+        old, fpset.empty_cols(2 * ocap, 2), chunk=REHASH_TEST_CHUNK,
+        materialize="shift",
+    )
+    ref, rhm_ref = fpset.rehash_cols(
+        old, fpset.empty_cols(2 * ocap, 2), chunk=REHASH_TEST_CHUNK
+    )
+    assert fpset.rhm_logical(rhm) == fpset.rhm_logical(rhm_ref)
+    assert fpset.rhm_logical(rhm)[:2] == (0, len(keys))
+    for c, r in zip(new, ref):  # slot for slot; the last is the trash row
+        assert np.array_equal(np.asarray(c)[:-1], np.asarray(r)[:-1])
+
+
+def test_rehash_into_a_table_too_small_counts_its_failures():
+    keys, old = _table_at_load(1 << 12, 0.5, 2, seed=13)
+    new, rhm = fpset.rehash_cols(old, fpset.empty_cols(1 << 10, 2))
+    failed, moved, _lanes = fpset.rhm_logical(rhm)
+    assert moved == len(keys) == 2048
+    # never a silent drop: what did not land is counted
+    landed = int(np.asarray(fpset.occupied_mask(new)).sum())
+    assert failed > 0 and landed + failed == moved
+
+
+def test_rehash_of_a_chunk_past_the_load_contract_counts_what_it_leaves():
+    """A chunk fuller than the packed buffer (5/8 of it; the contract
+    is 1/2): the keys that do not fit are failures, not silent drops."""
+    cap = 1 << 12
+    keys, old = _table_at_load(cap, 0.7, 2, seed=19)
+    new, rhm = fpset.rehash_cols(old, fpset.empty_cols(2 * cap, 2))
+    failed, held, _lanes = fpset.rhm_logical(rhm)
+    width = cap * fpset.REHASH_PACK_NUM // fpset.REHASH_PACK_DEN
+    assert held == len(keys) > width
+    assert failed == held - width
+    assert int(np.asarray(fpset.occupied_mask(new)).sum()) == width
+
+
+def test_rehash_presents_lanes_by_the_pending_count():
+    """The mechanism (ISSUE 35): a chunk narrows with what is pending,
+    where one full-width loop pays its whole tail at the chunk's
+    width."""
+    cap = 1 << 16
+    keys, old = _table_at_load(cap, 0.5, 2, seed=17)
+    _new, rhm = fpset.rehash_cols(old, fpset.empty_cols(2 * cap, 2))
+    failed, moved, lane_rounds = fpset.rhm_logical(rhm)
+    assert failed == 0 and moved == len(keys)
+    assert lane_rounds / moved < 5
+    ks = tuple(c[:cap] for c in old)
+    _f, _t, _o, pending, rounds = fpset.probe_insert(
+        fpset.empty_cols(2 * cap, 2), ks, ~fpset.all_sentinel(ks)
+    )
+    assert not np.asarray(pending).any()
+    assert cap * int(rounds) / moved > 8
+
+
+def test_rhm_logical_sums_shards_and_reads_lane_rounds_as_64_bits():
+    one = np.array([0, 5, -1, 2], np.int32)  # lo word 2^32 - 1, hi 2
+    assert fpset.rhm_logical(one) == (0, 5, (2 << 32) + 2**32 - 1)
+    two = np.stack([one, np.array([3, 7, 10, 0], np.int32)])
+    assert fpset.rhm_logical(two) == (3, 12, (2 << 32) + 2**32 + 9)
+
+
 def test_failure_count_on_overload():
     """More distinct keys than the table can hold: the unresolved lanes
     MUST surface in n_failed (and the wrapper must raise) — never a

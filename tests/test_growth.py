@@ -21,6 +21,7 @@ from pulsar_tlaplus_tpu.engine import bodies
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.obs import spans
+from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from tests.test_units import VERDICT, _cli_check
 from tests.test_units_programs import CFG_253K
@@ -33,7 +34,7 @@ PARENT = {
 GROW_KEYS = (
     "grow_events", "grow_rehashes", "grow_rehash_slots",
     "grow_copy_bytes", "grow_tiers_final", "grow_wall_max_s",
-    "grow_wall_max_at",
+    "grow_wall_max_at", "grow_rehash_keys", "grow_rehash_lane_rounds",
 )
 
 
@@ -83,6 +84,26 @@ def _record_growth_calls(ck):
     return calls
 
 
+def _record_doublings(ck):
+    """``[(old slots, occupied among them)]`` of the table doublings of
+    the runs to come, read off each old table before it is rehashed."""
+    seen = []
+    rehash_jit = ck._rehash_jit
+
+    def recording():
+        fn = rehash_jit()
+
+        def call(vk):
+            occ = int(fpset.occupied_mask(vk).sum())
+            seen.append((vk[0].shape[0] - 1, occ))
+            return fn(vk)
+
+        return call
+
+    ck._rehash_jit = recording
+    return seen
+
+
 def _doublings(cur, target):
     """The old sizes the growers' loops copy on the way to ``target``."""
     while cur < target:
@@ -105,6 +126,7 @@ def test_a_run_from_small_tables_counts_the_tiers_it_crossed(
     assert tiers0 == (1 << 11, 1 << 10, 1 << 10)
     staircase = ck._fused_tier_triples()
     calls = _record_growth_calls(ck)
+    doubled = _record_doublings(ck)
     t0 = time.perf_counter()
     r = ck.run()
     wall = time.perf_counter() - t0
@@ -122,6 +144,13 @@ def test_a_run_from_small_tables_counts_the_tiers_it_crossed(
     assert tiers1 == (1 << 17, 1 << 16, 1 << 16)
     assert st["grow_rehashes"] == 6
     assert st["grow_rehash_slots"] == tiers1[0] - tiers0[0]
+    # the keys the doublings moved: each old table's occupancy, read
+    # in the sync that reads the failure count (the fetch pin below);
+    # the lanes presented follow them, not the slots walked
+    assert [s for s, _ in doubled] == [tiers0[0] << i for i in range(6)]
+    assert st["grow_rehash_keys"] == sum(k for _, k in doubled) > 0
+    assert st["grow_rehash_keys"] <= st["grow_rehash_lane_rounds"]
+    assert st["grow_rehash_lane_rounds"] < st["grow_rehash_slots"] * 3
     assert st["grow_copy_bytes"] == _copy_bytes(ck, tiers0, tiers1)
     # every outermost growth call, replayed through the arithmetic:
     # the initial level's two, then the fused path's one a dispatch
@@ -205,8 +234,9 @@ def test_a_second_cli_check_crosses_the_same_tiers_on_the_same_programs(
         assert k in second[3], k  # on the -telemetry result event
     assert second[3]["grow_tiers_final"] == [1 << 21, 1 << 20, 1 << 20]
     assert second[3]["grow_rehashes"] == 4  # 2^17 -> 2^21 slots
-    for k in GROW_KEYS[:5]:
+    for k in GROW_KEYS[:5] + GROW_KEYS[7:]:
         assert second[3][k] == first[3][k], k
+    assert 0 < second[3]["grow_rehash_keys"] <= 253361
     assert second[3]["jit_body_traces"] == 0
     # no unit compiles again; the one small jit a checker still builds
     # for itself does under the suite's cache threshold (conftest.py),

@@ -294,6 +294,10 @@ def test_dispatches_per_level_counts_every_program_of_the_run():
         c in ("ptt_shard_init", "ptt_shard_round") for c in calls
     )
     assert calls.count("ptt_shard_stats") == st["stats_fetches"]
+    # the doublings' counters ride the fetch that reads their failure
+    # counts: keys moved, and the lanes the ladder presented for them
+    assert calls.count("ptt_shard_rehash") >= 1
+    assert 0 < st["grow_rehash_keys"] <= st["grow_rehash_lane_rounds"]
 
 
 # ---- the scopes are in what is compiled --------------------------------

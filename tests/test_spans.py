@@ -24,6 +24,7 @@ from pulsar_tlaplus_tpu.engine import bodies, device_bfs
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.obs import spans
+from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from tests.helpers import SMALL_CONFIGS, assert_valid_counterexample
 
@@ -268,6 +269,35 @@ def test_each_stage_jit_carries_its_scope(monkeypatch):
         ("ptt_init", "ptt.init"), ("ptt_rehash", "ptt.rehash"),
     ):
         assert scope in texts[name], name
+
+
+@pytest.mark.parametrize("materialize", ["shift", "gather"])
+def test_every_operation_of_the_rehash_lies_under_its_scope(materialize):
+    """A doubling of two chunks, as compiled: the chunk loop, the
+    ladder's three probe loops and the three compactions (the pack and
+    two hand-overs; a loop each where they are shift passes) all carry
+    ``ptt.rehash``, so no second of it reads as unscoped device time.
+    The names without it are the reducers' own (a scatter's ``min``, a
+    sum's ``add``), which no device event carries."""
+    slots = 2 * fpset.REHASH_CHUNK
+    old = tuple(
+        jax.ShapeDtypeStruct((slots + 1,), jnp.uint32) for _ in range(2)
+    )
+    hlo = bodies.ptt_rehash.lower(
+        old, materialize=materialize
+    ).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    scoped = [
+        n for n in names if n.startswith("jit(ptt_rehash)/ptt.rehash/")
+    ]
+    assert len(scoped) > 500
+    assert {n.rsplit("/", 1)[-1] for n in set(names) - set(scoped)} <= {
+        "reduce_sum", "reduce_window_sum", "scatter", "scatter-min",
+        "min", "old[0]", "old[1]",
+    }
+    assert set(re.findall(r"ptt\.[a-z]+", hlo)) == {"ptt.rehash"}
+    loops = {"shift": 4 + 3, "gather": 4}[materialize]
+    assert len(re.findall(r" while\(", hlo)) == loops
 
 
 def test_level_kernel_scopes_are_the_same_on_a_miss_and_on_a_hit(
