@@ -148,7 +148,7 @@ def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
     print(
         f"tpu-tlc: checking {module} @ {spec_path} via the spec->kernel "
         f"compiler (state width {cs.layout.total_bits} bits, {cs.A} "
-        f"successor lanes; invariants: {list(invariants) or 'none'})"
+        f"successor lanes; {_checking_what(args, invariants)})"
     )
     for cname, mapping in interned.items():
         pairs = ", ".join(f'"{s}" -> {i}' for s, i in mapping.items())
@@ -163,9 +163,7 @@ def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
     ck = DeviceChecker(
         cs,
         check_deadlock=not args.nodeadlock,
-        sub_batch=min(args.chunk, 4096),
-        visited_cap=1 << 16,
-        frontier_cap=1 << 14,
+        **_explorer_tiers(args),
         max_states=args.maxstates,
         progress=True,
         metrics_path=args.metrics,
@@ -249,6 +247,59 @@ def _check_interp(args, module, spec_path, tlc_cfg, invariants):
     return _report(r, None, time.time() - t0)
 
 
+def _explorer_tiers(args) -> dict:
+    """Where every ``check`` starts its single-chip explorer, whatever
+    the ``.cfg`` says and whichever question is asked (a safety check,
+    or a temporal property through ``LivenessChecker``): the table is
+    ``2 * visited_cap`` slots and grows lazily from there, so one
+    binding meets the same table sizes, and the same programs' shapes,
+    on both paths."""
+    return dict(
+        sub_batch=min(args.chunk, 4096),
+        visited_cap=1 << 16,
+        frontier_cap=1 << 14,
+    )
+
+
+def _checking_what(args, invariants) -> str:
+    """The banner's last clause: what this check decides."""
+    if getattr(args, "liveness_property", None):
+        return (
+            f"temporal property: {args.liveness_property} under "
+            f"fairness {args.fairness}; no invariant checked"
+        )
+    return f"invariants: {list(invariants) or 'none'}"
+
+
+def _print_graph_summary(graph) -> None:
+    """The behaviour graph a liveness verdict was computed on, so that
+    an untraced run can be held to a reference: one line for the whole
+    graph and one per BFS level."""
+    if not graph:
+        return
+    # no fairness assumed: the verdict needed no edge and none was swept
+    swept = graph["edges"] is not None
+    print(
+        f"Behaviour graph: {graph['states']} states in "
+        f"{graph['levels']} levels, "
+        f"{graph['edges'] if swept else 'n/a'} <Next>_vars edges, "
+        f"{graph['goal_states']} goal states, "
+        f"{graph['dead_ends'] if swept else 'n/a'} dead ends."
+    )
+    by = graph.get("by_level")
+    if not by:
+        return
+    unswept = ["n/a"] * len(by["size"])
+    for i, row in enumerate(zip(
+        by["size"], by.get("edges", unswept), by["goal"],
+        by.get("dead_ends", unswept),
+    )):
+        print(
+            "  graph level {}: {} states, {} edges, {} goal, "
+            "{} dead ends".format(i + 1, *row)
+        )
+
+
 def _report_liveness(prop, args, lres) -> int:
     """Liveness verdict report + exit code (0 holds, 1 violated, 3
     preempted/truncated — an interrupted run carries NO verdict)."""
@@ -279,6 +330,7 @@ def _report_liveness(prop, args, lres) -> int:
         f"{verdict} — {lres.reason}"
     )
     print(f"{lres.distinct_states} distinct states examined.")
+    _print_graph_summary(lres.graph)
     return 0 if lres.holds else 1
 
 
@@ -363,6 +415,7 @@ def _check_properties(args, model, properties, rc):
                     goal=prop,
                     fairness=args.fairness,
                     frontier_chunk=args.chunk,
+                    visited_cap=_explorer_tiers(args)["visited_cap"],
                     max_states=args.maxstates,
                     # the safety phase completed cleanly, so its frame
                     # at this path is obsolete — the liveness phase
@@ -440,6 +493,7 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 goal=args.liveness_property,
                 fairness=args.fairness,
                 frontier_chunk=args.chunk,
+                visited_cap=_explorer_tiers(args)["visited_cap"],
                 max_states=args.maxstates,
                 checkpoint_path=args.checkpoint,
                 sweep_group=args.sweep_group,
@@ -554,9 +608,7 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 model,
                 invariants=invariants,
                 check_deadlock=not args.nodeadlock,
-                sub_batch=min(args.chunk, 4096),
-                visited_cap=1 << 16,
-                frontier_cap=1 << 14,
+                **_explorer_tiers(args),
                 max_states=args.maxstates,
                 progress=True,
                 metrics_path=args.metrics,
@@ -2454,7 +2506,7 @@ def _cmd_check(args) -> int:
     print(
         f"tpu-tlc: checking {module} @ {cfg_path} "
         f"(state width {model.layout.total_bits} bits, "
-        f"{model.A} successor lanes; invariants: {list(invariants) or 'none'})"
+        f"{model.A} successor lanes; {_checking_what(args, invariants)})"
     )
     t0 = time.time()
     return _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0)

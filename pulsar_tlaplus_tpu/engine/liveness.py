@@ -48,6 +48,7 @@ import numpy as np
 from jax import lax
 
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL
 from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
@@ -86,6 +87,9 @@ class LivenessResult:
     # ``run(resume=True)`` continues from the last frame
     truncated: bool = False
     stop_reason: Optional[str] = None
+    # the behaviour graph the verdict was computed on, level by level
+    # (``graph_summary``); None where the run was cut before it had one
+    graph: Optional[dict] = None
 
 
 class LivenessChecker:
@@ -199,6 +203,9 @@ class LivenessChecker:
         inner_kw = dict(
             checkpoint_path=checkpoint_path,
             checkpoint_every=checkpoint_every,
+            # the explorer's per-level progress lines, as ``cli check``
+            # prints them
+            progress=progress,
         )
         # resolve the ctor-or-PTT_HBM_BUDGET budget HERE so the env
         # var gets the same gating/forwarding as the explicit knob
@@ -262,6 +269,7 @@ class LivenessChecker:
         self._edge_cache = None  # (src, dst, out_deg) — goal-independent
         self._jits = {}
         self._diameter = 0
+        self._level_sizes = None  # per BFS level, once explored
         self._watcher = None
         self._observer = None
         self._resume_explore = False
@@ -397,6 +405,11 @@ class LivenessChecker:
                 del self._checker.last_bufs[k]
         self._explored = (res.distinct_states, res.level_sizes[0])
         self._diameter = res.diameter
+        # gids are in discovery order on one device; the sharded
+        # branch above remaps them shard by shard, so a level is no gid
+        # range there and the graph summary gives no per-level columns
+        if self.n_devices <= 1:
+            self._level_sizes = [int(x) for x in res.level_sizes]
         return self._explored
 
     def run_goal(self, goal: str) -> LivenessResult:
@@ -430,14 +443,15 @@ class LivenessChecker:
             return self._jits[key]
         K = self.K
 
-        def step(rows_flat, n):
+        @spans.staged("live_table")
+        def ptt_live_table(rows_flat, n):
             kc = self._keys_of_rows(rows_flat, cap)
             live = jnp.arange(cap, dtype=jnp.int32) < n
             kc = tuple(jnp.where(live, c, SENTINEL) for c in kc)
             gid = jnp.arange(cap, dtype=jnp.uint32)
             return lax.sort((*kc, gid), num_keys=K, is_stable=False)
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_live_table)
         self._jits[key] = fn
         return fn
 
@@ -450,7 +464,8 @@ class LivenessChecker:
         W = layout.W
         F = self.F
 
-        def step(rows_flat, n):
+        @spans.staged("live_goal")
+        def ptt_live_goal(rows_flat, n):
             def chunk(c, _):
                 rows = lax.dynamic_slice(
                     rows_flat, (c * F * W,), (F * W,)
@@ -465,7 +480,7 @@ class LivenessChecker:
             )
             return gs.reshape(cap)
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_live_goal)
         self._jits[key] = fn
         return fn
 
@@ -505,8 +520,8 @@ class LivenessChecker:
         NQ = SF * A
         K = self.K
 
-        def one_chunk(rows_flat, off, n_live, targs):
-            tcols, tg = targs[:K], targs[K]
+        @spans.staged("sweep_expand")
+        def expand(rows_flat, off, n_live):
             rows = lax.dynamic_slice(
                 rows_flat, (off * W,), (SF * W,)
             ).reshape(SF, W)
@@ -517,14 +532,20 @@ class LivenessChecker:
             sp = jax.vmap(jax.vmap(layout.pack))(succ).reshape(NQ, W)
             qc = self.keys.make(sp)
             vq = valid.reshape(NQ)
-            qc = tuple(jnp.where(vq, c, SENTINEL) for c in qc)
+            return tuple(jnp.where(vq, c, SENTINEL) for c in qc), vq
+
+        @spans.staged("sweep_join")
+        def merge(tcols, tg, qc):
             qpay = jnp.arange(NQ, dtype=jnp.uint32) | TAG
             cols = tuple(
                 jnp.concatenate([t, q]) for t, q in zip(tcols, qc)
             )
             pay = jnp.concatenate([tg, qpay])
             out = lax.sort((*cols, pay), num_keys=K + 1, is_stable=False)
-            scols, sp_ = out[:K], out[K]
+            return out[:K], out[K]
+
+        @spans.staged("sweep_prop")
+        def propagate(scols, sp_):
             # carried gid: table rows expose their gid; query rows start
             # unknown (-1) and take it from the nearest preceding
             # equal-key row via log-shift propagation
@@ -555,6 +576,10 @@ class LivenessChecker:
                     same = same & (pk == c)
                 gid = jnp.where((gid < 0) & same, pg, gid)
                 d <<= 1
+            return gid
+
+        @spans.staged("sweep_join")
+        def unmerge(sp_, gid, vq):
             # back to query order: payload sort; queries (TAG set) sort
             # after every table gid and ascend by lane index
             _, gq = lax.sort(
@@ -562,7 +587,10 @@ class LivenessChecker:
                 num_keys=1, is_stable=False,
             )
             dst = lax.bitcast_convert_type(gq[cap:], jnp.int32)
-            dst = jnp.where(vq, jnp.where(dst < 0, -2, dst), -1)
+            return jnp.where(vq, jnp.where(dst < 0, -2, dst), -1)
+
+        @spans.staged("sweep_compact")
+        def keep_edges(dst, off):
             # device-side compaction: keep valid non-stutter lanes
             # (dst == -2 kept so the host sees incomplete exploration)
             lane = jnp.arange(NQ, dtype=jnp.int32)
@@ -577,7 +605,16 @@ class LivenessChecker:
             n_kept = jnp.sum(keep.astype(jnp.int32))
             return n_kept, idxc, dstc
 
-        def step(rows_flat, off0, n_live, *targs):
+        def one_chunk(rows_flat, off, n_live, targs):
+            qc, vq = expand(rows_flat, off, n_live)
+            scols, sp_ = merge(targs[:K], targs[K], qc)
+            gid = propagate(scols, sp_)
+            return keep_edges(unmerge(sp_, gid, vq), off)
+
+        # the scan's own work (its counter, the stacking of each
+        # chunk's compacted planes) is part of writing the kept edges
+        @spans.staged("sweep_compact")
+        def ptt_sweep(rows_flat, off0, n_live, *targs):
             def body(carry, g):
                 out = one_chunk(
                     rows_flat, off0 + g * SF, n_live, targs
@@ -589,7 +626,7 @@ class LivenessChecker:
             )
             return nk, idxc, dstc
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_sweep)
         self._jits[key] = fn
         return fn
 
@@ -645,9 +682,16 @@ class LivenessChecker:
         # the last group's scan windows may run past the table cap;
         # pad the flat rows so no dynamic_slice can clamp (the overrun
         # chunks' lanes are masked dead and compact to zero kept)
-        rows = self._rows_padded(cap + (G - 1) * SF)
-        targs = self._table_jit(cap)(rows, jnp.int32(n))
-        sweep = self._sweep_jit(cap, G)
+        clock = self._clock
+        with clock.phase("live_table"):
+            rows = self._rows_padded(cap + (G - 1) * SF)
+            targs = self._table_jit(cap)(rows, jnp.int32(n))
+        jitted = self._sweep_jit(cap, G)
+
+        def sweep(*args):
+            with clock.phase("sweep_dispatch"):
+                return jitted(*args)
+
         starts = list(range(0, n, SF))
         src_parts, dst_parts = [], []
         out_deg = np.zeros((n,), np.int64)
@@ -669,6 +713,10 @@ class LivenessChecker:
         # smaller fraction of group time at that size anyway).
         prefetch = G * SF * A <= (1 << 22)
         gstarts = list(range(c0, len(starts), G))
+        self._sweep_n.update(
+            chunks=len(starts) - c0, groups=len(gstarts),
+            query_lanes=(len(starts) - c0) * NQ,
+        )
         pending = (
             [sweep(rows, jnp.int32(starts[gstarts[0]]), jnp.int32(n),
                    *targs)]
@@ -693,90 +741,97 @@ class LivenessChecker:
             # edge planes sliced to the group's max kept prefix — the
             # per-chunk round trip this loop used to pay 3x per chunk
             # now amortizes across the G chunks of the group
-            nk_host = np.asarray(nk_g)
-            self._fetch_n += 1
-            last = min(g0 + G, len(starts))
-            kmax = int(nk_host[: last - g0].max()) if last > g0 else 0
-            if kmax:
-                idx_all = np.asarray(idx_g[:, :kmax])
-                dst_all = np.asarray(dst_g[:, :kmax])
-            for i in range(g0, last):
-                start = starts[i]
-                # deterministic fault site: sweep chunk i+1 is about
-                # to be consumed (kill/sigterm fire inside poll; an
-                # injected oom raises — the sweep has no
-                # degraded-capacity rebuild)
-                kinds = faults.poll("sweep", i + 1)
-                if "oom" in kinds:
-                    raise faults.oom_error("sweep", i + 1)
-                k = int(nk_host[i - g0])
-                if k:
-                    idx = idx_all[i - g0, :k].astype(np.int64)
-                    dst = dst_all[i - g0, :k].view(np.int32).astype(
-                        np.int64
+            with clock.phase("sweep_fetch"):
+                nk_host = np.asarray(nk_g)
+                self._fetch_n += 1
+                last = min(g0 + G, len(starts))
+                kmax = int(nk_host[: last - g0].max()) if last > g0 else 0
+                self._sweep_n["d2h_bytes"] += nk_host.nbytes
+                if kmax:
+                    idx_all = np.asarray(idx_g[:, :kmax])
+                    dst_all = np.asarray(dst_g[:, :kmax])
+                    self._sweep_n["d2h_bytes"] += (
+                        idx_all.nbytes + dst_all.nbytes
                     )
-                    if (dst == -2).any():
-                        raise RuntimeError(
-                            "edge sweep could not resolve a successor "
-                            "gid: either BFS exploration was "
-                            "incomplete, or one state has more than "
-                            f"{self._run_cover} equal-key predecessors "
-                            "inside a single sweep chunk — shrink "
-                            "sweep_chunk or raise max_run "
-                            f"(currently {self.max_run})"
+            with clock.phase("sweep_account"):
+                for i in range(g0, last):
+                    start = starts[i]
+                    # deterministic fault site: sweep chunk i+1 is about
+                    # to be consumed (kill/sigterm fire inside poll; an
+                    # injected oom raises — the sweep has no
+                    # degraded-capacity rebuild)
+                    kinds = faults.poll("sweep", i + 1)
+                    if "oom" in kinds:
+                        raise faults.oom_error("sweep", i + 1)
+                    k = int(nk_host[i - g0])
+                    if k:
+                        idx = idx_all[i - g0, :k].astype(np.int64)
+                        dst = dst_all[i - g0, :k].view(np.int32).astype(
+                            np.int64
                         )
-                    uu = start + idx // A
-                    src_parts.append(uu)
-                    dst_parts.append(dst)
-                    np.add.at(out_deg, uu, 1)
-                    n_edges += k
-                # progress for the heartbeat (zero extra device syncs:
-                # the group planes were already materialized above) +
-                # the stream record
-                swept = min(start + SF, n)
-                self._work_sweep["sort_lanes"] += chunk_sort
-                self._work_sweep["prop_lanes"] += chunk_prop
-                self._work_sweep["prop_passes"] += passes
-                self._work_sweep["compact_elems"] += NQ
-                self._snap.update(
-                    distinct_states=n, level=i + 1, generated=n_edges
-                )
-                self.tel.emit(
-                    "sweep",
-                    chunk=i + 1,
-                    chunks=len(starts),
-                    swept=swept,
-                    edges=n_edges,
-                    group=G,
-                    wall_s=round(time.time() - self._t0, 3),
-                    # cumulative sweep work units (v7)
-                    sort_lanes=self._work_sweep["sort_lanes"],
-                    prop_lanes=self._work_sweep["prop_lanes"],
-                    prop_passes=self._work_sweep["prop_passes"],
-                    compact_elems=self._work_sweep["compact_elems"],
-                )
-                done = i + 1 >= len(starts)
-                preempt = (
-                    self._watcher is not None
-                    and self._watcher.requested
-                )
-                if self.checkpoint_path and not done and (
-                    preempt
-                    or (i + 1 - c0) % self.checkpoint_every == 0
-                ):
-                    self._save_sweep_frame(
-                        n, src_parts, dst_parts, out_deg, i + 1
+                        if (dst == -2).any():
+                            raise RuntimeError(
+                                "edge sweep could not resolve a successor "
+                                "gid: either BFS exploration was "
+                                "incomplete, or one state has more than "
+                                f"{self._run_cover} equal-key predecessors "
+                                "inside a single sweep chunk — shrink "
+                                "sweep_chunk or raise max_run "
+                                f"(currently {self.max_run})"
+                            )
+                        uu = start + idx // A
+                        src_parts.append(uu)
+                        dst_parts.append(dst)
+                        np.add.at(out_deg, uu, 1)
+                        n_edges += k
+                    # progress for the heartbeat (zero extra device syncs:
+                    # the group planes were already materialized above) +
+                    # the stream record
+                    swept = min(start + SF, n)
+                    self._work_sweep["sort_lanes"] += chunk_sort
+                    self._work_sweep["prop_lanes"] += chunk_prop
+                    self._work_sweep["prop_passes"] += passes
+                    self._work_sweep["compact_elems"] += NQ
+                    self._snap.update(
+                        distinct_states=n, level=i + 1, generated=n_edges
                     )
-                    if preempt:
-                        raise _Preempted(n, "sweep")
-        src = (
-            np.concatenate(src_parts) if src_parts
-            else np.zeros(0, np.int64)
-        )
-        dst = (
-            np.concatenate(dst_parts) if dst_parts
-            else np.zeros(0, np.int64)
-        )
+                    self.tel.emit(
+                        "sweep",
+                        chunk=i + 1,
+                        chunks=len(starts),
+                        swept=swept,
+                        edges=n_edges,
+                        group=G,
+                        wall_s=round(time.time() - self._t0, 3),
+                        # cumulative sweep work units (v7)
+                        sort_lanes=self._work_sweep["sort_lanes"],
+                        prop_lanes=self._work_sweep["prop_lanes"],
+                        prop_passes=self._work_sweep["prop_passes"],
+                        compact_elems=self._work_sweep["compact_elems"],
+                    )
+                    done = i + 1 >= len(starts)
+                    preempt = (
+                        self._watcher is not None
+                        and self._watcher.requested
+                    )
+                    if self.checkpoint_path and not done and (
+                        preempt
+                        or (i + 1 - c0) % self.checkpoint_every == 0
+                    ):
+                        self._save_sweep_frame(
+                            n, src_parts, dst_parts, out_deg, i + 1
+                        )
+                        if preempt:
+                            raise _Preempted(n, "sweep")
+        with clock.phase("sweep_account"):
+            src = (
+                np.concatenate(src_parts) if src_parts
+                else np.zeros(0, np.int64)
+            )
+            dst = (
+                np.concatenate(dst_parts) if dst_parts
+                else np.zeros(0, np.int64)
+            )
         self._edge_cache = (src, dst, out_deg)
         return self._edge_cache
 
@@ -812,6 +867,7 @@ class LivenessChecker:
             "n": np.int64(n),
             "n_init": np.int64(n_init),
             "diameter": np.int64(self._diameter),
+            "level_sizes": np.asarray(self._level_sizes or [], np.int64),
             "next_chunk": np.int64(next_chunk),
             "rows": np.asarray(self._rows_flat[: n * W]),
             "src": (
@@ -870,6 +926,8 @@ class LivenessChecker:
         n = int(d["n"])
         self._explored = (n, int(d["n_init"]))
         self._diameter = int(d["diameter"])
+        if "level_sizes" in d:  # a frame from before PR 36 has none
+            self._level_sizes = [int(x) for x in d["level_sizes"]]
         self._rows_flat = jnp.asarray(np.asarray(d["rows"], np.uint32))
         src = np.asarray(d["src"], np.int64)
         dst = np.asarray(d["dst"], np.int64)
@@ -918,11 +976,19 @@ class LivenessChecker:
         re-exploration); an exploration-phase frame resumes the inner
         engine's BFS first.  SIGTERM/SIGINT during the run exit
         resumably with ``stop_reason="preempted"``."""
-        t0 = time.time()
-        self._t0 = t0
-        rid = obs.new_run_id()
+        # this run's exclusive host phases (spans.LIVE_PHASES) and the
+        # compile meter's reading before it, as DeviceChecker.run()
+        # keeps its own; ptt:run is the container the phases lie in
+        clock = self._clock = spans.PhaseClock(obs.new_run_id())
+        self._jit0 = spans.compile_meter().snapshot()
+        with spans.span("run", run_id=clock.run_id):
+            return self._run(resume)
+
+    def _run(self, resume: bool) -> LivenessResult:
+        self._t0 = time.time()
+        rid = self._clock.run_id
         self.tel = obs.as_telemetry(self._telemetry_arg, run_id=rid)
-        self._run_id = self.tel.run_id or rid
+        self._run_id = self._clock.run_id = self.tel.run_id or rid
         self._resume_meta = {}
         self._snap = {"distinct_states": 0}
         self._fetch_n = 0
@@ -938,6 +1004,12 @@ class LivenessChecker:
             "sort_lanes": 0, "prop_lanes": 0, "prop_passes": 0,
             "compact_elems": 0,
         }
+        # what this run's sweep did and fetched (0 where the edge list
+        # came from an earlier goal's run), and its analysis
+        self._sweep_n = {
+            "chunks": 0, "groups": 0, "query_lanes": 0, "d2h_bytes": 0,
+        }
+        self._peel_rounds = 0
         # a crash mid-frame-write can leave a dead tmp file behind
         ckpt.cleanup_stale_tmp(self.checkpoint_path)
         # crash breadcrumbs FIRST: fault events flush before the fault
@@ -999,35 +1071,8 @@ class LivenessChecker:
                         truncated=True,
                         stop_reason="preempted",
                     )
-                if any(self._work_sweep.values()):
-                    # the sweep's per-stage work totals, machine-
-                    # readable for the attribution layer (r14)
-                    self.tel.emit(
-                        "attribution",
-                        stages={
-                            f"sweep_{k}": int(v)
-                            for k, v in self._work_sweep.items()
-                        },
-                    )
-                self.tel.emit(
-                    "result",
-                    distinct_states=lres.distinct_states,
-                    diameter=self._diameter,
-                    wall_s=round(time.time() - t0, 3),
-                    truncated=lres.truncated,
-                    stop_reason=lres.stop_reason,
-                    holds=None if lres.truncated else lres.holds,
-                    reason=lres.reason,
-                    goal=self.goal_name,
-                    fairness=self.fairness,
-                    ckpt_frames=self._ckpt_frames,
-                    ckpt_retries=self._ckpt_retries,
-                    **{
-                        f"work_sweep_{k}": int(v)
-                        for k, v in self._work_sweep.items()
-                        if v
-                    },
-                )
+                with self._clock.phase("result"):
+                    self._emit_result(lres)
                 return lres
         except BaseException as e:
             self.tel.emit("error", error=repr(e)[:300])
@@ -1042,6 +1087,65 @@ class LivenessChecker:
             if obs.owns_stream(self._telemetry_arg):
                 self.tel.close()
             self.tel = obs.NULL
+
+    def _emit_result(self, lres: LivenessResult):
+        if any(self._work_sweep.values()):
+            # the sweep's per-stage work totals, machine-readable for
+            # the attribution layer (r14)
+            self.tel.emit(
+                "attribution",
+                stages={
+                    f"sweep_{k}": int(v)
+                    for k, v in self._work_sweep.items()
+                },
+            )
+        # stats: the explorer's own (its phases, growth and fpset
+        # counters, as its result event carries them) and on top the
+        # liveness run's phases, which sum with host_unaccounted_s to
+        # this run's wall, what the sweep and the analysis counted, and
+        # the compile meter over the whole run (obs/spans.py)
+        g = lres.graph or {}
+        stats = dict(
+            self._checker.last_stats,
+            distinct_states=lres.distinct_states,
+            sweep_chunks=self._sweep_n["chunks"],
+            sweep_groups=self._sweep_n["groups"],
+            sweep_edges=g.get("edges"),
+            sweep_query_lanes=self._sweep_n["query_lanes"],
+            sweep_sort_lanes=self._work_sweep["sort_lanes"],
+            sweep_prop_lanes=self._work_sweep["prop_lanes"],
+            sweep_d2h_bytes=self._sweep_n["d2h_bytes"],
+            live_goal_states=g.get("goal_states"),
+            live_dead_ends=g.get("dead_ends"),
+            analyse_peel_rounds=self._peel_rounds,
+            fp_collision_prob=lres.fp_collision_prob,
+            **spans.compile_meter().since(self._jit0),
+        )
+        stats.update(self._clock.host_seconds(spans.LIVE_PHASES))
+        self.tel.emit(
+            "result",
+            distinct_states=lres.distinct_states,
+            diameter=self._diameter,
+            wall_s=round(self._clock.elapsed(), 3),
+            truncated=lres.truncated,
+            stop_reason=lres.stop_reason,
+            holds=None if lres.truncated else lres.holds,
+            reason=lres.reason,
+            goal=self.goal_name,
+            fairness=self.fairness,
+            ckpt_frames=self._ckpt_frames,
+            ckpt_retries=self._ckpt_retries,
+            **{
+                f"work_sweep_{k}": int(v)
+                for k, v in self._work_sweep.items()
+                if v
+            },
+            graph=lres.graph,
+            stats={
+                k: (round(v, 4) if isinstance(v, float) else v)
+                for k, v in stats.items()
+            },
+        )
 
     def _emit_header(self, resume: bool):
         if not self.tel.enabled:
@@ -1083,7 +1187,9 @@ class LivenessChecker:
         self.tel.emit("run_header", **f)
 
     def _check(self) -> LivenessResult:
-        n, n_init = self._explore()
+        clock = self._clock
+        with clock.phase("explore"):
+            n, n_init = self._explore()
         if self._watcher is not None and self._watcher.requested:
             # preemption landed during/after exploration: the inner
             # engine already wrote its frame on the way out — exit
@@ -1092,32 +1198,78 @@ class LivenessChecker:
         if self._hb is not None:
             self._snap["distinct_states"] = n
             self._hb.start()
-        cap = self._table_cap(n)
-        rows = self._rows_padded(cap)
-        goal = np.asarray(self._goal_jit(cap)(rows, jnp.int32(n)))[:n]
+        with clock.phase("live_goal"):
+            cap = self._table_cap(n)
+            rows = self._rows_padded(cap)
+            goal = np.asarray(
+                self._goal_jit(cap)(rows, jnp.int32(n))
+            )[:n]
         cprob = self.keys.collision_prob(n)
 
-        if self.fairness == "none":
-            bad = np.nonzero(~goal[:n_init])[0]
-            if len(bad):
-                return LivenessResult(
-                    False,
-                    "stuttering counterexample: initial state "
-                    f"#{int(bad[0])} may stutter forever without reaching "
-                    "the goal (no fairness assumed)",
-                    n,
-                    lasso_prefix=[int(bad[0])],
-                    lasso_cycle=[int(bad[0])],
-                    fp_collision_prob=cprob,
+        out_deg = None
+        if self.fairness == "wf_next":
+            # materialize the edge list (cached across goals)
+            src, dst, out_deg = self._edges(n)
+        with clock.phase("analyse"):
+            if out_deg is None:
+                lres = self._unfair(n, n_init, goal, cprob)
+            else:
+                lres = self._fair(
+                    n, n_init, goal, src, dst, out_deg, cprob
                 )
+            lres.graph = self._graph_summary(n, goal, out_deg)
+        return lres
+
+    def _graph_summary(self, n, goal, out_deg) -> dict:
+        """The graph the verdict was computed on: states, goal states
+        and, where the edge sweep ran, ``<Next>_vars`` edges and dead
+        ends (not-goal states with no state-changing successor), in all
+        and by BFS level.  The sweep walks gids in discovery order, so a
+        level is a gid range."""
+        out = {
+            "states": int(n), "levels": int(self._diameter),
+            "goal_states": int(goal.sum()),
+            "edges": None, "dead_ends": None, "by_level": None,
+        }
+        cols = {"goal": goal.astype(np.int64)}
+        if out_deg is not None:
+            dead = (out_deg == 0) & ~goal
+            out.update(
+                edges=int(out_deg.sum()), dead_ends=int(dead.sum())
+            )
+            cols.update(edges=out_deg, dead_ends=dead.astype(np.int64))
+        sizes = self._level_sizes
+        if sizes and sum(sizes) == n:
+            at = np.cumsum([0] + list(sizes[:-1]))
+            out["by_level"] = dict(
+                {k: np.add.reduceat(v, at).tolist() for k, v in cols.items()},
+                size=[int(x) for x in sizes],
+            )
+        return out
+
+    @staticmethod
+    def _unfair(n, n_init, goal, cprob) -> LivenessResult:
+        bad = np.nonzero(~goal[:n_init])[0]
+        if len(bad):
             return LivenessResult(
-                True, "every initial state satisfies the goal", n,
+                False,
+                "stuttering counterexample: initial state "
+                f"#{int(bad[0])} may stutter forever without reaching "
+                "the goal (no fairness assumed)",
+                n,
+                lasso_prefix=[int(bad[0])],
+                lasso_cycle=[int(bad[0])],
                 fp_collision_prob=cprob,
             )
+        return LivenessResult(
+            True, "every initial state satisfies the goal", n,
+            fp_collision_prob=cprob,
+        )
 
-        # ---- wf_next: materialize the edge list (cached across goals) ----
-        src, dst, out_deg = self._edges(n)
-
+    def _fair(
+        self, n, n_init, goal, src, dst, out_deg, cprob
+    ) -> LivenessResult:
+        """The fair-cycle search under ``WF_vars(Next)`` on the host."""
         # restrict to not-goal -> not-goal edges; CSR over sources
         keep = ~goal[src] & ~goal[dst]
         rsrc, rdst = src[keep], dst[keep]
@@ -1179,6 +1331,7 @@ class LivenessChecker:
         alive = in_r.copy()
         wave = r_nodes[indeg[r_nodes] == 0]
         while len(wave):
+            self._peel_rounds += 1
             alive[wave] = False
             cnt = starts[wave + 1] - starts[wave]
             total = int(cnt.sum())
@@ -1208,6 +1361,7 @@ class LivenessChecker:
             bstarts = np.searchsorted(bdst, np.arange(n + 1))
             wave = cyc_nodes[odeg[cyc_nodes] == 0]
             while len(wave):
+                self._peel_rounds += 1
                 alive[wave] = False
                 cnt = bstarts[wave + 1] - bstarts[wave]
                 total = int(cnt.sum())

@@ -10,7 +10,10 @@ variable, exporter or telemetry event of their own:
   bookkeeping, the packed stats vector and the frontier-window shift),
   ``ptt.rehash`` (table growth), ``ptt.grow`` (row-store and log
   growth), ``ptt.seed`` (seed merge/write) and ``ptt.init``
-  (initial-state generation).  A scope is HLO metadata
+  (initial-state generation), and in the liveness run
+  (``engine/liveness.py``) ``ptt.live_table``, ``ptt.live_goal``,
+  ``ptt.sweep_expand``, ``ptt.sweep_join``, ``ptt.sweep_prop`` and
+  ``ptt.sweep_compact``.  A scope is HLO metadata
   only: it lands in every operation's ``op_name`` path, which a device
   trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
   metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of
@@ -55,6 +58,15 @@ SCOPE_PREFIX = "ptt."
 PHASES = (
     "init", "seed_load", "grow", "dispatch", "fetch", "account", "ckpt",
     "spill", "trace_walk", "result",
+)
+
+# the exclusive phases of one LivenessChecker.run(): ``explore`` holds
+# the inner DeviceChecker.run() (whose own phases stay inside it), the
+# rest is the edge sweep and the host's graph analysis; each lands in
+# the liveness result's stats as host_<phase>_s
+LIVE_PHASES = (
+    "explore", "live_table", "live_goal", "sweep_dispatch", "sweep_fetch",
+    "sweep_account", "analyse", "result",
 )
 
 
@@ -212,14 +224,19 @@ class PhaseClock:
         self._settle_grow(level)
         self._boundary_level = int(level)
 
-    def stats(self) -> Dict[str, float]:
-        """``host_<phase>_s`` for every phase (0.0 for one never
-        entered), ``host_unaccounted_s`` = the run's wall so far less
-        their sum, the longest level stretch and the longest stay in
-        ``grow``."""
+    def host_seconds(self, phases=PHASES) -> Dict[str, float]:
+        """``host_<phase>_s`` for every phase of ``phases`` (0.0 for one
+        never entered) and ``host_unaccounted_s`` = the run's wall so
+        far less their sum."""
         wall = self.elapsed()
-        out = {f"host_{p}_s": self.seconds_of(p) for p in PHASES}
+        out = {f"host_{p}_s": self.seconds_of(p) for p in phases}
         out["host_unaccounted_s"] = wall - sum(out.values())
+        return out
+
+    def stats(self) -> Dict[str, float]:
+        """``host_seconds()`` of a ``DeviceChecker.run()``, the longest
+        level stretch and the longest stay in ``grow``."""
+        out = self.host_seconds()
         out["level_wall_max_s"] = self.level_wall_max_s
         out["level_wall_max_at"] = self.level_wall_max_at
         self._settle_grow(self._boundary_level + 1)
