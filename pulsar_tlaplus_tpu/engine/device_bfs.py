@@ -97,8 +97,10 @@ BIG = bodies.BIG
 # host-side 64-bit view); max_probe_rounds is the worst flush's probe
 # depth (a running max, not a sum).  Pre-widening checkpoint frames
 # carry the 3- or 5-wide prefix and restore zero-padded.  Shared with
-# the sharded engine via ops/fpset.py (r9).
-FPM_N = fpset.FPM_N
+# the sharded engine via ops/fpset.py (r9); this engine's vector also
+# carries the probe rounds run at each step of the ladder in the
+# ``fpset.FPM_STEPS`` words behind those (PR 37, ``fpset_step_rounds``).
+FPM_N = fpset.FPM_WIDE_N
 
 # In-kernel work-unit vector (round 14, fused-era cost attribution):
 # the level megakernel accumulates per-stage work units — live expand
@@ -1198,7 +1200,7 @@ class DeviceChecker:
             kcols = keyspec.make(rows)
             lane = jnp.arange(NCs, dtype=jnp.int32)
             valid = lane < n_valid
-            is_new, tc2, n_failed, rounds, lane_rounds = (
+            is_new, tc2, n_failed, rounds, lane_rounds, step_rounds = (
                 fpset.lookup_or_insert(
                     tc, kcols, valid,
                     dense_rounds=self.fps_dense,
@@ -1218,6 +1220,7 @@ class DeviceChecker:
             fpm = fpset.fpm_update(
                 fpm, rounds, n_failed,
                 jnp.sum(valid.astype(jnp.int32)), lane_rounds,
+                step_rounds,
             )
             return (
                 *tc2,
@@ -4120,6 +4123,12 @@ class DeviceChecker:
                     fpset_lanes_presented_per_valid=round(
                         lr / vl, 4
                     ) if vl else None,
+                    # the rounds by the schedule's entry [dense,
+                    # *stages]: they sum to fpset_probe_rounds, and a
+                    # 0 names a step no flush of this run entered
+                    fpset_step_rounds=fpset.fpm_step_rounds(
+                        self._last_fpm, self.fps_stages
+                    ),
                 )
         # fusion telemetry (r13): this run's total dispatches per BFS
         # level — the regression-gate signal (steady-state fused levels

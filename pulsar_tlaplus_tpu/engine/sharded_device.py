@@ -389,10 +389,15 @@ class ShardedDeviceChecker:
         # table carries TCAP = 2 * VCAP slots so the nk_bound <= VCAP
         # invariant IS the load-factor <= 1/2 contract.
         # fpset probe schedule: ctor params > PTT_FPSET_SCHEDULE env >
-        # ops/fpset.py defaults (sweepable on the real chip against
-        # the fpset_max_probe_rounds telemetry signal)
+        # the two-step ladder, by value (sweepable on the real chip
+        # against the fpset_max_probe_rounds telemetry signal).  Not
+        # the single-chip engine's halving ladder (PR 37): this
+        # engine's programs are traced and lowered again every check,
+        # so every step is paid a check, for a flush of 98,304 lanes
+        # that holds some 1,852 valid ones (ROADMAP S5, S9 (a))
         self.fps_dense, self.fps_stages = fpset.resolve_schedule(
-            fpset_dense_rounds, fpset_stages
+            fpset_dense_rounds, fpset_stages,
+            default_stages=fpset.STAGES_TWO_STEP,
         )
         self.VCAP = self._round_cap(visited_cap)
         self.TCAP = 2 * self.VCAP
@@ -862,7 +867,7 @@ class ShardedDeviceChecker:
             lanei = jnp.arange(ACAP, dtype=jnp.int32)
             amask = lanei < n_acc
             valid = amask & ~fpset.all_sentinel(ak)
-            is_new, vk2, n_failed, rounds, lane_rounds = (
+            is_new, vk2, n_failed, rounds, lane_rounds, _ = (
                 fpset.lookup_or_insert(
                     vk, ak, valid,
                     dense_rounds=self.fps_dense,

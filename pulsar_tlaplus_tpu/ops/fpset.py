@@ -29,12 +29,27 @@ probing design generalised to the device hot path:
   therefore walks a ladder of narrowing buffers — all ``nq`` lanes,
   then 1/div of them per stage — compacting the surviving pending
   lanes (the `compact_by_flag` idiom) at each step, and a step ends
-  as soon as what is pending fits the next one (PR 28).  The round
-  counts of the schedule are CEILINGS: ``dense_rounds`` and each
-  stage's limit bound how long a step may wait for its survivors to
-  fit.  At load <= 1/2 the expected pending fraction after r rounds
-  is ~2^-r, so at a ceiling the static stage capacities carry 2-8x
-  safety margins; a lane that overflows a stage there is counted in
+  as soon as what is pending fits the next one (PR 28).  The default
+  ladder narrows by HALVES between 1/4 and 1/64 of the batch (PR 37),
+  so a round is presented no more than about twice the lanes that
+  were pending when its step began, whatever share of the batch is
+  valid: a ``cli check`` flush holds one valid lane in ten, which the
+  two-step ladder before (1/4, then 1/64: a 16x gap) presented 6.10
+  times over and the halves 3.06 (``STAGES`` below, with the chip's
+  readings; ``STAGES_TWO_STEP`` is kept for the sharded engine and
+  the rehash).  A step is one order-preserving compaction of what is
+  pending, one probe loop and one scatter of its winners' flags: its
+  price is a few bandwidth-bound passes over the step before it, and
+  a probe loop more to compile in every program that holds a flush,
+  which is why a buffer of over 2^20 lanes narrows by quarters
+  (``QUARTER_ABOVE``; :func:`ladder_steps` is the ladder of a width).
+  The round counts of the schedule are CEILINGS: ``dense_rounds`` and
+  each stage's limit bound how long a step may wait for its survivors
+  to fit.  At load <= 1/2 the expected pending fraction after r
+  rounds is ~2^-r, so at a ceiling the static stage capacities carry
+  2-8x safety margins (the dense step's 4 rounds against 1/4; every
+  later step waits to round 16 or more for a half or a quarter of
+  itself); a lane that overflows a stage there is counted in
   ``n_failed`` and the engine fails LOUDLY (the same fail-stop
   contract as `ops/hashtable.py`), never a silent drop.  Every
   pending lane probes slot (h + r(r+1)/2) at the same global round r
@@ -49,8 +64,8 @@ probing design generalised to the device hot path:
   `fori_loop` over chunks of ``REHASH_CHUNK`` old slots; a chunk's
   occupied slots are packed to the front (one ``compact_by_flag``;
   the buffer is 5/8 of the chunk, the load contract's 1/2 and a
-  margin) and go through ``lookup_or_insert``'s ladder at the
-  module's schedule — one dispatch, no host staging, and the
+  margin) and go through ``lookup_or_insert``'s two-step ladder
+  (``STAGES_TWO_STEP``) — one dispatch, no host staging, and the
   transient is old + new table + a few columns of one chunk.  A
   rehash so costs by the key it moves: an empty slot is read once and
   never presented to the new table (a parked lane costs MORE than a
@@ -107,6 +122,19 @@ from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, _fmix
 # sharded_device.
 FPM_N = 8
 
+# The single-chip engine's vector carries ``FPM_STEPS`` more words
+# behind those (PR 37): the probe rounds run at each step of the
+# ladder, summed over flushes and indexed by the SCHEDULE's entry
+# ([dense, stage 1, stage 2, ...]; a stage that was not built, having
+# no shrink to offer, reads 0 and its rounds count where they ran; a
+# schedule with more entries sums its tail into the last word).  They
+# sum to ``probe_rounds``, and a step no traffic enters shows as a 0
+# that can be taken out of the schedule (``fpset_step_rounds``).  A
+# vector of ``FPM_N`` words (the sharded engine's, an older checkpoint
+# frame's) is updated as it always was.
+FPM_STEPS = 8
+FPM_WIDE_N = FPM_N + FPM_STEPS
+
 # length of the host-side LOGICAL view: [flushes, probe_rounds,
 # failures, valid_lanes (64-bit), max_probe_rounds, lane_rounds
 # (64-bit)]
@@ -130,28 +158,35 @@ def u64(lo_word, hi_word):
     return (hi_word << 32) | np.int64(np.uint32(lo_word & 0xFFFFFFFF))
 
 
-def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds):
+def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds, step_rounds=()):
     """One flush's device-side metrics update (jit-traceable).
 
     ``fpm`` is the int32[FPM_N] vector; ``n_valid`` (int32, < 2^31 per
     flush) and ``lane_rounds`` (uint32) accumulate into their LO words
     with uint32 wraparound and the carry lands in the HI words, so
     1B-state runs report honest duplicate ratios instead of a wrapped
-    counter."""
+    counter.  ``step_rounds`` (``lookup_or_insert``'s, one int32 a
+    schedule entry) lands in the words behind ``FPM_N`` of a vector
+    that has them."""
     valid_lo, valid_hi = add_u32(fpm[3], fpm[5], n_valid)
     lanes_lo, lanes_hi = add_u32(fpm[6], fpm[7], lane_rounds)
-    return jnp.stack(
-        [
-            fpm[0] + 1,
-            fpm[1] + rounds,
-            fpm[2] + n_failed,
-            valid_lo,
-            jnp.maximum(fpm[4], rounds),
-            valid_hi,
-            lanes_lo,
-            lanes_hi,
-        ]
-    )
+    words = [
+        fpm[0] + 1,
+        fpm[1] + rounds,
+        fpm[2] + n_failed,
+        valid_lo,
+        jnp.maximum(fpm[4], rounds),
+        valid_hi,
+        lanes_lo,
+        lanes_hi,
+    ]
+    n_steps = fpm.shape[0] - FPM_N
+    if n_steps > 0:
+        steps = list(step_rounds)
+        steps = steps[: n_steps - 1] + [sum(steps[n_steps - 1:])]
+        steps += [0] * (n_steps - len(steps))
+        words += [fpm[FPM_N + i] + d for i, d in enumerate(steps)]
+    return jnp.stack(words)
 
 
 def fpm_logical(vec):
@@ -171,6 +206,14 @@ def fpm_logical(vec):
         [v[0], v[1], v[2], u64(v[3], v[5]), v[4], u64(v[6], v[7])],
         np.int64,
     )
+
+
+def fpm_step_rounds(vec, stages):
+    """``fpset_step_rounds``: the rounds run at each entry of the
+    schedule ``[dense, *stages]``, from a fetched ``FPM_WIDE_N``
+    vector."""
+    n = min(1 + len(stages), FPM_STEPS)
+    return [int(x) for x in vec[FPM_N: FPM_N + n]]
 
 # Width of the zero-sync WORK-UNIT vector (r14, fused-era cost
 # attribution): the level megakernel accumulates per-stage work units
@@ -253,26 +296,48 @@ MAX_PROBES = 64
 # ~2^-rounds, well under 1/divisor (see module docstring).  The
 # real-chip signal is the zero-sync ``fpset_lane_rounds`` counter over
 # ``fpset_valid_lanes`` (lanes presented per valid lane; with
-# ``fpset_max_probe_rounds``, docs/observability.md), and the schedule
-# is sweepable without code edits: engine/FPSet ctor params, or the
-# ``PTT_FPSET_SCHEDULE`` env override parsed by
-# :func:`resolve_schedule` (round 10).  The last stage is 1/64 wide
-# (1/16 before PR 28): with hand-over on the pending count the tail's
-# ~8 rounds run there as soon as under 1.6% of the lanes are pending,
-# which on the flagship level is after round 1 (7.47 s against 8.47 s
-# a level; my chip run, PR 28).  A third stage reads another second
-# better there and is not added: every stage is one more traced loop
-# and compaction in each flush program a ``cli check`` re-traces
-# (+0.5-0.65 s a check, PERF.md §6 "PR 28").
+# ``fpset_max_probe_rounds`` and, a step, ``fpset_step_rounds``:
+# docs/observability.md), and the schedule is sweepable without code
+# edits: engine/FPSet ctor params, or the ``PTT_FPSET_SCHEDULE`` env
+# override parsed by :func:`resolve_schedule` (round 10).
+#
+# ``STAGES`` narrows by HALVES from 1/4 to 1/64 (PR 37): a step hands
+# over once what is pending fits the next, so with halving steps a
+# round is presented at most about twice the lanes that were pending
+# when its step began.  With the 16x gap of the two-step ladder a
+# ``cli check`` flush (65,536 lanes, one in ten valid: about 7,400
+# pending) sat in the 16,384-lane buffer for the 2-3 rounds it takes
+# to fall under 1,024: 6.10 lanes presented a valid one over the
+# 9,445,152-state binding, 3.06 with the halves (counted; PERF.md §6
+# "PR 37"; on a v5e a flush of that shape against 2^25 slots is 16.3 ms
+# by the two-step ladder, 13.4 with one step added at 1/16 and 8.5 by
+# the halves; the compactions as one rolled loop each: 8.7; my chip
+# runs, PR 37, ``scripts/profile.py ladder``).  A step with no shrink
+# to be had (``MIN_STAGE``) is not built, so a narrow batch pays
+# nothing for the steps it cannot use: 1/256 of a ``cli check`` flush
+# is under the floor (a true 1/256 step exists from 2^18 lanes up).
+# A buffer WIDER than ``QUARTER_ABOVE`` lanes narrows by quarters, not
+# halves (the flagship level's 26,738,688 lanes walk 1/4, 1/16, 1/64,
+# 1/256): what a step costs the device scales with the batch and what
+# it costs the compiler does not (``QUARTER_ABOVE`` below).
+#
+# ``STAGES_TWO_STEP`` is the ladder of PR 28-36, kept BY VALUE for the
+# two callers whose programs are built anew a check or a table size
+# and whose batches gain nothing from more steps: the sharded engine's
+# flush (``engine/sharded_device.py``: every step is traced and
+# lowered again a check there) and :func:`rehash_cols` (its chunks are
+# packed to 80% valid before round 0: 2.09 lanes a key).
 DENSE_ROUNDS = 4
-STAGES = ((4, 16), (64, MAX_PROBES))
+STAGES = ((4, 16), (8, 24), (16, 32), (32, 40), (64, 48), (256, MAX_PROBES))
+STAGES_TWO_STEP = ((4, 16), (64, MAX_PROBES))
 
 
 def parse_schedule(spec: str) -> Tuple[int, Tuple[Tuple[int, int], ...]]:
     """Parse a probe-schedule spec ``"DENSE[,DIV:LIMIT]*"`` — e.g. the
-    default is ``"4,4:16,64:64"`` (at most 4 dense rounds, then a
-    1/4-width stage probing to round 16 at the latest and a 1/64-width
-    stage to round 64).
+    default is ``"4,4:16,8:24,16:32,32:40,64:48,256:64"`` (at most 4
+    dense rounds, then a 1/4-width stage probing to round 16 at the
+    latest, halving stages down to 1/64, and a 1/256-width stage to
+    round 64).
     Raises ValueError with the offending token on malformed input."""
     parts = [p.strip() for p in spec.split(",") if p.strip()]
     if not parts:
@@ -312,7 +377,9 @@ def schedule_hint(dense_rounds, stages) -> str:
     dense-only or LIMIT-truncated sweep via ``PTT_FPSET_SCHEDULE`` —
     the truncated probe budget is the likelier culprit, so name it
     instead of blaming visited_cap."""
-    if (int(dense_rounds), tuple(stages)) == (DENSE_ROUNDS, STAGES):
+    if int(dense_rounds) == DENSE_ROUNDS and tuple(stages) in (
+        STAGES, STAGES_TWO_STEP
+    ):
         return (
             "raise visited_cap (the table broke its load-factor "
             "contract)"
@@ -329,11 +396,14 @@ def schedule_hint(dense_rounds, stages) -> str:
 
 
 def resolve_schedule(
-    dense_rounds: Optional[int] = None, stages=None
+    dense_rounds: Optional[int] = None, stages=None,
+    default_stages=STAGES,
 ) -> Tuple[int, Tuple[Tuple[int, int], ...]]:
     """The effective probe schedule: explicit ctor values win, then the
     ``PTT_FPSET_SCHEDULE`` env override (so a real-chip tuning pass can
-    sweep the schedule without code edits), then the module defaults."""
+    sweep the schedule without code edits), then the module defaults
+    (``default_stages``: the caller's own, ``STAGES_TWO_STEP`` for the
+    sharded engine)."""
     env = os.environ.get("PTT_FPSET_SCHEDULE")
     env_dense, env_stages = (
         parse_schedule(env) if env else (None, None)
@@ -341,7 +411,7 @@ def resolve_schedule(
     if dense_rounds is None:
         dense_rounds = env_dense if env_dense is not None else DENSE_ROUNDS
     if stages is None:
-        stages = env_stages if env_stages is not None else STAGES
+        stages = env_stages if env_stages is not None else default_stages
     return int(dense_rounds), tuple(tuple(s) for s in stages)
 # stage-capacity floor: the 1/div shrink is a concentration argument
 # that only holds for large batches (binomial tail at nq/16 expected
@@ -349,6 +419,22 @@ def resolve_schedule(
 # nq below the floor the stages run in place, where overflow is
 # impossible and compaction would save nothing anyway.
 MIN_STAGE = 1 << 10
+# a step is built from a buffer wider than this only if it narrows it
+# to a QUARTER or less (PR 37).  On the device a step's price and its
+# saving both go by the lanes, so the halves pay at any width: on the
+# flagship level's one flush of 26,738,688 lanes they read 3.42 s
+# against the two-step ladder's 4.17 (my chip runs, PR 37).  But a
+# probe loop over millions of lanes is 15-30 s of compiling and 20-40
+# MB of code in EVERY program that holds a flush (this module's
+# ``lookup_or_insert`` alone, compiled for a v5e: 38 s and 70 MB by the
+# two-step ladder, 79 s and 146 MB by the halves, 49 s and 103 MB by
+# quarters; at 65,536 lanes 7.9 s whatever the ladder), and the
+# flagship cell's compiling process went from 195 s to 335 of the 360
+# a run is given; with quarters it is 292 (PERF.md §6 "PR 37").
+# Quarters keep the step that matters there: round 1 runs at 1/16 of
+# the batch instead of 1/4 (3.54 s a flush, 2,754,800 states a second
+# against the two-step ladder's 2,357,800 and the halves' 2,742,900).
+QUARTER_ABOVE = 1 << 20
 
 _NO_LANE = jnp.int32(2**31 - 1)  # claims fill: above every real lane id
 
@@ -492,6 +578,28 @@ def probe_insert(
     return is_new, tcols, (occ_out if has_occ else None), pending, r
 
 
+def ladder_steps(nq: int, dense_rounds: int, stages, max_probes=MAX_PROBES):
+    """The ladder a batch of ``nq`` lanes walks under a schedule, as
+    static ``(width, round ceiling, schedule entry)`` steps.  A stage
+    with no shrink to be had (``MIN_STAGE``), or only a half of a
+    buffer wider than ``QUARTER_ABOVE``, is not built: it just raises
+    the ceiling of the step before it, which probes on in place."""
+    ladder = [(nq, min(dense_rounds, max_probes), 0)]
+    for j, (div, limit) in enumerate(stages, 1):
+        limit = min(limit, max_probes)
+        capi = max(nq // div, min(nq, MIN_STAGE))
+        width, ceiling, entry = ladder[-1]
+        if (
+            capi >= width
+            or limit <= dense_rounds
+            or (width > QUARTER_ABOVE and 4 * capi > width)
+        ):
+            ladder[-1] = (width, max(ceiling, limit), entry)
+        else:
+            ladder.append((capi, limit, j))
+    return ladder
+
+
 def lookup_or_insert(
     tcols: Tuple[jax.Array, ...],
     kcols: Tuple[jax.Array, ...],
@@ -505,36 +613,39 @@ def lookup_or_insert(
     docstring for the why of the stages; ``materialize`` is the
     ladder's compactions', ``ops.compact.compact_by_flag``).
 
-    Returns ``(is_new, tcols', n_failed, rounds, lane_rounds)`` where
+    Returns ``(is_new, tcols', n_failed, rounds, lane_rounds,
+    step_rounds)`` where
     ``is_new`` is in ORIGINAL lane order (exactly one True per distinct
     new key — the minimum valid lane), ``n_failed`` counts lanes
     dropped at a stage overflow or still pending at ``max_probes``
     (callers treat nonzero as a hard error), ``rounds`` is the probe
     rounds consumed (the per-flush probe metric) and ``lane_rounds``
     (uint32) the lanes presented to the table summed over those rounds:
-    each stage's width times the rounds run at it.
+    each stage's width times the rounds run at it; ``step_rounds`` is
+    those rounds by the schedule's entry (a tuple ``[dense, *stages]``
+    long: int32 scalars, and a plain 0 for a stage that was not built).
     """
     nq = kcols[0].shape[0]
     K = len(kcols)
     dense_rounds, stages = resolve_schedule(dense_rounds, stages)
-    # the ladder as static (width, round ceiling) steps; a stage with
-    # no shrink to be had (tiny batches) only raises the ceiling of the
-    # step before it: it probes on in place
-    ladder = [(nq, min(dense_rounds, max_probes))]
-    for div, limit in stages:
-        limit = min(limit, max_probes)
-        capi = max(nq // div, min(nq, MIN_STAGE))
-        width, ceiling = ladder[-1]
-        if capi >= width or limit <= dense_rounds:
-            ladder[-1] = (width, max(ceiling, limit))
-        else:
-            ladder.append((capi, limit))
+    ladder = ladder_steps(nq, dense_rounds, stages, max_probes)
+    # the compactions of the batch and of its quarter (all that the
+    # two-step ladder has) keep the process's materialization; a
+    # narrower buffer's shift passes run as ONE loop.  A ``ptt_level``
+    # is traced, lowered and loaded anew for every table size, so the
+    # halving steps' passes unrolled were paid on the host a tier (948
+    # more equations a flush; a fresh process's first check of the 9m
+    # binding 58 s against 50), and on a narrow buffer the loop's copy
+    # more a pass costs the device next to nothing (PERF.md §6 "PR 37")
+    mat = materialize or compact_ops.materialization()
+    mat_narrow = "roll" if mat == "shift" else mat
     is_new = jnp.zeros((nq,), jnp.bool_)
     n_failed = jnp.int32(0)
     lane_rounds = jnp.uint32(0)
+    step_rounds = [0] * (1 + len(stages))
     r = jnp.int32(0)
     cur_keys, cur_ids, cur_pending, width = kcols, None, valid, nq
-    for i, (capi, limit) in enumerate(ladder):
+    for i, (capi, limit, entry) in enumerate(ladder):
         if capi < width:
             # order-preserving compaction of the pending lanes (+ their
             # original lane ids) into the narrower stage buffer
@@ -546,7 +657,8 @@ def lookup_or_insert(
             drop = (~cur_pending).astype(jnp.uint32)
             ccols, _ = compact_ops.compact_by_flag(
                 drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
-                need_idx=False, materialize=materialize,
+                need_idx=False,
+                materialize=mat if 4 * width >= nq else mat_narrow,
             )
             npend = jnp.sum(cur_pending.astype(jnp.int32))
             n_failed = n_failed + jnp.maximum(npend - capi, 0)
@@ -563,12 +675,13 @@ def lookup_or_insert(
             start_round=r, lane_ids=cur_ids, handover=fits,
         )
         is_new = _merge_new(is_new, stage_new, cur_ids, nq)
+        step_rounds[entry] = r2 - r
         lane_rounds = lane_rounds + jnp.uint32(width) * (
-            r2 - r
+            step_rounds[entry]
         ).astype(jnp.uint32)
         r = r2
     n_failed = n_failed + jnp.sum(cur_pending.astype(jnp.int32))
-    return is_new, tcols, n_failed, r, lane_rounds
+    return is_new, tcols, n_failed, r, lane_rounds, tuple(step_rounds)
 
 
 def _merge_new(is_new, stage_new, stage_ids, nq):
@@ -607,14 +720,16 @@ def flush_acc(
     lanei = jnp.arange(nq, dtype=jnp.int32)
     amask = lanei < n_acc
     valid = amask & ~all_sentinel(kcols)
-    is_new, tcols2, n_failed, rounds, lane_rounds = lookup_or_insert(
-        tcols, kcols, valid,
-        dense_rounds=dense_rounds, stages=stages, materialize=materialize,
+    is_new, tcols2, n_failed, rounds, lane_rounds, step_rounds = (
+        lookup_or_insert(
+            tcols, kcols, valid, dense_rounds=dense_rounds,
+            stages=stages, materialize=materialize,
+        )
     )
     n_new = jnp.sum(is_new.astype(jnp.int32))
     fpm2 = fpm_update(
         fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32)),
-        lane_rounds,
+        lane_rounds, step_rounds,
     )
     return tcols2, n_new, is_new.astype(jnp.uint32), fpm2
 
@@ -707,8 +822,8 @@ def rehash_cols(
     of the old table: a chunk's occupied slots are packed to the front
     (``REHASH_PACK_NUM / REHASH_PACK_DEN`` of its width; a chunk no
     wider than ``MIN_STAGE`` goes as it is) and go through
-    :func:`lookup_or_insert`'s ladder at the module's schedule
-    (``DENSE_ROUNDS`` / ``STAGES``), so that every round presents the
+    :func:`lookup_or_insert`'s two-step ladder (``DENSE_ROUNDS`` /
+    ``STAGES_TWO_STEP``), so that every round presents the
     lanes still pending and little else (module docstring, "On-device
     growth").
 
@@ -761,9 +876,9 @@ def _rehash_cols(old_cols, new_cols, *, chunk, max_probes, materialize):
             )
             ks = tuple(c[:width] for c in packed)
             occm = jnp.arange(width, dtype=jnp.int32) < n_occ
-        _new_flags, new, n_failed, _r, lane_rounds = lookup_or_insert(
+        _new_flags, new, n_failed, _r, lane_rounds, _ = lookup_or_insert(
             new, ks, occm, max_probes=max_probes,
-            dense_rounds=DENSE_ROUNDS, stages=STAGES,
+            dense_rounds=DENSE_ROUNDS, stages=STAGES_TWO_STEP,
             materialize=materialize,
         )
         lanes_lo, lanes_hi = add_u32(rhm[2], rhm[3], lane_rounds)
@@ -850,7 +965,7 @@ class FPSet:
         if valid is None:
             valid = jnp.ones((nq,), jnp.bool_)
         self.reserve(self.n + nq)
-        is_new, self.cols, n_failed, rounds, lane_rounds = (
+        is_new, self.cols, n_failed, rounds, lane_rounds, _ = (
             lookup_or_insert(
                 self.cols, kcols, valid,
                 dense_rounds=self.dense_rounds, stages=self.stages,

@@ -82,7 +82,9 @@ _NOMINAL_SPILL_RATIO = 0.4
 # default probe schedule constants mirrored from ops/fpset.py (not
 # imported: predict must stay importable without jax)
 _DENSE_DEFAULT = 4
-_STAGES_DEFAULT = ((4, 16), (64, 64))
+_STAGES_DEFAULT = (
+    (4, 16), (8, 24), (16, 32), (32, 40), (64, 48), (256, 64),
+)
 
 def schedule_lane_factor(
     dense: int, stages: Tuple[Tuple[int, int], ...], avg_rounds: float

@@ -436,7 +436,7 @@ def cmd_stages(args):
     arows = z((ck.W, ck.ACAP), jnp.uint32)
     rows_store = z((ck._rows_len(),), jnp.uint32)
     vk = fpset.empty_cols(ck.TCAP, K)
-    fpm = z((fpset.FPM_N,), jnp.int32)
+    fpm = z((fpset.FPM_WIDE_N,), jnp.int32)
     n_inv = len(ck.invariant_names)
     viol0 = jnp.full((n_inv,), int(BIG), jnp.int32)
 
@@ -540,7 +540,7 @@ def cmd_stages(args):
         "parent": z((ck2.PCAP,), jnp.int32),
         "lane": z((ck2.PCAP,), jnp.int32),
         "nv": jnp.int32(0),
-        "fpm": z((fpset.FPM_N,), jnp.int32),
+        "fpm": z((fpset.FPM_WIDE_N,), jnp.int32),
     }
 
     def do_fused():
@@ -801,6 +801,96 @@ def cmd_calibrate(args):
     return 0
 
 
+# ------------------------------------------------------------- ladder
+
+
+def cmd_ladder(args):
+    """Seconds a flush for each probe schedule at ONE flush shape: the
+    chip's answer to which steps of ``fpset.lookup_or_insert``'s
+    ladder pay for themselves (PR 37).  The table is pre-filled to
+    ``--load``; every flush presents ``--lanes`` lanes of which a
+    ``--valid`` share is valid, a ``--dup`` share of those keys the
+    table holds already and the rest new; ``--reps`` flushes run in
+    one dispatch, each on the table the one before left."""
+    import json
+
+    from pulsar_tlaplus_tpu.ops import fpset
+    from pulsar_tlaplus_tpu.ops.dedup import _fmix
+
+    nq, cap, K = args.lanes, 1 << args.cap_log2, args.cols
+    n_pre = int(args.load * cap)
+    chunk = 1 << 20
+    u = jnp.uint32
+
+    def keys_of(idx):
+        # col 0 is a bijection of idx, so distinct idx are distinct keys
+        return tuple(
+            _fmix(idx ^ u((0x9E3779B9 * (c + 1)) & 0xFFFFFFFF))
+            for c in range(K)
+        )
+
+    @jax.jit
+    def prefill(tcols):
+        def body(i, tc):
+            idx = i.astype(u) * u(chunk) + jnp.arange(chunk, dtype=u)
+            _, tc, _, _, _, _ = fpset.lookup_or_insert(
+                tc, keys_of(idx), idx < u(n_pre),
+                stages=fpset.STAGES_TWO_STEP,
+            )
+            return tc
+        return lax.fori_loop(0, -(-n_pre // chunk), body, tcols)
+
+    table = barrier(prefill(fpset.empty_cols(cap, K)))
+    lane = jnp.arange(nq, dtype=u)
+    share = lambda x: u(int(x * 65536))  # noqa: E731
+
+    def flushes(dense, stages, materialize):
+        def body(i, carry):
+            tc, lanes, valid_n, failed = carry
+            h = _fmix(lane ^ _fmix(i.astype(u) + u(0x51ED27)))
+            valid = (h & u(0xFFFF)) < share(args.valid)
+            dup = ((h >> 16) & u(0xFFFF)) < share(args.dup)
+            old = _fmix(h) % u(max(n_pre, 1))
+            new = u(n_pre) + i.astype(u) * u(nq) + lane
+            idx = jnp.where(dup & (n_pre > 0), old, new)
+            _, tc, nf, _, lr, _ = fpset.lookup_or_insert(
+                tc, keys_of(idx), valid, dense_rounds=dense,
+                stages=stages, materialize=materialize,
+            )
+            return (tc, lanes + lr.astype(jnp.float32),
+                    valid_n + jnp.sum(valid.astype(jnp.int32)),
+                    failed + nf)
+        return jax.jit(lambda tc: lax.fori_loop(
+            0, args.reps, body,
+            (tc, jnp.float32(0), jnp.int32(0), jnp.int32(0)),
+        )[1:])
+
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    with open(args.out, "a") as f:
+        for spec in args.schedules.split(";"):
+            mat = None
+            if "@" in spec:
+                spec, mat = spec.split("@")
+            dense, stages = fpset.parse_schedule(spec)
+            (lanes, valid_n, failed), med = timed(
+                f"{spec} {mat or ''}", flushes(dense, stages, mat), table,
+                reps=args.timed_reps,
+            )
+            row = {
+                "schedule": spec, "materialize": mat, "lanes": nq,
+                "cap_log2": args.cap_log2, "load": args.load,
+                "valid": args.valid, "dup": args.dup, "reps": args.reps,
+                "ms_a_flush": round(med * 1e3 / args.reps, 4),
+                "lanes_presented_per_valid": round(
+                    float(lanes) / max(int(valid_n), 1), 4),
+                "failed": int(failed),
+                "device": jax.devices()[0].device_kind,
+            }
+            print(json.dumps(row), flush=True)
+            f.write(json.dumps(row) + "\n")
+    return 0
+
+
 # --------------------------------------------------------------- main
 
 
@@ -863,6 +953,28 @@ def main(argv=None):
                     help="also run a liveness check and calibrate the "
                     "sweep unit cost from its measured sweep wall")
     pc.set_defaults(fn=cmd_calibrate)
+
+    pd = sub.add_parser(
+        "ladder", help="seconds a flush for each fpset probe schedule "
+        "at one flush shape (which ladder steps pay for themselves)")
+    pd.add_argument("--lanes", type=int, default=65536,
+                    help="lanes a flush (cli check: sub_batch 4096 x "
+                    "16 actions)")
+    pd.add_argument("--cap-log2", type=int, default=25)
+    pd.add_argument("--cols", type=int, default=2)
+    pd.add_argument("--load", type=float, default=0.3)
+    pd.add_argument("--valid", type=float, default=0.11)
+    pd.add_argument("--dup", type=float, default=0.45)
+    pd.add_argument("--reps", type=int, default=200)
+    pd.add_argument("--timed-reps", type=int, default=3)
+    pd.add_argument(
+        "--schedules",
+        default="4,4:16,64:64;4,4:16,16:32,64:64;"
+        "4,4:16,8:24,16:32,32:48,64:64",
+        help="';'-separated PTT_FPSET_SCHEDULE specs, each optionally "
+        "'@shift' / '@roll' / '@gather' for its compactions")
+    pd.add_argument("--out", default="chiprun_out/ladder.jsonl")
+    pd.set_defaults(fn=cmd_ladder)
 
     args = ap.parse_args(argv)
     return args.fn(args) or 0

@@ -92,7 +92,7 @@ def _table_at_load(cap, load, ncols, seed):
         axis=0,
     )
     kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
-    _new, tcols, n_failed, _r, _l = fpset.lookup_or_insert(
+    _new, tcols, n_failed, _r, _l, _s = fpset.lookup_or_insert(
         fpset.empty_cols(cap, ncols), kcols,
         jnp.ones((len(keys),), jnp.bool_),
     )
@@ -224,7 +224,7 @@ def test_failure_count_on_overload():
     rng = np.random.default_rng(5)
     keys = rng.integers(0, 2**31, size=(4 * cap, 2), dtype=np.uint32)
     cols = fpset.empty_cols(cap, 2)
-    is_new, cols, n_failed, _rounds, _lanes = fpset.lookup_or_insert(
+    is_new, cols, n_failed, _rounds, _lanes, _ = fpset.lookup_or_insert(
         cols, (keys[:, 0], keys[:, 1]),
         jnp.ones((len(keys),), jnp.bool_),
     )
@@ -251,7 +251,7 @@ def test_staged_compaction_matches_single_loop():
     keys = pool[rng.integers(0, len(pool), size=4096)]
     kcols = (jnp.asarray(keys[:, 0]), jnp.asarray(keys[:, 1]))
     valid = jnp.ones((len(keys),), jnp.bool_)
-    staged_new, staged_cols, nf, _, _ = fpset.lookup_or_insert(
+    staged_new, staged_cols, nf, _, _, _ = fpset.lookup_or_insert(
         fpset.empty_cols(cap, 2), kcols, valid
     )
     simple_new, simple_cols, _, pending, _ = fpset.probe_insert(
@@ -271,10 +271,11 @@ def test_staged_compaction_matches_single_loop():
 # from which both schedules' presented lanes follow by arithmetic.
 
 
-def _batch(seed, nq, cap, load, dup, K):
+def _batch(seed, nq, cap, load, dup, K, valid_share=0.95):
     """A table pre-filled so that it ends near ``load`` once the batch
     is in, and ``nq`` lanes of which a ``dup`` share repeat an earlier
-    lane or a key the table already holds; ~5% of the lanes invalid."""
+    lane or a key the table already holds; ~5% of the lanes invalid
+    (a ``cli check`` flush: nine in ten)."""
     rng = np.random.default_rng(seed)
     n_fresh = max(int(nq * (1.0 - dup)), 1)
     n_pre = max(int(load * cap) - n_fresh, 0)
@@ -298,7 +299,7 @@ def _batch(seed, nq, cap, load, dup, K):
         )
         assert not bool(np.asarray(pending).any())
     kcols = tuple(jnp.asarray(keys[:, i]) for i in range(K))
-    valid = jnp.asarray(rng.random(nq) < 0.95)
+    valid = jnp.asarray(rng.random(nq) < valid_share)
     return tcols, kcols, valid
 
 
@@ -309,8 +310,11 @@ def _ladder(nq, dense, stages):
     for div, limit in stages:
         limit = min(limit, fpset.MAX_PROBES)
         capi = max(nq // div, min(nq, fpset.MIN_STAGE))
-        if capi >= steps[-1][0] or limit <= dense:
-            steps[-1] = (steps[-1][0], max(steps[-1][1], limit))
+        width = steps[-1][0]
+        if capi >= width or limit <= dense or (
+            width > fpset.QUARTER_ABOVE and 4 * capi > width
+        ):
+            steps[-1] = (width, max(steps[-1][1], limit))
         else:
             steps.append((capi, limit))
     return steps
@@ -362,6 +366,17 @@ SCHEDULE_CASES = [
                  id="stage-under-dense-ceiling"),
 ]
 
+# the default ladder's halving steps (PR 37) at the shapes it was made
+# for, each also held to the two-step ladder it replaced:
+# nq, cap, load, dup, K, share of the lanes that is valid
+HALVING_CASES = [
+    # a ``cli check`` flush: sub_batch 4096 x 16 actions, one lane in
+    # ten valid, the table at load 0.3
+    pytest.param(1 << 16, 1 << 19, 0.3, 0.45, 2, 0.10, id="cli-flush"),
+    pytest.param(1 << 18, 1 << 20, 0.3, 0.1, 2, 0.7, id="wide"),
+    pytest.param(512, 1 << 12, 0.3, 0.2, 2, 0.5, id="below-min-stage"),
+]
+
 
 @pytest.mark.parametrize(
     "nq,cap,load,dup,K,dense,stages", SCHEDULE_CASES
@@ -376,7 +391,7 @@ def test_pending_driven_schedule_matches_single_loop(
     tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
     d, st = fpset.resolve_schedule(dense, stages)
     ceiling = _ladder(nq, d, st)[-1][1]
-    got_new, got_cols, n_failed, rounds, _ = fpset.lookup_or_insert(
+    got_new, got_cols, n_failed, rounds, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=dense, stages=stages
     )
     want_new, want_cols, _, pending, want_rounds = fpset.probe_insert(
@@ -402,7 +417,7 @@ def test_lane_rounds_is_width_times_rounds_and_never_above_fixed(
     tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
     d, st = fpset.resolve_schedule(dense, stages)
     ladder = _ladder(nq, d, st)
-    _, _, n_failed, rounds, lane_rounds = fpset.lookup_or_insert(
+    _, _, n_failed, rounds, lane_rounds, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=dense, stages=stages
     )
     pending = _pending_by_round(tcols, kcols, valid, ladder[-1][1])
@@ -414,6 +429,62 @@ def test_lane_rounds_is_width_times_rounds_and_never_above_fixed(
         assert followed < fixed
     if len(ladder) == 1:
         assert followed == fixed == nq * int(rounds)
+
+
+@pytest.mark.parametrize("nq,cap,load,dup,K,share", HALVING_CASES)
+def test_halving_ladder_matches_single_loop_and_two_step_ladder(
+    nq, cap, load, dup, K, share
+):
+    """The default ladder against the single loop AND against the
+    two-step ladder of PR 28-36: ``is_new``, the columns, ``n_failed``
+    and ``rounds`` bit for bit; ``step_rounds`` sums to ``rounds``
+    with a 0 for every stage that was not built; and it never presents
+    more lanes, and far fewer for a flush with one valid lane in ten."""
+    assert fpset.resolve_schedule() == (fpset.DENSE_ROUNDS, fpset.STAGES)
+    assert [d for d, _ in fpset.STAGES] == [4, 8, 16, 32, 64, 256]
+    tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K, share)
+    got = fpset.lookup_or_insert(tcols, kcols, valid)
+    two = fpset.lookup_or_insert(
+        tcols, kcols, valid, stages=fpset.STAGES_TWO_STEP
+    )
+    one_new, one_cols, _, pending, one_rounds = fpset.probe_insert(
+        tcols, kcols, valid
+    )
+    assert not bool(np.asarray(pending).any())
+    for new, cols, n_failed, rounds, _, steps in (got, two):
+        assert np.array_equal(np.asarray(new), np.asarray(one_new))
+        for a, b in zip(cols, one_cols):
+            assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
+        assert int(n_failed) == 0
+        assert int(rounds) == int(one_rounds)
+        assert sum(int(x) for x in steps) == int(rounds)
+    built = {0} | {
+        j for j, (d, _) in enumerate(fpset.STAGES, 1)
+        if nq // d >= fpset.MIN_STAGE and nq // d < nq
+    }
+    steps = got[5]
+    assert len(steps) == 1 + len(fpset.STAGES)
+    assert all(
+        isinstance(x, int) and x == 0
+        for j, x in enumerate(steps) if j not in built
+    )
+    halves, two_step = int(got[4]), int(two[4])
+    assert halves <= two_step
+    if share <= 0.1:
+        # the chip's materialization: the batch's and its quarter's
+        # compactions unrolled, the narrower steps' as one loop each
+        chip = fpset.lookup_or_insert(
+            tcols, kcols, valid, materialize="shift"
+        )
+        assert np.array_equal(np.asarray(chip[0]), np.asarray(one_new))
+        for a, b in zip(chip[1], one_cols):
+            assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
+        assert [int(x) for x in chip[2:5]] == [0, int(one_rounds), halves]
+    if share <= 0.1:
+        # 9-11% valid: the pending lanes skip the 1/4 buffer's rounds
+        assert halves < 0.7 * two_step
+    if nq < fpset.MIN_STAGE:
+        assert halves == two_step == nq * int(one_rounds)
 
 
 def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
@@ -429,7 +500,7 @@ def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
     carried = max(nq // 16, fpset.MIN_STAGE)
     after_one = _pending_by_round(tcols, kcols, valid, 1)[1]
     assert after_one > carried
-    is_new, cols, n_failed, _, lane_rounds = fpset.lookup_or_insert(
+    is_new, cols, n_failed, _, lane_rounds, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, **sched
     )
     assert int(n_failed) == after_one - carried
@@ -439,7 +510,7 @@ def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
     assert int((np.asarray(valid) & ~member).sum()) <= int(n_failed)
     assert int(lane_rounds) >= nq + carried
     # the same batch with room in the next stage: nothing fails
-    _, _, none_failed, _, _ = fpset.lookup_or_insert(
+    _, _, none_failed, _, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=1, stages=((2, 64),)
     )
     assert int(none_failed) == 0
@@ -477,6 +548,167 @@ def test_fpm_carries_lane_rounds_past_32_bits_and_pads_old_frames():
     ]
 
 
+def test_fpm_step_rounds_ride_the_wide_vector_only():
+    """The single-chip engine's vector carries the rounds of each
+    ladder step behind ``FPM_N`` (a longer schedule folds its tail into
+    the last word); the sharded engine's ``FPM_N`` words and the
+    logical view are what they were."""
+    steps = (jnp.int32(1), 0, jnp.int32(2), jnp.int32(3))
+    args = (jnp.int32(6), jnp.int32(0), jnp.int32(7), jnp.uint32(9))
+    wide = jnp.zeros((fpset.FPM_WIDE_N,), jnp.int32)
+    narrow = jnp.zeros((fpset.FPM_N,), jnp.int32)
+    for _ in range(2):
+        wide = fpset.fpm_update(wide, *args, steps)
+        narrow = fpset.fpm_update(narrow, *args, steps)
+    assert narrow.shape == (fpset.FPM_N,)
+    assert np.array_equal(np.asarray(wide)[: fpset.FPM_N], np.asarray(narrow))
+    assert list(fpset.fpm_logical(np.asarray(wide))) == [2, 12, 0, 14, 6, 18]
+    assert fpset.fpm_step_rounds(np.asarray(wide), ((4, 16),) * 3) == [
+        2, 0, 4, 6,
+    ]
+    long = tuple(jnp.int32(1) for _ in range(fpset.FPM_STEPS + 3))
+    folded = fpset.fpm_update(
+        jnp.zeros((fpset.FPM_WIDE_N,), jnp.int32), *args, long
+    )
+    got = fpset.fpm_step_rounds(
+        np.asarray(folded), ((2, 8),) * (len(long) - 1)
+    )
+    assert got == [1] * (fpset.FPM_STEPS - 1) + [4]
+
+
+# nq -> the default ladder's widths: halves between 1/4 and 1/64 of
+# the batch; no step under ``MIN_STAGE``; quarters from a buffer wider
+# than ``QUARTER_ABOVE`` lanes
+LADDER_WIDTHS = [
+    pytest.param(512, [512], id="below-min-stage"),
+    pytest.param(
+        1 << 16, [65536, 16384, 8192, 4096, 2048, 1024], id="cli-flush"
+    ),
+    pytest.param(
+        1 << 18,
+        [262144, 65536, 32768, 16384, 8192, 4096, 1024],
+        id="true-256th-from-2^18",
+    ),
+    pytest.param(
+        1 << 22,
+        [1 << 22, 1 << 20, 1 << 19, 1 << 18, 1 << 17, 1 << 16, 1 << 14],
+        id="halves-at-2^20",
+    ),
+    pytest.param(
+        26738688,
+        [26738688, 6684672, 1671168, 417792, 104448],
+        id="flagship-quarters",
+    ),
+]
+
+
+@pytest.mark.parametrize("nq,widths", LADDER_WIDTHS)
+def test_default_ladder_by_batch_width(nq, widths):
+    """The ladder is a function of the batch's static width alone; the
+    two-step tuple is untouched by the quarter rule (its gaps are 4x and
+    16x); and the traced program has one probe loop a step."""
+    steps = fpset.ladder_steps(nq, fpset.DENSE_ROUNDS, fpset.STAGES)
+    assert [w for w, _, _ in steps] == widths
+    assert [(w, c) for w, c, _ in steps] == _ladder(
+        nq, fpset.DENSE_ROUNDS, fpset.STAGES
+    )
+    assert steps[-1][1] == fpset.MAX_PROBES
+    two = fpset.ladder_steps(nq, fpset.DENSE_ROUNDS, fpset.STAGES_TWO_STEP)
+    assert [w for w, _, _ in two] == [
+        w for w in dict.fromkeys(
+            (nq, max(nq // 4, min(nq, fpset.MIN_STAGE)),
+             max(nq // 64, min(nq, fpset.MIN_STAGE)))
+        )
+    ]
+    lanes = jax.ShapeDtypeStruct((nq,), jnp.uint32)
+    table = jax.ShapeDtypeStruct(
+        ((1 << (nq.bit_length() + 1)) + 1,), jnp.uint32
+    )
+    jaxpr = jax.make_jaxpr(
+        lambda t, k, v: fpset.lookup_or_insert(t, k, v, materialize="roll")
+    )((table, table), (lanes, lanes), jax.ShapeDtypeStruct((nq,), jnp.bool_))
+    names = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+    assert names.count("while") == len(widths)
+
+
+def test_sharded_engine_and_rehash_keep_the_two_step_ladder(monkeypatch):
+    """One default ladder and one named two-step tuple: the single-chip
+    engine resolves the first; ``ShardedDeviceChecker`` and
+    ``rehash_cols`` the second, by value (ISSUE 37)."""
+    from pulsar_tlaplus_tpu.engine.sharded_device import (
+        ShardedDeviceChecker,
+    )
+
+    assert fpset.STAGES_TWO_STEP == ((4, 16), (64, 64))
+    m = CompactionModel(SMALL_CONFIGS["producer_on"])
+    one = DeviceChecker(m, invariants=(), sub_batch=64, visited_cap=1 << 10)
+    assert (one.fps_dense, one.fps_stages) == (
+        fpset.DENSE_ROUNDS, fpset.STAGES
+    )
+    four = ShardedDeviceChecker(
+        m, n_devices=4, invariants=(), sub_batch=64, visited_cap=1 << 6,
+    )
+    assert (four.fps_dense, four.fps_stages) == (
+        fpset.DENSE_ROUNDS, fpset.STAGES_TWO_STEP
+    )
+    # the two-step ladder's programs are the parent's on the chip too:
+    # under the chip's materialization its compactions stay unrolled
+    # (its loops are its three probe loops), while the halving ladder
+    # runs the compactions of its 1/8, 1/16 and 1/32 buffers as loops
+    def whiles(stages):  # (probe loops, rolled compactions)
+        cols = tuple(jnp.zeros((1 << 16,), jnp.uint32) for _ in range(2))
+        jaxpr = jax.make_jaxpr(
+            lambda t, k, v: fpset.lookup_or_insert(
+                t, k, v, stages=stages, materialize="shift"
+            )[:5]
+        )(fpset.empty_cols(1 << 18, 2), cols, cols[0] > 0)
+        names = [e.primitive.name for e in jaxpr.jaxpr.eqns]
+        return names.count("while"), names.count("scan")
+
+    assert whiles(fpset.STAGES_TWO_STEP) == (3, 0)
+    assert whiles(fpset.STAGES) == (6, 3)
+    seen = []
+    real = fpset.lookup_or_insert
+
+    def spy(*a, **kw):
+        seen.append((kw.get("dense_rounds"), kw.get("stages")))
+        return real(*a, **kw)
+
+    keys, old = _table_at_load(1 << 13, 0.3, 2, seed=37)
+    monkeypatch.setattr(fpset, "lookup_or_insert", spy)
+    # a chunk no other test asks for, so that the body is traced here
+    new, rhm = fpset.rehash_cols(
+        old, fpset.empty_cols(1 << 14, 2), chunk=1 << 12
+    )
+    assert seen == [(fpset.DENSE_ROUNDS, fpset.STAGES_TWO_STEP)]
+    assert fpset.rhm_logical(rhm)[:2] == (0, len(keys))
+    _assert_holds_exactly(new, keys)
+    # the env override still reaches both engines, and a ctor value wins
+    monkeypatch.setenv("PTT_FPSET_SCHEDULE", "2,8:32")
+    assert fpset.resolve_schedule(
+        default_stages=fpset.STAGES_TWO_STEP
+    ) == (2, ((8, 32),))
+    assert fpset.resolve_schedule(
+        3, ((4, 8),), default_stages=fpset.STAGES_TWO_STEP
+    ) == (3, ((4, 8),))
+
+
+def test_predict_mirrors_the_default_schedule():
+    """``tune/predict.py`` prices the module's default ladder (it
+    mirrors the constants so that it imports without JAX)."""
+    from pulsar_tlaplus_tpu.tune import predict
+
+    assert predict._DENSE_DEFAULT == fpset.DENSE_ROUNDS
+    assert predict._STAGES_DEFAULT == fpset.STAGES == (
+        (4, 16), (8, 24), (16, 32), (32, 40), (64, 48), (256, 64)
+    )
+    f = predict.schedule_lane_factor
+    # full width to round 4, then a quarter, an eighth, a sixteenth
+    assert f(4, predict._STAGES_DEFAULT, 3.0) == 3.0
+    assert f(4, predict._STAGES_DEFAULT, 10.0) == 4 + 6 / 4
+    assert f(4, predict._STAGES_DEFAULT, 30.0) == 4 + 12 / 4 + 8 / 8 + 6 / 16
+
+
 # ---- the engines on the published oracles ---------------------------
 
 
@@ -506,6 +738,39 @@ def test_fpset_full_cfg_published_count():
     assert r.distinct_states == 253361
     assert r.diameter == 23
     assert r.violation is None and not r.deadlock
+
+
+# the 253,361-state binding's level sizes after the initial state, as
+# the parent commit's ``cli check`` prints them (PR 37, CPU)
+_SIZES_253K = [
+    10, 99, 990, 3267, 4860, 6642, 8595, 8748, 10935, 13131, 13293, 15633,
+    20232, 15291, 17640, 22455, 20943, 21555, 27936, 4041, 6372, 10692,
+]
+
+
+def test_cli_check_of_the_253k_binding_presents_under_2_6_lanes(tmp_path):
+    """The counter that says how the ladder engages, on a whole ``cli
+    check`` at the CLI's own tiers: 3.3606 lanes presented a valid one
+    under the two-step ladder (the parent, CPU: the counter is
+    hardware-independent), 1.9005 with the halving steps; the search is
+    the parent's, level for level, and every round is some step's."""
+    from tests.test_units import VERDICT, _cli_check
+    from tests.test_units_programs import CFG_253K
+
+    rc, out, sizes, stats = _cli_check(tmp_path, 0, "-config", CFG_253K)
+    assert rc == 0
+    assert VERDICT.search(out).groups() == ("253361", "23")
+    assert sizes == _SIZES_253K and 1 + sum(sizes) == 253361
+    assert stats["fpset_failures"] == 0
+    assert stats["fpset_lanes_presented_per_valid"] < 2.6
+    assert stats["fpset_lane_rounds"] < 0.6 * 1414144  # the parent's
+    steps = stats["fpset_step_rounds"]
+    assert len(steps) == 1 + len(fpset.STAGES)
+    assert sum(steps) == stats["fpset_probe_rounds"] == 316
+    # no step idles on this traffic; 1/256 of 65,536 lanes is under
+    # ``MIN_STAGE``, so that step is not built and its rounds are the
+    # 1/64 step's
+    assert all(n > 0 for n in steps[1:6]) and steps[6] == 0
 
 
 # ---- _load_seed frontier-window guard (ADVICE r5 medium) -------------

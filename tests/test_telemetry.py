@@ -15,6 +15,7 @@ import pytest
 from pulsar_tlaplus_tpu.engine.device_bfs import FPM_N, DeviceChecker
 from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.obs import report, telemetry
+from pulsar_tlaplus_tpu.ops import fpset
 from pulsar_tlaplus_tpu.ref import pyeval as pe
 from pulsar_tlaplus_tpu.utils import ckpt
 
@@ -114,8 +115,9 @@ def test_zero_sync_counters_ride_the_stats_fetch(std_run):
     most)."""
     _stream, _frame, ck, r, events = std_run
     # r12: valid_lanes split into hi/lo uint32 words (int32-wrap fix);
-    # PR 28: lane_rounds appended the same way
-    assert FPM_N == 8
+    # PR 28: lane_rounds appended the same way; PR 37: this engine's
+    # vector carries the rounds of each ladder step behind those
+    assert fpset.FPM_N == 8 and FPM_N == 8 + fpset.FPM_STEPS
     stats = [e for e in events if e["event"] == "result"][-1]["stats"]
     flushes = [e for e in events if e["event"] == "flush"]
     assert stats["fpset_flushes"] == sum(e["flushes"] for e in flushes)
@@ -192,6 +194,12 @@ def test_six_wide_fpm_frame_restores_zero_padded(std_run, tmp_path):
     # the new counter restarts at the frame: it covers the resumed part
     assert 0 < st["fpset_lane_rounds"] < ck.last_stats["fpset_lane_rounds"]
     assert st["fpset_lane_rounds"] >= st["fpset_valid_lanes"] - old_valid
+    # so do the rounds by ladder step (PR 37): an 8-wide frame of PR
+    # 28-36 restores the same way
+    assert 0 < sum(st["fpset_step_rounds"]) < st["fpset_probe_rounds"]
+    assert sum(ck.last_stats["fpset_step_rounds"]) == (
+        ck.last_stats["fpset_probe_rounds"]
+    )
 
 
 def test_ckpt_frame_stall_accounting(std_run):
