@@ -1,0 +1,11 @@
+"""Device self seconds of the window's operations under the part scope
+``part.write`` of the program's ``ptt.probe`` stage
+(``benchmark/lib/probe_parts.py``): the winners' keys written: one scatter a
+key column into the table; the level kernel's probe of a table that grows
+from 2^17 to 2^25 slots inside the check, at 4,096 states a sub-batch."""
+
+from benchmark.lib import probe_parts
+
+
+def read(ctx, params):
+    return probe_parts.part_seconds(ctx, "probe", "write")

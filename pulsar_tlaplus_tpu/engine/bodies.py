@@ -3,8 +3,10 @@
 Every public function here is a unit (``engine/units.py``) and a whole
 PROGRAM of ``engine/device_bfs.py``: a module-level function that closes
 over nothing, wrapped once, at import, in ``jax.jit`` and dispatched from
-the host under the name it has always had (``jit_ptt_level``,
-``jit_ptt_expand``, ...).  What a program used to read of its
+the host under its own name (``jit_ptt_expand``, ``jit_ptt_level2``,
+...; the three that trace the probe carry a ``2`` since its part scopes
+came, PR 38: the cache-key rule of docs/observability.md).  What a
+program used to read of its
 ``DeviceChecker`` is an explicit argument — arrays traced, everything
 else static and hashed by value (the model by class and constants,
 ``models.ByConstants``; the key spec by its layout,
@@ -300,7 +302,7 @@ def ptt_init(ak, arows, f_off, acc_off, *, model, keys, NCs, Fi):
 
 
 @unit(static=("dense_rounds", "stages", "materialize"), donate=(0,))
-def ptt_fpflush(tc, ak, n_acc, fpm, *, dense_rounds, stages, materialize):
+def ptt_fpflush2(tc, ak, n_acc, fpm, *, dense_rounds, stages, materialize):
     """The flush dispatch — the body lives in ops/fpset.py so that the
     level kernel chains the IDENTICAL trace: ``(*tc', n_new, flag_acc,
     fpm')``."""
@@ -312,7 +314,7 @@ def ptt_fpflush(tc, ak, n_acc, fpm, *, dense_rounds, stages, materialize):
 
 
 @unit("rehash", static=("materialize",))
-def ptt_rehash(old, *, materialize):
+def ptt_rehash2(old, *, materialize):
     """Table growth: the old table's columns -> double-capacity
     columns and the rehash vector (``fpset.rhm_logical``: failures,
     keys moved, lanes presented), ``(*new, rhm)``.  No donation: the
@@ -370,7 +372,7 @@ LEVEL_STATIC = (
 # loop's own control flow, the boundary bookkeeping and the stats
 # vector are what levelctl keeps
 @unit("levelctl", static=LEVEL_STATIC, donate=(0, 1, 2, 3, 4, 5))
-def ptt_level(
+def ptt_level2(
     vk, ak, arows, rows, parent, lane, n_visited, dead, viol, fpm, wkm,
     level_base, nf, w_off, levels_left, groups_left, row_base, rows_ok, *,
     model, keys, invariant_names, Fi, G, FLUSH, check_deadlock,

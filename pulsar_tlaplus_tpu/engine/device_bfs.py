@@ -135,7 +135,7 @@ def _growth_call(fn):
     (``_grow_fused`` > ``_grow_store`` > ``_grow_logs``), one
     ``grow_events`` if any of (TCAP, LCAP, PCAP) moved.  (Not
     ``spans.in_phase`` under a second wrapper: a grower's first call at
-    a tier traces ``ptt_rehash`` / ``ptt_grow``, and every frame above
+    a tier traces ``ptt_rehash2`` / ``ptt_grow``, and every frame above
     a traced equation is on its traceback.)"""
 
     @functools.wraps(fn)
@@ -815,7 +815,7 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
         return self._program(
-            key, bodies.ptt_fpflush, dense_rounds=self.fps_dense,
+            key, bodies.ptt_fpflush2, dense_rounds=self.fps_dense,
             stages=self.fps_stages, materialize=key[-1],
         )
 
@@ -828,7 +828,7 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
         return self._program(
-            key, bodies.ptt_rehash, materialize=key[-1]
+            key, bodies.ptt_rehash2, materialize=key[-1]
         )
 
     # invariant-evaluation chunk for the append: bounds the unpacked-
@@ -910,7 +910,7 @@ class DeviceChecker:
         sub-functions the stage chain dispatches separately
         (expand -> ``ops.fpset.flush_acc`` ->
         ``ops.compact.compact_rows`` -> append) with every buffer
-        donated end-to-end.  The program itself is ``bodies.ptt_level``.
+        donated end-to-end.  The program itself is ``bodies.ptt_level2``.
 
         Operands: ``(vk, ak, arows, rows, parent, lane, n_visited,
         dead_gid, viol, fpm, wkm, level_base, nf, w_off, levels_left,
@@ -957,7 +957,7 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
         return self._program(
-            key, bodies.ptt_level, model=self.model, keys=self.keys,
+            key, bodies.ptt_level2, model=self.model, keys=self.keys,
             invariant_names=self.invariant_names, Fi=self.Fi, G=self.G,
             FLUSH=self.FLUSH, check_deadlock=self.check_deadlock,
             dense_rounds=self.fps_dense, stages=self.fps_stages,
@@ -1194,7 +1194,7 @@ class DeviceChecker:
         keyspec = self.keys
 
         @spans.staged("seed")
-        def ptt_fpseed_merge(*args):
+        def ptt_fpseed_merge2(*args):
             tc = args[:K]
             rows, n_valid, n_visited, viol, gid_base, fpm = args[K:]
             kcols = keyspec.make(rows)
@@ -1229,7 +1229,7 @@ class DeviceChecker:
             )
 
         fn = jax.jit(
-            ptt_fpseed_merge, donate_argnums=tuple(range(self.K))
+            ptt_fpseed_merge2, donate_argnums=tuple(range(self.K))
         )
         self._jits[key] = fn
         return fn
@@ -1993,6 +1993,12 @@ class DeviceChecker:
         self._ckpt_retries = 0
         self._fetch_n = 0
         self._fpm_prev = np.zeros((fpset.FPM_LOGICAL_N,), np.int64)
+        # fpset_slot_rounds (PR 38): the table's slots summed over
+        # probe rounds, folded at each fetch; the rounds already
+        # folded, and the valid lanes the ratio starts from
+        self._slot_rounds = 0
+        self._slot_rounds_at = 0
+        self._slot_valid_at = 0
         # work-unit state (r14): the ``work_*`` counters are PER-RUN
         # (cost attribution prices THIS run; a pooled checker's next
         # job must not inherit the last job's work), so clear them and
@@ -2415,6 +2421,13 @@ class DeviceChecker:
         self._work_nv_prev = nv
         n_inv = len(self.invariant_names)
         self._last_fpm = out[2 + n_inv: 2 + n_inv + FPM_N]
+        # fpset_slot_rounds: the rounds since the last fetch all ran
+        # on the table as it is now — a table grows only after a fetch
+        # and before the next dispatch (_grow_fused, _grow_visited).
+        # A rehash's own rounds are in no fpm vector and stay out
+        rounds = int(self._last_fpm[1])
+        self._slot_rounds += (rounds - self._slot_rounds_at) * self.TCAP
+        self._slot_rounds_at = rounds
         self._snap["occupancy"] = nv / max(self.TCAP, 1)
         if len(self._last_fpm) >= 4:
             # TLC's "states generated": candidate lanes examined
@@ -3890,6 +3903,11 @@ class DeviceChecker:
         # flush telemetry deltas continue from the frame's counts,
         # not from zero (a resumed run must not re-report them)
         self._fpm_prev = fpset.fpm_logical(fpm)
+        # the slot-rounds restart with the work counters below: the
+        # frame does not say which table its rounds ran on
+        self._slot_rounds = 0
+        self._slot_rounds_at = int(self._fpm_prev[1])
+        self._slot_valid_at = int(self._fpm_prev[3])
         if self.fuse == "level":
             # work counters restart after resume (frames don't carry
             # them — the same regime as the r8 counter widenings);
@@ -4129,6 +4147,14 @@ class DeviceChecker:
                     fpset_step_rounds=fpset.fpm_step_rounds(
                         self._last_fpm, self.fps_stages
                     ),
+                    # the table's slots summed over this run's probe
+                    # rounds (folded in _fetch), and over the valid
+                    # lanes of the same rounds: what the table-sized
+                    # passes of a round cost by (PR 38)
+                    fpset_slot_rounds=self._slot_rounds,
+                    fpset_slots_per_valid=round(
+                        self._slot_rounds / (vl - self._slot_valid_at), 4
+                    ) if vl > self._slot_valid_at else None,
                 )
         # fusion telemetry (r13): this run's total dispatches per BFS
         # level — the regression-gate signal (steady-state fused levels

@@ -21,6 +21,19 @@ variable, exporter or telemetry event of their own:
   the jitted functions that carry scopes are named ``ptt_*``: a cache
   written before they had scopes misses by module name instead of
   handing back executables without them — docs/observability.md.)
+- **Part scopes** (``part``): a second level under a stage scope, for
+  the parts of ONE stage's work — ``jax.named_scope("part.<name>")``,
+  entered with an inline ``with`` where the work is written
+  (``ops/fpset.py``: the parts of a probe round, ``gather``,
+  ``claims_fill``, ``claims_bid``, ``write``, ``reread``, and the
+  ladder's ``narrow``).  The prefix holds no ``ptt.``, so whatever
+  reads stages reads what it read before parts existed.  An
+  operation's stage is the innermost ``ptt.`` scope of its path, as
+  above; its part is the innermost part scope BELOW that stage scope,
+  else none: a probe round traced by the rehash is stage ``rehash``,
+  part ``claims_fill``.  Metadata only, like a stage scope, and under
+  the same cache-key rule: the programs that trace a part were
+  renamed when the parts came (``ptt_level2`` ...).
 - **Host spans** (``span`` / ``spanned`` / ``PhaseClock``):
   ``jax.profiler.TraceAnnotation`` named ``ptt:<name>``, so they lie
   in the same ``.xplane.pb`` and on the same clock as the device's
@@ -39,8 +52,8 @@ variable, exporter or telemetry event of their own:
   (``engine/units.py``): ``jit_body_traces``, the units whose Python
   body ran (misses).
 
-This module is the only place of the package that constructs a
-``TraceAnnotation`` or registers a ``jax.monitoring`` listener.
+This module is the only place of the package that constructs a scope,
+a ``TraceAnnotation`` or a ``jax.monitoring`` listener.
 """
 
 from __future__ import annotations
@@ -52,6 +65,8 @@ from typing import Dict, Optional
 
 SPAN_PREFIX = "ptt:"
 SCOPE_PREFIX = "ptt."
+# no ``ptt.`` in it: a reader of stages (``ptt\.[a-z_]+``) never sees a part
+PART_PREFIX = "part."
 
 # the exclusive phases of one DeviceChecker.run(), in the order a run
 # meets them; each lands in last_stats as host_<phase>_s
@@ -77,6 +92,16 @@ def stage(name: str):
     import jax
 
     return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def part(name: str):
+    """``jax.named_scope("part.<name>")``: a part of the stage whose
+    scope it is entered under — HLO metadata only.  Enter it inline
+    (``with spans.part("gather"):``), never through a decorator: every
+    Python frame above a traced equation is paid on a first check."""
+    import jax
+
+    return jax.named_scope(PART_PREFIX + name)
 
 
 def _under(enter):

@@ -97,6 +97,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.ops import compact as compact_ops
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL, _fmix
 
@@ -531,38 +532,47 @@ def probe_insert(
         r, npend = st[0], st[1]
         return (r < max_probes) & (npend > handover)
 
+    # A round's work is named by part (``spans.part``: docs/
+    # observability.md "Parts"), inline, so that a device trace splits
+    # the stage's seconds where the remedies differ.  The slot
+    # arithmetic, the pending count and the loop's carry stay under no
+    # part.
     def body(st):
         r, _, pending, is_new, tc, oc = st
         ru = r.astype(jnp.uint32)
         off = (ru * (ru + jnp.uint32(1))) >> 1
         slot = ((h + off) & capm).astype(jnp.int32)
         s = jnp.where(pending, slot, cap)  # parked lanes hit the trash row
-        sv = tuple(c[s] for c in tc)
-        occ_s = occupied_at(tc, oc, s, sv)
-        eq = sv[0] == kcols[0]
-        for cv, ck in zip(sv[1:], kcols[1:]):
-            eq = eq & (cv == ck)
+        with spans.part("gather"):
+            sv = tuple(c[s] for c in tc)
+            occ_s = occupied_at(tc, oc, s, sv)
+            eq = sv[0] == kcols[0]
+            for cv, ck in zip(sv[1:], kcols[1:]):
+                eq = eq & (cv == ck)
         found = pending & occ_s & eq
         pending = pending & ~found
         # bid for empty slots with the lane id; min wins
         bid = pending & ~occ_s
         bid_slot = jnp.where(bid, s, cap)
-        claims = jnp.full((cap + 1,), _NO_LANE, jnp.int32).at[
-            bid_slot
-        ].min(lane_ids)
-        win = bid & (claims[s] == lane_ids)
+        with spans.part("claims_fill"):
+            claims = jnp.full((cap + 1,), _NO_LANE, jnp.int32)
+        with spans.part("claims_bid"):
+            claims = claims.at[bid_slot].min(lane_ids)
+            win = bid & (claims[s] == lane_ids)
         ws = jnp.where(win, s, cap)
-        tc = tuple(c.at[ws].set(k) for c, k in zip(tc, kcols))
-        if has_occ:
-            oc = oc.at[ws].set(1)
+        with spans.part("write"):
+            tc = tuple(c.at[ws].set(k) for c, k in zip(tc, kcols))
+            if has_occ:
+                oc = oc.at[ws].set(1)
         is_new = is_new | win
         pending = pending & ~win
         # same-key losers resolve against the newly written slot
-        sv2 = tuple(c[s] for c in tc)
-        eq2 = sv2[0] == kcols[0]
-        for cv, ck in zip(sv2[1:], kcols[1:]):
-            eq2 = eq2 & (cv == ck)
-        occ2 = occupied_at(tc, oc, s, sv2)
+        with spans.part("reread"):
+            sv2 = tuple(c[s] for c in tc)
+            eq2 = sv2[0] == kcols[0]
+            for cv, ck in zip(sv2[1:], kcols[1:]):
+                eq2 = eq2 & (cv == ck)
+            occ2 = occupied_at(tc, oc, s, sv2)
         pending = pending & ~(occ2 & eq2)
         return (r + 1, n_set(pending), pending, is_new, tc, oc)
 
@@ -631,7 +641,7 @@ def lookup_or_insert(
     ladder = ladder_steps(nq, dense_rounds, stages, max_probes)
     # the compactions of the batch and of its quarter (all that the
     # two-step ladder has) keep the process's materialization; a
-    # narrower buffer's shift passes run as ONE loop.  A ``ptt_level``
+    # narrower buffer's shift passes run as ONE loop.  A ``ptt_level2``
     # is traced, lowered and loaded anew for every table size, so the
     # halving steps' passes unrolled were paid on the host a tier (948
     # more equations a flush; a fresh process's first check of the 9m
@@ -654,16 +664,18 @@ def lookup_or_insert(
                 if cur_ids is not None
                 else jnp.arange(nq, dtype=jnp.int32)
             )
-            drop = (~cur_pending).astype(jnp.uint32)
-            ccols, _ = compact_ops.compact_by_flag(
-                drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
-                need_idx=False,
-                materialize=mat if 4 * width >= nq else mat_narrow,
-            )
+            with spans.part("narrow"):
+                drop = (~cur_pending).astype(jnp.uint32)
+                ccols, _ = compact_ops.compact_by_flag(
+                    drop, tuple(cur_keys) + (ids.astype(jnp.uint32),),
+                    need_idx=False,
+                    materialize=mat if 4 * width >= nq else mat_narrow,
+                )
             npend = jnp.sum(cur_pending.astype(jnp.int32))
             n_failed = n_failed + jnp.maximum(npend - capi, 0)
-            cur_keys = tuple(c[:capi] for c in ccols[:K])
-            cur_ids = ccols[K][:capi].astype(jnp.int32)
+            with spans.part("narrow"):
+                cur_keys = tuple(c[:capi] for c in ccols[:K])
+                cur_ids = ccols[K][:capi].astype(jnp.int32)
             cur_pending = jnp.arange(capi, dtype=jnp.int32) < npend
             width = capi
         # a step ends as soon as what is pending fits the next one: a
@@ -674,7 +686,8 @@ def lookup_or_insert(
             tcols, cur_keys, cur_pending, max_probes=limit,
             start_round=r, lane_ids=cur_ids, handover=fits,
         )
-        is_new = _merge_new(is_new, stage_new, cur_ids, nq)
+        with spans.part("narrow"):
+            is_new = _merge_new(is_new, stage_new, cur_ids, nq)
         step_rounds[entry] = r2 - r
         lane_rounds = lane_rounds + jnp.uint32(width) * (
             step_rounds[entry]

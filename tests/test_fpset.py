@@ -17,6 +17,21 @@ from pulsar_tlaplus_tpu.ref import pyeval as pe
 from tests.helpers import SMALL_CONFIGS
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _release_the_modules_programs():
+    """This module loads more executables than any other (every table
+    size is a program of its own), and a loaded XLA:CPU executable
+    holds its memory mappings for as long as ``jax.jit``'s cache keeps
+    it: a worker that went on to ``tests/test_codegen.py`` with them
+    met the kernel's ``vm.max_map_count`` (65,530) inside a compile and
+    died of it (PR 38).  Let them go when the module is done."""
+    yield
+    import gc
+
+    jax.clear_caches()
+    gc.collect()
+
+
 # ---- table properties ------------------------------------------------
 
 
@@ -771,6 +786,98 @@ def test_cli_check_of_the_253k_binding_presents_under_2_6_lanes(tmp_path):
     # ``MIN_STAGE``, so that step is not built and its rounds are the
     # 1/64 step's
     assert all(n > 0 for n in steps[1:6]) and steps[6] == 0
+
+
+# ---- fpset_slot_rounds: the table's slots summed over rounds (PR 38) --
+
+
+def _run_recording_fetches(fuse, visited_cap, seed=None):
+    """One run of the small binding; beside its ``last_stats``, what
+    every stats fetch found: the cumulative probe rounds and the
+    table's slots at that moment (a recording wrapper around
+    ``_fetch``, as ``tests/test_spans.py: _recorded_jits`` records
+    programs)."""
+    ck = DeviceChecker(
+        CompactionModel(SMALL_CONFIGS["producer_on"]), sub_batch=256,
+        fuse=fuse, visited_cap=visited_cap, frontier_cap=1 << 12,
+    )
+    seen = []
+    fetch = ck._fetch
+
+    def recording_fetch(st, vec=None):
+        out = fetch(st, vec)
+        seen.append((int(ck._last_fpm[1]), int(ck.TCAP)))
+        return out
+
+    ck._fetch = recording_fetch
+    r = ck.run(seed=seed)
+    return r, dict(ck.last_stats), seen
+
+
+def _slot_rounds_of(seen):
+    """The sum the counter should be, from what the fetches found, and
+    the rounds it covers."""
+    want, done = 0, 0
+    for rounds, cap in seen:
+        want += (rounds - done) * cap
+        done = rounds
+    return want, done
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_slot_rounds_sum_each_rounds_own_table(fuse):
+    """A table that grows at least twice inside the run: the counter
+    is the sum, over fetches, of the rounds since the last fetch times
+    the slots of the table they ran on, made here from what each fetch
+    found; so it lies strictly between all rounds at the first tier
+    and all rounds at the last."""
+    r, st, seen = _run_recording_fetches(fuse, 1 << 8)
+    assert r.distinct_states == 1654 and st["fpset_failures"] == 0
+    assert st["grow_rehashes"] >= 2
+    caps = sorted({cap for _rounds, cap in seen})
+    # (a crossing may double more than once: the rounds ran on fewer
+    # distinct tables than there were rehashes)
+    assert len(caps) >= 2 and caps[-1] == st["fpset_table_cap"]
+    want, done = _slot_rounds_of(seen)
+    assert done == st["fpset_probe_rounds"] > 0
+    assert len(seen) == st["stats_fetches"]
+    assert st["fpset_slot_rounds"] == want
+    assert (
+        st["fpset_probe_rounds"] * caps[0]
+        < st["fpset_slot_rounds"]
+        < st["fpset_probe_rounds"] * caps[-1]
+    )
+    assert st["fpset_slots_per_valid"] == round(
+        want / st["fpset_valid_lanes"], 4
+    )
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_slot_rounds_of_a_table_that_never_grows(fuse):
+    """One table all through: every round walked the same slots."""
+    r, st, seen = _run_recording_fetches(fuse, 1 << 16)
+    assert r.distinct_states == 1654
+    assert st.get("grow_rehashes", 0) == 0
+    assert {cap for _rounds, cap in seen} == {st["fpset_table_cap"]}
+    assert st["fpset_slot_rounds"] == (
+        st["fpset_probe_rounds"] * st["fpset_table_cap"]
+    )
+    assert st["fpset_slots_per_valid"] == round(
+        st["fpset_slot_rounds"] / st["fpset_valid_lanes"], 4
+    )
+
+
+def test_slot_rounds_count_a_seed_loads_rounds_on_the_table_it_grew():
+    """The seed load grows the table first and merges afterwards, with
+    no fetch between its merges: all their rounds ran on the table the
+    next fetch finds."""
+    m = CompactionModel(SMALL_CONFIGS["producer_on"])
+    seed = m.host_seed(max_level_states=200, max_total=600)
+    r, st, seen = _run_recording_fetches("level", 1 << 8, seed=seed)
+    assert r.distinct_states == 1654
+    want, done = _slot_rounds_of(seen)
+    assert seen[0][0] > 0  # the merges' rounds ride the first fetch
+    assert st["fpset_slot_rounds"] == want > 0
 
 
 # ---- _load_seed frontier-window guard (ADVICE r5 medium) -------------

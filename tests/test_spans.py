@@ -253,7 +253,7 @@ def _lowered_texts(monkeypatch, fuse):
 
 
 def test_level_kernel_carries_the_four_stage_scopes(monkeypatch):
-    txt = _lowered_texts(monkeypatch, "level")["ptt_level"]
+    txt = _lowered_texts(monkeypatch, "level")["ptt_level2"]
     found = set(re.findall(r"ptt\.[a-z]+", txt))
     assert set(STAGES) <= found and "ptt.levelctl" in found
     # a stage nests inside the kernel's own scope: innermost wins
@@ -264,9 +264,9 @@ def test_each_stage_jit_carries_its_scope(monkeypatch):
     texts = _lowered_texts(monkeypatch, "stage")
     for name, scope in (
         ("ptt_slice", "ptt.expand"), ("ptt_expand", "ptt.expand"),
-        ("ptt_fpflush", "ptt.probe"), ("ptt_compact", "ptt.compact"),
+        ("ptt_fpflush2", "ptt.probe"), ("ptt_compact", "ptt.compact"),
         ("ptt_append", "ptt.append"), ("ptt_stats", "ptt.levelctl"),
-        ("ptt_init", "ptt.init"), ("ptt_rehash", "ptt.rehash"),
+        ("ptt_init", "ptt.init"), ("ptt_rehash2", "ptt.rehash"),
     ):
         assert scope in texts[name], name
 
@@ -283,12 +283,12 @@ def test_every_operation_of_the_rehash_lies_under_its_scope(materialize):
     old = tuple(
         jax.ShapeDtypeStruct((slots + 1,), jnp.uint32) for _ in range(2)
     )
-    hlo = bodies.ptt_rehash.lower(
+    hlo = bodies.ptt_rehash2.lower(
         old, materialize=materialize
     ).compile().as_text()
     names = re.findall(r'op_name="([^"]*)"', hlo)
     scoped = [
-        n for n in names if n.startswith("jit(ptt_rehash)/ptt.rehash/")
+        n for n in names if n.startswith("jit(ptt_rehash2)/ptt.rehash/")
     ]
     assert len(scoped) > 500
     assert {n.rsplit("/", 1)[-1] for n in set(names) - set(scoped)} <= {
@@ -298,6 +298,205 @@ def test_every_operation_of_the_rehash_lies_under_its_scope(materialize):
     assert set(re.findall(r"ptt\.[a-z]+", hlo)) == {"ptt.rehash"}
     loops = {"shift": 4 + 3, "gather": 4}[materialize]
     assert len(re.findall(r" while\(", hlo)) == loops
+
+
+# ---- (c2) the parts of a probe (ISSUE 38) ------------------------------
+
+PARTS = ("gather", "claims_fill", "claims_bid", "write", "reread", "narrow")
+ROUND = "/while/body/"  # a probe round: the body of a step's loop
+
+
+def _stage_and_part(path):
+    """The rule of docs/observability.md "Parts", as the benchmark's
+    reader applies it: the innermost ``ptt.`` scope, and the innermost
+    ``part.`` scope below it."""
+    from benchmark.lib import probe_parts
+
+    return probe_parts.stage_and_part(path, "")
+
+
+def _primitive(path):
+    return path.rsplit("/", 1)[-1]
+
+
+@pytest.fixture(scope="module")
+def flush_unit_texts():
+    """The flush unit at a ``cli check`` flush's shape (65,536 lanes,
+    six ladder steps), as lowered and as compiled."""
+    shape = jax.ShapeDtypeStruct
+    lowered = bodies.ptt_fpflush2.lower(
+        tuple(shape(((1 << 17) + 1,), jnp.uint32) for _ in range(2)),
+        tuple(shape((1 << 16,), jnp.uint32) for _ in range(2)),
+        shape((), jnp.int32), shape((fpset.FPM_WIDE_N,), jnp.int32),
+        dense_rounds=fpset.DENSE_ROUNDS, stages=fpset.STAGES,
+        materialize="shift",
+    )
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    return lowered.as_text(debug_info=True), [
+        n for n in names if n.startswith("jit(ptt_fpflush2)/")
+    ]
+
+
+@pytest.mark.parametrize("part", PARTS)
+def test_the_flush_unit_carries_each_part_below_its_stage(
+    part, flush_unit_texts,
+):
+    """Every part is in what is traced, below ``ptt.probe`` (a round's
+    five inside the loop's body, ``narrow`` between the loops), and the
+    stage the existing readers' rule reads is ``probe`` for every
+    operation that carries it.  As compiled, each ladder step's loop
+    keeps its part on the operations the part is about (on the CPU the
+    refill's table-sized ``broadcast`` keeps no ``op_name`` at all, so
+    ``claims_fill`` is held to the traced text alone)."""
+    from benchmark.lib import program_spans
+
+    lowered, names = flush_unit_texts
+    where = (
+        "ptt.probe/part.narrow/" if part == "narrow"
+        else f"ptt.probe/while/body/part.{part}/"
+    )
+    assert where in lowered
+    mine = [n for n in names if _stage_and_part(n) == ("probe", part)]
+    assert all(program_spans.scope_of(n) == "probe" for n in mine)
+    assert not [n for n in names if f"part.{part}/" in n and n not in mine]
+    steps = len(fpset.ladder_steps(1 << 16, fpset.DENSE_ROUNDS, fpset.STAGES))
+    assert steps == 6
+    primitives = {_primitive(n) for n in mine}
+    want = {
+        "gather": {"gather", "eq"}, "claims_fill": set(),
+        "claims_bid": {"scatter-min", "gather"}, "write": {"scatter"},
+        "reread": {"gather", "eq"},
+        "narrow": {"select_n", "concatenate", "slice"},
+    }[part]
+    assert want <= primitives
+    for prim in want & {"gather", "scatter", "scatter-min"}:
+        # one a key column (K = 2) a step, or one a step for the bid
+        n = sum(_primitive(x) == prim for x in mine)
+        assert n >= steps and n % steps == 0, (prim, n)
+
+
+def test_every_table_operation_of_a_round_carries_a_part(flush_unit_texts):
+    """No ``gather``, ``scatter`` or ``scatter-min`` of a probe round is
+    under no part, each lies under the part that is about it, and the
+    ladder's compactions are wholly under ``narrow``: what is left
+    under the stage alone is the slot arithmetic, the masks, the sums
+    and the loops' shells."""
+    _lowered, names = flush_unit_texts
+    by_part = {}
+    for n in names:
+        stage, part = _stage_and_part(n)
+        assert stage == "probe", n
+        by_part.setdefault(part, []).append(n)
+    assert set(PARTS) - {"claims_fill"} | {"(no part)"} <= set(by_part)
+    assert set(by_part) <= set(PARTS) | {"(no part)"}
+    allowed = {
+        "gather": {"gather", "reread", "claims_bid", "narrow"},
+        "scatter": {"write", "narrow"}, "scatter-min": {"claims_bid"},
+    }
+    for part, paths in by_part.items():
+        for n in paths:
+            if _primitive(n) in allowed:
+                assert part in allowed[_primitive(n)], n
+            if part != "narrow" and part != "(no part)":
+                assert ROUND + f"part.{part}/" in n, n
+    loose = by_part["(no part)"]
+    assert not {_primitive(n) for n in loose} & {
+        "gather", "scatter", "scatter-min", "closed_call",
+        "shift_right_arithmetic", "dynamic_slice", "cumsum", "sort",
+    }
+    # the compactions are thousands of operations (6,324 of 7,121 named
+    # ones when the parts came): none of them outside ``narrow``
+    outside_rounds = [n for n in loose if ROUND not in n]
+    assert len(by_part["narrow"]) > 4000
+    assert len(outside_rounds) < len(by_part["narrow"]) // 10
+    assert len(loose) < len(names) // 6
+
+
+def test_the_rehash_reads_its_own_stage_with_the_probes_parts():
+    """``rehash_cols`` traces the same ``lookup_or_insert``: every
+    operation with a part reads stage ``rehash`` (the existing rule:
+    there is no ``ptt.probe`` in the program), and a round's parts are
+    all there."""
+    from benchmark.lib import program_spans
+
+    slots = 2 * fpset.REHASH_CHUNK
+    old = tuple(
+        jax.ShapeDtypeStruct((slots + 1,), jnp.uint32) for _ in range(2)
+    )
+    lowered = bodies.ptt_rehash2.lower(old, materialize="shift")
+    # (the lowered text nests its locations: an inner jit's paths are
+    # relative to its call site)
+    assert "while/body/part.claims_fill/broadcast_in_dim" in (
+        lowered.as_text(debug_info=True)
+    )
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    # (a scatter-min's own reducer keeps a path without the program's
+    # name, which no device event carries: the existing rehash test)
+    parted = [
+        n for n in names
+        if "/part." in n and n.startswith("jit(ptt_rehash2)/")
+    ]
+    assert len(parted) > 400
+    assert {_stage_and_part(n)[0] for n in parted} == {"rehash"}
+    assert {program_spans.scope_of(n) for n in parted} == {"rehash"}
+    # (whether the refill's broadcast keeps its ``op_name`` as compiled
+    # is the backend's: held to the traced text above)
+    found = {_stage_and_part(n)[1] for n in parted}
+    assert set(PARTS) - {"claims_fill"} <= found <= set(PARTS)
+    # the chunk's own packing is the rehash's, under no part
+    assert any(
+        _stage_and_part(n) == ("rehash", "(no part)")
+        and _primitive(n) == "concatenate" for n in names
+    )
+
+
+def test_part_scopes_hold_no_stage_prefix():
+    """What reads stages must read what it read before the parts: the
+    prefix matches no ``ptt.`` pattern, and a part entered under a
+    stage leaves the stage's name the only ``ptt.`` one."""
+    assert "ptt." not in spans.PART_PREFIX
+    assert not re.search(r"ptt\.([a-z_]+)", spans.PART_PREFIX + "gather")
+
+    @jax.jit
+    def program(x):
+        with spans.stage("probe"):
+            with spans.part("gather"):
+                return x + 1
+
+    txt = program.lower(jnp.zeros((4,))).as_text(debug_info=True)
+    assert "ptt.probe/part.gather/add" in txt
+    assert set(re.findall(r"ptt\.[a-z_]+", txt)) == {"ptt.probe"}
+
+
+def test_level_kernel_part_paths_are_the_same_on_a_miss_and_on_a_hit(
+    monkeypatch,
+):
+    """The three cases of the unit test below, for the parts alone: the
+    level kernel's ``op_name`` paths that carry a part are the same
+    when its body is traced (a miss), when JAX's cache answers (a hit)
+    and with the stage chain, which shares ``ops/fpset.py``'s bodies,
+    traced first; all six parts are among them, under ``ptt.probe``."""
+
+    def part_paths(lower):
+        txt = lower().as_text(debug_info=True)
+        return sorted(
+            n for n in re.findall(r'"(jit\(ptt_level2\)/[^"]*)"', txt)
+            if "/part." in n
+        )
+
+    level = _recorded_jits(monkeypatch, "level")["ptt_level2"]
+    jax.clear_caches()
+    miss = part_paths(level)
+    hit = part_paths(level)
+    jax.clear_caches()
+    for lower in _recorded_jits(monkeypatch, "stage").values():
+        lower()
+    stage_first = part_paths(level)
+    assert len(miss) > 30  # distinct paths: a location is written once
+    assert {_stage_and_part(n) for n in miss} == {
+        ("probe", p) for p in PARTS
+    }
+    assert miss == hit == stage_first
 
 
 def test_level_kernel_scopes_are_the_same_on_a_miss_and_on_a_hit(
@@ -311,9 +510,9 @@ def test_level_kernel_scopes_are_the_same_on_a_miss_and_on_a_hit(
 
     def op_names(lower):
         txt = lower().as_text(debug_info=True)
-        return sorted(re.findall(r'"(jit\(ptt_level\)/[^"]*)"', txt))
+        return sorted(re.findall(r'"(jit\(ptt_level2\)/[^"]*)"', txt))
 
-    level = _recorded_jits(monkeypatch, "level")["ptt_level"]
+    level = _recorded_jits(monkeypatch, "level")["ptt_level2"]
     meter = spans.compile_meter()
     jax.clear_caches()
     before = meter.snapshot()

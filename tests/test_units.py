@@ -217,7 +217,7 @@ def test_the_materialization_is_in_the_key(monkeypatch, base_verdict):
 # ---- (c) structure: module-level, closure-free, no self -----------------
 
 UNITS = (
-    "ptt_level", "ptt_expand", "ptt_init", "ptt_fpflush", "ptt_rehash",
+    "ptt_level2", "ptt_expand", "ptt_init", "ptt_fpflush2", "ptt_rehash2",
     "ptt_compact", "ptt_append", "ptt_grow",
 )
 
