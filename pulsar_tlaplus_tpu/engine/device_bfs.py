@@ -1416,7 +1416,10 @@ class DeviceChecker:
                 )
             off += count
         bufs["vk"] = vks
-        if int(np.asarray(st["fpm"])[2]):
+        fpm = np.asarray(st["fpm"])
+        # the merges' rounds ran at SEED_CHUNK's ladder, not a flush's
+        self._fold_lane_arb(fpm, NCs)
+        if int(fpm[2]):
             raise RuntimeError(
                 "fpset probe overflow while loading the seed — "
                 "raise visited_cap"
@@ -1999,6 +2002,11 @@ class DeviceChecker:
         self._slot_rounds = 0
         self._slot_rounds_at = 0
         self._slot_valid_at = 0
+        # fpset_lane_arb_rounds (PR 40): of those rounds, the ones
+        # arbitrated among the lanes, the rounds they are a share of,
+        # and the step rounds already folded
+        self._arb_rounds = self._arb_of = 0
+        self._arb_steps_at = np.zeros((fpset.FPM_STEPS,), np.int64)
         # work-unit state (r14): the ``work_*`` counters are PER-RUN
         # (cost attribution prices THIS run; a pooled checker's next
         # job must not inherit the last job's work), so clear them and
@@ -2361,6 +2369,20 @@ class DeviceChecker:
         nf = nv - level_base
         return t0, bufs, st, rb, level_sizes, level_base, nf, stats
 
+    def _fold_lane_arb(self, fpm, nq: int) -> None:
+        """fpset_lane_arb_rounds: of the probe rounds since the last
+        fold, all run by batches of ``nq`` lanes on the table as it is
+        now, those at a step of the ladder that arbitrates among its
+        lanes (``fpset.arbitrates_among_lanes``: a static rule, so the
+        host can apply it to ``fpset_step_rounds``' deltas)."""
+        steps = np.asarray(fpm, np.int64)[fpset.FPM_N:]
+        delta = steps - self._arb_steps_at
+        self._arb_rounds += fpset.lane_arb_rounds(
+            delta, nq, self.TCAP, self.fps_dense, self.fps_stages
+        )
+        self._arb_of += int(delta.sum())
+        self._arb_steps_at = steps
+
     def _fetch(self, st, vec=None):
         """One stats fetch (the only hot-path host sync): returns the
         numpy stats vector and fail-stops on fpset probe overflow.
@@ -2428,6 +2450,7 @@ class DeviceChecker:
         rounds = int(self._last_fpm[1])
         self._slot_rounds += (rounds - self._slot_rounds_at) * self.TCAP
         self._slot_rounds_at = rounds
+        self._fold_lane_arb(self._last_fpm, self.ACAP)
         self._snap["occupancy"] = nv / max(self.TCAP, 1)
         if len(self._last_fpm) >= 4:
             # TLC's "states generated": candidate lanes examined
@@ -3908,6 +3931,8 @@ class DeviceChecker:
         self._slot_rounds = 0
         self._slot_rounds_at = int(self._fpm_prev[1])
         self._slot_valid_at = int(self._fpm_prev[3])
+        self._arb_rounds = self._arb_of = 0
+        self._arb_steps_at = fpm[fpset.FPM_N:].astype(np.int64)
         if self.fuse == "level":
             # work counters restart after resume (frames don't carry
             # them — the same regime as the r8 counter widenings);
@@ -4155,6 +4180,13 @@ class DeviceChecker:
                     fpset_slots_per_valid=round(
                         self._slot_rounds / (vl - self._slot_valid_at), 4
                     ) if vl > self._slot_valid_at else None,
+                    # of this run's probe rounds, those arbitrated
+                    # among the lanes, with no ``claims`` array at the
+                    # table's size (folded in _fetch; PR 40)
+                    fpset_lane_arb_rounds=self._arb_rounds,
+                    fpset_lane_arb_rounds_pct=round(
+                        100.0 * self._arb_rounds / self._arb_of, 4
+                    ) if self._arb_of else None,
                 )
         # fusion telemetry (r13): this run's total dispatches per BFS
         # level — the regression-gate signal (steady-state fused levels

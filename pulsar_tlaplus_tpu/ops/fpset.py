@@ -56,9 +56,29 @@ probing design generalised to the device hot path:
   with its original lane id whichever buffer holds it, so winners,
   table and round count are those of the single loop, bit for bit.
 - **Deterministic discovery order.**  Equal-key lanes resolve to the
-  minimum lane id (scatter-min bidding; compaction is order-preserving
-  and stages bid with original lane ids): "lowest accumulator slot
-  wins", so gids follow lane order whatever the table's layout.
+  minimum lane id (the least ``lane_ids`` among the bidders of a slot
+  wins it; compaction is order-preserving and stages bid with original
+  lane ids): "lowest accumulator slot wins", so gids follow lane order
+  whatever the table's layout.
+- **Two arbitrations, one result** (PR 40).  Which bidder of an empty
+  slot wins is found in one of two ways, chosen from the static shapes
+  of the round alone (:func:`arbitrates_among_lanes`, a function of
+  the buffer's lanes ``nq`` and the table's slots ``cap``):
+  :func:`win_by_claims` refills a ``claims`` array of ``cap + 1`` lane
+  ids, scatter-mins the bidders' ids into it and reads it back — two
+  passes over the table's size a round, and by the lane scattered, so
+  it is the WIDE round's (a flagship flush scatters 26.7M lanes into
+  2^27 slots in 0.23 s); :func:`win_among_lanes` compares the ``nq``
+  lanes with each other — a lane wins unless another bidder has its
+  slot and a smaller lane id — which touches nothing of the table's
+  size and costs ``nq * nq`` compares, so it is the NARROW round's
+  (a ``cli check`` of 9.4M states runs 17,857 of its 23,133 rounds at
+  1,024 lanes on tables of up to 2^25 slots, where the ``claims``
+  passes were 5.3 s of a 23.9 s check: ``LANE_ARB_*`` below, with the
+  chip's readings).  Min-lane-wins holds on both, same-key losers
+  resolve in the round's reread and a loser with another key moves
+  on, so ``(is_new, table, pending, rounds)`` are the same bit for bit
+  and the choice is invisible to every caller.
 - **On-device growth**: :func:`rehash_cols` re-inserts every occupied
   slot of the old table into the new one, fully on device: a
   `fori_loop` over chunks of ``REHASH_CHUNK`` old slots; a chunk's
@@ -439,6 +459,88 @@ QUARTER_ABOVE = 1 << 20
 
 _NO_LANE = jnp.int32(2**31 - 1)  # claims fill: above every real lane id
 
+# A round whose buffer is NARROW against the table picks the winner of
+# a slot among its ``nq`` lanes and builds no ``claims`` array (PR 40,
+# :func:`win_among_lanes`): up to ``LANE_ARB_MAX_LANES`` lanes, on a
+# table of ``LANE_ARB_MIN_SLOTS`` slots or more, where the ``nq * nq``
+# pairs are no more than ``LANE_ARB_PAIRS_A_SLOT`` a slot of the table
+# — up to 8,192 lanes from 2^22 slots, 16,384 from 2^24, nothing wider
+# and nothing on a smaller table.
+# Measured on a v5e (``scripts/profile.py arbitrate``, my chip runs,
+# PR 40), microseconds a round, arbitration alone: the pairwise
+# min-reduce 1.5 / 4.4 / 15 / 66 / 262 at 1,024 / 2,048 / 4,096 /
+# 8,192 / 16,384 lanes (about 1 ps a pair, whatever the table; as two
+# compares and an or-reduce 2.4 / 8.1 / 28 / 102 / 404; one sort by
+# slot and id and a scatter back 11 / 28 / 51 / 75 / 122, and 5.7 s to
+# compile at 16,384 lanes against 0.8); the ``claims`` array 16 / 30 /
+# 59 / 117 / 232 on 2^18 and 2^20 slots (14 ns a lane: it goes by the
+# lane scattered too), 90 / 152 / 274 / 520 at 2^24 and 320 / 432 /
+# 659 / 1,110 / 2,014 at 2^25 (a refill and a pass of the table, and
+# 0.1 us a lane scattered into a table that large).  Inside
+# ``probe_insert`` on a table at load 0.3, claims path less lane path
+# a round: 1,024 lanes +16 (of 60) at 2^18 slots, +56 at 2^22, +148 at
+# 2^24, +333 (of 621) at 2^25; 4,096 lanes +40 (of 227) / +56 / +490 /
+# +698; 8,192 lanes -52 at 2^18, +32 at 2^20, +125 at 2^22, +336 at
+# 2^24, +1,142 (of 3,514) at 2^25; 16,384 lanes -307 / -138 / -124 /
+# +152 / +1,882 (of 6,826) at 2^18 / 2^20 / 2^22 / 2^24 / 2^25, all
+# with the two-compare form, the min-reduce a third cheaper: the
+# crossover follows ``nq * nq = 16 * cap``.  Under 2^22 slots the
+# lanes win 16 to 40 us a round up to 4,096 lanes, on tables whose
+# checks are short (a ``cli check`` of 253,361 states runs 316 rounds:
+# 5 ms of 420), and XLA:CPU spends 0.7 ns a pair (0.7 ms a round at
+# 1,024 lanes where its ``claims`` round is 15 us), which every test
+# of tier-1 would pay: the floor keeps small tables, and the programs
+# of the two small CLI cells, as they were.
+LANE_ARB_MAX_LANES = 1 << 14
+LANE_ARB_PAIRS_A_SLOT = 16
+LANE_ARB_MIN_SLOTS = 1 << 22
+
+
+def arbitrates_among_lanes(nq: int, cap: int) -> bool:
+    """Whether a probe round of ``nq`` lanes on a table of ``cap``
+    slots arbitrates among its lanes (:func:`win_among_lanes`) and not
+    through a ``claims`` array (:func:`win_by_claims`).  The static
+    shapes alone decide; the winners are the same either way."""
+    return (
+        nq <= LANE_ARB_MAX_LANES
+        and cap >= LANE_ARB_MIN_SLOTS
+        and nq * nq <= LANE_ARB_PAIRS_A_SLOT * cap
+    )
+
+
+def win_by_claims(bid, s, lane_ids, cap: int):
+    """The winner of every empty slot through a ``claims`` array of the
+    table's size: refilled, bid into with a scatter-min of the lane
+    ids, and read back — bool[nq], True for the bidding lane with the
+    least ``lane_ids`` of its slot.  ``s`` is the lane's slot (``cap``
+    for a parked lane), ``bid`` the lanes that bid for theirs.  It
+    costs by the table (two passes over ``cap + 1`` words a round) and
+    by the lane scattered, so it is the wide round's arbitration."""
+    bid_slot = jnp.where(bid, s, cap)
+    with spans.part("claims_fill"):
+        claims = jnp.full((cap + 1,), _NO_LANE, jnp.int32)
+    with spans.part("claims_bid"):
+        claims = claims.at[bid_slot].min(lane_ids)
+        return bid & (claims[s] == lane_ids)
+
+
+def win_among_lanes(bid, s, lane_ids, cap: int):
+    """The same winners found among the ``nq`` lanes themselves: a lane
+    wins if the least ``lane_ids`` among the bidders of its slot is its
+    own.  One pairwise ``[nq, nq]`` compare, select and min-reduce (the
+    compiler fuses it: no ``[nq, nq]`` value exists) and nothing of the
+    table's size, so it costs ``nq * nq`` pairs whatever the table."""
+    bid_slot = jnp.where(bid, s, cap)  # no bidder shares a parked lane's
+    with spans.part("claims_bid"):
+        least = jnp.min(
+            jnp.where(
+                bid_slot[None, :] == bid_slot[:, None],
+                lane_ids[None, :], _NO_LANE,
+            ),
+            axis=1,
+        )
+        return bid & (least == lane_ids)
+
 
 def slot_hash(kcols: Tuple[jax.Array, ...]) -> jax.Array:
     """Mix K key columns into a table-index basis (u32).  Exact keys
@@ -493,9 +595,14 @@ def probe_insert(
 
     Probe round r inspects slot ``(h + r(r+1)/2) & (cap-1)`` (covers
     every slot when cap is a power of two); lanes seeing their key
-    resolve as duplicates; lanes seeing an empty slot bid for it with a
-    scatter-min of their lane id (the unique winner writes its key, and
-    same-key losers resolve against the freshly written slot).
+    resolve as duplicates; lanes seeing an empty slot bid for it with
+    their lane id, and the least id of a slot wins it (the unique
+    winner writes its key, and same-key losers resolve against the
+    freshly written slot).  The winner is found through a ``claims``
+    array of the table's size (:func:`win_by_claims`) or, where the
+    buffer is narrow against the table, among the lanes themselves
+    (:func:`win_among_lanes`): :func:`arbitrates_among_lanes` chooses
+    from ``nq`` and ``cap``, and every output is the same either way.
 
     ``occ`` selects the empty-slot encoding: ``None`` = all-SENTINEL
     key (the engines' layout), else an explicit occupancy column (the
@@ -519,6 +626,10 @@ def probe_insert(
     capm = jnp.uint32(cap - 1)
     has_occ = occ is not None
     occ0 = occ if has_occ else jnp.zeros((0,), jnp.int32)
+    arbitrate = (
+        win_among_lanes if arbitrates_among_lanes(nq, cap)
+        else win_by_claims
+    )
 
     def occupied_at(tc, oc, s, sv):
         if has_occ:
@@ -553,12 +664,7 @@ def probe_insert(
         pending = pending & ~found
         # bid for empty slots with the lane id; min wins
         bid = pending & ~occ_s
-        bid_slot = jnp.where(bid, s, cap)
-        with spans.part("claims_fill"):
-            claims = jnp.full((cap + 1,), _NO_LANE, jnp.int32)
-        with spans.part("claims_bid"):
-            claims = claims.at[bid_slot].min(lane_ids)
-            win = bid & (claims[s] == lane_ids)
+        win = arbitrate(bid, s, lane_ids, cap)
         ws = jnp.where(win, s, cap)
         with spans.part("write"):
             tc = tuple(c.at[ws].set(k) for c, k in zip(tc, kcols))
@@ -608,6 +714,21 @@ def ladder_steps(nq: int, dense_rounds: int, stages, max_probes=MAX_PROBES):
         else:
             ladder.append((capi, limit, j))
     return ladder
+
+
+def lane_arb_rounds(step_rounds, nq: int, cap: int, dense_rounds, stages):
+    """Of the probe rounds by the schedule's entry (``fpset_step_rounds``,
+    or the difference of two fetches), those run at a step that
+    arbitrates among its lanes when the batch is ``nq`` lanes wide and
+    the table ``cap`` slots: the host's fold of
+    ``fpset_lane_arb_rounds``, from the same static shapes that
+    :func:`probe_insert` chose by."""
+    words = {
+        min(entry, len(step_rounds) - 1)
+        for width, _, entry in ladder_steps(nq, dense_rounds, stages)
+        if arbitrates_among_lanes(width, cap)
+    }
+    return sum(int(step_rounds[w]) for w in words)
 
 
 def lookup_or_insert(

@@ -7,7 +7,7 @@ docstring for the probing/bidding algorithm and the discovery-order
 guarantee).  The host-loop engines
 (``engine/core.py``, ``engine/bfs.py``, ``engine/sharded.py``) keep
 this module's original fixed 3-column + occupancy-column API on
-``fpset.probe_insert``'s triangular probing and scatter-min bidding.
+``fpset.probe_insert``'s triangular probing and min-lane bidding.
 
 Layout: four uint32[cap + 1] columns — three key words plus an
 occupancy column.  ``cap`` is a power of two; slot ``cap`` is the

@@ -13,6 +13,9 @@ dispatch timing, completion barriers) live in one place:
     python scripts/profile.py bucket
     python scripts/profile.py calibrate [--out calibration.json]  # r14:
         # unit costs for the work-unit cost-attribution model
+    python scripts/profile.py ladder    [--schedules ...]  # PR 37
+    python scripts/profile.py arbitrate [--widths ...] [--caps-log2 ...]
+    python scripts/profile.py scatter   [--updates ...] [--caps-log2 ...]
 
 Mapping from the retired scripts:
 
@@ -804,26 +807,17 @@ def cmd_calibrate(args):
 # ------------------------------------------------------------- ladder
 
 
-def cmd_ladder(args):
-    """Seconds a flush for each probe schedule at ONE flush shape: the
-    chip's answer to which steps of ``fpset.lookup_or_insert``'s
-    ladder pay for themselves (PR 37).  The table is pre-filled to
-    ``--load``; every flush presents ``--lanes`` lanes of which a
-    ``--valid`` share is valid, a ``--dup`` share of those keys the
-    table holds already and the rest new; ``--reps`` flushes run in
-    one dispatch, each on the table the one before left."""
-    import json
-
+def _prefilled(cap, K, load):
+    """A table of ``cap`` slots holding ``load * cap`` keys, and the
+    key function (column 0 is a bijection of the index)."""
     from pulsar_tlaplus_tpu.ops import fpset
     from pulsar_tlaplus_tpu.ops.dedup import _fmix
 
-    nq, cap, K = args.lanes, 1 << args.cap_log2, args.cols
-    n_pre = int(args.load * cap)
-    chunk = 1 << 20
     u = jnp.uint32
+    n_pre = int(load * cap)
+    chunk = min(1 << 18, cap // 2)
 
     def keys_of(idx):
-        # col 0 is a bijection of idx, so distinct idx are distinct keys
         return tuple(
             _fmix(idx ^ u((0x9E3779B9 * (c + 1)) & 0xFFFFFFFF))
             for c in range(K)
@@ -840,7 +834,25 @@ def cmd_ladder(args):
             return tc
         return lax.fori_loop(0, -(-n_pre // chunk), body, tcols)
 
-    table = barrier(prefill(fpset.empty_cols(cap, K)))
+    return barrier(prefill(fpset.empty_cols(cap, K))), keys_of, n_pre
+
+
+def cmd_ladder(args):
+    """Seconds a flush for each probe schedule at ONE flush shape: the
+    chip's answer to which steps of ``fpset.lookup_or_insert``'s
+    ladder pay for themselves (PR 37).  The table is pre-filled to
+    ``--load``; every flush presents ``--lanes`` lanes of which a
+    ``--valid`` share is valid, a ``--dup`` share of those keys the
+    table holds already and the rest new; ``--reps`` flushes run in
+    one dispatch, each on the table the one before left."""
+    import json
+
+    from pulsar_tlaplus_tpu.ops import fpset
+    from pulsar_tlaplus_tpu.ops.dedup import _fmix
+
+    nq, cap, K = args.lanes, 1 << args.cap_log2, args.cols
+    u = jnp.uint32
+    table, keys_of, n_pre = _prefilled(cap, K, args.load)
     lane = jnp.arange(nq, dtype=u)
     share = lambda x: u(int(x * 65536))  # noqa: E731
 
@@ -888,6 +900,233 @@ def cmd_ladder(args):
             }
             print(json.dumps(row), flush=True)
             f.write(json.dumps(row) + "\n")
+    return 0
+
+
+# ---------------------------------------------------------- arbitrate
+
+
+def _win_by_sort(bid, s, lane_ids, cap):
+    """The sort form of the lane arbitration (ISSUE 40's other
+    candidate, kept here for the measurement): one sort by (slot, lane
+    id) carrying the position, the head of each slot's run wins, and
+    the flags go back by position."""
+    nq = s.shape[0]
+    bid_slot = jnp.where(bid, s, cap)
+    pos = jnp.arange(nq, dtype=jnp.int32)
+    ss, _, sp = lax.sort((bid_slot, lane_ids, pos), num_keys=2)
+    head = jnp.concatenate([jnp.ones((1,), jnp.bool_), ss[1:] != ss[:-1]])
+    won = jnp.zeros((nq,), jnp.bool_).at[sp].set(head, unique_indices=True)
+    return bid & won
+
+
+def _win_by_lane_or(bid, s, lane_ids, cap):
+    """The pairwise form as an or-reduce (two compares, an and and an
+    or a pair, against ``fpset.win_among_lanes``' compare, select and
+    min): a lane wins unless another bidder has its slot and a smaller
+    id."""
+    bid_slot = jnp.where(bid, s, cap)
+    beaten = jnp.any(
+        (bid_slot[None, :] == bid_slot[:, None])
+        & (lane_ids[None, :] < lane_ids[:, None]),
+        axis=1,
+    )
+    return bid & ~beaten
+
+
+def cmd_arbitrate(args):
+    """Microseconds a round for the probe's arbitrations (PR 40): the
+    ``claims`` array at the table's size against the pairwise compare
+    among the lanes (and the sort form), bare in a ``fori_loop`` and
+    inside ``fpset.probe_insert`` on a pre-filled table with the rule
+    held to each side.  It is the measurement behind
+    ``fpset.arbitrates_among_lanes``."""
+    import json
+
+    from pulsar_tlaplus_tpu.ops import fpset
+    from pulsar_tlaplus_tpu.ops.dedup import _fmix
+
+    u = jnp.uint32
+    widths = [int(x) for x in args.widths.split(",")]
+    caps = [1 << int(x) for x in args.caps_log2.split(",")]
+    forms = {
+        "claims": fpset.win_by_claims,
+        "lanes": fpset.win_among_lanes,
+        "lanes_or": _win_by_lane_or,
+        "sort": _win_by_sort,
+    }
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    out = open(args.out, "a")
+
+    def emit(row):
+        row["device"] = jax.devices()[0].device_kind
+        print(json.dumps(row), flush=True)
+        out.write(json.dumps(row) + "\n")
+        out.flush()
+
+    def bare(form, nq, cap, reps):
+        lane = jnp.arange(nq, dtype=u)
+        ids = lane.astype(jnp.int32) * 3 + 7  # a compacted buffer's ids
+
+        def body(i, acc):
+            h = _fmix(lane ^ _fmix(i.astype(u) + acc.astype(u)))
+            s = (h & u(cap - 1)).astype(jnp.int32)
+            bid = ((h >> 24) & u(0xFF)) < u(77)  # 30% bid
+            if form is None:
+                return acc + jnp.sum((bid & (s > 3)).astype(jnp.int32))
+            win = form(bid, jnp.where(bid, s, cap), ids, cap)
+            return acc + jnp.sum(win.astype(jnp.int32))
+        return jax.jit(lambda: lax.fori_loop(0, reps, body, jnp.int32(0)))
+
+    if "bare" in args.sections:
+        for nq in widths:
+            _, base = timed(f"bare none {nq}", bare(None, nq, caps[0],
+                                                    args.reps))
+            for name, form in forms.items():
+                for cap in (caps if name == "claims" else caps[-1:]):
+                    t0 = time.time()
+                    fn = bare(form, nq, cap, args.reps)
+                    _, med = timed(f"bare {name} {nq} 2^{cap.bit_length()-1}",
+                                   fn)
+                    emit({
+                        "section": "bare", "form": name, "lanes": nq,
+                        "cap_log2": cap.bit_length() - 1,
+                        "us_a_round": round(
+                            (med - base) * 1e6 / args.reps, 3),
+                        "first_call_s": round(time.time() - t0, 2),
+                    })
+
+    if "probe" in args.sections:
+        K = 2
+        real_rule = fpset.arbitrates_among_lanes
+        for cap in caps:
+            table, keys_of, n_pre = _prefilled(cap, K, args.load)
+            for nq in widths:
+                # what an iteration adds stays under a tenth of the table
+                reps = max(2, min(args.reps, int(0.1 * cap / (0.15 * nq))))
+                lane = jnp.arange(nq, dtype=u)
+
+                def loop(tc):
+                    def body(i, carry):
+                        tc, rounds = carry
+                        h = _fmix(lane ^ _fmix(i.astype(u) + u(0x51ED27)))
+                        valid = (h & u(0xFF)) < u(128)
+                        dup = ((h >> 8) & u(0xFF)) < u(179)
+                        old = _fmix(h) % u(max(n_pre, 1))
+                        new = u(n_pre) + i.astype(u) * u(nq) + lane
+                        idx = jnp.where(dup, old, new)
+                        _, tc, _, _, r = fpset.probe_insert(
+                            tc, keys_of(idx), valid)
+                        return tc, rounds + r
+                    return lax.fori_loop(
+                        0, reps, body, (tc, jnp.int32(0)))[1]
+
+                for name, rule in (
+                    ("claims", lambda nq, cap: False),
+                    ("lanes", lambda nq, cap: True),
+                ):
+                    fpset.arbitrates_among_lanes = rule
+                    try:
+                        # a new function a side: one traced under the
+                        # other rule must not be found again
+                        rounds, med = timed(
+                            f"probe {name} {nq} 2^{cap.bit_length()-1}",
+                            jax.jit(lambda tc, _f=loop: _f(tc)), table)
+                    finally:
+                        fpset.arbitrates_among_lanes = real_rule
+                    emit({
+                        "section": "probe", "form": name, "lanes": nq,
+                        "cap_log2": cap.bit_length() - 1, "reps": reps,
+                        "rounds": int(rounds),
+                        "us_a_round": round(
+                            med * 1e6 / max(int(rounds), 1), 3),
+                        "rule": bool(real_rule(nq, cap)),
+                    })
+            del table
+    return 0
+
+
+# ------------------------------------------------------------ scatter
+
+
+def cmd_scatter(args):
+    """Microseconds a scatter for the probe's column write (the part
+    ``write``; PR 40, for the PR after it): ``--updates`` lanes into a
+    ``u32[cap + 1]`` column carried by a ``fori_loop``, as
+    ``probe_insert`` writes it (non-winners parked on the trash row)
+    and with the candidates: non-winners dropped out of bounds,
+    ``unique_indices`` (a hint that the parked lanes belie: a timing,
+    not a result), every lane a winner with and without
+    ``indices_are_sorted``, and two columns in one loop.  Whether the
+    seconds go by the slot or by the round shows in the rows of one
+    width across the table sizes."""
+    import json
+
+    from pulsar_tlaplus_tpu.ops.dedup import _fmix
+
+    u = jnp.uint32
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    out = open(args.out, "a")
+
+    def variant(name, nq, cap, reps):
+        lane = jnp.arange(nq, dtype=u)
+
+        def body(i, cols):
+            h = _fmix(lane ^ _fmix(i.astype(u) + cols[0][cap]))
+            if "allwin" in name:
+                win = lane >= u(0)
+            else:
+                win = ((h >> 24) & u(0xFF)) < u(int(args.win * 256))
+            if "sorted" in name:
+                # distinct ascending slots, a random start a round
+                s = (h[0] & u(cap // 2 - 1)) + lane * u(cap // (2 * nq))
+            else:
+                s = h & u(cap - 1)
+            s = s.astype(jnp.int32)
+            kw = {}
+            if "drop" in name:
+                ws, kw["mode"] = jnp.where(win, s, cap + 1), "drop"
+            else:
+                ws = jnp.where(win, s, cap)
+            if "unique" in name:
+                kw["unique_indices"] = True
+            if "sorted" in name:
+                kw["indices_are_sorted"] = True
+            return tuple(
+                c.at[ws].set(h ^ u(j + 1), **kw)
+                for j, c in enumerate(cols)
+            )
+        ncols = 2 if "two" in name else 1
+        return jax.jit(
+            lambda cols: lax.fori_loop(0, reps, body, cols),
+            donate_argnums=0,
+        ), ncols
+
+    for cap_log2 in (int(x) for x in args.caps_log2.split(",")):
+        cap = 1 << cap_log2
+        for nq in (int(x) for x in args.updates.split(",")):
+            for name in args.variants.split(","):
+                fn, ncols = variant(name, nq, cap, args.reps)
+                cols = tuple(
+                    jnp.full((cap + 1,), 0xFFFFFFFF, u) for _ in range(ncols)
+                )
+                cols = barrier(fn(cols))  # compile
+                times = []
+                for _ in range(3):
+                    t0 = time.time()
+                    cols = barrier(fn(cols))
+                    times.append(time.time() - t0)
+                row = {
+                    "variant": name, "updates": nq, "cap_log2": cap_log2,
+                    "win": args.win, "columns": ncols,
+                    "us_a_scatter": round(
+                        sorted(times)[1] * 1e6 / args.reps / ncols, 3),
+                    "device": jax.devices()[0].device_kind,
+                }
+                print(json.dumps(row), flush=True)
+                out.write(json.dumps(row) + "\n")
+                out.flush()
+                del cols
     return 0
 
 
@@ -975,6 +1214,35 @@ def main(argv=None):
         "'@shift' / '@roll' / '@gather' for its compactions")
     pd.add_argument("--out", default="chiprun_out/ladder.jsonl")
     pd.set_defaults(fn=cmd_ladder)
+
+    pa = sub.add_parser(
+        "arbitrate", help="microseconds a probe round by arbitration: "
+        "the claims array against the compare among the lanes")
+    pa.add_argument("--widths", default="1024,2048,4096,8192,16384")
+    pa.add_argument("--caps-log2", default="18,20,22,24,25")
+    pa.add_argument("--sections", default="bare,probe")
+    pa.add_argument("--load", type=float, default=0.3)
+    pa.add_argument("--reps", type=int, default=200)
+    pa.add_argument("--out", default="chiprun_out/arbitrate.jsonl")
+    pa.set_defaults(fn=cmd_arbitrate)
+
+    pw = sub.add_parser(
+        "scatter", help="microseconds a scatter for the probe's column "
+        "write, as it is and by the candidates of the next PR")
+    pw.add_argument("--updates", default="1024,4096")
+    pw.add_argument("--caps-log2", default="20,24,25")
+    pw.add_argument("--variants",
+                    default="as_is,drop,unique,drop_unique,allwin,"
+                    "allwin_unique,allwin_sorted_unique,two_as_is",
+                    help="names made of: drop (non-winners out of "
+                    "bounds, not on the trash row), unique, sorted "
+                    "(the scatter's hints; sorted wants allwin), "
+                    "allwin (every lane writes), two (two columns)")
+    pw.add_argument("--win", type=float, default=0.1,
+                    help="share of the lanes that win a slot")
+    pw.add_argument("--reps", type=int, default=500)
+    pw.add_argument("--out", default="chiprun_out/scatter.jsonl")
+    pw.set_defaults(fn=cmd_scatter)
 
     args = ap.parse_args(argv)
     return args.fn(args) or 0

@@ -880,6 +880,417 @@ def test_slot_rounds_count_a_seed_loads_rounds_on_the_table_it_grew():
     assert st["fpset_slot_rounds"] == want > 0
 
 
+# ---- the probe's two arbitrations (PR 40) ----------------------------
+
+ARB_WIDTHS = [1024, 2048, 4096, 8192, 16384]
+
+
+def _arb_case(case, nq, cap, seed):
+    """``(bid, s, lane_ids)`` of one round: which lanes bid, the slot
+    each lane looks at (``cap`` for a parked one) and the ids they bid
+    with."""
+    rng = np.random.default_rng(seed)
+    lane_ids = np.arange(nq, dtype=np.int32)
+    bid = np.ones((nq,), bool)
+    if case == "one_slot":
+        # every lane bids for the same slot
+        s = np.full((nq,), 5, np.int32)
+    elif case == "own_slots":
+        s = rng.permutation(cap)[:nq].astype(np.int32)
+    elif case == "groups":
+        # runs of 1 to 40 lanes a slot (same-key duplicates and other
+        # keys alike are just lanes on one slot here), 30% bidding
+        s = rng.integers(0, max(nq // 8, 1), size=nq).astype(np.int32)
+        bid = rng.random(nq) < 0.3
+    elif case == "parked":
+        # most lanes parked on the trash row; a parked lane's id may
+        # be below every bidder's and must not beat them
+        s = rng.integers(0, 64, size=nq).astype(np.int32)
+        bid = rng.random(nq) < 0.05
+        s = np.where(rng.random(nq) < 0.5, s, cap).astype(np.int32)
+        bid &= s < cap
+    elif case == "compacted_ids":
+        # a compacted buffer: sorted sparse original ids, garbage ids
+        # (zeros) on the lanes past the pending ones
+        s = rng.integers(0, nq // 4, size=nq).astype(np.int32)
+        lane_ids = np.sort(
+            rng.choice(1 << 24, size=nq, replace=False)
+        ).astype(np.int32)
+        npend = nq // 3
+        bid = (np.arange(nq) < npend) & (rng.random(nq) < 0.7)
+        lane_ids[npend:] = 0
+        s[npend:] = cap
+    elif case == "unordered_ids":
+        # ids in no order: the least ID wins, not the first position
+        s = rng.integers(0, nq // 16, size=nq).astype(np.int32)
+        lane_ids = rng.permutation(nq).astype(np.int32)
+        bid = rng.random(nq) < 0.6
+    else:
+        raise AssertionError(case)
+    return bid, s, lane_ids
+
+
+@pytest.mark.parametrize("nq", ARB_WIDTHS)
+@pytest.mark.parametrize(
+    "case",
+    ["one_slot", "own_slots", "groups", "parked", "compacted_ids",
+     "unordered_ids"],
+)
+def test_the_two_arbitrations_pick_the_same_winners(case, nq):
+    """``win_among_lanes`` against ``win_by_claims`` on the same round,
+    and both against the definition: the bidder with the least id of
+    its slot."""
+    cap = 1 << 15
+    bid, s, lane_ids = _arb_case(case, nq, cap, seed=nq + len(case))
+    args = (jnp.asarray(bid), jnp.asarray(s), jnp.asarray(lane_ids), cap)
+    # jitted, as a round runs them: the pairwise compare fuses, and no
+    # [nq, nq] value (1 GiB at 16,384 lanes) is built
+    by_claims, among = (
+        np.asarray(jax.jit(form, static_argnums=3)(*args))
+        for form in (fpset.win_by_claims, fpset.win_among_lanes)
+    )
+    best = {}
+    for i in np.flatnonzero(bid):
+        best[s[i]] = min(best.get(s[i], 1 << 31), lane_ids[i])
+    want = bid & np.array(
+        [best.get(s[i]) == lane_ids[i] for i in range(nq)]
+    )
+    assert np.array_equal(by_claims, want)
+    assert np.array_equal(among, want)
+    assert want.sum() == len(best)
+
+
+def _held_to(monkeypatch, among_lanes):
+    """Hold the rule to one side for what is traced next."""
+    monkeypatch.setattr(
+        fpset, "arbitrates_among_lanes", lambda nq, cap: among_lanes
+    )
+
+
+def _colliding_batch(nq, ncols, cap, seed):
+    """A batch that meets every case of a round at once on a table of
+    ``cap`` slots at load 0.3: keys the table holds, new keys, each new
+    key up to five times in the batch, far more lanes than slots (so
+    different keys bid for one slot), and lanes that are not valid."""
+    held, tcols = _table_at_load(cap, 0.3, ncols, seed)
+    rng = np.random.default_rng(seed + 1)
+    fresh = rng.integers(0, 2**32 - 2, size=(nq // 4, ncols),
+                         dtype=np.uint32)
+    pool = np.concatenate([held[: nq // 8], fresh])
+    keys = pool[rng.integers(0, len(pool), size=nq)]
+    valid = rng.random(nq) < 0.8
+    return held, tcols, keys, valid
+
+
+def _first_lanes_of_new_keys(held, keys, valid):
+    """A Python set's answer: the first valid lane of every key that
+    ``held`` does not hold."""
+    seen = {tuple(k) for k in held}
+    want = np.zeros((len(keys),), bool)
+    for i in np.flatnonzero(valid):
+        if tuple(keys[i]) not in seen:
+            seen.add(tuple(keys[i]))
+            want[i] = True
+    return want
+
+
+@pytest.mark.parametrize("layout", ["sentinel", "occ"])
+@pytest.mark.parametrize("nq", [1024, 4096])
+def test_probe_insert_is_the_same_by_either_arbitration(
+    monkeypatch, nq, layout
+):
+    """One ``probe_insert`` loop by both arbitrations: ``is_new``, the
+    table, ``occ``, ``pending`` and ``rounds`` bit for bit, and
+    ``is_new`` the first valid lane of every key the table did not
+    hold (a Python set's answer)."""
+    ncols, cap = 2, 1 << 11
+    held, tcols, keys, valid = _colliding_batch(nq, ncols, cap, seed=nq)
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
+    occ = None
+    if layout == "occ":
+        occ = fpset.occupied_mask(tcols).astype(jnp.int32)
+        occ = jnp.concatenate([occ, jnp.zeros((1,), jnp.int32)])
+        # the occ layout tells an empty slot by the column alone: put
+        # a key no lane carries where the SENTINEL marker was
+        tcols = tuple(
+            jnp.where(occ == 1, c, jnp.uint32(7)) for c in tcols
+        )
+    got = {}
+    for among_lanes in (False, True):
+        _held_to(monkeypatch, among_lanes)
+        got[among_lanes] = fpset.probe_insert(
+            tcols, kcols, jnp.asarray(valid), occ=occ, max_probes=3,
+        )
+    (new_c, cols_c, occ_c, pend_c, r_c) = got[False]
+    (new_l, cols_l, occ_l, pend_l, r_l) = got[True]
+    assert np.array_equal(np.asarray(new_c), np.asarray(new_l))
+    assert np.array_equal(np.asarray(pend_c), np.asarray(pend_l))
+    assert int(r_c) == int(r_l) == 3  # cut short: lanes still pending
+    assert int(np.asarray(pend_l).sum()) > 0
+    for a, b in zip(cols_c, cols_l):
+        assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
+    if occ is not None:
+        assert np.array_equal(
+            np.asarray(occ_c)[:cap], np.asarray(occ_l)[:cap]
+        )
+    # to the end, against a Python set
+    _held_to(monkeypatch, True)
+    is_new, _cols, _occ, pending, _r = fpset.probe_insert(
+        tcols, kcols, jnp.asarray(valid), occ=occ,
+    )
+    assert int(np.asarray(pending).sum()) == 0
+    assert np.array_equal(
+        np.asarray(is_new), _first_lanes_of_new_keys(held, keys, valid)
+    )
+
+
+def test_a_resumed_narrow_stage_bids_with_its_original_lane_ids(
+    monkeypatch,
+):
+    """A compacted buffer resumes at round 2 with sparse original ids
+    in DESCENDING position order: the least id wins by both
+    arbitrations, not the first position."""
+    nq, cap = 1024, 1 << 12
+    rng = np.random.default_rng(5)
+    pool = rng.integers(0, 2**31, size=(200, 2), dtype=np.uint32)
+    keys = pool[rng.integers(0, len(pool), size=nq)]
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(2))
+    ids = np.sort(rng.choice(1 << 20, nq, replace=False))[::-1].astype(
+        np.int32
+    )
+    valid = jnp.asarray(rng.random(nq) < 0.9)
+    out = {}
+    for among_lanes in (False, True):
+        _held_to(monkeypatch, among_lanes)
+        out[among_lanes] = fpset.probe_insert(
+            fpset.empty_cols(cap, 2), kcols, valid, start_round=2,
+            lane_ids=jnp.asarray(ids),
+        )
+    for a, b in zip(jax.tree.leaves(out[False]), jax.tree.leaves(out[True])):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    is_new = np.asarray(out[True][0])
+    first = {}
+    for i in np.flatnonzero(np.asarray(valid)):
+        k = tuple(keys[i])
+        if k not in first or ids[i] < ids[first[k]]:
+            first[k] = i
+    assert sorted(np.flatnonzero(is_new)) == sorted(first.values())
+
+
+@pytest.mark.parametrize("nq", [4096, 20000])
+def test_lookup_or_insert_is_the_same_by_either_arbitration(
+    monkeypatch, nq
+):
+    """The whole ladder (its narrow steps resume on compacted buffers
+    with original lane ids) by both arbitrations and against a Python
+    set: every output bit for bit."""
+    ncols, cap = 2, 1 << 16
+    held, tcols, keys, valid = _colliding_batch(nq, ncols, cap, seed=nq)
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
+    got = {}
+    for among_lanes in (False, True):
+        _held_to(monkeypatch, among_lanes)
+        got[among_lanes] = fpset.lookup_or_insert(
+            tcols, kcols, jnp.asarray(valid)
+        )
+    for a, b in zip(jax.tree.leaves(got[False]), jax.tree.leaves(got[True])):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.shape == (cap + 1,):  # the trash row holds any loser
+            a, b = a[:cap], b[:cap]
+        assert np.array_equal(a, b)
+    is_new, _cols, n_failed, rounds, _lanes, steps = got[True]
+    assert int(n_failed) == 0 and int(rounds) == sum(int(x) for x in steps)
+    assert np.array_equal(
+        np.asarray(is_new), _first_lanes_of_new_keys(held, keys, valid)
+    )
+
+
+def _jaxpr_values(jaxpr):
+    """Every ``(primitive name, output shape, output dtype)`` of a
+    jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield eqn.primitive.name, v.aval.shape, v.aval.dtype
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _jaxpr_values(sub)
+
+
+def _probe_round_values(nq, cap):
+    u32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.uint32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(fpset.probe_insert)(
+        (u32(cap + 1), u32(cap + 1)), (u32(nq), u32(nq)),
+        jax.ShapeDtypeStruct((nq,), jnp.bool_),
+    )
+    return list(_jaxpr_values(jaxpr.jaxpr))
+
+
+def test_a_narrow_round_builds_nothing_of_the_tables_size_to_arbitrate():
+    """The jaxpr of a narrow ``probe_insert`` (the CLI's 1,024-lane
+    step on the 9m binding's 2^24-slot table) holds no int32 value of
+    ``cap + 1`` elements and no scatter-min; a wide one (the flagship
+    level's flush) still holds both."""
+    cap = 1 << 24
+    assert fpset.arbitrates_among_lanes(1024, cap)
+    narrow = _probe_round_values(1024, cap)
+    assert not [v for v in narrow if v[0] == "scatter-min"]
+    assert not [
+        v for v in narrow if v[1] == (cap + 1,) and v[2] == jnp.int32
+    ]
+    # the two key columns are still written at the table's size
+    assert len([
+        v for v in narrow if v[0] == "scatter" and v[1] == (cap + 1,)
+    ]) == 2
+    assert not fpset.arbitrates_among_lanes(65536, cap)
+    wide = _probe_round_values(65536, cap)
+    assert [v for v in wide if v[0] == "scatter-min"] == [
+        ("scatter-min", (cap + 1,), jnp.int32)
+    ]
+
+
+def test_the_rule_reads_the_two_static_shapes_and_nothing_else():
+    """``arbitrates_among_lanes(nq, cap)``: two parameters; narrower
+    or on a larger table never turns it off; the CLI's ladder on the
+    9m binding engages it at its 1,024-lane step at 2^24 slots, and
+    the wide flushes (the CLI's 65,536 lanes, the flagship level's
+    26,738,688 on 2^27 slots, the sharded engine's 98,304) never."""
+    import inspect
+
+    rule = fpset.arbitrates_among_lanes
+    assert list(inspect.signature(rule).parameters) == ["nq", "cap"]
+    widths = [
+        w for w, _c, _e in fpset.ladder_steps(
+            65536, fpset.DENSE_ROUNDS, fpset.STAGES
+        )
+    ]
+    assert widths == [65536, 16384, 8192, 4096, 2048, 1024]
+    assert rule(1024, 1 << 24) and rule(1024, 1 << 25)
+    assert not rule(65536, 1 << 25)
+    assert not rule(26738688, 1 << 27) and not rule(98304, 1 << 23)
+    # the measured crossovers (PR 40): 16,384 lanes from 2^24 slots,
+    # up to 8,192 from 2^22, nothing wider than was measured and
+    # nothing on a smaller table (tier-1's, the two small CLI cells')
+    assert rule(16384, 1 << 24) and not rule(16384, 1 << 23)
+    assert rule(8192, 1 << 22) and rule(64, 1 << 22)
+    assert not rule(1024, 1 << 21) and not rule(64, 1 << 21)
+    assert not rule(32768, 1 << 27)
+    for log_cap in range(6, 28):
+        engaged = [
+            nq for nq in (64, 256, 1024, 1536, 2048, 2560, 4096, 8192,
+                          16384, 65536)
+            if rule(nq, 1 << log_cap)
+        ]
+        # a prefix of the widths: narrower never turns it off
+        assert engaged == [
+            nq for nq in (64, 256, 1024, 1536, 2048, 2560, 4096, 8192,
+                          16384, 65536)
+            if nq <= max(engaged, default=0)
+        ]
+        # nor does a larger table
+        assert all(rule(nq, 2 << log_cap) for nq in engaged)
+
+
+def test_lane_arb_rounds_folds_step_rounds_by_the_rule(monkeypatch):
+    """The host's fold: of the rounds by schedule entry, those at a
+    step whose width the rule arbitrates among the lanes."""
+    monkeypatch.setattr(
+        fpset, "arbitrates_among_lanes",
+        lambda nq, cap: nq <= 2048 and cap >= 1 << 20,
+    )
+    steps = [2, 853, 1265, 1694, 1462, 17857, 0, 0]
+    fold = lambda nq, cap: fpset.lane_arb_rounds(  # noqa: E731
+        steps, nq, cap, fpset.DENSE_ROUNDS, fpset.STAGES
+    )
+    assert fold(65536, 1 << 24) == 1462 + 17857
+    assert fold(65536, 1 << 19) == 0
+    # a seed merge's 32,768 lanes walk 8,192 / 4,096 / 2,048 / 1,024
+    # at the entries 1 to 4 (1/32 and under have no shrink to offer)
+    assert fold(32768, 1 << 24) == 1694 + 1462
+    # a batch no wider than MIN_STAGE runs every round at entry 0
+    assert fold(1024, 1 << 24) == steps[0]
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_lane_arb_rounds_counter_is_the_rule_over_the_fetches(
+    monkeypatch, fuse
+):
+    """``fpset_lane_arb_rounds`` in ``last_stats``: folded at every
+    fetch from the step rounds since the last one and the table as it
+    is then.  Under a rule that turns on with the table's size the
+    counter is the rounds of the narrow steps from that tier on, made
+    here from what each fetch found."""
+    rule = lambda nq, cap: nq <= 1024 and cap >= 1 << 14  # noqa: E731
+    monkeypatch.setattr(fpset, "arbitrates_among_lanes", rule)
+    ck = DeviceChecker(
+        CompactionModel(SMALL_CONFIGS["producer_on"]), sub_batch=256,
+        fuse=fuse, visited_cap=1 << 8, frontier_cap=1 << 12,
+    )
+    seen = []
+    fetch = ck._fetch
+
+    def recording_fetch(st, vec=None):
+        out = fetch(st, vec)
+        seen.append((
+            np.asarray(ck._last_fpm, np.int64)[fpset.FPM_N:], ck.TCAP
+        ))
+        return out
+
+    ck._fetch = recording_fetch
+    r = ck.run()
+    st = ck.last_stats
+    assert r.distinct_states == 1654 and st["grow_rehashes"] >= 2
+    lad = fpset.ladder_steps(ck.ACAP, ck.fps_dense, ck.fps_stages)
+    assert lad[0][0] > 1024 >= lad[-1][0]  # a wide step and a narrow one
+    assert min(cap for _s, cap in seen) < 1 << 14 <= st["fpset_table_cap"]
+    want, prev = 0, np.zeros((fpset.FPM_STEPS,), np.int64)
+    for steps, cap in seen:
+        want += sum(
+            int((steps - prev)[e]) for w, _c, e in lad if rule(w, cap)
+        )
+        prev = steps
+    assert int(prev.sum()) == st["fpset_probe_rounds"]
+    assert 0 < want < st["fpset_probe_rounds"]
+    assert st["fpset_lane_arb_rounds"] == want
+    assert st["fpset_lane_arb_rounds_pct"] == round(
+        100.0 * want / st["fpset_probe_rounds"], 4
+    )
+
+
+def test_lane_arb_rounds_fold_a_seed_loads_merges_at_their_own_ladder(
+    monkeypatch,
+):
+    """The seed merges run ``SEED_CHUNK`` lanes, not a flush's: their
+    rounds are folded by their own ladder before the first fetch."""
+    rule = lambda nq, cap: nq <= 1024  # noqa: E731
+    monkeypatch.setattr(fpset, "arbitrates_among_lanes", rule)
+    monkeypatch.setattr(DeviceChecker, "SEED_CHUNK", 1024)
+    m = CompactionModel(SMALL_CONFIGS["producer_on"])
+    seed = m.host_seed(max_level_states=200, max_total=600)
+    ck = DeviceChecker(
+        m, sub_batch=256, visited_cap=1 << 8, frontier_cap=1 << 12,
+    )
+    folds = []
+    fold = ck._fold_lane_arb
+
+    def recording_fold(fpm, nq):
+        before = ck._arb_rounds, ck._arb_of
+        fold(fpm, nq)
+        folds.append(
+            (nq, ck._arb_rounds - before[0], ck._arb_of - before[1])
+        )
+
+    ck._fold_lane_arb = recording_fold
+    r = ck.run(seed=seed)
+    assert r.distinct_states == 1654
+    assert folds[0][0] == ck.SEED_CHUNK == 1024 < ck.ACAP
+    # the merges' rounds, folded before a fetch: all run in place at
+    # 1,024 lanes, where a flush's first step is wider than the rule
+    assert folds[0][1] == folds[0][2] > 0
+    assert {nq for nq, _a, _o in folds[1:]} == {ck.ACAP}
+    st = ck.last_stats
+    assert st["fpset_lane_arb_rounds"] == sum(a for _n, a, _o in folds)
+    assert st["fpset_probe_rounds"] == sum(o for _n, _a, o in folds)
+
+
 # ---- _load_seed frontier-window guard (ADVICE r5 medium) -------------
 
 
