@@ -262,13 +262,37 @@ def _explorer_tiers(args) -> dict:
 
 
 def _checking_what(args, invariants) -> str:
-    """The banner's last clause: what this check decides."""
+    """The banner's last clause: what this check decides, and the
+    device-memory budget it was given, if any."""
+    budget = (
+        f"; device-memory budget {args.hbm_budget}"
+        if getattr(args, "hbm_budget", None) else ""
+    )
     if getattr(args, "liveness_property", None):
         return (
             f"temporal property: {args.liveness_property} under "
-            f"fairness {args.fairness}; no invariant checked"
+            f"fairness {args.fairness}; no invariant checked{budget}"
         )
-    return f"invariants: {list(invariants) or 'none'}"
+    return f"invariants: {list(invariants) or 'none'}{budget}"
+
+
+def tiered_line(st: dict, distinct_states: int) -> str:
+    """What a check under ``-hbm-budget`` spilled, from the engine's
+    ``last_stats``: one line on stdout after the verdict, so that an
+    untraced run can be held to its budget (docs/memory.md has the
+    grammar)."""
+    tc, lc, pc = st["spill_tier_ceilings"]
+    return (
+        f"Tiered store: budget {st['hbm_budget']} B (table <= {tc} "
+        f"slots, rows <= {lc}, logs <= {pc}), hot tier peak "
+        f"{st['spill_hot_keys_max']} keys "
+        f"({100.0 * st['spill_hot_keys_max'] / max(distinct_states, 1):.1f}"
+        f"% of {distinct_states}), {st['spill_evictions']} evictions of "
+        f"{st['spill_keys_evicted']} keys, {st['spill_misses_resolved']} "
+        f"cold lookups ({st['spill_miss_hits']} already visited), "
+        f"{st['spill_rows_evicted']} rows spilled, budget overridden: "
+        f"{'yes' if st['spill_budget_overridden'] else 'no'}."
+    )
 
 
 def _print_graph_summary(graph) -> None:
@@ -670,6 +694,8 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         rc = _report(
             r, constants, time.time() - t0, checkpoint=args.checkpoint
         )
+        if "spill_tier_ceilings" in getattr(ck, "last_stats", {}):
+            print(tiered_line(ck.last_stats, r.distinct_states))
     # cfg PROPERTIES are honored automatically after a clean safety pass
     # (TLC checks temporal properties from the same run); the sharded
     # drivers do not keep the state log the liveness engine needs

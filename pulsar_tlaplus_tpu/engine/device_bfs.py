@@ -113,6 +113,28 @@ FPM_N = fpset.FPM_WIDE_N
 WKM_N = fpset.WKM_N
 
 
+def _scoped(scope: str, name: str, fn):
+    """``fn`` traced under the stage scope ``scope``, as a program
+    named ``name`` (``jax.jit`` names a program after its function)."""
+    out = spans.staged(scope)(fn)
+    out.__name__ = out.__qualname__ = name
+    return out
+
+
+# the least length the tiered store fetches from the device: a fetch is
+# the next power of two at or over what is needed, from here up to the
+# buffer's own length, so a run meets a handful of fetch programs
+SPILL_FETCH_MIN = 1 << 12
+
+
+@functools.partial(jax.jit, static_argnames=("size",))
+def ptt_spill_fetch(buf, start, *, size):
+    """``buf[start: start + size]``: the slice a spill fetch brings to
+    the host, one program a ``(buffer, size)`` (``start`` is traced)."""
+    with spans.stage("spill_fetch"):
+        return lax.dynamic_slice(buf, (start,), (size,))
+
+
 class _Growth:
     """What one ``run()`` grew, counted on the host at the growth sites
     (no dispatch, sync or fetch of their own; ``last_stats`` carries
@@ -519,14 +541,10 @@ class DeviceChecker:
             group = max(
                 1, min(group, tc // 2 // self.ACAP - 1)
             )
-        # per-run spill state (reset in run())
-        self._spill_active = False
-        self._epoch = 1
-        self._hot_n = 0
-        self._spill_sync_n = 0
-        self._spill_emit_mark = 0
-        self._spill_degraded_emitted = False
-        self._budget_overridden = False
+            # the ceilings the budget gave, as the result reports them:
+            # an override moves ``_tcap_max`` / ``_lcap_max`` / ``_pcap_max``
+            self._tier_ceilings = [tc, lc, pc]
+        self._reset_spill_state()
         max_rows = (
             self.LCAP if rows_window == "frontier"
             else self._lcap_max if self.tiered
@@ -635,6 +653,33 @@ class DeviceChecker:
         return n
 
     # ----------------------------------------------- tiered-store sizing
+
+    def _reset_spill_state(self) -> None:
+        """The tiered store's per-run state: epochs, the hot tier's
+        count and its peak, the fetch counters."""
+        self._spill_active = False
+        self._epoch = 1
+        self._hot_max = 0
+        self._hot_n = 0
+        self._spill_sync_n = 0
+        self._spill_emit_mark = 0
+        self._spill_degraded_emitted = False
+        self._budget_overridden = False
+        self._spill_fetch_s = 0.0
+        self._spill_d2h_bytes = 0
+        self._spill_d2h_padded_bytes = 0
+        self._spill_evict_slots = 0
+
+    @property
+    def _hot_n(self) -> int:
+        """Keys in the hot table (every assignment keeps the run's
+        peak, ``spill_hot_keys_max``)."""
+        return self._hot_keys
+
+    @_hot_n.setter
+    def _hot_n(self, n: int) -> None:
+        self._hot_keys = n
+        self._hot_max = max(self._hot_max, n)
 
     def _device_bytes_est(self, tcap: int, lcap: int, pcap: int) -> int:
         """Worst-case resident bytes at a (TCAP, LCAP, PCAP) tier
@@ -984,8 +1029,7 @@ class DeviceChecker:
         W = self.W
         CW = self.SHIFT_CW
 
-        @spans.staged("levelctl")
-        def ptt_shift(rows, src_off, n_rows):
+        def shift(rows, src_off, n_rows):
             nw = n_rows * W
 
             def body(i, rows):
@@ -998,6 +1042,14 @@ class DeviceChecker:
                 0, (nw + CW - 1) // CW, body, rows
             )
 
+        # a tiered run's window slides after a spill: the same copy
+        # under the spill's scope, and (a program's name is part of its
+        # cache key, its scopes are not) under a name of its own
+        ptt_shift = (
+            _scoped("spill_shift", "ptt_spill_shift", shift)
+            if self.tiered
+            else _scoped("levelctl", "ptt_shift", shift)
+        )
         fn = jax.jit(ptt_shift, donate_argnums=(0,))
         self._jits[key] = fn
         return fn
@@ -1015,7 +1067,8 @@ class DeviceChecker:
             return self._jits[key]
         CW = self.LOG_CW
 
-        def step(parent, lane, src_off, n):
+        @spans.staged("spill_shift")
+        def ptt_spill_logshift(parent, lane, src_off, n):
             def body(i, st):
                 p, ln = st
                 cp = lax.dynamic_slice(p, (src_off + i * CW,), (CW,))
@@ -1029,7 +1082,7 @@ class DeviceChecker:
                 0, (n + CW - 1) // CW, body, (parent, lane)
             )
 
-        fn = jax.jit(step, donate_argnums=(0, 1))
+        fn = jax.jit(ptt_spill_logshift, donate_argnums=(0, 1))
         self._jits[key] = fn
         return fn
 
@@ -1042,12 +1095,13 @@ class DeviceChecker:
             return self._jits[key]
         K = self.K
 
-        def step(*args):
+        @spans.staged("spill_tag")
+        def ptt_spill_tag(*args):
             return store_sieve.tag_generation(
                 args[:K], args[K], args[K + 1]
             )
 
-        fn = jax.jit(step, donate_argnums=(self.K,))
+        fn = jax.jit(ptt_spill_tag, donate_argnums=(self.K,))
         self._jits[key] = fn
         return fn
 
@@ -1062,13 +1116,16 @@ class DeviceChecker:
             return self._jits[key]
         K = self.K
 
-        def step(*args):
+        @spans.staged("spill_evict")
+        def ptt_spill_evict(*args):
             holed, gen, ev, n = store_sieve.extract_cold(
                 args[:K], args[K], args[K + 1]
             )
             return (*holed, gen, *ev, n)
 
-        fn = jax.jit(step, donate_argnums=tuple(range(self.K + 1)))
+        fn = jax.jit(
+            ptt_spill_evict, donate_argnums=tuple(range(self.K + 1))
+        )
         self._jits[key] = fn
         return fn
 
@@ -1083,13 +1140,16 @@ class DeviceChecker:
             return self._jits[key]
         K, TCAP = self.K, self.TCAP
 
-        def step(*old):
+        # a rehash like a doubling's: ``stage_device_s.rehash.*`` reads
+        # it, and its probe rounds keep the probe's parts
+        @spans.staged("rehash")
+        def ptt_spill_rehash(*old):
             new, rhm = fpset.rehash_cols(
                 old, fpset.empty_cols(TCAP, K)
             )
             return (*new, rhm[0])
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_spill_rehash)
         self._jits[key] = fn
         return fn
 
@@ -1102,10 +1162,11 @@ class DeviceChecker:
             return self._jits[key]
         K = self.K
 
-        def step(*args):
+        @spans.staged("spill_sieve")
+        def ptt_spill_sieve(*args):
             return store_sieve.sieve_new(args[:K], args[K])
 
-        fn = jax.jit(step)
+        fn = jax.jit(ptt_spill_sieve)
         self._jits[key] = fn
         return fn
 
@@ -1122,10 +1183,11 @@ class DeviceChecker:
         if key in self._jits:
             return self._jits[key]
 
-        def step(flag_acc, lanes, n):
+        @spans.staged("spill_unflag")
+        def ptt_spill_unflag(flag_acc, lanes, n):
             return store_sieve.unflag_lanes(flag_acc, lanes, n)
 
-        fn = jax.jit(step, donate_argnums=(0,))
+        fn = jax.jit(ptt_spill_unflag, donate_argnums=(0,))
         self._jits[key] = fn
         return fn
 
@@ -2036,13 +2098,7 @@ class DeviceChecker:
         # fresh TieredStore — a fresh (non-resume) run WIPES its spill
         # dir (dead prior runs must not leak host/disk bytes); resume
         # restores the cold tiers from the frame's manifest instead
-        self._spill_active = False
-        self._epoch = 1
-        self._hot_n = 0
-        self._spill_sync_n = 0
-        self._spill_emit_mark = 0
-        self._spill_degraded_emitted = False
-        self._budget_overridden = False
+        self._reset_spill_state()
         if self.tiered and not resume:
             # fresh runs own their spill dir; resume builds the store
             # inside _restore_frame from the frame's manifest instead
@@ -2610,6 +2666,39 @@ class DeviceChecker:
     def _spill_tier_label(self) -> str:
         return "ram+disk" if self.tstore.durable else "ram"
 
+    @staticmethod
+    def _spill_fetch_size(n: int, length: int) -> int:
+        """Elements a fetch of ``n`` from a buffer of ``length`` brings
+        over: a power of two from ``SPILL_FETCH_MIN`` up, or the
+        buffer's own length."""
+        return min(max(SPILL_FETCH_MIN, 1 << max(n - 1, 0).bit_length()),
+                   length)
+
+    def _spill_fetch(self, buf, n: int, off: int = 0) -> np.ndarray:
+        """``buf[off: off + n]`` on the host.  The device slices a
+        bucketed length (:meth:`_spill_fetch_size`) and the host trims
+        it, so a check compiles a handful of fetch programs and not one
+        a flush; ``spill_d2h_bytes`` counts what was needed,
+        ``spill_d2h_padded_bytes`` what crossed the link."""
+        length = buf.shape[0]
+        size = self._spill_fetch_size(n, length)
+        start = min(off, length - size)
+        t0 = time.perf_counter()
+        with spans.span("spill.fetch"):
+            got = np.asarray(
+                buf if size == length
+                else ptt_spill_fetch(buf, jnp.int32(start), size=size)
+            )
+        dt = time.perf_counter() - t0
+        out = got[off - start: off - start + n]
+        if size != n:
+            out = out.copy()  # what is kept does not hold the padding
+        self._spill_fetch_s += dt
+        self.tstore.note_transfer(dt)
+        self._spill_d2h_bytes += out.nbytes
+        self._spill_d2h_padded_bytes += got.nbytes
+        return out
+
     @spans.in_phase("spill")
     def _resolve_cold_misses(self, bufs, flag_acc, n_new):
         """Sieve the flush's hot-filter survivors, resolve them
@@ -2624,16 +2713,16 @@ class DeviceChecker:
             "sieve", self._sieve_jit()(*bufs["ak"], flag_acc)
         )
         kc, lanes, n_dev = out[:K], out[K], out[K + 1]
-        n = int(np.asarray(n_dev))
+        with spans.span("spill.sieve_wait"):
+            n = int(np.asarray(n_dev))
         self._spill_sync_n += 1
         false_lanes = []
         for off in range(0, n, self.miss_batch):
             m = min(self.miss_batch, n - off)
-            t0 = time.perf_counter()
-            kq = [np.asarray(c[off: off + m]) for c in kc]
-            lq = np.asarray(lanes[off: off + m])
-            self.tstore.note_transfer(time.perf_counter() - t0)
-            dup = self.tstore.lookup_keys(kq)
+            kq = [self._spill_fetch(c, m, off) for c in kc]
+            lq = self._spill_fetch(lanes, m, off)
+            with spans.span("spill.lookup"):
+                dup = self.tstore.lookup_keys(kq)
             if dup.any():
                 false_lanes.append(lq[dup])
         self._hot_n += n
@@ -2655,12 +2744,14 @@ class DeviceChecker:
             )
         return jnp.int32(n - k), flag_acc
 
+    @spans.spanned("spill.evict")
     def _evict_cold_keys(self, bufs, cutoff: int) -> int:
         """Evict generations <= cutoff to the cold tier: extract +
         device-sort, D2H the dense prefix, rehash the survivors (probe
         chains break across holes), restart the epoch clock.  Returns
         the evicted count."""
         K = self.K
+        self._spill_evict_slots += self.TCAP
         out = self._stage_mark(
             "evict",
             self._evict_jit()(
@@ -2675,9 +2766,7 @@ class DeviceChecker:
             # table — where(False, ...) returned the originals
             bufs["vk"], bufs["gen"] = holed, gen
             return 0
-        t0 = time.perf_counter()
-        ev_np = [np.asarray(c[:n]) for c in ev]
-        self.tstore.note_transfer(time.perf_counter() - t0)
+        ev_np = [self._spill_fetch(c, n) for c in ev]
         out2 = self._stage_mark(
             "evict", self._rehash_same_jit()(*holed)
         )
@@ -2734,6 +2823,7 @@ class DeviceChecker:
         self._tcap_max *= 2
         self._grow_visited(bufs, self._hot_n + head)
 
+    @spans.spanned("spill.rows")
     def _spill_aged(self, bufs, rb, upto: int, nv: int) -> None:
         """Spill rows + trace logs of [row_base, upto) to the cold
         tier and slide both device windows down (rows and logs share
@@ -2741,12 +2831,9 @@ class DeviceChecker:
         base = rb["row_base"]
         if upto <= base:
             return
-        W = self.W
-        t0 = time.perf_counter()
-        rows_np = np.asarray(bufs["rows"][: (upto - base) * W])
-        par_np = np.asarray(bufs["parent"][: upto - base])
-        lan_np = np.asarray(bufs["lane"][: upto - base])
-        self.tstore.note_transfer(time.perf_counter() - t0)
+        rows_np = self._spill_fetch(bufs["rows"], (upto - base) * self.W)
+        par_np = self._spill_fetch(bufs["parent"], upto - base)
+        lan_np = self._spill_fetch(bufs["lane"], upto - base)
         self.tstore.spill_rows(base, upto, rows_np)
         self.tstore.spill_logs(base, upto, par_np, lan_np)
         n_keep = nv - upto
@@ -2811,9 +2898,7 @@ class DeviceChecker:
                          nf: int, nv: int, level: int) -> None:
         """Level-boundary spill housekeeping: tag the epoch, spill
         aged rows/logs once spilling is active, keep the hot table
-        inside the budget, and emit the cumulative ``spill`` record
-        (after joining the async transfers so byte counts are
-        final)."""
+        inside the budget, and emit the cumulative ``spill`` record."""
         bufs["gen"] = self._tag_jit()(
             *bufs["vk"], bufs["gen"], jnp.int32(self._epoch)
         )
@@ -2829,9 +2914,16 @@ class DeviceChecker:
         self._ensure_hot_capacity(bufs, 2 * self.ACAP)
         self._emit_spill(level)
 
-    def _emit_spill(self, level: int) -> None:
+    def _emit_spill(self, level: int, final: bool = False) -> None:
         """One cumulative ``spill`` record per boundary with new spill
-        work (schema v9; the validator cross-checks monotonicity).
+        work (schema v9; the validator cross-checks monotonicity), and
+        one at the result (``final``), whose byte counts are the run's.
+        The background worker is joined where correctness needs it,
+        telemetry on or off alike: by a durable store at every such
+        boundary (a write that failed has to stop the run there, and a
+        frame's manifest needs every file), by an in-RAM store never
+        before the result (an evicted run is queryable at once; a
+        boundary's record carries the bytes encoded so far).
         A degraded store (ENOSPC on the durable writer) flags its
         record ``degraded`` and is emitted once even without fresh
         spill work — the honest breadcrumb behind
@@ -2839,18 +2931,23 @@ class DeviceChecker:
         if self.tstore is None:
             return
         s = self.tstore.stats
-        degraded = bool(self.tstore.degraded)
-        force = degraded and not self._spill_degraded_emitted
         mark = (
             s.evictions + s.keys_evicted + s.rows_evicted
             + s.misses_resolved
         )
-        if (
-            mark == self._spill_emit_mark and not force
-        ) or not self.tel.enabled:
+        fresh = mark != self._spill_emit_mark
+        if fresh and self.tstore.durable:
+            with spans.span("spill.join"):
+                self.tstore.flush()  # waits are measured (blocked_s)
+        degraded = bool(self.tstore.degraded)
+        force = (final and mark > 0) or (
+            degraded and not self._spill_degraded_emitted
+        )
+        if not (fresh or force):
             return
-        self.tstore.flush()  # byte counts final; waits are measured
         self._spill_emit_mark = mark
+        if not self.tel.enabled:
+            return
         if degraded:
             self._spill_degraded_emitted = True
         self.tel.emit(
@@ -4203,7 +4300,8 @@ class DeviceChecker:
         # input) and the overlap ratio (1.0 = boundaries never waited
         # on a transfer)
         if self.tiered and self.tstore is not None:
-            self.tstore.flush()
+            with spans.span("spill.join"):
+                self.tstore.flush()
             sp = self.tstore.stats
             self.last_stats.update(
                 hbm_budget=self.hbm_budget,
@@ -4212,18 +4310,38 @@ class DeviceChecker:
                 spill_rows_evicted=int(sp.rows_evicted),
                 spill_bytes_raw=int(sp.bytes_raw),
                 spill_bytes_comp=int(sp.bytes_comp),
+                # the fetches' seconds and the encoder's, mixed;
+                # spill_fetch_s is the fetches' alone
                 spill_transfer_s=round(sp.transfer_s, 3),
+                spill_fetch_s=self._spill_fetch_s,
+                spill_lookup_s=sp.lookup_s,
+                spill_blocked_s=sp.blocked_s,
+                spill_joins=int(sp.joins),
                 spill_misses_resolved=int(sp.misses_resolved),
                 spill_miss_hits=int(sp.miss_hits),
                 spill_syncs=int(self._spill_sync_n),
                 spill_hot_keys=int(self._hot_n),
+                # the hot tier's peak over the run, against the final
+                # count: what the budget held the device to
+                spill_hot_keys_max=int(self._hot_max),
+                spill_hot_share_max_pct=round(
+                    100.0 * self._hot_max / max(nv, 1), 4
+                ),
+                spill_cold_runs=self.tstore.cold_runs,
+                spill_d2h_bytes=self._spill_d2h_bytes,
+                spill_d2h_padded_bytes=self._spill_d2h_padded_bytes,
+                # table slots summed over the evictions (a roofline's
+                # bytes: benchmark/lib/spill_bytes.py)
+                spill_evict_slots=self._spill_evict_slots,
+                spill_tier_ceilings=list(self._tier_ceilings),
+                spill_budget_overridden=bool(self._budget_overridden),
                 spill_overlap_ratio=sp.overlap_ratio,
                 spill_bytes_per_state=round(
                     sp.bytes_comp / max(nv, 1), 2
                 ),
                 spill_degraded=bool(self.tstore.degraded),
             )
-            self._emit_spill(len(level_sizes))
+            self._emit_spill(len(level_sizes), final=True)
             # run over: release the spill worker thread (the in-RAM
             # tiers stay readable for the trace walk / liveness sweep)
             self.tstore.quiesce()

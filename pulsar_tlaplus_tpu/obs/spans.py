@@ -13,7 +13,11 @@ variable, exporter or telemetry event of their own:
   (initial-state generation), and in the liveness run
   (``engine/liveness.py``) ``ptt.live_table``, ``ptt.live_goal``,
   ``ptt.sweep_expand``, ``ptt.sweep_join``, ``ptt.sweep_prop`` and
-  ``ptt.sweep_compact``.  A scope is HLO metadata
+  ``ptt.sweep_compact``, and under a device-memory budget (the tiered
+  store's programs, ``engine/device_bfs.py``) ``ptt.spill_tag``,
+  ``ptt.spill_evict``, ``ptt.spill_sieve``, ``ptt.spill_unflag``,
+  ``ptt.spill_shift`` and ``ptt.spill_fetch`` (the rehash at the same
+  size after an eviction is ``ptt.rehash``).  A scope is HLO metadata
   only: it lands in every operation's ``op_name`` path, which a device
   trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
   metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of
@@ -40,7 +44,10 @@ variable, exporter or telemetry event of their own:
   operations; with no trace running one costs under a microsecond
   (a phase of the clock about three).  A ``PhaseClock`` also adds each phase's seconds up,
   exclusively (an inner phase pauses the outer), so the phases of one
-  ``run()`` sum to its wall.
+  ``run()`` sum to its wall.  Inside the phase ``spill`` plain spans
+  name what the host does there (``ptt:spill.sieve_wait``, ``.fetch``,
+  ``.lookup``, ``.evict``, ``.rows``, ``.join``): they split the
+  phase's idle time and add nothing to the clock.
 - **The compile meter** (``compile_meter``): one process-wide
   ``jax.monitoring`` listener, registered on first use, that counts per
   calling thread how often JAX traced, lowered, compiled or loaded from

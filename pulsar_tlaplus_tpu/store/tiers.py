@@ -99,6 +99,7 @@ class SpillStats:
         "evictions", "keys_evicted", "rows_evicted", "logs_evicted",
         "bytes_raw", "bytes_comp", "transfer_s", "blocked_s",
         "misses_resolved", "miss_hits", "miss_batches", "lookup_s",
+        "joins",
     )
 
     def __init__(self):
@@ -183,6 +184,11 @@ class TieredStore:
     @property
     def cold_keys(self) -> int:
         return sum(r["n"] for r in self._runs)
+
+    @property
+    def cold_runs(self) -> int:
+        """Sorted runs a lookup walks (they are never merged)."""
+        return len(self._runs)
 
     def evict_keys(self, kcols_np) -> int:
         """Ingest one SORTED evicted key run (dense numpy columns from
@@ -426,6 +432,7 @@ class TieredStore:
         for f in pending:
             f.result()  # re-raises a worker failure loudly
         self.stats.blocked_s += time.perf_counter() - t0
+        self.stats.joins += 1
 
     def quiesce(self) -> None:
         """Join + shut down the spill worker while keeping the in-RAM

@@ -1,0 +1,389 @@
+"""The tiered store names, counts and prints what a spilled run does
+(ISSUE 41): the CLI's tiered line against the reference and the
+engine's own counters; the spill programs' scopes as compiled; the
+bucketed fetch; the new counters of ``last_stats``; the traced and the
+timed run as one path; an untiered run untouched.
+"""
+
+import contextlib
+import io
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.lib import reference as bench_reference
+from benchmark.lib import spill_bytes, tlafmt
+from pulsar_tlaplus_tpu import cli
+from pulsar_tlaplus_tpu.engine import device_bfs
+from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
+from pulsar_tlaplus_tpu.models.compaction import CompactionModel
+from pulsar_tlaplus_tpu.obs import spans
+from pulsar_tlaplus_tpu.ref import pyeval as pe
+from tests.helpers import SMALL_CONFIGS, tight_hbm_budget
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SPEC = os.path.join(ROOT, "specs", "compaction.tla")
+CFG = os.path.join(ROOT, "specs", "compaction.cfg")
+LEVEL = re.compile(r"^\s*level (\d+): \+(\d+) \(total (\d+),", re.M)
+
+NEW_KEYS = (
+    "spill_lookup_s", "spill_blocked_s", "spill_fetch_s", "spill_d2h_bytes",
+    "spill_d2h_padded_bytes", "spill_hot_keys_max",
+    "spill_hot_share_max_pct", "spill_cold_runs", "spill_budget_overridden",
+    "spill_tier_ceilings", "spill_evict_slots", "spill_joins",
+)
+COUNTS = (
+    "spill_evictions", "spill_keys_evicted", "spill_rows_evicted",
+    "spill_misses_resolved", "spill_miss_hits", "spill_syncs",
+    "spill_hot_keys", "spill_hot_keys_max", "spill_cold_runs",
+    "spill_d2h_bytes", "spill_d2h_padded_bytes", "spill_evict_slots",
+    "spill_bytes_raw", "spill_bytes_comp", "spill_joins",
+    "spill_budget_overridden", "spill_tier_ceilings", "stage_sieve_n",
+    "stage_unflag_n", "stage_evict_n", "stage_flush_n", "fpset_flushes",
+    "fpset_probe_rounds",
+)
+
+
+def _mk(c=None, **kw):
+    kw.setdefault("invariants", ())
+    kw.setdefault("check_deadlock", False)
+    kw.setdefault("sub_batch", 64)
+    kw.setdefault("visited_cap", 1 << 9)
+    kw.setdefault("frontier_cap", 1 << 9)
+    return DeviceChecker(
+        CompactionModel(c or SMALL_CONFIGS["producer_on"]), **kw
+    )
+
+
+def _tight(**kw):
+    return tight_hbm_budget(lambda b: _mk(hbm_budget=b, **kw))
+
+
+@pytest.fixture(scope="module")
+def tiered():
+    """One tiered run of the 1,654-state binding at a tight budget."""
+    ck = _mk(hbm_budget=_tight())
+    return ck, ck.run()
+
+
+# ---- the CLI's line -----------------------------------------------------
+
+
+def _cli(*extra):
+    out, err = io.StringIO(), io.StringIO()
+    with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
+        rc = cli.main(["check", SPEC, "-config", CFG, *extra])
+    rows = [tuple(int(x) for x in m.groups())
+            for m in LEVEL.finditer(err.getvalue())]
+    sizes = [rows[0][2] - rows[0][1]] + [r[1] for r in rows]
+    return rc, out.getvalue(), sizes
+
+
+def test_cli_prints_what_it_spilled_and_the_reference_agrees(tmp_path):
+    """``-hbm-budget 4M`` on the shipped binding: the reference's
+    count, diameter and level sizes, and a tiered line that the
+    comparison's parser reads back number for number, each the
+    engine's own counter."""
+    tel = str(tmp_path / "t.jsonl")
+    rc, text, sizes = _cli("-hbm-budget", "4M", "-telemetry", tel)
+    want, _seen = bench_reference.bfs_levels(tlafmt.constants_from_cfg(CFG))
+    assert rc == 0 and sizes == want
+    assert tlafmt.parse_counts(text) == (45198, 20) == (sum(want), len(want))
+    assert "device-memory budget 4M" in text  # the banner names it
+    line = spill_bytes.parse_tiered_line(text)
+    st = [json.loads(x) for x in open(tel) if '"result"' in x][-1]["stats"]
+    assert line == {
+        "budget": st["hbm_budget"],
+        "table": st["spill_tier_ceilings"][0],
+        "rows": st["spill_tier_ceilings"][1],
+        "logs": st["spill_tier_ceilings"][2],
+        "hot_peak": st["spill_hot_keys_max"],
+        "hot_pct": round(100.0 * st["spill_hot_keys_max"] / 45198, 1),
+        "states": 45198,
+        "evictions": st["spill_evictions"],
+        "keys_evicted": st["spill_keys_evicted"],
+        "lookups": st["spill_misses_resolved"],
+        "hits": st["spill_miss_hits"],
+        "rows_spilled": st["spill_rows_evicted"],
+        "overridden": False,
+    }
+    assert line["budget"] == 4 << 20
+    assert line["hot_peak"] <= line["table"] // 2
+    assert min(line["keys_evicted"], line["lookups"], line["hits"],
+               line["rows_spilled"]) > 0
+    assert st["spill_degraded"] is False
+
+
+def test_cli_without_a_budget_prints_no_tiered_line():
+    rc, text, _sizes = _cli()
+    assert rc == 0 and "Tiered store" not in text
+    assert "budget" not in text
+
+
+def test_cli_says_when_the_budget_was_overridden(monkeypatch):
+    """Tiers too small for the binding's widest levels: the run ends
+    exact, says ``budget overridden: yes``, and the benchmark's
+    comparison reads that as not correct."""
+    from benchmark.lib import plug
+
+    tiers = dict(sub_batch=64, visited_cap=1 << 9, frontier_cap=1 << 9)
+    monkeypatch.setattr(cli, "_explorer_tiers", lambda args: dict(tiers))
+    budget = tight_hbm_budget(lambda b: _mk(
+        pe.SHIPPED_CFG, hbm_budget=b, **tiers))
+    rc, text, sizes = _cli("-hbm-budget", str(budget))
+    line = spill_bytes.parse_tiered_line(text)
+    assert rc == 0 and sum(sizes) == 45198
+    assert line["overridden"] is True and "overridden: yes." in text
+    config = {
+        "budget": {"bytes": line["budget"], "table_slots": line["table"],
+                   "rows": line["rows"], "logs": line["logs"],
+                   "hot_keys_max": line["table"] // 2},
+        "reference": {"prefix_levels": 20, "pinned_level_sizes": {}},
+    }
+    answers = [{"rc": rc, "text": text, "level_sizes": sizes}]
+    wrong = [c["name"] for c in plug.load_file(
+        "comparisons", "pyeval-prefix-plus-pinned-tiered").compare(
+            config, {"cfg_path": CFG}, answers, 1) if not c["ok"]]
+    assert "budget_overridden" in wrong
+
+
+# ---- the device scopes --------------------------------------------------
+
+
+def _struct(args):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a)),
+        args,
+    )
+
+
+@pytest.fixture(scope="module")
+def spill_programs():
+    """``{program name: compiled HLO}`` of every per-checker program a
+    tiered run calls, compiled again at the shapes it was called with
+    (the pattern of tests/test_spans.py)."""
+    real, seen = jax.jit, {}
+
+    def recording_jit(fn, **kw):
+        j = real(fn, **kw)
+
+        def call(*args):
+            shapes = _struct(args)
+            seen.setdefault(fn.__name__, lambda: j.lower(*shapes))
+            return j(*args)
+
+        return call
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(device_bfs.jax, "jit", recording_jit)
+    try:
+        _mk(hbm_budget=_tight()).run()
+    finally:
+        mp.undo()
+    return {
+        name: lower().compile().as_text() for name, lower in seen.items()
+        if name.startswith("ptt_spill")
+    }
+
+
+@pytest.mark.parametrize("name, scope", [
+    ("ptt_spill_tag", "ptt.spill_tag"),
+    ("ptt_spill_evict", "ptt.spill_evict"),
+    ("ptt_spill_rehash", "ptt.rehash"),
+    ("ptt_spill_sieve", "ptt.spill_sieve"),
+    ("ptt_spill_unflag", "ptt.spill_unflag"),
+    ("ptt_spill_shift", "ptt.spill_shift"),
+    ("ptt_spill_logshift", "ptt.spill_shift"),
+])
+def test_every_spill_program_carries_its_scope_as_compiled(
+    spill_programs, name, scope
+):
+    hlo = spill_programs[name]
+    names = re.findall(r'op_name="([^"]*)"', hlo)
+    under = [n for n in names if f"jit({name})/{scope}/" in n]
+    assert under and len(under) >= len(names) // 2, (len(under), len(names))
+    assert set(re.findall(r"ptt\.[a-z_]+", hlo)) == {scope}
+
+
+def test_the_same_size_rehash_keeps_the_probes_parts(spill_programs):
+    hlo = spill_programs["ptt_spill_rehash"]
+    assert re.search(r"ptt\.rehash/.*part\.(gather|write)", hlo)
+
+
+def test_the_fetch_program_carries_its_scope():
+    buf = jax.ShapeDtypeStruct((1 << 14,), jnp.uint32)
+    hlo = device_bfs.ptt_spill_fetch.lower(
+        buf, jnp.int32(0), size=1 << 12
+    ).compile().as_text()
+    assert "jit(ptt_spill_fetch)/ptt.spill_fetch/" in hlo
+
+
+def test_an_untiered_shift_is_the_parents_program(monkeypatch):
+    """Frontier-window mode slides its rows under ``ptt.levelctl`` as
+    the program named ``ptt_shift``, as before the tiered store had a
+    scope of its own."""
+    named = []
+    real = jax.jit
+
+    def recording_jit(fn, **kw):
+        named.append(fn.__name__)
+        return real(fn, **kw)
+
+    monkeypatch.setattr(device_bfs.jax, "jit", recording_jit)
+    ck = _mk(rows_window="frontier")
+    fn = ck._shift_jit()
+    assert named == ["ptt_shift"]
+    txt = fn.lower(
+        jax.ShapeDtypeStruct((ck._rows_len(),), jnp.uint32),
+        jnp.int32(0), jnp.int32(0),
+    ).as_text(debug_info=True)
+    assert set(re.findall(r"ptt\.[a-z_]+", txt)) == {"ptt.levelctl"}
+
+
+# ---- the bucketed fetch -------------------------------------------------
+
+
+def test_fetch_sizes_are_powers_of_two_up_to_the_buffer():
+    size = DeviceChecker._spill_fetch_size
+    lo = device_bfs.SPILL_FETCH_MIN
+    assert [size(n, 100000) for n in (0, 1, lo, lo + 1, 70000, 100000)] == [
+        lo, lo, lo, 2 * lo, 100000, 100000]
+    assert size(5, 1000) == 1000  # a buffer under the least bucket
+    assert len({size(n, 1 << 20) for n in range(1, 1 << 20, 997)}) == 9
+
+
+def test_fetch_returns_the_unpadded_slice_through_few_shapes(monkeypatch):
+    """Fetches of many lengths and offsets, the last ones clamped to
+    the buffer's end: each returns exactly the slice, and the device
+    program meets at most its bucket count of shapes."""
+    ck = _mk(hbm_budget=_tight())
+    ck._mk_tstore()
+    length = 3 * device_bfs.SPILL_FETCH_MIN + 17
+    host = np.arange(length, dtype=np.uint32) * 7 + 1
+    buf = jnp.asarray(host)
+    shapes = set()
+    real = device_bfs.ptt_spill_fetch
+
+    def counting(b, start, *, size):
+        shapes.add((b.shape, str(b.dtype), size))
+        return real(b, start, size=size)
+
+    monkeypatch.setattr(device_bfs, "ptt_spill_fetch", counting)
+    rng = np.random.default_rng(41)
+    needed = 0
+    for _ in range(200):
+        n = int(rng.integers(0, length + 1))
+        off = int(rng.integers(0, length - n + 1))
+        got = ck._spill_fetch(buf, n, off)
+        assert got.shape == (n,) and (got == host[off: off + n]).all()
+        needed += got.nbytes
+    assert len(shapes) <= 2  # 2^12 and 2^13; over that the whole buffer
+    assert ck._spill_d2h_bytes == needed
+    assert ck._spill_d2h_padded_bytes >= needed
+    assert ck._spill_fetch_s > 0
+    ck.tstore.close()
+
+
+def test_a_runs_fetches_meet_few_shapes_though_its_flushes_differ(
+    monkeypatch
+):
+    lengths, shapes = set(), set()
+    real_fetch = DeviceChecker._spill_fetch
+    real_prog = device_bfs.ptt_spill_fetch
+
+    def fetch(self, buf, n, off=0):
+        lengths.add(n)
+        return real_fetch(self, buf, n, off)
+
+    def prog(b, start, *, size):
+        shapes.add((b.shape, str(b.dtype), size))
+        return real_prog(b, start, size=size)
+
+    monkeypatch.setattr(DeviceChecker, "_spill_fetch", fetch)
+    monkeypatch.setattr(device_bfs, "ptt_spill_fetch", prog)
+    ck = _mk(hbm_budget=_tight())
+    r = ck.run()
+    assert r.distinct_states == 1654
+    assert len(lengths) > 20  # a length a flush, nearly
+    # whole buffers need no program; what is left is a size a buffer
+    buckets = {
+        (s, d, DeviceChecker._spill_fetch_size(n, s[0]))
+        for s, d, _size in shapes for n in range(1, s[0])
+    }
+    assert len(shapes) <= len(buckets) <= 8
+
+
+# ---- the counters -------------------------------------------------------
+
+
+def test_new_counters_are_in_last_stats(tiered, monkeypatch):
+    ck, r = tiered
+    st = ck.last_stats
+    for k in NEW_KEYS:
+        assert k in st, k
+    assert r.distinct_states == 1654
+    assert st["spill_hot_keys_max"] >= st["spill_hot_keys"] > 0
+    assert st["spill_hot_share_max_pct"] == round(
+        100.0 * st["spill_hot_keys_max"] / 1654, 4)
+    assert st["spill_cold_runs"] == st["spill_evictions"] >= 1
+    assert st["spill_evict_slots"] >= st["spill_evictions"] * (1 << 10)
+    assert st["spill_tier_ceilings"] == ck._tier_ceilings
+    assert st["spill_budget_overridden"] is ck._budget_overridden
+    assert st["spill_d2h_padded_bytes"] >= st["spill_d2h_bytes"] > 0
+    assert 0 < st["spill_fetch_s"] <= st["spill_transfer_s"] + 1e-3
+    assert st["spill_lookup_s"] > 0 and st["spill_blocked_s"] >= 0
+    assert st["spill_joins"] == 1  # an in-RAM store: at the result
+    assert st["fuse"] == "level" and st["fpset_slot_rounds"] > 0
+    # host_<phase>_s still sum to the wall of run()
+    phases = sum(st[f"host_{p}_s"] for p in spans.PHASES)
+    assert st["host_spill_s"] > 0
+    assert abs(phases + st["host_unaccounted_s"] - r.wall_s) < 0.05
+
+
+def test_d2h_bytes_are_the_fetched_planes_summed(monkeypatch):
+    kept = []
+    real = DeviceChecker._spill_fetch
+
+    def fetch(self, buf, n, off=0):
+        out = real(self, buf, n, off)
+        kept.append(out.nbytes)
+        return out
+
+    monkeypatch.setattr(DeviceChecker, "_spill_fetch", fetch)
+    ck = _mk(hbm_budget=_tight())
+    ck.run()
+    assert ck.last_stats["spill_d2h_bytes"] == sum(kept) > 0
+
+
+def test_traced_and_timed_runs_are_one_path(tiered, tmp_path):
+    """Telemetry on and off: the worker is joined the same number of
+    times and every count agrees."""
+    ck_off, r_off = tiered
+    ck_on = _mk(hbm_budget=_tight(), telemetry=str(tmp_path / "t.jsonl"))
+    r_on = ck_on.run()
+    assert r_on.level_sizes == r_off.level_sizes
+    for k in COUNTS:
+        assert ck_on.last_stats[k] == ck_off.last_stats[k], k
+    evs = [json.loads(x) for x in open(tmp_path / "t.jsonl")]
+    spills = [e for e in evs if e["event"] == "spill"]
+    assert spills and spills[-1]["bytes_raw"] == ck_on.last_stats[
+        "spill_bytes_raw"]
+
+
+def test_a_durable_store_joins_at_its_boundaries(tmp_path):
+    ck = _mk(hbm_budget=_tight(), checkpoint_path=str(tmp_path / "ck.npz"))
+    ck.run()
+    assert ck.last_stats["spill_joins"] > 1
+
+
+def test_an_untiered_run_has_no_new_key():
+    ck = _mk()
+    ck.run()
+    st = ck.last_stats
+    assert not [k for k in st if k.startswith("spill_")]
+    assert st["host_spill_s"] == 0.0 and "hbm_budget" not in st
