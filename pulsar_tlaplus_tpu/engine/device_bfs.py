@@ -99,7 +99,9 @@ BIG = bodies.BIG
 # carry the 3- or 5-wide prefix and restore zero-padded.  Shared with
 # the sharded engine via ops/fpset.py (r9); this engine's vector also
 # carries the probe rounds run at each step of the ladder in the
-# ``fpset.FPM_STEPS`` words behind those (PR 37, ``fpset_step_rounds``).
+# ``fpset.FPM_STEPS`` words behind those (PR 37, ``fpset_step_rounds``)
+# and, in the word after them, the lanes its narrow rounds kept from
+# the table's column scatters (PR 42, ``fpset_write_lanes``).
 FPM_N = fpset.FPM_WIDE_N
 
 # In-kernel work-unit vector (round 14, fused-era cost attribution):
@@ -1262,12 +1264,13 @@ class DeviceChecker:
             kcols = keyspec.make(rows)
             lane = jnp.arange(NCs, dtype=jnp.int32)
             valid = lane < n_valid
-            is_new, tc2, n_failed, rounds, lane_rounds, step_rounds = (
-                fpset.lookup_or_insert(
-                    tc, kcols, valid,
-                    dense_rounds=self.fps_dense,
-                    stages=self.fps_stages,
-                )
+            (
+                is_new, tc2, n_failed, rounds, lane_rounds, step_rounds,
+                write_saved,
+            ) = fpset.lookup_or_insert(
+                tc, kcols, valid,
+                dense_rounds=self.fps_dense,
+                stages=self.fps_stages,
             )
             if n_inv:
                 states = jax.vmap(layout.unpack)(rows)
@@ -1282,7 +1285,7 @@ class DeviceChecker:
             fpm = fpset.fpm_update(
                 fpm, rounds, n_failed,
                 jnp.sum(valid.astype(jnp.int32)), lane_rounds,
-                step_rounds,
+                step_rounds, write_saved,
             )
             return (
                 *tc2,
@@ -2069,6 +2072,12 @@ class DeviceChecker:
         # and the step rounds already folded
         self._arb_rounds = self._arb_of = 0
         self._arb_steps_at = np.zeros((fpset.FPM_STEPS,), np.int64)
+        # fpset_write_lanes (PR 42): the lanes presented that the
+        # table's column scatters were not handed (a device word that
+        # wraps, folded at each fetch), the word as last folded, and
+        # the lanes presented that the difference starts from
+        self._write_saved = self._write_saved_at = 0
+        self._write_lanes_at = 0
         # work-unit state (r14): the ``work_*`` counters are PER-RUN
         # (cost attribution prices THIS run; a pooled checker's next
         # job must not inherit the last job's work), so clear them and
@@ -2431,7 +2440,9 @@ class DeviceChecker:
         now, those at a step of the ladder that arbitrates among its
         lanes (``fpset.arbitrates_among_lanes``: a static rule, so the
         host can apply it to ``fpset_step_rounds``' deltas)."""
-        steps = np.asarray(fpm, np.int64)[fpset.FPM_N:]
+        steps = np.asarray(fpm, np.int64)[
+            fpset.FPM_N: fpset.FPM_WRITE_SAVED
+        ]
         delta = steps - self._arb_steps_at
         self._arb_rounds += fpset.lane_arb_rounds(
             delta, nq, self.TCAP, self.fps_dense, self.fps_stages
@@ -2507,6 +2518,10 @@ class DeviceChecker:
         self._slot_rounds += (rounds - self._slot_rounds_at) * self.TCAP
         self._slot_rounds_at = rounds
         self._fold_lane_arb(self._last_fpm, self.ACAP)
+        # fpset_write_lanes: the device's word wraps at 2^32
+        saved = int(self._last_fpm[fpset.FPM_WRITE_SAVED])
+        self._write_saved += (saved - self._write_saved_at) & 0xFFFFFFFF
+        self._write_saved_at = saved
         self._snap["occupancy"] = nv / max(self.TCAP, 1)
         if len(self._last_fpm) >= 4:
             # TLC's "states generated": candidate lanes examined
@@ -4029,7 +4044,12 @@ class DeviceChecker:
         self._slot_rounds_at = int(self._fpm_prev[1])
         self._slot_valid_at = int(self._fpm_prev[3])
         self._arb_rounds = self._arb_of = 0
-        self._arb_steps_at = fpm[fpset.FPM_N:].astype(np.int64)
+        self._arb_steps_at = fpm[
+            fpset.FPM_N: fpset.FPM_WRITE_SAVED
+        ].astype(np.int64)
+        self._write_saved = 0
+        self._write_saved_at = int(fpm[fpset.FPM_WRITE_SAVED])
+        self._write_lanes_at = int(self._fpm_prev[5])
         if self.fuse == "level":
             # work counters restart after resume (frames don't carry
             # them — the same regime as the r8 counter widenings);
@@ -4284,6 +4304,17 @@ class DeviceChecker:
                     fpset_lane_arb_rounds_pct=round(
                         100.0 * self._arb_rounds / self._arb_of, 4
                     ) if self._arb_of else None,
+                )
+                # of this run's lanes presented, those handed to the
+                # table's column scatters: all of them at a step that
+                # writes every lane, the winners in chunks at a narrow
+                # one (folded in _fetch; PR 42)
+                wl = lr - self._write_lanes_at - self._write_saved
+                self.last_stats.update(
+                    fpset_write_lanes=wl,
+                    fpset_write_lanes_per_valid=round(
+                        wl / (vl - self._slot_valid_at), 4
+                    ) if vl > self._slot_valid_at else None,
                 )
         # fusion telemetry (r13): this run's total dispatches per BFS
         # level — the regression-gate signal (steady-state fused levels

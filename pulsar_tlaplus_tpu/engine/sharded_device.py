@@ -867,7 +867,7 @@ class ShardedDeviceChecker:
             lanei = jnp.arange(ACAP, dtype=jnp.int32)
             amask = lanei < n_acc
             valid = amask & ~fpset.all_sentinel(ak)
-            is_new, vk2, n_failed, rounds, lane_rounds, _ = (
+            is_new, vk2, n_failed, rounds, lane_rounds, _, _ = (
                 fpset.lookup_or_insert(
                     vk, ak, valid,
                     dense_rounds=self.fps_dense,

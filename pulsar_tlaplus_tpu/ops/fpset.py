@@ -79,6 +79,25 @@ probing design generalised to the device hot path:
   resolve in the round's reread and a loser with another key moves
   on, so ``(is_new, table, pending, rounds)`` are the same bit for bit
   and the choice is invisible to every caller.
+- **Two writes, one table** (PR 42).  The winners' keys go to the
+  table by a scatter a key column, and on a table past some 2^22 slots
+  a scatter costs by the LANE HANDED to it, parked or winning (0.1 us
+  on a v5e while it is handed fewer than a lane to 1,024 slots; a
+  tenth of that from there on).  A wide round hands the scatters every
+  lane of its buffer, the others parked on the trash row, and most of
+  a wide round's lanes win.  A round NARROW against its table
+  (:func:`writes_winners`, the same two static shapes) presents the
+  tail of a flush, of which one lane in five wins (one in twenty at
+  1,024 lanes): :func:`write_winners` packs the winners' slots and key
+  words to the front of the round's own buffer (a prefix sum and K + 1
+  scatters into ``nq + 1`` words) and hands the table chunks of
+  ``WRITE_CHUNK`` lanes, as many as hold the winners and none for a
+  round nobody won (a ``cli check`` of 9.4M states handed the table
+  52.7M lanes a column where 9.4M carried a key: ``NARROW_*`` and
+  ``WRITE_*`` below, with the chip's readings).  The same words land
+  in the same slots, the trash row alone differs (write-only: no
+  checkpoint, digest or rehash reads it), and ``write_saved`` counts
+  what the table was spared (``fpset_write_lanes``).
 - **On-device growth**: :func:`rehash_cols` re-inserts every occupied
   slot of the old table into the new one, fully on device: a
   `fori_loop` over chunks of ``REHASH_CHUNK`` old slots; a chunk's
@@ -153,8 +172,17 @@ FPM_N = 8
 # that can be taken out of the schedule (``fpset_step_rounds``).  A
 # vector of ``FPM_N`` words (the sharded engine's, an older checkpoint
 # frame's) is updated as it always was.
-FPM_STEPS = 8
-FPM_WIDE_N = FPM_N + FPM_STEPS
+#
+# And one word behind the steps, at ``FPM_WRITE_SAVED`` (PR 42; the
+# vector had eight step words and the default schedule's seven entries
+# left the last at 0, so the width, and every program that writes all
+# its lanes, is what it was): the lanes presented that the table's
+# column scatters were NOT handed, a uint32 that wraps
+# (``lookup_or_insert``'s ``write_saved``; the host folds its deltas
+# at every fetch: ``fpset_write_lanes``, docs/observability.md).
+FPM_STEPS = 7
+FPM_WRITE_SAVED = FPM_N + FPM_STEPS
+FPM_WIDE_N = FPM_WRITE_SAVED + 1
 
 # length of the host-side LOGICAL view: [flushes, probe_rounds,
 # failures, valid_lanes (64-bit), max_probe_rounds, lane_rounds
@@ -179,7 +207,10 @@ def u64(lo_word, hi_word):
     return (hi_word << 32) | np.int64(np.uint32(lo_word & 0xFFFFFFFF))
 
 
-def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds, step_rounds=()):
+def fpm_update(
+    fpm, rounds, n_failed, n_valid, lane_rounds, step_rounds=(),
+    write_saved=0,
+):
     """One flush's device-side metrics update (jit-traceable).
 
     ``fpm`` is the int32[FPM_N] vector; ``n_valid`` (int32, < 2^31 per
@@ -188,7 +219,8 @@ def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds, step_rounds=()):
     1B-state runs report honest duplicate ratios instead of a wrapped
     counter.  ``step_rounds`` (``lookup_or_insert``'s, one int32 a
     schedule entry) lands in the words behind ``FPM_N`` of a vector
-    that has them."""
+    that has them, and ``write_saved`` (its seventh value) in the word
+    at ``FPM_WRITE_SAVED``."""
     valid_lo, valid_hi = add_u32(fpm[3], fpm[5], n_valid)
     lanes_lo, lanes_hi = add_u32(fpm[6], fpm[7], lane_rounds)
     words = [
@@ -201,12 +233,21 @@ def fpm_update(fpm, rounds, n_failed, n_valid, lane_rounds, step_rounds=()):
         lanes_lo,
         lanes_hi,
     ]
-    n_steps = fpm.shape[0] - FPM_N
+    n_steps = min(fpm.shape[0] - FPM_N, FPM_STEPS)
     if n_steps > 0:
         steps = list(step_rounds)
-        steps = steps[: n_steps - 1] + [sum(steps[n_steps - 1:])]
+        # (one entry there is taken as it is: ``sum`` would add a 0 to
+        # it, an equation the vector's programs never had)
+        tail = steps[n_steps - 1:]
+        steps = steps[: n_steps - 1] + [
+            tail[0] if len(tail) == 1 else sum(tail)
+        ]
         steps += [0] * (n_steps - len(steps))
         words += [fpm[FPM_N + i] + d for i, d in enumerate(steps)]
+    if fpm.shape[0] > FPM_WRITE_SAVED:
+        if not isinstance(write_saved, int):
+            write_saved = lax.bitcast_convert_type(write_saved, jnp.int32)
+        words.append(fpm[FPM_WRITE_SAVED] + write_saved)
     return jnp.stack(words)
 
 
@@ -459,13 +500,17 @@ QUARTER_ABOVE = 1 << 20
 
 _NO_LANE = jnp.int32(2**31 - 1)  # claims fill: above every real lane id
 
-# A round whose buffer is NARROW against the table picks the winner of
-# a slot among its ``nq`` lanes and builds no ``claims`` array (PR 40,
-# :func:`win_among_lanes`): up to ``LANE_ARB_MAX_LANES`` lanes, on a
-# table of ``LANE_ARB_MIN_SLOTS`` slots or more, where the ``nq * nq``
-# pairs are no more than ``LANE_ARB_PAIRS_A_SLOT`` a slot of the table
-# — up to 8,192 lanes from 2^22 slots, 16,384 from 2^24, nothing wider
-# and nothing on a smaller table.
+# A round whose buffer is NARROW against its table: up to
+# ``NARROW_MAX_LANES`` lanes on a table of ``NARROW_MIN_SLOTS`` slots or
+# more (:func:`narrow_against_table`).  Two things go by that rule,
+# both chosen from the round's static shapes alone.
+#
+# (1) The arbitration (PR 40, :func:`win_among_lanes`): such a round
+# picks the winner of a slot among its ``nq`` lanes and builds no
+# ``claims`` array, where the ``nq * nq`` pairs are no more than
+# ``LANE_ARB_PAIRS_A_SLOT`` a slot of the table — up to 8,192 lanes
+# from 2^22 slots, 16,384 from 2^24, nothing wider and nothing on a
+# smaller table.
 # Measured on a v5e (``scripts/profile.py arbitrate``, my chip runs,
 # PR 40), microseconds a round, arbitration alone: the pairwise
 # min-reduce 1.5 / 4.4 / 15 / 66 / 262 at 1,024 / 2,048 / 4,096 /
@@ -491,9 +536,61 @@ _NO_LANE = jnp.int32(2**31 - 1)  # claims fill: above every real lane id
 # 1,024 lanes where its ``claims`` round is 15 us), which every test
 # of tier-1 would pay: the floor keeps small tables, and the programs
 # of the two small CLI cells, as they were.
-LANE_ARB_MAX_LANES = 1 << 14
+#
+# (2) The column write (PR 42, :func:`write_winners`): such a round
+# hands the table's K scatters its WINNERS, packed to the front of the
+# round's own buffer and written in chunks of ``WRITE_CHUNK`` lanes,
+# and not every lane it presents, where it presents fewer than one
+# lane to ``WRITE_SLOTS_A_LANE`` slots of the table.  Of the lanes a
+# ``cli check`` of 9,445,152 states presents to its narrow rounds 18%
+# win (5% at 1,024 lanes, 20-28% on the steps above; counted, PERF.md
+# §5).  Measured on a v5e (``scripts/profile.py scatter``, my chip
+# runs, PR 42; microseconds a round of two ``u32[cap + 1]`` columns at
+# 1,024 / 2,048 / 4,096 / 8,192 / 16,384 lanes).  The full-width
+# write, the same at every share of winners: 36 / 46 / 65 / 105 / 184
+# at 2^20 slots (60 / 66 at 2^21); 205 / 331 / 162 / 201 / 279 at
+# 2^22; - / 405 / 640 / 305 / 380 at 2^23; 216 / 428 / 848 / 1,391 /
+# 597 at 2^24; 205 / 405 / 806 / 1,605 / 3,206 at 2^25: a scatter into
+# a table of 2^22 slots and over costs 0.1 us a LANE HANDED to it,
+# parked or winning, while it is handed fewer than a lane to 1,024
+# slots, and 6 to 20 ns a lane from there on (4,096 lanes at 2^22
+# slots are cheaper than 2,048); under 2^22 slots it is cheap at any
+# width.  The winners' write at 2^25 slots (the same at 2^22 to 2^24
+# within 5%): with no winner 23 / 37 / 66 / 123 / 238 (the packing:
+# 14-22 ns a lane presented), one lane in twenty winning 47 / 61 / 114
+# / 216 / 402, one in four 81 / 143 / 266 / 511 / 999, every lane 210
+# / 412 / 816 / 1,621 / 3,234 (0.2 us a lane handed over, both
+# columns, and some 1.5 us a trip).  At the shares counted it wins
+# wherever the full-width write is in its dear regime (1,024 lanes:
+# 47 against 205) and loses wherever that is in its cheap one (8,192
+# lanes at 2^23: 507 against 305; 1,024 at 2^21: 74 against 60): the
+# rule is the regime's own boundary.  Chunks of 64 / 128 / 256 / 512
+# lanes read 36 / 47 / 76 / 126 at 1,024 lanes with one in twenty
+# winning and within 4% of each other from 4,096 lanes up, and chunks
+# of an eighth of the buffer 595-624 where 128 lanes read 402 at
+# 16,384: a wide chunk pays more in the last trip's parked lanes than
+# a narrow one in trips, so one width for every round.  The same
+# packing with the lane indices scattered and the slots and key words
+# gathered through them reads 30 / 62 / 115 / 222 / 436 with no winner
+# (a gather is 12 ns a lane more than a scatter there), so the slots
+# and the key words are scattered to their ranks themselves; packed
+# by one sort on the flag it reads 11 / 12 / 13 / 14 / 20, and 3 s
+# more to compile a loop at 16,384 lanes (4.6 against 1.5), which
+# every program that holds a flush would pay a tier: not taken
+# (PERF.md §7).  The pairs term is the arbitration's own price and
+# the write has none.
+NARROW_MAX_LANES = 1 << 14
+NARROW_MIN_SLOTS = 1 << 22
 LANE_ARB_PAIRS_A_SLOT = 16
-LANE_ARB_MIN_SLOTS = 1 << 22
+WRITE_SLOTS_A_LANE = 1 << 10
+WRITE_CHUNK = 128
+
+
+def narrow_against_table(nq: int, cap: int) -> bool:
+    """Whether a probe round of ``nq`` lanes is narrow against a table
+    of ``cap`` slots: the static shapes alone decide, and the round's
+    outputs are the same whatever follows from it."""
+    return nq <= NARROW_MAX_LANES and cap >= NARROW_MIN_SLOTS
 
 
 def arbitrates_among_lanes(nq: int, cap: int) -> bool:
@@ -502,10 +599,23 @@ def arbitrates_among_lanes(nq: int, cap: int) -> bool:
     through a ``claims`` array (:func:`win_by_claims`).  The static
     shapes alone decide; the winners are the same either way."""
     return (
-        nq <= LANE_ARB_MAX_LANES
-        and cap >= LANE_ARB_MIN_SLOTS
+        narrow_against_table(nq, cap)
         and nq * nq <= LANE_ARB_PAIRS_A_SLOT * cap
     )
+
+
+def writes_winners(nq: int, cap: int) -> bool:
+    """Whether a probe round of ``nq`` lanes on a table of ``cap``
+    slots hands the table's column scatters its winners alone
+    (:func:`write_winners`) and not every lane it presents.  The
+    static shapes alone decide; the table is the same either way."""
+    return narrow_against_table(nq, cap) and nq * WRITE_SLOTS_A_LANE < cap
+
+
+def write_chunk(nq: int) -> int:
+    """Lanes a trip of :func:`write_winners` hands the table for a
+    round of ``nq`` lanes."""
+    return min(nq, WRITE_CHUNK)
 
 
 def win_by_claims(bid, s, lane_ids, cap: int):
@@ -540,6 +650,50 @@ def win_among_lanes(bid, s, lane_ids, cap: int):
             axis=1,
         )
         return bid & (least == lane_ids)
+
+
+def write_winners(tc, oc, win, s, kcols, chunk: int):
+    """A narrow round's column write: the lanes of ``win`` write their
+    keys ``kcols`` to their slots ``s`` (and 1 to the occupancy column
+    ``oc``, where the layout has one), and the table's scatters are
+    handed the WINNERS alone.  Their slots and key words are packed to
+    the front of ``nq``-lane buffers at their rank (a prefix sum, and a
+    scatter a buffer into ``nq + 1`` words, the losers on its own trash
+    word), and the table is written in ``ceil(n_win / chunk)`` trips of
+    ``chunk`` lanes each; the lanes of a trip past the winners go to
+    the table's trash row.  A round with no winner makes no trip, one
+    whose every lane wins scatters what the full-width write does, and
+    the table's slots under ``cap`` come out the same words either way.
+
+    Returns ``(tc', oc', lanes)``, ``lanes`` (uint32) what the table's
+    scatters were handed a column: ``chunk`` times the trips."""
+    nq = win.shape[0]
+    cap = tc[0].shape[0] - 1
+    rank = jnp.cumsum(win.astype(jnp.int32))  # a winner's rank, plus 1
+    n_win = rank[nq - 1]
+    at = jnp.where(win, rank - 1, nq)
+    ws = jnp.full((nq + 1,), cap, jnp.int32).at[at].set(s)[:nq]
+    ks = tuple(
+        jnp.zeros((nq + 1,), jnp.uint32).at[at].set(k)[:nq] for k in kcols
+    )
+    trips = (n_win + (chunk - 1)) // chunk
+
+    def trip(i, cols):
+        # a last chunk that would pass the buffer's end is clamped back
+        # over winners already written: the same words to the same slots
+        start = (i * chunk,)
+        ws_i = lax.dynamic_slice(ws, start, (chunk,))
+        tc, oc = cols
+        tc = tuple(
+            c.at[ws_i].set(lax.dynamic_slice(k, start, (chunk,)))
+            for c, k in zip(tc, ks)
+        )
+        if oc is not None:
+            oc = oc.at[ws_i].set(1)
+        return tc, oc
+
+    tc, oc = lax.fori_loop(0, trips, trip, (tuple(tc), oc))
+    return tc, oc, (trips * chunk).astype(jnp.uint32)
 
 
 def slot_hash(kcols: Tuple[jax.Array, ...]) -> jax.Array:
@@ -603,6 +757,11 @@ def probe_insert(
     buffer is narrow against the table, among the lanes themselves
     (:func:`win_among_lanes`): :func:`arbitrates_among_lanes` chooses
     from ``nq`` and ``cap``, and every output is the same either way.
+    The winners' keys go to the table by a scatter a column: of every
+    lane of the buffer, the others parked on the trash row, or, where
+    the buffer is narrow against the table (:func:`writes_winners`, the
+    same two shapes), of the winners alone (:func:`write_winners`): the
+    table's slots under ``cap`` are the same words either way.
 
     ``occ`` selects the empty-slot encoding: ``None`` = all-SENTINEL
     key (the engines' layout), else an explicit occupancy column (the
@@ -614,9 +773,14 @@ def probe_insert(
     are pending (the staged wrapper passes the next, narrower stage's
     capacity; the default 0 probes until every lane resolved).
 
-    Returns ``(is_new, tcols', occ', pending, rounds)``; ``pending``
-    lanes are unresolved after ``max_probes`` rounds or handed over
-    (callers count the former as hard failures, never silent drops).
+    Returns ``(is_new, tcols', occ', pending, rounds, write_saved)``;
+    ``pending`` lanes are unresolved after ``max_probes`` rounds or
+    handed over (callers count the former as hard failures, never
+    silent drops).  ``write_saved`` is the lanes presented over the
+    loop's rounds LESS the lanes the table's scatters were handed a
+    column: uint32 where the winners alone are written, the plain
+    integer 0 where every lane is (so that such a loop carries nothing
+    for it).
     """
     cap = tcols[0].shape[0] - 1
     nq = kcols[0].shape[0]
@@ -630,6 +794,7 @@ def probe_insert(
         win_among_lanes if arbitrates_among_lanes(nq, cap)
         else win_by_claims
     )
+    chunk = write_chunk(nq) if writes_winners(nq, cap) else 0
 
     def occupied_at(tc, oc, s, sv):
         if has_occ:
@@ -649,7 +814,7 @@ def probe_insert(
     # arithmetic, the pending count and the loop's carry stay under no
     # part.
     def body(st):
-        r, _, pending, is_new, tc, oc = st
+        r, _, pending, is_new, tc, oc, *saved = st
         ru = r.astype(jnp.uint32)
         off = (ru * (ru + jnp.uint32(1))) >> 1
         slot = ((h + off) & capm).astype(jnp.int32)
@@ -665,11 +830,21 @@ def probe_insert(
         # bid for empty slots with the lane id; min wins
         bid = pending & ~occ_s
         win = arbitrate(bid, s, lane_ids, cap)
-        ws = jnp.where(win, s, cap)
-        with spans.part("write"):
-            tc = tuple(c.at[ws].set(k) for c, k in zip(tc, kcols))
-            if has_occ:
-                oc = oc.at[ws].set(1)
+        if chunk:
+            # the packing of the winners is the write's cost
+            with spans.part("write"):
+                tc, oc_w, handed = write_winners(
+                    tc, oc if has_occ else None, win, s, kcols, chunk
+                )
+                if has_occ:
+                    oc = oc_w
+                saved = [saved[0] + (jnp.uint32(nq) - handed)]
+        else:
+            ws = jnp.where(win, s, cap)
+            with spans.part("write"):
+                tc = tuple(c.at[ws].set(k) for c, k in zip(tc, kcols))
+                if has_occ:
+                    oc = oc.at[ws].set(1)
         is_new = is_new | win
         pending = pending & ~win
         # same-key losers resolve against the newly written slot
@@ -680,7 +855,7 @@ def probe_insert(
                 eq2 = eq2 & (cv == ck)
             occ2 = occupied_at(tc, oc, s, sv2)
         pending = pending & ~(occ2 & eq2)
-        return (r + 1, n_set(pending), pending, is_new, tc, oc)
+        return (r + 1, n_set(pending), pending, is_new, tc, oc, *saved)
 
     st = (
         jnp.asarray(start_round, jnp.int32),
@@ -689,9 +864,14 @@ def probe_insert(
         jnp.zeros((nq,), jnp.bool_),
         tuple(tcols),
         occ0,
+    ) + ((jnp.uint32(0),) if chunk else ())
+    r, _, pending, is_new, tcols, occ_out, *saved = lax.while_loop(
+        cond, body, st
     )
-    r, _, pending, is_new, tcols, occ_out = lax.while_loop(cond, body, st)
-    return is_new, tcols, (occ_out if has_occ else None), pending, r
+    return (
+        is_new, tcols, (occ_out if has_occ else None), pending, r,
+        saved[0] if chunk else 0,
+    )
 
 
 def ladder_steps(nq: int, dense_rounds: int, stages, max_probes=MAX_PROBES):
@@ -745,7 +925,7 @@ def lookup_or_insert(
     ladder's compactions', ``ops.compact.compact_by_flag``).
 
     Returns ``(is_new, tcols', n_failed, rounds, lane_rounds,
-    step_rounds)`` where
+    step_rounds, write_saved)`` where
     ``is_new`` is in ORIGINAL lane order (exactly one True per distinct
     new key — the minimum valid lane), ``n_failed`` counts lanes
     dropped at a stage overflow or still pending at ``max_probes``
@@ -754,7 +934,11 @@ def lookup_or_insert(
     (uint32) the lanes presented to the table summed over those rounds:
     each stage's width times the rounds run at it; ``step_rounds`` is
     those rounds by the schedule's entry (a tuple ``[dense, *stages]``
-    long: int32 scalars, and a plain 0 for a stage that was not built).
+    long: int32 scalars, and a plain 0 for a stage that was not built);
+    ``write_saved`` is the part of ``lane_rounds`` that the table's
+    column scatters were NOT handed, the steps that write their winners
+    alone summed (:func:`probe_insert`: uint32, or the plain integer 0
+    where no step of the ladder does).
     """
     nq = kcols[0].shape[0]
     K = len(kcols)
@@ -774,6 +958,7 @@ def lookup_or_insert(
     n_failed = jnp.int32(0)
     lane_rounds = jnp.uint32(0)
     step_rounds = [0] * (1 + len(stages))
+    write_saved = 0
     r = jnp.int32(0)
     cur_keys, cur_ids, cur_pending, width = kcols, None, valid, nq
     for i, (capi, limit, entry) in enumerate(ladder):
@@ -803,10 +988,11 @@ def lookup_or_insert(
         # round costs by the lane presented, parked or not, so the
         # ``limit`` is a ceiling and the pending count sets the width
         fits = ladder[i + 1][0] if i + 1 < len(ladder) else 0
-        stage_new, tcols, _, cur_pending, r2 = probe_insert(
+        stage_new, tcols, _, cur_pending, r2, saved = probe_insert(
             tcols, cur_keys, cur_pending, max_probes=limit,
             start_round=r, lane_ids=cur_ids, handover=fits,
         )
+        write_saved = write_saved + saved
         with spans.part("narrow"):
             is_new = _merge_new(is_new, stage_new, cur_ids, nq)
         step_rounds[entry] = r2 - r
@@ -815,7 +1001,10 @@ def lookup_or_insert(
         ).astype(jnp.uint32)
         r = r2
     n_failed = n_failed + jnp.sum(cur_pending.astype(jnp.int32))
-    return is_new, tcols, n_failed, r, lane_rounds, tuple(step_rounds)
+    return (
+        is_new, tcols, n_failed, r, lane_rounds, tuple(step_rounds),
+        write_saved,
+    )
 
 
 def _merge_new(is_new, stage_new, stage_ids, nq):
@@ -854,7 +1043,7 @@ def flush_acc(
     lanei = jnp.arange(nq, dtype=jnp.int32)
     amask = lanei < n_acc
     valid = amask & ~all_sentinel(kcols)
-    is_new, tcols2, n_failed, rounds, lane_rounds, step_rounds = (
+    is_new, tcols2, n_failed, rounds, lane_rounds, step_rounds, saved = (
         lookup_or_insert(
             tcols, kcols, valid, dense_rounds=dense_rounds,
             stages=stages, materialize=materialize,
@@ -863,7 +1052,7 @@ def flush_acc(
     n_new = jnp.sum(is_new.astype(jnp.int32))
     fpm2 = fpm_update(
         fpm, rounds, n_failed, jnp.sum(valid.astype(jnp.int32)),
-        lane_rounds, step_rounds,
+        lane_rounds, step_rounds, saved,
     )
     return tcols2, n_new, is_new.astype(jnp.uint32), fpm2
 
@@ -1010,7 +1199,7 @@ def _rehash_cols(old_cols, new_cols, *, chunk, max_probes, materialize):
             )
             ks = tuple(c[:width] for c in packed)
             occm = jnp.arange(width, dtype=jnp.int32) < n_occ
-        _new_flags, new, n_failed, _r, lane_rounds, _ = lookup_or_insert(
+        _new_flags, new, n_failed, _r, lane_rounds, _, _ = lookup_or_insert(
             new, ks, occm, max_probes=max_probes,
             dense_rounds=DENSE_ROUNDS, stages=STAGES_TWO_STEP,
             materialize=materialize,
@@ -1099,7 +1288,7 @@ class FPSet:
         if valid is None:
             valid = jnp.ones((nq,), jnp.bool_)
         self.reserve(self.n + nq)
-        is_new, self.cols, n_failed, rounds, lane_rounds, _ = (
+        is_new, self.cols, n_failed, rounds, lane_rounds, _, _ = (
             lookup_or_insert(
                 self.cols, kcols, valid,
                 dense_rounds=self.dense_rounds, stages=self.stages,

@@ -61,7 +61,7 @@ def lookup_insert(
     ``n_failed`` counts lanes still unresolved after ``max_probes`` rounds
     (callers must treat nonzero as an error — see module docstring).
     """
-    is_new, (t1, t2, t3), occ, pending, _rounds = fpset.probe_insert(
+    is_new, (t1, t2, t3), occ, pending, _rounds, _ = fpset.probe_insert(
         (t1, t2, t3), (k1, k2, k3), valid, occ=occ,
         max_probes=max_probes,
     )

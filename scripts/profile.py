@@ -16,6 +16,7 @@ dispatch timing, completion barriers) live in one place:
     python scripts/profile.py ladder    [--schedules ...]  # PR 37
     python scripts/profile.py arbitrate [--widths ...] [--caps-log2 ...]
     python scripts/profile.py scatter   [--updates ...] [--caps-log2 ...]
+        [--variants two_as_is,compact8,...] [--win 0,0.02,0.1,0.5,1]
 
 Mapping from the retired scripts:
 
@@ -827,7 +828,7 @@ def _prefilled(cap, K, load):
     def prefill(tcols):
         def body(i, tc):
             idx = i.astype(u) * u(chunk) + jnp.arange(chunk, dtype=u)
-            _, tc, _, _, _, _ = fpset.lookup_or_insert(
+            _, tc, _, _, _, _, _ = fpset.lookup_or_insert(
                 tc, keys_of(idx), idx < u(n_pre),
                 stages=fpset.STAGES_TWO_STEP,
             )
@@ -865,7 +866,7 @@ def cmd_ladder(args):
             old = _fmix(h) % u(max(n_pre, 1))
             new = u(n_pre) + i.astype(u) * u(nq) + lane
             idx = jnp.where(dup & (n_pre > 0), old, new)
-            _, tc, nf, _, lr, _ = fpset.lookup_or_insert(
+            _, tc, nf, _, lr, _, _ = fpset.lookup_or_insert(
                 tc, keys_of(idx), valid, dense_rounds=dense,
                 stages=stages, materialize=materialize,
             )
@@ -1015,7 +1016,7 @@ def cmd_arbitrate(args):
                         old = _fmix(h) % u(max(n_pre, 1))
                         new = u(n_pre) + i.astype(u) * u(nq) + lane
                         idx = jnp.where(dup, old, new)
-                        _, tc, _, _, r = fpset.probe_insert(
+                        _, tc, _, _, r, _ = fpset.probe_insert(
                             tc, keys_of(idx), valid)
                         return tc, rounds + r
                     return lax.fori_loop(
@@ -1050,39 +1051,101 @@ def cmd_arbitrate(args):
 
 
 def cmd_scatter(args):
-    """Microseconds a scatter for the probe's column write (the part
-    ``write``; PR 40, for the PR after it): ``--updates`` lanes into a
-    ``u32[cap + 1]`` column carried by a ``fori_loop``, as
-    ``probe_insert`` writes it (non-winners parked on the trash row)
-    and with the candidates: non-winners dropped out of bounds,
+    """Microseconds a round for the probe's column write (the part
+    ``write``; PR 40 and PR 42): ``--updates`` lanes into ``u32[cap +
+    1]`` columns carried by a ``fori_loop``, as ``probe_insert`` writes
+    them on a wide round (non-winners parked on the trash row) and with
+    the candidates: non-winners dropped out of bounds,
     ``unique_indices`` (a hint that the parked lanes belie: a timing,
     not a result), every lane a winner with and without
-    ``indices_are_sorted``, and two columns in one loop.  Whether the
-    seconds go by the slot or by the round shows in the rows of one
-    width across the table sizes."""
+    ``indices_are_sorted``, two columns in one loop; and the narrow
+    round's write on two columns, the winners packed and handed over in
+    chunks of 1/D of the lanes (``directD``) or of N lanes
+    (``directcN``): ``fpset.write_winners`` itself (a prefix sum, the
+    slots and key words scattered to their ranks, the chunked table
+    scatters), and the packings not taken: the lane indices scattered
+    and the rest gathered through them (``compactD``, ``compactcN``),
+    one sort on the flag (``sortedD``, ``sortedcN``).  ``--win`` is the
+    share of the lanes that win, a list: one compile serves every
+    share.  Whether the seconds go by the slot, by the lane handed over
+    or by the round shows in the rows of one width across table sizes
+    and shares."""
     import json
+    import re
 
+    from pulsar_tlaplus_tpu.ops import fpset
     from pulsar_tlaplus_tpu.ops.dedup import _fmix
 
     u = jnp.uint32
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     out = open(args.out, "a")
 
+    def in_chunks(cols, ws, ks, n_win, chunk):
+        # the packed winners to the table, a chunk a trip
+        def trip(i, cols):
+            ws_i = lax.dynamic_slice(ws, (i * chunk,), (chunk,))
+            return tuple(
+                c.at[ws_i].set(lax.dynamic_slice(k, (i * chunk,), (chunk,)))
+                for c, k in zip(cols, ks)
+            )
+
+        return lax.fori_loop(0, (n_win + chunk - 1) // chunk, trip, cols)
+
+    def by_index(cols, win, s, ks, chunk):
+        # the packing not taken: the winners' lane indices scattered to
+        # their ranks, the slots and key words gathered through them
+        nq, cap = win.shape[0], cols[0].shape[0] - 1
+        rank = jnp.cumsum(win.astype(jnp.int32))
+        n_win = rank[nq - 1]
+        lane = jnp.arange(nq, dtype=jnp.int32)
+        idx = jnp.zeros((nq + 1,), jnp.int32).at[
+            jnp.where(win, rank - 1, nq)
+        ].set(lane)[:nq]
+        ws = jnp.where(lane < n_win, s[idx], cap)
+        return in_chunks(cols, ws, tuple(k[idx] for k in ks), n_win, chunk)
+
+    def by_sort(cols, win, s, ks, chunk):
+        # the packing not taken: one sort on the flag
+        cap = cols[0].shape[0] - 1
+        _, ws, *ks = lax.sort(
+            ((~win).astype(jnp.int32), jnp.where(win, s, cap), *ks),
+            num_keys=1, is_stable=False,
+        )
+        return in_chunks(
+            cols, ws, ks, jnp.sum(win.astype(jnp.int32)), chunk
+        )
+
     def variant(name, nq, cap, reps):
         lane = jnp.arange(nq, dtype=u)
+        packed = re.fullmatch(r"(compact|direct|sorted)(c?)(\d+)", name)
+        chunk = 0
+        if packed and packed.group(2):
+            chunk = min(nq, int(packed.group(3)))
+        elif packed:
+            chunk = min(nq, max(nq // int(packed.group(3)), fpset.WRITE_CHUNK))
 
-        def body(i, cols):
+        def body(i, carry):
+            cols, win_of_1024 = carry
             h = _fmix(lane ^ _fmix(i.astype(u) + cols[0][cap]))
             if "allwin" in name:
                 win = lane >= u(0)
             else:
-                win = ((h >> 24) & u(0xFF)) < u(int(args.win * 256))
+                win = ((h >> 22) & u(0x3FF)) < win_of_1024
             if "sorted" in name:
                 # distinct ascending slots, a random start a round
                 s = (h[0] & u(cap // 2 - 1)) + lane * u(cap // (2 * nq))
             else:
                 s = h & u(cap - 1)
             s = s.astype(jnp.int32)
+            ks = tuple(h ^ u(j + 1) for j in range(len(cols)))
+            if packed and packed.group(1) == "direct":
+                cols, _, _ = fpset.write_winners(
+                    cols, None, win, s, ks, chunk
+                )
+                return cols, win_of_1024
+            if packed:
+                pack = by_index if packed.group(1) == "compact" else by_sort
+                return pack(cols, win, s, ks, chunk), win_of_1024
             kw = {}
             if "drop" in name:
                 ws, kw["mode"] = jnp.where(win, s, cap + 1), "drop"
@@ -1093,39 +1156,46 @@ def cmd_scatter(args):
             if "sorted" in name:
                 kw["indices_are_sorted"] = True
             return tuple(
-                c.at[ws].set(h ^ u(j + 1), **kw)
-                for j, c in enumerate(cols)
-            )
-        ncols = 2 if "two" in name else 1
+                c.at[ws].set(k, **kw) for c, k in zip(cols, ks)
+            ), win_of_1024
+        ncols = 2 if packed or "two" in name else 1
         return jax.jit(
-            lambda cols: lax.fori_loop(0, reps, body, cols),
+            lambda cols, w: lax.fori_loop(0, reps, body, (cols, w))[0],
             donate_argnums=0,
-        ), ncols
+        ), ncols, chunk
 
+    shares = [float(x) for x in str(args.win).split(",")]
     for cap_log2 in (int(x) for x in args.caps_log2.split(",")):
         cap = 1 << cap_log2
         for nq in (int(x) for x in args.updates.split(",")):
             for name in args.variants.split(","):
-                fn, ncols = variant(name, nq, cap, args.reps)
+                fn, ncols, chunk = variant(name, nq, cap, args.reps)
                 cols = tuple(
                     jnp.full((cap + 1,), 0xFFFFFFFF, u) for _ in range(ncols)
                 )
-                cols = barrier(fn(cols))  # compile
-                times = []
-                for _ in range(3):
-                    t0 = time.time()
-                    cols = barrier(fn(cols))
-                    times.append(time.time() - t0)
-                row = {
-                    "variant": name, "updates": nq, "cap_log2": cap_log2,
-                    "win": args.win, "columns": ncols,
-                    "us_a_scatter": round(
-                        sorted(times)[1] * 1e6 / args.reps / ncols, 3),
-                    "device": jax.devices()[0].device_kind,
-                }
-                print(json.dumps(row), flush=True)
-                out.write(json.dumps(row) + "\n")
-                out.flush()
+                t0 = time.time()
+                cols = barrier(fn(cols, u(0)))  # compile
+                compile_s = round(time.time() - t0, 2)
+                for share in shares:
+                    win = u(round(share * 1024))
+                    times = []
+                    for _ in range(3):
+                        t0 = time.time()
+                        cols = barrier(fn(cols, win))
+                        times.append(time.time() - t0)
+                    us = sorted(times)[1] * 1e6 / args.reps
+                    row = {
+                        "variant": name, "updates": nq,
+                        "cap_log2": cap_log2, "win": share,
+                        "columns": ncols, "chunk": chunk,
+                        "compile_s": compile_s,
+                        "us_a_scatter": round(us / ncols, 3),
+                        "us_a_round": round(us, 3),
+                        "device": jax.devices()[0].device_kind,
+                    }
+                    print(json.dumps(row), flush=True)
+                    out.write(json.dumps(row) + "\n")
+                    out.flush()
                 del cols
     return 0
 
@@ -1237,9 +1307,12 @@ def main(argv=None):
                     help="names made of: drop (non-winners out of "
                     "bounds, not on the trash row), unique, sorted "
                     "(the scatter's hints; sorted wants allwin), "
-                    "allwin (every lane writes), two (two columns)")
-    pw.add_argument("--win", type=float, default=0.1,
-                    help="share of the lanes that win a slot")
+                    "allwin (every lane writes), two (two columns); "
+                    "or directD / directcN (compact, sorted: the "
+                    "packings not taken): the narrow round's write by "
+                    "the winners, chunks of 1/D of the lanes or of N")
+    pw.add_argument("--win", default="0.1",
+                    help="shares of the lanes that win a slot, a list")
     pw.add_argument("--reps", type=int, default=500)
     pw.add_argument("--out", default="chiprun_out/scatter.jsonl")
     pw.set_defaults(fn=cmd_scatter)

@@ -107,7 +107,7 @@ def _table_at_load(cap, load, ncols, seed):
         axis=0,
     )
     kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
-    _new, tcols, n_failed, _r, _l, _s = fpset.lookup_or_insert(
+    _new, tcols, n_failed, _r, _l, _s, _ = fpset.lookup_or_insert(
         fpset.empty_cols(cap, ncols), kcols,
         jnp.ones((len(keys),), jnp.bool_),
     )
@@ -217,7 +217,7 @@ def test_rehash_presents_lanes_by_the_pending_count():
     assert failed == 0 and moved == len(keys)
     assert lane_rounds / moved < 5
     ks = tuple(c[:cap] for c in old)
-    _f, _t, _o, pending, rounds = fpset.probe_insert(
+    _f, _t, _o, pending, rounds, _ = fpset.probe_insert(
         fpset.empty_cols(2 * cap, 2), ks, ~fpset.all_sentinel(ks)
     )
     assert not np.asarray(pending).any()
@@ -239,7 +239,7 @@ def test_failure_count_on_overload():
     rng = np.random.default_rng(5)
     keys = rng.integers(0, 2**31, size=(4 * cap, 2), dtype=np.uint32)
     cols = fpset.empty_cols(cap, 2)
-    is_new, cols, n_failed, _rounds, _lanes, _ = fpset.lookup_or_insert(
+    is_new, cols, n_failed, _rounds, _lanes, _, _ = fpset.lookup_or_insert(
         cols, (keys[:, 0], keys[:, 1]),
         jnp.ones((len(keys),), jnp.bool_),
     )
@@ -266,10 +266,10 @@ def test_staged_compaction_matches_single_loop():
     keys = pool[rng.integers(0, len(pool), size=4096)]
     kcols = (jnp.asarray(keys[:, 0]), jnp.asarray(keys[:, 1]))
     valid = jnp.ones((len(keys),), jnp.bool_)
-    staged_new, staged_cols, nf, _, _, _ = fpset.lookup_or_insert(
+    staged_new, staged_cols, nf, _, _, _, _ = fpset.lookup_or_insert(
         fpset.empty_cols(cap, 2), kcols, valid
     )
-    simple_new, simple_cols, _, pending, _ = fpset.probe_insert(
+    simple_new, simple_cols, _, pending, _, _ = fpset.probe_insert(
         fpset.empty_cols(cap, 2), kcols, valid
     )
     assert int(nf) == 0 and not bool(np.asarray(pending).any())
@@ -308,7 +308,7 @@ def _batch(seed, nq, cap, load, dup, K, valid_share=0.95):
     rng.shuffle(keys)
     tcols = fpset.empty_cols(cap, K)
     if n_pre:
-        _, tcols, _, pending, _ = fpset.probe_insert(
+        _, tcols, _, pending, _, _ = fpset.probe_insert(
             tcols, tuple(jnp.asarray(pre[:, i]) for i in range(K)),
             jnp.ones((n_pre,), jnp.bool_),
         )
@@ -343,7 +343,7 @@ def _pending_by_round(tcols, kcols, valid, ceiling):
         counts.append(int(np.asarray(pending).sum()))
         if counts[-1] == 0 or r >= ceiling:
             return counts
-        _, tcols, _, pending, r2 = fpset.probe_insert(
+        _, tcols, _, pending, r2, _ = fpset.probe_insert(
             tcols, kcols, pending, max_probes=r + 1, start_round=r
         )
         assert int(r2) == r + 1
@@ -406,10 +406,10 @@ def test_pending_driven_schedule_matches_single_loop(
     tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
     d, st = fpset.resolve_schedule(dense, stages)
     ceiling = _ladder(nq, d, st)[-1][1]
-    got_new, got_cols, n_failed, rounds, _, _ = fpset.lookup_or_insert(
+    got_new, got_cols, n_failed, rounds, _, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=dense, stages=stages
     )
-    want_new, want_cols, _, pending, want_rounds = fpset.probe_insert(
+    want_new, want_cols, _, pending, want_rounds, _ = fpset.probe_insert(
         tcols, kcols, valid, max_probes=ceiling
     )
     assert np.array_equal(np.asarray(got_new), np.asarray(want_new))
@@ -432,7 +432,7 @@ def test_lane_rounds_is_width_times_rounds_and_never_above_fixed(
     tcols, kcols, valid = _batch(nq + K, nq, cap, load, dup, K)
     d, st = fpset.resolve_schedule(dense, stages)
     ladder = _ladder(nq, d, st)
-    _, _, n_failed, rounds, lane_rounds, _ = fpset.lookup_or_insert(
+    _, _, n_failed, rounds, lane_rounds, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=dense, stages=stages
     )
     pending = _pending_by_round(tcols, kcols, valid, ladder[-1][1])
@@ -462,11 +462,11 @@ def test_halving_ladder_matches_single_loop_and_two_step_ladder(
     two = fpset.lookup_or_insert(
         tcols, kcols, valid, stages=fpset.STAGES_TWO_STEP
     )
-    one_new, one_cols, _, pending, one_rounds = fpset.probe_insert(
+    one_new, one_cols, _, pending, one_rounds, _ = fpset.probe_insert(
         tcols, kcols, valid
     )
     assert not bool(np.asarray(pending).any())
-    for new, cols, n_failed, rounds, _, steps in (got, two):
+    for new, cols, n_failed, rounds, _, steps, _ in (got, two):
         assert np.array_equal(np.asarray(new), np.asarray(one_new))
         for a, b in zip(cols, one_cols):
             assert np.array_equal(np.asarray(a)[:cap], np.asarray(b)[:cap])
@@ -515,7 +515,7 @@ def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
     carried = max(nq // 16, fpset.MIN_STAGE)
     after_one = _pending_by_round(tcols, kcols, valid, 1)[1]
     assert after_one > carried
-    is_new, cols, n_failed, _, lane_rounds, _ = fpset.lookup_or_insert(
+    is_new, cols, n_failed, _, lane_rounds, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, **sched
     )
     assert int(n_failed) == after_one - carried
@@ -525,7 +525,7 @@ def test_handover_at_a_ceiling_counts_every_lane_it_cannot_carry():
     assert int((np.asarray(valid) & ~member).sum()) <= int(n_failed)
     assert int(lane_rounds) >= nq + carried
     # the same batch with room in the next stage: nothing fails
-    _, _, none_failed, _, _, _ = fpset.lookup_or_insert(
+    _, _, none_failed, _, _, _, _ = fpset.lookup_or_insert(
         tcols, kcols, valid, dense_rounds=1, stages=((2, 64),)
     )
     assert int(none_failed) == 0
@@ -1021,8 +1021,8 @@ def test_probe_insert_is_the_same_by_either_arbitration(
         got[among_lanes] = fpset.probe_insert(
             tcols, kcols, jnp.asarray(valid), occ=occ, max_probes=3,
         )
-    (new_c, cols_c, occ_c, pend_c, r_c) = got[False]
-    (new_l, cols_l, occ_l, pend_l, r_l) = got[True]
+    (new_c, cols_c, occ_c, pend_c, r_c, _) = got[False]
+    (new_l, cols_l, occ_l, pend_l, r_l, _) = got[True]
     assert np.array_equal(np.asarray(new_c), np.asarray(new_l))
     assert np.array_equal(np.asarray(pend_c), np.asarray(pend_l))
     assert int(r_c) == int(r_l) == 3  # cut short: lanes still pending
@@ -1035,7 +1035,7 @@ def test_probe_insert_is_the_same_by_either_arbitration(
         )
     # to the end, against a Python set
     _held_to(monkeypatch, True)
-    is_new, _cols, _occ, pending, _r = fpset.probe_insert(
+    is_new, _cols, _occ, pending, _r, _ = fpset.probe_insert(
         tcols, kcols, jnp.asarray(valid), occ=occ,
     )
     assert int(np.asarray(pending).sum()) == 0
@@ -1098,7 +1098,7 @@ def test_lookup_or_insert_is_the_same_by_either_arbitration(
         if a.shape == (cap + 1,):  # the trash row holds any loser
             a, b = a[:cap], b[:cap]
         assert np.array_equal(a, b)
-    is_new, _cols, n_failed, rounds, _lanes, steps = got[True]
+    is_new, _cols, n_failed, rounds, _lanes, steps, _ = got[True]
     assert int(n_failed) == 0 and int(rounds) == sum(int(x) for x in steps)
     assert np.array_equal(
         np.asarray(is_new), _first_lanes_of_new_keys(held, keys, valid)
@@ -1230,7 +1230,9 @@ def test_lane_arb_rounds_counter_is_the_rule_over_the_fetches(
     def recording_fetch(st, vec=None):
         out = fetch(st, vec)
         seen.append((
-            np.asarray(ck._last_fpm, np.int64)[fpset.FPM_N:], ck.TCAP
+            np.asarray(ck._last_fpm, np.int64)[
+                fpset.FPM_N: fpset.FPM_WRITE_SAVED
+            ], ck.TCAP
         ))
         return out
 
@@ -1289,6 +1291,434 @@ def test_lane_arb_rounds_fold_a_seed_loads_merges_at_their_own_ladder(
     st = ck.last_stats
     assert st["fpset_lane_arb_rounds"] == sum(a for _n, a, _o in folds)
     assert st["fpset_probe_rounds"] == sum(o for _n, _a, o in folds)
+
+
+# ---- a narrow round writes only its winners (PR 42) ------------------
+
+
+def _write_held_to(monkeypatch, winners):
+    """Hold the write's rule to one side for what is traced next."""
+    monkeypatch.setattr(fpset, "writes_winners", lambda nq, cap: winners)
+
+
+def _round_of_known_winners(nq, cap, n_win, seed):
+    """``(table columns, keys, valid)`` of a round whose winners are
+    known: every key of the pool has a round-0 slot of its own; 20 of
+    them are in the table already and 20 valid lanes carry them
+    (duplicates, found in round 0); ``n_win`` more valid lanes each
+    win their empty slot in round 0; and up to 50 lanes repeat a
+    winner's key from a higher lane: same-key losers, resolved by the
+    round's reread."""
+    rng = np.random.default_rng(seed)
+    pool = rng.integers(0, 2**32 - 2, size=(16 * cap, 2), dtype=np.uint32)
+    slots = np.asarray(
+        fpset.slot_hash((jnp.asarray(pool[:, 0]), jnp.asarray(pool[:, 1])))
+    ) & np.uint32(cap - 1)
+    _, one_a_slot = np.unique(slots, return_index=True)
+    assert len(one_a_slot) >= nq + 20
+    picked = rng.permutation(one_a_slot)[: nq + 20]
+    keys, held = pool[picked[:nq]].copy(), picked[nq:]
+    table = np.full((cap + 1, 2), 0xFFFFFFFF, np.uint32)
+    table[slots[held]] = pool[held]
+    lanes = rng.permutation(nq)
+    winners = np.sort(lanes[:n_win])
+    valid = np.zeros((nq,), bool)
+    valid[winners] = True
+    others = lanes[n_win:]
+    keys[others[:20]] = pool[held][: len(others[:20])]
+    valid[others[:20]] = True
+    for lane in others[20:70]:
+        below = winners[winners < lane]
+        if len(below):
+            keys[lane] = keys[rng.choice(below)]
+            valid[lane] = True
+    tcols = tuple(jnp.asarray(table[:, i]) for i in range(2))
+    return tcols, keys, valid
+
+
+_W1024 = fpset.write_chunk(1024)
+
+
+@pytest.mark.parametrize("layout", ["sentinel", "occ"])
+@pytest.mark.parametrize(
+    "n_win", [0, 1, _W1024 - 1, _W1024, _W1024 + 1, 1024]
+)
+def test_a_narrow_round_writes_the_same_table_by_its_winners(
+    monkeypatch, n_win, layout
+):
+    """One round of ``probe_insert`` (and the loop to its end) by the
+    winners' write against the full-width write, jitted as a round runs
+    them: ``is_new``, the table under ``cap``, ``occ``, ``pending`` and
+    ``rounds`` bit for bit, and the lanes the table was not handed:
+    all but ``chunk`` a trip."""
+    nq, cap = 1024, 1 << 12
+    assert _W1024 == 128
+    tcols, keys, valid = _round_of_known_winners(nq, cap, n_win, seed=n_win)
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(2))
+    occ = None
+    if layout == "occ":
+        occ = jnp.concatenate([
+            fpset.occupied_mask(tcols).astype(jnp.int32),
+            jnp.zeros((1,), jnp.int32),
+        ])
+    got = {}
+    for winners in (False, True):
+        _write_held_to(monkeypatch, winners)
+        for max_probes in (1, fpset.MAX_PROBES):
+            got[winners, max_probes] = jax.jit(
+                lambda t, k, v, o: fpset.probe_insert(
+                    t, k, v, occ=o, max_probes=max_probes
+                )
+            )(tcols, kcols, jnp.asarray(valid), occ)
+    for max_probes in (1, fpset.MAX_PROBES):
+        full = got[False, max_probes]
+        mine = got[True, max_probes]
+        for a, b in zip(jax.tree.leaves(full[:5]), jax.tree.leaves(mine[:5])):
+            a, b = np.asarray(a), np.asarray(b)
+            if a.shape == (cap + 1,):  # the trash row is write-only
+                a, b = a[:cap], b[:cap]
+            assert np.array_equal(a, b)
+        assert int(full[5]) == 0  # the plain 0, through the jit
+    is_new, cols, _occ, pending, rounds, saved = got[True, 1]
+    assert int(rounds) == 1 and int(np.asarray(is_new).sum()) == n_win
+    assert not np.asarray(pending).any()
+    assert int(np.asarray(fpset.occupied_mask(cols)).sum()) == n_win + 20
+    assert saved.dtype == jnp.uint32
+    assert int(saved) == nq - -(-n_win // _W1024) * _W1024
+
+
+def test_write_winners_clamps_a_last_chunk_that_passes_the_buffer():
+    """A buffer that the chunk does not divide: the last trip's slice
+    is clamped back over winners already written, the same words to
+    the same slots, and every winner lands."""
+    nq, cap, chunk = 300, 1 << 10, 128
+    rng = np.random.default_rng(42)
+    s = rng.permutation(cap)[:nq].astype(np.int32)
+    keys = rng.integers(0, 2**32 - 2, size=(nq, 2), dtype=np.uint32)
+    for n_win in (0, 129, 257, 300):
+        win = np.zeros((nq,), bool)
+        win[rng.choice(nq, n_win, replace=False)] = True
+        tc, oc, lanes = jax.jit(fpset.write_winners, static_argnums=5)(
+            fpset.empty_cols(cap, 2), jnp.zeros((cap + 1,), jnp.int32),
+            jnp.asarray(win), jnp.asarray(s),
+            tuple(jnp.asarray(keys[:, i]) for i in range(2)), chunk,
+        )
+        want = np.full((cap, 2), 0xFFFFFFFF, np.uint32)
+        want[s[win]] = keys[win]
+        assert np.array_equal(
+            np.stack([np.asarray(c)[:cap] for c in tc], 1), want
+        )
+        assert np.array_equal(
+            np.flatnonzero(np.asarray(oc)[:cap]), np.sort(s[win])
+        )
+        assert int(lanes) == -(-n_win // chunk) * chunk
+
+
+@pytest.mark.parametrize("among_lanes", [False, True])
+def test_a_resumed_narrow_stage_writes_the_same_by_its_winners(
+    monkeypatch, among_lanes
+):
+    """A compacted buffer resumes at round 2 with sparse original ids
+    in DESCENDING position order: the winners are packed in POSITION
+    order whatever their ids, and every output is the full-width
+    write's, by either arbitration."""
+    nq, cap = 1024, 1 << 12
+    rng = np.random.default_rng(6)
+    pool = rng.integers(0, 2**31, size=(200, 2), dtype=np.uint32)
+    keys = pool[rng.integers(0, len(pool), size=nq)]
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(2))
+    ids = np.sort(rng.choice(1 << 20, nq, replace=False))[::-1].astype(
+        np.int32
+    )
+    valid = jnp.asarray(rng.random(nq) < 0.9)
+    _held_to(monkeypatch, among_lanes)
+    out = {}
+    for winners in (False, True):
+        _write_held_to(monkeypatch, winners)
+        out[winners] = jax.jit(
+            lambda t, k, v, i: fpset.probe_insert(
+                t, k, v, start_round=2, lane_ids=i
+            )
+        )(fpset.empty_cols(cap, 2), kcols, valid, jnp.asarray(ids))
+    for a, b in zip(
+        jax.tree.leaves(out[False][:5]), jax.tree.leaves(out[True][:5])
+    ):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.shape == (cap + 1,):
+            a, b = a[:cap], b[:cap]
+        assert np.array_equal(a, b)
+    first = {}
+    for i in np.flatnonzero(np.asarray(valid)):
+        k = tuple(keys[i])
+        if k not in first or ids[i] < ids[first[k]]:
+            first[k] = i
+    assert sorted(np.flatnonzero(np.asarray(out[True][0]))) == sorted(
+        first.values()
+    )
+    lanes = nq * (int(out[True][4]) - 2)
+    assert 0 < lanes - int(out[True][5]) < lanes
+
+
+@pytest.mark.parametrize("nq", [4096, 20000])
+def test_lookup_or_insert_is_the_same_by_either_write(monkeypatch, nq):
+    """The whole ladder by both writes and against a Python set: every
+    output bit for bit but the trash row and the lanes kept from the
+    table, which the full-width write reports as the plain 0."""
+    ncols, cap = 2, 1 << 16
+    held, tcols, keys, valid = _colliding_batch(nq, ncols, cap, seed=nq + 1)
+    kcols = tuple(jnp.asarray(keys[:, i]) for i in range(ncols))
+    got = {}
+    for winners in (False, True):
+        _write_held_to(monkeypatch, winners)
+        # a function of its own a side: ``jax.jit`` of one function
+        # twice would hand the second side the first side's trace
+        got[winners] = jax.jit(
+            lambda t, k, v: fpset.lookup_or_insert(t, k, v)
+        )(tcols, kcols, jnp.asarray(valid))
+    for a, b in zip(
+        jax.tree.leaves(got[False][:6]), jax.tree.leaves(got[True][:6])
+    ):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.shape == (cap + 1,):
+            a, b = a[:cap], b[:cap]
+        assert np.array_equal(a, b)
+    is_new, _cols, n_failed, _r, lane_rounds, _steps, saved = got[True]
+    assert int(n_failed) == 0
+    assert np.array_equal(
+        np.asarray(is_new), _first_lanes_of_new_keys(held, keys, valid)
+    )
+    assert int(got[False][6]) == 0
+    # the table was handed its winners in chunks: fewer lanes than were
+    # presented, no fewer than won
+    handed = int(lane_rounds) - int(saved)
+    assert int(np.asarray(is_new).sum()) <= handed < int(lane_rounds)
+
+
+def test_rehash_cols_is_the_same_by_either_write(monkeypatch):
+    """A rehash (chunks packed, then the two-step ladder) by both
+    writes: the same keys in the same slots, the same counters."""
+    keys, old = _table_at_load(1 << 13, 0.4, 2, seed=42)
+    got = {}
+    for winners in (False, True):
+        _write_held_to(monkeypatch, winners)
+        # a jit of its own: the module's would hand the second side the
+        # first side's trace
+        got[winners] = jax.jit(
+            lambda old, new: fpset._rehash_cols.__wrapped__(
+                old, new, chunk=1 << 12, max_probes=fpset.MAX_PROBES,
+                materialize="roll",
+            )
+        )(old, fpset.empty_cols(1 << 14, 2))
+    (full, rhm_full), (mine, rhm_mine) = got[False], got[True]
+    for a, b in zip(full, mine):
+        assert np.array_equal(np.asarray(a)[:-1], np.asarray(b)[:-1])
+    assert np.array_equal(np.asarray(rhm_full), np.asarray(rhm_mine))
+    assert fpset.rhm_logical(rhm_mine)[:2] == (0, len(keys))
+    _assert_holds_exactly(mine, keys)
+
+
+def _scatters_into(nq, cap):
+    """The update widths of every scatter into a table column (a
+    ``u32[cap + 1]`` operand) in the jaxpr of a ``probe_insert`` of ``nq`` lanes, and the names
+    of all its primitives."""
+    u32 = lambda n: jax.ShapeDtypeStruct((n,), jnp.uint32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(fpset.probe_insert)(
+        (u32(cap + 1), u32(cap + 1)), (u32(nq), u32(nq)),
+        jax.ShapeDtypeStruct((nq,), jnp.bool_),
+    )
+    widths, names = [], []
+
+    def walk(j):
+        for eqn in j.eqns:
+            names.append(eqn.primitive.name)
+            if eqn.primitive.name.startswith("scatter") and (
+                eqn.invars[0].aval.shape == (cap + 1,)
+                and eqn.invars[0].aval.dtype == jnp.uint32
+            ):
+                widths.append(eqn.invars[2].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return widths, names
+
+
+def test_a_narrow_round_hands_the_table_no_scatter_as_wide_as_itself():
+    """The jaxpr of a narrow ``probe_insert`` (the CLI's 1,024- and
+    16,384-lane steps on the 9m binding's tables): every scatter into
+    a table column is a chunk wide, inside a loop of its own; a wide
+    round (the CLI's flush, the flagship's) and any round on a small
+    table scatter their full width in the one loop, as they did."""
+    for nq, cap in ((1024, 1 << 24), (16384, 1 << 25), (2560, 1 << 23)):
+        widths, names = _scatters_into(nq, cap)
+        assert widths == [(fpset.write_chunk(nq),)] * 2
+        assert names.count("while") == 2 and "cumsum" in names
+    for nq, cap in (
+        (65536, 1 << 25), (1024, 1 << 21), (163840, 1 << 24),
+        (16384, 1 << 24), (4096, 1 << 22),
+    ):
+        widths, names = _scatters_into(nq, cap)
+        assert widths == [(nq,)] * 2
+        assert names.count("while") == 1 and "cumsum" not in names
+        assert "dynamic_slice" not in names
+
+
+def test_the_writes_rule_reads_the_two_static_shapes_and_nothing_else():
+    """``writes_winners(nq, cap)``: two parameters; narrower or on a
+    larger table never turns it off; false under 2^22 slots, over
+    16,384 lanes, and from a lane to 1,024 slots on, where the chip's
+    full-width scatter is cheap by the lane (the measured crossovers);
+    the chunk is one width for every round."""
+    import inspect
+
+    rule = fpset.writes_winners
+    assert list(inspect.signature(rule).parameters) == ["nq", "cap"]
+    assert (fpset.NARROW_MAX_LANES, fpset.NARROW_MIN_SLOTS) == (
+        1 << 14, 1 << 22
+    )
+    widths = (64, 256, 1024, 1536, 2048, 2560, 4096, 8192, 16384, 32768,
+              65536, 163840)
+    widest = {22: 2560, 23: 4096, 24: 8192, 25: 16384, 26: 16384, 27: 16384}
+    for log_cap in range(6, 28):
+        engaged = [nq for nq in widths if rule(nq, 1 << log_cap)]
+        assert engaged == [
+            nq for nq in widths if nq <= widest.get(log_cap, 0)
+        ]
+        assert all(rule(nq, 2 << log_cap) for nq in engaged)
+    # the CLI's ladder on the 9m binding, the rehash's and the sharded
+    # flush's narrow steps; not the wide flushes
+    assert rule(1024, 1 << 22) and rule(1024, 1 << 25)
+    assert rule(2560, 1 << 22) and rule(1536, 1 << 23)
+    assert rule(8192, 1 << 24) and not rule(16384, 1 << 24)
+    assert not rule(4096, 1 << 22) and not rule(1024, 1 << 21)
+    assert not rule(26738688, 1 << 27) and not rule(98304, 1 << 23)
+    assert not rule(40960, 1 << 25) and not rule(65536, 1 << 27)
+    assert [fpset.write_chunk(n) for n in (64, 1024, 2560, 16384)] == [
+        64, 128, 128, 128
+    ]
+
+
+# a schedule that no other test runs, so that the programs of the runs
+# below are traced under the rule they hold
+_OWN_STAGES = ((4, 16), (8, 24), (16, 42))
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_write_lanes_are_the_lanes_presented_where_no_round_is_narrow(
+    fuse,
+):
+    """Tier-1's tables are under 2^22 slots: every lane presented is
+    handed to the scatters, the device's word stays 0 and the two
+    ratios are one."""
+    ck = DeviceChecker(
+        CompactionModel(SMALL_CONFIGS["producer_on"]), sub_batch=256,
+        fuse=fuse, visited_cap=1 << 8, frontier_cap=1 << 12,
+    )
+    r = ck.run()
+    st = ck.last_stats
+    assert r.distinct_states == 1654
+    assert int(ck._last_fpm[fpset.FPM_WRITE_SAVED]) == 0
+    assert st["fpset_write_lanes"] == st["fpset_lane_rounds"] > 0
+    assert (
+        st["fpset_write_lanes_per_valid"]
+        == st["fpset_lanes_presented_per_valid"]
+    )
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_write_lanes_fall_where_narrow_rounds_write_their_winners(
+    monkeypatch, fuse
+):
+    """With the rule held on, the same run hands the scatters fewer
+    lanes than it presents, and no fewer than won: the counter is the
+    lanes presented less the device's word, over the valid lanes."""
+    _write_held_to(monkeypatch, True)
+    ck = DeviceChecker(
+        CompactionModel(SMALL_CONFIGS["producer_on"]), sub_batch=256,
+        fuse=fuse, visited_cap=1 << 8, frontier_cap=1 << 12,
+        fpset_stages=_OWN_STAGES,
+    )
+    r = ck.run()
+    st = ck.last_stats
+    assert r.distinct_states == 1654 and st["fpset_failures"] == 0
+    saved = int(ck._last_fpm[fpset.FPM_WRITE_SAVED])
+    assert 0 < saved < st["fpset_lane_rounds"]
+    assert st["fpset_write_lanes"] == st["fpset_lane_rounds"] - saved
+    assert r.distinct_states <= st["fpset_write_lanes"]
+    assert st["fpset_write_lanes_per_valid"] == round(
+        st["fpset_write_lanes"] / st["fpset_valid_lanes"], 4
+    ) < st["fpset_lanes_presented_per_valid"]
+    # the rounds by step and the lanes presented are the schedule's,
+    # whatever the write
+    assert sum(st["fpset_step_rounds"]) == st["fpset_probe_rounds"]
+
+
+@pytest.mark.parametrize("winners", [False, True])
+def test_write_lanes_restart_at_a_resume(monkeypatch, tmp_path, winners):
+    """A resumed run counts the lanes of its own flushes: the frame's
+    lanes presented and the frame's device word are where the fold
+    starts, as the slot rounds and the lane arbitration's do."""
+    _write_held_to(monkeypatch, winners)
+    frame = str(tmp_path / "run.npz")
+    kw = dict(
+        sub_batch=256, visited_cap=1 << 8, frontier_cap=1 << 12,
+        fpset_stages=_OWN_STAGES if winners else None,
+        checkpoint_path=frame,
+    )
+    m = CompactionModel(SMALL_CONFIGS["producer_on"])
+    first = DeviceChecker(m, checkpoint_every=3, **kw)
+    assert first.run().distinct_states == 1654
+    whole = dict(first.last_stats)
+    with np.load(frame) as d:
+        at = np.asarray(d["fpm"], np.int64)
+    lanes_at = int(fpset.fpm_logical(at)[5])
+    valid_at = int(fpset.fpm_logical(at)[3])
+    assert 0 < lanes_at < whole["fpset_lane_rounds"]
+    ck = DeviceChecker(m, **kw)
+    assert ck.run(resume=True).distinct_states == 1654
+    st = ck.last_stats
+    assert st["fpset_lane_rounds"] == whole["fpset_lane_rounds"]
+    saved = int(ck._last_fpm[fpset.FPM_WRITE_SAVED]) - int(
+        at[fpset.FPM_WRITE_SAVED]
+    )
+    assert (saved > 0) == winners
+    assert st["fpset_write_lanes"] == (
+        whole["fpset_lane_rounds"] - lanes_at - saved
+    ) < whole["fpset_write_lanes"]
+    assert st["fpset_write_lanes_per_valid"] == round(
+        st["fpset_write_lanes"] / (st["fpset_valid_lanes"] - valid_at), 4
+    )
+
+
+def test_fpm_update_keeps_the_word_of_a_full_width_flush_as_it_was():
+    """``write_saved`` rides the word behind the step rounds: a uint32
+    that wraps, added as its bit pattern; the plain 0 of a flush that
+    writes every lane adds nothing, and a vector with no step words
+    (the sharded engine's) has no such word."""
+    args = (jnp.int32(3), jnp.int32(0), jnp.int32(7), jnp.uint32(9))
+    steps = (jnp.int32(1), 0, jnp.int32(2))
+    wide = jnp.zeros((fpset.FPM_WIDE_N,), jnp.int32)
+    assert fpset.FPM_WIDE_N == 16 and fpset.FPM_WRITE_SAVED == 15
+    one = fpset.fpm_update(wide, *args, steps, jnp.uint32(5))
+    two = fpset.fpm_update(one, *args, steps, jnp.uint32(2**32 - 2))
+    assert int(one[15]) == 5 and (int(two[15]) & 0xFFFFFFFF) == 3
+    assert int(fpset.fpm_update(two, *args, steps)[15]) == int(two[15])
+    assert fpset.fpm_step_rounds(np.asarray(two), ((4, 16),) * 2) == [
+        2, 0, 4
+    ]
+    narrow = fpset.fpm_update(
+        jnp.zeros((fpset.FPM_N,), jnp.int32), *args, steps, jnp.uint32(5)
+    )
+    assert narrow.shape == (fpset.FPM_N,)
+    # the equations of a flush that writes every lane: none for the word
+    # but the one add the vector's last word always had
+    with_word = jax.make_jaxpr(
+        lambda f: fpset.fpm_update(f, *args, steps, 0)
+    )(wide)
+    before = jax.make_jaxpr(lambda f: fpset.fpm_update(f, *args, steps))(
+        wide
+    )
+    assert str(with_word) == str(before)
 
 
 # ---- _load_seed frontier-window guard (ADVICE r5 medium) -------------

@@ -255,7 +255,7 @@ def test_sieve_tag_evict_unflag_roundtrip():
         jnp.asarray(np.arange(10, dtype=np.uint32) + 1),
         jnp.asarray(np.arange(10, dtype=np.uint32) * 3 + 1),
     )
-    is_new, tc, nf, _, _, _ = fps.lookup_or_insert(
+    is_new, tc, nf, _, _, _, _ = fps.lookup_or_insert(
         tc, keys, jnp.ones((10,), bool)
     )
     assert int(nf) == 0 and bool(np.asarray(is_new).all())
@@ -267,7 +267,7 @@ def test_sieve_tag_evict_unflag_roundtrip():
         jnp.asarray(np.arange(5, dtype=np.uint32) + 100),
         jnp.asarray(np.arange(5, dtype=np.uint32) + 200),
     )
-    _, tc, nf2, _, _, _ = fps.lookup_or_insert(
+    _, tc, nf2, _, _, _, _ = fps.lookup_or_insert(
         tc, keys2, jnp.ones((5,), bool)
     )
     assert int(nf2) == 0

@@ -116,8 +116,9 @@ def test_zero_sync_counters_ride_the_stats_fetch(std_run):
     _stream, _frame, ck, r, events = std_run
     # r12: valid_lanes split into hi/lo uint32 words (int32-wrap fix);
     # PR 28: lane_rounds appended the same way; PR 37: this engine's
-    # vector carries the rounds of each ladder step behind those
-    assert fpset.FPM_N == 8 and FPM_N == 8 + fpset.FPM_STEPS
+    # vector carries the rounds of each ladder step behind those, and
+    # PR 42 the lanes kept from the column scatters in the word after
+    assert fpset.FPM_N == 8 and FPM_N == 8 + fpset.FPM_STEPS + 1 == 16
     stats = [e for e in events if e["event"] == "result"][-1]["stats"]
     flushes = [e for e in events if e["event"] == "flush"]
     assert stats["fpset_flushes"] == sum(e["flushes"] for e in flushes)
