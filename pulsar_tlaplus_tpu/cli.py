@@ -295,6 +295,18 @@ def tiered_line(st: dict, distinct_states: int) -> str:
     )
 
 
+def recovered_line(st: dict) -> str:
+    """What a check under ``-recover`` resumed from, from the engine's
+    ``last_stats``: one line on stdout after the verdict, so that an
+    untraced run can be held to its frame (docs/robustness.md has the
+    grammar)."""
+    return (
+        f"Recovered from the checkpoint frame of level "
+        f"{st['resume_level']} ({st['resume_states']} states): "
+        f"{st['resume_levels_run']} levels expanded after it."
+    )
+
+
 def _print_graph_summary(graph) -> None:
     """The behaviour graph a liveness verdict was computed on, so that
     an untraced run can be held to a reference: one line for the whole
@@ -696,6 +708,8 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         )
         if "spill_tier_ceilings" in getattr(ck, "last_stats", {}):
             print(tiered_line(ck.last_stats, r.distinct_states))
+        if "resume_levels_run" in getattr(ck, "last_stats", {}):
+            print(recovered_line(ck.last_stats))
     # cfg PROPERTIES are honored automatically after a clean safety pass
     # (TLC checks temporal properties from the same run); the sharded
     # drivers do not keep the state log the liveness engine needs

@@ -336,6 +336,26 @@ def ptt_grow(buf, *, pad):
     return jnp.concatenate([buf, jnp.zeros((pad,), buf.dtype)])
 
 
+@unit("ckpt_fetch", static=("size",))
+def ptt_ckpt_fetch(buf, start, *, size):
+    """``buf[start: start + size]``: what a checkpoint frame needs of a
+    row store or a trace log that is under half full, sliced on the
+    device to a bucketed ``size`` (``start`` is traced), so that a
+    process meets a handful of these and not one a frame."""
+    return lax.dynamic_slice(buf, (start,), (size,))
+
+
+@unit("restore", static=("length",))
+def ptt_restore_pad(data, *, length):
+    """A restored row store or trace log: the frame's ``data`` (uploaded
+    at a bucketed length) with zeros after it up to the buffer's
+    ``length`` — one program a ``(bucket, length)`` where an eager fill
+    and an eager concatenate ran at the frame's own length."""
+    return jnp.concatenate(
+        [data, jnp.zeros((length - data.shape[0],), data.dtype)]
+    )
+
+
 @unit(static=("materialize",), donate=(0,))
 def ptt_compact(arows, flag_acc, *, materialize):
     """The compaction dispatch: ``(crows, idx)``."""

@@ -589,9 +589,7 @@ class DeviceChecker:
         # the artifact's original level count so trace walks reach the
         # roots (warm/plan.build_reseed_seed)
         self.extra_trace_depth = 0
-        self._ckpt_frames = 0
-        self._ckpt_bytes = 0
-        self._ckpt_retries = 0
+        self._reset_ckpt_state()
         self._watcher = None
         self._flush_seq = 0
         self._jits: Dict[tuple, object] = {}
@@ -617,7 +615,6 @@ class DeviceChecker:
         self._run_id: Optional[str] = None
         self._snap: Dict[str, object] = {}
         self._fetch_n = 0
-        self._ckpt_write_s = 0.0
         self._fpm_prev = np.zeros((fpset.FPM_LOGICAL_N,), np.int64)
         self._compact_prev = 0
         self._compact_prev_s = 0.0
@@ -2044,6 +2041,36 @@ class DeviceChecker:
                     self.tel.close()
                 self.tel = obs.NULL
 
+    def _reset_ckpt_state(self) -> None:
+        """A run's frame and restore counters at zero (a pooled
+        checker's next run must not inherit the last run's)."""
+        self._ckpt_frames = 0
+        self._ckpt_bytes = 0
+        self._ckpt_retries = 0
+        # the frames' stall on the run loop's thread, whole, and its
+        # three parts: the D2H gather, the host's pack of the table's
+        # occupied slots, the compressed write
+        self._ckpt_write_s = 0.0
+        self._ckpt_gather_s = 0.0
+        self._ckpt_pack_s = 0.0
+        self._ckpt_npz_s = 0.0
+        # what the frames hold before compression, what crossed the
+        # link for them (whole table columns, bucketed slices), the
+        # states in them summed, and the last frame's level
+        self._ckpt_raw_bytes = 0
+        self._ckpt_d2h_bytes = 0
+        self._ckpt_states = 0
+        self._ckpt_last_level = 0
+        # a restore's wall (THIS run's, on resume) and its three parts:
+        # the frame's load and decompression, the host's rebuild of the
+        # table's columns and the buffers' padding, the upload
+        self._restore_s = 0.0
+        self._restore_load_s = 0.0
+        self._restore_unpack_s = 0.0
+        self._restore_upload_s = 0.0
+        self._restore_h2d_bytes = 0
+        self._resume_level = self._resume_states = None
+
     def _begin_run(self, t0, resume: bool):
         """Per-run state, the telemetry stream and the crash
         breadcrumbs of one :meth:`run`; returns the heartbeat (or
@@ -2055,10 +2082,7 @@ class DeviceChecker:
         # per-run recovery/telemetry state: a fresh run() must not
         # inherit a previous run's degraded capacity or frame counts
         self.rec.reset()
-        self._ckpt_frames = 0
-        self._ckpt_bytes = 0
-        self._ckpt_write_s = 0.0
-        self._ckpt_retries = 0
+        self._reset_ckpt_state()
         self._fetch_n = 0
         self._fpm_prev = np.zeros((fpset.FPM_LOGICAL_N,), np.int64)
         # fpset_slot_rounds (PR 38): the table's slots summed over
@@ -2086,7 +2110,10 @@ class DeviceChecker:
         # in an error must not show the last run's)
         for k in [
             k for k in self.last_stats
-            if k.startswith(("work_", "host_", "jit_", "level_wall_max_"))
+            if k.startswith((
+                "work_", "host_", "jit_", "level_wall_max_", "restore_",
+                "resume_",
+            ))
         ]:
             del self.last_stats[k]
         self._wkm_prev = np.zeros((fpset.WKM_LOGICAL_N,), np.int64)
@@ -2132,7 +2159,6 @@ class DeviceChecker:
         # fuse_levels counts THIS run's megakernel-closed levels
         self._disp_prev = self._dispatch_total()
         self.last_stats.pop("fuse_levels", None)
-        self._restore_s = 0.0  # frame-restore wall of THIS run (resume)
         self._xprof_on = False
         self._xprof_done = False
         # a crash mid-frame-write can leave a dead multi-GB tmp behind
@@ -2294,7 +2320,19 @@ class DeviceChecker:
             # rebuild) — the serve bench's counterpart to the frame
             # write stall; the scheduler reads it per resumed slice
             self._restore_s = time.perf_counter() - t_restore
-            self.last_stats["restore_s"] = round(self._restore_s, 3)
+            # what the run resumed from: the frame's level and states
+            # (cli.recovered_line prints them), and the restore's wall
+            # by part
+            self._resume_level = len(level_sizes)
+            self.last_stats.update(
+                restore_s=round(self._restore_s, 3),
+                restore_load_s=self._restore_load_s,
+                restore_unpack_s=self._restore_unpack_s,
+                restore_upload_s=self._restore_upload_s,
+                restore_h2d_bytes=self._restore_h2d_bytes,
+                resume_level=self._resume_level,
+                resume_states=self._resume_states,
+            )
             t0 = time.perf_counter() - saved_wall
             self.rec.arm()  # the on-disk frame is valid
             self._emit_header(resume=True)
@@ -2689,24 +2727,31 @@ class DeviceChecker:
         return min(max(SPILL_FETCH_MIN, 1 << max(n - 1, 0).bit_length()),
                    length)
 
+    def _bucketed_fetch(self, buf, n: int, off: int, program):
+        """``(what crossed the link, its view of buf[off: off + n])``:
+        the buffer whole where the bucket
+        (:meth:`_spill_fetch_size`) is its length, else the bucket,
+        sliced on the device by ``program(buf, start, size=)``."""
+        length = buf.shape[0]
+        size = self._spill_fetch_size(n, length)
+        start = min(off, length - size)
+        got = np.asarray(
+            buf if size == length
+            else program(buf, jnp.int32(start), size=size)
+        )
+        return got, got[off - start: off - start + n]
+
     def _spill_fetch(self, buf, n: int, off: int = 0) -> np.ndarray:
         """``buf[off: off + n]`` on the host.  The device slices a
         bucketed length (:meth:`_spill_fetch_size`) and the host trims
         it, so a check compiles a handful of fetch programs and not one
         a flush; ``spill_d2h_bytes`` counts what was needed,
         ``spill_d2h_padded_bytes`` what crossed the link."""
-        length = buf.shape[0]
-        size = self._spill_fetch_size(n, length)
-        start = min(off, length - size)
         t0 = time.perf_counter()
         with spans.span("spill.fetch"):
-            got = np.asarray(
-                buf if size == length
-                else ptt_spill_fetch(buf, jnp.int32(start), size=size)
-            )
+            got, out = self._bucketed_fetch(buf, n, off, ptt_spill_fetch)
         dt = time.perf_counter() - t0
-        out = got[off - start: off - start + n]
-        if size != n:
+        if len(got) != n:
             out = out.copy()  # what is kept does not hold the padding
         self._spill_fetch_s += dt
         self.tstore.note_transfer(dt)
@@ -3807,76 +3852,88 @@ class DeviceChecker:
             else 0 if self.rows_window == "all"
             else level_base
         )
-        arrays = {
-            "n_visited": np.int64(nv),
-            "level_sizes": np.asarray(level_sizes, np.int64),
-            "lb": np.int64(level_base),
-            "nf": np.int64(nf),
-            "rows_lo": np.int64(lo),
-            "hbm_recovered": np.int64(self._hbm_recovered),
-            "fpm": np.asarray(st["fpm"]),
-            # logs are windowed ONLY in tiered mode (frontier mode
-            # windows the rows but keeps full logs)
-            "parent": np.asarray(
-                bufs["parent"][: nv - (lo if self.tiered else 0)]
-            ),
-            "lane": np.asarray(
-                bufs["lane"][: nv - (lo if self.tiered else 0)]
-            ),
-            "rows": np.asarray(
-                bufs["rows"][
-                    (lo - rb["row_base"]) * W:
-                    (nv - rb["row_base"]) * W
-                ]
-            ),
-        }
-        # compacted occupancy (keys + slot index): frame size
-        # scales with the state count, not the table tier
-        arrays.update(
-            ckpt.pack_fpset(tuple(np.asarray(c) for c in bufs["vk"]))
-        )
-        if self.tiered:
-            # the spill manifest: every cold run/segment with file
-            # names + content digests, so resume restores the WHOLE
-            # tiered store (manifest() joins the async writes first —
-            # a frame never references a half-written spill file)
-            import json as _json
+        # logs are windowed ONLY in tiered mode (frontier mode windows
+        # the rows but keeps full logs)
+        n_log = nv - (lo if self.tiered else 0)
+        with spans.span("ckpt.gather"):
+            arrays = {
+                "n_visited": np.int64(nv),
+                "level_sizes": np.asarray(level_sizes, np.int64),
+                "lb": np.int64(level_base),
+                "nf": np.int64(nf),
+                "rows_lo": np.int64(lo),
+                "hbm_recovered": np.int64(self._hbm_recovered),
+                "fpm": np.asarray(st["fpm"]),
+                "parent": self._ckpt_fetch(bufs["parent"], n_log),
+                "lane": self._ckpt_fetch(bufs["lane"], n_log),
+                "rows": self._ckpt_fetch(
+                    bufs["rows"], (nv - lo) * W,
+                    (lo - rb["row_base"]) * W,
+                ),
+            }
+            # both table columns whole: the occupied slots are picked
+            # out on the host (below)
+            cols = tuple(np.asarray(c) for c in bufs["vk"])
+            self._ckpt_d2h_bytes += sum(c.nbytes for c in cols)
+        t_gather = time.perf_counter()
+        with spans.span("ckpt.pack"):
+            # compacted occupancy (keys + slot index): frame size
+            # scales with the state count, not the table tier
+            arrays.update(ckpt.pack_fpset(cols))
+            del cols
+            if self.tiered:
+                # the spill manifest: every cold run/segment with file
+                # names + content digests, so resume restores the WHOLE
+                # tiered store (manifest() joins the async writes first
+                # — a frame never references a half-written spill file)
+                import json as _json
 
-            try:
-                man = self.tstore.manifest()
-            except ValueError:
-                # the join just latched ENOSPC degradation: the spill
-                # dir is incomplete, keep the previous valid frame
-                return False
-            arrays["spill_manifest"] = np.frombuffer(
-                _json.dumps(man).encode(),
-                dtype=np.uint8,
+                try:
+                    man = self.tstore.manifest()
+                except ValueError:
+                    # the join just latched ENOSPC degradation: the
+                    # spill dir is incomplete, keep the previous valid
+                    # frame
+                    return False
+                arrays["spill_manifest"] = np.frombuffer(
+                    _json.dumps(man).encode(),
+                    dtype=np.uint8,
+                )
+                arrays["spill_hot_n"] = np.int64(self._hot_n)
+                arrays["spill_epoch"] = np.int64(self._epoch)
+        t_pack = time.perf_counter()
+        with spans.span("ckpt.write"):
+            nbytes, write_s, retries = ckpt.save_frame(
+                self.checkpoint_path, self._config_sig(), arrays,
+                wall_s=time.perf_counter() - t0,
+                meta={
+                    "run_id": self._run_id,
+                    "frame_seq": self._ckpt_frames + 1,
+                    "level": len(level_sizes),
+                    "engine": "device_bfs",
+                },
             )
-            arrays["spill_hot_n"] = np.int64(self._hot_n)
-            arrays["spill_epoch"] = np.int64(self._epoch)
-        nbytes, write_s, retries = ckpt.save_frame(
-            self.checkpoint_path, self._config_sig(), arrays,
-            wall_s=time.perf_counter() - t0,
-            meta={
-                "run_id": self._run_id,
-                "frame_seq": self._ckpt_frames + 1,
-                "level": len(level_sizes),
-                "engine": "device_bfs",
-            },
-        )
-        # the frame-write STALL is everything the run loop was blocked
-        # on here: the D2H gathers above plus the compressed write
-        stall_s = time.perf_counter() - t_stall
+        # the frame's STALL is everything the run loop was blocked on
+        # here, in three parts that add up to it: the D2H gather, the
+        # host's pack of the table's occupied slots, the compressed
+        # write (ckpt_write_s keeps its name and is the whole stall)
+        t_end = time.perf_counter()
+        stall_s = t_end - t_stall
         self._ckpt_frames += 1
         self._ckpt_bytes += nbytes
         self._ckpt_write_s += stall_s
+        self._ckpt_gather_s += t_gather - t_stall
+        self._ckpt_pack_s += t_pack - t_gather
+        self._ckpt_npz_s += t_end - t_pack
+        self._ckpt_raw_bytes += sum(
+            np.asarray(a).nbytes for a in arrays.values()
+        )
+        self._ckpt_states += nv
+        self._ckpt_last_level = len(level_sizes)
         self._ckpt_retries += retries
         self.rec.arm()
         self.last_stats.update(
-            ckpt_frames=self._ckpt_frames,
-            ckpt_bytes=self._ckpt_bytes,
-            ckpt_write_s=round(self._ckpt_write_s, 3),
-            ckpt_retries=self._ckpt_retries,
+            self._ckpt_stats(),
             # the LAST frame's costs stand alone: when a slice suspends,
             # this frame IS the suspend frame — the scheduler attaches
             # these to the job_suspend event (context-switch write cost)
@@ -3889,6 +3946,8 @@ class DeviceChecker:
             bytes=nbytes,
             write_s=round(write_s, 3),
             stall_s=round(stall_s, 3),
+            gather_s=round(t_gather - t_stall, 3),
+            pack_s=round(t_pack - t_gather, 3),
             retries=retries,
             level=len(level_sizes),
             distinct_states=nv,
@@ -3900,16 +3959,78 @@ class DeviceChecker:
         )
         return True
 
+    def _ckpt_stats(self) -> Dict[str, object]:
+        """This run's frame counters as ``last_stats`` carries them
+        (docs/observability.md): ``ckpt_write_s`` the whole stall,
+        ``ckpt_gather_s`` + ``ckpt_pack_s`` + ``ckpt_npz_s`` its three
+        parts."""
+        return dict(
+            ckpt_frames=self._ckpt_frames,
+            ckpt_bytes=self._ckpt_bytes,
+            ckpt_write_s=round(self._ckpt_write_s, 3),
+            ckpt_gather_s=self._ckpt_gather_s,
+            ckpt_pack_s=self._ckpt_pack_s,
+            ckpt_npz_s=self._ckpt_npz_s,
+            ckpt_raw_bytes=self._ckpt_raw_bytes,
+            ckpt_d2h_bytes=self._ckpt_d2h_bytes,
+            ckpt_states=self._ckpt_states,
+            ckpt_last_level=self._ckpt_last_level,
+            ckpt_retries=self._ckpt_retries,
+        )
+
+    def _ckpt_fetch(self, buf, n: int, off: int = 0) -> np.ndarray:
+        """``buf[off: off + n]`` on the host, for a frame.  A buffer
+        over half full comes over whole; under that the device slices a
+        bucketed length (:meth:`_spill_fetch_size`, one program a
+        ``(buffer, size)``) and the host trims it, where an eager slice
+        at the frame's own length was an executable a frame."""
+        got, out = self._bucketed_fetch(
+            buf, n, off, bodies.ptt_ckpt_fetch
+        )
+        self._ckpt_d2h_bytes += got.nbytes
+        return out
+
+    def _restore_pad(self, data, length: int, dtype):
+        """The frame's ``data`` with zeros after it up to a bucketed
+        size (:meth:`_spill_fetch_size`: a power of two, or the
+        buffer's own ``length``), ready for :meth:`_restore_upload`."""
+        data = np.asarray(data, dtype)
+        host = np.zeros(
+            (self._spill_fetch_size(len(data), length),), dtype
+        )
+        host[: len(data)] = data
+        return host
+
+    def _restore_upload(self, host, length: int):
+        """The device buffer of ``length`` that starts with ``host``:
+        uploaded whole where the bucket is the buffer's length, else
+        padded on the device by one program a ``(bucket, length)``,
+        where an eager fill and concatenate at the frame's own length
+        compiled anew for every frame."""
+        self._restore_h2d_bytes += host.nbytes
+        up = jnp.array(host)  # a copy: the flush donates these buffers
+        if len(host) == length:
+            return up
+        return bodies.ptt_restore_pad(up, length=length)
+
     def _restore_frame(self):
         """Rebuild device buffers + level frame from the checkpoint;
         returns (bufs, st, rb, level_sizes, level_base, nf, wall_s)."""
-        d = ckpt.load_frame(self.checkpoint_path, self._config_sig())
+        t_load = time.perf_counter()
+        with spans.span("restore.load"):
+            d = ckpt.load_frame(self.checkpoint_path, self._config_sig())
+            # an npz member is read and decompressed when it is asked
+            # for: ask for every one here, so that the load is timed
+            # apart from what is done with it
+            d = {k: d[k] for k in d.files}
+        t_unpack = time.perf_counter()
+        self._restore_load_s += t_unpack - t_load
         # writer identity (run_id / frame_seq) for the resume header —
         # the telemetry stream of the resumed run links back to the
         # prior run's last ckpt_frame event
         self._resume_meta = ckpt.frame_meta(d)
-        K, W = self.K, self.W
-        nv = int(d["n_visited"])
+        K = self.K
+        nv = self._resume_states = int(d["n_visited"])
         level_sizes = [int(x) for x in d["level_sizes"]]
         level_base = int(d["lb"])
         nf = int(d["nf"])
@@ -3919,18 +4040,13 @@ class DeviceChecker:
                 f"checkpoint holds {nv} states — beyond max_states "
                 f"({self.SCAP}); raise max_states to resume it"
             )
-        cols = ckpt.unpack_fpset(d, K)
+        with spans.span("restore.unpack"):
+            cols = ckpt.unpack_fpset(d, K)
         # the snapshot fixes the table tier (jit programs are
         # tier-keyed, so no cache invalidation is needed); growth,
-        # if the resumed run needs it, goes through regular rehash.
-        # jnp.array (copy=True), NOT jnp.asarray: on the CPU
-        # backend asarray can alias the numpy buffer zero-copy,
-        # and the flush DONATES these columns — donating memory
-        # numpy still owns is a use-after-free (observed as flaky
-        # probe overflows and GC segfaults in the resume tests)
+        # if the resumed run needs it, goes through regular rehash
         self.TCAP = cols[0].shape[0] - 1
         self.VCAP = self.TCAP // 2
-        vk = tuple(jnp.array(c) for c in cols)
         # size the row/log tiers BEFORE allocating (same doubling-with-
         # cap formulas as _grow_store/_grow_logs, minus the buffers).
         # Tiered frames hold the device WINDOW only, so the need is
@@ -3952,50 +4068,45 @@ class DeviceChecker:
             self.PCAP += min(
                 self.PCAP, max(cap - self.PCAP, need - self.PCAP)
             )
-        rdata = np.asarray(d["rows"], np.uint32)
-        bufs = {
-            "vk": vk,
-            "ak": tuple(
-                jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
-                for _ in range(K)
-            ),
-            "arows": jnp.zeros((self.W, self.ACAP), jnp.uint32),
+        with spans.span("restore.unpack"):
             # saved rows land at their absolute offset in "all" mode
             # (lo == 0) and at window offset 0 with row_base = lo in
             # frontier mode — both are "offset (lo - row_base) = 0"
-            "rows": jnp.concatenate(
-                [
-                    jnp.asarray(rdata),
-                    jnp.zeros(
-                        (self._rows_len() - len(rdata),), jnp.uint32
-                    ),
-                ]
-            ),
-            "parent": jnp.concatenate(
-                [
-                    jnp.asarray(np.asarray(d["parent"], np.int32)),
-                    jnp.zeros(
-                        (
-                            self._logs_len()
-                            - (nv - (lo if self.tiered else 0)),
-                        ),
-                        jnp.int32,
-                    ),
-                ]
-            ),
-            "lane": jnp.concatenate(
-                [
-                    jnp.asarray(np.asarray(d["lane"], np.int32)),
-                    jnp.zeros(
-                        (
-                            self._logs_len()
-                            - (nv - (lo if self.tiered else 0)),
-                        ),
-                        jnp.int32,
-                    ),
-                ]
-            ),
-        }
+            lengths = {
+                "rows": self._rows_len(), "parent": self._logs_len(),
+                "lane": self._logs_len(),
+            }
+            padded = {
+                k: self._restore_pad(
+                    d[k], n, np.uint32 if k == "rows" else np.int32
+                )
+                for k, n in lengths.items()
+            }
+        t_upload = time.perf_counter()
+        self._restore_unpack_s += t_upload - t_unpack
+        with spans.span("restore.upload"):
+            bufs = {
+                # jnp.array (copy=True), NOT jnp.asarray: on the CPU
+                # backend asarray can alias the numpy buffer zero-copy,
+                # and the flush DONATES these columns — donating memory
+                # numpy still owns is a use-after-free (observed as
+                # flaky probe overflows and GC segfaults in the resume
+                # tests)
+                "vk": tuple(jnp.array(c) for c in cols),
+                "ak": tuple(
+                    jnp.full((self.ACAP,), SENTINEL, jnp.uint32)
+                    for _ in range(K)
+                ),
+                "arows": jnp.zeros((self.W, self.ACAP), jnp.uint32),
+                **{
+                    k: self._restore_upload(padded[k], n)
+                    for k, n in lengths.items()
+                },
+            }
+            self._restore_h2d_bytes += sum(c.nbytes for c in cols)
+            del cols, padded
+            jax.block_until_ready(bufs)
+        self._restore_upload_s += time.perf_counter() - t_upload
         if self.tiered:
             # restore the cold tiers through the frame's manifest
             # (digest-verified; a torn spill file fails loudly) and
@@ -4381,12 +4492,14 @@ class DeviceChecker:
             fuse=self.fuse,
             **obs.IMPL_FIELDS,
             hbm_recovered=self._hbm_recovered,
-            ckpt_frames=self._ckpt_frames,
-            ckpt_bytes=self._ckpt_bytes,
-            ckpt_write_s=round(self._ckpt_write_s, 3),
-            ckpt_retries=self._ckpt_retries,
+            **self._ckpt_stats(),
             stats_fetches=self._fetch_n,
         )
+        if self._resume_level is not None:
+            # the levels this resumed run closed on top of its frame's
+            self.last_stats["resume_levels_run"] = (
+                len(level_sizes) - self._resume_level
+            )
         res = CheckerResult(
             distinct_states=nv,
             diameter=len(level_sizes),

@@ -83,12 +83,14 @@ def save_frame(
     meta: Optional[Dict[str, object]] = None,
 ) -> Tuple[int, float, int]:
     """Write one checkpoint frame atomically; returns ``(nbytes,
-    write_s, retries)`` — size, the frame-write stall time the caller
-    was blocked here (the ``ckpt_write_s`` telemetry counter:
-    compression + fsync-adjacent filesystem time, NOT the D2H gather,
-    which engines time on their side), and how many transient-failure
-    retries the write needed (0 on the happy path; the ``ckpt_retries``
-    breadcrumb).  ``sig`` is the writer's config signature (verified by
+    write_s, retries)`` — size, the seconds the caller was blocked
+    HERE (compression + fsync-adjacent filesystem time and a retry's
+    backoff, NOT the D2H gather or the pack, which engines time on
+    their side: a ``ckpt_frame`` event's ``write_s``, and summed the
+    single-chip engine's ``ckpt_npz_s``; the engines' ``ckpt_write_s``
+    counter is the frames' whole stall, gather and pack included), and
+    how many transient-failure retries the write needed (0 on the happy
+    path; the ``ckpt_retries`` breadcrumb).  ``sig`` is the writer's config signature (verified by
     :func:`load_frame`); ``wall_s`` the cumulative run wall time so a
     resumed run's states/sec stays meaningful end to end.  ``meta`` is
     an optional small JSON-able dict (writer run_id, frame_seq, level)

@@ -218,7 +218,8 @@ def test_the_materialization_is_in_the_key(monkeypatch, base_verdict):
 
 UNITS = (
     "ptt_level2", "ptt_expand", "ptt_init", "ptt_fpflush2", "ptt_rehash2",
-    "ptt_compact", "ptt_append", "ptt_grow",
+    "ptt_compact", "ptt_append", "ptt_grow", "ptt_ckpt_fetch",
+    "ptt_restore_pad",
 )
 
 

@@ -10,6 +10,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import pytest
 
 from tests.test_units import CFG_45K, ROOT, SPEC, VERDICT, _cli_check
@@ -18,6 +19,9 @@ CFG_253K = os.path.join(ROOT, "specs", "compaction_253k.cfg")
 
 
 def test_the_45k_binding_after_the_253k_binding(tmp_path):
+    # whatever this worker's earlier tests built (several run the 45k
+    # binding through cli.main in process): start with no program held
+    jax.clear_caches()
     big = _cli_check(tmp_path, 0, "-config", CFG_253K)
     assert VERDICT.search(big[1]).groups() == ("253361", "23")
     small = _cli_check(tmp_path, 1, "-config", CFG_45K)
