@@ -2054,6 +2054,13 @@ class DeviceChecker:
         self._ckpt_gather_s = 0.0
         self._ckpt_pack_s = 0.0
         self._ckpt_npz_s = 0.0
+        # where the write's deflate ran: the largest pool a frame used
+        # (1: the run loop's own thread), the blocks compressed, the
+        # threads' own seconds in zlib (the work; ckpt_npz_s is the
+        # run loop's blocked seconds)
+        self._ckpt_deflate_threads = 0
+        self._ckpt_deflate_blocks = 0
+        self._ckpt_deflate_cpu_s = 0.0
         # what the frames hold before compression, what crossed the
         # link for them (whole table columns, bucketed slices), the
         # states in them summed, and the last frame's level
@@ -3902,6 +3909,7 @@ class DeviceChecker:
                 arrays["spill_hot_n"] = np.int64(self._hot_n)
                 arrays["spill_epoch"] = np.int64(self._epoch)
         t_pack = time.perf_counter()
+        deflate: Dict[str, object] = {}
         with spans.span("ckpt.write"):
             nbytes, write_s, retries = ckpt.save_frame(
                 self.checkpoint_path, self._config_sig(), arrays,
@@ -3912,6 +3920,7 @@ class DeviceChecker:
                     "level": len(level_sizes),
                     "engine": "device_bfs",
                 },
+                stats=deflate,
             )
         # the frame's STALL is everything the run loop was blocked on
         # here, in three parts that add up to it: the D2H gather, the
@@ -3925,6 +3934,11 @@ class DeviceChecker:
         self._ckpt_gather_s += t_gather - t_stall
         self._ckpt_pack_s += t_pack - t_gather
         self._ckpt_npz_s += t_end - t_pack
+        self._ckpt_deflate_threads = max(
+            self._ckpt_deflate_threads, deflate["deflate_threads"]
+        )
+        self._ckpt_deflate_blocks += deflate["deflate_blocks"]
+        self._ckpt_deflate_cpu_s += deflate["deflate_cpu_s"]
         self._ckpt_raw_bytes += sum(
             np.asarray(a).nbytes for a in arrays.values()
         )
@@ -3948,6 +3962,9 @@ class DeviceChecker:
             stall_s=round(stall_s, 3),
             gather_s=round(t_gather - t_stall, 3),
             pack_s=round(t_pack - t_gather, 3),
+            deflate_threads=deflate["deflate_threads"],
+            deflate_blocks=deflate["deflate_blocks"],
+            deflate_cpu_s=round(deflate["deflate_cpu_s"], 3),
             retries=retries,
             level=len(level_sizes),
             distinct_states=nv,
@@ -3963,7 +3980,8 @@ class DeviceChecker:
         """This run's frame counters as ``last_stats`` carries them
         (docs/observability.md): ``ckpt_write_s`` the whole stall,
         ``ckpt_gather_s`` + ``ckpt_pack_s`` + ``ckpt_npz_s`` its three
-        parts."""
+        parts; ``ckpt_deflate_cpu_s`` the write's work in zlib wherever
+        it ran, over ``ckpt_npz_s`` the ``ckpt_deflate_speedup``."""
         return dict(
             ckpt_frames=self._ckpt_frames,
             ckpt_bytes=self._ckpt_bytes,
@@ -3971,6 +3989,13 @@ class DeviceChecker:
             ckpt_gather_s=self._ckpt_gather_s,
             ckpt_pack_s=self._ckpt_pack_s,
             ckpt_npz_s=self._ckpt_npz_s,
+            ckpt_deflate_threads=self._ckpt_deflate_threads,
+            ckpt_deflate_blocks=self._ckpt_deflate_blocks,
+            ckpt_deflate_cpu_s=self._ckpt_deflate_cpu_s,
+            ckpt_deflate_speedup=(
+                self._ckpt_deflate_cpu_s / self._ckpt_npz_s
+                if self._ckpt_npz_s else 0.0
+            ),
             ckpt_raw_bytes=self._ckpt_raw_bytes,
             ckpt_d2h_bytes=self._ckpt_d2h_bytes,
             ckpt_states=self._ckpt_states,

@@ -40,16 +40,31 @@ Design rules every engine follows:
   atomic ``os.replace`` already guarantees it never shadows a valid
   frame, but a dead multi-GB temp file must not squat the checkpoint
   volume either.
+- **Block-parallel deflate**: a frame is the ``.npz`` ``np.load``
+  opens (one ZIP_DEFLATED ``<name>.npy`` member an array), and where
+  its deflate runs adapts to its size alone.  A member's stream is
+  :data:`DEFLATE_BLOCK`-byte blocks, each raw-deflated on its own at
+  zlib's default level and ended on a sync flush, concatenated in
+  order (pigz's scheme: one valid stream).  A frame of
+  :func:`_deflate_threads` blocks or more hands them to a pool of
+  threads (zlib releases the GIL), the blocks of all its arrays in
+  flight together; a smaller one is deflated on the caller's thread.
+  Same bytes, same level, same container: readers never know.
 """
 
 from __future__ import annotations
 
+import collections
+import io
 import json
 import os
 import signal
+import struct
 import threading
 import time
-from typing import Dict, Optional, Sequence, Tuple
+import zlib
+from concurrent.futures import ThreadPoolExecutor
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -76,11 +91,198 @@ def config_sig(**fields) -> str:
 MAX_WRITE_RETRIES = 3
 WRITE_BACKOFF_S = 0.05
 
+# ------------------------------------------- the frame's npz writer
+
+# A member's deflate stream is cut into blocks of this many bytes.  At
+# 256 KB the file is 0.04% over np.savez_compressed's (a block starts
+# with an empty window) and a 10 MB column is 40 tasks; at 4 MB the
+# same pool gave x2.2 where this gives x3.4-8 (ISSUE 45's planning-host
+# runs, ``scripts/profile.py deflate``): an array was two or three tasks.
+DEFLATE_BLOCK = 256 << 10
+# the pool: a thread a usable core, one left to the caller, at most 8
+MAX_DEFLATE_THREADS = 8
+# blocks handed to the pool and not yet in the file, a worker: enough
+# that no worker waits on the caller's CRC and writes, few enough that
+# the compressed blocks held in memory stay under a few MB
+_BLOCKS_IN_FLIGHT = 4
+# an empty final block: what closes a stream of sync-flushed blocks
+_DEFLATE_END = b"\x03\x00"
+# a size, an offset or a count from here on goes into a zip64 field,
+# and its plain field reads all ones
+_ZIP64_FROM = _ZIP32_MAX = 0xFFFFFFFF
+_ZIP_DATE = (1 << 5) | 1  # 1980-01-01: equal arrays give equal files
+
+
+def _usable_cores() -> int:
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:  # not on this platform
+        return os.cpu_count() or 1
+
+
+def _deflate_threads(nbytes: int) -> int:
+    """Threads that deflate a frame of ``nbytes``: ``min(8, usable
+    cores - 1)``, and 1 (the caller's own, no pool) for a frame under
+    two blocks a worker."""
+    workers = min(MAX_DEFLATE_THREADS, max(1, _usable_cores() - 1))
+    return workers if nbytes >= 2 * DEFLATE_BLOCK * workers else 1
+
+
+def _deflate_block(block) -> Tuple[bytes, float]:
+    """One block as a raw-deflate stream of its own at the level
+    ``np.savez_compressed`` uses, ended on a byte boundary and not
+    closed; and the thread's own seconds in it."""
+    t0 = time.thread_time()
+    c = zlib.compressobj(zlib.Z_DEFAULT_COMPRESSION, zlib.DEFLATED, -15)
+    out = c.compress(block) + c.flush(zlib.Z_SYNC_FLUSH)
+    return out, time.thread_time() - t0
+
+
+def _npy_parts(value) -> Tuple[bytes, np.ndarray]:
+    """What ``np.save`` writes for ``value``, byte for byte, as the
+    ``.npy`` header (v1.0) and a flat byte view of the data in the
+    order the header names (a copy only where ``value`` is contiguous
+    in neither)."""
+    a = np.asanyarray(value)
+    d = np.lib.format.header_data_from_array_1_0(a)
+    head = io.BytesIO()
+    np.lib.format.write_array_header_1_0(head, d)
+    body = np.ascontiguousarray(a.T if d["fortran_order"] else a)
+    return head.getvalue(), body.reshape(-1).view(np.uint8)
+
+
+def _blocks(head: bytes, body: np.ndarray):
+    """The member ``head + body`` in blocks of :data:`DEFLATE_BLOCK`
+    bytes (the last one shorter); only the header's block is a copy."""
+    lead = body[: -len(head) % DEFLATE_BLOCK]
+    first = memoryview(head + lead.tobytes())
+    for off in range(0, len(first), DEFLATE_BLOCK):
+        yield first[off: off + DEFLATE_BLOCK]
+    for off in range(lead.size, body.size, DEFLATE_BLOCK):
+        yield body[off: off + DEFLATE_BLOCK]
+
+
+class _Member:
+    """One zip member while its blocks are in flight."""
+
+    __slots__ = ("name", "usize", "left", "crc", "csize", "offset")
+
+    def __init__(self, name: str, usize: int):
+        self.name = name.encode()
+        self.usize = usize
+        self.left = -(-usize // DEFLATE_BLOCK)  # blocks not yet written
+        self.crc = 0
+        self.csize = 0
+        self.offset: Optional[int] = None
+
+    def local_header(self) -> bytes:
+        # sizes in a zip64 field whatever they are, as numpy's writer
+        # has them (``force_zip64``): one header shape, patched in place
+        return struct.pack(
+            "<4s5H3L2H", b"PK\x03\x04", 45, 0, 8, 0, _ZIP_DATE,
+            self.crc, _ZIP32_MAX, _ZIP32_MAX, len(self.name), 20,
+        ) + self.name + struct.pack("<2H2Q", 1, 16, self.usize, self.csize)
+
+    def central_header(self) -> bytes:
+        big = [v for v in (self.usize, self.csize, self.offset)
+               if v >= _ZIP64_FROM]
+        extra = struct.pack(f"<2H{len(big)}Q", 1, 8 * len(big), *big) \
+            if big else b""
+        return struct.pack(
+            "<4s4B4H3L5H2L", b"PK\x01\x02", 45, 3, 45, 0, 0, 8, 0,
+            _ZIP_DATE, self.crc, _zip32(self.csize), _zip32(self.usize),
+            len(self.name), len(extra), 0, 0, 0, 0o600 << 16,
+            _zip32(self.offset),
+        ) + self.name + extra
+
+
+def _zip32(v: int) -> int:
+    return v if v < _ZIP64_FROM else _ZIP32_MAX
+
+
+def _zip_end(count: int, size: int, offset: int) -> bytes:
+    """The end-of-central-directory record, behind a zip64 one where a
+    number passes what the plain one holds."""
+    out = b""
+    if count >= 0xFFFF or max(size, offset) >= _ZIP64_FROM:
+        out = struct.pack(
+            "<4sQ2H2L4Q", b"PK\x06\x06", 44, 45, 45, 0, 0, count, count,
+            size, offset,
+        ) + struct.pack("<4sLQL", b"PK\x06\x07", 0, offset + size, 1)
+    return out + struct.pack(
+        "<4s4H2LH", b"PK\x05\x06", 0, 0, min(count, 0xFFFF),
+        min(count, 0xFFFF), _zip32(size), _zip32(offset), 0,
+    )
+
+
+def _write_npz(path: str, arrays: Dict[str, object]) -> Dict[str, object]:
+    """``np.savez_compressed(path, **arrays)`` with the deflate in
+    blocks (the module's design rule): the blocks of ALL the arrays go
+    to the pool in order, at most :data:`_BLOCKS_IN_FLIGHT` a worker
+    ahead of the file, and land in it in order; the caller's thread
+    takes the CRCs, writes, and patches a member's header when its last
+    block has landed.  Returns the frame's ``deflate_threads`` (1: no
+    pool, every block deflated here), ``deflate_blocks`` and
+    ``deflate_cpu_s`` (the threads' own seconds in zlib, summed)."""
+    parts = [(k,) + _npy_parts(v) for k, v in arrays.items()]
+    threads = _deflate_threads(sum(len(h) + b.size for _k, h, b in parts))
+    pool = ThreadPoolExecutor(
+        threads, thread_name_prefix="ckpt-deflate"
+    ) if threads > 1 else None
+    ahead = threads * _BLOCKS_IN_FLIGHT if pool else 0
+    in_flight: collections.deque = collections.deque()
+    members: List[_Member] = []
+    stats = {"deflate_threads": threads, "deflate_blocks": 0,
+             "deflate_cpu_s": 0.0}
+    try:
+        with open(path, "wb") as f:
+
+            def land():
+                m, got = in_flight.popleft()
+                data, cpu_s = got.result() if pool else got
+                stats["deflate_blocks"] += 1
+                stats["deflate_cpu_s"] += cpu_s
+                if m.offset is None:
+                    m.offset = f.tell()
+                    f.write(m.local_header())
+                f.write(data)
+                m.csize += len(data)
+                m.left -= 1
+                if not m.left:
+                    f.write(_DEFLATE_END)
+                    m.csize += len(_DEFLATE_END)
+                    f.seek(m.offset)
+                    f.write(m.local_header())
+                    f.seek(0, os.SEEK_END)
+
+            for name, head, body in parts:
+                m = _Member(name + ".npy", len(head) + body.size)
+                members.append(m)
+                for block in _blocks(head, body):
+                    # the member's CRC is whole before its last block
+                    # lands: a block is fed before it is written
+                    m.crc = zlib.crc32(block, m.crc)
+                    in_flight.append((m, pool.submit(
+                        _deflate_block, block
+                    ) if pool else _deflate_block(block)))
+                    while len(in_flight) > ahead:
+                        land()
+            while in_flight:
+                land()
+            start = f.tell()
+            f.writelines(m.central_header() for m in members)
+            f.write(_zip_end(len(members), f.tell() - start, start))
+    finally:
+        if pool is not None:
+            pool.shutdown(wait=True, cancel_futures=True)
+    return stats
+
 
 def save_frame(
     path: str, sig: str, arrays: Dict[str, np.ndarray],
     wall_s: float = 0.0,
     meta: Optional[Dict[str, object]] = None,
+    stats: Optional[Dict[str, object]] = None,
 ) -> Tuple[int, float, int]:
     """Write one checkpoint frame atomically; returns ``(nbytes,
     write_s, retries)`` — size, the seconds the caller was blocked
@@ -95,7 +297,9 @@ def save_frame(
     resumed run's states/sec stays meaningful end to end.  ``meta`` is
     an optional small JSON-able dict (writer run_id, frame_seq, level)
     stored under ``__meta__`` — read back with :func:`frame_meta`; v2
-    frames without it still load.
+    frames without it still load.  ``stats``, where given, is updated
+    with the published write's ``deflate_threads``, ``deflate_blocks``
+    and ``deflate_cpu_s`` (:func:`_write_npz`).
 
     Transient ``OSError`` (disk full, EIO) retries with bounded
     exponential backoff; only a persistent failure propagates.  The
@@ -129,16 +333,17 @@ def save_frame(
                     "No space left on device "
                     "(injected fault ckpt_fail, PTT_FAULT)",
                 )
-            np.savez_compressed(
-                tmp,
+            deflate = _write_npz(tmp, dict(
                 __format__=np.int64(FORMAT_VERSION),
                 sig=np.frombuffer(sig.encode(), dtype=np.uint8),
                 wall_s=np.float64(wall_s),
                 **extra,
                 **arrays,
-            )
+            ))
             nbytes = os.path.getsize(tmp)
             os.replace(tmp, path)  # atomic vs crashes + readers
+            if stats is not None:
+                stats.update(deflate)
             return nbytes, time.perf_counter() - t0, retries
         except OSError:
             # a half-written tmp from the failed attempt must not

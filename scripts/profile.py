@@ -17,6 +17,8 @@ dispatch timing, completion barriers) live in one place:
     python scripts/profile.py arbitrate [--widths ...] [--caps-log2 ...]
     python scripts/profile.py scatter   [--updates ...] [--caps-log2 ...]
         [--variants two_as_is,compact8,...] [--win 0,0.02,0.1,0.5,1]
+    python scripts/profile.py deflate   [--states N] [--blocks-kb ...]
+        [--threads ...]  # PR 45: the frame writer, no device touched
 
 Mapping from the retired scripts:
 
@@ -1200,6 +1202,69 @@ def cmd_scatter(args):
     return 0
 
 
+def cmd_deflate(args):
+    """Seconds, bytes and the threads' own seconds in zlib for one
+    frame-like set of arrays at ``--states`` (random key words, sorted
+    slots, packed rows, a sorted parent log, small-integer lanes: 32 B
+    a state) through ``utils/ckpt._write_npz`` at each ``--blocks-kb``
+    x ``--threads`` (PR 45: the measurement behind ``DEFLATE_BLOCK`` and
+    the worker rule), beside ``np.savez_compressed`` on the same arrays.
+    Host code only: no device is touched."""
+    import json
+
+    from pulsar_tlaplus_tpu.utils import ckpt
+
+    rng = np.random.default_rng(0)
+    n = args.states
+    arrays = {
+        "fpk0": rng.integers(0, 2**32, n, dtype=np.uint32),
+        "fpk1": rng.integers(0, 2**32, n, dtype=np.uint32),
+        "fp_slot": np.sort(rng.integers(0, 4 * n, n)).astype(np.int64),
+        "rows": rng.integers(0, 2**20, 2 * n).astype(np.uint32),
+        "parent": np.sort(rng.integers(0, n, n)).astype(np.int32),
+        "lane": rng.integers(0, 19, n).astype(np.int32),
+    }
+    raw = sum(a.nbytes for a in arrays.values())
+    os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    tmp = args.out + ".npz"
+    cores = ckpt._usable_cores()
+
+    def best_of(fn):
+        rows = []
+        for _ in range(args.reps):
+            t0 = time.perf_counter()
+            st = fn()
+            rows.append((time.perf_counter() - t0, st))
+        return min(rows, key=lambda r: r[0])
+
+    with open(args.out, "a") as out:
+        def say(row):
+            row.update(states=n, raw_bytes=raw, usable_cores=cores)
+            print(json.dumps(row), flush=True)
+            out.write(json.dumps(row) + "\n")
+
+        s, _ = best_of(lambda: np.savez_compressed(tmp, **arrays))
+        ref_bytes = os.path.getsize(tmp)
+        say({"writer": "np.savez_compressed", "s": round(s, 4),
+             "bytes": ref_bytes, "mb_s": round(raw / s / 1e6, 2)})
+        for kb in (int(x) for x in args.blocks_kb.split(",")):
+            for thr in (int(x) for x in args.threads.split(",")):
+                ckpt.DEFLATE_BLOCK = kb << 10
+                ckpt._deflate_threads = lambda _n, t=thr: t
+                s, st = best_of(lambda: ckpt._write_npz(tmp, arrays))
+                nbytes = os.path.getsize(tmp)
+                say({"writer": "blocks", "block_kb": kb, "threads": thr,
+                     "s": round(s, 4), "bytes": nbytes,
+                     "over_numpy_pct": round(
+                         100.0 * (nbytes - ref_bytes) / ref_bytes, 4),
+                     "mb_s": round(raw / s / 1e6, 2),
+                     "blocks": st["deflate_blocks"],
+                     "cpu_s": round(st["deflate_cpu_s"], 4),
+                     "speedup": round(st["deflate_cpu_s"] / s, 3)})
+    os.remove(tmp)
+    return 0
+
+
 # --------------------------------------------------------------- main
 
 
@@ -1316,6 +1381,16 @@ def main(argv=None):
     pw.add_argument("--reps", type=int, default=500)
     pw.add_argument("--out", default="chiprun_out/scatter.jsonl")
     pw.set_defaults(fn=cmd_scatter)
+
+    pz = sub.add_parser(
+        "deflate", help="the frame writer by block size and threads, "
+        "beside np.savez_compressed (host only)")
+    pz.add_argument("--states", type=int, default=1_500_000)
+    pz.add_argument("--blocks-kb", default="64,256,1024,4096")
+    pz.add_argument("--threads", default="1,2,4,8,12")
+    pz.add_argument("--reps", type=int, default=2)
+    pz.add_argument("--out", default="chiprun_out/deflate.jsonl")
+    pz.set_defaults(fn=cmd_deflate)
 
     args = ap.parse_args(argv)
     return args.fn(args) or 0

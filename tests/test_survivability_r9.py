@@ -65,7 +65,7 @@ def test_save_frame_retries_transient_oserror(tmp_path, monkeypatch):
     """One transient OSError is absorbed by the retry/backoff path;
     the frame lands intact and the retry count comes back."""
     calls = {"n": 0}
-    real = np.savez_compressed
+    real = ckpt._write_npz
 
     def flaky(*a, **k):
         calls["n"] += 1
@@ -73,7 +73,7 @@ def test_save_frame_retries_transient_oserror(tmp_path, monkeypatch):
             raise OSError(28, "No space left on device")
         return real(*a, **k)
 
-    monkeypatch.setattr(ckpt.np, "savez_compressed", flaky)
+    monkeypatch.setattr(ckpt, "_write_npz", flaky)
     monkeypatch.setattr(ckpt, "WRITE_BACKOFF_S", 0.001)
     p = str(tmp_path / "f.npz")
     nbytes, write_s, retries = ckpt.save_frame(
@@ -89,7 +89,7 @@ def test_save_frame_persistent_failure_raises(tmp_path, monkeypatch):
     def dead(*a, **k):
         raise OSError(5, "Input/output error")
 
-    monkeypatch.setattr(ckpt.np, "savez_compressed", dead)
+    monkeypatch.setattr(ckpt, "_write_npz", dead)
     monkeypatch.setattr(ckpt, "WRITE_BACKOFF_S", 0.001)
     p = str(tmp_path / "f.npz")
     with pytest.raises(OSError, match="Input/output"):
