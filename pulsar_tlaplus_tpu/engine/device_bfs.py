@@ -137,6 +137,26 @@ def ptt_spill_fetch(buf, start, *, size):
         return lax.dynamic_slice(buf, (start,), (size,))
 
 
+@functools.partial(jax.jit, static_argnames=("size",))
+def ptt_spill_fetch_cols(cols, start, *, size):
+    """``[c[start: start + size] for c in cols]`` as ONE flat array of
+    ``len(cols) * size`` ``uint32`` words, column after column: what a
+    fetch of several equal-length columns brings to the host in one
+    transfer (``int32`` columns cross as their bits; the host views them
+    back).  One-dimensional, the layout of every other fetch: a
+    ``[len(cols), size]`` result pads to the chip's tiles.  One program
+    a ``(columns, size)``; ``start`` is traced."""
+    with spans.stage("spill_fetch"):
+        return jnp.concatenate([
+            lax.dynamic_slice(
+                c if c.dtype == jnp.uint32
+                else lax.bitcast_convert_type(c, jnp.uint32),
+                (start,), (size,),
+            )
+            for c in cols
+        ])
+
+
 class _Growth:
     """What one ``run()`` grew, counted on the host at the growth sites
     (no dispatch, sync or fetch of their own; ``last_stats`` carries
@@ -665,6 +685,8 @@ class DeviceChecker:
         self._spill_degraded_emitted = False
         self._budget_overridden = False
         self._spill_fetch_s = 0.0
+        self._spill_fetches = 0
+        self._spill_fetch_planes = 0
         self._spill_d2h_bytes = 0
         self._spill_d2h_padded_bytes = 0
         self._spill_evict_slots = 0
@@ -2734,14 +2756,22 @@ class DeviceChecker:
         return min(max(SPILL_FETCH_MIN, 1 << max(n - 1, 0).bit_length()),
                    length)
 
+    @classmethod
+    def _spill_fetch_window(cls, n: int, off: int, length: int):
+        """``(size, start)`` of the bucketed slice that holds
+        ``[off, off + n)`` of a buffer of ``length``: the bucket of
+        :meth:`_spill_fetch_size`, pushed back where it would pass the
+        buffer's end."""
+        size = cls._spill_fetch_size(n, length)
+        return size, min(off, length - size)
+
     def _bucketed_fetch(self, buf, n: int, off: int, program):
         """``(what crossed the link, its view of buf[off: off + n])``:
         the buffer whole where the bucket
         (:meth:`_spill_fetch_size`) is its length, else the bucket,
         sliced on the device by ``program(buf, start, size=)``."""
         length = buf.shape[0]
-        size = self._spill_fetch_size(n, length)
-        start = min(off, length - size)
+        size, start = self._spill_fetch_window(n, off, length)
         got = np.asarray(
             buf if size == length
             else program(buf, jnp.int32(start), size=size)
@@ -2760,19 +2790,60 @@ class DeviceChecker:
         dt = time.perf_counter() - t0
         if len(got) != n:
             out = out.copy()  # what is kept does not hold the padding
+        self._note_spill_fetch(dt, 1, out.nbytes, got.nbytes)
+        return out
+
+    def _spill_fetch_cols(self, cols, n: int, off: int = 0):
+        """``[c[off: off + n] for c in cols]`` on the host, for device
+        columns of ONE length (``uint32`` or ``int32``), in ONE round
+        trip: :func:`ptt_spill_fetch_cols` packs their bucketed slices
+        (:meth:`_spill_fetch_size`, as :meth:`_spill_fetch` buckets
+        one) into a flat word array whose rows the host trims and views
+        back.  Accounted as the planes fetched one by one would be:
+        ``spill_d2h_bytes`` what was needed, ``spill_d2h_padded_bytes``
+        a bucket a column."""
+        cols = tuple(cols)
+        size, start = self._spill_fetch_window(n, off, cols[0].shape[0])
+        t0 = time.perf_counter()
+        with spans.span("spill.fetch"):
+            got = np.asarray(
+                ptt_spill_fetch_cols(cols, jnp.int32(start), size=size)
+            ).reshape(len(cols), size)
+        dt = time.perf_counter() - t0
+        outs = [
+            row[off - start: off - start + n].view(c.dtype)
+            for c, row in zip(cols, got)
+        ]
+        if size != n:
+            # what is kept does not hold the padding
+            outs = [out.copy() for out in outs]
+        self._note_spill_fetch(
+            dt, len(cols), sum(out.nbytes for out in outs), got.nbytes
+        )
+        return outs
+
+    def _note_spill_fetch(self, dt: float, planes: int, kept: int,
+                          crossed: int) -> None:
+        """Account one round trip of ``dt`` seconds that brought
+        ``planes`` columns: ``kept`` bytes needed, ``crossed`` over the
+        link."""
         self._spill_fetch_s += dt
         self.tstore.note_transfer(dt)
-        self._spill_d2h_bytes += out.nbytes
-        self._spill_d2h_padded_bytes += got.nbytes
-        return out
+        self._spill_fetches += 1
+        self._spill_fetch_planes += planes
+        self._spill_d2h_bytes += kept
+        self._spill_d2h_padded_bytes += crossed
 
     @spans.in_phase("spill")
     def _resolve_cold_misses(self, bufs, flag_acc, n_new):
         """Sieve the flush's hot-filter survivors, resolve them
         against the cold runs in ``miss_batch``-wide batches, and
-        clear the false-new lanes.  Returns the corrected
-        ``(n_new, flag_acc)``.  No cold keys yet = free (the hot
-        verdict is exact; ``_hot_n`` tracks lazily off the fetches)."""
+        clear the false-new lanes.  A batch's ``K`` key columns and
+        its lanes cross the link in ONE fetch
+        (:meth:`_spill_fetch_cols`), after the one sync that reads the
+        sieve's count.  Returns the corrected ``(n_new, flag_acc)``.
+        No cold keys yet = free (the hot verdict is exact; ``_hot_n``
+        tracks lazily off the fetches)."""
         if not self.tstore.has_cold_keys:
             return n_new, flag_acc
         K = self.K
@@ -2786,8 +2857,7 @@ class DeviceChecker:
         false_lanes = []
         for off in range(0, n, self.miss_batch):
             m = min(self.miss_batch, n - off)
-            kq = [self._spill_fetch(c, m, off) for c in kc]
-            lq = self._spill_fetch(lanes, m, off)
+            *kq, lq = self._spill_fetch_cols((*kc, lanes), m, off)
             with spans.span("spill.lookup"):
                 dup = self.tstore.lookup_keys(kq)
             if dup.any():
@@ -2833,7 +2903,7 @@ class DeviceChecker:
             # table — where(False, ...) returned the originals
             bufs["vk"], bufs["gen"] = holed, gen
             return 0
-        ev_np = [self._spill_fetch(c, n) for c in ev]
+        ev_np = self._spill_fetch_cols(ev, n)
         out2 = self._stage_mark(
             "evict", self._rehash_same_jit()(*holed)
         )
@@ -2899,8 +2969,9 @@ class DeviceChecker:
         if upto <= base:
             return
         rows_np = self._spill_fetch(bufs["rows"], (upto - base) * self.W)
-        par_np = self._spill_fetch(bufs["parent"], upto - base)
-        lan_np = self._spill_fetch(bufs["lane"], upto - base)
+        par_np, lan_np = self._spill_fetch_cols(
+            (bufs["parent"], bufs["lane"]), upto - base
+        )
         self.tstore.spill_rows(base, upto, rows_np)
         self.tstore.spill_logs(base, upto, par_np, lan_np)
         n_keep = nv - upto
@@ -3030,6 +3101,8 @@ class DeviceChecker:
             miss_hits=int(s.miss_hits),
             evictions=int(s.evictions),
             hot_keys=int(self._hot_n),
+            fetches=self._spill_fetches,
+            fetch_planes=self._spill_fetch_planes,
             **({"degraded": True} if degraded else {}),
         )
 
@@ -4487,6 +4560,10 @@ class DeviceChecker:
                 spill_misses_resolved=int(sp.misses_resolved),
                 spill_miss_hits=int(sp.miss_hits),
                 spill_syncs=int(self._spill_sync_n),
+                # round trips the fetches made, and the columns they
+                # brought: planes a fetch is how often columns share one
+                spill_fetches=self._spill_fetches,
+                spill_fetch_planes=self._spill_fetch_planes,
                 spill_hot_keys=int(self._hot_n),
                 # the hot tier's peak over the run, against the final
                 # count: what the budget held the device to

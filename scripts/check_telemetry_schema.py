@@ -252,10 +252,12 @@ def _check_fused_levels(path: str, runs: dict) -> List[str]:
 
 
 # the spill record's cumulative counters (v9): each must be
-# monotone non-decreasing per run_id
+# monotone non-decreasing per run_id (``fetches`` / ``fetch_planes``,
+# the round trips and the columns they brought, where a record has
+# them: optional, PR 47)
 SPILL_CUMULATIVE = (
     "keys_evicted", "rows_evicted", "bytes_raw", "bytes_comp",
-    "transfer_s", "misses_resolved",
+    "transfer_s", "misses_resolved", "fetches", "fetch_planes",
 )
 
 # the sim record's cumulative counters (v11): each must be monotone

@@ -36,6 +36,7 @@ NEW_KEYS = (
     "spill_d2h_padded_bytes", "spill_hot_keys_max",
     "spill_hot_share_max_pct", "spill_cold_runs", "spill_budget_overridden",
     "spill_tier_ceilings", "spill_evict_slots", "spill_joins",
+    "spill_fetches", "spill_fetch_planes",
 )
 COUNTS = (
     "spill_evictions", "spill_keys_evicted", "spill_rows_evicted",
@@ -215,12 +216,19 @@ def test_the_same_size_rehash_keeps_the_probes_parts(spill_programs):
     assert re.search(r"ptt\.rehash/.*part\.(gather|write)", hlo)
 
 
-def test_the_fetch_program_carries_its_scope():
-    buf = jax.ShapeDtypeStruct((1 << 14,), jnp.uint32)
-    hlo = device_bfs.ptt_spill_fetch.lower(
-        buf, jnp.int32(0), size=1 << 12
+@pytest.mark.parametrize("name, args", [
+    ("ptt_spill_fetch", lambda u, i: u),
+    ("ptt_spill_fetch_cols", lambda u, i: (u, u, i)),
+    ("ptt_spill_fetch_cols", lambda u, i: (i, i)),
+], ids=["one", "keys_and_lanes", "logs"])
+def test_the_fetch_program_carries_its_scope(name, args):
+    u = jax.ShapeDtypeStruct((1 << 14,), jnp.uint32)
+    i = jax.ShapeDtypeStruct((1 << 14,), jnp.int32)
+    hlo = getattr(device_bfs, name).lower(
+        args(u, i), jnp.int32(0), size=1 << 12
     ).compile().as_text()
-    assert "jit(ptt_spill_fetch)/ptt.spill_fetch/" in hlo
+    assert f"jit({name})/ptt.spill_fetch/" in hlo
+    assert set(re.findall(r"ptt\.[a-z_]+", hlo)) == {"ptt.spill_fetch"}
 
 
 def test_an_untiered_shift_is_the_parents_program(monkeypatch):
@@ -289,33 +297,142 @@ def test_fetch_returns_the_unpadded_slice_through_few_shapes(monkeypatch):
     ck.tstore.close()
 
 
-def test_a_runs_fetches_meet_few_shapes_though_its_flushes_differ(
-    monkeypatch
+def _same_slices(got, want):
+    """``got`` is ``want`` array for array: values, dtype, shape, and
+    C-contiguous memory of its own."""
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert isinstance(g, np.ndarray) and g.dtype == w.dtype
+        assert g.shape == w.shape and (g == w).all()
+        assert g.flags["C_CONTIGUOUS"]
+    return True
+
+
+FETCH_LENGTH = 3 * device_bfs.SPILL_FETCH_MIN + 17
+# (n, off): nothing, one, a bucket's edge and its two neighbours, the
+# whole buffer, and offsets that push ``start`` back to length - size
+FETCH_CASES = {
+    "none": (0, 5),
+    "one": (1, 0),
+    "edge_less_1": (device_bfs.SPILL_FETCH_MIN - 1, 3),
+    "edge": (device_bfs.SPILL_FETCH_MIN, 3),
+    "edge_plus_1": (device_bfs.SPILL_FETCH_MIN + 1, 3),
+    "whole": (FETCH_LENGTH, 0),
+    "clamped": (100, FETCH_LENGTH - 100),
+    "clamped_2_buckets": (
+        device_bfs.SPILL_FETCH_MIN + 9,
+        FETCH_LENGTH - device_bfs.SPILL_FETCH_MIN - 9,
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def fetcher():
+    ck = _mk(hbm_budget=_tight())
+    ck._mk_tstore()
+    yield ck
+    ck.tstore.close()
+
+
+@pytest.mark.parametrize("case", sorted(FETCH_CASES))
+@pytest.mark.parametrize("dtypes", [
+    "u", "i", "ui", "uui", "ii", "iuiu",
+])
+def test_columns_come_back_as_their_slices_in_one_fetch(
+    fetcher, dtypes, case
 ):
+    """1 to 4 columns of one length, ``uint32`` and ``int32`` mixed:
+    exactly ``[np.asarray(c)[off: off + n] for c in cols]``, dtypes
+    kept, in ONE round trip accounted as the planes one by one."""
+    n, off = FETCH_CASES[case]
+    rng = np.random.default_rng(47)
+    host = [
+        rng.integers(0, 1 << 32, FETCH_LENGTH, np.uint64).astype(
+            np.uint32
+        ).view(np.uint32 if d == "u" else np.int32)
+        for d in dtypes
+    ]
+    cols = [jnp.asarray(h) for h in host]
+    ck = fetcher
+    before = (
+        ck._spill_fetches, ck._spill_fetch_planes, ck._spill_d2h_bytes,
+        ck._spill_d2h_padded_bytes, ck._spill_fetch_s,
+    )
+    got = ck._spill_fetch_cols(cols, n, off)
+    assert _same_slices(got, [h[off: off + n] for h in host])
+    assert [g.dtype for g in got] == [c.dtype for c in cols]
+    size = DeviceChecker._spill_fetch_size(n, FETCH_LENGTH)
+    # what is kept holds no padding: a copy wherever the bucket is wider
+    assert all(g.base is None for g in got) or n == size
+    assert ck._spill_fetches == before[0] + 1
+    assert ck._spill_fetch_planes == before[1] + len(cols)
+    assert ck._spill_d2h_bytes == before[2] + 4 * n * len(cols)
+    assert ck._spill_d2h_padded_bytes == before[3] + 4 * size * len(cols)
+    assert ck._spill_fetch_s > before[4]
+    # plane by plane, the parent's way, accounts the same bytes
+    one = [ck._spill_fetch(c, n, off) for c in cols]
+    assert _same_slices(one, got)
+    assert ck._spill_d2h_bytes == before[2] + 8 * n * len(cols)
+    assert ck._spill_d2h_padded_bytes == before[3] + 8 * size * len(cols)
+    assert ck._spill_fetches == before[0] + 1 + len(cols)
+
+
+def _recorded_fetches(monkeypatch):
+    """Patch both fetch methods and both programs to record what a run
+    asks of them: ``(lengths asked for, shapes the programs met)``."""
     lengths, shapes = set(), set()
-    real_fetch = DeviceChecker._spill_fetch
-    real_prog = device_bfs.ptt_spill_fetch
+    real = {
+        k: getattr(DeviceChecker, k)
+        for k in ("_spill_fetch", "_spill_fetch_cols")
+    }
 
     def fetch(self, buf, n, off=0):
         lengths.add(n)
-        return real_fetch(self, buf, n, off)
+        return real["_spill_fetch"](self, buf, n, off)
 
-    def prog(b, start, *, size):
-        shapes.add((b.shape, str(b.dtype), size))
-        return real_prog(b, start, size=size)
+    def fetch_cols(self, cols, n, off=0):
+        lengths.add(n)
+        return real["_spill_fetch_cols"](self, cols, n, off)
+
+    def recording(name):
+        prog = getattr(device_bfs, name)
+
+        def call(b, start, *, size):
+            shapes.add((
+                name,
+                tuple((c.shape, str(c.dtype)) for c in jax.tree.leaves(b)),
+                size,
+            ))
+            return prog(b, start, size=size)
+
+        return call
 
     monkeypatch.setattr(DeviceChecker, "_spill_fetch", fetch)
-    monkeypatch.setattr(device_bfs, "ptt_spill_fetch", prog)
+    monkeypatch.setattr(DeviceChecker, "_spill_fetch_cols", fetch_cols)
+    for name in ("ptt_spill_fetch", "ptt_spill_fetch_cols"):
+        monkeypatch.setattr(device_bfs, name, recording(name))
+    return lengths, shapes
+
+
+def test_a_runs_fetches_meet_few_shapes_though_its_flushes_differ(
+    monkeypatch
+):
+    lengths, shapes = _recorded_fetches(monkeypatch)
     ck = _mk(hbm_budget=_tight())
     r = ck.run()
     assert r.distinct_states == 1654
     assert len(lengths) > 20  # a length a flush, nearly
-    # whole buffers need no program; what is left is a size a buffer
+    # a program a (columns, bucket): the keys with their lanes, the
+    # evicted keys, the two logs, the rows; none a length
     buckets = {
-        (s, d, DeviceChecker._spill_fetch_size(n, s[0]))
-        for s, d, _size in shapes for n in range(1, s[0])
+        (name, cols, DeviceChecker._spill_fetch_size(n, cols[0][0][0]))
+        for name, cols, _size in shapes for n in range(1, cols[0][0][0])
     }
-    assert len(shapes) <= len(buckets) <= 8
+    assert len(shapes) <= len(buckets) <= 10
+    assert {name for name, _cols, _size in shapes} >= {
+        "ptt_spill_fetch_cols"}
+    assert {len(cols) for name, cols, _size in shapes
+            if name == "ptt_spill_fetch_cols"} == {ck.K, ck.K + 1, 2}
 
 
 # ---- the counters -------------------------------------------------------
@@ -338,6 +455,8 @@ def test_new_counters_are_in_last_stats(tiered, monkeypatch):
     assert 0 < st["spill_fetch_s"] <= st["spill_transfer_s"] + 1e-3
     assert st["spill_lookup_s"] > 0 and st["spill_blocked_s"] >= 0
     assert st["spill_joins"] == 1  # an in-RAM store: at the result
+    # a flush's keys and lanes share a round trip: over 2 planes a fetch
+    assert st["spill_fetch_planes"] > 2 * st["spill_fetches"] > 0
     assert st["fuse"] == "level" and st["fpset_slot_rounds"] > 0
     # host_<phase>_s still sum to the wall of run()
     phases = sum(st[f"host_{p}_s"] for p in spans.PHASES)
@@ -346,18 +465,76 @@ def test_new_counters_are_in_last_stats(tiered, monkeypatch):
 
 
 def test_d2h_bytes_are_the_fetched_planes_summed(monkeypatch):
-    kept = []
+    kept, trips = [], []
     real = DeviceChecker._spill_fetch
+    real_cols = DeviceChecker._spill_fetch_cols
 
     def fetch(self, buf, n, off=0):
         out = real(self, buf, n, off)
         kept.append(out.nbytes)
+        trips.append(1)
         return out
 
+    def fetch_cols(self, cols, n, off=0):
+        outs = real_cols(self, cols, n, off)
+        kept.extend(out.nbytes for out in outs)
+        trips.append(len(outs))
+        return outs
+
     monkeypatch.setattr(DeviceChecker, "_spill_fetch", fetch)
+    monkeypatch.setattr(DeviceChecker, "_spill_fetch_cols", fetch_cols)
     ck = _mk(hbm_budget=_tight())
     ck.run()
-    assert ck.last_stats["spill_d2h_bytes"] == sum(kept) > 0
+    st = ck.last_stats
+    assert st["spill_d2h_bytes"] == sum(kept) > 0
+    assert st["spill_fetches"] == len(trips)
+    assert st["spill_fetch_planes"] == sum(trips) == len(kept)
+
+
+@pytest.fixture(scope="module")
+def plane_by_plane():
+    """The tiered run of ``tiered`` with every column fetched by a
+    round trip of its own through ``_spill_fetch``: the parent's
+    path."""
+    mp = pytest.MonkeyPatch()
+    mp.setattr(
+        DeviceChecker, "_spill_fetch_cols",
+        lambda self, cols, n, off=0: [
+            self._spill_fetch(c, n, off) for c in cols
+        ],
+    )
+    try:
+        ck = _mk(hbm_budget=_tight())
+        return ck, ck.run()
+    finally:
+        mp.undo()
+
+
+def test_packed_fetches_change_no_level(tiered, plane_by_plane):
+    (_ck, r), (_ck1, r1) = tiered, plane_by_plane
+    assert r.level_sizes == r1.level_sizes
+    assert r.distinct_states == r1.distinct_states == 1654
+    assert r.diameter == r1.diameter
+
+
+@pytest.mark.parametrize("key", COUNTS)
+def test_packed_fetches_change_no_count(tiered, plane_by_plane, key):
+    """Every count of the run, ``spill_d2h_bytes`` and
+    ``spill_d2h_padded_bytes`` to the byte, is what fetching the planes
+    one by one gives."""
+    (ck, _r), (ck1, _r1) = tiered, plane_by_plane
+    assert ck.last_stats[key] == ck1.last_stats[key], key
+
+
+def test_packing_makes_fewer_round_trips_for_the_same_planes(
+    tiered, plane_by_plane
+):
+    st, st1 = tiered[0].last_stats, plane_by_plane[0].last_stats
+    assert st1["spill_fetches"] == st1["spill_fetch_planes"]  # 1.0 a fetch
+    assert st["spill_fetch_planes"] == st1["spill_fetch_planes"]
+    assert st["spill_evictions"] >= 1
+    assert st["spill_fetch_planes"] > 2 * st["spill_fetches"]
+    assert st["spill_fetches"] < 0.5 * st1["spill_fetches"]
 
 
 def test_traced_and_timed_runs_are_one_path(tiered, tmp_path):

@@ -180,6 +180,38 @@ def test_store_evict_lookup_and_miss_accounting():
     ts.close()
 
 
+@pytest.mark.parametrize("K", [2, 3])
+def test_lookup_reads_the_rows_of_a_packed_fetch(K):
+    """A batch's key columns come to the host as rows of ONE flat word
+    array (``DeviceChecker._spill_fetch_cols``): ``lookup_keys`` on the
+    trimmed rows, views or copies, is the lookup on columns fetched one
+    by one, and the lanes ride as the last row."""
+    ts = TieredStore(K)
+    rng = np.random.default_rng(47)
+    cols = np.unique(
+        rng.integers(0, 1 << 32, (4000, K), np.uint64).astype(np.uint32),
+        axis=0,
+    )
+    cols = cols[np.lexsort(cols.T[::-1])]
+    assert ts.evict_keys(tuple(cols[:, j].copy() for j in range(K)))
+    size, n, lo = 1 << 10, 700, 13
+    q = np.concatenate([cols[:400], cols[400:1024] ^ np.uint32(0xA5A5A5A5)])
+    lanes = rng.permutation(size).astype(np.int32)
+    flat = np.concatenate(
+        [q[:, j] for j in range(K)] + [lanes.view(np.uint32)]
+    )
+    *kq, lq = (row[lo: lo + n] for row in flat.reshape(K + 1, size))
+    assert all(k.flags["C_CONTIGUOUS"] for k in kq)
+    want = ts.lookup_keys(
+        [np.ascontiguousarray(q[lo: lo + n, j]) for j in range(K)]
+    )
+    assert want[: 400 - lo].all() and not want[400 - lo:].any()
+    assert (ts.lookup_keys(kq) == want).all()
+    assert (ts.lookup_keys([k.copy() for k in kq]) == want).all()
+    assert (lq.view(np.int32) == lanes[lo: lo + n]).all()
+    ts.close()
+
+
 def test_store_rows_logs_gather_and_gap_detection():
     ts = TieredStore(2)
     W = 3
@@ -458,6 +490,15 @@ def test_tiered_shipped_45k_hot_under_quarter(tmp_path):
     assert spills, "tiered run emitted no spill records"
     hdr = next(e for e in evs if e["event"] == "run_header")
     assert hdr["hbm_budget"] == ck.hbm_budget
+    # the round trips and the columns they brought (PR 47): cumulative
+    # in every spill record, the run's in the result's stats
+    for k in ("fetches", "fetch_planes"):
+        seen = [e[k] for e in spills]
+        assert seen == sorted(seen) and seen[-1] == st[f"spill_{k}"] > 0
+    res = next(e for e in evs if e["event"] == "result")
+    assert res["stats"]["spill_fetches"] == st["spill_fetches"]
+    assert res["stats"]["spill_fetch_planes"] == st["spill_fetch_planes"]
+    assert st["spill_fetch_planes"] > 2 * st["spill_fetches"]
 
 
 def test_spill_monotone_validator_negative(tmp_path):
@@ -479,6 +520,32 @@ def test_spill_monotone_validator_negative(tmp_path):
         )) + "\n")
     errs = mod.validate_stream(path)
     assert any("bytes_raw went backwards" in e for e in errs)
+
+
+@pytest.mark.parametrize("key", ["fetches", "fetch_planes"])
+def test_spill_fetch_counters_are_cumulative_where_present(tmp_path, key):
+    """``fetches`` / ``fetch_planes`` (PR 47) are optional in a spill
+    record and cumulative where a record has them."""
+    mod = _checker_mod()
+    base = dict(
+        v=9, run_id="r1", tier="ram", keys_evicted=10, rows_evicted=0,
+        transfer_s=0.1, misses_resolved=5, bytes_raw=100, bytes_comp=50,
+        event="spill",
+    )
+    for name, vals, clean in (
+        ("old", (None, None), True), ("up", (3, 7), True),
+        ("down", (7, 3), False),
+    ):
+        path = str(tmp_path / f"{name}.jsonl")
+        with open(path, "w") as f:
+            for seq, v in enumerate(vals):
+                rec = dict(base, t=0.1 * (seq + 1), seq=seq)
+                if v is not None:
+                    rec[key] = v
+                f.write(json.dumps(rec) + "\n")
+        errs = mod.validate_stream(path)
+        assert (errs == []) is clean, errs
+        assert clean or any(f"spill.{key} went backwards" in e for e in errs)
 
 
 # ------------------------------------------- survive + resume drills

@@ -6,6 +6,7 @@ processes against one compile cache, and the published 253,361-state
 binding followed by the 45,198-state one in one process.
 """
 
+import inspect
 import os
 import subprocess
 import sys
@@ -13,6 +14,7 @@ import sys
 import jax
 import pytest
 
+from pulsar_tlaplus_tpu.engine import device_bfs
 from tests.test_units import CFG_45K, ROOT, SPEC, VERDICT, _cli_check
 
 CFG_253K = os.path.join(ROOT, "specs", "compaction_253k.cfg")
@@ -28,6 +30,26 @@ def test_the_45k_binding_after_the_253k_binding(tmp_path):
     assert small[3]["jit_body_traces"] > 0
     assert VERDICT.search(small[1]).groups() == ("45198", "20")
     assert len(small[2]) == 19 and sum(small[2]) + 729 == 45198
+
+
+# ---- the tiered store's fetch programs are units too ----------------------
+
+
+@pytest.mark.parametrize("name", ["ptt_spill_fetch", "ptt_spill_fetch_cols"])
+def test_a_fetch_program_is_a_module_level_closure_free_function(name):
+    """Built once a process and keyed on ``size`` alone (``start`` is
+    traced), under a name of its own: the name is part of the
+    persistent cache's key."""
+    jitted = getattr(device_bfs, name)
+    fn = inspect.unwrap(jitted)
+    assert inspect.isfunction(fn) and fn is not jitted
+    assert fn.__closure__ is None
+    assert fn.__qualname__ == fn.__name__ == name  # no <locals>
+    assert fn.__module__ == device_bfs.__name__
+    params = inspect.signature(fn).parameters
+    assert list(params)[-1] == "size" and "self" not in params
+    assert params["size"].kind is inspect.Parameter.KEYWORD_ONLY
+    assert params["start"].kind is inspect.Parameter.POSITIONAL_OR_KEYWORD
 
 
 # ---- the mechanism never changes a program -------------------------------
