@@ -389,7 +389,6 @@ def run_sim_bench(args) -> None:
         ckpt_bytes=sim.last_stats.get("ckpt_bytes"),
         ckpt_write_s=sim.last_stats.get("ckpt_write_s"),
         ckpt_retries=sim.last_stats.get("ckpt_retries"),
-        profile_sig=sim.profile_sig,
     )
     print(json.dumps(d))
 
@@ -839,15 +838,6 @@ def parse_args(argv=None):
         "(default auto, up to 8; 1 disables batching)",
     )
     ap.add_argument(
-        "--profile", default="auto", metavar="AUTO|NONE|FILE",
-        help="tuned-profile resolution (docs/tuning.md): 'auto' "
-        "(default) looks the bench workload's profile up by config "
-        "signature in PTT_TUNE_DIR and lets its knobs override the "
-        "hand defaults above (explicit CLI flags still win); 'none' "
-        "disables; a path loads that profile file.  The artifact "
-        "records profile_sig either way",
-    )
-    ap.add_argument(
         "--checkpoint", default=None,
         help="write level-boundary checkpoint frames to this .npz "
         "(survivable bench runs: SIGTERM/SIGINT exit resumably, HBM "
@@ -977,43 +967,6 @@ def main(argv=None):
     # candidates instead of per 8.9M).
     kw = dict(BENCH_CHECKER_KW)
     kw["max_states"] = args.max_states
-    # tuned-profile resolution (r15, docs/tuning.md): the profile's
-    # knobs replace the HAND defaults above — that is the point of
-    # the tuner — but explicit CLI flags still win, and the engine
-    # re-validates the profile against its own config signature
-    prof = None
-    if args.profile != "none":
-        from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
-
-        from pulsar_tlaplus_tpu.store import budget as store_budget
-
-        prof = tune_profiles.resolve(
-            "auto" if args.profile == "auto" else args.profile,
-            model=model,
-            invariants=tuple(
-                getattr(model, "default_invariants", ())
-            ),
-            engine="device_bfs",
-            # the tiered REGIME is part of the profile key (r16): a
-            # budgeted bench must resolve the spill-tuned profile,
-            # never the all-resident one — env var included
-            tiered=store_budget.resolve_budget(args.hbm_budget)
-            is not None,
-        )
-    if prof:
-        pk = tune_profiles.knobs_for(prof, "device_bfs")
-        user_set = set()
-        if args.fuse_group is not None:
-            user_set.add("fuse_group")
-        for k, v in sorted(pk.items()):
-            if k == "adapt" or k in user_set:
-                continue
-            kw[k] = v
-            print(
-                f"bench: tuned knob {k}={v} "
-                f"(profile {prof['sig']})",
-                file=sys.stderr,
-            )
     xprof_window = None
     if args.xprof_levels:
         from pulsar_tlaplus_tpu.obs.telemetry import parse_level_window
@@ -1022,23 +975,15 @@ def main(argv=None):
             xprof_window = parse_level_window(args.xprof_levels)
         except ValueError as e:
             sys.exit(f"bench: --xprof-levels: {e}")
-    # explicit flag wins; else the tuned profile's knob — popped
-    # UNCONDITIONALLY so the **kw pass-through can never duplicate
-    # the ctor kwarg
-    prof_spill_compress = kw.pop("spill_compress", None)
-    spill_compress = (
-        False if args.no_spill_compress else prof_spill_compress
-    )
     ck = DeviceChecker(
         model,
         time_budget_s=args.budget_s,
         progress=True,
         metrics_path=metrics_path,
         fuse=args.fuse,
-        fuse_group=kw.pop("fuse_group", args.fuse_group),
+        fuse_group=args.fuse_group,
         hbm_budget=args.hbm_budget,
-        spill_compress=spill_compress,
-        profile=prof,
+        spill_compress=(False if args.no_spill_compress else None),
         checkpoint_path=args.checkpoint,
         checkpoint_every=args.checkpoint_every,
         telemetry=args.telemetry,
@@ -1289,10 +1234,6 @@ def _emit(args, ck, c, r, compile_s, metrics_path):
                 # economy — total dispatches per BFS level, fused
                 # dispatches, and levels the ramp batched
                 "fuse": ck.fuse,
-                # tuned-profile attribution (r15): null on untuned
-                # runs — lets `ledger compare/gate` split tuned vs
-                # default bench trajectories (docs/tuning.md)
-                "profile_sig": ck.profile_sig,
                 "dispatches_per_level": stat("dispatches_per_level"),
                 "stage_fused_n": stat("stage_fused_n"),
                 "fuse_levels": stat("fuse_levels"),

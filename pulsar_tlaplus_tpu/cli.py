@@ -171,8 +171,6 @@ def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
         fuse_group=args.fuse_group,
         hbm_budget=args.hbm_budget,
         spill_compress=(False if args.no_spill_compress else None),
-        profile=_profile_arg(args),
-        adapt=_adapt_arg(args),
         telemetry=args.telemetry,
         heartbeat_s=args.progress,
         xprof_dir=args.xprof,
@@ -461,7 +459,6 @@ def _check_properties(args, model, properties, rc):
                     sweep_group=args.sweep_group,
                     hbm_budget=args.hbm_budget,
                     spill_compress=(False if args.no_spill_compress else None),
-                    profile=_profile_arg(args),
                     telemetry=args.telemetry,
                     heartbeat_s=args.progress,
                     progress=True,
@@ -486,18 +483,6 @@ def _check_properties(args, model, properties, rc):
         if not lres.holds:
             rc = 1
     return rc
-
-
-def _profile_arg(args):
-    return None if getattr(args, "no_profile", False) else "auto"
-
-
-def _adapt_arg(args):
-    if getattr(args, "no_adapt", False):
-        return False
-    if getattr(args, "adapt", False):
-        return True
-    return None  # profile/env decides (tune/online.py)
 
 
 def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
@@ -535,7 +520,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 sweep_group=args.sweep_group,
                 hbm_budget=args.hbm_budget,
                 spill_compress=(False if args.no_spill_compress else None),
-                profile=_profile_arg(args),
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
                 progress=True,
@@ -551,8 +535,7 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         return _report_liveness(args.liveness_property, args, lres)
     if args.simulate:
         # the streaming swarm engine (sim/, round 18): full telemetry,
-        # heartbeat, checkpoint/resume, and tuned-profile support —
-        # the legacy one-round semantics are the default budget
+        # heartbeat and checkpoint/resume — the legacy one-round semantics are the default budget
         from pulsar_tlaplus_tpu.sim.engine import StreamingSimulator
 
         try:
@@ -568,7 +551,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
                 progress=True,
-                profile=_profile_arg(args),
             )
             sres = sim.run(resume=args.recover)
         except FileNotFoundError:
@@ -654,8 +636,6 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 spill_compress=(
                     False if args.no_spill_compress else None
                 ),
-                profile=_profile_arg(args),
-                adapt=_adapt_arg(args),
                 checkpoint_path=args.checkpoint,
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
@@ -890,7 +870,6 @@ def _cmd_serve(args) -> int:
         sub_batch=min(args.chunk, 4096),
         specs=tuple(args.spec or ()),
         prewarm_tiers=not args.no_tiers,
-        profiles="none" if args.no_profiles else "auto",
         tcp=args.tcp or "",
         tokens_path=args.tokens or "",
         queue_cap=args.queue_cap,
@@ -1340,127 +1319,6 @@ def _cmd_ledger(args) -> int:
     return 2
 
 
-def _cmd_tune(args) -> int:
-    """Offline autotune (docs/tuning.md): predict the knob space with
-    the calibrated cost model, measure the top-K survivors with short
-    interleaved runs, persist the winner as a tuned profile the
-    engines / bench / daemon resolve by config signature."""
-    _jax_setup(args)
-    from pulsar_tlaplus_tpu.models import registry
-    from pulsar_tlaplus_tpu.obs import attribution, ledger
-    from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
-    from pulsar_tlaplus_tpu.tune import search as tune_search
-    from pulsar_tlaplus_tpu.utils import cfg as cfgmod
-
-    module = args.spec
-    if module.endswith(".tla"):
-        module = os.path.splitext(os.path.basename(module))[0]
-    if module not in registry.COMPILED:
-        print(
-            f"tpu-tlc: tune needs a compiled-registry spec (known: "
-            f"{sorted(registry.COMPILED)}); got {args.spec!r}",
-            file=sys.stderr,
-        )
-        return 2
-    cfg_path = args.config or os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "specs", f"{module}.cfg",
-    )
-    try:
-        tlc_cfg = cfgmod.load(cfg_path)
-        model, _constants = registry.COMPILED[module](tlc_cfg)
-    except (OSError, ValueError) as e:
-        print(f"tpu-tlc: {e}", file=sys.stderr)
-        return 2
-    invariants = tuple(args.invariant or tlc_cfg.invariants)
-    cal = None
-    if args.calibration:
-        try:
-            cal = attribution.load_calibration(args.calibration)
-        except (OSError, ValueError) as e:
-            print(f"tpu-tlc: {e}", file=sys.stderr)
-            return 2
-    stream_dir = args.stream_dir
-    if stream_dir is None and args.ledger:
-        import tempfile
-
-        stream_dir = tempfile.mkdtemp(prefix="ptt_tune_")
-
-    def log(msg: str) -> None:
-        print(f"tpu-tlc tune: {msg}", file=sys.stderr, flush=True)
-
-    def _ingest_tune_streams() -> None:
-        """Ingest the measured runs' telemetry streams into --ledger
-        (shared by the check and simulate branches)."""
-        if not (args.ledger and stream_dir):
-            return
-        import glob as globmod
-
-        recs = []
-        for p in sorted(
-            globmod.glob(os.path.join(stream_dir, "tune_*.jsonl"))
-        ):
-            try:
-                recs.append(ledger.record_from_file(p))
-            except (OSError, ValueError, json.JSONDecodeError):
-                continue
-        added = ledger.append(args.ledger, recs)
-        print(f"ingested {added} measured run(s) into {args.ledger}")
-
-    if args.mode == "simulate":
-        try:
-            profile, rows = tune_search.tune_sim(
-                model,
-                invariants=invariants,
-                spec_label=module,
-                depth=args.sim_depth,
-                total_steps=args.sim_steps,
-                top_k=args.top_k,
-                repeat=args.repeat,
-                calibration=cal,
-                stream_dir=stream_dir,
-                log=log,
-            )
-        except (ValueError, RuntimeError) as e:
-            print(f"tpu-tlc: tune failed: {e}", file=sys.stderr)
-            return 2
-        print(tune_search.render_report(profile, rows))
-        print(f"profile: {tune_profiles.path_for(profile['sig'])}")
-        _ingest_tune_streams()
-        return 0
-    try:
-        profile, rows = tune_search.tune_device(
-            model,
-            invariants=invariants,
-            spec_label=module,
-            base_kw=dict(
-                visited_cap=args.visited_cap,
-                frontier_cap=args.frontier_cap,
-                max_states=args.maxstates,
-                **(
-                    {"hbm_budget": args.hbm_budget}
-                    if args.hbm_budget
-                    else {}
-                ),
-            ),
-            budget_s=args.budget,
-            top_k=args.top_k,
-            repeat=args.repeat,
-            candidate_limit=args.candidates,
-            calibration=cal,
-            adapt=args.adapt,
-            stream_dir=stream_dir,
-            log=log,
-        )
-    except (ValueError, RuntimeError) as e:
-        print(f"tpu-tlc: tune failed: {e}", file=sys.stderr)
-        return 2
-    print(tune_search.render_report(profile, rows))
-    print(f"profile: {tune_profiles.path_for(profile['sig'])}")
-    _ingest_tune_streams()
-    return 0
-
-
 def _sim_model(args):
     """Model + constants + invariants for the ``simulate`` subcommand:
     a registry spec name (or .tla path of one), falling back to the
@@ -1539,7 +1397,6 @@ def _cmd_simulate(args) -> int:
             telemetry=args.telemetry,
             heartbeat_s=args.progress,
             progress=True,
-            profile=_profile_arg(args),
         )
         sres = sim.run(resume=args.recover)
     except FileNotFoundError:
@@ -1667,12 +1524,6 @@ def _build_parser():
         help="LRU byte cap on the warm-artifact store (incremental "
         "checking, docs/incremental.md; default 1 GiB; 0 disables "
         "the warm layer — no artifacts, every submit runs cold)",
-    )
-    ps.add_argument(
-        "--no-profiles", action="store_true",
-        help="skip tuned-profile resolution when building pooled "
-        "checkers (profiles otherwise shape the prewarmed "
-        "executables; docs/tuning.md)",
     )
     ps.add_argument(
         "--recover", action="store_true",
@@ -2001,107 +1852,6 @@ def _build_parser():
         "profile-sig prefix",
     )
 
-    ptn = sub.add_parser(
-        "tune",
-        help="cost-model-driven autotune: predict the knob space "
-        "(fuse_group, sub-batch, flush factor, fpset probe schedule, "
-        "compaction impl), measure the top-K candidates with short "
-        "interleaved runs, persist the winner as a tuned profile the "
-        "engines and the serve daemon resolve by config signature "
-        "(docs/tuning.md)",
-    )
-    ptn.add_argument(
-        "spec", help="compiled-registry spec name (or its .tla path)"
-    )
-    ptn.add_argument(
-        "-config", default=None,
-        help=".cfg constant bindings (default: specs/<spec>.cfg)",
-    )
-    ptn.add_argument(
-        "-invariant", action="append", default=None,
-        help="invariant set the tuned runs check (repeatable; "
-        "default: cfg INVARIANTS — part of the profile key)",
-    )
-    ptn.add_argument(
-        "--mode", choices=["check", "simulate"], default="check",
-        help="tune the exhaustive device engine (default) or the "
-        "streaming simulation engine's SIM_KNOBS (n_walkers, "
-        "segment_len; docs/simulation.md)",
-    )
-    ptn.add_argument(
-        "--sim-depth", dest="sim_depth", type=int, default=64,
-        help="with --mode simulate: steps per behavior",
-    )
-    ptn.add_argument(
-        "--sim-steps", dest="sim_steps", type=int, default=None,
-        help="with --mode simulate: swarm-total step budget per "
-        "measured run (default 4 rounds of 1024 walkers)",
-    )
-    ptn.add_argument(
-        "--maxstates", type=int, default=1 << 22,
-        help="state budget per measured run (keep it short: the "
-        "tuner needs relative wall, not exhaustion)",
-    )
-    ptn.add_argument(
-        "--budget", type=float, default=None, metavar="SEC",
-        help="optional per-run time budget",
-    )
-    ptn.add_argument(
-        "--hbm-budget",
-        dest="hbm_budget",
-        default=None,
-        metavar="BYTES",
-        help="tune the workload under a tiered-store byte budget "
-        "(adds the spill knobs — headroom, compression, miss batch — "
-        "to the searched space; docs/memory.md)",
-    )
-    ptn.add_argument(
-        "--visited-cap", type=int, default=1 << 16,
-        help="initial visited-set tier for the measured runs",
-    )
-    ptn.add_argument(
-        "--frontier-cap", type=int, default=1 << 14,
-        help="initial row-store tier for the measured runs",
-    )
-    ptn.add_argument(
-        "--top-k", type=int, default=4,
-        help="candidates measured beyond the default baseline "
-        "(everything else is pruned by the cost-model prediction)",
-    )
-    ptn.add_argument(
-        "--repeat", type=int, default=2,
-        help="interleaved repetitions per measured candidate "
-        "(min-of-N; default 2)",
-    )
-    ptn.add_argument(
-        "--candidates", type=int, default=None,
-        help="cap the enumerated space (default: the whole space)",
-    )
-    ptn.add_argument(
-        "--calibration", default=None, metavar="FILE",
-        help="calibration.json from scripts/profile.py calibrate "
-        "(default: per-backend fallback unit costs)",
-    )
-    ptn.add_argument(
-        "--adapt", action="store_true",
-        help="write the profile with online adaptation enabled "
-        "(engines then run the dispatch-boundary controller; "
-        "PTT_TUNE_ADAPT=0 still kills it)",
-    )
-    ptn.add_argument(
-        "--stream-dir", default=None, metavar="DIR",
-        help="keep the measured runs' telemetry streams here",
-    )
-    ptn.add_argument(
-        "--ledger", default=None, metavar="FILE",
-        help="ingest every measured run into this ledger (tuned runs "
-        "carry profile_sig=null during the search; the WINNING "
-        "profile's later runs carry its sig)",
-    )
-    ptn.add_argument(
-        "-cpu", action="store_true", help="force the CPU backend"
-    )
-
     psim = sub.add_parser(
         "simulate",
         help="streaming walker-swarm simulation (TLC -simulate, "
@@ -2123,8 +1873,7 @@ def _build_parser():
     )
     psim.add_argument(
         "-walkers", type=int, default=None, metavar="N",
-        help="walker swarm width (default 1024, or the tuned "
-        "profile's n_walkers)",
+        help="walker swarm width (default 1024)",
     )
     psim.add_argument(
         "-depth", type=int, default=64,
@@ -2134,7 +1883,7 @@ def _build_parser():
     psim.add_argument(
         "-segment", type=int, default=None, metavar="STEPS",
         help="steps per device dispatch (clamped to a divisor of "
-        "-depth; default min(depth, 32) or the tuned profile)",
+        "-depth; default min(depth, 32))",
     )
     psim.add_argument(
         "-seed", type=int, default=0,
@@ -2172,10 +1921,6 @@ def _build_parser():
         "-progress", type=float, default=None, metavar="SEC",
         help="heartbeat line every SEC seconds (states, steps, "
         "walks/s EWMA — zero extra device syncs)",
-    )
-    psim.add_argument(
-        "-no-profile", dest="no_profile", action="store_true",
-        help="skip tuned-profile resolution (SIM_KNOBS; docs/tuning.md)",
     )
     psim.add_argument(
         "-cpu", action="store_true", help="force the CPU backend"
@@ -2231,29 +1976,6 @@ def _build_parser():
         help="with -fuse level: max ramp levels batched into one "
         "dispatch (default: auto from the frontier size, up to 8; "
         "1 disables ramp batching)",
-    )
-    pc.add_argument(
-        "-no-profile",
-        dest="no_profile",
-        action="store_true",
-        help="skip tuned-profile resolution: run with the engine "
-        "defaults + explicit flags only (profiles otherwise resolve "
-        "by config signature from PTT_TUNE_DIR; docs/tuning.md)",
-    )
-    pc.add_argument(
-        "-adapt",
-        action="store_true",
-        help="enable online adaptation: a dispatch-boundary "
-        "controller nudges the fpset probe schedule and the ramp "
-        "batch cap from the streaming work counters (every change "
-        "is a telemetry 'tune' event; discovery order is unchanged)",
-    )
-    pc.add_argument(
-        "-no-adapt",
-        dest="no_adapt",
-        action="store_true",
-        help="force online adaptation OFF even when the tuned "
-        "profile enables it (PTT_TUNE_ADAPT=0 is the env equivalent)",
     )
     pc.add_argument(
         "-sweep-group",
@@ -2430,7 +2152,6 @@ def main(argv=None):
             "serve": _cmd_serve,
             "dispatch": _cmd_dispatch,
             "simulate": _cmd_simulate,
-            "tune": _cmd_tune,
             "submit": _cmd_submit,
             "status": _cmd_status,
             "watch": _cmd_watch,

@@ -376,9 +376,7 @@ class Checker:
             device=dev,
             visited_impl=self.dedup_mode,
             config_sig=self._config_sig(),
-            # v8 envelope: the host engine is never profile-tuned,
-            # but the field must exist so the ledger can split tuned
-            # vs default trajectories uniformly
+            # REQUIRED since schema v8, a constant null
             profile_sig=None,
             hbm_budget=None,
             # v10: tenant identity (None outside the daemon)

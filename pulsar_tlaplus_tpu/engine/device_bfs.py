@@ -74,8 +74,6 @@ from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.store import budget as store_budget
 from pulsar_tlaplus_tpu.store import sieve as store_sieve
 from pulsar_tlaplus_tpu.store.tiers import TieredStore
-from pulsar_tlaplus_tpu.tune import online as tune_online
-from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
 from pulsar_tlaplus_tpu.utils import ckpt, faults, recovery
 from pulsar_tlaplus_tpu.ops import compact as compact_ops
 from pulsar_tlaplus_tpu.ops import fpset
@@ -127,6 +125,12 @@ def _scoped(scope: str, name: str, fn):
 # the next power of two at or over what is needed, from here up to the
 # buffer's own length, so a run meets a handful of fetch programs
 SPILL_FETCH_MIN = 1 << 12
+# sieved keys a cold-lookup batch under a budget (one fetch and one
+# ``np.searchsorted`` pass over the cold runs a batch)
+MISS_BATCH = 1 << 15
+# share of ``hbm_budget`` kept back against transients: the tier
+# ceilings are walked inside ``budget * (1 - HBM_HEADROOM)``
+HBM_HEADROOM = 0.1
 
 
 @functools.partial(jax.jit, static_argnames=("size",))
@@ -213,8 +217,8 @@ class DeviceChecker:
     left them: ``rows`` (packed states in gid order, windowed by
     ``rows_window``), ``parent`` and ``lane`` (the trace logs, one
     entry per gid), ``vk`` (the visited set).  The liveness engine,
-    the tuner's differential, the tests and the benchmark's sample
-    replay read them; set it to None to free the device memory.
+    the tests and the benchmark's sample replay read them; set it to
+    None to free the device memory.
     """
 
     def __init__(
@@ -222,7 +226,7 @@ class DeviceChecker:
         model,
         invariants: Optional[Tuple[str, ...]] = None,
         check_deadlock: bool = True,
-        sub_batch: Optional[int] = None,
+        sub_batch: int = 8192,
         expand_chunk: Optional[int] = None,
         visited_cap: int = 1 << 16,
         frontier_cap: Optional[int] = None,
@@ -230,8 +234,8 @@ class DeviceChecker:
         time_budget_s: Optional[float] = None,
         progress: bool = False,
         metrics_path: Optional[str] = None,
-        group: Optional[int] = None,
-        flush_factor: Optional[int] = None,
+        group: int = 4,
+        flush_factor: int = 1,
         fp_bits: Optional[int] = None,
         append_chunk: Optional[int] = None,
         seed_cap: Optional[int] = None,
@@ -242,12 +246,8 @@ class DeviceChecker:
         fpset_dense_rounds: Optional[int] = None,
         fpset_stages=None,
         hbm_budget=None,
-        hbm_headroom: Optional[float] = None,
         spill_dir: Optional[str] = None,
         spill_compress: Optional[bool] = None,
-        miss_batch: Optional[int] = None,
-        profile=None,
-        adapt: Optional[bool] = None,
         checkpoint_path: Optional[str] = None,
         checkpoint_every: int = 5,
         telemetry=None,
@@ -282,66 +282,8 @@ class DeviceChecker:
         ):
             self.invariant_names += ("__EvalError__",)
         self.check_deadlock = check_deadlock
-        # Tuned-profile resolution (round 15, tune/profiles.py):
-        # explicit ctor knobs always win; knobs the caller left at
-        # their ``None`` sentinel take the resolved profile's value,
-        # then the engine default.  ``profile`` is None (off — direct
-        # constructions, tests), "auto" (look up by config signature),
-        # a path, or a profile dict; resolution failures warn and fall
-        # back — a tuned profile is an optimization, never a
-        # correctness dependency.
-        # the budget resolves BEFORE the profile: the tiered regime is
-        # part of the profile key (a spill-tuned winner must never
-        # auto-resolve for an all-resident run, or vice versa)
         self.hbm_budget = store_budget.resolve_budget(hbm_budget)
         self.tiered = self.hbm_budget is not None
-        prof = tune_profiles.resolve(
-            profile, model=model, invariants=self.invariant_names,
-            engine="device_bfs", tiered=self.tiered,
-        )
-        self.profile_sig = prof["sig"] if prof else None
-        _pk = tune_profiles.knobs_for(prof, "device_bfs")
-        self.profile_applied = tuple(
-            sorted(
-                k for k in _pk
-                if k != "adapt"
-                and {
-                    "sub_batch": sub_batch,
-                    "flush_factor": flush_factor,
-                    "group": group,
-                    "fuse_group": fuse_group,
-                    "fpset_dense_rounds": fpset_dense_rounds,
-                    "fpset_stages": fpset_stages,
-                    "hbm_headroom": hbm_headroom,
-                    "spill_compress": spill_compress,
-                    "miss_batch": miss_batch,
-                }.get(k) is None
-            )
-        )
-        # tiered-store knobs resolve like every other profile knob:
-        # explicit ctor value > tuned profile > engine default
-        if hbm_headroom is None:
-            hbm_headroom = _pk.get("hbm_headroom")
-        if spill_compress is None:
-            spill_compress = _pk.get("spill_compress")
-        if miss_batch is None:
-            miss_batch = _pk.get("miss_batch")
-        sub_batch = sub_batch or _pk.get("sub_batch") or 8192
-        group = group or _pk.get("group") or 4
-        flush_factor = flush_factor or _pk.get("flush_factor") or 1
-        fuse_group = (
-            fuse_group if fuse_group is not None
-            else _pk.get("fuse_group")
-        )
-        if fpset_dense_rounds is None:
-            fpset_dense_rounds = _pk.get("fpset_dense_rounds")
-        if fpset_stages is None:
-            fpset_stages = _pk.get("fpset_stages")
-        # online adaptation (tune/online.py): env kill switch >
-        # explicit ctor/CLI choice > the profile's "adapt" knob
-        self.adapt = tune_online.resolve_adapt(
-            adapt, bool(_pk.get("adapt", False))
-        )
         self.A = model.A
         self.W = self.layout.W
         self.G = sub_batch
@@ -392,19 +334,11 @@ class DeviceChecker:
         if fuse_group is not None and fuse_group < 1:
             raise ValueError(f"fuse_group must be >= 1: {fuse_group}")
         self.RMAX = min(fuse_group or 8, 64)
-        # fpset probe schedule: ctor params > PTT_FPSET_SCHEDULE env >
-        # ops/fpset.py defaults (the real-chip tuning pass sweeps these
-        # against the fpset_max_probe_rounds telemetry signal)
+        # fpset probe schedule: the caller's own, else ops/fpset.py's
+        # defaults
         self.fps_dense, self.fps_stages = fpset.resolve_schedule(
             fpset_dense_rounds, fpset_stages
         )
-        # online-adaptation state: the configured schedule is the
-        # per-run baseline (an adapted pooled checker must not leak
-        # its adjustments into the next job's run), and the ramp cap
-        # adapts within [1, RMAX] without re-jitting
-        self._fps_base = (self.fps_dense, self.fps_stages)
-        self._adapt_cap: Optional[int] = None
-        self._tuner = None
         # The visited set is the HBM-resident hash-table FPSet
         # (ops/fpset.py).  ``VCAP`` is the max states admissible before
         # growth; the table carries ``TCAP = 2 * VCAP`` slots so the
@@ -486,19 +420,9 @@ class DeviceChecker:
         # budget-derived tier and evicts cold generations to the host
         # store; the row/log stores become sliding windows whose aged
         # ranges spill at level boundaries.  docs/memory.md.
-        self.hbm_headroom = float(
-            hbm_headroom if hbm_headroom is not None else 0.1
-        )
-        if not (0.0 <= self.hbm_headroom < 1.0):
-            raise ValueError(
-                f"hbm_headroom must be in [0, 1): {self.hbm_headroom}"
-            )
         self.spill_compress = (
             True if spill_compress is None else bool(spill_compress)
         )
-        self.miss_batch = int(miss_batch or (1 << 15))
-        if self.miss_batch < 1:
-            raise ValueError(f"miss_batch must be >= 1: {self.miss_batch}")
         self._spill_dir_arg = spill_dir
         self.tstore: Optional[TieredStore] = None
         # log-shift chunk (tiered log windows slide like the rows)
@@ -515,7 +439,7 @@ class DeviceChecker:
             # the initial tiers while the worst-case resident bytes
             # stay inside the effective budget — deterministic, so
             # prewarm walks exactly the reachable (capped) staircase
-            eff = int(self.hbm_budget * (1.0 - self.hbm_headroom))
+            eff = int(self.hbm_budget * (1.0 - HBM_HEADROOM))
             capv_abs = max(self.SCAP + self.ACAP, self.ACAP * 2)
             capl_abs = max(
                 self.SCAP + self.APAD, self.NCs + self.APAD
@@ -525,7 +449,7 @@ class DeviceChecker:
                 raise ValueError(
                     "hbm_budget too small: the initial tiers need "
                     f"{store_budget.fmt_bytes(self._device_bytes_est(tc, lc, pc))}"
-                    f" (+{self.hbm_headroom:.0%} headroom) but the "
+                    f" (+{HBM_HEADROOM:.0%} headroom) but the "
                     f"budget is {store_budget.fmt_bytes(self.hbm_budget)}"
                     " — raise the budget or shrink sub_batch/"
                     "visited_cap"
@@ -2169,19 +2093,6 @@ class DeviceChecker:
             # inside _restore_frame from the frame's manifest instead
             self._mk_tstore()
             self.tstore.wipe()
-        # online adaptation (r15, tune/online.py): fresh controller
-        # per run, probe schedule reset to the configured baseline —
-        # an adapted pooled checker must not leak its adjustments
-        # into the next job's run
-        self.fps_dense, self.fps_stages = self._fps_base
-        self._adapt_cap = None
-        self._tuner = (
-            tune_online.OnlineController(
-                self.RMAX, self.fps_dense, self.fps_stages
-            )
-            if self.adapt and self.fuse == "level"
-            else None
-        )
         # per-run dispatch accounting baseline (the stage counters in
         # last_stats are lifetime-cumulative): dispatches_per_level in
         # the result reports THIS run's dispatch/level ratio, and
@@ -2255,11 +2166,8 @@ class DeviceChecker:
             rows_window=self.rows_window,
             invariants=list(self.invariant_names),
             resume=resume,
-            # tuned-profile attribution (r15, schema v8): None on
-            # untuned runs — the field itself is always present so
-            # the ledger can split tuned vs default trajectories
-            profile_sig=self.profile_sig,
-            adapt=self.adapt,
+            # REQUIRED since schema v8, a constant null
+            profile_sig=None,
             # tiered-store budget (r16, schema v9): None on untiered
             # runs — always present so spill trajectories split
             hbm_budget=self.hbm_budget,
@@ -2328,8 +2236,8 @@ class DeviceChecker:
         with self._clock.phase("init"):
             frame = self._start(t0, seed, resume)
         # everything of the level loop that is no phase of its own
-        # (level replay, telemetry emits, the log line, tuner, fault
-        # polls) is ``account``
+        # (level replay, telemetry emits, the log line, fault polls)
+        # is ``account``
         with self._clock.phase("account"):
             return self._run_recoverable(*frame)
 
@@ -2605,9 +2513,7 @@ class DeviceChecker:
             raise RuntimeError(
                 "fpset probe overflow "
                 f"({int(self._last_fpm[2])} lanes) — "
-                + fpset.schedule_hint(
-                    self.fps_dense, self.fps_stages
-                )
+                + fpset.OVERFLOW_HINT
             )
         return out
 
@@ -2742,7 +2648,6 @@ class DeviceChecker:
             spill_dir=sdir,
             compress=self.spill_compress,
             durable=bool(self.checkpoint_path),
-            miss_batch=self.miss_batch,
         )
 
     def _spill_tier_label(self) -> str:
@@ -2837,7 +2742,7 @@ class DeviceChecker:
     @spans.in_phase("spill")
     def _resolve_cold_misses(self, bufs, flag_acc, n_new):
         """Sieve the flush's hot-filter survivors, resolve them
-        against the cold runs in ``miss_batch``-wide batches, and
+        against the cold runs in ``MISS_BATCH``-wide batches, and
         clear the false-new lanes.  A batch's ``K`` key columns and
         its lanes cross the link in ONE fetch
         (:meth:`_spill_fetch_cols`), after the one sync that reads the
@@ -2855,8 +2760,8 @@ class DeviceChecker:
             n = int(np.asarray(n_dev))
         self._spill_sync_n += 1
         false_lanes = []
-        for off in range(0, n, self.miss_batch):
-            m = min(self.miss_batch, n - off)
+        for off in range(0, n, MISS_BATCH):
+            m = min(MISS_BATCH, n - off)
             *kq, lq = self._spill_fetch_cols((*kc, lanes), m, off)
             with spans.span("spill.lookup"):
                 dup = self.tstore.lookup_keys(kq)
@@ -3579,14 +3484,7 @@ class DeviceChecker:
         if self.rows_window != "all" or nf > self.G:
             lv = 1
         else:
-            # the online controller's adapted ramp cap stays within
-            # [1, RMAX] — inside the compiled kernel's static ramp
-            # vector, so adaptation never re-jits this program
-            lv = (
-                self.RMAX
-                if self._adapt_cap is None
-                else max(1, min(self.RMAX, self._adapt_cap))
-            )
+            lv = self.RMAX
         if self.checkpoint_path:
             lv = min(
                 lv,
@@ -3605,32 +3503,6 @@ class DeviceChecker:
         if self.time_budget_s is not None:
             return max(8 * self.group, 32)
         return 1 << 30
-
-    def _apply_tune(self, adj: Dict) -> None:
-        """Apply one online-controller adjustment at the dispatch
-        boundary and emit the schema-v8 ``tune`` event.  ``fuse_cap``
-        adjusts within the compiled kernel's ramp vector (no re-jit);
-        ``fpset_dense_rounds`` re-keys the megakernel so the NEXT
-        dispatch pays one compile — still never mid-kernel, and
-        discovery order is schedule-independent (min-lane-wins dedup;
-        pinned in tests/test_tune.py)."""
-        knob, new = adj["knob"], adj["to"]
-        if knob == "fuse_cap":
-            self._adapt_cap = int(new)
-        elif knob == "fpset_dense_rounds":
-            self.fps_dense = int(new)
-        else:  # an unknown knob from a future controller: ignore
-            return
-        self.last_stats["tune_adjustments"] = (
-            self.last_stats.get("tune_adjustments", 0) + 1
-        )
-        self.tel.emit(
-            "tune",
-            knob=knob,
-            value=new,
-            prev=adj.get("from"),
-            reason=adj.get("reason"),
-        )
 
     def _replay_flush_faults(self, st, fl_before: int):
         """The megakernel ran its flushes in-device; fire the host
@@ -3765,19 +3637,6 @@ class DeviceChecker:
                     work_compact_elems=int(wd.get("compact_elems", 0)),
                     work_append_rows=int(wd.get("append_rows", 0)),
                 )
-                if self._tuner is not None:
-                    # online adaptation (r15): the dispatch's own
-                    # feedback — levels closed vs asked and the
-                    # running max probe depth — drives knob nudges
-                    # applied BEFORE the next dispatch (never
-                    # mid-kernel); every change is a ``tune`` event
-                    fpml = fpset.fpm_logical(self._last_fpm)
-                    for adj in self._tuner.observe(
-                        levels_closed=int(n_lv),
-                        cap_asked=int(lv_cap),
-                        max_probe_rounds=int(fpml[4]),
-                    ):
-                        self._apply_tune(adj)
                 # ---- per-level accounting replay (the kernel's
                 # lsizes): level records, log lines, and PTT_FAULT
                 # level sites fire for every batched level, in order
@@ -3867,14 +3726,6 @@ class DeviceChecker:
 
     # ------------------------------------------------ checkpoint/resume
 
-    def _model_sig(self) -> str:
-        """Model identity for the checkpoint signature (same contract
-        as the sharded engine's): hand models carry their Constants in
-        ``.c``; compiled specs are identified by module name + constant
-        bindings + lane structure.  Shared with the tuned-profile key
-        (tune/profiles.py) so both layers agree on model identity."""
-        return tune_profiles.model_sig(self.model)
-
     def _config_sig(self) -> str:
         """Everything a frame must agree on to be resumable here: the
         model hash, invariant set, key geometry (fp_bits regime), the
@@ -3883,7 +3734,7 @@ class DeviceChecker:
         (tcap, n_visited, rows_lo) — a resumed run may legally raise
         ``max_states`` or ``row_cap_states``."""
         return ckpt.config_sig(
-            model=self._model_sig(),
+            model=ckpt.model_sig(self.model),
             invariants=self.invariant_names,
             check_deadlock=self.check_deadlock,
             state_bits=self.layout.total_bits,

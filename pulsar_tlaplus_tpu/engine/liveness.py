@@ -51,7 +51,6 @@ from pulsar_tlaplus_tpu.models.compaction import CompactionModel
 from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.ops.dedup import SENTINEL
-from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
 from pulsar_tlaplus_tpu.utils import ckpt, faults
 
 TAG = jnp.uint32(1 << 31)
@@ -111,7 +110,6 @@ class LivenessChecker:
         sweep_group: Optional[int] = None,
         hbm_budget=None,
         spill_compress: Optional[bool] = None,
-        profile=None,
         n_devices: int = 1,
         explorer_kw: Optional[dict] = None,
         max_run: int = 1 << 14,
@@ -155,19 +153,6 @@ class LivenessChecker:
         # threshold the round-5 prefetch gate used).
         if sweep_group is not None and sweep_group < 1:
             raise ValueError(f"sweep_group must be >= 1: {sweep_group}")
-        # Tuned-profile resolution (r15, tune/profiles.py): the
-        # liveness engine owns the sweep knobs; the inner explorer
-        # resolves its own device_bfs profile (``profile`` is
-        # forwarded below).  Explicit ctor knobs always win.  The key
-        # is goal-independent — sweep batching does not depend on
-        # which <>(predicate) is being checked.
-        prof = tune_profiles.resolve(
-            profile, model=model, invariants=(), engine="liveness"
-        )
-        self.profile_sig = prof["sig"] if prof else None
-        _pk = tune_profiles.knobs_for(prof, "liveness")
-        if sweep_group is None:
-            sweep_group = _pk.get("sweep_group")
         self.sweep_group = sweep_group
         # pointer-jumping cap for the sweep's equal-key gid propagation
         # (ADVICE r5): doubling shifts d = 1, 2, ..., p (p = the
@@ -224,11 +209,6 @@ class LivenessChecker:
             inner_kw.setdefault("hbm_budget", hbm_budget)
             if spill_compress is not None:
                 inner_kw.setdefault("spill_compress", spill_compress)
-        if n_devices <= 1:
-            # the single-chip explorer resolves its OWN tuned profile
-            # (keyed engine="device_bfs"); the sharded engine has no
-            # profile support yet
-            inner_kw.setdefault("profile", profile)
         inner_kw.update(explorer_kw or {})
         if n_devices > 1:
             from pulsar_tlaplus_tpu.engine.sharded_device import (
@@ -842,10 +822,8 @@ class LivenessChecker:
         here.  Goal and fairness are NOT part of it: the edge list is
         goal-independent (run_goal reuses it), and the verdict is
         recomputed from the restored edges."""
-        inner = self._checker
-        model_sig = inner._model_sig()
         return ckpt.config_sig(
-            model=model_sig,
+            model=ckpt.model_sig(self._checker.model),
             state_bits=self.model.layout.total_bits,
             key_cols=self.K,
             key_exact=self.keys.exact,
@@ -1159,9 +1137,8 @@ class LivenessChecker:
             device=dev,
             **obs.IMPL_FIELDS,
             config_sig=self._config_sig(),
-            # v8: the liveness engine's own tuned-profile attribution
-            # (the inner explorer's header carries its own)
-            profile_sig=self.profile_sig,
+            # REQUIRED since schema v8, a constant null
+            profile_sig=None,
             hbm_budget=getattr(self._checker, "hbm_budget", None),
             # v10: tenant identity (None outside the daemon)
             tenant=getattr(self, "tenant", None),

@@ -515,8 +515,7 @@ class ShardedChecker:
             n_devices=self.n_shards,
             visited_impl=self.dedup_mode,
             config_sig=self._config_sig(),
-            # v8 envelope: not profile-tuned yet; the field must
-            # still exist (schema v8 run_header contract)
+            # REQUIRED since schema v8, a constant null
             profile_sig=None,
             hbm_budget=None,
             # v10: tenant identity (None outside the daemon)

@@ -317,8 +317,6 @@ class ShardedDeviceChecker:
         checkpoint_path: Optional[str] = None,
         checkpoint_every: int = 5,
         n_slices: int = 1,
-        fpset_dense_rounds: Optional[int] = None,
-        fpset_stages=None,
         telemetry=None,
         heartbeat_s: Optional[float] = None,
     ):
@@ -388,17 +386,13 @@ class ShardedDeviceChecker:
         # keys).  VCAP is "max owned keys per shard before growth"; the
         # table carries TCAP = 2 * VCAP slots so the nk_bound <= VCAP
         # invariant IS the load-factor <= 1/2 contract.
-        # fpset probe schedule: ctor params > PTT_FPSET_SCHEDULE env >
-        # the two-step ladder, by value (sweepable on the real chip
-        # against the fpset_max_probe_rounds telemetry signal).  Not
+        # fpset probe schedule: the two-step ladder, by value.  Not
         # the single-chip engine's halving ladder (PR 37): this
         # engine's programs are traced and lowered again every check,
         # so every step is paid a check, for a flush of 98,304 lanes
         # that holds some 1,852 valid ones (ROADMAP S5, S9 (a))
-        self.fps_dense, self.fps_stages = fpset.resolve_schedule(
-            fpset_dense_rounds, fpset_stages,
-            default_stages=fpset.STAGES_TWO_STEP,
-        )
+        self.fps_dense = fpset.DENSE_ROUNDS
+        self.fps_stages = fpset.STAGES_TWO_STEP
         self.VCAP = self._round_cap(visited_cap)
         self.TCAP = 2 * self.VCAP
         self.SCAP = max_states  # global
@@ -1237,13 +1231,11 @@ class ShardedDeviceChecker:
         self._grow_visited(bufs, n + self.ACAP)
         self._grow_store(bufs, Mp + self.APAD)
         sh = self._shard()
-        tref = [time.time()]
         rows_d = jax.device_put(rows_sh, sh)
         par_d = jax.device_put(par_sh, sh)
         lane_d = jax.device_put(lane_sh, sh)
         nloc_d = jax.device_put(counts.astype(np.int32), sh)
         jax.block_until_ready(rows_d)
-        self._dbg(f"seed H2D ({rows_sh.nbytes >> 20} MB)", tref)
         write = self._seed_write_jit()
         for off in range(0, Mp, SC):
             (
@@ -1253,7 +1245,6 @@ class ShardedDeviceChecker:
                 rows_d, par_d, lane_d, nloc_d, jnp.int32(off),
             )
         jax.block_until_ready(bufs["rows"])
-        self._dbg(f"seed write x{-(-Mp // SC)}", tref)
         st["n_visited"] = jax.device_put(counts.astype(np.int32), sh)
         # key insertion through the regular routed flush (append
         # skipped — rows are already in place); retried wholesale on a
@@ -1288,7 +1279,6 @@ class ShardedDeviceChecker:
                 # so the except below can actually engage — without it
                 # dropped seed keys would masquerade as duplicates
                 stats = self._fetch(st)
-                self._dbg("seed key insert", tref)
                 nk = int(stats[:, 1].sum())
                 break
             except _RouteOverflow:
@@ -1403,32 +1393,10 @@ class ShardedDeviceChecker:
 
     # ------------------------------------------------- checkpoint/resume
 
-    def _model_sig(self) -> str:
-        """Model identity for the checkpoint signature.  Hand models
-        carry their Constants in ``.c``; compiled specs are identified
-        by module name + constant bindings + lane structure (so two
-        different .tla specs can never silently resume each other's
-        frames)."""
-        c = getattr(self.model, "c", None)
-        if c is not None:
-            return repr(c)
-        spec = getattr(self.model, "spec", None)
-        if spec is not None:
-            return repr(
-                (
-                    getattr(spec.module, "name", "?"),
-                    sorted(
-                        (k, repr(v)) for k, v in spec.constants.items()
-                    ),
-                    tuple(getattr(self.model, "lane_labels", ())),
-                )
-            )
-        return type(self.model).__name__
-
     def _config_sig(self) -> str:
         return repr(
             (
-                self._model_sig(),
+                ckpt.model_sig(self.model),
                 self.invariant_names,
                 self.check_deadlock,
                 self.layout.total_bits,
@@ -1936,8 +1904,7 @@ class ShardedDeviceChecker:
             n_slices=self.D,
             **obs.IMPL_FIELDS,
             config_sig=self._config_sig(),
-            # v8 envelope: the sharded engine is not profile-tuned
-            # yet; the field must still exist (schema v8 contract)
+            # REQUIRED since schema v8, a constant null
             profile_sig=None,
             hbm_budget=None,
             # v10: tenant identity (None outside the daemon)
@@ -2143,7 +2110,7 @@ class ShardedDeviceChecker:
             raise RuntimeError(
                 "fpset probe overflow on "
                 f"{int((self._last_fpm[:, 2] > 0).sum())} shard(s) — "
-                + fpset.schedule_hint(self.fps_dense, self.fps_stages)
+                + fpset.OVERFLOW_HINT
             )
         return out
 
@@ -2461,28 +2428,15 @@ class ShardedDeviceChecker:
             ):
                 self._save_checkpoint(bufs, st, level_sizes, lb, nf, t0)
 
-    def _dbg(self, tag, tref):
-        """Per-dispatch wall timing, enabled by SHARDED_TIMING=1 (read
-        per call so callers can toggle it after import)."""
-        import os
-
-        if os.environ.get("SHARDED_TIMING"):
-            now = time.time()
-            self._log(f"      {tag}: +{now - tref[0]:.2f}s")
-            tref[0] = now
-
     def _run_one_level(self, t0, bufs, st, stats, nv, lb, nf, level):
         """Expand level ``level``; returns (stats, nv2, stop)."""
-        tref = [time.time()]
         self._grow_store(bufs, int((lb + nf).max()) + self.G)
-        self._dbg("grow", tref)
         lb_dev = jax.device_put(
             np.asarray(lb, np.int32), self._shard()
         )
         nf_dev = jax.device_put(
             np.asarray(nf, np.int32), self._shard()
         )
-        self._dbg("device_put lb/nf", tref)
         rounds = int(-(-nf.max() // self.G))
         stop = False
         pending = 0
@@ -2506,7 +2460,6 @@ class ShardedDeviceChecker:
                 bufs["arows"], bufs["apar"], bufs["alane"],
                 bufs["aq"], bufs["aq2"], st["dead"], st["rt"],
             ) = out[1:]
-            self._dbg(f"round {r} dispatch", tref)
             w += 1
             if w < self.FLUSH and not last:
                 continue
@@ -2566,11 +2519,9 @@ class ShardedDeviceChecker:
                         + self.APAD,
                     )
             self._flush(bufs, st, w * self.RCV)
-            self._dbg("flush+append dispatch", tref)
             pending += 1
             w = 0
         stats = self._fetch(st)
-        self._dbg("level-end fetch", tref)
         return stats, stats[:, 0].copy(), stop
 
     # ----------------------------------------------------------- control

@@ -8,7 +8,7 @@ signature, :class:`SimulationResult` fields, one behavior round of
 is preserved here as a one-round budget on the streaming engine
 (``max_rounds=1``), so existing callers and tests run unchanged while
 every new capability (budgets, telemetry, checkpoints, the daemon,
-the bench/ledger loop, the tuner) lives in ``sim/engine.py``.
+the bench/ledger loop) lives in ``sim/engine.py``.
 
 Note the r18 PRNG derivation is functional in ``(seed, step,
 walker)`` (the resumability contract), so a given seed explores a
@@ -44,7 +44,6 @@ class Simulator:
             depth=depth,
             seed=seed,
             max_rounds=1,
-            profile=None,  # the one-shot API predates tuned profiles
         )
         self.model = model
         self.invariant_names = self._eng.invariant_names

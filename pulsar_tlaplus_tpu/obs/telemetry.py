@@ -77,13 +77,13 @@ from typing import Callable, Dict, Optional, Tuple, Union
 # sweep's ``sweep`` records carry cumulative sweep work units
 # (``sort_lanes``, ``prop_lanes``, ``compact_elems``); result stats
 # carry the ``work_*`` totals.
-# v8 (round 15, the self-tuning checker): run headers carry
-# ``profile_sig`` — the tuned profile that shaped the run's knobs
-# (null on untuned runs; the field itself is REQUIRED at v8 so the
-# ledger can always split tuned vs default trajectories) — and the
-# online-adaptation controller emits one ``tune`` record per knob
-# adjustment (knob, value, prev, reason) at the dispatch boundary
-# where it applied (tune/online.py; docs/tuning.md).
+# v8 (round 15, the self-tuning checker, taken out by PR 48): run
+# headers carry ``profile_sig`` — then the tuned profile that shaped
+# the run's knobs, a constant null since (the field itself is REQUIRED
+# at v8) — and streams of that time carry one ``tune`` record per knob
+# adjustment of the in-run controller (knob, value, prev, reason).
+# Both stay in the tables below so that committed streams validate;
+# no engine emits ``tune`` any more.
 # v9 (round 16, the tiered state store): run headers carry
 # ``hbm_budget`` — the device-memory byte budget the run was tiered
 # under (null on untiered runs; REQUIRED at v9 like profile_sig so
@@ -241,10 +241,9 @@ FIELD_SINCE: Dict[Tuple[str, str], int] = {
     ("sweep", "prop_lanes"): 7,
     ("sweep", "compact_elems"): 7,
     ("attribution", "stages"): 7,
-    # v8 (round 15): tuned-profile attribution on every run header
-    # (null when no profile was active) and the online-adaptation
-    # ``tune`` record — both gated so every committed v7-and-older
-    # stream stays validator-clean.
+    # v8 (round 15): ``profile_sig`` on every run header (null since
+    # PR 48) and the historic ``tune`` record — both gated so every
+    # committed v7-and-older stream stays validator-clean.
     ("run_header", "profile_sig"): 8,
     ("tune", "knob"): 8,
     ("tune", "value"): 8,
@@ -334,8 +333,8 @@ FIELD_SINCE: Dict[Tuple[str, str], int] = {
     ("spill", "misses_resolved"): 9,
 }
 EVENTS: Dict[str, Tuple[str, ...]] = {
-    # run lifecycle (v8 adds profile_sig — the tuned profile that
-    # shaped the run's knobs, null on untuned runs; v9 adds
+    # run lifecycle (v8 adds profile_sig — historic, a constant
+    # null since PR 48; v9 adds
     # hbm_budget — the tiered-store byte budget, null when untiered)
     "run_header": (
         "engine", "visited_impl", "config_sig", "profile_sig",
@@ -369,9 +368,8 @@ EVENTS: Dict[str, Tuple[str, ...]] = {
     # a run accumulated — the machine-readable input to the calibrated
     # cost model (obs/attribution.py); one record right before result
     "attribution": ("stages",),
-    # online adaptation (r15, tune/online.py): one record per knob
-    # adjustment the dispatch-boundary controller applied — an
-    # adapted run is never silently different from its profile
+    # historic (r15 to PR 48): one record per knob adjustment of the
+    # in-run controller; kept so that committed streams validate
     "tune": ("knob", "value"),
     # tiered state store (r16, store/): one record per eviction/spill
     # boundary with CUMULATIVE per-run counters — the tier the data

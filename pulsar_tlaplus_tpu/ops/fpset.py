@@ -129,7 +129,6 @@ per lane at ~2 and makes stage overflow astronomically unlikely.
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional, Tuple
 
 import jax
@@ -359,9 +358,9 @@ MAX_PROBES = 64
 # real-chip signal is the zero-sync ``fpset_lane_rounds`` counter over
 # ``fpset_valid_lanes`` (lanes presented per valid lane; with
 # ``fpset_max_probe_rounds`` and, a step, ``fpset_step_rounds``:
-# docs/observability.md), and the schedule is sweepable without code
-# edits: engine/FPSet ctor params, or the ``PTT_FPSET_SCHEDULE`` env
-# override parsed by :func:`resolve_schedule` (round 10).
+# docs/observability.md); ``scripts/profile.py ladder`` times other
+# ladders at one flush shape by handing them to
+# :func:`lookup_or_insert` as arguments.
 #
 # ``STAGES`` narrows by HALVES from 1/4 to 1/64 (PR 37): a step hands
 # over once what is pending fits the next, so with halving steps a
@@ -394,87 +393,25 @@ STAGES = ((4, 16), (8, 24), (16, 32), (32, 40), (64, 48), (256, MAX_PROBES))
 STAGES_TWO_STEP = ((4, 16), (64, MAX_PROBES))
 
 
-def parse_schedule(spec: str) -> Tuple[int, Tuple[Tuple[int, int], ...]]:
-    """Parse a probe-schedule spec ``"DENSE[,DIV:LIMIT]*"`` — e.g. the
-    default is ``"4,4:16,8:24,16:32,32:40,64:48,256:64"`` (at most 4
-    dense rounds, then a 1/4-width stage probing to round 16 at the
-    latest, halving stages down to 1/64, and a 1/256-width stage to
-    round 64).
-    Raises ValueError with the offending token on malformed input."""
-    parts = [p.strip() for p in spec.split(",") if p.strip()]
-    if not parts:
-        raise ValueError(f"empty fpset schedule: {spec!r}")
-    try:
-        dense = int(parts[0])
-    except ValueError:
-        raise ValueError(
-            f"fpset schedule must start with the dense round count "
-            f"(got {parts[0]!r} in {spec!r})"
-        ) from None
-    stages = []
-    for tok in parts[1:]:
-        try:
-            div_s, limit_s = tok.split(":", 1)
-            div, limit = int(div_s), int(limit_s)
-        except ValueError:
-            raise ValueError(
-                f"bad fpset schedule stage {tok!r} (want DIV:LIMIT) "
-                f"in {spec!r}"
-            ) from None
-        if div < 2 or limit < 1:
-            raise ValueError(
-                f"bad fpset schedule stage {tok!r} (DIV >= 2, "
-                f"LIMIT >= 1) in {spec!r}"
-            )
-        stages.append((div, limit))
-    if dense < 1:
-        raise ValueError(f"fpset dense rounds must be >= 1: {spec!r}")
-    return dense, tuple(stages)
-
-
-def schedule_hint(dense_rounds, stages) -> str:
-    """Remediation hint for a probe-overflow abort.  Under the default
-    schedule an overflow means the table broke its load-factor contract
-    (the capacity is the lever); under a custom schedule — notably a
-    dense-only or LIMIT-truncated sweep via ``PTT_FPSET_SCHEDULE`` —
-    the truncated probe budget is the likelier culprit, so name it
-    instead of blaming visited_cap."""
-    if int(dense_rounds) == DENSE_ROUNDS and tuple(stages) in (
-        STAGES, STAGES_TWO_STEP
-    ):
-        return (
-            "raise visited_cap (the table broke its load-factor "
-            "contract)"
-        )
-    sched = ",".join(
-        [str(int(dense_rounds))]
-        + [f"{d}:{limit}" for d, limit in stages]
-    )
-    return (
-        f"the active probe schedule '{sched}' (ctor / "
-        "PTT_FPSET_SCHEDULE) truncates probing — raise its round "
-        "LIMITs, add a stage, or raise visited_cap"
-    )
+# what a probe-overflow abort tells the user to do: under the module's
+# ladders an overflow means the table broke its load-factor contract
+OVERFLOW_HINT = (
+    "raise visited_cap (the table broke its load-factor contract)"
+)
 
 
 def resolve_schedule(
     dense_rounds: Optional[int] = None, stages=None,
-    default_stages=STAGES,
 ) -> Tuple[int, Tuple[Tuple[int, int], ...]]:
-    """The effective probe schedule: explicit ctor values win, then the
-    ``PTT_FPSET_SCHEDULE`` env override (so a real-chip tuning pass can
-    sweep the schedule without code edits), then the module defaults
-    (``default_stages``: the caller's own, ``STAGES_TWO_STEP`` for the
-    sharded engine)."""
-    env = os.environ.get("PTT_FPSET_SCHEDULE")
-    env_dense, env_stages = (
-        parse_schedule(env) if env else (None, None)
-    )
+    """The effective probe schedule: the caller's own values, and the
+    module's defaults for what it left out."""
     if dense_rounds is None:
-        dense_rounds = env_dense if env_dense is not None else DENSE_ROUNDS
+        dense_rounds = DENSE_ROUNDS
     if stages is None:
-        stages = env_stages if env_stages is not None else default_stages
+        stages = STAGES
     return int(dense_rounds), tuple(tuple(s) for s in stages)
+
+
 # stage-capacity floor: the 1/div shrink is a concentration argument
 # that only holds for large batches (binomial tail at nq/16 expected
 # pending vs nq/4 capacity).  Small batches get the full width — for
@@ -1240,9 +1177,6 @@ class FPSet:
         self.cols = empty_cols(cap, ncols)
         self.ncols = ncols
         self.n = 0
-        # probe schedule: ctor params > PTT_FPSET_SCHEDULE > defaults
-        # (the real-chip tuning pass sweeps these; the feedback signal
-        # is fpset_max_probe_rounds/avg — docs/observability.md)
         self.dense_rounds, self.stages = resolve_schedule(
             dense_rounds, stages
         )

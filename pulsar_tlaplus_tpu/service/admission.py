@@ -41,9 +41,9 @@ REASON_TENANT_RUNNING = "tenant_running"
 REASON_TENANT_STATES = "tenant_states"
 
 # sim-job pricing defaults (mirror sim/engine.py: n_walkers resolves
-# to 1024 when neither the submit nor a tuned profile pins it, depth
-# to 64, and the legacy no-budget contract is ONE depth-round =
-# B * (depth + 1) swarm states)
+# to 1024 when the submit does not pin it, depth to 64, and the
+# legacy no-budget contract is ONE depth-round = B * (depth + 1)
+# swarm states)
 SIM_DEFAULT_WALKERS = 1024
 SIM_DEFAULT_DEPTH = 64
 

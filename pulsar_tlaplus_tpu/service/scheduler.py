@@ -44,7 +44,6 @@ from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.service import admission as admmod
 from pulsar_tlaplus_tpu.service import jobs as jobmod
 from pulsar_tlaplus_tpu.service.jobs import Job
-from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
 from pulsar_tlaplus_tpu.utils import faults
 from pulsar_tlaplus_tpu.warm import plan as warm_plan
 from pulsar_tlaplus_tpu.warm import store as warm_store
@@ -86,13 +85,6 @@ class ServiceConfig:
     frontier_cap: int = 1 << 14
     max_states: int = 50_000_000  # service ceiling + default budget
     checkpoint_every: int = 2
-    # tuned-profile policy (r15, tune/profiles.py): "auto" resolves a
-    # profile per (spec, constants, invariants, backend) at checker
-    # construction — so PREWARM compiles the tuned knobs and a warm
-    # submit gets tuned executables with zero jit compiles; "none"
-    # disables lookups (serve --no-profiles).  The config knobs above
-    # are the fallback for knobs the profile does not pin.
-    profiles: str = "auto"
     # open-network hardening (r17, docs/service.md "Security" /
     # "Admission"): `tcp` = "HOST:PORT" adds an authenticated TCP
     # listener beside the unix socket (port 0 = ephemeral, the bound
@@ -171,8 +163,8 @@ class CheckerPool:
     live in ``jax.jit``'s own process-wide cache, keyed by what they
     read, so a pool MISS on a binding this process has met (another
     ``max_states``, a solo run before it) finds them built.  The pool
-    still holds what that cache does not: the checker's tier sizes and
-    tuned profile, its per-instance programs (stats, slice, shift, seed,
+    still holds what that cache does not: the checker's tier sizes,
+    its per-instance programs (stats, slice, shift, seed,
     trace walk) and the prewarm of its growth tiers; a pool HIT costs
     the same as before (PERF.md §6, PR 33).
     """
@@ -247,40 +239,13 @@ class CheckerPool:
             if ck is None:
                 cfg = self.config
                 model = self.build_model(spec, tlc_cfg)
-                # tuned-profile resolution (r15): the profile's knobs
-                # override the service-wide defaults, so prewarm
-                # compiles (and the compile cache stores) the TUNED
-                # programs — a warm submit against this key runs the
-                # tuned executables with zero jit compiles
-                prof = None
-                if cfg.profiles != "none":
-                    prof = tune_profiles.resolve(
-                        "auto", model=model,
-                        invariants=tuple(invariants),
-                        engine="device_bfs",
-                    )
-                pk = tune_profiles.knobs_for(prof, "device_bfs")
                 ck = DeviceChecker(
                     model,
                     invariants=invariants,
-                    sub_batch=pk.get("sub_batch", cfg.sub_batch),
+                    sub_batch=cfg.sub_batch,
                     visited_cap=cfg.visited_cap,
                     frontier_cap=cfg.frontier_cap,
                     max_states=key[3],
-                    flush_factor=pk.get("flush_factor"),
-                    group=pk.get("group"),
-                    fuse_group=pk.get("fuse_group"),
-                    fpset_dense_rounds=pk.get("fpset_dense_rounds"),
-                    fpset_stages=pk.get("fpset_stages"),
-                    # the engine re-validates the profile against its
-                    # own config signature and records profile_sig on
-                    # every slice's run header
-                    profile=prof,
-                    # online adaptation lazily compiles re-keyed
-                    # kernels post-warm — it would break the warmed
-                    # pool's zero-compile contract, so the daemon
-                    # pins it off regardless of the profile's knob
-                    adapt=False,
                 )
                 self._checkers[key] = ck
             return key, ck
@@ -312,11 +277,6 @@ class CheckerPool:
                     segment_len=sim.get("segment_len"),
                     seed=int(sim.get("seed") or 0),
                     max_steps=sim.get("max_steps"),
-                    profile=(
-                        "auto"
-                        if self.config.profiles != "none"
-                        else None
-                    ),
                 )
                 self._sims[key] = eng
             return key, eng

@@ -6,7 +6,7 @@ never does.  :class:`~pulsar_tlaplus_tpu.sim.engine.StreamingSimulator`
 runs thousands of vectorized random walks per dispatch, continuously,
 under state/time budgets — resumable, deterministic given ``seed``,
 wired through every platform layer (telemetry, metrics, traces,
-checkpoints, the serve daemon, the bench/ledger loop, the tuner).
+checkpoints, the serve daemon, the bench/ledger loop).
 ``engine/simulate.py`` keeps the legacy one-shot API as a thin shim.
 """
 

@@ -81,7 +81,7 @@ _SIM_CKPT_REV = 1
 
 
 def _model_sig(model) -> str:
-    """Model identity for the frame/profile signature (the engines'
+    """Model identity for the frame signature (the engines'
     shared contract: hand models carry their Constants in ``.c``)."""
     c = getattr(model, "c", None)
     if c is not None:
@@ -161,7 +161,6 @@ class StreamingSimulator:
         heartbeat_s: Optional[float] = None,
         progress: bool = False,
         suspend_hook=None,
-        profile="auto",
         tenant: Optional[str] = None,
     ):
         self.model = model
@@ -174,21 +173,8 @@ class StreamingSimulator:
         ]
         if unknown:
             raise ValueError(f"unknown invariant(s): {unknown}")
-        # tuned-profile resolution (r15 contract: explicit knobs win,
-        # the profile fills what the caller left unset, and a profile
-        # for a different config warns-and-ignores)
-        from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
-
-        prof = tune_profiles.resolve(
-            profile, model=model, invariants=self.invariant_names,
-            engine="sim",
-        ) if profile is not None else None
-        pk = tune_profiles.knobs_for(prof, "sim")
-        self.profile_sig = prof["sig"] if prof else None
         if n_walkers is None:
-            n_walkers = int(pk.get("n_walkers", 1024))
-        if segment_len is None:
-            segment_len = pk.get("segment_len")
+            n_walkers = 1024
         if depth < 1:
             raise ValueError(f"depth must be >= 1: {depth}")
         if n_walkers < 1:
@@ -642,7 +628,7 @@ class StreamingSimulator:
             device=dev,
             visited_impl=None,
             config_sig=self._config_sig(),
-            profile_sig=self.profile_sig,
+            profile_sig=None,
             hbm_budget=None,
             tenant=self.tenant,
             warm=getattr(self, "warm", None),

@@ -142,17 +142,13 @@ class TieredStore:
         spill_dir: Optional[str] = None,
         compress: bool = True,
         durable: bool = False,
-        miss_batch: int = 1 << 15,
     ):
         if durable and not spill_dir:
             raise ValueError("durable spill needs a spill_dir")
-        if miss_batch < 1:
-            raise ValueError(f"miss_batch must be >= 1: {miss_batch}")
         self.ncols = int(ncols)
         self.spill_dir = spill_dir
         self.compress = bool(compress)
         self.durable = bool(durable)
-        self.miss_batch = int(miss_batch)
         self.stats = SpillStats()
         # cold key runs: [{n, hi, lo, file, digest, raw, comp}]
         self._runs: List[Dict] = []

@@ -78,6 +78,31 @@ FORMAT_VERSION = 2
 _SENTINEL = np.uint32(0xFFFFFFFF)
 
 
+def model_sig(model) -> str:
+    """Model identity, the first field of a device engine's frame
+    signature: hand models carry their Constants in ``.c``; compiled
+    specs are identified by module name + constant bindings + lane
+    structure (so two different .tla specs can never resume each
+    other's frames).  The string is held byte for byte by
+    ``tests/test_knobs.py``: a frame written before a change to it
+    would no longer restore."""
+    c = getattr(model, "c", None)
+    if c is not None:
+        return repr(c)
+    spec = getattr(model, "spec", None)
+    if spec is not None:
+        return repr(
+            (
+                getattr(spec.module, "name", "?"),
+                sorted(
+                    (k, repr(v)) for k, v in spec.constants.items()
+                ),
+                tuple(getattr(model, "lane_labels", ())),
+            )
+        )
+    return type(model).__name__
+
+
 def config_sig(**fields) -> str:
     """Canonical signature string from keyword fields (sorted, so two
     call sites building the same logical config always agree)."""

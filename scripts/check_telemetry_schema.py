@@ -37,10 +37,10 @@ sizes match the result's ``level_sizes`` and, on clean runs, sum to
 its distinct-state count; r14: v7 ``fuse`` records carry per-dispatch
 work-unit deltas, ``sweep`` records cumulative sweep work units, and
 the new ``attribution`` record the per-stage work totals; r15: v8
-run headers carry ``profile_sig`` — the tuned profile that shaped
-the run's knobs, null on untuned runs — and the online-adaptation
-controller emits ``tune`` records (knob, value) at the dispatch
-boundaries where adjustments applied; r16: v9 run headers carry
+run headers carry ``profile_sig`` (historic: the tuned profile that
+shaped the run's knobs; null in every stream since PR 48 took the
+tuner out) and streams of that time carry ``tune`` records (knob,
+value) of its in-run controller; r16: v9 run headers carry
 ``hbm_budget`` — the tiered-store byte budget, null on untiered runs
 — and tiered engines emit ``spill`` records whose counters
 (keys/rows evicted, raw/compressed bytes, transfer seconds, misses
@@ -63,8 +63,6 @@ older streams stay clean).  ``--trace``
 validates an exported Perfetto trace file's event structure instead
 (obs/trace.py); ``--ledger`` validates cross-run regression ledger
 files (obs/ledger.py — record structure + digest integrity);
-``--profile`` validates tuned-profile JSON files (tune/profiles.py —
-format version, engine-known knobs, filename/sig agreement);
 ``--tokens`` validates daemon tokens.json files (service/auth.py —
 tokens_v, non-empty tenants, unique tokens/tenants, reserved-name
 and token-length rules); ``--warm`` validates warm-artifact
@@ -484,12 +482,6 @@ def main(argv=None) -> int:
         "+ digest integrity instead of the telemetry stream schema",
     )
     ap.add_argument(
-        "--profile", action="store_true",
-        help="treat the .json files as tuned-profile files (cli.py "
-        "tune output) and validate their structure against the "
-        "profile schema (tune/profiles.py)",
-    )
-    ap.add_argument(
         "--tokens", action="store_true",
         help="treat the .json files as daemon tokens.json files "
         "(serve --tokens) and validate their shape (service/auth.py)",
@@ -544,10 +536,6 @@ def main(argv=None) -> int:
             from pulsar_tlaplus_tpu.obs.trace import validate_trace
 
             errors += validate_trace(p)
-        elif args.profile:
-            from pulsar_tlaplus_tpu.tune.profiles import validate_file
-
-            errors += validate_file(p)
         elif args.tokens:
             from pulsar_tlaplus_tpu.service.auth import (
                 validate_tokens_file,

@@ -886,7 +886,11 @@ def cmd_ladder(args):
             mat = None
             if "@" in spec:
                 spec, mat = spec.split("@")
-            dense, stages = fpset.parse_schedule(spec)
+            dense, *steps = spec.split(",")
+            dense = int(dense)
+            stages = tuple(
+                tuple(int(x) for x in step.split(":")) for step in steps
+            )
             (lanes, valid_n, failed), med = timed(
                 f"{spec} {mat or ''}", flushes(dense, stages, mat), table,
                 reps=args.timed_reps,
@@ -1345,8 +1349,10 @@ def main(argv=None):
         "--schedules",
         default="4,4:16,64:64;4,4:16,16:32,64:64;"
         "4,4:16,8:24,16:32,32:48,64:64",
-        help="';'-separated PTT_FPSET_SCHEDULE specs, each optionally "
-        "'@shift' / '@roll' / '@gather' for its compactions")
+        help="';'-separated ladders 'DENSE[,DIV:LIMIT]*' (full-width "
+        "round ceiling, then shrink divisor and round ceiling a step), "
+        "each optionally '@shift' / '@roll' / '@gather' for its "
+        "compactions")
     pd.add_argument("--out", default="chiprun_out/ladder.jsonl")
     pd.set_defaults(fn=cmd_ladder)
 

@@ -7,7 +7,6 @@ chip_smoke.py.  Must run before jax is imported anywhere.
 
 import json
 import os
-import tempfile
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # The suite keeps a compile-time threshold of its own, set the way a
@@ -18,13 +17,6 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # they build are never kept from one test, worker or run to the next.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0.5")
 
-# Tuned-profile hermeticity (r15): CLI/daemon paths resolve profiles
-# from PTT_TUNE_DIR (default ~/.ptt_profiles) — a stray profile on the
-# developer's machine must never reshape pinned test geometry, and
-# adaptation must never default on mid-suite.  Set unconditionally
-# (not setdefault): subprocess-driven CLI tests inherit this env.
-os.environ["PTT_TUNE_DIR"] = tempfile.mkdtemp(prefix="ptt_test_profiles_")
-os.environ.pop("PTT_TUNE_ADAPT", None)
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (

@@ -92,16 +92,18 @@ def tight_hbm_budget(checker_ctor, slack=4096):
     """A budget just above a checker shape's initial-tier minimum —
     tiers pinned at their smallest, so a tiered run MUST spill.
     ``checker_ctor(hbm_budget)`` builds a throwaway probe checker with
-    the workload's exact shape knobs; the 0.9 divisor mirrors the
-    engine's default ``hbm_headroom=0.1``.  One definition so every
+    the workload's exact shape knobs; the divisor is the
+    engine's ``HBM_HEADROOM``.  One definition so every
     spill drill/test stays in lockstep with the engine's byte
     arithmetic (tests/test_store.py, tests/test_subscription.py,
     tests/_survivable_run.py)."""
+    from pulsar_tlaplus_tpu.engine import device_bfs
+
     probe = checker_ctor("1G")
     return (
         int(
             probe._device_bytes_est(probe.TCAP, probe.LCAP, probe.PCAP)
-            / (1.0 - probe.hbm_headroom)
+            / (1.0 - device_bfs.HBM_HEADROOM)
         )
         + slack
     )

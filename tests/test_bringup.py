@@ -150,18 +150,6 @@ def test_native_build_is_keyed_on_source_hash(tmp_path):
     assert os.stat(out).st_mtime_ns != first
 
 
-def test_predict_unknown_device_is_an_error():
-    """Host-link figures are measured per device kind; an unknown
-    accelerator never inherits another device's."""
-    from pulsar_tlaplus_tpu.tune import predict
-
-    ref = {"backend": "tpu", "device_kind": "TPU v9"}
-    with pytest.raises(ValueError, match="TPU v9"):
-        predict._device_link(ref, {}, "rtt_s")
-    cal = {"rtt_s": 0.002}
-    assert predict._device_link(ref, cal, "rtt_s") == 0.002
-
-
 @pytest.mark.parametrize(
     "flag,value",
     [

@@ -228,24 +228,6 @@ def test_sharded_prewarm_compiles_every_tier_before_run():
 # ---- fpset probe-schedule exposure ----------------------------------
 
 
-def test_fpset_schedule_parse_and_env_override(monkeypatch):
-    assert fpset.parse_schedule("4,4:16,16:64") == (
-        4, ((4, 16), (16, 64))
-    )
-    with pytest.raises(ValueError, match="DIV:LIMIT"):
-        fpset.parse_schedule("4,banana")
-    with pytest.raises(ValueError, match="dense round count"):
-        fpset.parse_schedule("x,4:16")
-    monkeypatch.setenv("PTT_FPSET_SCHEDULE", "2,8:32")
-    assert fpset.resolve_schedule() == (2, ((8, 32),))
-    # explicit ctor values always win over the env
-    assert fpset.resolve_schedule(5, ((4, 16),)) == (5, ((4, 16),))
-    monkeypatch.delenv("PTT_FPSET_SCHEDULE")
-    assert fpset.resolve_schedule() == (
-        fpset.DENSE_ROUNDS, fpset.STAGES
-    )
-
-
 def test_fpset_custom_schedule_is_exact():
     """A non-default probe schedule changes cost, never semantics:
     same winners as the defaults on an adversarial duplicate batch."""
@@ -261,21 +243,6 @@ def test_fpset_custom_schedule_is_exact():
     got_t = np.asarray(s_tuned.insert(kcols))
     assert np.array_equal(got_d, got_t)
     assert s_default.n == s_tuned.n == len(pool)
-
-
-def test_engine_schedule_env_round_trips(monkeypatch):
-    """An engine built under PTT_FPSET_SCHEDULE runs the same search
-    (exact counts) with the swept schedule."""
-    monkeypatch.setenv("PTT_FPSET_SCHEDULE", "2,4:32")
-    c = SMALL_CONFIGS["producer_on"]
-    ck = DeviceChecker(
-        CompactionModel(c), invariants=(), sub_batch=64,
-        visited_cap=1 << 10, frontier_cap=1 << 10,
-    )
-    assert ck.fps_dense == 2 and ck.fps_stages == ((4, 32),)
-    r = ck.run()
-    want = pe.check(c, invariants=())
-    assert r.distinct_states == want.distinct_states
 
 
 # ---- compact telemetry fields ---------------------------------------

@@ -698,30 +698,6 @@ def test_sharded_engine_and_rehash_keep_the_two_step_ladder(monkeypatch):
     assert seen == [(fpset.DENSE_ROUNDS, fpset.STAGES_TWO_STEP)]
     assert fpset.rhm_logical(rhm)[:2] == (0, len(keys))
     _assert_holds_exactly(new, keys)
-    # the env override still reaches both engines, and a ctor value wins
-    monkeypatch.setenv("PTT_FPSET_SCHEDULE", "2,8:32")
-    assert fpset.resolve_schedule(
-        default_stages=fpset.STAGES_TWO_STEP
-    ) == (2, ((8, 32),))
-    assert fpset.resolve_schedule(
-        3, ((4, 8),), default_stages=fpset.STAGES_TWO_STEP
-    ) == (3, ((4, 8),))
-
-
-def test_predict_mirrors_the_default_schedule():
-    """``tune/predict.py`` prices the module's default ladder (it
-    mirrors the constants so that it imports without JAX)."""
-    from pulsar_tlaplus_tpu.tune import predict
-
-    assert predict._DENSE_DEFAULT == fpset.DENSE_ROUNDS
-    assert predict._STAGES_DEFAULT == fpset.STAGES == (
-        (4, 16), (8, 24), (16, 32), (32, 40), (64, 48), (256, 64)
-    )
-    f = predict.schedule_lane_factor
-    # full width to round 4, then a quarter, an eighth, a sixteenth
-    assert f(4, predict._STAGES_DEFAULT, 3.0) == 3.0
-    assert f(4, predict._STAGES_DEFAULT, 10.0) == 4 + 6 / 4
-    assert f(4, predict._STAGES_DEFAULT, 30.0) == 4 + 12 / 4 + 8 / 8 + 6 / 16
 
 
 # ---- the engines on the published oracles ---------------------------

@@ -30,7 +30,7 @@ SIM_PINNED = os.path.join(
 # which is exactly what the duplicate estimator should report)
 SMALL_KW = dict(
     n_walkers=128, depth=16, segment_len=4, seed=3,
-    max_steps=128 * 16 * 3, profile=None,
+    max_steps=128 * 16 * 3,
 )
 
 
@@ -77,11 +77,11 @@ def shipped_model():
 
 def test_segment_len_clamps_to_depth_divisor(small_model):
     s = StreamingSimulator(
-        small_model, depth=48, segment_len=20, profile=None
+        small_model, depth=48, segment_len=20
     )
     assert s.L == 16 and 48 % s.L == 0  # largest divisor <= 20
     s2 = StreamingSimulator(
-        small_model, depth=48, segment_len=500, profile=None
+        small_model, depth=48, segment_len=500
     )
     assert s2.L == 48  # clamped to depth
 
@@ -89,13 +89,12 @@ def test_segment_len_clamps_to_depth_divisor(small_model):
 def test_unknown_invariant_raises(small_model):
     with pytest.raises(ValueError, match="unknown invariant"):
         StreamingSimulator(
-            small_model, invariants=("NoSuchInv",), profile=None
+            small_model, invariants=("NoSuchInv",)
         )
 
 
 def test_default_budget_is_one_round(small_model):
-    s = StreamingSimulator(small_model, n_walkers=8, depth=4,
-                           profile=None)
+    s = StreamingSimulator(small_model, n_walkers=8, depth=4)
     assert s.max_rounds == 1
 
 
@@ -104,7 +103,7 @@ def test_one_round_contract_spans_multiple_segments(small_model):
     a round spans several segments (steps are swarm-total: one round =
     B * depth, not depth — the r18 review regression)."""
     r = StreamingSimulator(
-        small_model, n_walkers=16, depth=64, profile=None
+        small_model, n_walkers=16, depth=64
     ).run()
     assert r.steps == 16 * 64
     assert r.states_visited == 16 * 65
@@ -128,13 +127,12 @@ def test_resume_restores_frame_budgets(small_model, tmp_path):
     r1 = StreamingSimulator(
         small_model, n_walkers=128, depth=16, segment_len=4, seed=3,
         max_steps=budget, checkpoint_path=ck, suspend_hook=hook,
-        profile=None,
     ).run()
     assert r1.stop_reason == "suspended" and r1.steps < budget
     # note: NO budget args — the frame must supply them
     r2 = StreamingSimulator(
         small_model, n_walkers=128, depth=16, segment_len=4, seed=3,
-        checkpoint_path=ck, profile=None,
+        checkpoint_path=ck,
     ).run(resume=True)
     assert r2.steps == budget
     assert r2.stop_reason == "step_budget"
@@ -258,7 +256,7 @@ c = pe.Constants(message_sent_limit=2, compaction_times_limit=2,
                  model_producer=True)
 StreamingSimulator(CompactionModel(c), n_walkers=128, depth=16,
                    segment_len=4, seed=3, max_steps=128*16*3,
-                   profile=None, telemetry={stream!r},
+                   telemetry={stream!r},
                    checkpoint_path={ck!r}, checkpoint_every=1).run()
 """
     env = dict(os.environ, PTT_FAULT="kill@segment:4",
@@ -291,18 +289,21 @@ StreamingSimulator(CompactionModel(c), n_walkers=128, depth=16,
 
 def test_sim_finds_leak_bug_pinned(shipped_model, tmp_path):
     """The retention-leak bug config (CompactedLedgerLeak, published
-    diameter 12) found within a pinned (seed, n_walkers, depth)
-    budget; the trace replays state-for-state through the interpreter;
+    diameter 12: a walk's trace is no shorter) found within a pinned
+    (seed, n_walkers, depth) budget; the trace replays state-for-state
+    through the interpreter;
     a deterministic re-run yields the identical discovery."""
     kw = dict(
-        n_walkers=256, depth=32, segment_len=16, seed=1, profile=None,
+        n_walkers=256, depth=32, segment_len=16, seed=1,
         invariants=("TypeSafe", "CompactedLedgerLeak"),
     )
     st = str(tmp_path / "leak.jsonl")
     r = StreamingSimulator(shipped_model, telemetry=st, **kw).run()
     assert r.violation == "CompactedLedgerLeak"
     assert r.stop_reason == "violation" and not r.truncated
-    assert len(r.trace) == 12  # the published shortest-diameter shape
+    # a random walk promises a real trace to the violation, not the
+    # shortest: the published diameter is the lower bound
+    assert len(r.trace) >= 12
     assert r.verified is True
     assert_valid_counterexample(
         pe.SHIPPED_CFG, r.trace, r.trace_actions, "CompactedLedgerLeak"
@@ -320,7 +321,7 @@ def test_sim_finds_dup_null_key_bug_pinned(shipped_model):
     """The dup-null-key bug config (DuplicateNullKeyMessage, published
     diameter 4) found within a pinned budget, interpreter-replayed."""
     kw = dict(
-        n_walkers=256, depth=16, segment_len=8, seed=0, profile=None,
+        n_walkers=256, depth=16, segment_len=8, seed=0,
         invariants=("DuplicateNullKeyMessage",),
     )
     r = StreamingSimulator(shipped_model, **kw).run()
@@ -389,7 +390,7 @@ def test_daemon_two_job_slice_with_sim_solo_parity(
     assert j1.state == "done" and j2.state == "done"
     assert j1.suspends >= 1 and j2.suspends >= 1  # genuine slicing
     r_solo = StreamingSimulator(
-        small_model, profile=None,
+        small_model,
         **{
             "n_walkers": 128, "depth": 16, "segment_len": 4,
             "seed": 3, "max_steps": 128 * 16 * 6,
@@ -598,44 +599,6 @@ def test_bench_schema9_requires_sim_keys():
     assert checker.validate_bench_artifact(d8, path="v8") == []
 
 
-# ----------------------------------------------------- tuned profile
-
-
-def test_sim_profile_resolution_and_explicit_wins(
-    small_model, tmp_path, monkeypatch
-):
-    from pulsar_tlaplus_tpu.tune import profiles as tune_profiles
-
-    monkeypatch.setenv("PTT_TUNE_DIR", str(tmp_path))
-    sig = tune_profiles.profile_key(
-        model=small_model, invariants=("TypeSafe",), engine="sim",
-    )
-    prof = tune_profiles.build(
-        sig=sig, engine="sim",
-        backend=tune_profiles.default_backend(),
-        knobs={"n_walkers": 512, "segment_len": 8}, spec="compaction",
-    )
-    tune_profiles.save(prof)
-    s = StreamingSimulator(
-        small_model, invariants=("TypeSafe",), depth=16
-    )
-    assert s.profile_sig == sig and s.B == 512 and s.L == 8
-    # explicit knobs win over the profile
-    s2 = StreamingSimulator(
-        small_model, invariants=("TypeSafe",), depth=16, n_walkers=64
-    )
-    assert s2.B == 64
-    # a wrong-engine profile warns-and-ignores
-    bad = dict(prof, engine="device_bfs")
-    path = tune_profiles.path_for(sig)
-    with open(path, "w") as f:
-        json.dump(bad, f)
-    s3 = StreamingSimulator(
-        small_model, invariants=("TypeSafe",), depth=16
-    )
-    assert s3.profile_sig is None and s3.B == 1024
-
-
 # ------------------------------------------------------- CLI surface
 
 
@@ -650,7 +613,6 @@ def test_cli_simulate_subcommand(tmp_path, capsys):
         [
             "simulate", "compaction", "-config", cfg, "-walkers", "64",
             "-depth", "8", "-seed", "5", "-cpu", "-telemetry", st,
-            "-no-profile",
         ]
     )
     out = capsys.readouterr().out
@@ -675,7 +637,7 @@ def test_cli_check_simulate_routes_streaming_engine(
         [
             "check", tla, "-config", cfg, "-simulate", "64",
             "-depth", "8", "-sim-seed", "5", "-cpu",
-            "-telemetry", st, "-no-profile",
+            "-telemetry", st,
         ]
     )
     out = capsys.readouterr().out

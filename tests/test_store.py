@@ -670,7 +670,7 @@ def test_tiered_liveness_lasso_verdict_from_cold_rows():
     )
 
 
-# ------------------------------------------------ ledger / tuner ties
+# ------------------------------------------------------- ledger ties
 
 
 def test_ledger_gate_spill_keys_pinned_baseline(tmp_path):
@@ -710,65 +710,3 @@ def test_ledger_gate_spill_keys_pinned_baseline(tmp_path):
     assert rc == 1
     v = ledger.gate(cur, bad, threshold=0.1, keys=tuple(keys))
     assert {x["key"] for x in v} == {"spill_bytes_per_state"}
-
-
-def test_tune_space_and_predict_price_spill_knobs():
-    from pulsar_tlaplus_tpu.tune import predict as tp
-    from pulsar_tlaplus_tpu.tune import space as ts
-
-    m = CompactionModel(SMALL_CONFIGS["producer_on"])
-    plain = ts.candidates(m)
-    spill = ts.candidates(m, spill=True)
-    assert len(spill) > len(plain)
-    assert any("miss_batch" in c for c in spill)
-    ref = {
-        "backend": "cpu", "work": {"expand_rows": 1000},
-        "level_sizes": [1, 10, 100], "sub_batch": 64,
-        "fuse_group": 8, "flush_factor": 1, "group": 4, "A": 7,
-        "dense_rounds": 4, "stages": ((4, 16), (16, 64)),
-        "avg_probe_rounds": 1.5, "distinct_states": 111,
-        "spill_bytes_raw": 10_000_000, "spill_bytes_comp": 3_000_000,
-        "spill_misses_resolved": 50_000, "spill_compress": True,
-        "miss_batch": 1 << 15,
-    }
-    cal = {"units": {}, "rtt_s": 0.001, "link_bytes_per_s": 1e6}
-    p_comp = tp.predict_candidate({}, ref, cal)
-    p_raw = tp.predict_candidate({"spill_compress": False}, ref, cal)
-    # uncompressed candidates cross more bytes -> cost more
-    assert p_raw["spill_s"] > p_comp["spill_s"] > 0
-    # narrower miss batches pay more resolution syncs
-    p_narrow = tp.predict_candidate({"miss_batch": 1 << 10}, ref, cal)
-    assert p_narrow["spill_s"] > p_comp["spill_s"]
-
-
-def test_profile_spill_knobs_validate_and_resolve(
-    tmp_path, monkeypatch
-):
-    from pulsar_tlaplus_tpu.tune import profiles as tprof
-
-    monkeypatch.setenv(tprof.TUNE_DIR_ENV, str(tmp_path))
-    m = CompactionModel(SMALL_CONFIGS["producer_on"])
-    sig = tprof.profile_key(
-        model=m, invariants=(), engine="device_bfs", backend="cpu",
-        tiered=True,  # spill knobs live under the tiered regime key
-    )
-    prof = tprof.build(
-        sig=sig, engine="device_bfs", backend="cpu",
-        knobs={
-            "miss_batch": 1 << 14, "spill_compress": False,
-            "hbm_headroom": 0.05,
-        },
-    )
-    path = tprof.save(prof)
-    assert tprof.validate_file(path) == []
-    ck = _mk(
-        SMALL_CONFIGS["producer_on"], hbm_budget="4M",
-        profile=path,
-    )
-    assert ck.miss_batch == 1 << 14
-    assert ck.spill_compress is False
-    assert ck.hbm_headroom == 0.05
-    # a hand-broken range fails validation (the resolver then
-    # warns-and-ignores instead of crashing a ctor)
-    prof["knobs"]["hbm_headroom"] = 2.0
-    assert tprof.validate(prof) != []

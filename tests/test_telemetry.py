@@ -453,6 +453,56 @@ def test_validator_rejects_bad_streams(tmp_path, checker_mod):
     )
 
 
+def _write_stream(path, events):
+    with open(path, "w") as f:
+        for e in events:
+            f.write(json.dumps(e) + "\n")
+    return path
+
+
+def test_profile_sig_is_required_from_v8_and_reads_null(
+    std_run, tmp_path, checker_mod
+):
+    """The header field of the tuner's time: REQUIRED since v8, a
+    constant null since the tuner went; a v7 header without it stays
+    clean (committed streams validate)."""
+    evs = [dict(e) for e in std_run[4]]
+    assert evs[0]["event"] == "run_header"
+    assert evs[0]["profile_sig"] is None and "adapt" not in evs[0]
+    del evs[0]["profile_sig"]
+    errs = checker_mod.validate_stream(
+        _write_stream(str(tmp_path / "bad.jsonl"), evs)
+    )
+    assert errs and "profile_sig" in errs[0]
+    evs[0]["v"] = 7
+    assert checker_mod.validate_stream(
+        _write_stream(str(tmp_path / "v7.jsonl"), evs)
+    ) == []
+
+
+def test_historic_tune_event_still_validates(
+    std_run, tmp_path, checker_mod
+):
+    """No engine emits ``tune`` any more; a stream of the controller's
+    time, with its records, validates, and one without ``knob`` does
+    not."""
+    assert not [e for e in std_run[4] if e["event"] == "tune"]
+    head = dict(std_run[4][0])
+    tune = dict(
+        v=head["v"], event="tune", t=head["t"], seq=head["seq"] + 1,
+        run_id=head["run_id"], knob="fuse_cap", value=4, prev=8,
+        reason="early_exit",
+    )
+    assert checker_mod.validate_stream(
+        _write_stream(str(tmp_path / "ok.jsonl"), [head, tune])
+    ) == []
+    del tune["knob"]
+    errs = checker_mod.validate_stream(
+        _write_stream(str(tmp_path / "bad.jsonl"), [head, tune])
+    )
+    assert errs and "knob" in errs[0]
+
+
 def test_validator_accepts_legacy_bench_artifacts(checker_mod, bench_dir):
     """Driver-wrapper BENCH_*.json files (pre-schema r1-r4, schema-2
     r5) validate under their declared bench_schema."""
