@@ -120,31 +120,44 @@ def _report(r, constants, wall: float, checkpoint=None) -> int:
 def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
     """Spec->kernel compiler path (SURVEY.md §2.2-E1): parse + bind,
     compile Init/Next/invariants to vmapped kernels, run the device BFS
-    engine.  Falls back to the generic interpreter when the spec uses a
-    construct outside the compilable subset."""
+    engine.  A spec that uses a construct outside the compilable subset
+    is checked by the generic interpreter instead, and the check SAYS
+    so ("spec->kernel compiler declined ...; falling back to the generic
+    interpreter") and prints no compiled line: the benchmark's cell
+    ``cli-compiled`` counts such a check as wrong, never as a slow one.
+
+    Parse and bind run under ``ptt:cli.parse``, the ``CompiledSpec``
+    constructor under ``ptt:cli.codegen`` (docs/observability.md "The
+    compiled path")."""
     from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
     from pulsar_tlaplus_tpu.frontend.codegen import CompiledSpec
     from pulsar_tlaplus_tpu.frontend.codegen_ir import CodegenError
     from pulsar_tlaplus_tpu.frontend.interp import Spec
     from pulsar_tlaplus_tpu.frontend.loader import bind_cfg
     from pulsar_tlaplus_tpu.frontend.parser import parse_file
+    from pulsar_tlaplus_tpu.obs import spans
 
     t0 = time.time()
+    t_parse = time.perf_counter()
     try:
-        ast = parse_file(spec_path)
-        consts = bind_cfg(ast, tlc_cfg)
-        interned = consts.pop("__string_interning__", None) or {}
-        spec = Spec(ast, consts)
+        with spans.span("cli.parse"):
+            ast = parse_file(spec_path)
+            consts = bind_cfg(ast, tlc_cfg)
+            interned = consts.pop("__string_interning__", None) or {}
+            spec = Spec(ast, consts)
     except (ValueError, OSError) as e:
         sys.exit(f"tpu-tlc: {e}")
+    parse_s = time.perf_counter() - t_parse
     try:
-        cs = CompiledSpec(spec, invariants=invariants)
+        with spans.span("cli.codegen"):
+            cs = CompiledSpec(spec, invariants=invariants)
     except CodegenError as e:
         print(
             f"tpu-tlc: note: spec->kernel compiler declined ({e}); "
             "falling back to the generic interpreter"
         )
         return _check_interp(args, module, spec_path, tlc_cfg, invariants)
+    cs.codegen_stats["codegen_parse_s"] = round(parse_s, 4)
     print(
         f"tpu-tlc: checking {module} @ {spec_path} via the spec->kernel "
         f"compiler (state width {cs.layout.total_bits} bits, {cs.A} "
@@ -160,27 +173,30 @@ def _check_compiled_spec(args, module, spec_path, tlc_cfg, invariants):
         # the compiled spec routes through the same dispatch as the
         # hand-compiled registry models (round-2 judge item #4)
         return _dispatch_engines(args, cs, None, invariants, tlc_cfg, t0)
-    ck = DeviceChecker(
-        cs,
-        check_deadlock=not args.nodeadlock,
-        **_explorer_tiers(args),
-        max_states=args.maxstates,
-        progress=True,
-        metrics_path=args.metrics,
-        fuse=args.fuse,
-        fuse_group=args.fuse_group,
-        hbm_budget=args.hbm_budget,
-        spill_compress=(False if args.no_spill_compress else None),
-        telemetry=args.telemetry,
-        heartbeat_s=args.progress,
-        xprof_dir=args.xprof,
-        xprof_levels=args.xprof_window,
-    )
     try:
+        with spans.span("cli.engine_init"):
+            ck = DeviceChecker(
+                cs,
+                check_deadlock=not args.nodeadlock,
+                **_explorer_tiers(args),
+                max_states=args.maxstates,
+                progress=True,
+                metrics_path=args.metrics,
+                fuse=args.fuse,
+                fuse_group=args.fuse_group,
+                hbm_budget=args.hbm_budget,
+                spill_compress=(False if args.no_spill_compress else None),
+                telemetry=args.telemetry,
+                heartbeat_s=args.progress,
+                xprof_dir=args.xprof,
+                xprof_levels=args.xprof_window,
+            )
         r = ck.run()
     except ValueError as e:
         sys.exit(f"tpu-tlc: {e}")
-    rc = _report(r, None, time.time() - t0)
+    with spans.span("cli.report"):
+        rc = _report(r, None, time.time() - t0)
+        _print_mode_lines(ck, r)
     if rc == 0 and tlc_cfg.properties:
         rc = _check_properties(args, cs, tlc_cfg.properties, rc)
     return rc
@@ -303,6 +319,36 @@ def recovered_line(st: dict) -> str:
         f"{st['resume_level']} ({st['resume_states']} states): "
         f"{st['resume_levels_run']} levels expanded after it."
     )
+
+
+def compiled_line(module: str, st: dict) -> str:
+    """Which kernels a check of the spec->kernel compiler ran, from the
+    engine's ``last_stats`` (the model's ``codegen_stats`` and the key
+    kind): one line on stdout after the verdict, so that an untraced
+    run can be held to its path and widths (docs/observability.md "The
+    compiled path" has the grammar).  A check through a hand-written
+    model, or one that fell back to the interpreter, prints none."""
+    return (
+        f"Compiled from the .tla: module {module}, state width "
+        f"{st['codegen_state_bits']} bits in {st['codegen_state_words']} "
+        f"words, {st['codegen_lanes']} successor lanes, "
+        f"{st['codegen_initial_states']} initial states, keys "
+        f"{'exact' if st['key_exact'] else 'hashed'}, code generation "
+        f"{st['codegen_s']:.2f} s after {st['codegen_parse_s']:.2f} s "
+        f"of parse and bind."
+    )
+
+
+def _print_mode_lines(ck, r) -> None:
+    """After the verdict, one line for each mode a check ran in that
+    an untraced run is held to: compiled, tiered, recovered."""
+    st = getattr(ck, "last_stats", {})
+    if "codegen_s" in st:
+        print(compiled_line(ck.model.spec.module.name, st))
+    if "spill_tier_ceilings" in st:
+        print(tiered_line(st, r.distinct_states))
+    if "resume_levels_run" in st:
+        print(recovered_line(st))
 
 
 def _print_graph_summary(graph) -> None:
@@ -686,10 +732,7 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         rc = _report(
             r, constants, time.time() - t0, checkpoint=args.checkpoint
         )
-        if "spill_tier_ceilings" in getattr(ck, "last_stats", {}):
-            print(tiered_line(ck.last_stats, r.distinct_states))
-        if "resume_levels_run" in getattr(ck, "last_stats", {}):
-            print(recovered_line(ck.last_stats))
+        _print_mode_lines(ck, r)
     # cfg PROPERTIES are honored automatically after a clean safety pass
     # (TLC checks temporal properties from the same run); the sharded
     # drivers do not keep the state log the liveness engine needs
@@ -2136,7 +2179,13 @@ def _build_parser():
         dest="force_compile",
         action="store_true",
         help="force the spec->kernel compiler path (TPU kernels compiled "
-        "from the .tla, bypassing any hand-written model)",
+        "from the .tla, bypassing any hand-written model; the path of "
+        "every module with no hand-written model).  After the verdict "
+        "one 'Compiled from the .tla: ...' line names the widths; a spec "
+        "outside the compilable subset is checked by the interpreter "
+        "instead and the check says 'falling back to the generic "
+        "interpreter', which the benchmark's cell cli-compiled counts "
+        "as wrong",
     )
     pc.add_argument("-chunk", type=int, default=4096)
     pc.add_argument("-maxstates", type=int, default=200_000_000)
@@ -2163,7 +2212,8 @@ def main(argv=None):
         }[args.cmd](args)
     # host spans of one check (obs/spans.py; with no profiler trace
     # running they cost nothing): ptt:check around ptt:cli.parse,
-    # .build, .engine_init, .report and the engine's own ptt:run
+    # .build (or, through the spec->kernel compiler, .codegen),
+    # .engine_init, .report and the engine's own ptt:run
     from pulsar_tlaplus_tpu.obs import spans
 
     with spans.span("check"):

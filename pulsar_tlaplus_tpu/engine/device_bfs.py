@@ -4447,6 +4447,7 @@ class DeviceChecker:
             hbm_recovered=self._hbm_recovered,
             **self._ckpt_stats(),
             stats_fetches=self._fetch_n,
+            **obs.model_stats(self.model, self.keys),
         )
         if self._resume_level is not None:
             # the levels this resumed run closed on top of its frame's

@@ -2762,6 +2762,7 @@ class ShardedDeviceChecker:
             ),
             grow_rehash_keys=self._rehash_keys,
             grow_rehash_lane_rounds=self._rehash_lane_rounds,
+            **obs.model_stats(self.model, self.keys),
         )
         self.tel.emit(
             "result",

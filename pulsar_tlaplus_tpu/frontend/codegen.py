@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import hashlib
 import itertools
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -2385,6 +2386,7 @@ class CompiledSpec:
     state bit, surfaced by the auto-invariant ``__EvalError__``."""
 
     def __init__(self, spec: Spec, invariants: Tuple[str, ...] = ()):
+        t0 = time.perf_counter()
         self.spec = spec
         spec.check_assumes()
         self.var_descs = infer_var_descs(spec)
@@ -2445,6 +2447,17 @@ class CompiledSpec:
             self.requested_invariants,
         )
         self._check_compiles()
+        # what this constructor measured, carried for the engine's
+        # result stats and the CLI's compiled line (docs/observability.md
+        # "The compiled path"); a caller that parsed the module adds
+        # ``codegen_parse_s``.  Not part of the model's identity
+        self.codegen_stats = dict(
+            codegen_s=round(time.perf_counter() - t0, 4),
+            codegen_state_bits=self.layout.total_bits,
+            codegen_state_words=self.layout.W,
+            codegen_lanes=self.A,
+            codegen_initial_states=self.n_initial,
+        )
 
     def __eq__(self, other):
         return (

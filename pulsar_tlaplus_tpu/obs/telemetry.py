@@ -191,6 +191,18 @@ IMPL_FIELDS: Dict[str, str] = {
     "sieve_impl": "legacy",
 }
 
+
+def model_stats(model, keys) -> Dict[str, object]:
+    """What a model's own constructor measured, for the device engines'
+    ``result.stats``: a ``CompiledSpec`` carries ``codegen_stats`` (the
+    generator's wall and the widths it came to) and, beside them, the
+    key kind the engine chose for those widths (``key_exact`` false:
+    the exact count rests on 64-bit hashes, as TLC's does).  A
+    hand-written model carries nothing and adds nothing."""
+    cg = getattr(model, "codegen_stats", None)
+    return dict(cg, key_exact=bool(keys.exact)) if cg else {}
+
+
 # Authoritative event table: event name -> required fields beyond the
 # base envelope.  Unknown events are legal (forward compatibility) but
 # must still carry the base envelope.

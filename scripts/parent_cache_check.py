@@ -51,6 +51,8 @@ PATHS = {
     "hbm": (_CLI + _SMALL + ["-hbm-budget", "24M"], {}),
     "termination": (_CLI + _SMALL + ["-property", "Termination"], {}),
     "shift": (_CLI + _SMALL, {"PTT_COMPACT_MATERIALIZE": "shift"}),
+    # the spec->kernel compiler's programs (PR 49: the cell cli-compiled)
+    "compiled": (_CLI + _SMALL + ["-compile"], {}),
     # the benchmark's device-bound program (needs the chip)
     "scaled": (
         ["benchmark/run.py", "--workload", "scaled-window", "--seed",
