@@ -38,9 +38,11 @@ Init/Next (lines 188-231) and invariants (236-294) under
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import itertools
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
 
@@ -2377,6 +2379,65 @@ def _encode_funseq(desc, per_pos, dom_vals):
     return ((mk, stacked, radices), n)
 
 
+class _KeptKernel:
+    """One generated kernel of a ``CompiledSpec`` (``successors``, an
+    invariant, ``__EvalError__``), walked out of the AST once for each
+    kind of state it meets.
+
+    A call looks its state up by tree, shapes and dtypes (under
+    ``jax.vmap`` a batched leaf is seen at its element's shape), walks
+    the AST on a kind it has not met, keeps the jaxpr, and answers from
+    the kept jaxpr by ``jax.core.eval_jaxpr``: the engine's expand
+    batches kept equations and runs no generator.  ``_check_compiles``
+    makes the first half of such a call on the dummy state (``trace``),
+    so the one walk a kernel needs is the constructor's, and an
+    unsupported construct fails there.
+
+    The kernel holds its model weakly: a model owns its kernels and no
+    cycle keeps it, or the traces, past its last reference.  A kernel
+    taken off a model does not outlive it: it answers kinds of state it
+    has met, and raises ``ReferenceError`` on a new one."""
+
+    def __init__(self, model, walk):
+        self._model = weakref.ref(model)
+        self._walk = walk
+        self._stats = model.codegen_stats
+        self._kept = {}
+
+    def _walked(self, state):
+        model = self._model()
+        if model is None:
+            raise ReferenceError(
+                "this kernel's CompiledSpec is gone and it has met no "
+                "state of this tree, shapes and dtypes: keep the model "
+                "as long as its kernels are traced"
+            )
+        self._stats["codegen_walks"] += 1
+        return self._walk(model, state)
+
+    def trace(self, state):
+        """(closed jaxpr, output tree) for states of ``state``'s kind;
+        the AST is walked where that kind is new."""
+        leaves, tree = jax.tree_util.tree_flatten(state)
+        kind = tree, tuple(
+            (t.shape, t.dtype, t.weak_type) for t in map(jax.typeof, leaves)
+        )
+        if kind not in self._kept:
+            closed, out = jax.make_jaxpr(self._walked, return_shape=True)(
+                state
+            )
+            self._kept[kind] = closed, jax.tree_util.tree_structure(out)
+        return self._kept[kind]
+
+    def __call__(self, state):
+        closed, out_tree = self.trace(state)
+        self._stats["codegen_replays"] += 1
+        out = jax.core.eval_jaxpr(
+            closed.jaxpr, closed.consts, *jax.tree_util.tree_leaves(state)
+        )
+        return jax.tree_util.tree_unflatten(out_tree, out)
+
+
 class CompiledSpec:
     """Engine-facing compiled model for an arbitrary spec (the device
     BFS protocol: layout/pack/unpack, gen_initial, successors, fused
@@ -2446,12 +2507,26 @@ class CompiledSpec:
             tuple(sorted((k, repr(v)) for k, v in spec.constants.items())),
             self.requested_invariants,
         )
-        self._check_compiles()
         # what this constructor measured, carried for the engine's
         # result stats and the CLI's compiled line (docs/observability.md
         # "The compiled path"); a caller that parsed the module adds
-        # ``codegen_parse_s``.  Not part of the model's identity
-        self.codegen_stats = dict(
+        # ``codegen_parse_s``.  The two counters go on counting after
+        # the constructor: the dictionary is read at result time.  Not
+        # part of the model's identity
+        self.codegen_stats = dict(codegen_walks=0, codegen_replays=0)
+        cls = type(self)
+        self._successors = _KeptKernel(self, cls._walk_successors)
+        self._invariants = {
+            name: _KeptKernel(
+                self, functools.partial(cls._walk_invariant, name=name)
+            )
+            for name in self.requested_invariants
+        }
+        self._invariants["__EvalError__"] = _KeptKernel(
+            self, cls._walk_eval_error
+        )
+        self._check_compiles()
+        self.codegen_stats.update(
             codegen_s=round(time.perf_counter() - t0, 4),
             codegen_state_bits=self.layout.total_bits,
             codegen_state_words=self.layout.W,
@@ -2478,6 +2553,11 @@ class CompiledSpec:
 
     def successors(self, state):
         """state dict -> (stacked successor dicts [A, ...], valid [A])."""
+        return self._successors(state)
+
+    def _walk_successors(self, state):
+        """The code generator for ``Next``: walks its AST and emits the
+        lanes' equations on ``state``."""
         ac = ActionCompiler(self.spec, primed=True)
         cenv = CEnv(
             {
@@ -2516,11 +2596,9 @@ class CompiledSpec:
 
     @property
     def invariants(self):
-        out = {}
-        for name in self.requested_invariants:
-            out[name] = self._invariant_fn(name)
-        out["__EvalError__"] = self._eval_error_fn()
-        return out
+        """name -> state predicate: the same functions on every
+        access."""
+        return dict(self._invariants)
 
     def _compile_invariant(self, name: str, state):
         if name not in self.spec.defs:
@@ -2535,23 +2613,18 @@ class CompiledSpec:
         )
         return c.cbool(body, cenv)
 
-    def _invariant_fn(self, name: str):
-        if name not in self.spec.defs:
-            raise CodegenError(f"spec defines no invariant {name}")
-
-        def fn(state):
-            cv = self._compile_invariant(name, state)
-            ok = cv.data
-            if cv.poison is not FALSE:
-                # poison while evaluating the invariant is an evaluation
-                # error, not a violation of ``name`` — mask it to "ok"
-                # here; ``__EvalError__`` (which re-derives the same
-                # poison, CSE'd by XLA inside the fused check) reports
-                # it with TLC's evaluation-error message instead
-                ok = ok | jnp.asarray(cv.poison)
-            return ok
-
-        return fn
+    def _walk_invariant(self, state, name: str):
+        """The code generator for the invariant ``name``."""
+        cv = self._compile_invariant(name, state)
+        ok = cv.data
+        if cv.poison is not FALSE:
+            # poison while evaluating the invariant is an evaluation
+            # error, not a violation of ``name`` — mask it to "ok"
+            # here; ``__EvalError__`` (which re-derives the same
+            # poison, CSE'd by XLA inside the fused check) reports
+            # it with TLC's evaluation-error message instead
+            ok = ok | jnp.asarray(cv.poison)
+        return ok
 
     @property
     def liveness_goals(self):
@@ -2591,33 +2664,29 @@ class CompiledSpec:
 
         return fn
 
-    def _eval_error_fn(self):
-        """Auto-invariant: no lane reached this state through poisoned
-        Init/Next evaluation (the ``ERR_VAR`` bit), and no requested
-        invariant's own evaluation poisons on it (TLC raises an
-        evaluation error in both cases rather than reporting the
-        invariant as violated)."""
-
-        def fn(state):
-            bad = jnp.asarray(state[ERR_VAR])
-            for name in self.requested_invariants:
-                cv = self._compile_invariant(name, state)
-                if cv.poison is not FALSE:
-                    bad = bad | jnp.asarray(cv.poison)
-            return ~bad
-
-        return fn
+    def _walk_eval_error(self, state):
+        """The code generator for the auto-invariant: no lane reached
+        this state through poisoned Init/Next evaluation (the
+        ``ERR_VAR`` bit), and no requested invariant's own evaluation
+        poisons on it (TLC raises an evaluation error in both cases
+        rather than reporting the invariant as violated)."""
+        bad = jnp.asarray(state[ERR_VAR])
+        for name in self.requested_invariants:
+            cv = self._compile_invariant(name, state)
+            if cv.poison is not FALSE:
+                bad = bad | jnp.asarray(cv.poison)
+        return ~bad
 
     def _check_compiles(self):
-        """Trace every kernel once on a dummy state (host, abstract
+        """Walk every kernel once on a dummy state (host, abstract
         shapes) so unsupported constructs fail at build time, not mid
-        check."""
+        check; the trace each walk makes is kept, and is what the
+        engine's programs are answered from (``_KeptKernel``)."""
         dummy = jax.tree_util.tree_map(
             jnp.asarray, self.gen_initial(jnp.int32(0))
         )
-        jax.eval_shape(self.successors, dummy)
-        for name, fn in self.invariants.items():
-            jax.eval_shape(fn, dummy)
+        for kernel in (self._successors, *self._invariants.values()):
+            kernel.trace(dummy)
 
     # -- trace rendering / replay -------------------------------------
 

@@ -4,9 +4,15 @@ counts, diameters, verdicts, and traces — on the real reference spec
 (/root/reference/compaction.tla) WITHOUT the hand-written model, and on
 the original specs in specs/."""
 
+import functools
 import os
+import weakref
 
+import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax import lax
 
 from pulsar_tlaplus_tpu.engine.device_bfs import DeviceChecker
 from pulsar_tlaplus_tpu.frontend import interp as I
@@ -213,3 +219,167 @@ def test_compiled_original_specs(name):
     got, _cs = _check(spec)
     assert got.distinct_states == want.distinct_states
     assert got.diameter == want.diameter
+
+
+# ---- a kernel is walked out of the AST once a model (ISSUE 50) -----------
+
+SHIPPED_BINDINGS = ["compaction", "bookkeeper", "subscription", "georeplication"]
+
+
+def _shipped(name):
+    """The compiled model of ``specs/<name>.tla`` under its shipped
+    ``.cfg``, invariants and all, and 256 of its reachable states (a
+    breadth-first walk by the WALKED kernel from the first initial
+    states, distinct by packed row) as one batch."""
+    from pulsar_tlaplus_tpu.frontend.loader import bind_cfg
+    from pulsar_tlaplus_tpu.utils.cfg import parse_cfg
+
+    mod = parse_file(os.path.join(SPECS, f"{name}.tla"))
+    with open(os.path.join(SPECS, f"{name}.cfg"), encoding="utf-8") as f:
+        cfg = parse_cfg(f.read())
+    consts = bind_cfg(mod, cfg)
+    consts.pop("__string_interning__", None)
+    cs = CompiledSpec(I.Spec(mod, consts), invariants=tuple(cfg.invariants))
+    n = 256
+    step = jax.jit(jax.vmap(cs._walk_successors))
+    pack = jax.jit(jax.vmap(cs.layout.pack))
+    tree = jax.tree_util
+    init = jax.vmap(cs.gen_initial)(
+        jnp.arange(min(cs.n_initial, n), dtype=jnp.int32))
+    pool = tree.tree_map(np.asarray, init)
+    seen = {bytes(r) for r in np.asarray(pack(init))}
+    at = 0
+    while len(seen) < n and at < len(seen):
+        idx = np.arange(at, at + n) % len(seen)  # a full batch: one shape
+        at = min(at + n, len(seen))
+        succ, valid = step(tree.tree_map(lambda x: jnp.asarray(x[idx]), pool))
+        flat = tree.tree_map(
+            lambda x: np.asarray(x).reshape((-1,) + x.shape[2:]), succ)
+        rows = np.asarray(pack(tree.tree_map(jnp.asarray, flat)))
+        new = []
+        for i in np.flatnonzero(np.asarray(valid).reshape(-1)):
+            if bytes(rows[i]) not in seen:
+                seen.add(bytes(rows[i]))
+                new.append(i)
+        pool = tree.tree_map(
+            lambda p, x: np.concatenate([p, x[new]]), pool, flat)
+    assert len(seen) >= n, (name, len(seen))
+    return cs, tree.tree_map(lambda x: jnp.asarray(x[:n]), pool)
+
+
+def _same_bits(got, want):
+    got, want = jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("name", SHIPPED_BINDINGS)
+def test_a_replayed_kernel_is_the_walked_one_bit_for_bit(name):
+    """``successors`` and every invariant, answered from the trace the
+    constructor kept, equal the code generator's own output on 256
+    reachable states: under ``jax.vmap`` at two batch sizes and
+    unbatched (``lax.map`` hands the kernel one state at its element
+    shapes); no call walks the AST again.  ``invariants`` hands out the
+    same functions on every access."""
+    cs, states = _shipped(name)
+    first, second = cs.invariants, cs.invariants
+    assert list(first) == list(cs.requested_invariants) + ["__EvalError__"]
+    assert all(first[k] is second[k] for k in first)
+    walked = {"successors": cs._walk_successors,
+              "__EvalError__": cs._walk_eval_error}
+    for inv in cs.requested_invariants:
+        walked[inv] = functools.partial(cs._walk_invariant, name=inv)
+    kept = dict(first, successors=cs.successors)
+    before = dict(cs.codegen_stats)
+    quarters = jax.tree_util.tree_map(
+        lambda x: x.reshape((4, 64) + x.shape[1:]), states)
+    for kernel, walk in walked.items():
+        want = jax.jit(jax.vmap(walk))(states)
+        fn = kept[kernel]
+        _same_bits(jax.jit(jax.vmap(fn))(states), want)
+        by64 = jax.jit(lambda q, fn=fn: lax.map(jax.vmap(fn), q))(quarters)
+        _same_bits(jax.tree_util.tree_map(
+            lambda x: x.reshape((256,) + x.shape[2:]), by64), want)
+        _same_bits(jax.jit(lambda s, fn=fn: lax.map(fn, s))(states), want)
+    after = cs.codegen_stats
+    assert after["codegen_walks"] == before["codegen_walks"] == len(walked)
+    assert after["codegen_replays"] - before["codegen_replays"] == (
+        3 * len(walked))
+
+
+def test_kernels_are_walked_in_the_constructor_and_replayed_after(module):
+    """After the constructor ``codegen_walks`` is the number of kernels
+    (``successors``, the invariants asked for, ``__EvalError__``); a
+    trace of ``jax.vmap(cs.successors)`` at two batch sizes and of a
+    ``jax.jit`` that calls it twice walks nothing and counts four
+    replays; a state of another dtype is walked ONCE, by the same path
+    (one more ``codegen_walks``), gives the generator's own answer and
+    is replayed from then on; a kernel that outlives its model answers
+    the kinds of state it has met and refuses a new one by name."""
+    cs = CompiledSpec(
+        _spec(module, pe.SHIPPED_CFG),
+        invariants=("TypeSafe", "CompactionHorizonCorrectness"))
+    counters = ("codegen_walks", "codegen_replays")
+    assert [cs.codegen_stats[k] for k in counters] == [4, 0]
+    for n in (8, 32):
+        batch = jax.vmap(cs.gen_initial)(jnp.arange(n, dtype=jnp.int32))
+        jax.make_jaxpr(jax.vmap(cs.successors))(batch)
+    one = cs.gen_initial(jnp.int32(3))
+    twice = jax.jit(lambda s: (cs.successors(s), cs.successors(s)))
+    (succ, valid), again = twice(one)
+    assert [cs.codegen_stats[k] for k in counters] == [4, 4]
+    _same_bits(again, (succ, valid))
+    _same_bits((succ, valid), jax.jit(cs._walk_successors)(one))
+    # the same state with its 32-bit integers held in 16: a kind of
+    # state the constructor did not meet
+    narrow = jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.int16) if x.dtype == jnp.int32 else x, one)
+    assert any(x.dtype == jnp.int16 for x in jax.tree_util.tree_leaves(narrow))
+    got = jax.jit(cs.successors)(narrow)
+    assert [cs.codegen_stats[k] for k in counters] == [5, 5]
+    _same_bits(got, jax.jit(cs._walk_successors)(narrow))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(valid))
+    jax.make_jaxpr(jax.vmap(cs.successors))(
+        jax.tree_util.tree_map(lambda x: jnp.stack([x, x]), narrow))
+    assert [cs.codegen_stats[k] for k in counters] == [5, 6]
+    # and the model's identity does not know about any of it
+    same = CompiledSpec(
+        _spec(module, pe.SHIPPED_CFG),
+        invariants=("TypeSafe", "CompactionHorizonCorrectness"))
+    assert same == cs and hash(same) == hash(cs)
+    assert [same.codegen_stats[k] for k in counters] == [4, 0]
+    # a kernel holds its model weakly: the last reference frees the
+    # model (no collector), and the kernel left behind says so
+    type_safe = same.invariants["TypeSafe"]
+    gone = weakref.ref(same)
+    del same
+    assert gone() is None
+    assert bool(jax.jit(type_safe)(one))
+    with pytest.raises(ReferenceError, match="CompiledSpec is gone"):
+        jax.jit(type_safe)(narrow)
+
+
+def test_an_unsupported_construct_is_refused_by_the_constructor():
+    """An invariant the generator cannot emit fails where
+    ``_check_compiles`` walks it, as ``CodegenError``, before any
+    check starts (the CLI then falls back to the interpreter)."""
+    from pulsar_tlaplus_tpu.frontend.codegen_ir import CodegenError
+    from pulsar_tlaplus_tpu.frontend.parser import parse_module
+
+    mod = parse_module(
+        """---- MODULE badinv ----
+EXTENDS Naturals, Sequences
+VARIABLES x
+Init == x = 0
+Next == x < 2 /\\ x' = x + 1
+BadInv == Len(x) > 0
+====
+"""
+    )
+    spec = I.Spec(mod, {})
+    assert CompiledSpec(spec).codegen_stats["codegen_walks"] == 2
+    with pytest.raises(CodegenError, match="Len of"):
+        CompiledSpec(spec, invariants=("BadInv",))

@@ -15,6 +15,8 @@ import json
 import os
 import random
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -158,6 +160,32 @@ def test_a_declined_spec_says_so_and_prints_no_line(monkeypatch, capsys):
     assert COMPILED.parse_compiled_line(text) is None
 
 
+def test_a_fresh_process_walks_each_kernel_once(tmp_path):
+    """The first check of a fresh process (ISSUE 50) reads
+    ``codegen_walks`` 4 (``successors``, ``TypeSafe``,
+    ``CompactionHorizonCorrectness``, ``__EvalError__``: all in the
+    constructor: no program hands a kernel a kind of state the
+    constructor did not meet), the engine's seven calls of a kernel are
+    replays, and ``jit_traces`` is under a ceiling
+    20% above what the change reads at this binding: **6,323** (7,590
+    the ceiling), where the tree before it, whose every program ran the
+    code generator again, read **15,689**."""
+    tel = tmp_path / "fresh.jsonl"
+    done = subprocess.run(
+        [sys.executable, "-m", "pulsar_tlaplus_tpu.cli", "check", SPEC,
+         "-config", CFG, "-compile", "-telemetry", str(tel)],
+        cwd=ROOT, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert tlafmt.parse_counts(done.stdout) == (45198, 20)
+    with open(tel, encoding="utf-8") as f:
+        events = [json.loads(x) for x in f if x.strip()]
+    st = [e for e in events if e["event"] == "result"][-1]["stats"]
+    assert [st["codegen_walks"], st["codegen_replays"]] == [4, 7]
+    assert st["jit_body_traces"] > 0  # a fresh process: the units ran
+    assert 0 < st["jit_traces"] < 7590
+
+
 # ---- seeded samples at the cell's first rung: 142 bits, 5 words, 19 lanes
 
 def _interp_values(s):
@@ -245,7 +273,9 @@ def test_the_rung_has_the_cells_widths(rung_4m):
     assert cs.codegen_stats == {
         "codegen_s": cs.codegen_stats["codegen_s"],
         "codegen_state_bits": 142, "codegen_state_words": 5,
-        "codegen_lanes": 19, "codegen_initial_states": 1}
+        "codegen_lanes": 19, "codegen_initial_states": 1,
+        "codegen_walks": 4,
+        "codegen_replays": cs.codegen_stats["codegen_replays"]}
     assert [len(x) for x in levels] == [
         1, 10, 99, 990, 9828, 37665, 59130, 80766]
 
