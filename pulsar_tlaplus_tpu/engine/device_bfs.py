@@ -4423,6 +4423,11 @@ class DeviceChecker:
                     100.0 * self._hot_max / max(nv, 1), 4
                 ),
                 spill_cold_runs=self.tstore.cold_runs,
+                # the one index a lookup reads (PR 51): the seconds and
+                # the count of the merges that built it, the keys in it
+                spill_merge_s=sp.merge_s,
+                spill_merges=int(sp.merges),
+                spill_index_keys=int(sp.index_keys),
                 spill_d2h_bytes=self._spill_d2h_bytes,
                 spill_d2h_padded_bytes=self._spill_d2h_padded_bytes,
                 # table slots summed over the evictions (a roofline's

@@ -16,9 +16,14 @@ Design rules:
   overlap is measured: ``blocked_s`` (time boundaries actually waited)
   over ``transfer_s`` (total encode/write work) is the
   ``spill_overlap_ratio`` the bench artifact carries.
-- **Batched miss resolution.**  :meth:`lookup_keys` resolves a whole
-  sieved batch against every cold run with range-pruned binary
-  searches — O(batch * log(run)) per run, no per-key host loops.
+- **Batched miss resolution, one index.**  Every evicted key is also
+  kept once in ONE sorted lookup index (:func:`_merge_sorted`, a merge
+  an eviction, on the evicting thread); :meth:`lookup_keys` sorts a
+  whole sieved batch and answers it with one binary search a key —
+  O(batch * log(cold keys)) however many runs were evicted, no
+  per-key host loops.  The RUNS stay the unit of everything durable:
+  one record, one encode job, one file, one manifest entry an
+  eviction.
 - **Crash hygiene** (the round-16 bugfix satellite): spill files are
   written to a per-writer-unique ``<name>.tmp.<pid>.<tid>`` and
   ``os.replace``d into place (the utils/ckpt.py frame discipline), so
@@ -57,6 +62,8 @@ from pulsar_tlaplus_tpu.store import compress as codec
 from pulsar_tlaplus_tpu.utils import faults
 
 _TMP_MARK = ".tmp."
+# the lookup index of a store that has evicted nothing: (hi, lo) planes
+_NO_KEYS = (np.zeros((0,), np.uint64), np.zeros((0,), np.uint32))
 
 
 def _digest(blob: bytes) -> str:
@@ -68,6 +75,47 @@ def _atomic_write(path: str, blob: bytes) -> None:
     with open(tmp, "wb") as f:
         f.write(blob)
     os.replace(tmp, path)
+
+
+def _merge_sorted(ahi, alo, bhi, blo):
+    """The union of two ``(hi, lo)``-sorted key planes, sorted, each
+    key once.  A stable merge on ``hi`` (``b``'s keys placed by one
+    search each, in order); an equal-``hi`` block — a 3-column key's,
+    or a key both sides hold — is then put in ``lo`` order and its
+    repeats dropped."""
+    at = np.searchsorted(ahi, bhi, "right") + np.arange(len(bhi))
+    n = len(ahi) + len(bhi)
+    old = np.ones(n, bool)
+    old[at] = False
+    hi, lo = np.empty(n, np.uint64), np.empty(n, np.uint32)
+    hi[at], hi[old] = bhi, ahi
+    lo[at], lo[old] = blo, alo
+    tie = hi[1:] == hi[:-1]
+    if tie.any():
+        blk = np.zeros(n, bool)
+        blk[1:] = tie
+        blk[:-1] |= tie
+        idx = np.nonzero(blk)[0]
+        lo[idx] = lo[idx][np.lexsort((lo[idx], hi[idx]))]
+        keep = np.ones(n, bool)
+        keep[1:] = ~tie | (lo[1:] != lo[:-1])
+        hi, lo = hi[keep], lo[keep]
+    return hi, lo
+
+
+def _find_sorted(hi, lo, qh, ql):
+    """Which of the ``hi``-sorted queries ``(qh, ql)`` are keys of the
+    ``(hi, lo)``-sorted planes: one search a query."""
+    left = np.searchsorted(hi, qh, "left")
+    same = hi.take(left, mode="clip") == qh
+    hit = same & (lo.take(left, mode="clip") == ql)
+    # a 3-column key whose hi is there under another lo (~never): its
+    # equal-hi block may go on, and hold the key further in
+    for t in np.nonzero(same & ~hit)[0]:
+        seg = lo[left[t]: np.searchsorted(hi, qh[t], "right")]
+        p = np.searchsorted(seg, ql[t])
+        hit[t] = p < len(seg) and seg[p] == ql[t]
+    return hit
 
 
 def cleanup_stale_spill(spill_dir: Optional[str]) -> int:
@@ -100,6 +148,10 @@ class SpillStats:
         "bytes_raw", "bytes_comp", "transfer_s", "blocked_s",
         "misses_resolved", "miss_hits", "miss_batches", "lookup_s",
         "joins",
+        # the lookup index (PR 51): seconds merging evicted runs into
+        # it, merges done, keys it holds (keys_evicted less the keys
+        # evicted twice)
+        "merge_s", "merges", "index_keys",
     )
 
     def __init__(self):
@@ -150,8 +202,10 @@ class TieredStore:
         self.compress = bool(compress)
         self.durable = bool(durable)
         self.stats = SpillStats()
-        # cold key runs: [{n, hi, lo, file, digest, raw, comp}]
+        # cold key runs: [{n, file, digest, raw, comp}]; their keys
+        # live in the lookup index alone once the encode job has run
         self._runs: List[Dict] = []
+        self._set_index(*_NO_KEYS)
         # row/log segments: [{lo, hi, arr(s), file(s), digest(s)}]
         self._rows: List[Dict] = []
         self._logs: List[Dict] = []
@@ -183,25 +237,36 @@ class TieredStore:
 
     @property
     def cold_runs(self) -> int:
-        """Sorted runs a lookup walks (they are never merged)."""
+        """Sorted runs evicted: one record, one durable file and one
+        manifest entry each.  A lookup walks none of them: it reads
+        the one index they were merged into."""
         return len(self._runs)
+
+    def _set_index(self, hi, lo) -> None:
+        self._index = (hi, lo)
+        self.stats.index_keys = len(hi)
 
     def evict_keys(self, kcols_np) -> int:
         """Ingest one SORTED evicted key run (dense numpy columns from
-        the device's ``extract_cold``).  Queryable immediately; encode
-        + durable write happen on the background worker."""
+        the device's ``extract_cold``).  Queryable immediately: the
+        run is merged into the lookup index before this returns;
+        encode + durable write happen on the background worker, which
+        is the last holder of the run's own planes."""
         hi, lo = codec.pack_keys(kcols_np)
         n = len(hi)
         if n == 0:
             return 0
         rec: Dict = {
-            "kind": "keys", "n": n, "hi": hi, "lo": lo,
-            "file": None, "digest": None,
+            "kind": "keys", "n": n, "file": None, "digest": None,
             "raw": hi.nbytes + lo.nbytes, "comp": None,
         }
         self._runs.append(rec)
         self.stats.evictions += 1
         self.stats.keys_evicted += n
+        t0 = time.perf_counter()
+        self._set_index(*_merge_sorted(*self._index, hi, lo))
+        self.stats.merges += 1
+        self.stats.merge_s += time.perf_counter() - t0
         self._submit_encode(
             rec, lambda: codec.encode_key_run(hi, lo, self.compress),
             f"keys_{self._next_seq()}.ptsk",
@@ -209,33 +274,18 @@ class TieredStore:
         return n
 
     def lookup_keys(self, kcols_np) -> np.ndarray:
-        """bool mask over the query batch: True = the key is in SOME
-        cold run (a false-new verdict the engine must merge back)."""
+        """bool mask over the query batch, in its order: True = the
+        key was evicted (a false-new verdict the engine must merge
+        back).  The batch is sorted, searched ONCE in the index, and
+        the hits scattered back."""
         t0 = time.perf_counter()
         qhi, qlo = codec.pack_keys(kcols_np)
         member = np.zeros(qhi.shape, bool)
-        for rec in self._runs:
-            hi, lo = rec["hi"], rec["lo"]
-            if not len(hi):
-                continue
-            # range pruning: most runs cover disjoint key ranges only
-            # probabilistically, but the bounds check is nearly free
-            sel = (qhi >= hi[0]) & (qhi <= hi[-1]) & ~member
-            if not sel.any():
-                continue
-            qh = qhi[sel]
-            left = np.searchsorted(hi, qh, "left")
-            right = np.searchsorted(hi, qh, "right")
-            hit = np.zeros(qh.shape, bool)
-            simple = right - left == 1
-            idx = np.clip(left, 0, len(hi) - 1)
-            hit[simple] = lo[idx[simple]] == qlo[sel][simple]
-            wide = np.nonzero(right - left > 1)[0]
-            for t in wide:  # equal-hi blocks (3-col keys, ~never)
-                seg = lo[left[t]: right[t]]
-                p = np.searchsorted(seg, qlo[sel][t])
-                hit[t] = p < len(seg) and seg[p] == qlo[sel][t]
-            member[np.nonzero(sel)[0][hit]] = True
+        if len(qhi) and len(self._index[0]):
+            order = np.argsort(qhi)
+            member[order] = _find_sorted(
+                *self._index, qhi[order], qlo[order]
+            )
         self.stats.misses_resolved += int(len(qhi))
         self.stats.miss_hits += int(member.sum())
         self.stats.miss_batches += 1
@@ -516,13 +566,14 @@ class TieredStore:
         if int(manifest.get("spill_v", 0)) > 1:
             raise ValueError("spill manifest newer than supported")
         self._runs, self._rows, self._logs = [], [], []
+        self._set_index(*_NO_KEYS)
         for e in manifest.get("key_runs", []):
             blob = self._read_verified(e["file"], e["digest"])
             hi, lo = codec.decode_key_run(blob)
             self._runs.append(
                 {
-                    "kind": "keys", "n": int(e["n"]), "hi": hi,
-                    "lo": lo, "file": e["file"], "digest": e["digest"],
+                    "kind": "keys", "n": int(e["n"]),
+                    "file": e["file"], "digest": e["digest"],
                     "raw": int(e["raw"]), "comp": int(e["comp"]),
                 }
             )
@@ -531,6 +582,7 @@ class TieredStore:
                     f"spill run {e['file']}: decoded {len(hi)} keys, "
                     f"manifest says {e['n']}"
                 )
+            self._set_index(*_merge_sorted(*self._index, hi, lo))
         for e in manifest.get("rows", []):
             blob = self._read_verified(e["file"], e["digest"])
             self._rows.append(
@@ -554,7 +606,9 @@ class TieredStore:
                 }
             )
         # cumulative stats continue from the frame (the monotone-
-        # cumulative telemetry contract survives resume)
+        # cumulative telemetry contract survives resume); rebuilding
+        # the index above is no eviction's merge and counts as none,
+        # and a frame from before the index says nothing of its keys
         st = manifest.get("stats") or {}
         for f in SpillStats.FIELDS:
             if f in st:
@@ -569,6 +623,7 @@ class TieredStore:
         run owns it — a dead prior run must not leak disk bytes) and
         reset the in-memory tiers."""
         self._runs, self._rows, self._logs = [], [], []
+        self._set_index(*_NO_KEYS)
         self.stats = SpillStats()
         if not self.spill_dir:
             return

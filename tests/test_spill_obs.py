@@ -37,6 +37,7 @@ NEW_KEYS = (
     "spill_hot_share_max_pct", "spill_cold_runs", "spill_budget_overridden",
     "spill_tier_ceilings", "spill_evict_slots", "spill_joins",
     "spill_fetches", "spill_fetch_planes",
+    "spill_merge_s", "spill_merges", "spill_index_keys",
 )
 COUNTS = (
     "spill_evictions", "spill_keys_evicted", "spill_rows_evicted",
@@ -46,7 +47,7 @@ COUNTS = (
     "spill_bytes_raw", "spill_bytes_comp", "spill_joins",
     "spill_budget_overridden", "spill_tier_ceilings", "stage_sieve_n",
     "stage_unflag_n", "stage_evict_n", "stage_flush_n", "fpset_flushes",
-    "fpset_probe_rounds",
+    "fpset_probe_rounds", "spill_merges", "spill_index_keys",
 )
 
 
@@ -448,6 +449,8 @@ def test_new_counters_are_in_last_stats(tiered, monkeypatch):
     assert st["spill_hot_share_max_pct"] == round(
         100.0 * st["spill_hot_keys_max"] / 1654, 4)
     assert st["spill_cold_runs"] == st["spill_evictions"] >= 1
+    assert st["spill_merges"] == st["spill_evictions"]
+    assert 0 < st["spill_index_keys"] <= st["spill_keys_evicted"]
     assert st["spill_evict_slots"] >= st["spill_evictions"] * (1 << 10)
     assert st["spill_tier_ceilings"] == ck._tier_ceilings
     assert st["spill_budget_overridden"] is ck._budget_overridden
