@@ -339,6 +339,23 @@ def compiled_line(module: str, st: dict) -> str:
     )
 
 
+def simulated_line(st: dict) -> str:
+    """What a check in simulation mode walked, from the engine's
+    ``result.stats``: one line on stdout after the verdict, so that an
+    untraced run can be held to its swarm, its budget, its dump and
+    its walk stream (docs/simulation.md has the grammar).  The digest
+    is the engine's keys-digest of the final walker states: the same
+    seed, walkers, depth and budget give the same one."""
+    return (
+        f"Simulated: {st['sim_walkers']} walkers of depth "
+        f"{st['sim_depth']} in segments of {st['sim_segment_len']} "
+        f"steps, {st['sim_rounds']} rounds, {st['sim_steps']} steps, "
+        f"{st['sim_dump_behaviours']} behaviours dumped "
+        f"({st['sim_dump_mismatches']} replay mismatches), final walker "
+        f"states sha256 {st['sim_keys_digest']}."
+    )
+
+
 def _print_mode_lines(ck, r) -> None:
     """After the verdict, one line for each mode a check ran in that
     an untraced run is held to: compiled, tiered, recovered."""
@@ -417,7 +434,13 @@ def _report_liveness(prop, args, lres) -> int:
 def _report_simulation(sres, constants, checkpoint=None) -> int:
     """TLC-``-simulate``-shaped report + exit code (0 clean, 1
     violation, 3 interrupted — an interrupted walk stream carries no
-    conclusion and resumes with -recover)."""
+    conclusion and resumes with -recover), then the simulated line."""
+    rc = _report_simulation_verdict(sres, constants, checkpoint)
+    print(simulated_line(sres.stats))
+    return rc
+
+
+def _report_simulation_verdict(sres, constants, checkpoint) -> int:
     from pulsar_tlaplus_tpu.utils.render import render_trace
 
     if sres.violation:
@@ -434,15 +457,29 @@ def _report_simulation(sres, constants, checkpoint=None) -> int:
         f"({sres.states_visited} states visited, {sres.steps} steps, "
         f"{sres.walks} completed walks)."
     )
+    # the wall holds each program's first dispatch (trace, lowering,
+    # load): the rate of the dispatches after those stands beside it
+    steady = (
+        f", {sres.steady_steps_per_sec:,.0f} steps/sec after each "
+        "program's first dispatch"
+        if sres.steady_steps_per_sec
+        else ""
+    )
     print(
         f"Finished in {sres.wall_s:.1f}s ({sres.steps_per_sec:,.0f} "
-        f"steps/sec, {sres.walks_per_sec:,.1f} walks/sec)"
+        f"steps/sec{steady}, {sres.walks_per_sec:,.1f} walks/sec)"
         + (
             f"; sampled duplicate ratio ~{sres.dup_ratio_est:.1%}."
             if sres.dup_ratio_est is not None
             else "."
         )
     )
+    if sres.dump_files:
+        print(
+            f"{len(sres.dump_files)} behaviours of the last round "
+            f"written to {sres.dump_files[0]} ... "
+            f"{os.path.basename(sres.dump_files[-1])}."
+        )
     if sres.violation:
         return 1
     if sres.truncated:
@@ -467,6 +504,27 @@ def _report_simulation(sres, constants, checkpoint=None) -> int:
         "exhaustive — absence of violations is inconclusive."
     )
     return 0
+
+
+def _run_simulation(args, make_sim, constants) -> int:
+    """Build and run a ``StreamingSimulator`` for ``check -simulate``
+    and ``simulate`` alike and report it; a dump asked for with a
+    budget that ends off a round boundary is exit 2."""
+    from pulsar_tlaplus_tpu.sim.engine import DumpBudgetError
+
+    try:
+        sres = make_sim().run(resume=args.recover)
+    except DumpBudgetError as e:
+        print(f"tpu-tlc: -sim-dump: {e}", file=sys.stderr)
+        return 2
+    except FileNotFoundError:
+        sys.exit(
+            "tpu-tlc: -recover needs an existing -checkpoint file "
+            f"(got: {args.checkpoint})"
+        )
+    except (ValueError, RuntimeError) as e:
+        sys.exit(f"tpu-tlc: {e}")
+    return _report_simulation(sres, constants, args.checkpoint)
 
 
 def _check_properties(args, model, properties, rc):
@@ -584,8 +642,9 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
         # heartbeat and checkpoint/resume — the legacy one-round semantics are the default budget
         from pulsar_tlaplus_tpu.sim.engine import StreamingSimulator
 
-        try:
-            sim = StreamingSimulator(
+        return _run_simulation(
+            args,
+            lambda: StreamingSimulator(
                 model,
                 invariants=invariants,
                 n_walkers=args.simulate,
@@ -597,16 +656,11 @@ def _dispatch_engines(args, model, constants, invariants, tlc_cfg, t0):
                 telemetry=args.telemetry,
                 heartbeat_s=args.progress,
                 progress=True,
-            )
-            sres = sim.run(resume=args.recover)
-        except FileNotFoundError:
-            sys.exit(
-                "tpu-tlc: -recover needs an existing -checkpoint file "
-                f"(got: {args.checkpoint})"
-            )
-        except (ValueError, RuntimeError) as e:
-            sys.exit(f"tpu-tlc: {e}")
-        return _report_simulation(sres, constants, args.checkpoint)
+                dump_path=args.sim_dump,
+                dump_num=args.sim_dump_num,
+            ),
+            constants,
+        )
     if args.sharded and (
         args.sharded_engine == "device"
         and args.sharded_dedup == "sort"
@@ -1425,8 +1479,9 @@ def _cmd_simulate(args) -> int:
         f"tpu-tlc: simulating {module} ({args.walkers} walkers, depth "
         f"{args.depth}; invariants: {list(invariants) or 'none'})"
     )
-    try:
-        sim = StreamingSimulator(
+    return _run_simulation(
+        args,
+        lambda: StreamingSimulator(
             model,
             invariants=invariants,
             n_walkers=args.walkers,
@@ -1440,16 +1495,30 @@ def _cmd_simulate(args) -> int:
             telemetry=args.telemetry,
             heartbeat_s=args.progress,
             progress=True,
-        )
-        sres = sim.run(resume=args.recover)
-    except FileNotFoundError:
-        sys.exit(
-            "tpu-tlc: -recover needs an existing -checkpoint file "
-            f"(got: {args.checkpoint})"
-        )
-    except (ValueError, RuntimeError) as e:
-        sys.exit(f"tpu-tlc: {e}")
-    return _report_simulation(sres, constants, args.checkpoint)
+            dump_path=args.sim_dump,
+            dump_num=args.sim_dump_num,
+        ),
+        constants,
+    )
+
+
+def _add_sim_dump_args(sp) -> None:
+    """TLC's ``-simulate file=F,num=N`` on ``check -simulate`` and on
+    ``simulate``."""
+    sp.add_argument(
+        "-sim-dump", dest="sim_dump", default=None, metavar="F",
+        help="in simulation mode: after the budget, write behaviours "
+        "of the last completed round, replayed from their key "
+        "streams, to F_<round>_<walker> (TLC's -simulate file=F); the "
+        "budget has to end on a round boundary (a multiple of walkers "
+        "x depth steps), else exit 2",
+    )
+    sp.add_argument(
+        "-sim-dump-num", dest="sim_dump_num", type=int, default=16,
+        metavar="K",
+        help="with -sim-dump: how many walkers, spread evenly over "
+        "the swarm and rotated by the seed (TLC's num=K; default 16)",
+    )
 
 
 def _add_client_args(sp) -> None:
@@ -1921,7 +1990,7 @@ def _build_parser():
     psim.add_argument(
         "-depth", type=int, default=64,
         help="steps per behavior before walkers restart (TLC "
-        "-simulate depth; default 64)",
+        "-simulate depth; default 64, where TLC's -depth defaults to 100)",
     )
     psim.add_argument(
         "-segment", type=int, default=None, metavar="STEPS",
@@ -1945,6 +2014,7 @@ def _build_parser():
         "-time-budget", dest="time_budget", type=float, default=None,
         metavar="SEC", help="wall-clock budget",
     )
+    _add_sim_dump_args(psim)
     psim.add_argument(
         "-checkpoint", default=None,
         help="checkpoint file (.npz): segment-boundary frames; "
@@ -2070,7 +2140,11 @@ def _build_parser():
         metavar="N",
         help="simulation mode: N random walkers instead of exhaustive BFS",
     )
-    pc.add_argument("-depth", type=int, default=64, help="simulation depth")
+    pc.add_argument(
+        "-depth", type=int, default=64,
+        help="with -simulate: steps per behaviour before the walkers "
+        "restart (default 64; TLC's -depth defaults to 100)",
+    )
     pc.add_argument(
         "-segment", type=int, default=None, metavar="STEPS",
         help="with -simulate: steps per device dispatch (clamped to "
@@ -2085,6 +2159,7 @@ def _build_parser():
         help="with -simulate: total step budget across the swarm "
         "(default: one depth-round, the legacy one-shot semantics)",
     )
+    _add_sim_dump_args(pc)
     pc.add_argument(
         "-metrics", help="write per-level JSONL metrics to this file"
     )

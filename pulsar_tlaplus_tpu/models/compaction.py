@@ -327,6 +327,15 @@ class CompactionModel(ByConstants):
         consumer = jnp.bool_(self.c.model_consumer)
         return consumer | self.termination_goal(s)
 
+    def stutter_action(self, ps: pyeval.State) -> str:
+        """The name of the stuttering disjunct a self-loop step from
+        ``ps`` took (a dumped behaviour names every step)."""
+        return (
+            "Terminating"
+            if pyeval.termination_goal(self.c, ps)
+            else "Consumer"
+        )
+
     def termination_goal(self, s: SState) -> jax.Array:
         """The body of the Termination liveness property
         (compaction.tla:303-307): producer done, compactor parked in

@@ -17,7 +17,12 @@ variable, exporter or telemetry event of their own:
   store's programs, ``engine/device_bfs.py``) ``ptt.spill_tag``,
   ``ptt.spill_evict``, ``ptt.spill_sieve``, ``ptt.spill_unflag``,
   ``ptt.spill_shift`` and ``ptt.spill_fetch`` (the rehash at the same
-  size after an eviction is ``ptt.rehash``).  A scope is HLO metadata
+  size after an eviction is ``ptt.rehash``), and in a simulation
+  (``sim/engine.py``) ``ptt.sim_init`` (a round's fresh initial
+  states), ``ptt.sim_expand`` (``model.successors`` and
+  ``stutter_enabled``), ``ptt.sim_choose`` (the keys, the draw, the
+  select), ``ptt.sim_inv``, ``ptt.sim_dup`` (the duplicate estimator)
+  and ``ptt.sim_replay``.  A scope is HLO metadata
   only: it lands in every operation's ``op_name`` path, which a device
   trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
   metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of
@@ -89,6 +94,14 @@ PHASES = (
 LIVE_PHASES = (
     "explore", "live_table", "live_goal", "sweep_dispatch", "sweep_fetch",
     "sweep_account", "analyse", "result",
+)
+
+# the exclusive phases of one StreamingSimulator.run() (sim/engine.py):
+# ``dispatch`` and ``fetch`` are a segment's, ``dump`` the behaviours
+# on request (replay, the check on the device, rendering, the files);
+# each lands in the simulation result's stats as host_<phase>_s
+SIM_PHASES = (
+    "init", "dispatch", "fetch", "account", "ckpt", "dump", "result",
 )
 
 

@@ -36,6 +36,17 @@ run BFS:
   independent single-state evaluation (chosen lane enabled, successor
   equal, invariant holding until the final state) before it is
   reported — ``result.verified``.
+- **Behaviours on request** (PR 52; TLC's ``-simulate file=F,num=N``).
+  After a budget that ends on a round boundary, ``dump_num`` walkers
+  of the last completed round are replayed by the same program and
+  written as counterexamples are rendered; each replay's last state is
+  compared on the device with the state the timed scan carried for
+  that walker (``sim_dump_mismatches``, 0 in a sound run).
+- **A step's memory does not grow with lanes times swarm** (PR 52):
+  the scan's step walks the swarm in chunks of ``SIM_STEP_CHUNK``
+  walkers, and the programs are module-level units whose static
+  argument is a value (``SimKernel``), the seed's keys traced
+  arguments: a second simulation of one binding traces nothing.
 - **Survivability.**  Checkpoint frames carry (walker states, epoch,
   dup table, cumulative counters, a keys-digest over the PRNG
   position) so kill/SIGTERM/suspend resume continues the IDENTICAL
@@ -44,20 +55,27 @@ run BFS:
 
 Telemetry: schema v11 ``sim`` records (cumulative steps / walkers /
 violations + the estimator), ``run_header.mode = "simulate"``, the
-standard ckpt_frame/fault/result records, heartbeat walks/s.
+standard ckpt_frame/fault/result records, heartbeat walks/s.  Tracing
+(``obs/spans.py``): stage scopes ``ptt.sim_init`` / ``sim_expand`` /
+``sim_choose`` / ``sim_inv`` / ``sim_dup`` / ``sim_replay``, a
+``PhaseClock`` over ``spans.SIM_PHASES`` under one ``ptt:run``, the
+compile meter's ``jit_*`` in ``result.stats``.
 """
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pulsar_tlaplus_tpu.engine import units
+from pulsar_tlaplus_tpu.obs import spans
 from pulsar_tlaplus_tpu.obs import telemetry as obs
 from pulsar_tlaplus_tpu.utils import ckpt, faults
 
@@ -75,6 +93,19 @@ CTR_DUP_HITS = 7  # duplicate-estimator hits (tag already present)
 CTR_N = 8
 
 _CLEAN = np.uint32(0xFFFFFFFF)
+
+# walkers one step expands at once.  ``model.successors`` builds every
+# lane's successor of every walker before the draw picks one, and XLA
+# materialises them: at the scaled compaction binding (34 lanes, 592 B a
+# state) 20 KB of transient a walker, 44 KB where they are gathered from
+# (the parent's step: 11.5 GB at 262,144 walkers by the TPU compiler's
+# own reckoning, and a refusal at 2^20; PERF.md 6, PR 52).  So the
+# scan's step walks the swarm in chunks of this many (``lax.map``), as
+# ``DeviceChecker`` cuts its expand into ``expand_chunk``: the transient
+# is this many walkers' whatever the swarm's width, and the walk stream
+# is the unchunked one bit for bit (every walker's key is a function of
+# (seed, step, walker) alone).
+SIM_STEP_CHUNK = 1 << 14
 
 # checkpoint frame format revision for this engine's sig
 _SIM_CKPT_REV = 1
@@ -95,6 +126,314 @@ def _model_sig(model) -> str:
             )
         )
     return type(model).__name__
+
+
+def _draw(k, probs):
+    """``jax.random.choice(k, len(probs), p=probs)``, draw for draw:
+    the same cumulative sum, the same uniform, and the index of the
+    first entry that reaches it — counted (entries below it) where
+    ``choice`` binary-searches.  On a non-decreasing array the two are
+    the same number, but the search is a loop of per-walker gathers,
+    which on the chip were 38% of a step's device seconds (PERF.md 6,
+    PR 52); ``tests/test_sim_cell.py`` holds the two equal."""
+    p_cuml = jnp.cumsum(probs)
+    r = p_cuml[-1] * (1 - jax.random.uniform(k, (), p_cuml.dtype))
+    return jnp.sum(p_cuml < r, dtype=jnp.int32)
+
+
+class SimKernel(NamedTuple):
+    """What the simulator's programs read, by value: the model (hand
+    models hash by their constants, ``models.ByConstants``), the
+    invariants and the swarm's shape.  It is the static argument of
+    the module-level units below, so a second simulation of one
+    binding and shape in a process, whatever its seed (the two base
+    keys are traced arguments), traces, lowers and loads nothing —
+    the single-chip engine's rule since PR 33 (``engine/units.py``)."""
+
+    model: object
+    invariant_names: Tuple[str, ...]
+    B: int   # walkers
+    T: int   # depth: steps a behaviour
+    L: int   # steps a segment (divides T)
+    S: int   # walkers the duplicate estimator samples
+    dup_table_bits: int
+    chunk: int  # SIM_STEP_CHUNK (a value a body reads is in its key)
+
+    @property
+    def A(self) -> int:
+        return int(self.model.A)
+
+    def init_one(self, k):
+        m = self.model
+        sampler = getattr(m, "sample_initial", None)
+        if sampler is not None:
+            return sampler(k)
+        if m.n_initial > 2**31 - 1:
+            raise ValueError(
+                f"n_initial = {m.n_initial} exceeds int32: the model "
+                "must provide sample_initial(key) for simulation mode"
+            )
+        idx = jax.random.randint(k, (), 0, m.n_initial, jnp.int32)
+        return m.gen_initial(idx)
+
+    def step_one(self, state, k, stage=spans.stage):
+        """One random step of one walker: uniform over enabled lanes
+        plus the stutter lane (TLC behavior-space semantics; no
+        enabled lane at all -> stay put).  Returns (next_state, lane
+        or -1 for stutter, enabled-lane count).  ``stage`` opens the
+        stage scopes of the timed step (``ptt.sim_expand``,
+        ``ptt.sim_choose``); the replay passes none, so that its
+        operations stay under its own ``ptt.sim_replay``."""
+        m, A = self.model, self.A
+        with stage("sim_expand"):
+            succ, valid = m.successors(state)
+            stutter = m.stutter_enabled(state)
+        with stage("sim_choose"):
+            weights = jnp.concatenate(
+                [
+                    valid.astype(jnp.float32),
+                    stutter.astype(jnp.float32)[None],
+                ]
+            )
+            total = jnp.sum(weights)
+            fallback = jnp.zeros((A + 1,)).at[A].set(1.0)
+            probs = jnp.where(
+                total > 0, weights / jnp.maximum(total, 1.0), fallback
+            )
+            lane = _draw(k, probs)
+            is_stutter = lane >= A
+            lane_c = jnp.minimum(lane, A - 1)
+            # the drawn lane's successor by a one-hot masked sum over
+            # the lane axis, not by the gather ``s[lane_c]``: the same
+            # values bit for bit (every leaf is an integer), but under
+            # vmap the gather makes XLA lay every lane's successor out
+            # walker-major and pad it to the (8,128) tile (44 KB a
+            # walker at the scaled binding where the lanes hold 20),
+            # while the sum reads them walker-minor (PERF.md 6, PR 52)
+            drawn = jnp.arange(A, dtype=jnp.int32) == lane_c
+
+            def pick(cur, s):
+                mask = drawn.reshape((A,) + (1,) * (s.ndim - 1))
+                if s.dtype == jnp.bool_:
+                    got = jnp.any(mask & s, axis=0)
+                else:
+                    got = jnp.sum(
+                        jnp.where(mask, s, jnp.zeros((), s.dtype)),
+                        axis=0, dtype=s.dtype,
+                    )
+                return jnp.where(is_stutter, cur, got)
+
+            nxt = jax.tree.map(pick, state, succ)
+            n_enabled = jnp.sum(valid.astype(jnp.uint32)) + (
+                stutter.astype(jnp.uint32)
+            )
+            return (
+                nxt,
+                jnp.where(is_stutter, -1, lane_c).astype(jnp.int32),
+                n_enabled,
+            )
+
+    def advance_one(self, state, k):
+        """One walker's step and the invariants of the state it lands
+        on: what the scan's step runs for every walker of a chunk."""
+        nxt, lane, n_en = self.step_one(state, k)
+        with spans.stage("sim_inv"):
+            ok = self.inv_ok(nxt)
+        return nxt, lane, n_en, ok
+
+    def inv_ok(self, state):
+        """bool[n_inv] — True = satisfied."""
+        if not self.invariant_names:
+            return jnp.ones((0,), bool)
+        return jnp.stack(
+            [self.model.invariants[n](state) for n in self.invariant_names]
+        )
+
+    def fingerprints(self, states_sub):
+        """u32[S] mixed fingerprints of the sampled walkers' states
+        (collisions only perturb the ADVISORY duplicate estimate)."""
+        h = jnp.zeros((self.S,), jnp.uint32)
+        for leaf in jax.tree_util.tree_leaves(states_sub):
+            x = leaf.astype(jnp.uint32).reshape(self.S, -1)
+            mult = (
+                2 * jnp.arange(x.shape[1], dtype=jnp.uint32) + 1
+            ) * jnp.uint32(0x9E3779B9)
+            h = h * jnp.uint32(0x85EBCA6B) + jnp.sum(
+                x * mult, axis=1, dtype=jnp.uint32
+            )
+        h ^= h >> 16
+        h = h * jnp.uint32(0x7FEB352D)
+        h ^= h >> 15
+        return h
+
+    def dup_insert(self, table, states):
+        """Hash the walker subsample into the fixed estimator table;
+        returns (table, hits).  No dedup — advisory sampling only."""
+        sub = jax.tree.map(lambda x: x[: self.S], states)
+        h = self.fingerprints(sub)
+        idx = (h >> jnp.uint32(32 - self.dup_table_bits)).astype(
+            jnp.int32
+        )
+        tag = h | jnp.uint32(1)
+        hits = jnp.sum((table[idx] == tag).astype(jnp.uint32))
+        return table.at[idx].set(tag), hits
+
+    def viol_update(self, ctrs, ok, code):
+        """Fold one batch of invariant results [B, n_inv] into the
+        counter vector at violation code ``code`` (2*step for a fresh
+        initial state, 2*step+1 for a post-step state)."""
+        if ok.shape[1] == 0:
+            return ctrs
+        bad = ~jnp.all(ok, axis=1)  # [B]
+        n_bad = jnp.sum(bad.astype(jnp.uint32))
+        w = jnp.argmax(bad).astype(jnp.uint32)  # first violating walker
+        inv = jnp.argmax(~ok[w]).astype(jnp.uint32)
+        cand = jnp.where(
+            n_bad > 0,
+            code.astype(jnp.uint32) * jnp.uint32(self.B) + w,
+            _CLEAN,
+        )
+        better = cand < ctrs[CTR_VKEY]
+        ctrs = ctrs.at[CTR_VIOL].add(n_bad)
+        ctrs = ctrs.at[CTR_VKEY].set(
+            jnp.where(better, cand, ctrs[CTR_VKEY])
+        )
+        ctrs = ctrs.at[CTR_VINV].set(
+            jnp.where(better, inv, ctrs[CTR_VINV])
+        )
+        return ctrs
+
+    def segment(self, states, table, epoch, k_init, k_step, restart):
+        """The segment megakernel: (states, table, epoch, the two base
+        keys) -> (states, table, counters).  ``restart`` is a STATIC
+        flag — the variant that opens a fresh behavior round draws new
+        initial states before the step scan (restarts only ever land
+        at segment boundaries because segment_len divides depth)."""
+        widx = jnp.arange(self.B, dtype=jnp.uint32)
+        ctrs = jnp.zeros((CTR_N,), jnp.uint32).at[CTR_VKEY].set(_CLEAN)
+        g0 = epoch.astype(jnp.int32) * jnp.int32(self.L)
+        if restart:
+            with spans.stage("sim_init"):
+                kr = jax.random.fold_in(k_init, g0)
+                keys = jax.vmap(lambda w: jax.random.fold_in(kr, w))(
+                    widx
+                )
+                states = jax.vmap(self.init_one)(keys)
+            with spans.stage("sim_inv"):
+                ok0 = jax.vmap(self.inv_ok)(states)
+                ctrs = self.viol_update(ctrs, ok0, jnp.uint32(0))
+            with spans.stage("sim_dup"):
+                table, hits = self.dup_insert(table, states)
+                ctrs = ctrs.at[CTR_DUP_ATT].add(jnp.uint32(self.S))
+                ctrs = ctrs.at[CTR_DUP_HITS].add(hits)
+
+        def step(carry, i):
+            st, tbl, c = carry
+            g = g0 + i
+            with spans.stage("sim_choose"):
+                ks = jax.random.fold_in(k_step, g)
+                keys = jax.vmap(lambda w: jax.random.fold_in(ks, w))(
+                    widx
+                )
+                # the swarm in chunks of SIM_STEP_CHUNK walkers, one
+                # after another (a swarm no wider is one chunk); the
+                # loop's own slicing and stacking read under this
+                # scope, a chunk's work under its own inner ones
+                nxt, lanes, n_en, ok = jax.lax.map(
+                    lambda a: self.advance_one(*a), (st, keys),
+                    batch_size=self.chunk,
+                )
+                en = jnp.sum(n_en, dtype=jnp.uint32)
+                lo = c[CTR_EN_LO] + en
+                c = c.at[CTR_EN_HI].add(
+                    (lo < c[CTR_EN_LO]).astype(jnp.uint32)
+                )
+                c = c.at[CTR_EN_LO].set(lo)
+                c = c.at[CTR_STUTTER].add(
+                    jnp.sum((lanes < 0).astype(jnp.uint32))
+                )
+            with spans.stage("sim_inv"):
+                c = self.viol_update(
+                    c, ok, (2 * i + 1).astype(jnp.uint32)
+                )
+            with spans.stage("sim_dup"):
+                tbl, hits = self.dup_insert(tbl, nxt)
+                c = c.at[CTR_DUP_ATT].add(jnp.uint32(self.S))
+                c = c.at[CTR_DUP_HITS].add(hits)
+            return (nxt, tbl, c), None
+
+        (states, table, ctrs), _ = jax.lax.scan(
+            step, (states, table, ctrs),
+            jnp.arange(self.L, dtype=jnp.int32),
+        )
+        return states, table, ctrs
+
+    def replay_one(self, w, r0, k_init, k_step):
+        """Walker ``w``'s behaviour of the round that starts at step
+        ``r0``, from its key stream alone: (s0, states [T], lanes
+        [T])."""
+        kw = jax.random.fold_in(jax.random.fold_in(k_init, r0), w)
+        s0 = self.init_one(kw)
+        unscoped = lambda _name: contextlib.nullcontext()
+
+        def step(s, j):
+            ks = jax.random.fold_in(
+                jax.random.fold_in(k_step, r0 + j), w
+            )
+            nxt, lane, _n = self.step_one(s, ks, unscoped)
+            return nxt, (nxt, lane)
+
+        _, (states, lanes) = jax.lax.scan(
+            step, s0, jnp.arange(self.T, dtype=jnp.int32)
+        )
+        return s0, states, lanes
+
+
+# The simulator's programs: module-level units (engine/units.py), the
+# kernel their static argument.  Scopes are HLO metadata and no part of
+# the persistent cache's key, hence the ptt_ names (obs/spans.py).
+
+
+@units.unit(static=("k", "restart"), donate=(0, 1))
+def ptt_sim_segment(states, table, epoch, k_init, k_step, *, k, restart):
+    return k.segment(states, table, epoch, k_init, k_step, restart)
+
+
+@units.unit(scope="sim_replay", static=("k",))
+def ptt_sim_replay(ws, r0, k_init, k_step, *, k):
+    """(walkers u32[K], round start) -> every state and lane of those
+    walkers' behaviours of that round: ``(s0 [K], states [K, depth],
+    lanes [K, depth])``."""
+    return jax.vmap(k.replay_one, in_axes=(0, None, None, None))(
+        ws, r0, k_init, k_step
+    )
+
+
+@units.unit(scope="sim_replay")
+def ptt_sim_replay_check(replayed, ws, swarm):
+    """(replayed states [K, depth], walkers [K], the swarm's states
+    [B]) -> how many of the K replays end in another state than the
+    one the timed scan carried for that walker."""
+    differs = jnp.zeros(ws.shape, bool)
+    for got, want in zip(
+        jax.tree_util.tree_leaves(replayed),
+        jax.tree_util.tree_leaves(swarm),
+    ):
+        ne = got[:, -1] != want[ws]
+        differs |= jnp.any(ne.reshape(ne.shape[0], -1), axis=1)
+    return jnp.sum(differs.astype(jnp.uint32))
+
+
+class DumpBudgetError(ValueError):
+    """``dump_path`` with a budget that need not end on a round
+    boundary: there is no last completed round to dump."""
+
+
+def _peak_bytes(states) -> Optional[int]:
+    """``peak_bytes_in_use`` of the device the swarm lives on, where
+    the backend reports it (the CPU does not)."""
+    dev = next(iter(jax.tree_util.tree_leaves(states)[0].devices()))
+    return (dev.memory_stats() or {}).get("peak_bytes_in_use")
 
 
 @dataclass
@@ -124,6 +463,10 @@ class SimulationResult:
     verified: Optional[bool] = None  # replayed behavior re-verified
     violation_walker: Optional[int] = None
     violation_step: Optional[int] = None  # global step of the bad state
+    # steps/s of the dispatches after each program's first (which
+    # traces, lowers and loads it): None where there was none
+    steady_steps_per_sec: Optional[float] = None
+    dump_files: List[str] = field(default_factory=list)
     stats: Dict[str, object] = field(default_factory=dict)
 
 
@@ -162,6 +505,8 @@ class StreamingSimulator:
         progress: bool = False,
         suspend_hook=None,
         tenant: Optional[str] = None,
+        dump_path: Optional[str] = None,
+        dump_num: int = 16,
     ):
         self.model = model
         if invariants is None:
@@ -212,6 +557,26 @@ class StreamingSimulator:
         if not self._budget_explicit:
             self.max_rounds = 1  # finite default: one behavior round
         self.time_budget_s = time_budget_s
+        # behaviours on request (TLC's -simulate file=F,num=N): after
+        # the budget, dump_num walkers of the LAST completed round are
+        # replayed and written to <dump_path>_<round>_<walker>; the
+        # budget has to end where a round does
+        self.dump_path = dump_path
+        self.dump_num = max(1, min(int(dump_num), self.B))
+        if dump_path and (
+            time_budget_s is not None
+            or (max_steps is not None and max_steps % (self.B * self.T))
+        ):
+            raise DumpBudgetError(
+                "a behaviour dump needs a budget that ends on a round "
+                "boundary: "
+                + (
+                    "a time budget ends anywhere"
+                    if time_budget_s is not None
+                    else f"{max_steps} steps is not a multiple of "
+                    f"walkers x depth = {self.B * self.T}"
+                )
+            )
         self.S = max(1, min(int(dup_sample), self.B))
         self.dup_table_bits = int(dup_table_bits)
         self.checkpoint_path = checkpoint_path
@@ -229,10 +594,11 @@ class StreamingSimulator:
         self._jits: Dict[str, object] = {}
         self._fetch_n = 0
         self._frame_seq = 0
-        self._inv_fns = [
-            model.invariants[n] for n in self.invariant_names
-        ]
-        self.A = int(model.A)
+        self._keys = None
+        self.k = SimKernel(
+            model, self.invariant_names, self.B, self.T, self.L,
+            self.S, self.dup_table_bits, SIM_STEP_CHUNK,
+        )
 
     # ------------------------------------------------------------ sig
 
@@ -248,215 +614,30 @@ class StreamingSimulator:
             seed=self.seed,
         )
 
-    # -------------------------------------------------- kernel pieces
+    # ------------------------------------------------------ programs
 
     def _bases(self):
-        base = jax.random.PRNGKey(self.seed)
-        k_init, k_step = jax.random.split(base)
-        return k_init, k_step
-
-    def _init_one(self, k):
-        m = self.model
-        sampler = getattr(m, "sample_initial", None)
-        if sampler is not None:
-            return sampler(k)
-        if m.n_initial > 2**31 - 1:
-            raise ValueError(
-                f"n_initial = {m.n_initial} exceeds int32: the model "
-                "must provide sample_initial(key) for simulation mode"
+        """The walk stream's two base keys (initial states, steps):
+        traced arguments of every program, so the seed is no part of a
+        program's identity."""
+        if self._keys is None:
+            self._keys = tuple(
+                jax.random.split(jax.random.PRNGKey(self.seed))
             )
-        idx = jax.random.randint(k, (), 0, m.n_initial, jnp.int32)
-        return m.gen_initial(idx)
+        return self._keys
 
-    def _step_one(self, state, k):
-        """One random step of one walker: uniform over enabled lanes
-        plus the stutter lane (TLC behavior-space semantics; no
-        enabled lane at all -> stay put).  Returns (next_state, lane
-        or -1 for stutter, enabled-lane count)."""
-        m = self.model
-        succ, valid = m.successors(state)
-        stutter = m.stutter_enabled(state)
-        weights = jnp.concatenate(
-            [valid.astype(jnp.float32), stutter.astype(jnp.float32)[None]]
-        )
-        total = jnp.sum(weights)
-        fallback = jnp.zeros((self.A + 1,)).at[self.A].set(1.0)
-        probs = jnp.where(
-            total > 0, weights / jnp.maximum(total, 1.0), fallback
-        )
-        lane = jax.random.choice(k, self.A + 1, p=probs)
-        is_stutter = lane >= self.A
-        lane_c = jnp.minimum(lane, self.A - 1)
-        nxt = jax.tree.map(
-            lambda cur, s: jnp.where(is_stutter, cur, s[lane_c]),
-            state,
-            succ,
-        )
-        n_enabled = jnp.sum(valid.astype(jnp.uint32)) + stutter.astype(
-            jnp.uint32
-        )
-        return (
-            nxt,
-            jnp.where(is_stutter, -1, lane_c).astype(jnp.int32),
-            n_enabled,
+    def _segment(self, states, table, epoch: int, restart: bool):
+        return ptt_sim_segment(
+            states, table, jnp.int32(epoch), *self._bases(),
+            k=self.k, restart=restart,
         )
 
-    def _inv_ok(self, state):
-        """bool[n_inv] — True = satisfied."""
-        if not self._inv_fns:
-            return jnp.ones((0,), bool)
-        return jnp.stack([f(state) for f in self._inv_fns])
-
-    def _fingerprints(self, states_sub):
-        """u32[S] mixed fingerprints of the sampled walkers' states
-        (collisions only perturb the ADVISORY duplicate estimate)."""
-        h = jnp.zeros((self.S,), jnp.uint32)
-        for leaf in jax.tree_util.tree_leaves(states_sub):
-            x = leaf.astype(jnp.uint32).reshape(self.S, -1)
-            mult = (
-                2 * jnp.arange(x.shape[1], dtype=jnp.uint32) + 1
-            ) * jnp.uint32(0x9E3779B9)
-            h = h * jnp.uint32(0x85EBCA6B) + jnp.sum(
-                x * mult, axis=1, dtype=jnp.uint32
-            )
-        h ^= h >> 16
-        h = h * jnp.uint32(0x7FEB352D)
-        h ^= h >> 15
-        return h
-
-    def _dup_insert(self, table, states):
-        """Hash the walker subsample into the fixed estimator table;
-        returns (table, hits).  No dedup — advisory sampling only."""
-        sub = jax.tree.map(lambda x: x[: self.S], states)
-        h = self._fingerprints(sub)
-        idx = (h >> jnp.uint32(32 - self.dup_table_bits)).astype(
-            jnp.int32
+    def _replay(self, walkers, r0: int):
+        """``ptt_sim_replay`` of walkers (u32[K]) of the round that
+        starts at step ``r0``."""
+        return ptt_sim_replay(
+            walkers, jnp.int32(r0), *self._bases(), k=self.k
         )
-        tag = h | jnp.uint32(1)
-        hits = jnp.sum((table[idx] == tag).astype(jnp.uint32))
-        return table.at[idx].set(tag), hits
-
-    def _viol_update(self, ctrs, ok, code):
-        """Fold one batch of invariant results [B, n_inv] into the
-        counter vector at violation code ``code`` (2*step for a fresh
-        initial state, 2*step+1 for a post-step state)."""
-        if ok.shape[1] == 0:
-            return ctrs
-        bad = ~jnp.all(ok, axis=1)  # [B]
-        n_bad = jnp.sum(bad.astype(jnp.uint32))
-        w = jnp.argmax(bad).astype(jnp.uint32)  # first violating walker
-        inv = jnp.argmax(~ok[w]).astype(jnp.uint32)
-        cand = jnp.where(
-            n_bad > 0,
-            code.astype(jnp.uint32) * jnp.uint32(self.B) + w,
-            _CLEAN,
-        )
-        better = cand < ctrs[CTR_VKEY]
-        ctrs = ctrs.at[CTR_VIOL].add(n_bad)
-        ctrs = ctrs.at[CTR_VKEY].set(
-            jnp.where(better, cand, ctrs[CTR_VKEY])
-        )
-        ctrs = ctrs.at[CTR_VINV].set(
-            jnp.where(better, inv, ctrs[CTR_VINV])
-        )
-        return ctrs
-
-    def _segment_fn(self, restart: bool):
-        """The segment megakernel: (states, table, epoch) -> (states,
-        table, counters).  ``restart`` is a STATIC flag — the variant
-        that opens a fresh behavior round draws new initial states
-        before the step scan (restarts only ever land at segment
-        boundaries because segment_len divides depth)."""
-        k_init, k_step = self._bases()
-        widx = jnp.arange(self.B, dtype=jnp.uint32)
-
-        def seg(states, table, epoch):
-            ctrs = jnp.zeros((CTR_N,), jnp.uint32).at[CTR_VKEY].set(
-                _CLEAN
-            )
-            g0 = epoch.astype(jnp.int32) * jnp.int32(self.L)
-            if restart:
-                kr = jax.random.fold_in(k_init, g0)
-                keys = jax.vmap(
-                    lambda w: jax.random.fold_in(kr, w)
-                )(widx)
-                states = jax.vmap(self._init_one)(keys)
-                ok0 = jax.vmap(self._inv_ok)(states)
-                ctrs = self._viol_update(ctrs, ok0, jnp.uint32(0))
-                table, hits = self._dup_insert(table, states)
-                ctrs = ctrs.at[CTR_DUP_ATT].add(jnp.uint32(self.S))
-                ctrs = ctrs.at[CTR_DUP_HITS].add(hits)
-
-            def step(carry, i):
-                st, tbl, c = carry
-                g = g0 + i
-                ks = jax.random.fold_in(k_step, g)
-                keys = jax.vmap(
-                    lambda w: jax.random.fold_in(ks, w)
-                )(widx)
-                nxt, lanes, n_en = jax.vmap(self._step_one)(st, keys)
-                en = jnp.sum(n_en, dtype=jnp.uint32)
-                lo = c[CTR_EN_LO] + en
-                c = c.at[CTR_EN_HI].add(
-                    (lo < c[CTR_EN_LO]).astype(jnp.uint32)
-                )
-                c = c.at[CTR_EN_LO].set(lo)
-                c = c.at[CTR_STUTTER].add(
-                    jnp.sum((lanes < 0).astype(jnp.uint32))
-                )
-                ok = jax.vmap(self._inv_ok)(nxt)
-                c = self._viol_update(
-                    c, ok, (2 * i + 1).astype(jnp.uint32)
-                )
-                tbl, hits = self._dup_insert(tbl, nxt)
-                c = c.at[CTR_DUP_ATT].add(jnp.uint32(self.S))
-                c = c.at[CTR_DUP_HITS].add(hits)
-                return (nxt, tbl, c), None
-
-            (states, table, ctrs), _ = jax.lax.scan(
-                step, (states, table, ctrs),
-                jnp.arange(self.L, dtype=jnp.int32),
-            )
-            return states, table, ctrs
-
-        return seg
-
-    def _segment_jit(self, restart: bool):
-        key = f"segment_restart{int(restart)}"
-        fn = self._jits.get(key)
-        if fn is None:
-            fn = jax.jit(
-                self._segment_fn(restart), donate_argnums=(0, 1)
-            )
-            self._jits[key] = fn
-        return fn
-
-    def _replay_jit(self):
-        fn = self._jits.get("replay")
-        if fn is None:
-            k_init, k_step = self._bases()
-
-            def replay(w, r0):
-                kw = jax.random.fold_in(
-                    jax.random.fold_in(k_init, r0), w
-                )
-                s0 = self._init_one(kw)
-
-                def step(s, j):
-                    ks = jax.random.fold_in(
-                        jax.random.fold_in(k_step, r0 + j), w
-                    )
-                    nxt, lane, _n = self._step_one(s, ks)
-                    return nxt, (nxt, lane)
-
-                _, (states, lanes) = jax.lax.scan(
-                    step, s0, jnp.arange(self.T, dtype=jnp.int32)
-                )
-                return s0, states, lanes
-
-            fn = jax.jit(replay)
-            self._jits["replay"] = fn
-        return fn
 
     def warmup(self) -> float:
         """Compile both segment variants up front; returns wall
@@ -464,9 +645,7 @@ class StreamingSimulator:
         t0 = time.perf_counter()
         states, table = self._fresh_buffers()
         for restart in (True, False):
-            s2, t2, c = self._segment_jit(restart)(
-                states, table, jnp.int32(0)
-            )
+            s2, t2, c = self._segment(states, table, 0, restart)
             np.asarray(c)
             states, table = s2, t2
         return time.perf_counter() - t0
@@ -480,7 +659,7 @@ class StreamingSimulator:
         states = jax.tree.map(
             lambda x: jnp.zeros((self.B,) + tuple(x.shape), x.dtype),
             jax.eval_shape(
-                lambda: self._init_one(jax.random.PRNGKey(0))
+                lambda: self.k.init_one(jax.random.PRNGKey(0))
             ),
         )
         table = jnp.zeros((1 << self.dup_table_bits,), jnp.uint32)
@@ -580,7 +759,7 @@ class StreamingSimulator:
                 "frame does not anchor this walk stream"
             )
         template = jax.eval_shape(
-            lambda: self._init_one(jax.random.PRNGKey(0))
+            lambda: self.k.init_one(jax.random.PRNGKey(0))
         )
         treedef = jax.tree_util.tree_structure(template)
         # COPIES, not jnp.asarray views: the restored buffers are
@@ -661,9 +840,18 @@ class StreamingSimulator:
             print(f"  {msg}", file=sys.stderr, flush=True)
 
     def run(self, resume: bool = False) -> SimulationResult:
-        rid = obs.new_run_id()
+        # this run's exclusive host phases (spans.SIM_PHASES) and the
+        # compile meter's reading before it, as DeviceChecker.run()
+        # keeps its own; ptt:run is the container the phases lie in
+        clock = self._clock = spans.PhaseClock(obs.new_run_id())
+        self._jit0 = spans.compile_meter().snapshot()
+        with spans.span("run", run_id=clock.run_id):
+            return self._run(resume)
+
+    def _run(self, resume: bool) -> SimulationResult:
+        rid = self._clock.run_id
         self.tel = obs.as_telemetry(self._telemetry_arg, run_id=rid)
-        self._run_id = self.tel.run_id or rid
+        self._run_id = self._clock.run_id = self.tel.run_id or rid
         self.last_stats = {}
         self._fetch_n = 0
         self._frame_seq = 0
@@ -695,24 +883,30 @@ class StreamingSimulator:
             self.tel = obs.NULL
 
     def _run_impl(self, resume: bool) -> SimulationResult:
+        clock = self._clock
         resume_meta: dict = {}
-        if resume:
-            if not self.checkpoint_path:
-                raise ValueError("resume=True needs a checkpoint_path")
-            states, table, epoch, cum, prior_wall, resume_meta = (
-                self._load_frame()
-            )
-            t0 = time.time() - prior_wall
-        else:
-            states, table = self._fresh_buffers()
-            epoch = 0
-            cum = {
-                "steps": 0, "states": 0, "violations": 0,
-                "stutter": 0, "enabled": 0, "dup_att": 0,
-                "dup_hits": 0, "segments": 0,
-            }
-            t0 = time.time()
-        self._emit_header(resume, resume_meta)
+        with clock.phase("init"):
+            if resume:
+                if not self.checkpoint_path:
+                    raise ValueError(
+                        "resume=True needs a checkpoint_path"
+                    )
+                states, table, epoch, cum, prior_wall, resume_meta = (
+                    self._load_frame()
+                )
+            else:
+                states, table = self._fresh_buffers()
+                epoch = 0
+                cum = {
+                    "steps": 0, "states": 0, "violations": 0,
+                    "stutter": 0, "enabled": 0, "dup_att": 0,
+                    "dup_hits": 0, "segments": 0,
+                }
+                prior_wall = 0.0
+            # the wall a result reports: this run's clock and, on a
+            # resume, what the frame's run had banked
+            t0 = time.time() - clock.elapsed() - prior_wall
+            self._emit_header(resume, resume_meta)
         self._log(
             f"simulation: {self.B} walkers, depth {self.T}, "
             f"segment {self.L} step(s)"
@@ -728,6 +922,9 @@ class StreamingSimulator:
             else time.monotonic() + self.time_budget_s
         )
         n_inv = len(self.invariant_names)
+        # the dispatches after each program's first, which traces,
+        # lowers and loads it: their steps and their seconds
+        met, steady = set(), [0, 0.0]
         with watcher:
             while True:
                 # budget / cooperative-stop checks FIRST: the segment
@@ -765,36 +962,44 @@ class StreamingSimulator:
                     break
                 faults.poll("segment", epoch)
                 restart = (epoch % self.segs_per_round) == 0
-                states, table, ctrs = self._segment_jit(restart)(
-                    states, table, jnp.int32(epoch)
-                )
-                c = np.asarray(ctrs)  # THE one fetch per dispatch
-                self._fetch_n += 1
-                cum["segments"] += 1
-                cum["steps"] += self.B * self.L
-                cum["states"] += self.B * self.L + (
-                    self.B if restart else 0
-                )
-                cum["stutter"] += int(c[CTR_STUTTER])
-                cum["enabled"] += (
-                    int(c[CTR_EN_HI]) << 32
-                ) + int(c[CTR_EN_LO])
-                cum["violations"] += int(c[CTR_VIOL])
-                cum["dup_att"] += int(c[CTR_DUP_ATT])
-                cum["dup_hits"] += int(c[CTR_DUP_HITS])
-                wall = time.time() - t0
-                walks = self.B * (cum["steps"] // (self.B * self.T))
-                self._snap.update(
-                    distinct_states=cum["states"],
-                    generated=cum["steps"],
-                    level=epoch + 1,
-                    walks=walks,
-                )
-                if (
-                    cum["segments"] % self.sim_event_every == 0
-                    or int(c[CTR_VIOL])
-                ):
-                    self._emit_sim_event(cum, epoch + 1, wall)
+                t_seg = time.perf_counter()
+                with clock.phase("dispatch", level=epoch):
+                    states, table, ctrs = self._segment(
+                        states, table, epoch, restart
+                    )
+                with clock.phase("fetch", level=epoch):
+                    c = np.asarray(ctrs)  # THE one fetch per dispatch
+                if restart in met:
+                    steady[0] += self.B * self.L
+                    steady[1] += time.perf_counter() - t_seg
+                met.add(restart)
+                with clock.phase("account", level=epoch):
+                    self._fetch_n += 1
+                    cum["segments"] += 1
+                    cum["steps"] += self.B * self.L
+                    cum["states"] += self.B * self.L + (
+                        self.B if restart else 0
+                    )
+                    cum["stutter"] += int(c[CTR_STUTTER])
+                    cum["enabled"] += (
+                        int(c[CTR_EN_HI]) << 32
+                    ) + int(c[CTR_EN_LO])
+                    cum["violations"] += int(c[CTR_VIOL])
+                    cum["dup_att"] += int(c[CTR_DUP_ATT])
+                    cum["dup_hits"] += int(c[CTR_DUP_HITS])
+                    wall = time.time() - t0
+                    walks = self.B * (cum["steps"] // (self.B * self.T))
+                    self._snap.update(
+                        distinct_states=cum["states"],
+                        generated=cum["steps"],
+                        level=epoch + 1,
+                        walks=walks,
+                    )
+                    if (
+                        cum["segments"] % self.sim_event_every == 0
+                        or int(c[CTR_VIOL])
+                    ):
+                        self._emit_sim_event(cum, epoch + 1, wall)
                 if int(c[CTR_VIOL]) and int(c[CTR_VKEY]) != int(_CLEAN):
                     viol = (
                         epoch,
@@ -810,21 +1015,120 @@ class StreamingSimulator:
                     self.checkpoint_path
                     and cum["segments"] % self.checkpoint_every == 0
                 ):
-                    self._save_frame(states, table, epoch, cum, wall)
+                    with clock.phase("ckpt", level=epoch):
+                        self._save_frame(states, table, epoch, cum, wall)
         wall = time.time() - t0
         if stop_reason in ("suspended", "preempted"):
-            self._save_frame(states, table, epoch, cum, wall)
+            with clock.phase("ckpt", level=epoch):
+                self._save_frame(states, table, epoch, cum, wall)
             self._log(
                 f"simulation {stop_reason} at epoch {epoch} "
                 f"({cum['steps']} steps banked)"
             )
-        res = self._mk_result(
-            cum, epoch, t0, truncated=truncated, stop_reason=stop_reason
-        )
-        if viol is not None:
-            self._attach_violation(res, viol)
+        dumped: List[str] = []
+        mismatches = 0
+        if (
+            self.dump_path
+            and stop_reason in ("step_budget", "round_budget")
+            and epoch >= self.segs_per_round
+        ):
+            with clock.phase("dump", level=epoch):
+                dumped, mismatches = self._dump_behaviours(states, epoch)
+        with clock.phase("result"):
+            res = self._mk_result(
+                cum, epoch, truncated=truncated, stop_reason=stop_reason
+            )
+            if steady[1] > 0:
+                res.steady_steps_per_sec = round(steady[0] / steady[1], 1)
+            res.dump_files = dumped
+            if viol is not None:
+                self._attach_violation(res, viol)
+            leaves = [
+                np.asarray(x) for x in jax.tree_util.tree_leaves(states)
+            ]
+            self.last_stats.update(
+                sim_depth=self.T,
+                sim_segment_len=self.L,
+                sim_rounds=cum["steps"] // (self.B * self.T),
+                sim_step_chunks=-(-self.B // self.k.chunk),
+                sim_dump_s=clock.seconds_of("dump"),
+                sim_dump_behaviours=len(dumped),
+                sim_dump_mismatches=mismatches,
+                sim_keys_digest=self._keys_digest(leaves, epoch),
+                sim_peak_bytes=_peak_bytes(states),
+                steady_steps_per_sec=res.steady_steps_per_sec,
+                **spans.compile_meter().since(self._jit0),
+            )
+            self._set_rates(res, t0)
+        self.last_stats.update(clock.host_seconds(spans.SIM_PHASES))
         self._emit_result(res)
         return res
+
+    def _dump_behaviours(self, states, epoch) -> Tuple[List[str], int]:
+        """Replay ``dump_num`` walkers of the last completed round,
+        spread evenly over the swarm and rotated by the seed, and write
+        each behaviour (every state, the self-loop steps too) to
+        ``<dump_path>_<round>_<walker>`` as a counterexample is
+        printed.  Each replay's last state is compared on the device
+        with the state the timed scan carried for that walker; returns
+        the files and how many differed (0 in a sound run)."""
+        from pulsar_tlaplus_tpu.utils.render import render_trace
+
+        k, b = self.dump_num, self.B
+        ws = [(i * b // k + self.seed) % b for i in range(k)]
+        r0 = (epoch - self.segs_per_round) * self.L
+        rnd = epoch // self.segs_per_round  # rounds completed
+        ws_dev = jnp.asarray(ws, jnp.uint32)
+        s0, replayed, lanes = self._replay(ws_dev, r0)
+        mismatches = ptt_sim_replay_check(replayed, ws_dev, states)
+        s0, replayed, lanes = jax.tree.map(
+            np.asarray, (s0, replayed, lanes)
+        )
+        files = []
+        for i, w in enumerate(ws):
+            trace, actions = self._behaviour(
+                *jax.tree.map(lambda x: x[i], (s0, replayed, lanes)),
+                self.T, keep_stutter=True,
+            )
+            path = f"{self.dump_path}_{rnd}_{w}"
+            with open(path, "w", encoding="utf-8") as f:
+                f.write(
+                    render_trace(
+                        trace, actions, getattr(self.model, "c", None)
+                    )
+                )
+            files.append(path)
+        return files, int(mismatches)
+
+    def _behaviour(self, s0, states, lanes, n_steps, keep_stutter):
+        """The first ``n_steps`` steps of one replayed walker (host
+        arrays) as (states, action names).  A counterexample drops the
+        self-loop steps (the state does not change); a dumped
+        behaviour keeps them, each under the name the model gives the
+        disjunct (``stutter_action``)."""
+        m = self.model
+        names = getattr(m, "action_names", ())
+        action_ids = getattr(m, "action_ids", None)
+        stutter_action = getattr(
+            m, "stutter_action", lambda _ps: "Stuttering"
+        )
+        trace = [m.to_pystate(s0)]
+        actions: List[str] = []
+        for step in range(n_steps):
+            lane = int(lanes[step])
+            if lane < 0:
+                if keep_stutter:
+                    actions.append(stutter_action(trace[-1]))
+                    trace.append(trace[-1])
+                continue
+            trace.append(
+                m.to_pystate(jax.tree.map(lambda x: x[step], states))
+            )
+            aid = (
+                int(action_ids[lane]) if action_ids is not None else lane
+            )
+            actions.append(names[aid] if aid < len(names) else str(aid))
+        return trace, actions
 
     def _emit_sim_event(self, cum, epoch, wall) -> None:
         walks = self.B * (cum["steps"] // (self.B * self.T))
@@ -852,9 +1156,11 @@ class StreamingSimulator:
         )
 
     def _mk_result(
-        self, cum, epoch, t0, truncated: bool, stop_reason
+        self, cum, epoch, truncated: bool, stop_reason
     ) -> SimulationResult:
-        wall = max(time.time() - t0, 1e-9)
+        """The result and its counters; ``_set_rates`` closes it with
+        the wall and the rates once the rest of the run's work (a
+        violation's replay, the digest) is done."""
         walks = self.B * (cum["steps"] // (self.B * self.T))
         dup = (
             round(cum["dup_hits"] / cum["dup_att"], 6)
@@ -869,12 +1175,8 @@ class StreamingSimulator:
             walks=walks,
             segments=cum["segments"],
             epoch=epoch,
-            wall_s=round(wall, 3),
             truncated=truncated,
             stop_reason=stop_reason,
-            steps_per_sec=round(cum["steps"] / wall, 1),
-            walks_per_sec=round(walks / wall, 2),
-            states_per_sec=round(cum["states"] / wall, 1),
             dup_ratio_est=dup,
         )
         res.stats = self.last_stats
@@ -891,8 +1193,6 @@ class StreamingSimulator:
             sim_dup_ratio_est=dup,
             sim_segments=cum["segments"],
             sim_epoch=epoch,
-            walks_per_sec=res.walks_per_sec,
-            steps_per_sec=res.steps_per_sec,
             steps_per_state=(
                 round(cum["steps"] / cum["states"], 4)
                 if cum["states"]
@@ -901,6 +1201,17 @@ class StreamingSimulator:
             stats_fetches=self._fetch_n,
         )
         return res
+
+    def _set_rates(self, res: SimulationResult, t0) -> None:
+        wall = max(time.time() - t0, 1e-9)
+        res.wall_s = round(wall, 3)
+        res.steps_per_sec = round(res.steps / wall, 1)
+        res.walks_per_sec = round(res.walks / wall, 2)
+        res.states_per_sec = round(res.states_visited / wall, 1)
+        self.last_stats.update(
+            walks_per_sec=res.walks_per_sec,
+            steps_per_sec=res.steps_per_sec,
+        )
 
     def _emit_result(self, res: SimulationResult) -> None:
         self.tel.emit(
@@ -933,28 +1244,15 @@ class StreamingSimulator:
         r0 = (g_state // self.T) * self.T  # behavior-round start
         n_steps = 0 if is_init else g_state - r0 + 1
         res.violation_step = None if is_init else g_state
-        s0, states, lanes = self._replay_jit()(
-            jnp.uint32(walker), jnp.int32(r0)
+        s0, states, lanes = jax.tree.map(
+            lambda x: x[0],
+            self._replay(jnp.asarray([walker], jnp.uint32), r0),
         )
         lane_log = np.asarray(lanes)
-        names = getattr(m, "action_names", ())
-        action_ids = getattr(m, "action_ids", None)
-        take = lambda tree, i: jax.tree.map(
-            lambda x: np.asarray(x)[i], tree
+        res.trace, res.trace_actions = self._behaviour(
+            *jax.tree.map(np.asarray, (s0, states)), lane_log, n_steps,
+            keep_stutter=False,
         )
-        trace = [m.to_pystate(jax.tree.map(np.asarray, s0))]
-        actions: List[str] = []
-        for step in range(n_steps):
-            lane = int(lane_log[step])
-            if lane < 0:
-                continue  # stutter: state unchanged, not in the trace
-            trace.append(m.to_pystate(take(states, step)))
-            aid = (
-                int(action_ids[lane]) if action_ids is not None else lane
-            )
-            actions.append(names[aid] if aid < len(names) else str(aid))
-        res.trace = trace
-        res.trace_actions = actions
         res.verified = self._verify_replay(
             s0, states, lane_log, n_steps, inv_idx
         )
@@ -963,7 +1261,7 @@ class StreamingSimulator:
             invariant=res.violation,
             walker=walker,
             step=res.violation_step,
-            trace_len=len(trace),
+            trace_len=len(res.trace),
             verified=res.verified,
         )
 
@@ -980,10 +1278,10 @@ class StreamingSimulator:
             succ_fn = jax.jit(m.successors)
             self._jits["verify_succ"] = succ_fn
         inv_fn = None
-        if self._inv_fns:
+        if self.invariant_names:
             inv_fn = self._jits.get("verify_inv")
             if inv_fn is None:
-                inv_fn = jax.jit(self._inv_ok)
+                inv_fn = jax.jit(self.k.inv_ok)
                 self._jits["verify_inv"] = inv_fn
         take = lambda tree, i: jax.tree.map(lambda x: x[i], tree)
         cur = s0
