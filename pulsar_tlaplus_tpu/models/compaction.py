@@ -318,6 +318,43 @@ class CompactionModel(ByConstants):
             )
         return succ, valid
 
+    def successor_at(self, s: SState, lane: jax.Array) -> SState:
+        """``successors(s)[0][lane]`` alone, leaf for leaf and bit for
+        bit, for a traced i32 ``lane`` in ``[0, A)``, enabled or not
+        (simulation mode protocol: a walker builds the one successor it
+        drew, docs/simulation.md).  The same action methods
+        :meth:`successors` stacks, selected among field by field: a
+        leaf no action replaces is ``s``'s own, and one an action
+        replaces is a select on the lane, so no array here has a lane
+        axis."""
+        lane = jnp.asarray(lane, jnp.int32)
+        n_prod = self.n_producer_lanes
+        steps: List[Tuple[jax.Array, SState]] = [
+            (lane == n_prod + j, act(s)[1])
+            for j, act in enumerate(
+                (
+                    self._phase_one,
+                    self._phase_two_write,
+                    self._update_context,
+                    self._update_horizon,
+                    self._persist_cursor,
+                    self._delete_ledger,
+                    self._broker_crash,
+                )
+            )
+        ]
+        if n_prod:
+            nv1 = self.c.num_values + 1
+            produced = self._producer(s, lane // nv1, lane % nv1)[1]
+            steps.append((lane < n_prod, produced))
+        leaves = []
+        for i, cur in enumerate(s):
+            for drawn, t in steps:
+                if t[i] is not s[i]:  # the action replaces this leaf
+                    cur = jnp.where(drawn, t[i], cur)
+            leaves.append(cur)
+        return SState(*leaves)
+
     def stutter_enabled(self, s: SState) -> jax.Array:
         """Enabledness of the stuttering disjuncts, for deadlock checking.
 

@@ -20,9 +20,10 @@ variable, exporter or telemetry event of their own:
   size after an eviction is ``ptt.rehash``), and in a simulation
   (``sim/engine.py``) ``ptt.sim_init`` (a round's fresh initial
   states), ``ptt.sim_expand`` (``model.successors`` and
-  ``stutter_enabled``), ``ptt.sim_choose`` (the keys, the draw, the
-  select), ``ptt.sim_inv``, ``ptt.sim_dup`` (the duplicate estimator)
-  and ``ptt.sim_replay``.  A scope is HLO metadata
+  ``stutter_enabled``: the lanes' guards alone where the model builds
+  the drawn successor itself), ``ptt.sim_choose`` (the keys, the draw,
+  the drawn lane's successor, built or selected), ``ptt.sim_inv``,
+  ``ptt.sim_dup`` (the duplicate estimator) and ``ptt.sim_replay``.  A scope is HLO metadata
   only: it lands in every operation's ``op_name`` path, which a device
   trace carries for each ``XLA Ops`` event (the ``tf_op`` stat of its
   metadata), and changes nothing that is compiled.  An operation belongs to the innermost ``ptt.`` scope of

@@ -43,10 +43,21 @@ run BFS:
   compared on the device with the state the timed scan carried for
   that walker (``sim_dump_mismatches``, 0 in a sound run).
 - **A step's memory does not grow with lanes times swarm** (PR 52):
-  the scan's step walks the swarm in chunks of ``SIM_STEP_CHUNK``
-  walkers, and the programs are module-level units whose static
-  argument is a value (``SimKernel``), the seed's keys traced
-  arguments: a second simulation of one binding traces nothing.
+  the scan's step walks the swarm in chunks of walkers (one module
+  constant a form of the step, below), and the programs are
+  module-level units whose static argument is a value
+  (``SimKernel``), the seed's keys traced arguments: a second
+  simulation of one binding traces nothing.
+- **A walker's step builds the ONE successor it drew** (PR 53):
+  choose, then apply.  ``model.successors`` is called for ``valid``
+  alone, the draw picks a lane, and where the model has
+  ``successor_at(state, lane)`` (the optional method of the model
+  protocol, docs/simulation.md) it builds that lane's successor and
+  no other: the DRAWN form of the step.  A model without it keeps the
+  LANES form, every lane's successor built and the drawn one taken by
+  a one-hot masked sum.  The two forms share the draw and are one
+  walk stream; ``run_header.sim_step_form`` names the form and
+  ``sim_drawn_steps`` counts the steps so built.
 - **Survivability.**  Checkpoint frames carry (walker states, epoch,
   dup table, cumulative counters, a keys-digest over the PRNG
   position) so kill/SIGTERM/suspend resume continues the IDENTICAL
@@ -94,18 +105,46 @@ CTR_N = 8
 
 _CLEAN = np.uint32(0xFFFFFFFF)
 
-# walkers one step expands at once.  ``model.successors`` builds every
-# lane's successor of every walker before the draw picks one, and XLA
-# materialises them: at the scaled compaction binding (34 lanes, 592 B a
-# state) 20 KB of transient a walker, 44 KB where they are gathered from
-# (the parent's step: 11.5 GB at 262,144 walkers by the TPU compiler's
-# own reckoning, and a refusal at 2^20; PERF.md 6, PR 52).  So the
-# scan's step walks the swarm in chunks of this many (``lax.map``), as
-# ``DeviceChecker`` cuts its expand into ``expand_chunk``: the transient
-# is this many walkers' whatever the swarm's width, and the walk stream
-# is the unchunked one bit for bit (every walker's key is a function of
-# (seed, step, walker) alone).
+# walkers one step expands at once, a constant a form of the step.
+#
+# The LANES form (a model without ``successor_at``): ``model.successors``
+# builds every lane's successor of every walker before the draw picks
+# one, and XLA materialises them: at the scaled compaction binding (34
+# lanes, 592 B a state) 20 KB of transient a walker, 44 KB where they
+# are gathered from (the parent's step: 11.5 GB at 262,144 walkers by
+# the TPU compiler's own reckoning, and a refusal at 2^20; PERF.md 6,
+# PR 52).  So the scan's step walks the swarm in chunks of this many
+# (``lax.map``), as ``DeviceChecker`` cuts its expand into
+# ``expand_chunk``: the transient is this many walkers' whatever the
+# swarm's width, and the walk stream is the unchunked one bit for bit
+# (every walker's key is a function of (seed, step, walker) alone).
 SIM_STEP_CHUNK = 1 << 14
+
+# The DRAWN form (the model builds the drawn lane's successor alone):
+# no lane axis, so the 20 KB a walker above are not this form's; a
+# walker's transient is what the model's own actions and invariants
+# hold (0.7 GB of temporaries at 262,144 walkers, 2.8 GB at 2^20, by
+# the TPU compiler's reckoning).  Chosen again on the chip at 262,144
+# walkers (PERF.md 6, PR 53), by two rules before speed: a traced 40 s
+# window of the benchmark's cell has to stay under the 6.29M device
+# events the profiler keeps (a step is 5,466 events in chunks of 4,096,
+# 2,295 at 16,384, 974 at 65,536, 672 as ONE chunk of the swarm's own
+# width and 502 with no chunk loop at all: the two smallest do not
+# fit), and 2^20 walkers have to run (they do at every chunk).  Then
+# speed, a check of four rounds: 5.44 s, 5.68, 6.62, 5.85 and 5.42.  A
+# chunk ABOVE the swarm's width leaves ``lax.map`` no loop (at the
+# width itself it leaves a loop of one turn, whose slicing and stacking
+# stay), so this is the widest swarm measured: up to 2^20 walkers the
+# step is unchunked (a round of 2^20 in 7.8 s, 9.1 in chunks of 2^18).
+SIM_DRAWN_STEP_CHUNK = 1 << 20
+
+
+def step_form(model) -> str:
+    """``"drawn"`` where the model has ``successor_at(state, lane)``
+    (docs/simulation.md), else ``"lanes"``: what the step adapts on."""
+    has = getattr(model, "successor_at", None) is not None
+    return "drawn" if has else "lanes"
+
 
 # checkpoint frame format revision for this engine's sig
 _SIM_CKPT_REV = 1
@@ -157,11 +196,40 @@ class SimKernel(NamedTuple):
     L: int   # steps a segment (divides T)
     S: int   # walkers the duplicate estimator samples
     dup_table_bits: int
-    chunk: int  # SIM_STEP_CHUNK (a value a body reads is in its key)
+    chunk: int  # the form's chunk (a value a body reads is in its key)
 
     @property
     def A(self) -> int:
         return int(self.model.A)
+
+    @property
+    def form(self) -> str:
+        """``"drawn"``: a step builds the drawn lane's successor alone
+        (the model has ``successor_at``); ``"lanes"``: it picks it from
+        all ``A``."""
+        return step_form(self.model)
+
+    def _pick(self, succ, lane_c):
+        """``succ[lane_c]`` of every leaf by a one-hot masked sum over
+        the lane axis, not by the gather: the same values bit for bit
+        (every leaf is an integer), but under vmap the gather makes XLA
+        lay every lane's successor out walker-major and pad it to the
+        (8,128) tile (44 KB a walker at the scaled compaction binding
+        where the lanes hold 20), while the sum reads them walker-minor
+        (PERF.md 6, PR 52)."""
+        A = self.A
+        drawn = jnp.arange(A, dtype=jnp.int32) == lane_c
+
+        def pick(s):
+            mask = drawn.reshape((A,) + (1,) * (s.ndim - 1))
+            if s.dtype == jnp.bool_:
+                return jnp.any(mask & s, axis=0)
+            return jnp.sum(
+                jnp.where(mask, s, jnp.zeros((), s.dtype)),
+                axis=0, dtype=s.dtype,
+            )
+
+        return jax.tree.map(pick, succ)
 
     def init_one(self, k):
         m = self.model
@@ -203,27 +271,16 @@ class SimKernel(NamedTuple):
             lane = _draw(k, probs)
             is_stutter = lane >= A
             lane_c = jnp.minimum(lane, A - 1)
-            # the drawn lane's successor by a one-hot masked sum over
-            # the lane axis, not by the gather ``s[lane_c]``: the same
-            # values bit for bit (every leaf is an integer), but under
-            # vmap the gather makes XLA lay every lane's successor out
-            # walker-major and pad it to the (8,128) tile (44 KB a
-            # walker at the scaled binding where the lanes hold 20),
-            # while the sum reads them walker-minor (PERF.md 6, PR 52)
-            drawn = jnp.arange(A, dtype=jnp.int32) == lane_c
-
-            def pick(cur, s):
-                mask = drawn.reshape((A,) + (1,) * (s.ndim - 1))
-                if s.dtype == jnp.bool_:
-                    got = jnp.any(mask & s, axis=0)
-                else:
-                    got = jnp.sum(
-                        jnp.where(mask, s, jnp.zeros((), s.dtype)),
-                        axis=0, dtype=s.dtype,
-                    )
-                return jnp.where(is_stutter, cur, got)
-
-            nxt = jax.tree.map(pick, state, succ)
+            if self.form == "drawn":
+                # choose, then apply: the model builds the drawn lane's
+                # successor alone; ``succ`` above is dead code the
+                # compiler drops, and ``valid`` all the draw needs
+                got = m.successor_at(state, lane_c)
+            else:
+                got = self._pick(succ, lane_c)
+            nxt = jax.tree.map(
+                lambda cur, g: jnp.where(is_stutter, cur, g), state, got
+            )
             n_enabled = jnp.sum(valid.astype(jnp.uint32)) + (
                 stutter.astype(jnp.uint32)
             )
@@ -595,9 +652,11 @@ class StreamingSimulator:
         self._fetch_n = 0
         self._frame_seq = 0
         self._keys = None
+        drawn = step_form(model) == "drawn"
         self.k = SimKernel(
             model, self.invariant_names, self.B, self.T, self.L,
-            self.S, self.dup_table_bits, SIM_STEP_CHUNK,
+            self.S, self.dup_table_bits,
+            SIM_DRAWN_STEP_CHUNK if drawn else SIM_STEP_CHUNK,
         )
 
     # ------------------------------------------------------------ sig
@@ -825,6 +884,7 @@ class StreamingSimulator:
             seed=self.seed,
             invariants=list(self.invariant_names),
             resume=resume,
+            sim_step_form=self.k.form,
         )
         if resume and resume_meta:
             if resume_meta.get("run_id"):
@@ -1130,6 +1190,12 @@ class StreamingSimulator:
             actions.append(names[aid] if aid < len(names) else str(aid))
         return trace, actions
 
+    def _drawn_steps(self, cum) -> int:
+        """Walker-steps whose successor the model built alone
+        (``successor_at``): every step in the drawn form, none in the
+        lanes form.  Host-derived, as ``steps`` is."""
+        return cum["steps"] if self.k.form == "drawn" else 0
+
     def _emit_sim_event(self, cum, epoch, wall) -> None:
         walks = self.B * (cum["steps"] // (self.B * self.T))
         dup = (
@@ -1144,6 +1210,7 @@ class StreamingSimulator:
             violations=cum["violations"],
             states=cum["states"],
             walks=walks,
+            drawn_steps=self._drawn_steps(cum),
             stutter_steps=cum["stutter"],
             enabled_lanes=cum["enabled"],
             dup_attempts=cum["dup_att"],
@@ -1182,6 +1249,7 @@ class StreamingSimulator:
         res.stats = self.last_stats
         self.last_stats.update(
             sim_steps=cum["steps"],
+            sim_drawn_steps=self._drawn_steps(cum),
             sim_states=cum["states"],
             sim_walks=walks,
             sim_walkers=self.B,

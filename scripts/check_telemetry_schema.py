@@ -259,10 +259,11 @@ SPILL_CUMULATIVE = (
 )
 
 # the sim record's cumulative counters (v11): each must be monotone
-# non-decreasing per run_id (the walk stream only moves forward)
+# non-decreasing per run_id (the walk stream only moves forward;
+# ``drawn_steps`` where a record has it: optional, PR 53)
 SIM_CUMULATIVE = (
     "steps", "states", "walks", "violations", "stutter_steps",
-    "enabled_lanes", "dup_attempts", "dup_hits",
+    "enabled_lanes", "dup_attempts", "dup_hits", "drawn_steps",
 )
 
 

@@ -4,7 +4,11 @@ parent's, bit for bit; behaviours on request (``-sim-dump``) held to
 the benchmark's own reference (``benchmark/ref/pyeval.py``) by the
 cell's own comparison; the draw's uniformity over the reference's
 successor sets; the simulated line and its digest; the engine's stage
-scopes, host phases and compile counters.
+scopes, host phases and compile counters.  Since ISSUE 53 a walker's
+step builds the one successor it drew (``successor_at``): the method
+against ``successors`` lane for lane, the two forms of the step as one
+stream, the compiled program without a lane axis, a frame the parent
+wrote resumed to the parent's digest.
 """
 
 import contextlib
@@ -12,6 +16,7 @@ import io
 import json
 import os
 import re
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -67,14 +72,17 @@ def check(argv, tmp_path, seed=5, dump=True, tel=False):
             rc = cli.main(argv)
         except SystemExit as e:
             rc = e.code
-    stats = {}
+    stats, header = {}, {}
     if tel and os.path.exists(tel_path):
         with open(tel_path, encoding="utf-8") as f:
             for line in f:
                 e = json.loads(line)
                 if e.get("event") == "result":
                     stats = e["stats"]
+                if e.get("event") == "run_header":
+                    header = e
     return {"rc": rc, "text": out.getvalue(), "stats": stats,
+            "header": header,
             "dump_prefix": prefix if dump else None}, err.getvalue()
 
 
@@ -119,21 +127,144 @@ PARENT_STREAMS = [
 ]
 
 
+class LanesOnly(CompactionModel):
+    """The compaction model with ``successor_at`` hidden: what the three
+    other hand models and every generated ``CompiledSpec`` look like to
+    the step, which then picks the drawn lane from all ``A``."""
+
+    successor_at = None
+
+
+FORMS = {"drawn": CompactionModel, "lanes": LanesOnly}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
 @pytest.mark.parametrize("chunk", [None, 48])
 @pytest.mark.parametrize("kw,rounds,digest,counters", PARENT_STREAMS)
 def test_the_chunked_step_walks_the_parents_stream_bit_for_bit(
-        monkeypatch, kw, rounds, digest, counters, chunk):
+        monkeypatch, kw, rounds, digest, counters, chunk, form):
+    """Both forms of the step, chunked or not, are ONE stream: the
+    parent's (PR 52's parent: every lane built, no chunk)."""
     if chunk is not None:
         monkeypatch.setattr(E, "SIM_STEP_CHUNK", chunk)
+        monkeypatch.setattr(E, "SIM_DRAWN_STEP_CHUNK", chunk)
     sim = StreamingSimulator(
-        CompactionModel(SMALL_CONFIGS["producer_on"]), max_rounds=rounds,
-        **kw)
+        FORMS[form](SMALL_CONFIGS["producer_on"]), max_rounds=rounds, **kw)
     st = sim.run().stats
     assert st["sim_keys_digest"] == digest
     assert {k: st[k] for k in counters} == counters
     assert st["sim_violations"] == 0
     want = 1 if chunk is None else -(-kw["n_walkers"] // chunk)
     assert st["sim_step_chunks"] == want
+    # the counter that says which mechanism built the successors
+    steps = kw["n_walkers"] * kw["depth"] * rounds
+    assert sim.k.form == form
+    assert st["sim_steps"] == steps
+    assert st["sim_drawn_steps"] == (steps if form == "drawn" else 0)
+
+
+def test_the_form_is_in_the_run_header_and_the_sim_records(clean, tmp_path):
+    assert clean["header"]["sim_step_form"] == "drawn"
+    assert clean["stats"]["sim_drawn_steps"] == clean["stats"]["sim_steps"]
+    tel = str(tmp_path / "lanes.jsonl")
+    StreamingSimulator(
+        LanesOnly(SMALL_CONFIGS["producer_on"]), n_walkers=16, depth=8,
+        segment_len=4, max_rounds=2, telemetry=tel).run()
+    with open(tel, encoding="utf-8") as f:
+        events = [json.loads(line) for line in f]
+    headers = [e for e in events if e["event"] == "run_header"]
+    assert [h["sim_step_form"] for h in headers] == ["lanes"]
+    sims = [e for e in events if e["event"] == "sim"]
+    assert len(sims) == 4 and all(e["drawn_steps"] == 0 for e in sims)
+    assert [e["steps"] for e in sims] == [64, 128, 192, 256]
+
+
+def _bindings():
+    return {
+        "producer_on": SMALL_CONFIGS["producer_on"],
+        "producer_off": SMALL_CONFIGS["no_retain"],
+        "scaled": cfgmod.to_constants(cfgmod.load(SCALED)),
+    }
+
+
+@pytest.mark.parametrize("binding", ["producer_on", "producer_off", "scaled"])
+def test_successor_at_is_the_lane_of_successors_leaf_for_leaf(binding):
+    """``successor_at(s, l)`` against ``successors(s)[0][l]`` for EVERY
+    lane ``l``, enabled or not, on every state of 24 simulated
+    behaviours: each leaf equal, of one dtype and one shape."""
+    c = _bindings()[binding]
+    assert c.model_producer == (binding != "producer_off")
+    model = CompactionModel(c)
+    depth = 100 if binding == "scaled" else 16
+    sim = StreamingSimulator(model, n_walkers=24, depth=depth, seed=53)
+    s0, states, _lanes = sim._replay(jnp.arange(24, dtype=jnp.uint32), 0)
+    flat = jax.tree.map(
+        lambda a, b: jnp.concatenate(
+            [a, b.reshape((-1,) + b.shape[2:])]), s0, states)
+    n = 24 * (depth + 1)
+    every, valid = jax.jit(jax.vmap(model.successors))(flat)
+    lanes = jnp.arange(model.A, dtype=jnp.int32)
+    one = jax.jit(jax.vmap(
+        lambda s: jax.vmap(lambda ln: model.successor_at(s, ln))(lanes)
+    ))(flat)
+    valid = np.asarray(valid)
+    assert valid.shape == (n, model.A)
+    # disabled lanes are among those compared, and every lane is met
+    # enabled somewhere but where the binding never enables it
+    assert (~valid).any() and valid.any(axis=0).sum() >= model.A - 2
+    assert len(np.unique(np.asarray(flat.cstate))) >= 4
+    for name in every._fields:
+        want, got = np.asarray(getattr(every, name)), np.asarray(
+            getattr(one, name))
+        assert want.dtype == got.dtype and want.shape == got.shape, name
+        assert np.array_equal(want, got), name
+
+
+def _segment_hlo(model, walkers=16):
+    sim = StreamingSimulator(
+        model, invariants=("TypeSafe", "CompactionHorizonCorrectness"),
+        n_walkers=walkers, depth=4, segment_len=2)
+    states, table = sim._fresh_buffers()
+    return E.ptt_sim_segment.lower(
+        states, table, jnp.int32(0), *sim._bases(), k=sim.k, restart=False
+    ).compile().as_text()
+
+
+def test_the_drawn_forms_program_holds_no_lane_axis():
+    """The compiled segment program at the scaled binding (34 lanes,
+    64 positions): the lanes form stacks ``[walkers, 34, 64]``, the
+    drawn form holds no array with a lane axis beside a ``[M]`` leaf's,
+    so the stacking cannot come back unseen."""
+    c = _bindings()["scaled"]
+    a, m = CompactionModel(c).A, c.message_sent_limit
+    assert (a, m) == (34, 64)
+    stacked = re.compile(rf"\[(?:\d+,)*{a},{m}\]|\[(?:\d+,)*{m},{a}\]")
+    assert stacked.search(_segment_hlo(LanesOnly(c)))
+    assert not stacked.search(_segment_hlo(CompactionModel(c)))
+
+
+def test_a_frame_the_parent_wrote_resumes_to_the_parents_digest(tmp_path):
+    """``tests/data/sim_frame_parent_pr52.ckpt``: written by the tree of
+    commit 94b16fc (every lane built) when suspended after four segments
+    of this run, which that tree finished at the digest below.  Frames
+    carry states and keys, not lanes."""
+    ck = str(tmp_path / "frame.ckpt")
+    shutil.copy(os.path.join(ROOT, "tests", "data",
+                             "sim_frame_parent_pr52.ckpt"), ck)
+    sim = StreamingSimulator(
+        CompactionModel(SMALL_CONFIGS["producer_on"]), n_walkers=96,
+        depth=12, segment_len=4, seed=53, max_rounds=3, checkpoint_path=ck)
+    res = sim.run(resume=True)
+    st = res.stats
+    assert sim.k.form == "drawn" and res.segments == 9
+    assert st["sim_keys_digest"] == (
+        "5d76b64d85bc8ea231b415eeff88653cda49a88961fd7402a2d31f398b8e0577")
+    assert {k: st[k] for k in ("sim_steps", "sim_stutter_steps",
+                               "sim_enabled_lanes", "sim_dup_hits")} == {
+        "sim_steps": 3456, "sim_stutter_steps": 0,
+        "sim_enabled_lanes": 7066, "sim_dup_hits": 2355}
+    # the steps of the frame's run were the parent's, this run's drawn
+    assert st["sim_drawn_steps"] == 3456
 
 
 def test_the_draw_is_jax_random_choice_draw_for_draw():
@@ -159,10 +290,15 @@ def test_the_draw_is_jax_random_choice_draw_for_draw():
 
 
 def test_the_chunk_is_a_constant_of_the_module_and_bounds_a_step():
+    """One constant a form of the step, chosen on the chip (PERF.md 6,
+    PR 52 and PR 53), and the form's is the one in the kernel's key."""
     assert E.SIM_STEP_CHUNK == 1 << 14
-    sim = StreamingSimulator(
-        CompactionModel(SMALL_CONFIGS["producer_on"]), n_walkers=64)
-    assert sim.k.chunk == E.SIM_STEP_CHUNK
+    assert E.SIM_DRAWN_STEP_CHUNK == 1 << 20
+    for form, chunk in (("drawn", E.SIM_DRAWN_STEP_CHUNK),
+                        ("lanes", E.SIM_STEP_CHUNK)):
+        sim = StreamingSimulator(
+            FORMS[form](SMALL_CONFIGS["producer_on"]), n_walkers=64)
+        assert (E.step_form(sim.model), sim.k.chunk) == (form, chunk)
 
 
 # ---- step 3 and 5 (a): behaviours on request --------------------------------
