@@ -678,15 +678,19 @@ class DeviceChecker:
         )
 
     def _stage_mark(self, name: str, out):
-        """Per-stage accounting.  Dispatch counts (``stage_<name>_n``)
-        are free host-side counters and always ride.  Under
-        ``PTT_STAGE_TIMING=1`` — the legacy differential mode — this
-        also blocks on ``out`` and charges the wait to
-        ``stage_<name>_s``, serializing the pipeline.  Each drain pays
-        one host<->device round trip; ``rtt_s`` (measured once at
+        """One more dispatch of the stage ``name``: ``stage_<name>_n``,
+        the free host-side counters ``dispatches_per_level`` sums (the
+        clock's ``calls`` of the stage's programs are the same numbers,
+        held to each other in the tests).  A stage is TIMED elsewhere:
+        on the device by the ``ptt.`` scopes of a trace, on the host by
+        the site's ``clock.call`` / ``clock.upload`` (obs/spans.py).
+        ``PTT_STAGE_TIMING=1`` is kept for the attribution model's
+        calibration alone (obs/attribution.py): it also blocks on
+        ``out`` here and charges the wait to ``stage_<name>_s``, which
+        serializes the pipeline it measures; each drain pays one
+        host<->device round trip, and ``rtt_s`` (measured once at
         warmup) is in ``last_stats`` so the report layer subtracts
-        ``stage_<name>_n x rtt_s`` — raw ``stage_<name>_s`` values
-        overstate device time."""
+        ``stage_<name>_n x rtt_s``."""
         self.last_stats[f"stage_{name}_n"] = (
             self.last_stats.get(f"stage_{name}_n", 0) + 1
         )
@@ -1398,6 +1402,7 @@ class DeviceChecker:
         _, rows_d, par_d, lan_d = staged
         self._seed_staged = None
         vks = bufs["vk"]  # insert straight into the main table
+        clock = self._clock
         n_vis = jnp.int32(0)
         off = 0
         for count in lsizes:
@@ -1407,21 +1412,25 @@ class DeviceChecker:
                 jrows = lax.dynamic_slice(
                     rows_d, (s0, 0), (NCs, W)
                 )
-                out = merge(
-                    *vks, jrows, jnp.int32(cn), n_vis, st["viol"],
-                    jnp.int32(s0), st["fpm"],
-                )
+                # one offset serves the merge and the write
+                with clock.upload("ptt_fpseed_merge2", 2):
+                    cn_d, s0_d = jnp.int32(cn), jnp.int32(s0)
+                with clock.call("ptt_fpseed_merge2"):
+                    out = merge(
+                        *vks, jrows, cn_d, n_vis, st["viol"], s0_d,
+                        st["fpm"],
+                    )
                 vks = out[: self.K]
                 n_vis, st["viol"], st["fpm"] = out[self.K:]
-                (
-                    bufs["rows"], bufs["parent"], bufs["lane"],
-                ) = write(
-                    bufs["rows"], bufs["parent"], bufs["lane"],
-                    jrows,
-                    lax.dynamic_slice(par_d, (s0,), (NCs,)),
-                    lax.dynamic_slice(lan_d, (s0,), (NCs,)),
-                    jnp.int32(s0),
-                )
+                jpar = lax.dynamic_slice(par_d, (s0,), (NCs,))
+                jlan = lax.dynamic_slice(lan_d, (s0,), (NCs,))
+                with clock.call("ptt_seed_write"):
+                    (
+                        bufs["rows"], bufs["parent"], bufs["lane"],
+                    ) = write(
+                        bufs["rows"], bufs["parent"], bufs["lane"],
+                        jrows, jpar, jlan, s0_d,
+                    )
             off += count
         bufs["vk"] = vks
         fpm = np.asarray(st["fpm"])
@@ -1462,8 +1471,10 @@ class DeviceChecker:
         # need past it is served by EVICTION, not growth
         # (_ensure_hot_capacity).
         grew = False
+        clock = self._clock
         while self.VCAP < need and self.VCAP < cap:
-            out = self._rehash_jit()(bufs["vk"])
+            with clock.call("ptt_rehash2"):
+                out = self._rehash_jit()(bufs["vk"])
             bufs["vk"] = out[: self.K]
             # the one host sync of a doubling: the fail-stop count and
             # the rehash's two counters in the same vector
@@ -1486,11 +1497,11 @@ class DeviceChecker:
             # all survivors at the base generation (a documented
             # coarsening — eviction order resets, membership and
             # discovery order are untouched)
-            bufs["gen"] = self._tag_jit()(
-                *bufs["vk"],
-                jnp.zeros((self.TCAP + 1,), jnp.int32),
-                jnp.int32(1),
-            )
+            gen0 = jnp.zeros((self.TCAP + 1,), jnp.int32)
+            with clock.upload("ptt_spill_tag", 1):
+                epoch_d = jnp.int32(1)
+            with clock.call("ptt_spill_tag"):
+                bufs["gen"] = self._tag_jit()(*bufs["vk"], gen0, epoch_d)
             self._epoch = 2
 
     def _rows_len(self) -> int:
@@ -1535,7 +1546,8 @@ class DeviceChecker:
         the ``ptt.grow`` scope (``bodies.ptt_grow``); the old buffer's
         bytes are the copy ``grow_copy_bytes`` counts."""
         self._growth.copy_bytes += buf.nbytes
-        return bodies.ptt_grow(buf, pad=pad)
+        with self._clock.call("ptt_grow"):
+            return bodies.ptt_grow(buf, pad=pad)
 
     @_growth_call
     def _grow_logs(self, bufs, need: int):
@@ -2389,10 +2401,13 @@ class DeviceChecker:
                     init_lanes=min(self.NCs, n_init - f_off)
                 )
                 with self._clock.phase("dispatch", level=1):
-                    out = self._init_jit()(
-                        bufs["ak"], bufs["arows"], jnp.int32(f_off),
-                        jnp.int32(w * self.NCs),
-                    )
+                    with self._clock.upload("ptt_init", 2):
+                        f_off_d = jnp.int32(f_off)
+                        acc_off_d = jnp.int32(w * self.NCs)
+                    with self._clock.call("ptt_init"):
+                        out = self._init_jit()(
+                            bufs["ak"], bufs["arows"], f_off_d, acc_off_d
+                        )
                 bufs["ak"], bufs["arows"] = out[:K], out[K]
                 w += 1
                 if w == self.FLUSH or f_off + self.NCs >= n_init:
@@ -2437,15 +2452,14 @@ class DeviceChecker:
         fpm block is returned untouched for the caller to parse."""
         # the host blocked on the device: host_fetch_s (= host_wait_s)
         with self._clock.phase("fetch"):
-            if vec is not None:
-                out = np.asarray(vec)
-            else:
-                out = np.asarray(
-                    self._stats_jit()(
+            dev = vec
+            if dev is None:
+                with self._clock.call("ptt_stats"):
+                    dev = self._stats_jit()(
                         st["n_visited"], st["dead_gid"], st["viol"],
                         st["fpm"],
                     )
-                )
+            out = np.asarray(dev)
         self._fetch_n += 1
         nv = int(out[0])
         self._snap["distinct_states"] = nv
@@ -2586,13 +2600,14 @@ class DeviceChecker:
             st["fpm"] = st["fpm"] + jnp.asarray(
                 [0, 0, 1] + [0] * (FPM_N - 3), jnp.int32
             )
-        out = self._stage_mark(
-            "flush",
-            self._fpflush_jit()(
-                bufs["vk"], bufs["ak"], jnp.int32(n_acc),
-                st["fpm"],
-            ),
-        )
+        clock = self._clock
+        with clock.upload("ptt_fpflush2", 1):
+            n_acc_d = jnp.int32(n_acc)
+        with clock.call("ptt_fpflush2"):
+            out = self._fpflush_jit()(
+                bufs["vk"], bufs["ak"], n_acc_d, st["fpm"]
+            )
+        self._stage_mark("flush", out)
         bufs["vk"] = out[:K]
         n_new, flag_acc, st["fpm"] = out[K], out[K + 1], out[K + 2]
         if self.tiered:
@@ -2610,24 +2625,26 @@ class DeviceChecker:
         # (its stale content is overwritten by expand DUS windows and
         # masked by n_acc at the next flush, the same contract the
         # accumulator always had)
-        crows, idx = self._stage_mark(
-            "compact",
-            self._compact_jit()(bufs["arows"], flag_acc),
-        )
+        with clock.call("ptt_compact"):
+            out = self._compact_jit()(bufs["arows"], flag_acc)
+        crows, idx = self._stage_mark("compact", out)
         bufs["arows"] = crows
+        with clock.upload("ptt_append", 5):
+            acc_base_d, is_init_d = jnp.int32(acc_base), jnp.bool_(is_init)
+            row_base_d = jnp.int32(rb["row_base"])
+            rows_ok_d = jnp.bool_(rb["rows_ok"])
+            log_base_d = jnp.int32(rb["row_base"] if self.tiered else 0)
+        with clock.call("ptt_append"):
+            out = self._append_jit()(
+                bufs["rows"], bufs["parent"], bufs["lane"],
+                crows, idx, n_new, st["n_visited"],
+                st["viol"], acc_base_d, is_init_d,
+                row_base_d, rows_ok_d, log_base_d,
+            )
         (
             bufs["rows"], bufs["parent"], bufs["lane"],
             st["n_visited"], st["viol"],
-        ) = self._stage_mark(
-            "append",
-            self._append_jit()(
-                bufs["rows"], bufs["parent"], bufs["lane"],
-                crows, idx, n_new, st["n_visited"],
-                st["viol"], jnp.int32(acc_base), jnp.bool_(is_init),
-                jnp.int32(rb["row_base"]), jnp.bool_(rb["rows_ok"]),
-                jnp.int32(rb["row_base"] if self.tiered else 0),
-            ),
-        )
+        ) = self._stage_mark("append", out)
 
     # ------------------------------------ tiered-store orchestration
 
@@ -2677,10 +2694,13 @@ class DeviceChecker:
         sliced on the device by ``program(buf, start, size=)``."""
         length = buf.shape[0]
         size, start = self._spill_fetch_window(n, off, length)
-        got = np.asarray(
-            buf if size == length
-            else program(buf, jnp.int32(start), size=size)
-        )
+        if size != length:
+            name = program.__name__
+            with self._clock.upload(name, 1):
+                start_d = jnp.int32(start)
+            with self._clock.call(name):
+                buf = program(buf, start_d, size=size)
+        got = np.asarray(buf)
         return got, got[off - start: off - start + n]
 
     def _spill_fetch(self, buf, n: int, off: int = 0) -> np.ndarray:
@@ -2711,9 +2731,11 @@ class DeviceChecker:
         size, start = self._spill_fetch_window(n, off, cols[0].shape[0])
         t0 = time.perf_counter()
         with spans.span("spill.fetch"):
-            got = np.asarray(
-                ptt_spill_fetch_cols(cols, jnp.int32(start), size=size)
-            ).reshape(len(cols), size)
+            with self._clock.upload("ptt_spill_fetch_cols", 1):
+                start_d = jnp.int32(start)
+            with self._clock.call("ptt_spill_fetch_cols"):
+                dev = ptt_spill_fetch_cols(cols, start_d, size=size)
+            got = np.asarray(dev).reshape(len(cols), size)
         dt = time.perf_counter() - t0
         outs = [
             row[off - start: off - start + n].view(c.dtype)
@@ -2752,9 +2774,10 @@ class DeviceChecker:
         if not self.tstore.has_cold_keys:
             return n_new, flag_acc
         K = self.K
-        out = self._stage_mark(
-            "sieve", self._sieve_jit()(*bufs["ak"], flag_acc)
-        )
+        clock = self._clock
+        with clock.call("ptt_spill_sieve"):
+            out = self._sieve_jit()(*bufs["ak"], flag_acc)
+        self._stage_mark("sieve", out)
         kc, lanes, n_dev = out[:K], out[K], out[K + 1]
         with spans.span("spill.sieve_wait"):
             n = int(np.asarray(n_dev))
@@ -2768,23 +2791,24 @@ class DeviceChecker:
             if dup.any():
                 false_lanes.append(lq[dup])
         self._hot_n += n
-        if not false_lanes:
-            return jnp.int32(n), flag_acc
-        fl = np.concatenate(false_lanes).astype(np.int32)
-        k = len(fl)
+        k = 0
+        if false_lanes:
+            fl = np.concatenate(false_lanes).astype(np.int32)
+            k = len(fl)
         P = self.UNFLAG_P
         for off in range(0, k, P):
             chunk = fl[off: off + P]
             padded = np.zeros((P,), np.int32)
             padded[: len(chunk)] = chunk
-            flag_acc = self._stage_mark(
-                "unflag",
-                self._unflag_jit()(
-                    flag_acc, jnp.asarray(padded),
-                    jnp.int32(len(chunk)),
-                ),
-            )
-        return jnp.int32(n - k), flag_acc
+            with clock.upload("ptt_spill_unflag", 2):
+                lanes_d, n_d = jnp.asarray(padded), jnp.int32(len(chunk))
+            with clock.call("ptt_spill_unflag"):
+                flag_acc = self._unflag_jit()(flag_acc, lanes_d, n_d)
+            self._stage_mark("unflag", flag_acc)
+        # the flush's corrected count: an argument of its append
+        with clock.upload("ptt_append", 1):
+            n_new = jnp.int32(n - k)
+        return n_new, flag_acc
 
     @spans.spanned("spill.evict")
     def _evict_cold_keys(self, bufs, cutoff: int) -> int:
@@ -2794,12 +2818,12 @@ class DeviceChecker:
         the evicted count."""
         K = self.K
         self._spill_evict_slots += self.TCAP
-        out = self._stage_mark(
-            "evict",
-            self._evict_jit()(
-                *bufs["vk"], bufs["gen"], jnp.int32(cutoff)
-            ),
-        )
+        clock = self._clock
+        with clock.upload("ptt_spill_evict", 1):
+            cutoff_d = jnp.int32(cutoff)
+        with clock.call("ptt_spill_evict"):
+            out = self._evict_jit()(*bufs["vk"], bufs["gen"], cutoff_d)
+        self._stage_mark("evict", out)
         holed, gen = out[:K], out[K]
         ev, n_dev = out[K + 1: 2 * K + 1], out[2 * K + 1]
         n = int(np.asarray(n_dev))
@@ -2809,9 +2833,9 @@ class DeviceChecker:
             bufs["vk"], bufs["gen"] = holed, gen
             return 0
         ev_np = self._spill_fetch_cols(ev, n)
-        out2 = self._stage_mark(
-            "evict", self._rehash_same_jit()(*holed)
-        )
+        with clock.call("ptt_spill_rehash"):
+            out2 = self._rehash_same_jit()(*holed)
+        self._stage_mark("evict", out2)
         vk, failed = out2[:K], out2[K]
         if int(np.asarray(failed)):
             raise RuntimeError(
@@ -2821,9 +2845,11 @@ class DeviceChecker:
         bufs["vk"] = vk
         # survivors restart at the base generation (their finer ages
         # died with the old slot layout — documented coarsening)
-        bufs["gen"] = self._tag_jit()(
-            *vk, jnp.zeros((self.TCAP + 1,), jnp.int32), jnp.int32(1)
-        )
+        gen0 = jnp.zeros((self.TCAP + 1,), jnp.int32)
+        with clock.upload("ptt_spill_tag", 1):
+            epoch_d = jnp.int32(1)
+        with clock.call("ptt_spill_tag"):
+            bufs["gen"] = self._tag_jit()(*vk, gen0, epoch_d)
         self._epoch = 2
         self.tstore.evict_keys(ev_np)
         self._hot_n -= n
@@ -2879,14 +2905,16 @@ class DeviceChecker:
         )
         self.tstore.spill_rows(base, upto, rows_np)
         self.tstore.spill_logs(base, upto, par_np, lan_np)
-        n_keep = nv - upto
-        bufs["rows"] = self._shift_jit()(
-            bufs["rows"], jnp.int32(upto - base), jnp.int32(n_keep)
-        )
-        bufs["parent"], bufs["lane"] = self._logshift_jit()(
-            bufs["parent"], bufs["lane"], jnp.int32(upto - base),
-            jnp.int32(n_keep),
-        )
+        clock = self._clock
+        # the two shifts take the same two scalars
+        with clock.upload("ptt_spill_shift", 2):
+            src_d, keep_d = jnp.int32(upto - base), jnp.int32(nv - upto)
+        with clock.call("ptt_spill_shift"):
+            bufs["rows"] = self._shift_jit()(bufs["rows"], src_d, keep_d)
+        with clock.call("ptt_spill_logshift"):
+            bufs["parent"], bufs["lane"] = self._logshift_jit()(
+                bufs["parent"], bufs["lane"], src_d, keep_d
+            )
         rb["row_base"] = upto
         self._spill_active = True
 
@@ -2942,9 +2970,12 @@ class DeviceChecker:
         """Level-boundary spill housekeeping: tag the epoch, spill
         aged rows/logs once spilling is active, keep the hot table
         inside the budget, and emit the cumulative ``spill`` record."""
-        bufs["gen"] = self._tag_jit()(
-            *bufs["vk"], bufs["gen"], jnp.int32(self._epoch)
-        )
+        with self._clock.upload("ptt_spill_tag", 1):
+            epoch_d = jnp.int32(self._epoch)
+        with self._clock.call("ptt_spill_tag"):
+            bufs["gen"] = self._tag_jit()(
+                *bufs["vk"], bufs["gen"], epoch_d
+            )
         self._epoch += 1
         # window pressure for the NEXT level: frontier + expand slack
         # + one blind append window
@@ -3201,11 +3232,13 @@ class DeviceChecker:
                     with self._clock.phase(
                         "dispatch", level=len(level_sizes) + 1
                     ):
-                        bufs["rows"] = self._shift_jit()(
-                            bufs["rows"],
-                            jnp.int32(level_base - rb["row_base"]),
-                            jnp.int32(nf),
-                        )
+                        with self._clock.upload("ptt_shift", 2):
+                            src_d = jnp.int32(level_base - rb["row_base"])
+                            nf_d = jnp.int32(nf)
+                        with self._clock.call("ptt_shift"):
+                            bufs["rows"] = self._shift_jit()(
+                                bufs["rows"], src_d, nf_d
+                            )
                     rb["row_base"] = level_base
                 if nf + self.G > self.LCAP:
                     # the frontier itself exceeds the rows window
@@ -3276,30 +3309,32 @@ class DeviceChecker:
                     raise faults.oom_error(
                         "level", len(level_sizes) + 1
                     )
+                clock = self._clock
                 for f_off in range(0, nf, self.G):
                     last = f_off + self.G >= nf
                     # live rows this window expands (the fused kernel
                     # counts the identical clip in-kernel)
                     self._work_add(expand_rows=min(self.G, nf - f_off))
-                    with self._clock.phase(
+                    with clock.phase(
                         "dispatch", level=len(level_sizes) + 1
                     ):
-                        out = self._stage_mark(
-                            "expand",
-                            self._expand_jit()(
-                                bufs["ak"], bufs["arows"],
-                                self._slice_jit()(
-                                    bufs["rows"],
-                                    jnp.int32(
-                                        level_base - rb["row_base"]
-                                        + f_off
-                                    ),
-                                ),
-                                jnp.int32(f_off), jnp.int32(nf),
-                                st["dead_gid"], jnp.int32(level_base),
-                                jnp.int32(w * self.NCs),
-                            ),
-                        )
+                        with clock.upload("ptt_slice", 1):
+                            off_d = jnp.int32(
+                                level_base - rb["row_base"] + f_off
+                            )
+                        with clock.call("ptt_slice"):
+                            window = self._slice_jit()(bufs["rows"], off_d)
+                        with clock.upload("ptt_expand", 4):
+                            f_off_d, nf_d = jnp.int32(f_off), jnp.int32(nf)
+                            level_base_d = jnp.int32(level_base)
+                            acc_off_d = jnp.int32(w * self.NCs)
+                        with clock.call("ptt_expand"):
+                            out = self._expand_jit()(
+                                bufs["ak"], bufs["arows"], window,
+                                f_off_d, nf_d, st["dead_gid"],
+                                level_base_d, acc_off_d,
+                            )
+                        self._stage_mark("expand", out)
                     bufs["ak"], bufs["arows"] = out[:K], out[K]
                     st["dead_gid"] = out[K + 1]
                     w += 1
@@ -3570,20 +3605,23 @@ class DeviceChecker:
                 with self._clock.phase(
                     "dispatch", level=len(level_sizes) + 1
                 ):
-                    out = self._stage_mark(
-                        "fused",
-                        self._fused_jit()(
-                            bufs["vk"], bufs["ak"], bufs["arows"],
-                            bufs["rows"], bufs["parent"], bufs["lane"],
-                            st["n_visited"], st["dead_gid"],
-                            st["viol"], st["fpm"], st["wkm"],
+                    with self._clock.upload("ptt_level2", 7):
+                        scalars = (
                             jnp.int32(level_base), jnp.int32(nf),
                             jnp.int32(w_off), jnp.int32(lv_cap),
                             jnp.int32(self._groups_cap()),
                             jnp.int32(rb["row_base"]),
                             jnp.bool_(rb["rows_ok"]),
-                        ),
-                    )
+                        )
+                    with self._clock.call("ptt_level2"):
+                        out = self._fused_jit()(
+                            bufs["vk"], bufs["ak"], bufs["arows"],
+                            bufs["rows"], bufs["parent"], bufs["lane"],
+                            st["n_visited"], st["dead_gid"],
+                            st["viol"], st["fpm"], st["wkm"],
+                            *scalars,
+                        )
+                    self._stage_mark("fused", out)
                 bufs["vk"] = out[:K]
                 bufs["ak"] = out[K: 2 * K]
                 (
@@ -3957,10 +3995,12 @@ class DeviceChecker:
         where an eager fill and concatenate at the frame's own length
         compiled anew for every frame."""
         self._restore_h2d_bytes += host.nbytes
-        up = jnp.array(host)  # a copy: the flush donates these buffers
+        with self._clock.upload("ptt_restore_pad", 1):
+            up = jnp.array(host)  # a copy: the flush donates these buffers
         if len(host) == length:
             return up
-        return bodies.ptt_restore_pad(up, length=length)
+        with self._clock.call("ptt_restore_pad"):
+            return bodies.ptt_restore_pad(up, length=length)
 
     def _restore_frame(self):
         """Rebuild device buffers + level frame from the checkpoint;
@@ -4077,11 +4117,11 @@ class DeviceChecker:
             self._spill_active = bool(
                 self.tstore.has_cold_keys or self.tstore._rows
             )
-            bufs["gen"] = self._tag_jit()(
-                *bufs["vk"],
-                jnp.zeros((self.TCAP + 1,), jnp.int32),
-                jnp.int32(1),
-            )
+            gen0 = jnp.zeros((self.TCAP + 1,), jnp.int32)
+            with self._clock.upload("ptt_spill_tag", 1):
+                epoch_d = jnp.int32(1)
+            with self._clock.call("ptt_spill_tag"):
+                bufs["gen"] = self._tag_jit()(*bufs["vk"], gen0, epoch_d)
         n_inv = len(self.invariant_names)
         st = {
             "n_visited": jnp.int32(nv),
@@ -4231,9 +4271,12 @@ class DeviceChecker:
             and self._last_rb["row_base"] > 0
         ):
             return self._trace_tiered(bufs, gid, max_depth)
-        gids, lanes, g_end = self._chain_jit(max_depth)(
-            bufs["parent"], bufs["lane"], jnp.int32(gid)
-        )
+        with self._clock.upload("step", 1):
+            gid_d = jnp.int32(gid)
+        with self._clock.call("step"):
+            gids, lanes, g_end = self._chain_jit(max_depth)(
+                bufs["parent"], bufs["lane"], gid_d
+            )
         gids = np.asarray(gids)
         lanes = np.asarray(lanes)
         g_end = int(np.asarray(g_end))

@@ -665,13 +665,11 @@ class LivenessChecker:
         clock = self._clock
         with clock.phase("live_table"):
             rows = self._rows_padded(cap + (G - 1) * SF)
-            targs = self._table_jit(cap)(rows, jnp.int32(n))
+            with clock.upload("ptt_live_table", 1):
+                n_d = jnp.int32(n)
+            with clock.call("ptt_live_table"):
+                targs = self._table_jit(cap)(rows, n_d)
         jitted = self._sweep_jit(cap, G)
-
-        def sweep(*args):
-            with clock.phase("sweep_dispatch"):
-                return jitted(*args)
-
         starts = list(range(0, n, SF))
         src_parts, dst_parts = [], []
         out_deg = np.zeros((n,), np.int64)
@@ -697,25 +695,20 @@ class LivenessChecker:
             chunks=len(starts) - c0, groups=len(gstarts),
             query_lanes=(len(starts) - c0) * NQ,
         )
-        pending = (
-            [sweep(rows, jnp.int32(starts[gstarts[0]]), jnp.int32(n),
-                   *targs)]
-            if gstarts
-            else []
-        )
+        pending = []
+        sent = 0  # groups dispatched so far: gstarts[:sent]
         for gi, g0 in enumerate(gstarts):
-            if not pending:  # serial mode: dispatch this group now
-                pending.append(
-                    sweep(rows, jnp.int32(starts[g0]), jnp.int32(n),
-                          *targs)
-                )
-            if prefetch and gi + 1 < len(gstarts):
-                pending.append(
-                    sweep(
-                        rows, jnp.int32(starts[gstarts[gi + 1]]),
-                        jnp.int32(n), *targs,
-                    )
-                )
+            # this group, if it is not in flight yet, and with
+            # prefetch the next one: ONE dispatch site for both
+            ahead = gi + (2 if prefetch else 1)
+            while sent < min(ahead, len(gstarts)):
+                with clock.phase("sweep_dispatch"):
+                    with clock.upload("ptt_sweep", 2):
+                        off_d = jnp.int32(starts[gstarts[sent]])
+                        n_d = jnp.int32(n)
+                    with clock.call("ptt_sweep"):
+                        pending.append(jitted(rows, off_d, n_d, *targs))
+                sent += 1
             nk_g, idx_g, dst_g = pending.pop(0)
             # three transfers per GROUP: the counts, then the two
             # edge planes sliced to the group's max kept prefix — the
@@ -1100,6 +1093,9 @@ class LivenessChecker:
             **spans.compile_meter().since(self._jit0),
         )
         stats.update(self._clock.host_seconds(spans.LIVE_PHASES))
+        # what the sweep's dispatch phase is made of (sweep_dispatch_*,
+        # sweep_calls_by_phase); the explorer's own ride in ITS stats
+        stats.update(self._clock.call_stats("sweep_dispatch"))
         self.tel.emit(
             "result",
             distinct_states=lres.distinct_states,
@@ -1178,9 +1174,11 @@ class LivenessChecker:
         with clock.phase("live_goal"):
             cap = self._table_cap(n)
             rows = self._rows_padded(cap)
-            goal = np.asarray(
-                self._goal_jit(cap)(rows, jnp.int32(n))
-            )[:n]
+            with clock.upload("ptt_live_goal", 1):
+                n_d = jnp.int32(n)
+            with clock.call("ptt_live_goal"):
+                goal_d = self._goal_jit(cap)(rows, n_d)
+            goal = np.asarray(goal_d)[:n]
         cprob = self.keys.collision_prob(n)
 
         out_deg = None

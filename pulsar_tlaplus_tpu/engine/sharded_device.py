@@ -530,7 +530,12 @@ class ShardedDeviceChecker:
                 )
             )
             self._jits[key] = fn
-        return fn(jnp.asarray(fill, dtype))
+        # the program is a ``shard_map`` of a lambda and has no name of
+        # its own: ``fill`` on the clock
+        with self._clock.upload("fill", 1):
+            fill_d = jnp.asarray(fill, dtype)
+        with self._clock.call("fill"):
+            return fn(fill_d)
 
     def _alloc_acc(self, bufs):
         """(Re)allocate the per-shard accumulator buffers (fresh run,
@@ -1237,13 +1242,18 @@ class ShardedDeviceChecker:
         nloc_d = jax.device_put(counts.astype(np.int32), sh)
         jax.block_until_ready(rows_d)
         write = self._seed_write_jit()
+        clock = self._clock
         for off in range(0, Mp, SC):
-            (
-                bufs["rows"], bufs["parent"], bufs["lane"], st["viol"],
-            ) = write(
-                bufs["rows"], bufs["parent"], bufs["lane"], st["viol"],
-                rows_d, par_d, lane_d, nloc_d, jnp.int32(off),
-            )
+            with clock.upload("ptt_shard_seed_write", 1):
+                off_d = jnp.int32(off)
+            with clock.call("ptt_shard_seed_write"):
+                (
+                    bufs["rows"], bufs["parent"], bufs["lane"],
+                    st["viol"],
+                ) = write(
+                    bufs["rows"], bufs["parent"], bufs["lane"],
+                    st["viol"], rows_d, par_d, lane_d, nloc_d, off_d,
+                )
         jax.block_until_ready(bufs["rows"])
         st["n_visited"] = jax.device_put(counts.astype(np.int32), sh)
         # key insertion through the regular routed flush (append
@@ -1254,10 +1264,13 @@ class ShardedDeviceChecker:
                 seed_round = self._seed_round_jit(SRC)
                 w = 0
                 for off in range(0, Mp, SRC):
-                    out = seed_round(
-                        bufs["ak"], bufs["aq"], bufs["aq2"], st["rt"],
-                        rows_d, nloc_d, jnp.int32(off), jnp.int32(w),
-                    )
+                    with clock.upload("ptt_shard_seed_round", 2):
+                        off_d, w_d = jnp.int32(off), jnp.int32(w)
+                    with clock.call("ptt_shard_seed_round"):
+                        out = seed_round(
+                            bufs["ak"], bufs["aq"], bufs["aq2"],
+                            st["rt"], rows_d, nloc_d, off_d, w_d,
+                        )
                     bufs["ak"] = tuple(out[0])
                     bufs["aq"], bufs["aq2"], st["rt"] = out[1:]
                     w += 1
@@ -1265,11 +1278,14 @@ class ShardedDeviceChecker:
                         # singleton meshes pack contiguously (w * SRC
                         # keys); routed meshes rebuild full RCV windows
                         n_acc = w * (SRC if N == 1 else self.RCV)
-                        fout = self._flush_jit()(
-                            bufs["vk"], bufs["ak"], bufs["aq"],
-                            bufs["aq2"], st["n_keys"], st["fpm"],
-                            jnp.int32(n_acc),
-                        )
+                        with clock.upload("ptt_shard_flush", 1):
+                            n_acc_d = jnp.int32(n_acc)
+                        with clock.call("ptt_shard_flush"):
+                            fout = self._flush_jit()(
+                                bufs["vk"], bufs["ak"], bufs["aq"],
+                                bufs["aq2"], st["n_keys"], st["fpm"],
+                                n_acc_d,
+                            )
                         bufs["vk"] = tuple(fout[0])
                         st["n_keys"] = fout[1]
                         st["fpm"] = fout[4]
@@ -1336,7 +1352,8 @@ class ShardedDeviceChecker:
     @spans.in_phase("grow")
     def _grow_visited(self, bufs, need: int):
         while self.VCAP < need:
-            out = self._rehash_jit()(bufs["vk"])
+            with self._clock.call("ptt_shard_rehash"):
+                out = self._rehash_jit()(bufs["vk"])
             bufs["vk"] = tuple(out[0])
             # one fetch, as before: every shard's fail-stop count and
             # its two rehash counters
@@ -2024,11 +2041,14 @@ class ShardedDeviceChecker:
                 w = 0
                 for base in range(0, n_init, per_round):
                     with self._clock.phase("dispatch", level=1):
-                        out = self._init_round_jit()(
-                            bufs["ak"], bufs["arows"], bufs["apar"],
-                            bufs["alane"], bufs["aq"], bufs["aq2"],
-                            st["rt"], jnp.int32(base), jnp.int32(w),
-                        )
+                        with self._clock.upload("ptt_shard_init", 2):
+                            base_d, w_d = jnp.int32(base), jnp.int32(w)
+                        with self._clock.call("ptt_shard_init"):
+                            out = self._init_round_jit()(
+                                bufs["ak"], bufs["arows"], bufs["apar"],
+                                bufs["alane"], bufs["aq"], bufs["aq2"],
+                                st["rt"], base_d, w_d,
+                            )
                     bufs["ak"] = tuple(out[0])
                     (
                         bufs["arows"], bufs["apar"], bufs["alane"],
@@ -2075,12 +2095,12 @@ class ShardedDeviceChecker:
         the per-shard fpset metrics [flushes, probe rounds, failures,
         valid lanes, max probe rounds]."""
         with self._clock.phase("fetch"):
-            out = np.asarray(
-                self._stats_jit()(
+            with self._clock.call("ptt_shard_stats"):
+                dev = self._stats_jit()(
                     st["n_visited"], st["n_keys"], st["dead"],
                     st["viol"], st["rt"], st["fpm"],
                 )
-            )
+            out = np.asarray(dev)
         self._fetch_n += 1
         n_inv = len(self.invariant_names)
         nv = int(out[:, 0].sum())
@@ -2185,10 +2205,14 @@ class ShardedDeviceChecker:
             bump = np.zeros((self.N, FPM_N), np.int32)
             bump[0, 2] = 1
             st["fpm"] = st["fpm"] + jnp.asarray(bump)
-        out = self._flush_jit()(
-            bufs["vk"], bufs["ak"], bufs["aq"], bufs["aq2"],
-            st["n_keys"], st["fpm"], jnp.int32(n_acc),
-        )
+        clock = self._clock
+        with clock.upload("ptt_shard_flush", 1):
+            n_acc_d = jnp.int32(n_acc)
+        with clock.call("ptt_shard_flush"):
+            out = self._flush_jit()(
+                bufs["vk"], bufs["ak"], bufs["aq"], bufs["aq2"],
+                st["n_keys"], st["fpm"], n_acc_d,
+            )
         bufs["vk"] = tuple(out[0])
         st["n_keys"], n_new, flag_local = out[1], out[2], out[3]
         st["fpm"] = out[4]
@@ -2196,20 +2220,22 @@ class ShardedDeviceChecker:
         # accumulator comes back compacted and is recycled as the next
         # fill's buffers (stale content is overwritten by the next
         # round's DUS windows and masked by n_acc at the next flush)
-        crows, cpar, clane = self._compact_jit()(
-            bufs["arows"], bufs["apar"], bufs["alane"], flag_local
-        )
+        with clock.call("ptt_shard_compact"):
+            crows, cpar, clane = self._compact_jit()(
+                bufs["arows"], bufs["apar"], bufs["alane"], flag_local
+            )
         bufs["arows"], bufs["apar"], bufs["alane"] = crows, cpar, clane
         self._compact_n += 1
         self.last_stats["stage_compact_n"] = self._compact_n
-        (
-            bufs["rows"], bufs["parent"], bufs["lane"],
-            st["n_visited"], st["viol"],
-        ) = self._append_jit()(
-            bufs["rows"], bufs["parent"], bufs["lane"],
-            crows, cpar, clane,
-            n_new, st["n_visited"], st["viol"],
-        )
+        with clock.call("ptt_shard_append"):
+            (
+                bufs["rows"], bufs["parent"], bufs["lane"],
+                st["n_visited"], st["viol"],
+            ) = self._append_jit()(
+                bufs["rows"], bufs["parent"], bufs["lane"],
+                crows, cpar, clane,
+                n_new, st["n_visited"], st["viol"],
+            )
 
     @spans.in_phase("grow")
     def _grow_route(self, bufs, st):
@@ -2431,12 +2457,14 @@ class ShardedDeviceChecker:
     def _run_one_level(self, t0, bufs, st, stats, nv, lb, nf, level):
         """Expand level ``level``; returns (stats, nv2, stop)."""
         self._grow_store(bufs, int((lb + nf).max()) + self.G)
-        lb_dev = jax.device_put(
-            np.asarray(lb, np.int32), self._shard()
-        )
-        nf_dev = jax.device_put(
-            np.asarray(nf, np.int32), self._shard()
-        )
+        # a level's two bounds, made once for all its rounds
+        with self._clock.upload("ptt_shard_round", 2):
+            lb_dev = jax.device_put(
+                np.asarray(lb, np.int32), self._shard()
+            )
+            nf_dev = jax.device_put(
+                np.asarray(nf, np.int32), self._shard()
+            )
         rounds = int(-(-nf.max() // self.G))
         stop = False
         pending = 0
@@ -2449,12 +2477,15 @@ class ShardedDeviceChecker:
         for r in range(rounds):
             last = r + 1 >= rounds
             with self._clock.phase("dispatch", level=level):
-                out = self._round_jit()(
-                    bufs["ak"], bufs["arows"], bufs["apar"],
-                    bufs["alane"], bufs["aq"], bufs["aq2"],
-                    bufs["rows"], lb_dev, nf_dev, st["dead"], st["rt"],
-                    jnp.int32(r), jnp.int32(w),
-                )
+                with self._clock.upload("ptt_shard_round", 2):
+                    r_d, w_d = jnp.int32(r), jnp.int32(w)
+                with self._clock.call("ptt_shard_round"):
+                    out = self._round_jit()(
+                        bufs["ak"], bufs["arows"], bufs["apar"],
+                        bufs["alane"], bufs["aq"], bufs["aq2"],
+                        bufs["rows"], lb_dev, nf_dev, st["dead"],
+                        st["rt"], r_d, w_d,
+                    )
             bufs["ak"] = tuple(out[0])
             (
                 bufs["arows"], bufs["apar"], bufs["alane"],

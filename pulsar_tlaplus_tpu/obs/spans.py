@@ -53,7 +53,18 @@ variable, exporter or telemetry event of their own:
   ``run()`` sum to its wall.  Inside the phase ``spill`` plain spans
   name what the host does there (``ptt:spill.sieve_wait``, ``.fetch``,
   ``.lookup``, ``.evict``, ``.rows``, ``.join``): they split the
-  phase's idle time and add nothing to the clock.
+  phase's idle time and add nothing to the clock.  Two overlays of the
+  clock say what a phase's seconds are made of, ``dispatch`` first:
+  ``clock.call(<program>)`` around ONE call of ONE jitted program
+  (span ``ptt:call.<program>``; the program's calls, the seconds from
+  entry to return of the call, which is the asynchronous launch and
+  not the device's work, and the compile meter's seconds inside it)
+  and ``clock.upload(<program>, n)`` around the making of ``n`` host
+  values into device arrays for it (span ``ptt:upload``).  Both are
+  entered with an inline ``with`` at the site, never through a wrapper
+  between the site and the program, and neither touches a phase's
+  seconds: ``call_stats()`` takes them out of the phase's total and
+  what is left is the engine's own Python.
 - **The compile meter** (``compile_meter``): one process-wide
   ``jax.monitoring`` listener, registered on first use, that counts per
   calling thread how often JAX traced, lowered, compiled or loaded from
@@ -146,12 +157,69 @@ def staged(name: str):
 
 # ----------------------------------------------------------- host: spans
 
+_annotation = None  # jax.profiler.TraceAnnotation, on first use
+
+
 def span(name: str, **fields):
     """A host span ``ptt:<name>`` in the profiler's trace (``fields``
     ride as the event's stats)."""
-    from jax.profiler import TraceAnnotation
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation as _annotation
+    return _annotation(SPAN_PREFIX + name, **fields)
 
-    return TraceAnnotation(SPAN_PREFIX + name, **fields)
+
+# one record a (phase, program) on a clock: the indices of
+# [calls, call_s, uploads, upload_s, jit_s]
+_CALLS, _CALL_S, _UPLOADS, _UPLOAD_S, _JIT_S = range(5)
+
+
+class _Call:
+    """One call of one program on a clock (``PhaseClock.call``)."""
+
+    __slots__ = ("rec", "jit", "ann", "t", "j")
+
+    def __init__(self, rec, jit, ann):
+        self.rec, self.jit, self.ann = rec, jit, ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.j = self.jit["host_s"]
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t
+        self.ann.__exit__(*exc)
+        rec = self.rec
+        rec[_CALLS] += 1
+        rec[_CALL_S] += dt
+        # the meter counts a trace nested in a trace twice: the part
+        # of THIS call that was JAX's is at most the call
+        rec[_JIT_S] += min(self.jit["host_s"] - self.j, dt)
+        return False
+
+
+class _Upload:
+    """One site's host values made device arrays
+    (``PhaseClock.upload``)."""
+
+    __slots__ = ("rec", "n", "ann", "t")
+
+    def __init__(self, rec, n, ann):
+        self.rec, self.n, self.ann = rec, n, ann
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t
+        self.ann.__exit__(*exc)
+        self.rec[_UPLOADS] += self.n
+        self.rec[_UPLOAD_S] += dt
+        return False
 
 
 def spanned(name: str):
@@ -198,7 +266,13 @@ class PhaseClock:
 
     ``with clock.phase("dispatch", level=n):`` enters the span
     ``ptt:dispatch`` (carrying ``run_id`` and ``level``) and charges the
-    time to ``dispatch``; a phase entered inside it pauses it.  Used
+    time to ``dispatch``; a phase entered inside it pauses it.
+
+    ``with clock.call("ptt_append"):`` around one call of a jitted
+    program and ``with clock.upload("ptt_append", 5):`` around the
+    making of its five host scalars into device arrays are overlays:
+    they count and time under whatever phase is open (``calls``) and
+    leave its seconds alone.  No phase is entered inside one.  Used
     from the run's own thread only."""
 
     def __init__(self, run_id: Optional[str] = None):
@@ -206,6 +280,9 @@ class PhaseClock:
         self.t0 = time.perf_counter()
         self.seconds: Dict[str, float] = {}
         self._stack = []  # [name, charged-up-to]
+        # {phase: {program: [calls, call_s, uploads, upload_s, jit_s]}}
+        # of the overlays, by the phase open at the site ("" for none)
+        self.calls: Dict[str, Dict[str, list]] = {}
         # the longest stretch between two level-boundary records (the
         # first runs from the start of the run) and the level it ends on
         self.level_wall_max_s = 0.0
@@ -222,6 +299,36 @@ class PhaseClock:
 
     def phase(self, name: str, **fields) -> _Phase:
         return _Phase(self, name, span(name, run_id=self.run_id, **fields))
+
+    def _rec(self, program: str) -> list:
+        phase = self._stack[-1][0] if self._stack else ""
+        by = self.calls.get(phase)
+        if by is None:
+            by = self.calls[phase] = {}
+        rec = by.get(program)
+        if rec is None:
+            rec = by[program] = [0, 0.0, 0, 0.0, 0.0]
+        return rec
+
+    def call(self, program: str) -> _Call:
+        """Around ONE call of the jitted program ``program``: the span
+        ``ptt:call.<program>``, one more of its calls, the seconds from
+        entry to return (the launch; the device works on after it) and
+        those of them the compile meter saw JAX trace, lower, compile
+        or load in."""
+        return _Call(
+            self._rec(program), compile_meter()._mine(),
+            span("call." + program),
+        )
+
+    def upload(self, program: str, n: int) -> _Upload:
+        """Around the making of ``n`` host values into device arrays
+        (``jnp.int32(...)``, ``jnp.asarray(...)`` of host data) for a
+        call of ``program``: the span ``ptt:upload``, and the values
+        and seconds on the program's record."""
+        return _Upload(
+            self._rec(program), n, span("upload", program=program)
+        )
 
     def _push(self, name):
         now = time.perf_counter()
@@ -279,10 +386,55 @@ class PhaseClock:
         out["host_unaccounted_s"] = wall - sum(out.values())
         return out
 
+    def call_stats(self, phase: str = "dispatch") -> Dict[str, object]:
+        """What the phase ``phase`` (``dispatch``, or the sweep's
+        ``sweep_dispatch``) is made of, from the overlays:
+        ``<phase>_calls`` / ``_call_s`` (program calls made under it
+        and their seconds), ``_uploads`` / ``_upload_s`` (host values
+        made device arrays at its sites), ``_jit_s`` (the part of
+        ``_call_s`` that was JAX's own tracing, lowering, compiling or
+        cache loading), ``_python_s`` (``host_<phase>_s`` less calls
+        and uploads: the engine's Python around them, so the three add
+        up to the phase) and ``_by_program``, ``{program: [calls,
+        call_s, uploads, upload_s, jit_s]}``.  The overlays entered
+        under every OTHER phase are summed in ``calls_by_phase``,
+        ``{phase: [calls, call_s, uploads, upload_s]}``, and listed in
+        ``programs_by_phase``, ``{phase: {program: [the five]}}`` (both
+        keys carry the prefix ``phase`` has before ``dispatch``)."""
+
+        def rounded(rec):
+            return [round(v, 6) if isinstance(v, float) else v for v in rec]
+
+        def summed(by, width):
+            return [sum(r[i] for r in by.values()) for i in range(width)]
+
+        by = self.calls.get(phase, {})
+        calls, call_s, uploads, upload_s, jit_s = summed(by, 5)
+        others = {p: b for p, b in self.calls.items() if p != phase}
+        prefix = phase[: -len("dispatch")]
+        return {
+            f"{phase}_calls": calls,
+            f"{phase}_call_s": call_s,
+            f"{phase}_uploads": uploads,
+            f"{phase}_upload_s": upload_s,
+            f"{phase}_jit_s": jit_s,
+            f"{phase}_python_s": self.seconds_of(phase) - call_s - upload_s,
+            f"{phase}_by_program": {k: rounded(r) for k, r in by.items()},
+            f"{prefix}calls_by_phase": {
+                p: rounded(summed(b, 4)) for p, b in others.items()
+            },
+            f"{prefix}programs_by_phase": {
+                p: {k: rounded(r) for k, r in b.items()}
+                for p, b in others.items()
+            },
+        }
+
     def stats(self) -> Dict[str, float]:
-        """``host_seconds()`` of a ``DeviceChecker.run()``, the longest
-        level stretch and the longest stay in ``grow``."""
+        """``host_seconds()`` of a ``DeviceChecker.run()`` with its
+        ``call_stats()``, the longest level stretch and the longest
+        stay in ``grow``."""
         out = self.host_seconds()
+        out.update(self.call_stats())
         out["level_wall_max_s"] = self.level_wall_max_s
         out["level_wall_max_at"] = self.level_wall_max_at
         self._settle_grow(self._boundary_level + 1)
@@ -312,6 +464,10 @@ _COUNTERS = (
     "traces", "trace_s", "lowerings", "lower_s", "compile_requests",
     "compile_request_s", "cache_hits", "cache_misses", "cache_load_s",
     "body_traces",
+    # trace_s + lower_s + compile_request_s as ONE running total (a
+    # compile request spans its cache load): what ``PhaseClock.call``
+    # reads on entry and on return
+    "host_s",
 )
 
 
@@ -341,6 +497,8 @@ class CompileMeter:
         if keys[0]:
             c[keys[0]] += 1
         c[keys[1]] += secs
+        if event != _CACHE_LOAD:
+            c["host_s"] += secs
 
     def _event(self, event, **_kw):
         if event == _CACHE_HIT:

@@ -652,6 +652,9 @@ class StreamingSimulator:
         self._fetch_n = 0
         self._frame_seq = 0
         self._keys = None
+        # a run's own clock replaces this one: it takes what a warm-up
+        # calls before any run
+        self._clock = spans.PhaseClock()
         drawn = step_form(model) == "drawn"
         self.k = SimKernel(
             model, self.invariant_names, self.B, self.T, self.L,
@@ -686,17 +689,22 @@ class StreamingSimulator:
         return self._keys
 
     def _segment(self, states, table, epoch: int, restart: bool):
-        return ptt_sim_segment(
-            states, table, jnp.int32(epoch), *self._bases(),
-            k=self.k, restart=restart,
-        )
+        bases = self._bases()
+        with self._clock.upload("ptt_sim_segment", 1):
+            epoch_d = jnp.int32(epoch)
+        with self._clock.call("ptt_sim_segment"):
+            return ptt_sim_segment(
+                states, table, epoch_d, *bases, k=self.k, restart=restart
+            )
 
     def _replay(self, walkers, r0: int):
         """``ptt_sim_replay`` of walkers (u32[K]) of the round that
         starts at step ``r0``."""
-        return ptt_sim_replay(
-            walkers, jnp.int32(r0), *self._bases(), k=self.k
-        )
+        bases = self._bases()
+        with self._clock.upload("ptt_sim_replay", 1):
+            r0_d = jnp.int32(r0)
+        with self._clock.call("ptt_sim_replay"):
+            return ptt_sim_replay(walkers, r0_d, *bases, k=self.k)
 
     def warmup(self) -> float:
         """Compile both segment variants up front; returns wall
@@ -1121,6 +1129,7 @@ class StreamingSimulator:
             )
             self._set_rates(res, t0)
         self.last_stats.update(clock.host_seconds(spans.SIM_PHASES))
+        self.last_stats.update(clock.call_stats())
         self._emit_result(res)
         return res
 
@@ -1138,9 +1147,11 @@ class StreamingSimulator:
         ws = [(i * b // k + self.seed) % b for i in range(k)]
         r0 = (epoch - self.segs_per_round) * self.L
         rnd = epoch // self.segs_per_round  # rounds completed
-        ws_dev = jnp.asarray(ws, jnp.uint32)
+        with self._clock.upload("ptt_sim_replay", 1):
+            ws_dev = jnp.asarray(ws, jnp.uint32)
         s0, replayed, lanes = self._replay(ws_dev, r0)
-        mismatches = ptt_sim_replay_check(replayed, ws_dev, states)
+        with self._clock.call("ptt_sim_replay_check"):
+            mismatches = ptt_sim_replay_check(replayed, ws_dev, states)
         s0, replayed, lanes = jax.tree.map(
             np.asarray, (s0, replayed, lanes)
         )
@@ -1312,9 +1323,10 @@ class StreamingSimulator:
         r0 = (g_state // self.T) * self.T  # behavior-round start
         n_steps = 0 if is_init else g_state - r0 + 1
         res.violation_step = None if is_init else g_state
+        with self._clock.upload("ptt_sim_replay", 1):
+            w_dev = jnp.asarray([walker], jnp.uint32)
         s0, states, lanes = jax.tree.map(
-            lambda x: x[0],
-            self._replay(jnp.asarray([walker], jnp.uint32), r0),
+            lambda x: x[0], self._replay(w_dev, r0)
         )
         lane_log = np.asarray(lanes)
         res.trace, res.trace_actions = self._behaviour(

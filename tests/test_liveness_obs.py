@@ -290,3 +290,54 @@ def test_cli_liveness_explorer_starts_at_cli_checks_tiers(monkeypatch):
         CompactionModel(BINDINGS["producer_on"]), fairness="wf_next"
     )
     assert own._checker.TCAP == 2 * (1 << 14)
+
+
+# ---- what the sweep's dispatches are made of (ISSUE 54) -----------------
+
+SWEEP_SPLIT_KEYS = (
+    "sweep_dispatch_calls", "sweep_dispatch_call_s",
+    "sweep_dispatch_uploads", "sweep_dispatch_upload_s",
+    "sweep_dispatch_jit_s", "sweep_dispatch_python_s",
+    "sweep_dispatch_by_program", "sweep_calls_by_phase",
+    "sweep_programs_by_phase",
+)
+
+
+@pytest.mark.parametrize("case", SWEPT, ids=ids(SWEPT))
+def test_the_sweep_says_what_its_dispatches_are_made_of(
+    case, tmp_path_factory
+):
+    """The liveness run's own clock splits ``sweep_dispatch``; the
+    explorer's split of ITS ``dispatch`` rides underneath, as its other
+    stats do."""
+    from tests.test_spans import SPLIT_KEYS, assert_split_adds_up
+
+    _res, events = run_case(case, tmp_path_factory)
+    st = [e for e in events if e["event"] == "result"][-1]["stats"]
+    for k in (*SWEEP_SPLIT_KEYS, *SPLIT_KEYS):
+        assert k in st, k
+    assert_split_adds_up(st, "sweep_dispatch")
+    assert_split_adds_up(st)
+    # a group a dispatch: one call of the sweep's program, two scalars
+    by = st["sweep_dispatch_by_program"]
+    assert list(by) == ["ptt_sweep"]
+    assert by["ptt_sweep"][0] == st["sweep_groups"] > 0
+    assert by["ptt_sweep"][2] == 2 * st["sweep_groups"]
+    assert 0.0 < st["sweep_dispatch_jit_s"] <= st["sweep_dispatch_call_s"]
+    # the two other programs of a liveness run, each under its phase
+    others = st["sweep_programs_by_phase"]
+    assert others["live_table"]["ptt_live_table"][:1] == [1]
+    assert others["live_goal"]["ptt_live_goal"][:1] == [1]
+    assert st["sweep_calls_by_phase"]["live_goal"][0] == 1
+    # the explorer's programs are on ITS clock, not on this one
+    assert "explore" not in others
+    assert "ptt_level2" in st["dispatch_by_program"]
+
+
+def test_an_unfair_run_sweeps_nothing_and_says_so(tmp_path_factory):
+    _res, events = run_case(("producer_on", "none"), tmp_path_factory)
+    st = [e for e in events if e["event"] == "result"][-1]["stats"]
+    assert st["sweep_dispatch_calls"] == 0
+    assert st["sweep_dispatch_by_program"] == {}
+    assert st["sweep_dispatch_python_s"] == st["host_sweep_dispatch_s"] == 0.0
+    assert list(st["sweep_programs_by_phase"]) == ["live_goal"]

@@ -403,3 +403,106 @@ def test_profiler_trace_shows_phase_spans_inside_the_run_span(tmp_path):
         sum(e[2] - e[1] for e in ev if e[0] == "ptt:run") / 1e9,
         r.wall_s, rtol=0.05,
     )
+
+
+# ---- what a sharded run's dispatches are made of (ISSUE 54) -------------
+
+# read off the parent commit (6085604), same constructor arguments: every
+# shard's parent and lane logs up to its count (the discovery order), and
+# the counterexample of the shipped binding's published bug
+PARENT_LOGS_SHA256 = (
+    "0aac45d6414e0fe345229043e0639224ec53ad34776b76baddc313db8cdab7c1"
+)
+PARENT_BUG = dict(
+    n=5832, gid=915, levels=[729, 1458, 1458, 2187],
+    actions=["CompactorPhaseOne", "CompactorPhaseTwoWrite",
+             "CompactorPhaseTwoUpdateContext"],
+)
+# the programs ``_dispatch_n`` counts: those built by ``_program``
+COUNTED = (
+    "ptt_shard_round", "ptt_shard_init", "ptt_shard_flush",
+    "ptt_shard_compact", "ptt_shard_append", "ptt_shard_stats",
+    "ptt_shard_rehash", "ptt_shard_seed_write", "ptt_shard_seed_round",
+)
+
+
+@pytest.mark.parametrize("seeded", [False, True], ids=["fresh", "seeded"])
+def test_dispatch_parts_add_up_on_the_mesh(seeded, tmp_path):
+    from tests.test_spans import (
+        SPLIT_KEYS, assert_split_adds_up, calls_of,
+    )
+
+    c = SMALL_CONFIGS["producer_on"]
+    stream = str(tmp_path / "split.jsonl")
+    ck = _mk(c, telemetry=stream)
+    seed = (CompactionModel(c).host_seed(max_level_states=40, max_total=120)
+            if seeded else None)
+    r = ck.run(seed=seed)
+    assert r.distinct_states == 1654
+    st = ck.last_stats
+    for k in SPLIT_KEYS:
+        assert k in st, k
+    assert_split_adds_up(st)
+    by = st["dispatch_by_program"]
+    want = {"ptt_shard_round", "ptt_shard_flush", "ptt_shard_compact",
+            "ptt_shard_append"} | (set() if seeded else {"ptt_shard_init"})
+    assert set(by) == want
+    assert by["ptt_shard_compact"][0] == st["stage_compact_n"]
+    assert by["ptt_shard_round"][2] == 2 * by["ptt_shard_round"][0]
+    # the two counts of a dispatch: every call of a program built by
+    # ``_program``, under whatever phase, is one of ``_dispatch_n``
+    assert sum(calls_of(st, p) for p in COUNTED) == ck._dispatch_n
+    assert st["dispatches_per_level"] == round(
+        ck._dispatch_n / r.diameter, 2)
+    others = st["programs_by_phase"]
+    assert others["fetch"]["ptt_shard_stats"][0] == st["stats_fetches"]
+    assert others["grow"]["ptt_shard_rehash"][0] > 0
+    # the fills of a growth and of the first buffers count on the clock
+    # and are no dispatch of the engine's
+    assert others["grow"]["fill"][0] > 0 and others["init"]["fill"][0] > 0
+    # a level's two bounds are made device arrays once for its rounds
+    bounds = others["account"]["ptt_shard_round"]
+    assert bounds[0] == 0 and bounds[2] % 2 == 0
+    assert 0 < bounds[2] // 2 <= r.diameter
+    if seeded:
+        assert others["seed_load"]["ptt_shard_seed_write"][0] > 0
+        assert others["seed_load"]["ptt_shard_seed_round"][0] > 0
+        assert others["seed_load"]["ptt_shard_flush"][0] > 0
+    with open(stream, encoding="utf-8") as f:
+        events = [json.loads(x) for x in f if x.strip()]
+    (res,) = [e for e in events if e["event"] == "result"]
+    for k in SPLIT_KEYS:
+        assert k in res["stats"], k
+    assert _checker_mod().validate_stream(stream) == []
+
+
+def test_hoisting_the_scalars_changed_no_state_on_the_mesh():
+    import hashlib
+
+    ck = _mk(SMALL_CONFIGS["two_crashes"])
+    r = ck.run()
+    assert [int(x) for x in r.level_sizes] == PARENT_LEVELS["two_crashes"]
+    counts = ck.last_stats_matrix[:, 0]
+    assert [int(x) for x in counts] == [1035] * 4
+    h = hashlib.sha256()
+    for s in range(ck.N):
+        for k in ("parent", "lane"):
+            h.update(np.asarray(
+                ck.last_bufs[k][s, : counts[s]]).astype(np.int32).tobytes())
+    assert h.hexdigest() == PARENT_LOGS_SHA256
+
+
+def test_the_sharded_counterexample_is_the_parents():
+    from tests.helpers import assert_valid_counterexample
+
+    inv = "DuplicateNullKeyMessage"
+    ck = ShardedDeviceChecker(
+        CompactionModel(pe.SHIPPED_CFG), n_devices=4, invariants=(inv,),
+        sub_batch=128, visited_cap=1 << 10,
+    )
+    r = ck.run()
+    assert r.violation == inv and r.violation_gid == PARENT_BUG["gid"]
+    assert r.distinct_states == PARENT_BUG["n"]
+    assert [int(x) for x in r.level_sizes] == PARENT_BUG["levels"]
+    assert [str(a) for a in r.trace_actions] == PARENT_BUG["actions"]
+    assert_valid_counterexample(pe.SHIPPED_CFG, r.trace, r.trace_actions, inv)

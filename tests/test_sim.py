@@ -646,3 +646,52 @@ def test_cli_check_simulate_routes_streaming_engine(
     evs, _ = report.load_events(st)
     hd = report.header(evs)
     assert hd["engine"] == "sim" and hd["mode"] == "simulate"
+
+
+# ---- what a simulation's dispatches are made of (ISSUE 54) --------------
+
+
+def test_a_simulation_says_what_its_dispatches_are_made_of(
+    small_model, tmp_path
+):
+    from tests.test_spans import (
+        SPLIT_KEYS, _checker_mod, assert_split_adds_up,
+    )
+
+    stream = str(tmp_path / "sim.jsonl")
+    sim = StreamingSimulator(
+        small_model, telemetry=stream, dump_path=str(tmp_path / "b"),
+        dump_num=4, **SMALL_KW,
+    )
+    r = sim.run()
+    st = sim.last_stats
+    for k in SPLIT_KEYS:
+        assert k in st, k
+    assert_split_adds_up(st)
+    # a segment a dispatch: one call and one scalar (the epoch) each
+    segments = r.steps // (SMALL_KW["n_walkers"] * SMALL_KW["segment_len"])
+    assert list(st["dispatch_by_program"]) == ["ptt_sim_segment"]
+    seg = st["dispatch_by_program"]["ptt_sim_segment"]
+    assert seg[0] == seg[2] == segments == st["dispatch_calls"]
+    # (the programs are units: traced once a process, maybe before this)
+    assert 0.0 <= st["dispatch_jit_s"] <= st["dispatch_call_s"]
+    # the dump replays the last round and checks it on the device
+    dump = st["programs_by_phase"]["dump"]
+    assert dump["ptt_sim_replay"][0] == 1 and dump["ptt_sim_replay"][2] == 2
+    assert dump["ptt_sim_replay_check"][0] == 1
+    assert st["calls_by_phase"]["dump"][0] == 2
+    assert len(r.dump_files) == 4 and st["sim_dump_mismatches"] == 0
+    with open(stream, encoding="utf-8") as f:
+        events = [json.loads(x) for x in f if x.strip()]
+    (res,) = [e for e in events if e["event"] == "result"]
+    for k in SPLIT_KEYS:
+        assert k in res["stats"], k
+    assert _checker_mod().validate_stream(stream) == []
+
+
+def test_a_warm_up_before_any_run_has_a_clock_to_count_on(small_model):
+    sim = StreamingSimulator(small_model, **SMALL_KW)
+    assert sim.warmup() > 0.0
+    assert sim._clock.calls[""]["ptt_sim_segment"][0] == 2
+    r = sim.run()  # and the run's own clock starts at nothing
+    assert sim.last_stats["dispatch_calls"] == r.steps // (128 * 4)

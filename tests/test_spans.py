@@ -644,3 +644,266 @@ def test_compile_meter_threads_do_not_read_each_other():
     assert got["busy"]["jit_backend_compiles"] >= 1
     assert got["idle"]["jit_traces"] == 0
     assert got["idle"]["jit_host_s"] == 0.0
+
+
+# ---- (g) what a dispatch is made of (ISSUE 54) --------------------------
+
+SPLIT_KEYS = (
+    "dispatch_calls", "dispatch_call_s", "dispatch_uploads",
+    "dispatch_upload_s", "dispatch_jit_s", "dispatch_python_s",
+    "dispatch_by_program", "calls_by_phase", "programs_by_phase",
+)
+# the stage counters ``dispatches_per_level`` is made of, and the
+# programs whose calls each one counts
+STAGE_PROGRAMS = {
+    "flush": ("ptt_fpflush2",), "compact": ("ptt_compact",),
+    "append": ("ptt_append",), "expand": ("ptt_expand",),
+    "fused": ("ptt_level2",), "sieve": ("ptt_spill_sieve",),
+    "unflag": ("ptt_spill_unflag",),
+    "evict": ("ptt_spill_evict", "ptt_spill_rehash"),
+}
+
+
+def calls_of(st, program, prefix=""):
+    """Calls of ``program`` under every phase, from a result's stats."""
+    tables = [st[prefix + "dispatch_by_program"]]
+    tables += st[prefix + "programs_by_phase"].values()
+    return sum(t[program][0] for t in tables if program in t)
+
+
+def assert_split_adds_up(st, phase="dispatch"):
+    """The three parts are the phase, within a millisecond, the jit
+    seconds lie inside the calls', and the table is the totals."""
+    parts = (st[f"{phase}_call_s"] + st[f"{phase}_upload_s"]
+             + st[f"{phase}_python_s"])
+    assert abs(parts - st[f"host_{phase}_s"]) < 1e-3
+    assert 0.0 <= st[f"{phase}_jit_s"] <= st[f"{phase}_call_s"] + 1e-9
+    assert st[f"{phase}_python_s"] > -1e-6
+    table = st[f"{phase}_by_program"].values()
+    assert sum(r[0] for r in table) == st[f"{phase}_calls"]
+    assert sum(r[2] for r in table) == st[f"{phase}_uploads"]
+    assert sum(r[1] for r in table) == pytest.approx(
+        st[f"{phase}_call_s"], abs=1e-4)
+    assert sum(r[3] for r in table) == pytest.approx(
+        st[f"{phase}_upload_s"], abs=1e-4)
+
+
+def test_call_and_upload_are_overlays_of_the_open_phase():
+    clock = spans.PhaseClock("rid")
+    with clock.phase("account"):
+        with clock.phase("dispatch", level=2):
+            with clock.upload("ptt_a", 3):
+                time.sleep(0.01)
+            with clock.call("ptt_a"):
+                time.sleep(0.02)
+            time.sleep(0.01)
+            with clock.phase("spill"):
+                with clock.call("ptt_b"):
+                    time.sleep(0.01)
+            with clock.call("ptt_a"):
+                pass
+    with clock.call("ptt_c"):  # under no phase: kept, under ""
+        pass
+    st = clock.stats()
+    for k in SPLIT_KEYS:
+        assert k in st, k
+    assert st["dispatch_calls"] == 2 and st["dispatch_uploads"] == 3
+    assert 0.018 <= st["dispatch_call_s"] < 0.04
+    assert 0.008 <= st["dispatch_upload_s"] < 0.03
+    assert 0.008 <= st["dispatch_python_s"] < 0.03
+    assert st["dispatch_jit_s"] == 0.0
+    assert_split_adds_up(st)
+    a = st["dispatch_by_program"]["ptt_a"]
+    assert a[0] == 2 and a[2] == 3 and list(st["dispatch_by_program"]) == [
+        "ptt_a"]
+    # a call under another phase is that phase's, and no phase's seconds
+    # moved: the overlays add nothing to the clock
+    assert st["calls_by_phase"]["spill"][0] == 1
+    assert st["calls_by_phase"][""][0] == 1
+    assert st["programs_by_phase"]["spill"]["ptt_b"][0] == 1
+    assert 0.008 <= st["host_spill_s"] < 0.03
+    assert 0.035 <= st["host_dispatch_s"] < 0.08
+    total = sum(v for k, v in st.items()
+                if k.startswith("host_") and k.endswith("_s"))
+    assert abs(total - clock.elapsed()) < 0.005
+
+
+def test_a_call_counts_even_where_the_program_raises():
+    clock = spans.PhaseClock()
+    with clock.phase("dispatch"):
+        with pytest.raises(ValueError):
+            with clock.call("ptt_a"):
+                raise ValueError("in a call")
+    st = clock.call_stats()
+    assert st["dispatch_calls"] == 1 and clock._stack == []
+
+
+def test_call_charges_the_meters_seconds_inside_it():
+    """A program's first call traces, lowers and compiles inside the
+    call: ``jit_s``.  Its second is JAX's cache's: none."""
+    fn = jax.jit(lambda x: (x * 5 + 2).sum())
+    x = jnp.arange(11)
+    clock = spans.PhaseClock()
+    with clock.phase("dispatch"):
+        with clock.call("first"):
+            fn(x)
+        with clock.call("second"):
+            fn(x)
+    by = clock.call_stats()["dispatch_by_program"]
+    assert 0.0 < by["first"][4] <= by["first"][1]
+    assert by["second"][4] == 0.0 and by["second"][1] < by["first"][1]
+
+
+def test_the_meters_running_total_is_jit_host_s():
+    meter = spans.compile_meter()
+    before = meter.snapshot()
+    jax.jit(lambda x: x * 7 - 1)(jnp.arange(5)).block_until_ready()
+    d = meter.since(before)
+    total = meter.snapshot()["host_s"] - before["host_s"]
+    assert total > 0.0
+    # a compile request spans its cache load; else the sums are one
+    assert total == pytest.approx(d["jit_host_s"], rel=1e-6) or (
+        d["jit_cache_load_s"] > 0.0 and total <= d["jit_host_s"])
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_dispatch_parts_add_up_and_name_every_program(fuse, tmp_path):
+    stream = str(tmp_path / f"split_{fuse}.jsonl")
+    ck = _mk(SMALL_CONFIGS["producer_on"], fuse=fuse, telemetry=stream)
+    r = ck.run()
+    st = ck.last_stats
+    for k in SPLIT_KEYS:
+        assert k in st, k
+    assert_split_adds_up(st)
+    by = st["dispatch_by_program"]
+    want = {"ptt_init", "ptt_fpflush2", "ptt_compact", "ptt_append"}
+    want |= ({"ptt_level2"} if fuse == "level"
+             else {"ptt_slice", "ptt_expand"})
+    assert set(by) == want
+    # the two counts of a dispatch: the clock's calls of the stage
+    # programs are the stage counters dispatches_per_level sums
+    stages = {k[len("stage_"):-len("_n")]: v for k, v in st.items()
+              if k.startswith("stage_") and k.endswith("_n")}
+    assert stages and set(stages) <= set(STAGE_PROGRAMS)
+    for stage, n in stages.items():
+        assert sum(calls_of(st, p) for p in STAGE_PROGRAMS[stage]) == n
+    assert st["dispatches_per_level"] == round(
+        sum(stages.values()) / r.diameter, 2)
+    # what a "dispatch" does not count: the init program, the slice
+    # inside an expand, the stats program of a fetch, the growers
+    assert by["ptt_init"][0] == 1
+    if fuse == "stage":
+        assert by["ptt_slice"][0] == by["ptt_expand"][0]
+        assert by["ptt_expand"][2] == 4 * by["ptt_expand"][0]
+        assert by["ptt_append"][2] == 5 * by["ptt_append"][0]
+    else:
+        assert by["ptt_level2"][2] == 7 * by["ptt_level2"][0]
+    others = st["programs_by_phase"]
+    assert others["fetch"]["ptt_stats"][0] == (
+        st["stats_fetches"] - stages.get("fused", 0))
+    assert set(others["grow"]) <= {"ptt_rehash2", "ptt_grow"}
+    assert others["grow"]["ptt_rehash2"][0] == st["grow_rehashes"]
+    assert st["calls_by_phase"]["grow"][0] == sum(
+        r_[0] for r_ in others["grow"].values())
+    # what a first run traced and compiled, it did inside its calls (the
+    # units are traced once a process: maybe by a test before this one)
+    assert 0.0 <= st["dispatch_jit_s"] <= st["jit_host_s"] + 1e-6
+    with open(stream, encoding="utf-8") as f:
+        events = [json.loads(x) for x in f if x.strip()]
+    (res,) = [e for e in events if e["event"] == "result"]
+    for k in SPLIT_KEYS:
+        assert k in res["stats"], k
+    assert_split_adds_up(res["stats"])
+    assert res["stats"]["dispatch_by_program"].keys() == by.keys()
+    assert _checker_mod().validate_stream(stream) == []
+
+
+class _RecordedSpan:
+    def __init__(self, rec, name, fields):
+        self.rec, self.name, self.fields = rec, name, fields
+
+    def __enter__(self):
+        self.rec.seen.append((self.name, tuple(self.rec.open), self.fields))
+        self.rec.open.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        assert self.rec.open.pop() == self.name
+        return False
+
+
+class _Recorder:
+    """Stands in for ``spans.span``: the spans a run opens, in order,
+    each with the names open around it."""
+
+    def __init__(self):
+        self.open, self.seen = [], []
+
+    def __call__(self, name, **fields):
+        return _RecordedSpan(self, name, fields)
+
+
+@pytest.mark.parametrize("fuse", ["level", "stage"])
+def test_every_call_and_upload_span_lies_inside_a_phase(fuse, monkeypatch):
+    rec = _Recorder()
+    monkeypatch.setattr(spans, "span", rec)
+    ck = _mk(SMALL_CONFIGS["producer_on"], fuse=fuse)
+    ck.run()
+    assert rec.open == []
+    st = ck.last_stats
+    overlays = [s for s in rec.seen
+                if s[0].startswith("call.") or s[0] == "upload"]
+    assert overlays
+    for name, around, fields in overlays:
+        # the innermost span around it is a phase of the clock, and the
+        # run's span is around that
+        assert around and around[-1] in spans.PHASES, (name, around)
+        assert around[0] == "run"
+        if name == "upload":
+            assert fields["program"].startswith("ptt_")
+    # one span a call, one an upload group: none per row, lane or round
+    programs = {p for t in (st["dispatch_by_program"],
+                            *st["programs_by_phase"].values()) for p in t}
+    for p in programs:
+        assert len([s for s in overlays if s[0] == "call." + p]) == (
+            calls_of(st, p)), p
+    groups = len([s for s in overlays if s[0] == "upload"])
+    n_up = st["dispatch_uploads"] + sum(
+        v[2] for v in st["calls_by_phase"].values())
+    assert 0 < groups <= n_up
+    assert groups <= st["dispatch_calls"] + sum(
+        v[0] for v in st["calls_by_phase"].values())
+
+
+def test_spans_py_alone_constructs_a_trace_annotation():
+    pkg = os.path.join(ROOT, "pulsar_tlaplus_tpu")
+    hits = []
+    for path in glob.glob(os.path.join(pkg, "**", "*.py"), recursive=True):
+        with open(path, encoding="utf-8") as f:
+            if "TraceAnnotation" in f.read():
+                hits.append(os.path.relpath(path, pkg))
+    assert hits == [os.path.join("obs", "spans.py")]
+
+
+def test_profiler_trace_shows_call_spans_inside_the_dispatch_span(tmp_path):
+    ck = _mk(SMALL_CONFIGS["producer_on"], fuse="stage")
+    ck.run()  # compiled, so the traced run is short
+    po = jax.profiler.ProfileOptions()
+    po.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=po)
+    try:
+        ck.run()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(str(tmp_path))
+    st = ck.last_stats
+    phases = [e for e in ev if e[0] == "ptt:dispatch"]
+    calls = [e for e in ev if e[0] == "ptt:call.ptt_fpflush2"]
+    ups = [e for e in ev if e[0] == "ptt:upload"]
+    assert len(calls) == st["dispatch_by_program"]["ptt_fpflush2"][0]
+    assert {e[3]["program"] for e in ups} >= {"ptt_fpflush2", "ptt_append"}
+    # an untiered run makes every flush's calls and uploads under dispatch
+    for _n, s, e, stats in calls + ups:
+        if stats.get("program", "ptt_fpflush2") in ("ptt_fpflush2",
+                                                    "ptt_append"):
+            assert any(p[1] <= s and e <= p[2] for p in phases)

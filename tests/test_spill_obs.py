@@ -567,3 +567,97 @@ def test_an_untiered_run_has_no_new_key():
     st = ck.last_stats
     assert not [k for k in st if k.startswith("spill_")]
     assert st["host_spill_s"] == 0.0 and "hbm_budget" not in st
+
+
+# ---- what a spilled run's dispatches are made of (ISSUE 54) -------------
+
+# the stage counters of the spill programs, and the programs each counts
+SPILL_STAGES = {
+    "sieve": ("ptt_spill_sieve",), "unflag": ("ptt_spill_unflag",),
+    "evict": ("ptt_spill_evict", "ptt_spill_rehash"),
+}
+# read off the parent commit (6085604), same constructor arguments: the
+# parent and lane logs of every state in gid order (the discovery order),
+# cold runs first, and the tiered counterexample
+PARENT_LOGS_SHA256 = (
+    "8ff482a8b833d1dcc8cdd6987496a5e1e6acfdb6620589a2f2f3842f836feedb"
+)
+PARENT_LEVELS = [1, 5, 24, 56, 76, 108, 124, 128, 156, 156, 160, 192,
+                 212, 56, 88, 112]
+PARENT_BUG = dict(
+    n=3741, gid=3645, levels=[729, 1458, 1458, 96],
+    actions=["CompactorPhaseOne", "CompactorPhaseTwoWrite",
+             "CompactorPhaseTwoUpdateContext"],
+)
+
+
+def test_every_spill_program_dispatched_is_on_the_clock(tiered):
+    from tests.test_spans import assert_split_adds_up, calls_of
+
+    ck, _r = tiered
+    st = ck.last_stats
+    assert_split_adds_up(st)
+    # a spill program is launched under ``spill`` (from a flush, from
+    # the level boundary) or under ``grow``: in the other phases'
+    # tables, not lost
+    spill = st["programs_by_phase"]["spill"]
+    for stage, programs in SPILL_STAGES.items():
+        assert st[f"stage_{stage}_n"] > 0, stage
+        assert sum(calls_of(st, p) for p in programs) == (
+            st[f"stage_{stage}_n"]), stage
+        assert set(programs) <= set(spill), stage
+    for p in ("ptt_spill_tag", "ptt_spill_shift", "ptt_spill_logshift",
+              "ptt_spill_fetch_cols"):
+        assert spill[p][0] > 0, p
+    # a shift and a log shift a spill of aged rows, on the same scalars
+    assert spill["ptt_spill_shift"][0] == spill["ptt_spill_logshift"][0]
+    assert spill["ptt_spill_shift"][2] == 2 * spill["ptt_spill_shift"][0]
+    assert spill["ptt_spill_logshift"][2] == 0
+    # a fetch that brings a buffer whole calls no program
+    fetched = sum(calls_of(st, p)
+                  for p in ("ptt_spill_fetch", "ptt_spill_fetch_cols"))
+    assert 0 < fetched <= st["spill_fetches"]
+    assert st["calls_by_phase"]["spill"][0] == sum(
+        r[0] for r in spill.values())
+    # the corrected count of a flush is an upload for its append, made
+    # under ``spill``: uploads with no call there
+    assert spill["ptt_append"][0] == 0 < spill["ptt_append"][2]
+    assert spill["ptt_append"][2] == st["stage_sieve_n"]
+    # the stage programs under ``dispatch`` are the stage counters too
+    by = st["dispatch_by_program"]
+    for stage, p in (("flush", "ptt_fpflush2"), ("compact", "ptt_compact"),
+                     ("append", "ptt_append"), ("expand", "ptt_expand")):
+        assert by[p][0] == st[f"stage_{stage}_n"], stage
+
+
+def test_hoisting_the_scalars_changed_no_state_of_a_tiered_run(tiered):
+    import hashlib
+
+    ck, r = tiered
+    assert [int(x) for x in r.level_sizes] == PARENT_LEVELS
+    base = ck._last_rb["row_base"]
+    assert base == 1542
+    cold_par, cold_lan = ck.tstore.fetch_logs(0, base)
+    n = r.distinct_states - base
+    par = np.concatenate([cold_par, np.asarray(ck.last_bufs["parent"][:n])])
+    lan = np.concatenate([cold_lan, np.asarray(ck.last_bufs["lane"][:n])])
+    digest = hashlib.sha256(
+        par.astype(np.int32).tobytes() + lan.astype(np.int32).tobytes()
+    ).hexdigest()
+    assert digest == PARENT_LOGS_SHA256
+
+
+def test_the_tiered_counterexample_is_the_parents():
+    from tests.helpers import assert_valid_counterexample
+
+    inv = "DuplicateNullKeyMessage"
+    kw = dict(invariants=(inv,), check_deadlock=True)
+    ck = _mk(pe.SHIPPED_CFG, hbm_budget=tight_hbm_budget(
+        lambda b: _mk(pe.SHIPPED_CFG, hbm_budget=b, **kw)), **kw)
+    r = ck.run()
+    assert r.violation == inv and r.violation_gid == PARENT_BUG["gid"]
+    assert r.distinct_states == PARENT_BUG["n"]
+    assert [int(x) for x in r.level_sizes] == PARENT_BUG["levels"]
+    assert [str(a) for a in r.trace_actions] == PARENT_BUG["actions"]
+    assert ck.last_stats["spill_evictions"] == 1
+    assert_valid_counterexample(pe.SHIPPED_CFG, r.trace, r.trace_actions, inv)
