@@ -441,14 +441,19 @@ class CompactionModel(ByConstants):
     def _context_ledger_bits(self, s: SState) -> jax.Array:
         """bool[M] kept-position mask of compactedLedgers[compactedTopicContext];
         all-false when context = 0 or the slot is Nil (the TLC out-of-domain
-        case, never forced on reachable states — SURVEY.md C23)."""
+        case, never forced on reachable states — SURVEY.md C23).
+
+        The slot is a value of the state, so it is read as it is written
+        (_phase_two_write, _delete_ledger): a one-hot select over the C
+        slots.  ``s.led_mask[slot]`` is a per-state gather under vmap."""
         if self.C == 0:
             return jnp.zeros((self.M,), jnp.bool_)
         slot = jnp.clip(s.context - 1, 0, self.C - 1)
-        words = s.led_mask[slot]
-        present = (s.context >= 1) & (
-            jnp.take(s.led_present, slot, axis=0) == 1
+        onehot = jnp.arange(self.C, dtype=jnp.int32) == slot
+        words = jnp.max(
+            jnp.where(onehot[:, None], s.led_mask, jnp.uint32(0)), axis=0
         )
+        present = (s.context >= 1) & jnp.any(onehot & (s.led_present == 1))
         return self._mask_bits(words) & present
 
     def compaction_horizon_correctness(self, s: SState) -> jax.Array:
